@@ -16,7 +16,7 @@ StableHLO module XLA would compile. Three properties gate the manifest:
   avals (pure shape math, deterministic everywhere). Pool growth or an
   accidentally materialized copy shows up here.
 * **sharding signatures** — arguments carrying a ``NamedSharding`` lower
-  with ``mhlo.sharding`` attributes; the sorted multiset of those strings
+  with ``sdy.sharding`` attributes; the sorted multiset of those strings
   is the replication-creep gate for mesh variants.
 
 FLOPs / bytes-accessed from ``Lowered.cost_analysis()`` are recorded as
@@ -32,7 +32,9 @@ from typing import Any
 __all__ = ["audit_variant", "lower_variant", "count_aliased", "tree_bytes"]
 
 _ALIAS_RE = re.compile(r"tf\.aliasing_output")
-_SHARDING_RE = re.compile(r'mhlo\.sharding = "([^"]*)"')
+# jax 0.9 lowers with the Shardy partitioner: argument shardings are
+# ``sdy.sharding = #sdy.sharding<@mesh, [{}, {"tp"}]>`` attributes
+_SHARDING_RE = re.compile(r'sdy\.sharding = #sdy\.sharding<@\w+, (\[[^>]*\])>')
 
 
 def tree_bytes(tree: Any) -> int:
@@ -97,7 +99,7 @@ def audit_variant(
 
     Returns a manifest-entry dict: ``donated_leaves`` (declared),
     ``aliased`` (what lowering kept), ``arg_bytes``/``out_bytes`` (static
-    footprint), optional ``arg_shardings`` (sorted mhlo strings, mesh
+    footprint), optional ``arg_shardings`` (sorted sdy strings, mesh
     variants only), and non-gated ``info`` (flops / bytes accessed).
     """
     lowered = lower_variant(fn, args, static_kwargs)

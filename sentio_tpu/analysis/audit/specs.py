@@ -20,7 +20,7 @@ Variant-space honesty notes:
   its variants here sweep the static axes (steps x sampled) at one
   representative shape point;
 * mesh variants lower the same families with 2-device tp-sharded state and
-  record the ``mhlo.sharding`` argument signatures; the live params/pool
+  record the ``sdy.sharding`` argument signatures; the live params/pool
   sharding specs land in the report's ``sharding`` section.
 """
 
@@ -290,7 +290,7 @@ def _sharding_section(mesh) -> dict:
     out["paged.pool.k"] = str(paged.pool.k.sharding.spec)
     out["paged.pool.v"] = str(paged.pool.v.sharding.spec)
 
-    # mesh lowerings: the mhlo.sharding argument signature of the two
+    # mesh lowerings: the sdy.sharding argument signature of the two
     # hottest families — replication creep inside the COMPILED artifact
     from sentio_tpu.analysis.audit.lowering import audit_variant
 
@@ -344,7 +344,7 @@ def build_audit_report(include_mesh: bool = True) -> dict:
             lambda desc, _n=name: _paged_args(quant, _n, desc),
         )
 
-    # the committed footprint claim: int8 pages + f16 per-vector scales vs
+    # the committed footprint claim: int8 pages + bf16 per-vector scales vs
     # bf16 pages at identical pool geometry. Measured at a SERVING head_dim
     # (64 — the llama/GQA families this engine serves), not the dim-16
     # lowering micro-config: per-vector scale overhead is 2/head_dim bytes,

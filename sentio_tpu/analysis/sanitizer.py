@@ -410,8 +410,9 @@ def _radix_nodes(radix):
 
 def _check_pool_repr(engine) -> None:
     """KV-pool representation consistency: the quantized pool is a
-    ``{"q": int8, "s": f16}`` pytree whose scale tree mirrors the payload
-    shape minus the vector axis; the unquantized pool is a plain array.
+    ``{"q": int8, "s": bf16}`` pytree whose page-minor scale tree
+    ([L, P, Hkv, page]) mirrors the payload ([L, P, page, Hkv, D]) minus the
+    vector axis; the unquantized pool is a plain array.
     Pure host-side metadata checks (shape/dtype/type), no device sync —
     a repr drift (e.g. a refactor materializing a dense copy into the
     pool slot, or dropping the scale tree) fails the tick that did it."""
@@ -437,12 +438,12 @@ def _check_pool_repr(engine) -> None:
                 f"{sorted(side) if isinstance(side, dict) else type(side).__name__}"
             )
         q, s = side["q"], side["s"]
-        if str(q.dtype) != "int8" or str(s.dtype) != "float16":
+        if str(q.dtype) != "int8" or str(s.dtype) != "bfloat16":
             raise SanitizerError(
                 f"quantized pool.{name} dtypes drifted: q={q.dtype} "
-                f"(want int8), s={s.dtype} (want float16)"
+                f"(want int8), s={s.dtype} (want bfloat16)"
             )
-        if tuple(q.shape[:-1]) != tuple(s.shape):
+        if tuple(q.shape[:-1]) != (*s.shape[:-2], s.shape[-1], s.shape[-2]):
             raise SanitizerError(
                 f"quantized pool.{name} scale shape {tuple(s.shape)} does "
                 f"not mirror payload {tuple(q.shape)} minus the vector axis"

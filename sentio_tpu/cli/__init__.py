@@ -37,9 +37,17 @@ def _cmd_ingest(args: argparse.Namespace) -> int:
 
 
 def _cmd_serve(args: argparse.Namespace) -> int:
+    import logging
+
     from sentio_tpu.config import get_settings
     from sentio_tpu.serve.app import run_server
 
+    # a server's start-up story (weights loaded, kernels selected, address
+    # bound) is logged at INFO; without a handler it was never printed
+    logging.basicConfig(
+        level=logging.INFO,
+        format="%(asctime)s %(levelname)s %(name)s: %(message)s",
+    )
     settings = get_settings()
     if args.host:
         settings.serve.host = args.host
@@ -229,7 +237,7 @@ def _cmd_convert(args: argparse.Namespace) -> int:
 
 
 def _cmd_eval(args: argparse.Namespace) -> int:
-    """Run the BASELINE.md measurement matrix (five configs + the measured
+    """Run the BASELINE.json measurement matrix (five configs + the measured
     reference-architecture baseline) and write EVAL.json."""
     from sentio_tpu.eval.runner import run_eval
 
@@ -386,6 +394,9 @@ def _cmd_info(args: argparse.Namespace) -> int:
 
 
 def main(argv: list[str] | None = None) -> int:
+    from sentio_tpu.infra.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()  # before any subcommand imports JAX
     parser = argparse.ArgumentParser(prog="sentio-tpu", description=__doc__)
     sub = parser.add_subparsers(dest="command", required=True)
 

@@ -263,7 +263,7 @@ class GeneratorConfig:
     # decode sub-steps fused into one device dispatch per engine tick —
     # amortizes host round trips; admission waits at most one tick. With an
     # empty queue the engine grows ticks toward the max so long generations
-    # cost few host fetches (the per-tick fetch is ~RTT on remote devices)
+    # cost few host fetches (each per-tick fetch blocks on the device)
     decode_steps_per_tick: int = 16
     decode_max_tick_steps: int = 64
     # 2 = dispatch tick N+1 before fetching tick N (host round trip overlaps

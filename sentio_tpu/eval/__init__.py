@@ -1,4 +1,4 @@
-"""Evaluation harness: bundled retrieval-QA dataset, the five BASELINE.md
+"""Evaluation harness: bundled retrieval-QA dataset, the five BASELINE.json
 pipeline configs, and a measured reference-architecture baseline.
 
 The reference publishes no benchmark numbers (SURVEY.md §6), so parity and

@@ -1,7 +1,7 @@
-"""Eval orchestration: the five BASELINE.md configs + the measured baseline.
+"""Eval orchestration: the five BASELINE.json configs + the measured baseline.
 
 This is the wiring that turns the eval subsystem into published numbers
-(BASELINE.md's measurement matrix, EVAL.json): build the retrieval-QA
+(BASELINE.json's ``configs`` matrix, EVAL.json): build the retrieval-QA
 bundle, stand up the framework components once, run each config through
 :func:`sentio_tpu.eval.harness.run_queries`, and measure the
 reference-architecture loopback baseline (:mod:`sentio_tpu.eval.baseline`).

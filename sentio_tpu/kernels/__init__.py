@@ -1,8 +1,10 @@
 """Pallas/TPU kernels: flash attention, ring (sequence-parallel) attention.
 
-Every kernel has an XLA fallback (models/layers.py:attention) so the whole
-framework runs on CPU; the kernels take over on TPU where the problem size
-pays for them. ``flash_attn_fn`` is the adapter signature models accept
+Every kernel has an XLA counterpart (models/layers.py:attention) so the
+whole framework runs on CPU; the kernels are SELECTED on TPU from what the
+code can observe (backend, mesh, head counts) — a kernel that fails to
+compile or run there is an error, never a reason to carry on with XLA.
+``flash_attn_fn`` is the adapter signature models accept
 (``llama_forward(..., attn_fn=...)``): (q, k, v, kv_lens) → [B, T, H, D]
 with causal semantics.
 """
@@ -67,7 +69,6 @@ def make_mesh_attn_fn(mesh, causal: bool = True):
     (prefill / training); encoders pass ``causal=False`` (sp must be 1).
     """
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from sentio_tpu.parallel.mesh import AXIS_DP, AXIS_SP, AXIS_TP
@@ -82,7 +83,8 @@ def make_mesh_attn_fn(mesh, causal: bool = True):
     def fn(q, k, v, kv_lens=None):
         b, t, h, _ = q.shape
         if h % tp != 0 or t % sp != 0:
-            # indivisible shapes fall back to XLA attention upstream
+            # callers test divisibility before choosing this kernel; an
+            # indivisible shape here is an error, never a quiet XLA path
             raise ValueError(f"heads {h} % tp {tp} or seq {t} % sp {sp} != 0")
         batch_axis = AXIS_DP if (dp > 1 and b % dp == 0) else None
         spec = P(batch_axis, AXIS_SP if sp > 1 else None,
@@ -100,10 +102,10 @@ def make_mesh_attn_fn(mesh, causal: bool = True):
                 return flash_attention(q, k, v, lens, causal=causal,
                                        interpret=interpret)
 
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(spec, spec, spec, lens_spec),
-            out_specs=spec, check_rep=False,
+            out_specs=spec, check_vma=False,
         )(q, k, v, kv_lens)
 
     return fn

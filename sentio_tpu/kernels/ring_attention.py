@@ -130,8 +130,6 @@ def ring_attention_sharded(
 ) -> jax.Array:
     """Standalone entry: global [B, T, H, D] arrays, batch over dp, sequence
     over sp. T must divide by the sp axis size."""
-    from jax.experimental.shard_map import shard_map
-
     t = q.shape[1]
     sp = mesh.shape[sp_axis]
     if t % sp != 0:
@@ -139,12 +137,12 @@ def ring_attention_sharded(
     batch_spec = batch_axes[0] if len(batch_axes) == 1 else batch_axes
     spec = P(batch_spec, sp_axis, None, None)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=sp_axis, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     q = jax.device_put(q, NamedSharding(mesh, spec))
     k = jax.device_put(k, NamedSharding(mesh, spec))

@@ -75,6 +75,8 @@ class BM25Index:
     check term ids against their own snapshot's term count.
     """
 
+    backend = "numpy"  # which scoring core serves queries (/info shows it)
+
     def __init__(
         self,
         params: BM25Params | None = None,
@@ -360,6 +362,8 @@ class NativeBM25Index(BM25Index):
     numpy implementation, which reads the lock-free ``_Postings`` snapshot
     — concurrent rebuilds can't tear it either.
     """
+
+    backend = "native"
 
     def __init__(self, *args, **kwargs) -> None:
         super().__init__(*args, **kwargs)

@@ -275,14 +275,6 @@ def _build_topk(mesh, dtype: str, k_local: int, k_out: int):
 
         return single
 
-    # jax moved shard_map out of experimental across the versions this tree
-    # supports (same compat-shim pattern as the kernels' TPUCompilerParams
-    # rename): 0.4.x only has jax.experimental.shard_map; newer releases
-    # expose jax.shard_map and eventually drop the experimental alias.
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # experimental alias removed in newer jax
-        from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = tuple(mesh.axis_names)
@@ -295,7 +287,6 @@ def _build_topk(mesh, dtype: str, k_local: int, k_out: int):
         first = jax.lax.axis_index(axes[0])
         idx = first
         for a in axes[1:]:
-            # static mesh extent (jax.lax.axis_size only exists on newer jax)
             idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
         n_local = corpus.shape[0]
         glob_i = loc_i + idx * n_local
@@ -309,21 +300,12 @@ def _build_topk(mesh, dtype: str, k_local: int, k_out: int):
         best_i = jnp.take_along_axis(cat_i, pos, axis=1)
         return best_s, best_i
 
-    # the replication-check kwarg was renamed check_rep -> check_vma along
-    # the way; pass whichever this jax understands (the check is disabled
-    # either way: all_gather'd outputs are replicated by construction)
-    import inspect
-
-    check_kw = (
-        "check_vma"
-        if "check_vma" in inspect.signature(shard_map).parameters
-        else "check_rep"
-    )
-    fn = shard_map(
+    # check_vma off: all_gather'd outputs are replicated by construction
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(axes, None), P(axes), P(None, None)),
         out_specs=(P(None, None), P(None, None)),
-        **{check_kw: False},
+        check_vma=False,
     )
     return jax.jit(fn)

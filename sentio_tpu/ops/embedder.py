@@ -223,12 +223,10 @@ class TpuEmbedder(BaseEmbedder):
         self.tokenizer = tokenizer or ByteTokenizer(self.model_config.vocab_size)
         if params is None:
             params = init_encoder(jax.random.PRNGKey(0), self.model_config)
-        self.params = params
-        self.mesh = mesh
-        if mesh is not None:
-            from sentio_tpu.parallel.sharding import ENCODER_TP_RULES, shard_params
+        from sentio_tpu.parallel.sharding import ENCODER_TP_RULES, shard_params
 
-            self.params = shard_params(params, mesh, ENCODER_TP_RULES)
+        self.params = shard_params(params, mesh, ENCODER_TP_RULES)
+        self.mesh = mesh
 
         cfg = self.model_config
         # bidirectional flash kernel for the encoder pass — policy lives in
@@ -302,9 +300,7 @@ class TpuEmbedder(BaseEmbedder):
     def embed_device(self, texts: list[str]):
         """Embed → [n, D] array WITHOUT a blocking host download. The dense
         retrieval leg chains this straight into the index's top-k program so
-        the query vector never makes a host round trip — on remote-attached
-        devices each blocking transfer costs ~RTT, which dominated the
-        retrieve leg before this path existed.
+        the query vector never makes a blocking host round trip.
 
         Single-query calls (the /chat hot path — one worker thread per
         request) coalesce across threads through a deadline batcher so
@@ -362,8 +358,14 @@ _PROVIDERS = {"hash": HashEmbedder, "tpu": TpuEmbedder}
 
 
 def get_embedder(config: Optional[EmbedderConfig] = None, **kwargs) -> BaseEmbedder:
-    """Provider registry (reference: embeddings/factory.py:55-120). Unknown
-    providers fall back to ``hash`` like the reference falls back to jina."""
+    """Provider registry (reference: embeddings/factory.py:55-120). An
+    unknown provider is an error, as in ``get_reranker``: a typo must not
+    quietly serve the hash fake in place of the model."""
     config = config or get_settings().embedder
-    cls = _PROVIDERS.get(config.provider, HashEmbedder)
+    cls = _PROVIDERS.get(config.provider)
+    if cls is None:
+        raise ValueError(
+            f"unknown embedder provider {config.provider!r}; "
+            f"known: {sorted(_PROVIDERS)}"
+        )
     return cls(config, **kwargs) if cls is TpuEmbedder else cls(config)

@@ -11,6 +11,7 @@ deterministic offline fake (the reference's mock-mode test pattern).
 
 from __future__ import annotations
 
+import logging
 import threading
 from dataclasses import dataclass, field
 from typing import Iterator, Optional, Protocol, Sequence
@@ -18,6 +19,8 @@ from typing import Iterator, Optional, Protocol, Sequence
 from sentio_tpu.config import GeneratorConfig, get_settings
 from sentio_tpu.models.document import Document
 from sentio_tpu.ops.prompts import PromptBuilder
+
+logger = logging.getLogger(__name__)
 
 
 class ChatProvider(Protocol):
@@ -148,6 +151,12 @@ class TpuProvider:
                     raise
                 if self.engine is None:
                     raise
+                # loud: the answer that follows did NOT come from the paged
+                # path (its Pallas kernel, its radix cache) — a 200 must not
+                # be the only trace of a decode path the device refused
+                logger.warning("paged decode failed (%s); the contiguous "
+                               "engine answers this request", exc,
+                               exc_info=True)
             if self.engine is None:
                 raise RuntimeError("paged decode failed and no contiguous engine")
         if self.speculative is not None:
@@ -198,6 +207,9 @@ class TpuProvider:
                 if (yielded_any or self.engine is None
                         or getattr(exc, "soft_fail_exempt", False)):
                     raise
+                logger.warning("paged stream failed (%s); the contiguous "
+                               "engine answers this request", exc,
+                               exc_info=True)
         yield from self.engine.stream(
             prompt, max_new_tokens=max_new_tokens, temperature=temperature
         )

@@ -133,11 +133,9 @@ class CrossEncoderReranker(Reranker):
         self.tokenizer = tokenizer or ByteTokenizer(self.model_config.vocab_size)
         if params is None:
             params = init_cross_encoder(jax.random.PRNGKey(7), self.model_config)
-        if mesh is not None:
-            from sentio_tpu.parallel.sharding import ENCODER_TP_RULES, shard_params
+        from sentio_tpu.parallel.sharding import ENCODER_TP_RULES, shard_params
 
-            params = shard_params(params, mesh, ENCODER_TP_RULES)
-        self.params = params
+        self.params = shard_params(params, mesh, ENCODER_TP_RULES)
         cfg = self.model_config
         # bidirectional flash kernel for pair scoring — policy lives in
         # kernels.select_encoder_attn_fn (shared with the embedder)

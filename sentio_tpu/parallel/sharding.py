@@ -93,10 +93,15 @@ def make_param_shardings(params: Any, mesh: Mesh, rules: Rules) -> Any:
     return jax.tree_util.tree_map_with_path(one, params)
 
 
-def shard_params(params: Any, mesh: Mesh, rules: Rules) -> Any:
-    """Place a host pytree onto the mesh according to the rules. This is the
-    startup weight-load step (reference's lazy first-request init inverted —
-    SURVEY.md §3.3)."""
+def shard_params(params: Any, mesh: Optional[Mesh], rules: Rules) -> Any:
+    """Place a host pytree onto the mesh according to the rules — or, with
+    no mesh, onto the default device. This is the startup weight-load step
+    (reference's lazy first-request init inverted — SURVEY.md §3.3): every
+    leaf goes from host memory straight to its final placement ONCE. A
+    checkpoint tree left as host numpy would ride every jit call as an
+    argument and be uploaded again on each dispatch."""
+    if mesh is None:
+        return jax.device_put(params)
     shardings = make_param_shardings(params, mesh, rules)
     return jax.device_put(params, shardings)
 

@@ -22,8 +22,8 @@ owns slot admission / EOS retirement, mirroring the reference's resilience
 stance (a failing request fails alone, SURVEY.md §5).
 
 The engine is built around ONE cost model: device dispatches are async and
-effectively free, while every host-visible transfer is a round trip (~RTT —
-dominant through remote-attached chips, real overhead locally). Hence:
+effectively free, while every host-visible transfer is a blocking round
+trip (how much each costs on the chip is not measured yet). Hence:
 
 * **multi-step fused ticks** — one ``lax.scan`` dispatch runs up to
   ``max_tick_steps`` decode sub-steps with per-row budgets and EOS halting;
@@ -71,8 +71,10 @@ Array = object  # jax.Array — jax imported lazily
 @dataclass
 class PagedPool:
     """Device-side page pool. k/v: [L, P, page, Hkv, D] arrays, or — with
-    int8 KV quantization — pytrees ``{"q": int8 [L,P,page,Hkv,D], "s": f16
-    [L,P,page,Hkv]}`` (per-token-per-head absmax scales). The pytree form
+    int8 KV quantization — pytrees ``{"q": int8 [L,P,page,Hkv,D], "s": bf16
+    [L,P,Hkv,page]}`` (per-token-per-head absmax scales, stored page-minor
+    so the Pallas kernel DMAs one lane-dense scale tile per page — see
+    kernels/paged_attention.py). The pytree form
     rides through every jit signature, scan carry, and donation unchanged;
     only the read/write helpers below understand the representation.
     Page id 0 = scratch."""
@@ -100,18 +102,22 @@ class PagedPool:
 
 
 def quantize_kv(x):
-    """[..., D] float → (int8 [..., D], f16 scale [...]). Symmetric absmax
+    """[..., D] float → (int8 [..., D], bf16 scale [...]). Symmetric absmax
     per vector; a zero vector gets scale 0 and dequantizes to exact zeros.
-    float16 scales keep the overhead at D/2 bytes per vector with ~0.1%
-    scale error — negligible next to the int8 step itself."""
+    bfloat16 scales keep the overhead at 2 bytes per vector in a dtype the
+    TPU's vector units load natively (float16 is refused by Mosaic on v5e).
+    The payload is quantized against the ROUNDED scale that is stored, so
+    the 8-bit scale mantissa adds no error on top of the int8 step: a scale
+    that rounded down merely clips the largest element at 127."""
     import jax.numpy as jnp
 
     xf = x.astype(jnp.float32)
-    scale = jnp.max(jnp.abs(xf), axis=-1) / 127.0
+    scale = (jnp.max(jnp.abs(xf), axis=-1) / 127.0).astype(jnp.bfloat16)
     q = jnp.clip(
-        jnp.round(xf / jnp.maximum(scale, 1e-8)[..., None]), -127, 127
+        jnp.round(xf / jnp.maximum(scale.astype(jnp.float32), 1e-30)[..., None]),
+        -127, 127,
     ).astype(jnp.int8)
-    return q, scale.astype(jnp.float16)
+    return q, scale
 
 
 def dequantize_kv(q, scale, dtype):
@@ -122,6 +128,12 @@ def dequantize_kv(q, scale, dtype):
     ).astype(dtype)
 
 
+def dequantize_pages(q, scale, dtype):
+    """Page-shaped pair as the pool stores it — payload [..., page, Hkv, D],
+    scales page-minor [..., Hkv, page] — → dense [..., page, Hkv, D]."""
+    return dequantize_kv(q, scale.swapaxes(-1, -2), dtype)
+
+
 def _page_write(pages, layer, page_ids, offsets, val):
     """Write val [B, Hkv, D] at (layer, page_ids[b], offsets[b]) per row —
     representation-aware (plain array or int8+scale pytree)."""
@@ -129,7 +141,9 @@ def _page_write(pages, layer, page_ids, offsets, val):
         q, s = quantize_kv(val)
         return {
             "q": pages["q"].at[layer, page_ids, offsets].set(q),
-            "s": pages["s"].at[layer, page_ids, offsets].set(s),
+            # scales are page-minor [L, P, Hkv, page]: s [B, Hkv] lands at
+            # (layer, page_ids[b], :, offsets[b])
+            "s": pages["s"].at[layer, page_ids, :, offsets].set(s),
         }
     return pages.at[layer, page_ids, offsets].set(val)
 
@@ -147,10 +161,9 @@ def _page_dim(pages) -> int:
 def _gather_pages(pages_l, page_table, dtype):
     """[P, page, Hkv, D](-repr) + table [B, NB] → dense [B, NB*page, Hkv, D]."""
     if isinstance(pages_l, dict):
-        q = pages_l["q"][page_table]
-        s = pages_l["s"][page_table]
         b, nb = page_table.shape
-        out = dequantize_kv(q, s, dtype)
+        out = dequantize_pages(
+            pages_l["q"][page_table], pages_l["s"][page_table], dtype)
         return out.reshape(b, nb * out.shape[2], *out.shape[3:])
     b, nb = page_table.shape
     kc = pages_l[page_table]
@@ -166,14 +179,14 @@ def init_pool(
     crosses devices) and page tables stay replicated host-side. With
     ``quantized`` the pool stores int8 + per-vector scales — ~half the HBM
     and half the decode-attention read bandwidth of bf16 pages."""
-    import jax
     import jax.numpy as jnp
 
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
 
     def alloc(arr_shape, dtype, spec=None):
-        z = jnp.zeros(arr_shape, dtype)
-        return z if spec is None else jax.device_put(z, spec)
+        # born in its final placement: a pool zero-filled on the default
+        # device and resharded afterwards stages the WHOLE pool on one chip
+        return jnp.zeros(arr_shape, dtype, device=spec)
 
     kv_spec = scale_spec = None
     if mesh is not None:
@@ -187,13 +200,14 @@ def init_pool(
                 f"n_kv_heads={cfg.n_kv_heads} not divisible by tp={tp}"
             )
         kv_spec = NamedSharding(mesh, P(None, None, None, AXIS_TP, None))
-        scale_spec = NamedSharding(mesh, P(None, None, None, AXIS_TP))
+        scale_spec = NamedSharding(mesh, P(None, None, AXIS_TP, None))
 
     if quantized:
+        scale_shape = (cfg.n_layers, num_pages, cfg.n_kv_heads, page_size)
         k = {"q": alloc(shape, jnp.int8, kv_spec),
-             "s": alloc(shape[:-1], jnp.float16, scale_spec)}
+             "s": alloc(scale_shape, jnp.bfloat16, scale_spec)}
         v = {"q": alloc(shape, jnp.int8, kv_spec),
-             "s": alloc(shape[:-1], jnp.float16, scale_spec)}
+             "s": alloc(scale_shape, jnp.bfloat16, scale_spec)}
     else:
         k = alloc(shape, cfg.jdtype, kv_spec)
         v = alloc(shape, cfg.jdtype, kv_spec)
@@ -340,7 +354,7 @@ def scatter_prefill(k_pages, v_pages, k_cache, v_cache, page_table):
             q, sc = quantize_kv(r)
             return {
                 "q": pages["q"].at[:, page_table].set(q),
-                "s": pages["s"].at[:, page_table].set(sc),
+                "s": pages["s"].at[:, page_table].set(sc.swapaxes(-1, -2)),
             }
         # dims 1 of pages indexed by [B, NB] table → scatter [L,B,NB,page,H,D]
         return pages.at[:, page_table].set(r)
@@ -533,6 +547,16 @@ class ContinuousBatchingEngine:
                 params = init_moe(jax.random.PRNGKey(rng_seed), self.cfg)
             else:
                 params = init_llama(jax.random.PRNGKey(rng_seed), self.cfg)
+        if mesh is None and not all(
+                isinstance(leaf, jax.Array)
+                for leaf in jax.tree_util.tree_leaves(params)):
+            # a checkpoint tree handed over as host numpy (worker replicas
+            # load it memory-mapped) goes to the device ONCE, here — as jit
+            # arguments its leaves would be uploaded again on every
+            # dispatch. A tree already on the device is kept as it is
+            # (replicas share ONE copy of the weights by identity). Under a
+            # mesh the caller has placed the tree by its sharding rules.
+            params = jax.device_put(params)
         self.params = params
         if forward_fn is None:
             forward_fn = moe_serving_forward if is_moe else llama_forward
@@ -552,8 +576,7 @@ class ContinuousBatchingEngine:
         self.page_size = page_size
         self.max_pages_per_seq = max_pages_per_seq
         # decode sub-steps fused into ONE device dispatch per tick: host
-        # round trips (the dominant per-token cost through remote-attached
-        # devices, and real overhead even locally) amortize over the chunk.
+        # round trips amortize over the chunk.
         # Admission latency grows by at most steps_per_tick decode steps.
         self.steps_per_tick = max(int(steps_per_tick), 1)
         # with an EMPTY queue nothing waits on admission, so ticks may grow
@@ -720,9 +743,12 @@ class ContinuousBatchingEngine:
         self._lp_min = np.zeros(max_slots, np.float32)
         self._lp_cnt = np.zeros(max_slots, np.int32)
         # Pallas paged-attention kernel walks page tables in VMEM on TPU;
-        # the XLA gather path is the universal fallback (and CPU test path).
+        # the XLA gather path is what runs elsewhere (the CPU test path).
+        # This is a SELECTION by backend, made once: nothing catches a
+        # kernel failure and carries on with the other path. Under a mesh
+        # the kernel runs inside shard_map over tp (heads-sharded pool).
         # The kernel is representation-aware: int8 pools route to the quant
-        # variant (int8 pages + f16 scales DMA'd per block, dequantized
+        # variant (int8 pages + bf16 scales DMA'd per block, dequantized
         # in-register), so kv_quant="int8" keeps the fast path
         if use_pallas is None:
             use_pallas = jax.default_backend() == "tpu"
@@ -730,7 +756,7 @@ class ContinuousBatchingEngine:
         if use_pallas:
             from sentio_tpu.kernels.paged_attention import make_paged_attn_impl
 
-            self._attn_impl = make_paged_attn_impl()
+            self._attn_impl = make_paged_attn_impl(mesh=mesh)
         self._build_fns()
 
     # ------------------------------------------------------------- compiled
@@ -903,9 +929,9 @@ class ContinuousBatchingEngine:
             if pnb:
                 def prime(cache_arr, pages):
                     if isinstance(pages, dict):
-                        qv = pages["q"][:, prior_table]
-                        sc = pages["s"][:, prior_table]
-                        dense = dequantize_kv(qv, sc, cache_arr.dtype)
+                        dense = dequantize_pages(
+                            pages["q"][:, prior_table],
+                            pages["s"][:, prior_table], cache_arr.dtype)
                     else:
                         dense = pages[:, prior_table]  # [L, B, PNB, pg, Hk, Hd]
                     lcount, bb, nb_, pg_, hk_, hd_ = dense.shape
@@ -1627,8 +1653,7 @@ class ContinuousBatchingEngine:
         flavor. rows_data: [(token_ids, temperature, top_k, pages)]. Pad
         rows and unused scatter blocks point at scratch page 0; args stay
         host numpy (a jit call ships them asynchronously, while an explicit
-        jnp.asarray is a SYNCHRONOUS upload — ~RTT each on remote-attached
-        devices)."""
+        jnp.asarray is a SYNCHRONOUS upload)."""
         rows = bucket_size(len(rows_data), self.ADMIT_BUCKETS)
         nb = width // self.page_size
         ids = np.full((rows, width), self.tokenizer.pad_id, np.int32)
@@ -1867,7 +1892,7 @@ class ContinuousBatchingEngine:
         # outputs (host mirrors seed the first tick); admission's device-
         # resident first tokens / prompt lengths scatter in via the jitted
         # merge. Jit dispatches are async; eager index-update ops and
-        # explicit jnp.asarray uploads each block ~RTT on remote devices.
+        # explicit jnp.asarray uploads each block.
         if self._dev_state is None:
             tok_in = self._last_tok.copy()
             lens_in = self._lens.copy()
@@ -2089,6 +2114,9 @@ class ContinuousBatchingEngine:
             "total_pages": self.allocator.num_pages,
             "page_size": self.page_size,
             "kv_quant": self.kv_quant,
+            # which decode-attention path the constructor SELECTED (the
+            # Pallas page-table walk on TPU, the XLA gather elsewhere)
+            "paged_attention": "pallas" if self._attn_impl is not None else "xla",
             "pool_hbm_bytes": self.pool.hbm_bytes,
             "head_skips": self._head_skips,
             "ttft_count": self.ttft_count,

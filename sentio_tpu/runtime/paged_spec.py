@@ -72,11 +72,11 @@ def build_spec_tick(target_fwd, cfg, draft_fwd, dcfg, eos_id: int,
     import jax
     import jax.numpy as jnp
 
-    from sentio_tpu.runtime.paged import dequantize_kv, scatter_prefill
+    from sentio_tpu.runtime.paged import dequantize_pages, scatter_prefill
 
     def densify(pages, table, dtype):
         if isinstance(pages, dict):
-            dense = dequantize_kv(
+            dense = dequantize_pages(
                 pages["q"][:, table], pages["s"][:, table], dtype
             )
         else:

@@ -2582,6 +2582,7 @@ class ReplicaSet:
         first = per[0]
         agg["page_size"] = first.get("page_size")
         agg["kv_quant"] = first.get("kv_quant")
+        agg["paged_attention"] = first.get("paged_attention")
         agg["n_replicas"] = len(per)
         agg["replicas"] = per
         with self._mutex:

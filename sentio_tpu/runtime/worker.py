@@ -106,6 +106,7 @@ from dataclasses import dataclass, field
 from typing import Any, Iterator, Optional
 
 from sentio_tpu.infra import faults
+from sentio_tpu.infra.compile_cache import ensure_compile_cache
 from sentio_tpu.infra.exceptions import (
     DeadlineExceededError,
     ReplicaUnavailable,
@@ -710,6 +711,7 @@ class _WorkerServer:  # frame-emit: worker-to-router
 
 def worker_main(conn, spec: WorkerSpec) -> None:
     """Child-process entry point (spawned by :class:`ProcessReplica`)."""
+    ensure_compile_cache()
     # the worker must die with its router even when wedged in XLA: the
     # router holds the other pipe end, so a clean router close() still
     # reaches the recv loop; SIGTERM from terminate() gets a fast exit
@@ -745,6 +747,7 @@ def worker_main_socket(addr, spec: WorkerSpec, slot: int) -> None:
     acks it back; the worker adopts the assignment so every redial keeps
     the same fleet identity instead of allocating a new slot per
     reconnect."""
+    ensure_compile_cache()
     signal.signal(signal.SIGTERM, lambda *_: os._exit(0))
     logging.basicConfig(level=logging.WARNING)
     svc = None
@@ -838,6 +841,8 @@ def worker_serve(
     last dialed it. ``bound_cb`` (tests) receives the bound
     ``(host, port)``."""
     import socket as _socket
+
+    ensure_compile_cache()
 
     stop = stop_event or threading.Event()
     listener = _socket.socket(_socket.AF_INET, _socket.SOCK_STREAM)

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import dataclasses
 import json
 import logging
 import os
@@ -699,10 +700,20 @@ def _speculative_info(container: DependencyContainer) -> dict:
     return out
 
 
+def _model_config_of(component) -> Optional[dict]:
+    """The config a component's model ACTUALLY runs at (None for fakes with
+    no model) — a reranker with no checkpoint is ``EncoderConfig.tiny()``,
+    and only this shows a toy standing on the serving path."""
+    cfg = getattr(component, "model_config", None)
+    return dataclasses.asdict(cfg) if dataclasses.is_dataclass(cfg) else None
+
+
 async def info(request: web.Request) -> web.Response:
     container: DependencyContainer = request.app["container"]
     settings = container.settings
     engine = container.engine
+    service = container.peek("generation_service")
+    serving = service.stats() if service is not None else {}
     return web.json_response(
         {
             "service": "sentio-tpu",
@@ -712,12 +723,28 @@ async def info(request: web.Request) -> web.Response:
                 "fusion": settings.retrieval.fusion_method,
                 "top_k": settings.retrieval.top_k,
                 "corpus_size": container.dense_index.size,
+                "bm25_backend": getattr(container.sparse_index, "backend", None),
             },
-            "reranker": {"enabled": settings.rerank.enabled, "kind": settings.rerank.kind},
+            "embedder": {
+                "provider": settings.embedder.provider,
+                "model": _model_config_of(container.embedder),
+            },
+            "reranker": {
+                "enabled": settings.rerank.enabled,
+                "kind": settings.rerank.kind,
+                "model": _model_config_of(container.reranker),
+            },
             "generator": {
                 "provider": settings.generator.provider,
                 "preset": settings.generator.model_preset,
+                "model": _model_config_of(engine),
                 "verifier": settings.generator.use_verifier,
+                # the paged decode path as the engine resolved it: page
+                # representation, and whether decode attention is the
+                # Pallas page-table walk or the XLA gather
+                "kv_quant": serving.get("kv_quant"),
+                "paged_attention": serving.get("paged_attention"),
+                "pool_hbm_bytes": serving.get("pool_hbm_bytes"),
                 # a configured draft accelerates BOTH serving paths now —
                 # paged (runtime/paged_spec.py, the default) and contiguous
                 # (runtime/speculative.py); the genuine exclusions (chunked
@@ -725,6 +752,9 @@ async def info(request: web.Request) -> web.Response:
                 "speculative": _speculative_info(container),
             },
             "device": engine.device_stats() if engine is not None else None,
+            # where this process keeps JAX's persistent compile cache
+            # (infra/compile_cache.py; None = not placed, e.g. under tests)
+            "compile_cache_dir": os.environ.get("JAX_COMPILATION_CACHE_DIR"),
         }
     )
 

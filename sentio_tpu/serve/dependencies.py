@@ -61,11 +61,14 @@ class DependencyContainer:
     def mesh(self):
         def build():
             cfg = self.settings.mesh
-            if cfg.dp_size == 0 and cfg.tp_size <= 1 and cfg.sp_size <= 1:
-                import jax
-
-                if len(jax.devices()) <= 1:
-                    return None  # single chip: skip mesh machinery entirely
+            if max(cfg.dp_size, cfg.tp_size, cfg.sp_size, cfg.pp_size,
+                   cfg.ep_size, cfg.dcn_size) <= 1:
+                # no MESH_* axis asked for: one device, no mesh machinery.
+                # More devices being VISIBLE is not a request to replicate
+                # every model over them (a four-chip host used to get a
+                # dp=4 mesh this way); MESH_DP=0 still means "absorb the
+                # devices the other axes leave" once any axis is set.
+                return None
             from sentio_tpu.parallel.mesh import build_mesh
 
             return build_mesh(cfg)
@@ -278,6 +281,29 @@ class DependencyContainer:
                     replica_mode,
                 )
                 replica_mode = "thread"
+
+            if (replica_mode in ("process", "socket")
+                    and not (replica_mode == "socket"
+                             and serve.parsed_replica_workers())):
+                # workers are spawned on THIS host, and a chip belongs to
+                # one process: this router already holds it (the engine's
+                # weights above, the embedder, the reranker), so a worker
+                # that needs the same chip hangs in start-up until
+                # warmup_budget_s. Refuse now, and say why.
+                import jax
+
+                if jax.default_backend() == "tpu":
+                    from sentio_tpu.infra.exceptions import DeviceError
+
+                    raise DeviceError(
+                        f"REPLICA_MODE={replica_mode} spawns worker "
+                        "processes on this host, but the router process "
+                        "already holds the TPU (engine weights, embedder, "
+                        "reranker) and a chip belongs to one process at a "
+                        "time. Use REPLICA_MODE=thread here, or socket mode "
+                        "with REPLICA_WORKERS pointing at workers that own "
+                        "their chips on other hosts."
+                    )
 
             # paged speculative decoding: a configured draft checkpoint now
             # accelerates the DEFAULT serving path (runtime/paged_spec.py)

@@ -110,9 +110,10 @@ class TestTpuEmbedder:
         np.testing.assert_allclose(solo, batched, atol=1e-5)
 
 
-def test_registry_fallback():
-    emb = get_embedder(EmbedderConfig(provider="unknown-thing", dim=8))
-    assert isinstance(emb, HashEmbedder)
+def test_registry_rejects_unknown_provider():
+    """A typo in EMBEDDER_PROVIDER must not quietly serve the hash fake."""
+    with pytest.raises(ValueError, match="unknown embedder provider"):
+        get_embedder(EmbedderConfig(provider="unknown-thing", dim=8))
     assert isinstance(get_embedder(EmbedderConfig(provider="hash", dim=8)), HashEmbedder)
 
 

@@ -1,5 +1,5 @@
 """Eval subsystem: the runner wiring, the OpenAI-compatible provider, and
-the loopback baseline — the measurement path behind BASELINE.md's matrix."""
+the loopback baseline — the measurement path behind BASELINE.json's matrix."""
 
 import pytest
 

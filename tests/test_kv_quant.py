@@ -41,7 +41,7 @@ class TestQuantPair:
         i8 = init_pool(cfg, num_pages=33, page_size=16, quantized=True)
         bf16_bytes = bf16.k.nbytes
         i8_bytes = i8.k["q"].nbytes + i8.k["s"].nbytes
-        assert i8_bytes < 0.6 * bf16_bytes  # int8 + f16 scales (2/D overhead)
+        assert i8_bytes < 0.6 * bf16_bytes  # int8 + bf16 scales (2/D overhead)
 
 
 class TestAttentionParity:
@@ -149,7 +149,7 @@ class TestEngineWithInt8KV:
                          quantized=True)
         # kv-head dim sharded over tp for both payload and scales
         assert pool.k["q"].sharding.spec[3] == "tp"
-        assert pool.k["s"].sharding.spec[3] == "tp"
+        assert pool.k["s"].sharding.spec[2] == "tp"  # page-minor scales
 
         eng = ContinuousBatchingEngine(
             model_config=cfg, mesh=mesh, max_slots=4, page_size=16,

@@ -145,6 +145,44 @@ class TestFactory:
             np.testing.assert_allclose(loaded.scores(q), idx.scores(q), rtol=1e-5)
 
 
+class TestForeignBinary:
+    def test_foreign_binary_is_rebuilt_and_backend_reported(
+            self, tmp_path, monkeypatch):
+        """The shared object is git-ignored and built ``-march=native``: one
+        that came along with a copied working tree (another machine's, or
+        older than the source) must never be loaded. Binaries are named by
+        a key of (source, flags, host CPU); anything else in the directory
+        is ignored and the library is built from ``bm25.cpp`` here. The
+        index says which core serves."""
+        import shutil
+
+        from sentio_tpu import native
+
+        shutil.copy(native._SRC_DIR / "bm25.cpp", tmp_path / "bm25.cpp")
+        # the legacy un-keyed name, and a keyed one from "another host" —
+        # both newer than the source, neither loadable
+        foreign = [tmp_path / "libbm25.so",
+                   tmp_path / "libbm25.0123456789abcdef.so"]
+        for path in foreign:
+            path.write_bytes(b"not an ELF file")
+        monkeypatch.setattr(native, "_SRC_DIR", tmp_path)
+        monkeypatch.setattr(native, "_CACHE", {})
+
+        lib = native.load_bm25()
+        assert lib is not None, "rebuild from source must succeed (g++ present)"
+        assert lib.sbm25_version() >= 1
+        built_here = tmp_path / f"libbm25.{native._build_key(tmp_path / 'bm25.cpp')}.so"
+        assert built_here.exists()
+        assert all(p.read_bytes() == b"not an ELF file" for p in foreign)
+
+        # another CPU (or other flags) is another key: never this binary
+        monkeypatch.setattr(native, "_host_cpu", lambda: "another-machine")
+        assert native._build_key(tmp_path / "bm25.cpp") not in built_here.name
+
+        assert make_bm25_index(backend="auto").backend == "native"
+        assert make_bm25_index(backend="numpy").backend == "numpy"
+
+
 class TestEmptyIndex:
     def test_empty_native_index_search_does_not_deadlock(self):
         """Regression: search on an empty native index falls back to the

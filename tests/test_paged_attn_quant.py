@@ -26,7 +26,8 @@ def _quant_pool(rng, num_pages, page, hkv, d):
     v = jnp.asarray(rng.standard_normal((num_pages, page, hkv, d)), jnp.float32)
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
-    return k, v, kq, ks, vq, vs
+    # the pool stores scales page-minor: [P, Hkv, page]
+    return k, v, kq, ks.swapaxes(-1, -2), vq, vs.swapaxes(-1, -2)
 
 
 class TestInt8KernelParity:
@@ -85,9 +86,9 @@ class TestInt8KernelParity:
         clean = paged_attention_quant(
             q, kq, ks, vq, vs, table, lens, interpret=True)
         kq2 = kq.at[3, 4:].set(127)
-        ks2 = ks.at[3, 4:].set(100.0)
+        ks2 = ks.at[3, :, 4:].set(100.0)
         vq2 = vq.at[3, 4:].set(127)
-        vs2 = vs.at[3, 4:].set(100.0)
+        vs2 = vs.at[3, :, 4:].set(100.0)
         poisoned = paged_attention_quant(
             q, kq2, ks2, vq2, vs2, table, lens, interpret=True)
         np.testing.assert_array_equal(np.asarray(clean), np.asarray(poisoned))

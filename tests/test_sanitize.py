@@ -259,7 +259,7 @@ class TestEngineInvariants:
 
 class TestQuantPoolRepr:
     """The sanitizer's pool-representation half: the ``{"q","s"}`` dict
-    pool is held to per-tick metadata invariants (int8 payload, f16 scales
+    pool is held to per-tick metadata invariants (int8 payload, bf16 scales
     mirroring the payload shape), so a refactor that silently densifies or
     drops the scale tree fails the tick that did it."""
 
@@ -299,7 +299,7 @@ class TestQuantPoolRepr:
         from sentio_tpu.runtime.paged import quantize_kv
 
         q, s = quantize_kv(eng.pool.k)
-        eng.pool.k = {"q": q, "s": s}
+        eng.pool.k = {"q": q, "s": s.swapaxes(-1, -2)}
         with pytest.raises(SanitizerError, match="unquantized"):
             check_engine_invariants(eng)
 

@@ -15,6 +15,7 @@ from sentio_tpu.config import (
     AuthConfig,
     EmbedderConfig,
     GeneratorConfig,
+    MeshConfig,
     RerankConfig,
     ServeConfig,
     Settings,
@@ -336,7 +337,7 @@ class TestHealthAndInfo:
         — not a dead knob shown as live."""
 
         async def body(client, container):
-            # the 8 virtual CPU devices build a real dp mesh by default
+            # a mesh is only built when a MESH_* axis asks for one
             assert container.mesh is not None
             data = await (await client.get("/info")).json()
             spec = data["generator"]["speculative"]
@@ -348,7 +349,7 @@ class TestHealthAndInfo:
             provider="tpu", model_preset="tiny", use_verifier=False,
             draft_checkpoint_path="/nonexistent-draft",
             use_paged_decode=False,
-        ))
+        ), mesh=MeshConfig(dp_size=8))
         run(with_client(settings, body))
 
 
