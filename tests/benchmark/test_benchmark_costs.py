@@ -1,0 +1,67 @@
+"""Configuration files against the program's config class, and the byte and
+operation counts against numbers worked by hand."""
+
+import json
+from pathlib import Path
+
+import pytest
+
+from benchmark.families import llama as family
+from benchmark.roofline import device_peaks, least_time_s
+
+REPO = Path(__file__).resolve().parents[2]
+CONFIGS = {p.stem: json.loads(p.read_text()) for p in (REPO / "benchmark" / "configs").glob("*.json")}
+
+
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_file_widths_are_what_the_program_config_reports(name):
+    from sentio_tpu.models.llama import LlamaConfig
+
+    model = CONFIGS[name]
+    cfg = LlamaConfig(**family.program_config(model))
+    assert cfg.dim == model["hidden_size"] and cfg.mlp_dim == model["intermediate_size"]
+    assert cfg.n_heads == model["num_attention_heads"] and cfg.n_kv_heads == model["num_key_value_heads"]
+    assert cfg.head_dim == model["head_dim"] == 128
+    assert cfg.vocab_size == model["vocab_size"] and cfg.n_layers == model["num_hidden_layers"]
+    assert cfg.rope_theta == model["rope_theta"] and cfg.norm_eps == model["rms_norm_eps"]
+    assert cfg.max_len == model["max_position_embeddings"] and cfg.dtype == "bfloat16"
+    tiny = LlamaConfig(**family.program_config({**model, **model["rehearsal"]}))
+    assert tiny.dim % tiny.n_heads == 0 and tiny.n_heads % tiny.n_kv_heads == 0
+
+
+def test_mistral_bytes_and_flops_by_hand():
+    m = CONFIGS["mistral-7b-v0.3-l16"]
+    w = family.weight_bytes(m)
+    # wq 4096x4096, wk and wv 4096x1024, wo 4096x4096, three MLP matrices 4096x14336
+    params = 2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 14336
+    assert params == 218_103_808 and w["layer"] == 2 * params
+    assert w["layers"] == 16 * 2 * params == 6_979_321_856
+    assert w["head"] == w["embed"] == 32768 * 4096 * 2 == 268_435_456
+    # K and V, 8 heads of 128, bf16, 16 layers
+    assert family.kv_bytes_per_token(m) == 2 * 8 * 128 * 2 * 16 == 65_536
+    cost = family.decode_substep_cost(m, rows=16, context_tokens=8 * 1200)
+    assert cost["bytes"] == 6_979_321_856 + 268_435_456 + 16 * 4096 * 2 + 9600 * 65_536
+    assert cost["flops"] == 2 * 16 * (16 * params + 32768 * 4096) + 4 * 9600 * 4096 * 16
+    bound = least_time_s(cost, "TPU v5 lite")
+    assert bound["bound"] == "bandwidth"
+    assert bound["seconds"] == pytest.approx(cost["bytes"] / 819e9) and 0.0095 < bound["seconds"] < 0.0097
+
+
+def test_yi_bytes_and_flops_by_hand():
+    m = CONFIGS["yi-1.5-6b-l16"]
+    params = 2 * 4096 * 4096 + 2 * 4096 * 512 + 3 * 4096 * 11008
+    assert family.weight_bytes(m)["layer"] == 2 * params == 346_030_080
+    assert family.weight_bytes(m)["head"] == 64000 * 4096 * 2
+    assert family.kv_bytes_per_token(m) == 2 * 4 * 128 * 2 * 16 == 32_768
+    # 32 slots of which 11 hold 830 tokens each: the head is the larger part here
+    cost = family.decode_substep_cost(m, rows=32, context_tokens=11 * 830)
+    assert cost["bytes"] == 16 * 346_030_080 + 524_288_000 + 32 * 4096 * 2 + 9130 * 32_768
+    assert cost["flops"] == 2 * 32 * (16 * params + 64000 * 4096) + 4 * 9130 * 4096 * 16
+    bound = least_time_s(cost, "TPU v5 lite")
+    assert bound["bound"] == "bandwidth" and 0.0077 < bound["seconds"] < 0.0078
+
+
+def test_an_unknown_device_has_no_peaks():
+    assert device_peaks("TPU v5 lite") == {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    with pytest.raises(KeyError):
+        device_peaks("cpu")

@@ -1,0 +1,104 @@
+"""``benchmark/run.py`` end to end at tiny size on the CPU: once as the
+committed cell on one virtual device, once as a four-chip cell made of
+scratch files only — the proof that a tp=4 cell is data, not code."""
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parents[2]
+BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
+
+
+def run_cell(cell: str, trace: int, extra: list[str], xla_flags: str):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": xla_flags,
+           "JAX_ENABLE_COMPILATION_CACHE": "false", "BENCH_RUN": "ignored"}
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "benchmark" / "run.py"), "--workload", cell,
+         "--seed", "2147483659", "--seconds", "3", "--trace", str(trace), *extra],
+        cwd=str(REPO), env=env, capture_output=True, text=True, timeout=420)
+    assert proc.returncode == 0, proc.stderr[-2000:] + proc.stdout[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
+
+
+def check_line(line: dict, cell: str, key: str, devices: int):
+    assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
+    assert line["correct"] is False and line["device"]["platform"] == "cpu"
+    assert line["device"]["count"] == devices
+    assert line["attempted"] > 0 and line["failed"] == 0
+    want = {m["name"] for m in BENCH[key] if cell in m.get("workloads", [cell])}
+    for name, metric in line["metrics"].items():
+        assert name in want and isinstance(metric["value"], float) and metric["unit"]
+    return want
+
+
+def test_committed_cell_on_one_virtual_device():
+    cell = "mistral7b-rag-open"
+    line, out = run_cell(cell, 0, [], "--xla_force_host_platform_device_count=1")
+    want = check_line(line, cell, "end_to_end", devices=1)
+    assert set(line["metrics"]) == want and line["metrics"]["setup_s"]["value"] > 0
+    notes = [json.loads(ln) for ln in out.splitlines()[:-1] if ln.startswith("{")]
+    window = next(n for n in notes if n.get("phase") == "window")
+    # the only thing wrong with a rehearsal is its platform: nothing compiled
+    # inside the window, /info equalled the file, the reference agreed
+    assert window["problems"] == ["platform is cpu, not tpu (rehearsal)"], window
+    assert window["answer_tokens_per_request"] == 96
+
+
+def test_four_chip_cell_from_scratch_files_only(tmp_path):
+    """A tensor-parallel cell is a configuration file with MESH_TP in its
+    serve_env and ``chips: 4`` — no line of the harness changes."""
+    config = json.loads((REPO / "benchmark" / "configs" / "yi-1.5-6b-l16.json").read_text())
+    config["name"] = "scratch-tp4"
+    config["chips"] = 4
+    config["rehearsal"].update(num_attention_heads=8, num_key_value_heads=4, head_dim=8)
+    config["rehearsal"]["serve_env"].update(MESH_TP="4", MESH_DP="1")
+    (tmp_path / "scratch-tp4.json").write_text(json.dumps(config))
+    bench = {**BENCH,
+             "configs": [{"name": "scratch-tp4", "source": config["source"],
+                          "file": str(tmp_path / "scratch-tp4.json"), "reduced": [], "why": "test"}],
+             "workloads": [{"name": "scratch-tp4-chat", "config": "scratch-tp4",
+                            "traffic": "chat-closed", "chips": 4, "why": "test"}]}
+    for metric in bench["end_to_end"] + bench["per_layer"]:
+        metric.pop("workloads", None)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    line, out = run_cell("scratch-tp4-chat", 1, ["--benchmark-file", str(tmp_path / "BENCHMARK.json")],
+                         xla_flags="")
+    check_line(line, "scratch-tp4-chat", "per_layer", devices=4)
+    assert line["device"]["busy_s"] > 0 and line["device"]["window_s"] > 0
+    assert line["breakdown"]["device_ops"] and "tick_host_share" in line["metrics"]
+    assert '"mesh": {' in out or "tp" in out  # the plan/trace notes name the mesh run
+
+
+def test_no_accelerator_is_an_error_not_a_cpu_run(monkeypatch, capsys):
+    """JAX seeing only a CPU, without the caller having asked for a
+    rehearsal, ends the run before anything starts: exit code 1, no result."""
+    sys.path.insert(0, str(REPO))
+    from benchmark import run, server
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(server, "probe_device",
+                        lambda env: {"platform": "cpu", "kind": "cpu", "count": 1})
+    monkeypatch.setattr(sys, "argv", ["run.py", "--workload", "mistral7b-rag-open",
+                                      "--seed", "1", "--seconds", "1", "--trace", "0"])
+    assert run.main() == 1
+    captured = capsys.readouterr()
+    assert "no accelerator" in captured.err and '"correct"' not in captured.out
+
+
+def test_fewer_chips_than_the_cell_asks_is_an_error(monkeypatch, capsys, tmp_path):
+    from benchmark import run, server
+
+    bench = {**BENCH, "workloads": [{**BENCH["workloads"][0], "chips": 4}]}
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=1")
+    monkeypatch.setattr(server, "probe_device",
+                        lambda env: {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    monkeypatch.setattr(sys, "argv", ["run.py", "--workload", BENCH["workloads"][0]["name"],
+                                      "--benchmark-file", str(tmp_path / "BENCHMARK.json")])
+    assert run.main() == 1
+    assert "asks 4 chips" in capsys.readouterr().err
