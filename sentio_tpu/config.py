@@ -698,23 +698,16 @@ class AuthConfig:
 
 @dataclass
 class ObservabilityConfig:
-    """Tracing + metrics (reference: observability/tracing.py, metrics.py)."""
+    """Metrics, the resource monitor and where profiler windows are written
+    (spans need no setting: infra/tracing.py is always on)."""
 
-    tracing_enabled: bool = False
-    otlp_endpoint: str = ""
-    console_exporter: bool = False
-    service_name: str = "sentio-tpu"
     metrics_enabled: bool = True
     monitor_interval_s: float = 30.0
-    profiler_dir: str = ""  # non-empty => jax.profiler traces per batch step
+    profiler_dir: str = ""  # where /debug/profile writes when the caller names no dir
 
     @classmethod
     def from_env(cls) -> "ObservabilityConfig":
         return cls(
-            tracing_enabled=_env_bool(["TRACING_ENABLED", "OTEL_ENABLED"], False),
-            otlp_endpoint=_env_str(["OTEL_EXPORTER_OTLP_ENDPOINT"], ""),
-            console_exporter=_env_bool(["OTEL_CONSOLE"], False),
-            service_name=_env_str(["OTEL_SERVICE_NAME"], "sentio-tpu"),
             metrics_enabled=_env_bool(["METRICS_ENABLED"], True),
             monitor_interval_s=_env_float(["MONITOR_INTERVAL_S"], 30.0),
             profiler_dir=_env_str(["JAX_PROFILER_DIR"], ""),

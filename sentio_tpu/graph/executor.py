@@ -26,6 +26,7 @@ from dataclasses import dataclass, field
 from typing import Any, Awaitable, Callable, Mapping, Optional, Union
 
 from sentio_tpu.infra.exceptions import GraphError
+from sentio_tpu.infra.tracing import span
 
 logger = logging.getLogger(__name__)
 
@@ -89,13 +90,6 @@ class CompiledGraph:
             meta.setdefault("graph_config", dict(config))
         state["metadata"] = meta
 
-        # OTel node spans (infra/tracing.py): resolved once per run; the
-        # single `enabled` bool keeps the default (tracing-off) path free
-        # of any span or context-manager overhead per node
-        from sentio_tpu.infra.tracing import get_tracing
-
-        tracing = get_tracing()
-
         current = self.entry
         steps = 0
         path: list[str] = []
@@ -132,20 +126,15 @@ class CompiledGraph:
                 continue
             t0 = time.perf_counter()
             try:
-                if tracing.enabled:
-                    # span per node, carrying the trace id and (once the
-                    # generate node stamped it) the serving replica — the
-                    # correlation keys that join graph spans to flight
-                    # ticks and XLA step annotations
-                    with tracing.span(
-                        f"graph.{node.name}",
-                        request_id=str(meta.get("query_id", "")),
-                        replica_id=int(state["metadata"].get("replica_id", -1)),
-                    ):
-                        update = node.fn(state)
-                        if inspect.isawaitable(update):
-                            update = await update
-                else:
+                # span per node, carrying the trace id and (once the
+                # generate node stamped it) the serving replica: the
+                # request stages written inside the node hang under it
+                query_id = meta.get("query_id")
+                with span(
+                    f"graph.{node.name}",
+                    request_id=str(query_id) if query_id else None,
+                    replica_id=int(state["metadata"].get("replica_id", -1)),
+                ):
                     update = node.fn(state)
                     if inspect.isawaitable(update):
                         update = await update
@@ -190,24 +179,17 @@ def _run_detached(node: _Node, state: dict) -> None:
     event loop — the spawning loop is long gone by the time a slow audit
     decode finishes). Exceptions are logged, never propagated: the caller
     already has its answer."""
-    from sentio_tpu.infra.tracing import get_tracing
-
-    tracing = get_tracing()
+    meta = state.get("metadata", {})
+    query_id = meta.get("query_id")
     try:
-        if tracing.enabled:
-            with tracing.span(
-                f"graph.{node.name}", detached=True,
-                request_id=str(state.get("metadata", {}).get("query_id", "")),
-                replica_id=int(
-                    state.get("metadata", {}).get("replica_id", -1)),
-            ):
-                update = node.fn(state)
-                if inspect.isawaitable(update):
-                    asyncio.run(_await_detached(update))
-            return
-        update = node.fn(state)
-        if inspect.isawaitable(update):
-            asyncio.run(_await_detached(update))
+        with span(
+            f"graph.{node.name}", detached=True,
+            request_id=str(query_id) if query_id else None,
+            replica_id=int(meta.get("replica_id", -1)),
+        ):
+            update = node.fn(state)
+            if inspect.isawaitable(update):
+                asyncio.run(_await_detached(update))
     except Exception:  # noqa: BLE001 — off-path stage must not crash anything
         logger.exception("detached node %s failed", node.name)
 

@@ -24,6 +24,7 @@ from sentio_tpu.graph.state import (
     deadline_remaining_s,
     deadline_ts,
 )
+from sentio_tpu.infra.tracing import span
 from sentio_tpu.models.document import Document
 
 logger = logging.getLogger(__name__)
@@ -94,25 +95,26 @@ def select_documents(
     """Sort by best score, dedup by id, enforce the ≈4-chars/token context
     budget (reference nodes.py:276-338). Shared by the graph's select node
     and the SSE streaming path so the two can never drift."""
-    docs = sorted(docs, key=lambda d: d.score(), reverse=True)
-    seen: set[str] = set()
-    budget_chars = budget_tokens * CHARS_PER_TOKEN
-    used = 0
-    selected: list[Document] = []
-    for doc in docs:
-        if doc.id in seen:
-            continue
-        seen.add(doc.id)
-        text = doc.content
-        if not text.strip():
-            continue
-        cost = len(text)
-        if used + cost > budget_chars and selected:
-            continue  # keep scanning: a shorter doc may still fit
-        selected.append(doc)
-        used += cost
-        if used >= budget_chars:
-            break
+    with span("select", candidates=len(docs)):
+        docs = sorted(docs, key=lambda d: d.score(), reverse=True)
+        seen: set[str] = set()
+        budget_chars = budget_tokens * CHARS_PER_TOKEN
+        used = 0
+        selected: list[Document] = []
+        for doc in docs:
+            if doc.id in seen:
+                continue
+            seen.add(doc.id)
+            text = doc.content
+            if not text.strip():
+                continue
+            cost = len(text)
+            if used + cost > budget_chars and selected:
+                continue  # keep scanning: a shorter doc may still fit
+            selected.append(doc)
+            used += cost
+            if used >= budget_chars:
+                break
     return selected, used
 
 
@@ -161,8 +163,8 @@ def create_generator_node(generator, settings: Optional[Settings] = None):
         try:
             # device generation is the longest stage — keep it off the event
             # loop so concurrent requests, streams, and health checks proceed
-            answer = await asyncio.get_running_loop().run_in_executor(
-                None,
+            # (to_thread carries the node's span context to the admission)
+            answer = await asyncio.to_thread(
                 lambda: generator.generate(
                     state["query"], docs, mode=mode,
                     temperature=temperature if temperature is None else float(temperature),
@@ -331,8 +333,7 @@ def create_verifier_node(verifier, settings: Optional[Settings] = None,
         tenant = meta.get("tenant")
         priority = meta.get("priority")
         t0 = time.perf_counter()
-        result = await asyncio.get_running_loop().run_in_executor(
-            None,
+        result = await asyncio.to_thread(
             lambda: verifier.verify(
                 state["query"], answer, docs,
                 request_id=str(request_id) if request_id else None,

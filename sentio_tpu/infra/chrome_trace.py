@@ -3,10 +3,10 @@
 Turns the flight recorder's tick ring + request table into the Chrome
 Trace Event Format (the JSON ``ui.perfetto.dev`` and ``chrome://tracing``
 open directly): one process row per serving replica, the pump's ticks as
-slices with their named phases (infra/phases.py) nested inside, request
-lifecycles as spans on per-request lanes (admit → engine decode →
-first-token mark → finish), replica health transitions as instants, and
-verify verdicts as trailing slices.
+slices with their named phases (infra/phases.py) nested inside, each
+request's span tree (infra/tracing.py: the request stages from pool_wait to
+decode, the audit's under ``verify``) on a lane of its own, and replica
+health transitions as instants.
 
 Everything here is a PURE function over plain dicts — the exact shapes
 ``FlightRecorder.timeline()``/``records()`` return — so the exporter is
@@ -20,10 +20,10 @@ Layout conventions (Chrome trace event fields):
   ``phase_ms`` laid out as child slices in canonical phase order from the
   tick's start — phases sum to the tick's ``pump_ms`` by construction
   (runtime/service.py), so children exactly tile the parent;
-* ``tid 1..`` = request lanes: the request's wall span, the engine decode
-  sub-span (flight ``t_submit_s`` → finish), a ``first_token`` instant at
-  submit + TTFT, and the verify verdict (when recorded) as a slice
-  trailing the answer — async/gated verdicts visibly overhang the span;
+* ``tid 1..`` = request lanes: the ``request`` root (receipt → finish)
+  and every span the request wrote, each a slice carrying its parent and
+  fields — an async or gated audit's ``verify`` span visibly overhangs the
+  root, whose record closed when the answer did;
 * health transitions ride ``tid 0`` as process-scoped instants.
 
 Timestamps: flight records share one ``perf_counter`` origin
@@ -34,6 +34,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+from sentio_tpu.infra.flight import ROOT_SPAN, shift_spans, span_tree
 from sentio_tpu.infra.phases import TICK_PHASES
 
 __all__ = ["build_chrome_trace", "build_fleet_trace", "flight_to_chrome"]
@@ -106,73 +107,41 @@ def _tick_events(ticks: list[dict]) -> list[dict]:
 
 
 def _request_events(records: list[dict]) -> tuple[list[dict], dict]:
-    """Request spans, one lane per record per replica. Returns the events
-    plus {pid: max_tid} so thread-name metadata can be emitted."""
+    """Request lanes, one per record per replica, laid out from the
+    record's ``spans`` (infra/tracing.py wrote them; flight.span_tree adds
+    the ``request`` root): one slice a span, its parent and fields as args.
+    Returns the events plus {pid: max_tid} so thread-name metadata can be
+    emitted."""
     events: list[dict] = []
     lanes: dict[int, int] = {}
     for record in records:
-        engine = record.get("engine") or {}
-        pid = int(engine.get("replica_id", 0))
+        pid = int((record.get("engine") or {}).get("replica_id", 0))
         tid = lanes.get(pid, _REQUEST_TID_BASE)
         lanes[pid] = tid + 1
         rid = record.get("request_id", "?")
-        t_start = record.get("t_start_s")
-        latency_ms = record.get("latency_ms")
-        if latency_ms is None:
-            # records opened outside the HTTP handler (sentio trace, direct
-            # graph invokes) never get finish_request's latency; the graph
-            # node timings are the honest span fallback
-            timings = record.get("node_timings_ms")
-            if timings:
-                latency_ms = sum(timings.values())
-        if t_start is not None and latency_ms is not None:
+        spans = record.get("spans") or []
+        if not spans or spans[0]["name"] != ROOT_SPAN:
+            spans = span_tree(record)
+        for sp in spans:
+            root = sp["name"] == ROOT_SPAN
+            args = dict(sp.get("fields") or {})
+            if root:
+                args.update({k: record[k] for k in
+                             ("status", "mode", "endpoint", "question_chars",
+                              "ttft_server_ms", "stages_ms", "stream_lag_max_ms")
+                             if k in record})
+            else:
+                args["parent"] = sp["parent"]
+                if sp["name"] == "verify" and record.get("verify"):
+                    args.update({k: record["verify"][k] for k in
+                                 ("mode", "outcome", "confidence", "skipped")
+                                 if k in record["verify"]})
             events.append({
-                "name": f"request {rid}",
+                "name": f"request {rid}" if root else sp["name"],
                 "ph": "X", "pid": pid, "tid": tid,
-                "ts": _us(t_start), "dur": round(float(latency_ms) * 1e3, 1),
-                "args": {k: record[k] for k in
-                         ("status", "mode", "endpoint", "question_chars")
-                         if k in record},
-            })
-            t_finish = t_start + latency_ms / 1e3
-        else:
-            t_finish = t_start
-        t_submit = engine.get("t_submit_s")
-        ttft_ms = engine.get("ttft_ms")
-        if t_submit is not None and t_finish is not None \
-                and t_finish > t_submit:
-            # engine-side sub-span: admit → retire
-            events.append({
-                "name": "engine",
-                "ph": "X", "pid": pid, "tid": tid,
-                "ts": _us(t_submit),
-                "dur": _us(t_finish - t_submit),
-                "args": {k: engine[k] for k in
-                         ("tokens", "prompt_tokens", "prefix_hit_tokens",
-                          "finish_reason", "tpot_ms")
-                         if k in engine},
-            })
-        if t_submit is not None and ttft_ms is not None:
-            events.append({
-                "name": "first_token",
-                "ph": "i", "s": "t",
-                "pid": pid, "tid": tid,
-                "ts": _us(t_submit + ttft_ms / 1e3),
-                "args": {"ttft_ms": ttft_ms},
-            })
-        verify = record.get("verify")
-        if verify and t_finish is not None:
-            # the audit trails the answer (async/gated: visibly AFTER the
-            # request slice ends; sync: inside it — either is the truth)
-            verdict_ms = verify.get("verdict_ms") or 0.0
-            events.append({
-                "name": f"verify:{verify.get('outcome', 'pending')}",
-                "ph": "X", "pid": pid, "tid": tid,
-                "ts": _us(t_finish),
-                "dur": round(float(verdict_ms) * 1e3, 1),
-                "args": {k: verify[k] for k in
-                         ("mode", "confidence", "skipped", "verdict")
-                         if k in verify},
+                "ts": _us(sp["t0_s"]),
+                "dur": _us(sp["t1_s"] - sp["t0_s"]),
+                "args": args,
             })
     return events, lanes
 
@@ -264,6 +233,8 @@ def build_fleet_trace(workers: list[dict], router_ticks: Optional[list] = None,
             if shifted.get("t_start_s") is not None:
                 shifted["t_start_s"] = round(
                     float(shifted["t_start_s"]) + shift, 6)
+            if shifted.get("spans"):
+                shifted["spans"] = shift_spans(shifted["spans"], shift)
             all_records.append(shifted)
     trace = build_chrome_trace(all_ticks, all_records, label=label)
     named: set[int] = set()

@@ -18,8 +18,10 @@ Two bounded, thread-safe stores:
   ``stream_resumed`` (a delivered-token stream spliced onto a survivor —
   ``replica_from``/``replica_to``, ``replayed_tokens``, ``splice_index``);
 * a **request table** — per-request records keyed by the serving layer's
-  ``query_id`` (graph node timings, TTFT, TPOT, token counts, and the tick
-  window the request's decode rode), LRU-evicted at ``max_requests``.
+  ``query_id`` (graph node timings, TTFT, TPOT, token counts, the tick
+  window the request's decode rode, and its ``spans``: what
+  infra/tracing.py wrote under this id, each naming the span that caused
+  it, on this recorder's timeline), LRU-evicted at ``max_requests``.
 
 Writers never block on readers beyond one short mutex; the pump appends one
 small dict per tick, so recording cost is noise next to a device dispatch.
@@ -29,18 +31,52 @@ into HTTP responses and bench artifacts.
 
 from __future__ import annotations
 
+import statistics
 import threading
 import time
 from collections import OrderedDict, deque
 from typing import Any, Optional
 
 from sentio_tpu.analysis.sanitizer import assert_held, guard_locksets, make_lock
+from sentio_tpu.infra.phases import (
+    REQUEST_STAGES,
+    ROW_STEP_KINDS,
+    TTFT_STAGES,
+    tile_ttft,
+)
 
 __all__ = ["FlightRecorder", "get_flight_recorder", "set_flight_recorder"]
 
 # tick events returned inline with one request's record — the full ring is
 # available via timeline(); per-request responses stay bounded
 MAX_TICKS_PER_RECORD = 256
+# spans kept on one request record; a request writes about twenty
+MAX_SPANS_PER_RECORD = 64
+ROOT_SPAN = "request"
+# the audit's span: the stages of ITS admission hang under it and are kept
+# out of the request's own tile and histogram samples
+AUDIT_SPAN = "verify"
+
+
+def shift_spans(spans: list[dict], shift_s: float) -> list[dict]:
+    """Spans re-based onto another recorder's timeline (a worker's record
+    stitched into the router's; clock work is the caller's)."""
+    return [dict(sp, t0_s=round(sp["t0_s"] + shift_s, 6),
+                 t1_s=round(sp["t1_s"] + shift_s, 6)) for sp in spans]
+
+
+def span_tree(record: dict) -> list[dict]:
+    """A record's spans as one tree: the synthesized ``request`` root
+    (receipt → finish, or → the last span's end while it is open) first,
+    then what was written, a span without a parent hanging under the root."""
+    spans = record.get("spans") or []
+    t_start = record.get("t_start_s", 0.0)
+    if record.get("latency_ms") is not None:
+        t_end = t_start + record["latency_ms"] / 1e3
+    else:
+        t_end = max([t_start] + [sp["t1_s"] for sp in spans])
+    root = {"name": ROOT_SPAN, "t0_s": t_start, "t1_s": round(t_end, 6), "parent": None}
+    return [root] + [dict(sp, parent=sp["parent"] or ROOT_SPAN) for sp in spans]
 
 
 @guard_locksets
@@ -56,6 +92,9 @@ class FlightRecorder:
         self._records: "OrderedDict[str, dict]" = OrderedDict()  # guarded-by: _lock
         self.max_requests = max_requests
         self.dropped_requests = 0  # guarded-by: _lock
+        # request id → when the pump queued tokens no socket write has
+        # covered yet (the open end of a stream_lag sample)
+        self._stream_puts: dict[str, float] = {}  # guarded-by: _lock
         self._t0 = time.perf_counter()  # timeline origin for tick timestamps
 
     # ------------------------------------------------------------- requests
@@ -73,8 +112,11 @@ class FlightRecorder:
             self._evict_locked()
         return record
 
-    def start_request(self, request_id: str, **fields: Any) -> None:
-        """Open a record. Extra fields merge in verbatim. A finished record
+    def start_request(self, request_id: str, t_received: Optional[float] = None,
+                      **fields: Any) -> None:
+        """Open a record. ``t_received`` (raw ``perf_counter``) backdates
+        its start to when the request reached the server, before it waited
+        for a thread. Extra fields merge in verbatim. A finished record
         under the same id (multi-turn conversations pin ``thread_id``, which
         doubles as the trace id) is replaced, not merged — otherwise turn 2's
         node timings would sum onto turn 1's; the latest turn wins."""
@@ -85,6 +127,8 @@ class FlightRecorder:
             if prior is not None and prior.get("status") != "active":
                 del self._records[request_id]
             record = self._ensure_locked(request_id)
+            if t_received is not None:
+                record["t_start_s"] = round(t_received - self._t0, 6)
             record.update(fields)
             self._records.move_to_end(request_id)
 
@@ -164,10 +208,77 @@ class FlightRecorder:
             record.setdefault("verify", {}).update(fields)
             self._records.move_to_end(request_id)
 
+    # ---------------------------------------------------------------- spans
+
+    def add_span(self, request_id: str, name: str, t0: float, t1: float,
+                 parent: Optional[str] = None,
+                 fields: Optional[dict] = None) -> None:
+        """Append one closed span (infra/tracing.py is the writer). ``t0``
+        and ``t1`` are raw ``perf_counter`` values, stored on this
+        recorder's timeline. Bounded per record: past the cap spans are
+        counted, not kept."""
+        with self._lock:
+            record = self._ensure_locked(request_id)
+            spans = record.setdefault("spans", [])
+            if len(spans) >= MAX_SPANS_PER_RECORD:
+                record["spans_dropped"] = record.get("spans_dropped", 0) + 1
+                return
+            entry = {"name": name, "t0_s": round(t0 - self._t0, 6),
+                     "t1_s": round(t1 - self._t0, 6), "parent": parent}
+            if fields:
+                entry["fields"] = dict(fields)
+            spans.append(entry)
+
+    def close_ttft(self, request_id: str, t_first: float) -> Optional[dict]:
+        """The request's first token is host-visible at ``t_first`` (raw
+        ``perf_counter``): tile receipt → now with the stage spans written
+        so far (phases.tile_ttft; the audit's inner stages excluded), keep
+        the tile on the record and return it in seconds. ``None`` when the
+        record is gone or was closed before (a second admission under one
+        id): a request is observed once."""
+        with self._lock:
+            record = self._records.get(request_id)
+            if record is None or "stages_ms" in record:
+                return None
+            ttft_s = (t_first - self._t0) - record["t_start_s"]
+            stage_s: dict[str, float] = {}
+            for sp in record.get("spans", ()):
+                if sp["name"] in TTFT_STAGES[:-1] and sp["parent"] != AUDIT_SPAN:
+                    stage_s[sp["name"]] = (
+                        stage_s.get(sp["name"], 0.0) + sp["t1_s"] - sp["t0_s"])
+            tile = tile_ttft(stage_s, ttft_s)
+            record["ttft_server_ms"] = round(ttft_s * 1e3, 3)
+            record["stages_ms"] = {k: round(v * 1e3, 3) for k, v in tile.items()}
+            return tile
+
+    def note_stream_put(self, request_id: str, t_put: float) -> None:
+        """The pump queued tokens for this stream at ``t_put``. Only the
+        OLDEST put no write has covered is kept: puts that coalesce into
+        one socket write, or a put whose bytes were withheld, wait from
+        the first of them."""
+        with self._lock:
+            self._stream_puts.setdefault(request_id, t_put)
+
+    def take_stream_lag(self, request_id: str, t_written: float) -> Optional[float]:
+        """Tokens reached the socket at ``t_written``: seconds since the
+        oldest uncovered put (``None`` when nothing was pending), the
+        largest kept on the record."""
+        with self._lock:
+            t_put = self._stream_puts.pop(request_id, None)
+            if t_put is None:
+                return None
+            lag = t_written - t_put
+            record = self._records.get(request_id)
+            if record is not None:
+                record["stream_lag_max_ms"] = max(
+                    record.get("stream_lag_max_ms", 0.0), round(lag * 1e3, 3))
+            return lag
+
     def finish_request(self, request_id: str, **fields: Any) -> None:
         if not request_id:
             return
         with self._lock:
+            self._stream_puts.pop(request_id, None)
             record = self._records.get(request_id)
             if record is None:
                 return
@@ -194,6 +305,14 @@ class FlightRecorder:
             event.update(fields)
             self._ticks.append(event)
             return self._tick_seq
+
+    def next_tick(self) -> int:
+        """The sequence number the next :meth:`record_tick` will assign —
+        what the pump's ``decode_tick`` step annotation carries. A guess
+        only when several pumps share this recorder (the tick event's
+        ``step`` field then says which annotation was its own)."""
+        with self._lock:
+            return self._tick_seq + 1
 
     def amend_tick(self, tick: int, restamp: bool = True,
                    **fields: Any) -> int:
@@ -223,6 +342,8 @@ class FlightRecorder:
             if record is None:
                 return None
             out = dict(record)
+            if "spans" in record:
+                out["spans"] = span_tree(record)
             engine = record.get("engine")
             if engine:
                 out["engine"] = dict(engine)
@@ -247,11 +368,63 @@ class FlightRecorder:
         """Shallow copies of every retained request record, insertion order
         (the Chrome-trace exporter's request-span source)."""
         with self._lock:
-            return [
-                dict(record, engine=dict(record["engine"]))
-                if "engine" in record else dict(record)
-                for record in self._records.values()
-            ]
+            out = []
+            for record in self._records.values():
+                copy = dict(record)
+                if "engine" in record:
+                    copy["engine"] = dict(record["engine"])
+                if "spans" in record:
+                    copy["spans"] = span_tree(record)
+                out.append(copy)
+            return out
+
+    def stage_summary(self, last: Optional[int] = None) -> dict:
+        """Where the retained finished requests waited (``last``: only the
+        N that finished most recently — a load window without its warm-up):
+        per stage count, mean and median in ms. The tile
+        stages come from each record's ``stages_ms``, the later ones from
+        its spans (the audit's inner stages left out) and ``stream_lag``
+        from each record's largest. ``residual_ms_max``
+        is the largest |sum of a request's tile − its server-side TTFT|:
+        zero but for rounding, by construction. ``row_steps`` sums the
+        retained ticks' counted row-steps."""
+        with self._lock:
+            done = [r for r in self._records.values()
+                    if r.get("status") == "done" and "stages_ms" in r]
+            if last:
+                done = done[-last:]  # the table is ordered by last touch
+            samples: dict[str, list] = {stage: [] for stage in REQUEST_STAGES}
+            residual = 0.0
+            for record in done:
+                for stage, ms in record["stages_ms"].items():
+                    samples[stage].append(ms)
+                residual = max(residual, abs(
+                    sum(record["stages_ms"].values()) - record["ttft_server_ms"]))
+                for sp in record.get("spans", ()):
+                    if sp["name"] in ("decode", AUDIT_SPAN) and sp["parent"] != AUDIT_SPAN:
+                        samples[sp["name"]].append((sp["t1_s"] - sp["t0_s"]) * 1e3)
+                if "stream_lag_max_ms" in record:
+                    samples["stream_lag"].append(record["stream_lag_max_ms"])
+            ttft = [r["ttft_server_ms"] for r in done]
+            row_steps = dict.fromkeys(ROW_STEP_KINDS, 0)
+            sub_steps = 0
+            for event in self._ticks:
+                sub_steps += event.get("sub_steps", 0)
+                for kind, n in (event.get("row_steps") or {}).items():
+                    row_steps[kind] = row_steps.get(kind, 0) + n
+        return {
+            "requests": len(done),
+            "ttft_server_ms": ({"mean": round(sum(ttft) / len(ttft), 3),
+                                "p50": round(statistics.median(ttft), 3)} if ttft else None),
+            # stream_lag: each request's LARGEST lag (the histogram has every event)
+            "stages_ms": {stage: {"count": len(v), "mean": round(sum(v) / len(v), 3),
+                                  "p50": round(statistics.median(v), 3)}
+                          for stage, v in samples.items() if v},
+            "residual_ms_max": round(residual, 6),
+            # over the retained ticks: the kinds sum to slots x sub_steps
+            "row_steps": row_steps,
+            "sub_steps": sub_steps,
+        }
 
     def origin(self) -> float:
         """This recorder's timeline zero as a raw ``perf_counter`` value.
@@ -292,6 +465,7 @@ class FlightRecorder:
         with self._lock:
             self._ticks.clear()
             self._records.clear()
+            self._stream_puts.clear()
             self._tick_seq = 0
             self.dropped_requests = 0
 
@@ -303,7 +477,8 @@ class FlightRecorder:
     def _evict_locked(self) -> None:
         assert_held(self._lock)
         while len(self._records) > self.max_requests:
-            self._records.popitem(last=False)
+            evicted, _ = self._records.popitem(last=False)
+            self._stream_puts.pop(evicted, None)
             self.dropped_requests += 1
 
 

@@ -147,27 +147,13 @@ class MetricsCollector:
             "embeddings": Counter(
                 "sentio_embeddings_total", "texts embedded", ["provider"], registry=r
             ),
-            "retrieval_latency": Histogram(
-                "sentio_retrieval_latency_seconds", "retrieval latency", ["strategy"], registry=r
-            ),
             "llm_tokens": Counter(
                 "sentio_llm_tokens_total", "tokens generated", ["kind"], registry=r
             ),
             "llm_latency": Histogram(
                 "sentio_llm_latency_seconds", "LLM call latency", ["op"], registry=r
             ),
-            "breaker_state": Gauge(
-                "sentio_circuit_breaker_state", "0 closed / 1 half-open / 2 open",
-                ["name"], registry=r,
-            ),
             # TPU device dimension
-            "hbm_bytes": Gauge(
-                "sentio_tpu_hbm_bytes_in_use", "device memory in use", ["device"], registry=r
-            ),
-            "batch_occupancy": Histogram(
-                "sentio_tpu_batch_occupancy", "coalesced batch fill fraction", ["batcher"],
-                buckets=(0.125, 0.25, 0.5, 0.75, 1.0), registry=r,
-            ),
             "serving_stat": Gauge(
                 "sentio_tpu_serving_stat",
                 "decode service point-in-time stats (occupancy, queue depth, pages)",
@@ -296,6 +282,28 @@ class MetricsCollector:
                 buckets=(1e-5, 1e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 0.01,
                          0.025, 0.05, 0.1, 0.25, 0.5, 1, 5),
                 registry=r,
+            ),
+            # where a request waits (infra/phases.py REQUEST_STAGES): the
+            # nine stages from pool_wait to other are observed together
+            # when a request's first token lands and sum to its server-side
+            # TTFT; decode, verify and stream_lag when they close
+            "request_stage": Histogram(
+                "sentio_tpu_request_stage_seconds",
+                "request time per named stage, receipt to last token",
+                ["stage"],
+                buckets=(5e-4, 1e-3, 2.5e-3, 5e-3, 0.01, 0.025, 0.05, 0.1,
+                         0.25, 0.5, 1, 2.5, 5, 10, 30),
+                registry=r,
+            ),
+            # what the decode slots did with the sub-steps the device ran:
+            # per harvested tick, slots x sub-steps row-steps, each one
+            # useful (its token was delivered), halted (a request held the
+            # slot but the row had finished, spent its budget or was still
+            # prefilling) or empty (no request in the slot)
+            "row_steps": Counter(
+                "sentio_tpu_decode_row_steps_total",
+                "decode row-steps by what they produced",
+                ["kind"], registry=r,
             ),
             # process-mode replica tier (runtime/worker.py): worker
             # process deaths observed by the router-side shim (SIGKILL,
@@ -460,13 +468,6 @@ class MetricsCollector:
         if self._prom:
             self._prom["embeddings"].labels(provider).inc(n_texts)
 
-    def record_retrieval(self, strategy: str, latency_s: float) -> None:
-        if not self.enabled:
-            return
-        self.memory.observe("retrieval_latency", (strategy,), latency_s)
-        if self._prom:
-            self._prom["retrieval_latency"].labels(strategy).observe(latency_s)
-
     def record_llm(self, op: str, latency_s: float, tokens: int = 0) -> None:
         if not self.enabled:
             return
@@ -528,6 +529,34 @@ class MetricsCollector:
             self.memory.observe("tick_phase", (key,), float(value))
             if hist is not None:
                 hist.labels(phase=key).observe(float(value))
+
+    def record_request_stage(self, stage: str, seconds: float) -> None:
+        """One closed request stage. A stage outside ``REQUEST_STAGES``
+        RAISES: the writer is this program's own code, and a typo'd stage
+        must fail there, not mint a series."""
+        from sentio_tpu.infra.phases import REQUEST_STAGES
+
+        if stage not in REQUEST_STAGES:
+            raise KeyError(f"unknown stage {stage!r} (bounded set: {REQUEST_STAGES})")
+        if not self.enabled:
+            return
+        self.memory.observe("request_stage", (stage,), float(seconds))
+        hist = self._prom.get("request_stage")
+        if hist is not None:
+            hist.labels(stage=stage).observe(float(seconds))
+
+    def record_row_steps(self, counts: dict) -> None:
+        """One harvested tick's row-steps by kind (useful / halted / empty)."""
+        if not self.enabled:
+            return
+        from sentio_tpu.infra.phases import ROW_STEP_KINDS
+
+        counter = self._prom.get("row_steps")
+        for kind in ROW_STEP_KINDS:
+            n = int(counts.get(kind, 0))
+            self.memory.inc("row_steps", (kind,), n)
+            if counter is not None:
+                counter.labels(kind=kind).inc(n)
 
     def record_duty_cycle(self, replica: int, fractions: dict) -> None:
         """Publish one replica's host/device/idle duty-cycle fractions
@@ -886,31 +915,6 @@ class MetricsCollector:
             gauge = self._prom.get("replica_health")
             if gauge is not None:
                 gauge.labels(replica=str(replica), state=name).set(value)
-
-    def record_breaker(self, name: str, state: str) -> None:
-        value = {"closed": 0.0, "half_open": 1.0, "open": 2.0}.get(state, 0.0)
-        self.memory.set_gauge("breaker_state", (name,), value)
-        if self._prom:
-            self._prom["breaker_state"].labels(name).set(value)
-
-    def record_batch_occupancy(self, batcher: str, occupancy: float) -> None:
-        self.memory.observe("batch_occupancy", (batcher,), occupancy)
-        if self._prom:
-            self._prom["batch_occupancy"].labels(batcher).observe(occupancy)
-
-    def collect_device_memory(self) -> None:
-        """Poll jax device memory stats into the HBM gauge (best effort)."""
-        try:
-            import jax
-
-            for dev in jax.devices():
-                stats = dev.memory_stats()
-                if stats and "bytes_in_use" in stats:
-                    self.memory.set_gauge("hbm_bytes", (str(dev.id),), stats["bytes_in_use"])
-                    if self._prom:
-                        self._prom["hbm_bytes"].labels(str(dev.id)).set(stats["bytes_in_use"])
-        except Exception:  # noqa: BLE001 — device-memory scrape is best-effort telemetry
-            pass
 
     # --------------------------------------------------------------- helpers
 

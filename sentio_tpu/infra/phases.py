@@ -45,6 +45,52 @@ Phase glossary (one pump iteration, in canonical order):
 
 ``idle`` is not a tick phase: it is the duty-cycle complement (wall time
 with no pump iteration running — pump down, or gaps between bursts).
+
+Every region timed through :meth:`PhaseTimer.phase` also runs under a
+profiler annotation named ``tick.<phase>``: inside an armed
+``/debug/profile`` window the device trace's idle gaps are then named by
+what the pump was doing, on the device's own clock.
+
+**Request stages** are the same idea one level up: where a REQUEST's time
+goes between its receipt and its last token. ``REQUEST_STAGES`` is fixed and
+bounded like ``TICK_PHASES``; each stage is written where its work happens
+(infra/tracing.py spans) and observed once per request in
+``sentio_tpu_request_stage_seconds{stage}``:
+
+``pool_wait``
+    ``/chat`` received → the request's pipeline starts on an executor
+    thread (the server runs as many streams at a time as that pool has
+    threads).
+``embed``
+    The dense retrieval leg: query embedding dispatched → its top-k on the
+    host (on the fused path the query vector never visits the host; the one
+    blocking fetch is the index's).
+``sparse_fuse``
+    Dense leg back → fused, scored candidates: what is left of BM25, which
+    ran beside the dense leg, then fusion and scorer plugins.
+``rerank``
+    Cross-encoder call → scores on the host.
+``select``
+    Dedup and the context token budget.
+``inbox_wait``
+    Ticket created → the pump's inbox drain hands it to ``engine.submit``.
+``slot_wait``
+    ``engine.submit`` → admitted into a slot.
+``prefill``
+    Admitted → first token host-visible.
+``other``
+    The residual: receipt → first token minus the eight above (prompt
+    building, thread hops, JSON). Explicit, so the stages sum to the
+    request's server-side time to first token by construction
+    (:func:`tile_ttft`), as the tick phases sum to ``pump_ms``.
+``decode``
+    First token → finished.
+``verify``
+    The audit: its own admission's inbox_wait/slot_wait/prefill/decode are
+    its children in the request's span tree and are not observed again.
+``stream_lag``
+    Per stream event: the pump put tokens on the ticket's queue → the HTTP
+    handler wrote them to the socket.
 """
 
 from __future__ import annotations
@@ -52,6 +98,10 @@ from __future__ import annotations
 import time
 
 __all__ = [
+    "REQUEST_STAGES",
+    "ROW_STEP_KINDS",
+    "TTFT_STAGES",
+    "tile_ttft",
     "TICK_PHASES",
     "ENGINE_PHASES",
     "HOST_PHASES",
@@ -89,22 +139,65 @@ HOST_PHASES = tuple(p for p in TICK_PHASES if p != "device_wait")
 
 DUTY_STATES = ("host", "device", "idle")
 
+# the stages that tile receipt → first token, the residual last
+TTFT_STAGES = (
+    "pool_wait",
+    "embed",
+    "sparse_fuse",
+    "rerank",
+    "select",
+    "inbox_wait",
+    "slot_wait",
+    "prefill",
+    "other",
+)
+
+# the one bounded stage set — the request-stage histogram's `stage` label
+REQUEST_STAGES = TTFT_STAGES + ("decode", "verify", "stream_lag")
+
+# what a decode slot did with one sub-step the device ran (the engine counts
+# slots x sub-steps per harvested tick, runtime/paged.py): `useful` folded a
+# token into an answer, `halted` held a request that had finished, spent its
+# budget or was still prefilling, `empty` held none
+ROW_STEP_KINDS = ("useful", "halted", "empty")
+
+
+def tile_ttft(stage_s: dict, ttft_s: float) -> dict:
+    """Seconds per measured stage + a request's server-side time to first
+    token → the full ``TTFT_STAGES`` dict, zeros included, ``other`` taking
+    what the measured stages leave. ``sum(tile.values()) == ttft_s`` by
+    construction. A key outside the set raises, as ``PhaseTimer`` does: a
+    typo'd stage must fail where it is written, not mint a series."""
+    tile = dict.fromkeys(TTFT_STAGES, 0.0)
+    for key, seconds in stage_s.items():
+        if key not in tile or key == "other":
+            raise KeyError(f"unknown stage {key!r} (bounded set: {TTFT_STAGES[:-1]})")
+        tile[key] += seconds
+    tile["other"] = ttft_s - sum(tile.values())
+    return tile
+
 
 class _PhaseSpan:
-    """Tiny enter/exit timer — two perf_counter calls and a dict add."""
+    """Tiny enter/exit timer — two perf_counter calls and a dict add, under
+    a ``tick.<phase>`` profiler annotation."""
 
-    __slots__ = ("_timer", "_key", "_t0")
+    __slots__ = ("_timer", "_key", "_t0", "_ann")
 
     def __init__(self, timer: "PhaseTimer", key: str) -> None:
         self._timer = timer
         self._key = key
 
     def __enter__(self) -> "_PhaseSpan":
+        from jax.profiler import TraceAnnotation
+
+        self._ann = TraceAnnotation(f"tick.{self._key}")
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         self._timer.add(self._key, time.perf_counter() - self._t0)
+        self._ann.__exit__(*exc)
         return False
 
 

@@ -23,6 +23,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from sentio_tpu.infra.tracing import annotation
 from sentio_tpu.models.document import Document
 
 
@@ -193,8 +194,10 @@ class TpuDenseIndex:
         scores, rows = _topk_fn(self.mesh, self.dtype, k_local, k_out)(
             corpus_dev, valid_dev, qn
         )
-        # one blocking fetch for both outputs, not two sequential ones
-        scores, rows = jax.device_get((scores, rows))
+        # one blocking fetch for both outputs, not two sequential ones. On
+        # the fused retrieval path it waits for the query's embedding too
+        with annotation("embed.fetch"):
+            scores, rows = jax.device_get((scores, rows))
         scores = np.asarray(scores, np.float32)
 
         out: list[list[tuple[Document, float]]] = []

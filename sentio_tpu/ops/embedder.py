@@ -25,6 +25,7 @@ import numpy as np
 
 from sentio_tpu.config import EmbedderConfig, get_settings
 from sentio_tpu.infra import faults
+from sentio_tpu.infra.tracing import annotation
 
 logger = logging.getLogger(__name__)
 
@@ -294,8 +295,10 @@ class TpuEmbedder(BaseEmbedder):
             constant_values=self.tokenizer.pad_id,
         )
         mask = np.pad(mask, ((0, rows - n), (0, width - mask.shape[1])))
-        out = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask))
-        return np.asarray(out, np.float32)[:n]
+        with annotation("embed.dispatch", rows=rows, width=width):
+            out = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask))
+        with annotation("embed.fetch"):
+            return np.asarray(out, np.float32)[:n]
 
     def embed_device(self, texts: list[str]):
         """Embed → [n, D] array WITHOUT a blocking host download. The dense
@@ -337,7 +340,9 @@ class TpuEmbedder(BaseEmbedder):
             constant_values=self.tokenizer.pad_id,
         )
         mask = np.pad(mask, ((0, rows - n), (0, width - mask.shape[1])))
-        out = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask))[:n]
+        # the blocking half is the consumer's (ops/dense_index.py, embed.fetch)
+        with annotation("embed.dispatch", rows=rows, width=width):
+            out = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask))[:n]
 
         if self.cache.max_size > 0:  # cache off → skip the device download
 

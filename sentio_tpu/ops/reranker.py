@@ -20,6 +20,7 @@ import numpy as np
 
 from sentio_tpu.config import RerankConfig, get_settings
 from sentio_tpu.infra import faults
+from sentio_tpu.infra.tracing import span
 from sentio_tpu.models.document import Document
 
 logger = logging.getLogger(__name__)
@@ -50,7 +51,9 @@ class Reranker:
         top_k = top_k if top_k is not None else len(documents)
         try:
             faults.hit("reranker.score")
-            scores = np.asarray(self._score(query, documents), np.float32)
+            # the `rerank` request stage: scorer called → scores on the host
+            with span("rerank", pairs=len(documents)):
+                scores = np.asarray(self._score(query, documents), np.float32)
             if scores.shape != (len(documents),):
                 raise ValueError(f"scorer returned shape {scores.shape}")
         except Exception:
@@ -86,9 +89,8 @@ class Reranker:
     async def arerank(
         self, query: str, documents: Sequence[Document], top_k: Optional[int] = None
     ) -> RerankingResult:
-        return await asyncio.get_running_loop().run_in_executor(
-            None, self.rerank, query, list(documents), top_k
-        )
+        # to_thread carries the caller's span context to the `rerank` stage
+        return await asyncio.to_thread(self.rerank, query, list(documents), top_k)
 
 
 class PassthroughReranker(Reranker):

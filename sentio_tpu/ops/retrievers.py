@@ -12,6 +12,7 @@ CPU scoring overlap (`asyncio.gather` over the executor).
 from __future__ import annotations
 
 import asyncio
+import time
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -19,6 +20,7 @@ import numpy as np
 
 from sentio_tpu.config import RetrievalConfig, Settings, get_settings
 from sentio_tpu.infra import faults
+from sentio_tpu.infra.tracing import span, stamp
 from sentio_tpu.models.document import Document
 from sentio_tpu.ops.bm25 import BM25Index
 from sentio_tpu.ops.dense_index import TpuDenseIndex
@@ -39,9 +41,9 @@ class BaseRetriever:
         raise NotImplementedError
 
     async def aretrieve(self, query: str, top_k: int = 10) -> list[Document]:
-        return await asyncio.get_running_loop().run_in_executor(
-            None, self.retrieve, query, top_k
-        )
+        # to_thread, not run_in_executor: the leg's stage spans find their
+        # request through the caller's context
+        return await asyncio.to_thread(self.retrieve, query, top_k)
 
 
 @dataclass
@@ -52,13 +54,17 @@ class DenseRetriever(BaseRetriever):
 
     def retrieve(self, query: str, top_k: int = 10) -> list[Document]:
         faults.hit("retriever.dense")
-        # fused path: embedder output stays on device and feeds the index's
-        # top-k program directly — one host round trip for the whole leg
-        if hasattr(self.embedder, "embed_device") and isinstance(self.index, TpuDenseIndex):
-            q_dev = self.embedder.embed_device([query])
-            return [doc for doc, _ in self._scored(q_dev, top_k)]
-        q_vec = self.embedder.embed(query)
-        return self.index.retrieve(np.asarray(q_vec, np.float32), top_k)
+        # the `embed` request stage is this whole leg: on the fused path the
+        # query vector never visits the host, so the embedding is back only
+        # when the index's top-k is (embed.dispatch / embed.fetch inside)
+        with span("embed"):
+            # fused path: embedder output stays on device and feeds the index's
+            # top-k program directly — one host round trip for the whole leg
+            if hasattr(self.embedder, "embed_device") and isinstance(self.index, TpuDenseIndex):
+                q_dev = self.embedder.embed_device([query])
+                return [doc for doc, _ in self._scored(q_dev, top_k)]
+            q_vec = self.embedder.embed(query)
+            return self.index.retrieve(np.asarray(q_vec, np.float32), top_k)
 
     def _scored(self, q_dev, top_k: int):
         out = []
@@ -108,7 +114,19 @@ class HybridRetriever(BaseRetriever):
 
     async def aretrieve(self, query: str, top_k: int = 10) -> list[Document]:
         pool = max(top_k * 2, 10)
-        fetchers = [r.aretrieve(query, pool) for r in self.retrievers]
+        t_start = t_dense = time.perf_counter()
+
+        async def timed_dense(retriever: BaseRetriever):
+            nonlocal t_dense
+            try:
+                return await retriever.aretrieve(query, pool)
+            finally:
+                t_dense = time.perf_counter()
+
+        # the dense leg writes the `embed` stage itself; what this call
+        # adds after that leg is back is `sparse_fuse`
+        fetchers = [timed_dense(r) if isinstance(r, DenseRetriever)
+                    else r.aretrieve(query, pool) for r in self.retrievers]
         if self.web_cache is not None:
             fetchers.append(self.web_cache.aretrieve(query, pool))
         legs = await asyncio.gather(*fetchers, return_exceptions=True)
@@ -146,6 +164,8 @@ class HybridRetriever(BaseRetriever):
             rrf_k=self.config.rrf_k,
         )
         fused = self._apply_scorers(query, fused)
+        stamp("sparse_fuse", max(t_dense, t_start), time.perf_counter(),
+              legs=len(ok_lists))
         return fused[:top_k]
 
     def _apply_scorers(self, query: str, docs: list[Document]) -> list[Document]:

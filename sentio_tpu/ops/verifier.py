@@ -14,6 +14,7 @@ from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
 from sentio_tpu.config import GeneratorConfig, get_settings
+from sentio_tpu.infra.tracing import span
 from sentio_tpu.models.document import Document
 from sentio_tpu.ops.generator import LLMGenerator
 from sentio_tpu.ops.prompts import PromptBuilder
@@ -57,33 +58,36 @@ class AnswerVerifier:
         priority: Optional[str] = None,
     ) -> VerifyResult:
         try:
-            # the audit prompt EMBEDS the generate prompt verbatim as its
-            # head (same instruction profile + context + question, in the
-            # same bytes) — on the paged engine the radix prefix cache then
-            # serves that whole span from the generate admission's KV pages
-            # and this call prefills only the audit tail
-            context = self.generator.prepare_context(documents)
-            prompt = self.prompts.build(
-                "verify",
-                instruction=self.prompts.load("profile"),
-                context=context,
-                query=query,
-                answer=answer,
-            )
-            # the caller's deadline bounds the audit decode too — an
-            # expired caller's verification is cancelled like its
-            # generation — and the audit admission is charged to the
-            # caller's WFQ tenant (a flooding tenant's verify traffic
-            # competes inside ITS quota, not against everyone)
-            reply = self.generator.chat_raw(
-                prompt,
-                max_new_tokens=self.config.verifier_max_tokens,
-                temperature=0.0,
-                request_id=request_id,
-                deadline_ts=deadline_ts,
-                tenant=tenant,
-                priority=priority,
-            )
+            # the `verify` request stage: the audit's own admission writes
+            # its inbox_wait/slot_wait/prefill/decode under this span
+            with span("verify", request_id=request_id):
+                # the audit prompt EMBEDS the generate prompt verbatim as its
+                # head (same instruction profile + context + question, in the
+                # same bytes) — on the paged engine the radix prefix cache then
+                # serves that whole span from the generate admission's KV pages
+                # and this call prefills only the audit tail
+                context = self.generator.prepare_context(documents)
+                prompt = self.prompts.build(
+                    "verify",
+                    instruction=self.prompts.load("profile"),
+                    context=context,
+                    query=query,
+                    answer=answer,
+                )
+                # the caller's deadline bounds the audit decode too — an
+                # expired caller's verification is cancelled like its
+                # generation — and the audit admission is charged to the
+                # caller's WFQ tenant (a flooding tenant's verify traffic
+                # competes inside ITS quota, not against everyone)
+                reply = self.generator.chat_raw(
+                    prompt,
+                    max_new_tokens=self.config.verifier_max_tokens,
+                    temperature=0.0,
+                    request_id=request_id,
+                    deadline_ts=deadline_ts,
+                    tenant=tenant,
+                    priority=priority,
+                )
             return self._normalize(reply)
         except Exception as exc:  # noqa: BLE001 — the audit must never 500
             return VerifyResult(verdict="warn", notes=[f"verifier error: {exc}"])

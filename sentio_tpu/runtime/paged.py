@@ -58,7 +58,8 @@ import numpy as np
 from sentio_tpu.analysis.audit.registry import jit_family
 from sentio_tpu.analysis.sanitizer import check_engine_invariants, engine_guard
 from sentio_tpu.infra import faults
-from sentio_tpu.infra.phases import ENGINE_PHASES, PhaseTimer
+from sentio_tpu.infra.phases import ENGINE_PHASES, ROW_STEP_KINDS, PhaseTimer
+from sentio_tpu.infra.tracing import annotation
 from sentio_tpu.models.llama import LlamaConfig
 from sentio_tpu.parallel.batcher import bucket_size
 
@@ -401,6 +402,10 @@ class _Slot:
     # budget — decode ticks for OTHER slots interleave with its segments.
     prefill_todo: Optional[list] = None
     prefill_done: int = 0
+    # wall-clock at admission into this slot (the end of slot_wait, the
+    # start of prefill) and the prefill dispatches this request took so far
+    admit_t: float = 0.0
+    prefill_segments: int = 0
 
 
 @dataclass
@@ -461,6 +466,11 @@ class PagedResult:
     # engine outside any service); stamped by PagedGenerationService at
     # completion so tracing spans and stats sinks can name the replica
     replica_id: int = -1
+    # engine-side stage stamps: when the request was admitted into a slot
+    # (perf_counter; 0.0 = never) and how many prefill dispatches its
+    # prompt took — what the service turns into slot_wait and prefill
+    admit_t: float = 0.0
+    prefill_segments: int = 0
 
     @property
     def logprob_mean(self) -> Optional[float]:
@@ -478,6 +488,7 @@ class PagedResult:
             "logprob_count": self.logprob_count,
             "logprob_mean": self.logprob_mean,
             "tokens": len(self.tokens),
+            "prompt_tokens": self.prompt_tokens,
             "finish_reason": self.finish_reason,
         }
         if self.replica_id >= 0:
@@ -672,6 +683,15 @@ class ContinuousBatchingEngine:
         # under chunked prefill); decode counts every folded sampled token.
         self.prefill_tokens_total = 0
         self.decode_tokens_total = 0
+        # what the slots did with the sub-steps the device ran, counted when
+        # a tick is harvested: slots x sub-steps row-steps, each useful (its
+        # token was folded into an answer), halted (a request held the slot,
+        # but the row had finished, spent its budget or was still
+        # prefilling) or empty (no request in the slot). Lifetime totals,
+        # and the same for the tick(s) harvested by the latest step().
+        self.row_steps_total = dict.fromkeys(ROW_STEP_KINDS, 0)
+        self.last_tick_row_steps = dict.fromkeys(ROW_STEP_KINDS, 0)
+        self.last_tick_sub_steps = 0
         self._queue: list[_Request] = []  # guarded-by: engine-thread
         # skip-ahead admission: a request too large for the current free
         # pages may be jumped by later, smaller requests — but only
@@ -1171,6 +1191,10 @@ class ContinuousBatchingEngine:
         self._finished_buffer.clear()
         self._pending_first.clear()
         self._dev_state = None
+        if self._inflight is not None:
+            # dispatched, never harvested: the device ran these row-steps
+            # and nothing of them was delivered
+            self._count_row_steps(self._inflight, useful=0)
         self._inflight = None
         if self._prefix_cache_enabled:
             from sentio_tpu.runtime.radix import RadixPrefixCache
@@ -1269,25 +1293,29 @@ class ContinuousBatchingEngine:
         completed this tick."""
         if self._san is not None:
             self._san.enter("step")
-        # the timer resets BEFORE the injection point: whatever a failed
-        # step leaves in the accumulator belongs to THIS step alone, so the
+        # the timer and the row-step counts reset BEFORE the injection point:
+        # whatever a failed step leaves in them belongs to THIS step alone, so the
         # pump's crash-path flush (partial_step_phases) can never re-count
         # the previous tick's already-recorded phases
         acc = self._phase.acc
         self._phase.reset()
+        self.last_tick_row_steps = dict.fromkeys(ROW_STEP_KINDS, 0)
+        self.last_tick_sub_steps = 0
         # chaos-drill injection point: a raised fault propagates exactly like
         # a real failed device dispatch (the serving pump resets + requeues)
         faults.hit("paged.step")
         t0 = time.perf_counter()
         self.last_tick_active = 0
-        self._admit()
-        if self.prefill_chunk is not None:
-            self._advance_prefill()
+        with annotation("tick.admission_build"):
+            self._admit()
+            if self.prefill_chunk is not None:
+                self._advance_prefill()
         t_admit = time.perf_counter()
         # the admission span minus its jit dispatch calls is pure host build
         # work (tokenize, radix match, page alloc, padded array assembly)
         acc["admission_build"] += (t_admit - t0) - acc["prefill_dispatch"]
-        record = self._dispatch_tick() if any(s.active for s in self.slots) else None
+        with annotation("tick.decode_dispatch"):
+            record = self._dispatch_tick() if any(s.active for s in self.slots) else None
         t_dispatch = time.perf_counter()
         # decode dispatch is HOST CALL time of an async dispatch; any
         # blocking first-token fold inside it already went to device_wait
@@ -1295,13 +1323,14 @@ class ContinuousBatchingEngine:
         # buffer swap AFTER dispatch: defensive retires made while budgeting
         # must ride THIS step's results (there may not be a next step)
         out, self._finished_buffer = self._finished_buffer, []
-        if self.pipeline_depth <= 1:
-            if record is not None:
-                out.extend(self._harvest(record))
-        else:
-            prev, self._inflight = self._inflight, record
-            if prev is not None:
-                out.extend(self._harvest(prev))
+        with annotation("tick.device_wait"):
+            if self.pipeline_depth <= 1:
+                if record is not None:
+                    out.extend(self._harvest(record))
+            else:
+                prev, self._inflight = self._inflight, record
+                if prev is not None:
+                    out.extend(self._harvest(prev))
         t_harvest = time.perf_counter()
         # the harvest span is dominated by the blocking packed-token fetch;
         # with pipeline_depth=2 this wait belongs to the PREVIOUS tick's
@@ -1573,6 +1602,8 @@ class ContinuousBatchingEngine:
             slot.prompt_ids = list(tok_ids) if self._radix is not None else None
             slot.donated = []
             slot.submit_t = req.submit_t
+            slot.admit_t = time.perf_counter()
+            slot.prefill_segments = 0 if chunked else 1
             slot.prefill_todo = list(tok_ids[shared:]) if chunked else None
             slot.prefill_done = 0
             slot.active = True
@@ -1784,6 +1815,7 @@ class ContinuousBatchingEngine:
                         n_prior, top_ks, do_sample=is_last,
                     )
             self.prefill_tokens_total += len(seg)
+            slot.prefill_segments += 1
             if is_last:
                 slot.prefill_todo = None
                 slot.pending_first = True
@@ -1966,6 +1998,10 @@ class ContinuousBatchingEngine:
                 slot.inflight_steps += int(budgets[i])
         return {"packed": packed, "budgets": budgets, "spec": spec,
                 "lp_state": lp_state,
+                # for the row-step count at harvest: the scan's length and
+                # the rows that held a request when it was dispatched
+                "steps": int(steps),
+                "live": sum(s.active for s in self.slots),
                 "pending_slots": set(pending_slots),
                 # request ids pin each lane: a slot retired at harvest time
                 # and re-admitted before THIS record is harvested must not
@@ -1989,6 +2025,7 @@ class ContinuousBatchingEngine:
         lp_state = record.get("lp_state")
         lp_rows = np.asarray(lp_state) if lp_state is not None else None
         finished: list[PagedResult] = []
+        useful = 0
         for i, slot in enumerate(self.slots):
             if not slot.active or slot.request_id != record["rids"][i]:
                 continue  # lane retired+reused since dispatch: stale tokens
@@ -2027,11 +2064,25 @@ class ContinuousBatchingEngine:
                 slot.length += 1
                 self._lens[i] = slot.length
                 self._last_tok[i] = int(toks[s])
+                useful += 1
                 result = self._fold_and_maybe_retire(i)
                 if result is not None:
                     finished.append(result)
                     break
+        self._count_row_steps(record, useful)
         return finished
+
+    def _count_row_steps(self, record: dict, useful: int) -> None:
+        """Book one dispatched tick's ``max_slots x steps`` row-steps:
+        ``useful`` of them folded a token into an answer, the rest of the
+        rows that held a request were halted, the other rows empty."""
+        steps, live = record["steps"], record["live"]
+        counts = {"useful": useful, "halted": steps * live - useful,
+                  "empty": steps * (self.max_slots - live)}
+        for kind, n in counts.items():
+            self.row_steps_total[kind] += n
+            self.last_tick_row_steps[kind] += n
+        self.last_tick_sub_steps += steps
 
     def _fold_and_maybe_retire(self, i: int) -> Optional[PagedResult]:
         """Fold ``_last_tok[i]`` (sampled, not yet forwarded) into slot ``i``;
@@ -2074,6 +2125,8 @@ class ContinuousBatchingEngine:
             logprob_sum=float(self._lp_sum[i]),
             logprob_min=float(self._lp_min[i]),
             logprob_count=int(self._lp_cnt[i]),
+            admit_t=slot.admit_t,
+            prefill_segments=slot.prefill_segments,
         )
         if slot.donated:
             donated = set(slot.donated)

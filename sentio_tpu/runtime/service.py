@@ -61,6 +61,7 @@ from sentio_tpu.infra.exceptions import (
     ReplicaUnavailable,
     ServiceOverloaded,
 )
+from sentio_tpu.infra import tracing
 from sentio_tpu.infra.flight import get_flight_recorder
 from sentio_tpu.infra.metrics import get_metrics
 from sentio_tpu.infra.phases import TICK_PHASES, duty_fractions, phases_to_ms
@@ -187,6 +188,14 @@ class _Ticket:
     # this ticket is mirrored under, so a worker-side extract_inbox can
     # name its never-dispatched tickets back to the router's shadow queue
     shadow_id: Optional[int] = None
+    # request stages (infra/phases.py): the span this admission hangs under
+    # ("verify" for the audit's, whose stages are recorded but not observed
+    # a second time), when the pump handed the ticket to engine.submit, and
+    # the flight ticks of its admission and of its first token
+    parent: Optional[str] = None
+    t_engine: float = 0.0
+    tick_admit: int = 0
+    tick_first: int = 0
 
     @property
     def path(self) -> str:
@@ -334,7 +343,8 @@ class PagedGenerationService:
                          retries_left=self.retry_budget,
                          tenant=tenant, priority=priority,
                          cost_tokens=int(cost_tokens),
-                         seed=seed, shadow_id=shadow_id)
+                         seed=seed, shadow_id=shadow_id,
+                         parent=tracing.parent_for(request_id))
         if request_id:
             get_flight_recorder().note_engine_submit(
                 request_id, replica_id=self.replica_id)
@@ -457,7 +467,8 @@ class PagedGenerationService:
                          cost_tokens=int(cost_tokens),
                          prior_tokens=(list(prior_tokens)
                                        if prior_tokens else None),
-                         seed=seed, shadow_id=shadow_id)
+                         seed=seed, shadow_id=shadow_id,
+                         parent=tracing.parent_for(request_id))
         if request_id:
             get_flight_recorder().note_engine_submit(
                 request_id, replica_id=self.replica_id)
@@ -1071,12 +1082,6 @@ class PagedGenerationService:
         self.engine.pressure_hint = lambda: len(self._inbox)  # lint: allow(lock-discipline)
         recorder = get_flight_recorder()
         metrics = get_metrics()
-        # tracing manager resolved ONCE per pump: when tracing is off
-        # (default) the per-tick cost is a single bool test — no span
-        # objects, no context managers on the hot path
-        from sentio_tpu.infra.tracing import get_tracing
-
-        tracing = get_tracing()
         # baselines for diffing the engine's lifetime counters into per-tick
         # attributions (pump-local: a restarted pump re-baselines, so the
         # first tick of a new burst never inherits the previous burst's work)
@@ -1102,375 +1107,401 @@ class PagedGenerationService:
         last_hit_toks = self.engine.prefix_hit_tokens_total
         last_miss_toks = self.engine.prefix_miss_tokens_total
         while True:
-            t_iter = now = time.perf_counter()
-            with self._mutex:
-                # heartbeat: the watchdog's liveness signal. Stamped at the
-                # top of EVERY loop iteration, so a tick wedged inside the
-                # device dispatch below leaves the stamp aging while the
-                # backlog grows — exactly the stall signature
-                self._heartbeat_ts = now
-                for ticket in self._inbox:
-                    if ticket.cancelled:
-                        # abandoned before admission
-                        self._close_cancelled_locked(ticket)
-                        continue
-                    if (ticket.deadline_ts is not None
-                            and now >= ticket.deadline_ts):
-                        # expired before admission: never pay prefill for a
-                        # caller that already gave up
-                        self._expired += 1
-                        metrics.record_shed("expired")
-                        self._finish_error_locked(
-                            ticket,
-                            DeadlineExceededError(
-                                "deadline expired before admission"),
-                            "expired",
-                        )
-                        continue
-                    rid = self.engine.submit(
-                        ticket.prompt,
-                        max_new_tokens=ticket.max_new_tokens,
-                        temperature=ticket.temperature,
-                        deadline_ts=ticket.deadline_ts,
-                        top_k=ticket.top_k,
-                        prior_tokens=ticket.prior_tokens,
-                        seed=ticket.seed,
-                    )
-                    self._tickets[rid] = ticket
-                self._inbox.clear()
-                # abandoned or expired callers: stop decoding for nobody,
-                # free the slot for live traffic
-                for rid, ticket in list(self._tickets.items()):
-                    if ticket.cancelled:
-                        self.engine.cancel(rid)
-                        self._tickets.pop(rid, None)
-                        self._close_cancelled_locked(ticket)
-                    elif (ticket.deadline_ts is not None
-                          and now >= ticket.deadline_ts):
-                        self.engine.cancel(rid)
-                        self._tickets.pop(rid, None)
-                        self._expired += 1
-                        metrics.record_shed("expired")
-                        self._finish_error_locked(
-                            ticket,
-                            DeadlineExceededError(
-                                "deadline expired mid-decode; request "
-                                "cancelled"),
-                            "expired",
-                        )
-                if self._closed or not self.engine.has_work:
-                    # flag flips inside the mutex: a racing submit either
-                    # lands in the inbox before this check (we continue) or
-                    # sees _pump_running=False and starts a fresh pump
-                    self._pump_running = False
-                    if self._closed:
-                        self._fail_all_locked("service closed")
-                    return
-            # device work runs WITHOUT any lock: the pump is the engine's
-            # only driver, and submitters must never wait on a decode tick
-            t_drain = time.perf_counter()
-            try:
-                if tracing.enabled:
-                    # StepTraceAnnotation around the tick: an armed XLA
-                    # profiler window (/debug/profile) lines its device
-                    # traces up with flight ticks by step number
-                    with tracing.profile_step(
-                        "decode_tick",
-                        step=self._ticks + 1,  # lint: allow(lock-discipline) — GIL-atomic read
-                    ):
-                        finished = self.engine.step()
-                else:
-                    finished = self.engine.step()
-                tick_dur_s = time.perf_counter() - t_drain
-            except Exception:
-                t_fail = time.perf_counter()
-                logger.exception(
-                    "paged decode tick failed; attempting crash containment")
-                # flush the FAILED iteration's partial phase snapshot
-                # (residual folded into "other"): the success path's
-                # record/amend never runs on this branch, and without the
-                # flush a chaos round's Perfetto trace holes every failed
-                # tick and the duty-cycle gauge under-counts host time.
-                # sum(phase_ms) == pump_ms holds here too, by construction.
-                try:
-                    # full bounded key shape (zeros included): the tier-1
-                    # conservation gate pins phase_ms records to exactly
-                    # TICK_PHASES, failed ticks included
-                    phase_s = dict.fromkeys(TICK_PHASES, 0.0)
-                    partial = getattr(
-                        self.engine, "partial_step_phases", dict)() or {}
-                    for key, val in partial.items():
-                        if key in phase_s:
-                            phase_s[key] = val
-                    phase_s["inbox_drain"] = t_drain - t_iter
-                    pump_s = t_fail - t_iter
-                    phase_s["other"] = phase_s.get("other", 0.0) + max(
-                        pump_s - sum(phase_s.values()), 0.0
-                    )
-                    recorder.record_tick(
-                        event="tick_failure", replica=self.replica_id,
-                        dur_ms=round((t_fail - t_drain) * 1e3, 3),
-                        pump_ms=round(pump_s * 1e3, 3),
-                        phase_ms=phases_to_ms(phase_s),
-                    )
-                    metrics.record_tick_phases(phase_s)
-                    for key, val in phase_s.items():
-                        self._phase_totals[key] = (
-                            self._phase_totals.get(key, 0.0) + val
-                        )
-                except Exception:  # noqa: BLE001 — telemetry best-effort
-                    logger.debug("failed-tick phase telemetry failed",
-                                 exc_info=True)
-                # the failed dispatch may have consumed the donated pool
-                # buffers and left slots half-admitted — rebuild the decode
-                # state so the NEXT request gets a working engine instead of
-                # a permanently poisoned one. Reset runs BEFORE waiters are
-                # touched and before _pump_running flips: this pump still
-                # exclusively owns the engine, so a retrying caller cannot
-                # start a new pump that races the reset.
-                reset_ok = True
-                try:
-                    self.engine.reset()
-                except Exception:
-                    logger.exception("paged engine reset failed; paged path disabled")
-                    reset_ok = False
-                casualties: list[_Ticket] = []
-                with self._mutex:
-                    self._tick_failures += 1
-                    if not reset_ok:
-                        self._pump_running = False
-                        self._broken = True
-                        self._fail_all_locked(
-                            "decode tick failed; engine reset failed")
-                        return
-                    # crash containment: the reset brought the engine back —
-                    # requeue innocent waiters instead of failing every one
-                    # of them. ADMITTED tickets were part of the failed tick
-                    # and burn one retry; inbox tickets never dispatched, so
-                    # they requeue for free (charging them would let a
-                    # request exhaust its budget with zero execution
-                    # attempts). Only exhausted-budget tickets — or streams
-                    # that already delivered tokens, which cannot restart
-                    # without duplicating output — get the error result.
-                    survivors: list[_Ticket] = []
-                    requeued = 0
-                    for ticket in self._tickets.values():
-                        if ticket.event.is_set():
-                            continue
-                        if ticket.cancelled:
-                            # abandoned caller swept up in the crash
-                            self._close_cancelled_locked(ticket)
-                            continue
-                        resumable = (
-                            ticket.stream_q is None or ticket.sent_tokens == 0
-                        )
-                        if resumable and ticket.retries_left > 0:
-                            ticket.retries_left -= 1
-                            requeued += 1
-                            survivors.append(ticket)
-                        else:
-                            casualties.append(ticket)
+            # the whole iteration runs under the profiler's step marker:
+            # inside an armed /debug/profile window the device trace groups
+            # what ran by the flight tick number it carries
+            step_num = recorder.next_tick()
+            with tracing.tick_annotation(step_num):
+                t_iter = now = time.perf_counter()
+                with tracing.annotation("tick.inbox_drain"), self._mutex:
+                    # heartbeat: the watchdog's liveness signal. Stamped at the
+                    # top of EVERY loop iteration, so a tick wedged inside the
+                    # device dispatch below leaves the stamp aging while the
+                    # backlog grows — exactly the stall signature
+                    self._heartbeat_ts = now
                     for ticket in self._inbox:
-                        if ticket.event.is_set():
-                            continue
                         if ticket.cancelled:
+                            # abandoned before admission
                             self._close_cancelled_locked(ticket)
                             continue
-                        survivors.append(ticket)  # free: never dispatched
-                    self._tickets.clear()
+                        if (ticket.deadline_ts is not None
+                                and now >= ticket.deadline_ts):
+                            # expired before admission: never pay prefill for a
+                            # caller that already gave up
+                            self._expired += 1
+                            metrics.record_shed("expired")
+                            self._finish_error_locked(
+                                ticket,
+                                DeadlineExceededError(
+                                    "deadline expired before admission"),
+                                "expired",
+                            )
+                            continue
+                        ticket.t_engine = time.perf_counter()
+                        rid = self.engine.submit(
+                            ticket.prompt,
+                            max_new_tokens=ticket.max_new_tokens,
+                            temperature=ticket.temperature,
+                            deadline_ts=ticket.deadline_ts,
+                            top_k=ticket.top_k,
+                            prior_tokens=ticket.prior_tokens,
+                            seed=ticket.seed,
+                        )
+                        self._tickets[rid] = ticket
                     self._inbox.clear()
-                    self._inbox.extend(survivors)
-                    self._requeued += requeued
-                    for ticket in casualties:
-                        self._fail_ticket_locked(ticket, "decode tick failed")
-                    if casualties:
-                        # counted BEFORE the early returns below, or pump
-                        # exits (no survivors / closed) would drop exactly
-                        # the sheds where waiters actually failed
-                        metrics.record_shed("crash", len(casualties))
-                    if self._closed:
+                    # abandoned or expired callers: stop decoding for nobody,
+                    # free the slot for live traffic
+                    for rid, ticket in list(self._tickets.items()):
+                        if ticket.cancelled:
+                            self.engine.cancel(rid)
+                            self._tickets.pop(rid, None)
+                            self._close_cancelled_locked(ticket)
+                        elif (ticket.deadline_ts is not None
+                              and now >= ticket.deadline_ts):
+                            self.engine.cancel(rid)
+                            self._tickets.pop(rid, None)
+                            self._expired += 1
+                            metrics.record_shed("expired")
+                            self._finish_error_locked(
+                                ticket,
+                                DeadlineExceededError(
+                                    "deadline expired mid-decode; request "
+                                    "cancelled"),
+                                "expired",
+                            )
+                    if self._closed or not self.engine.has_work:
+                        # flag flips inside the mutex: a racing submit either
+                        # lands in the inbox before this check (we continue) or
+                        # sees _pump_running=False and starts a fresh pump
                         self._pump_running = False
-                        self._fail_all_locked("service closed")
+                        if self._closed:
+                            self._fail_all_locked("service closed")
                         return
-                    if not self._inbox:
-                        self._pump_running = False
-                        return
-                # requeued tickets resubmit at the top of the loop; THIS
-                # pump keeps engine ownership across the reset (no handoff)
-                continue
-            # in-tick occupancy from the engine: rows that shared the fused
-            # decode dispatch (post-tick slot counts would miss requests that
-            # retired inside the tick)
-            active = getattr(self.engine, "last_tick_active", None)
-            if active is None:
-                active = sum(s.active for s in self.engine.slots)
-            t_step_end = time.perf_counter()
-            # flight-recorder tick event BEFORE delivery: finish_engine in
-            # the deliver section stamps tick_last from the recorder's
-            # sequence, and the request-window filter (first < tick <=
-            # last) must include the tick a request FINISHED in — recording
-            # after delivery would silently drop every request's final tick
-            # from /debug/flight. The completed phase decomposition cannot
-            # exist yet (delivery hasn't happened); it is AMENDED onto this
-            # event below. Telemetry is strictly best-effort — an exception
-            # here must never kill the pump (waiters would hang).
-            tick_seq = None
-            try:
-                engine = self.engine
-                queued = len(engine._queue)
-                inbox = len(self._inbox)  # lint: allow(lock-discipline) — GIL-atomic depth hint
-                free = engine.allocator.free_pages
-                radix = getattr(engine, "_radix", None)
-                # XLA compiles this tick triggered (jit-family cache growth,
-                # analysis/audit/fence.py) — steady-state serving should
-                # record 0 here; the event list names the offending family
-                # and abstract signature when it does not
-                compiles_now = paged_compiles()
-                compile_fields: dict = {
-                    "xla_compiles": compiles_now - last_compiles,
-                }
-                if compiles_now != last_compiles:
-                    # the event ring is process-global and drained
-                    # destructively — with several engines alive the
-                    # family filter keeps foreign events off this tick,
-                    # but a second paged pump may consume events first
-                    # (counts above stay exact either way)
-                    compile_fields["compile_events"] = [
-                        e for e in fence.drain_events()
-                        if e["family"].startswith(("paged.", "paged_spec."))
-                    ]
-                last_compiles = compiles_now
-                tick_seq = recorder.record_tick(
-                    **compile_fields,
-                    replica=self.replica_id,
-                    dur_ms=round(tick_dur_s * 1e3, 3),
-                    active_slots=int(active),
-                    queue_depth=queued,
-                    inbox_depth=inbox,
-                    prefill_tokens=engine.prefill_tokens_total - last_prefill,
-                    decode_tokens=engine.decode_tokens_total - last_decode,
-                    spec_accepted=engine.spec_emitted_total - last_spec,
-                    # prompt tokens this tick served read-only from the radix
-                    # prefix cache vs actually forwarded, plus the cache's
-                    # page occupancy — the per-tick evidence of prefill
-                    # skipped (replaces the old boolean hit/miss counts)
-                    prefix_hit_tokens=(
-                        engine.prefix_hit_tokens_total - last_hit_toks),
-                    prefix_miss_tokens=(
-                        engine.prefix_miss_tokens_total - last_miss_toks),
-                    prefix_cache_pages=(radix.pages_held if radix else 0),
-                    free_pages=free,
-                    used_pages=engine.allocator.num_pages - 1 - free,
-                    # overload counters (lifetime totals — diffs between
-                    # consecutive ticks attribute sheds to a tick window)
-                    shed_total=self._shed,  # lint: allow(lock-discipline) — GIL-atomic total
-                    expired_total=self._expired,  # lint: allow(lock-discipline) — GIL-atomic total
-                    cancelled_total=self._cancelled,  # lint: allow(lock-discipline) — GIL-atomic total
-                )
-                last_prefill = engine.prefill_tokens_total
-                last_decode = engine.decode_tokens_total
-                last_spec = engine.spec_emitted_total
-                last_hit_toks = engine.prefix_hit_tokens_total
-                last_miss_toks = engine.prefix_miss_tokens_total
-                metrics.record_tick(tick_dur_s, int(active), queued + inbox)
-            except Exception:  # noqa: BLE001
-                logger.debug("tick telemetry failed", exc_info=True)
-            t_deliver_start = time.perf_counter()
-            now = t_deliver_start
-            with self._mutex:
-                self._heartbeat_ts = now  # tick survived: fresh liveness
-                self._ticks += 1
-                self._active_sum += active
-                self._max_active = max(self._max_active, active)
-                # push newly emitted tokens to streaming tickets still in
-                # flight (the engine's slot.emitted grows by up to
-                # steps_per_tick per tick)
-                for slot in self.engine.slots:
-                    if not slot.active:
-                        continue
-                    ticket = self._tickets.get(slot.request_id)
-                    if ticket is None:
-                        continue
-                    # TTFT: first tick where this sequence's sampled tokens
-                    # became host-visible (finish-inside-first-tick requests
-                    # are stamped at completion below instead)
-                    if slot.emitted and ticket.t_first == 0.0:
-                        ticket.t_first = now
-                        ticket.tokens_first = len(slot.emitted)
-                        metrics.record_ttft(now - ticket.t_submit,
-                                            path=ticket.path)
-                        self._note_ttft_locked(now - ticket.t_submit)
-                    if ticket.stream_q is None:
-                        continue
-                    if len(slot.emitted) > ticket.sent_tokens:
-                        ticket.stream_q.put(
-                            ("toks", list(slot.emitted[ticket.sent_tokens:]))
+                # device work runs WITHOUT any lock: the pump is the engine's
+                # only driver, and submitters must never wait on a decode tick
+                t_drain = time.perf_counter()
+                try:
+                    finished = self.engine.step()
+                    tick_dur_s = time.perf_counter() - t_drain
+                except Exception:
+                    t_fail = time.perf_counter()
+                    logger.exception(
+                        "paged decode tick failed; attempting crash containment")
+                    # flush the FAILED iteration's partial phase snapshot
+                    # (residual folded into "other"): the success path's
+                    # record/amend never runs on this branch, and without the
+                    # flush a chaos round's Perfetto trace holes every failed
+                    # tick and the duty-cycle gauge under-counts host time.
+                    # sum(phase_ms) == pump_ms holds here too, by construction.
+                    try:
+                        # full bounded key shape (zeros included): the tier-1
+                        # conservation gate pins phase_ms records to exactly
+                        # TICK_PHASES, failed ticks included
+                        phase_s = dict.fromkeys(TICK_PHASES, 0.0)
+                        partial = getattr(
+                            self.engine, "partial_step_phases", dict)() or {}
+                        for key, val in partial.items():
+                            if key in phase_s:
+                                phase_s[key] = val
+                        phase_s["inbox_drain"] = t_drain - t_iter
+                        pump_s = t_fail - t_iter
+                        phase_s["other"] = phase_s.get("other", 0.0) + max(
+                            pump_s - sum(phase_s.values()), 0.0
                         )
-                        ticket.sent_tokens = len(slot.emitted)
-                for result in finished:
-                    # which replica produced this result, for stats sinks
-                    # and tracing spans downstream (PagedResult defaults -1)
-                    result.replica_id = self.replica_id
-                    ticket = self._tickets.pop(result.request_id, None)
-                    if ticket is None:
-                        continue
-                    if result.finish_reason == "expired":
-                        # the ENGINE dropped it (deadline passed while in
-                        # its queue) — same typed error as a pump-side drop
-                        self._expired += 1
-                        metrics.record_shed("expired")
-                        self._finish_error_locked(
-                            ticket,
-                            DeadlineExceededError(
-                                "deadline expired while queued for a slot"),
-                            "expired",
+                        row_steps = self._row_steps()
+                        recorder.record_tick(
+                            event="tick_failure", replica=self.replica_id,
+                            step=step_num,
+                            dur_ms=round((t_fail - t_drain) * 1e3, 3),
+                            pump_ms=round(pump_s * 1e3, 3),
+                            phase_ms=phases_to_ms(phase_s),
+                            **row_steps,
                         )
-                        continue
-                    self._completed += 1
-                    if ticket.t_first == 0.0:
-                        # finished inside its first tick: _note_finished will
-                        # stamp TTFT=now − submit; fold the same sample into
-                        # the admission-control EMA here (mutex held)
-                        self._note_ttft_locked(now - ticket.t_submit)
-                    self._note_finished(ticket, result, now, metrics, recorder)
-                    ticket.result = result
-                    if ticket.stream_q is not None:
-                        ticket.stream_q.put(("done", result))
-                    ticket.event.set()
-            t_deliver_end = time.perf_counter()
-            # tick-phase decomposition (infra/phases.py): the engine's own
-            # section timings plus this pump's inbox_drain/deliver spans.
-            # Residual (the telemetry block above, mutex waits, call
-            # overhead) folds into "other", so sum(phase_ms) == pump_ms
-            # holds by CONSTRUCTION — the tier-1 conservation test pins it,
-            # and Perfetto slices built from phase_ms nest exactly inside
-            # their tick. The dict is AMENDED onto the already-recorded
-            # tick event (amend_tick restamps t_s to this span's end, the
-            # convention the Chrome exporter subtracts pump_ms from).
-            phase_s = dict(self.engine.last_step_phases)
-            phase_s["inbox_drain"] = t_drain - t_iter
-            phase_s["deliver"] = t_deliver_end - t_deliver_start
-            pump_s = t_deliver_end - t_iter
-            phase_s["other"] = phase_s.get("other", 0.0) + max(
-                pump_s - sum(phase_s.values()), 0.0
-            )
-            try:
-                if tick_seq is not None:
-                    recorder.amend_tick(
-                        tick_seq,
-                        pump_ms=round(pump_s * 1e3, 3),
-                        phase_ms=phases_to_ms(phase_s),
+                        metrics.record_tick_phases(phase_s)
+                        metrics.record_row_steps(row_steps["row_steps"])
+                        for key, val in phase_s.items():
+                            self._phase_totals[key] = (
+                                self._phase_totals.get(key, 0.0) + val
+                            )
+                    except Exception:  # noqa: BLE001 — telemetry best-effort
+                        logger.debug("failed-tick phase telemetry failed",
+                                     exc_info=True)
+                    # the failed dispatch may have consumed the donated pool
+                    # buffers and left slots half-admitted — rebuild the decode
+                    # state so the NEXT request gets a working engine instead of
+                    # a permanently poisoned one. Reset runs BEFORE waiters are
+                    # touched and before _pump_running flips: this pump still
+                    # exclusively owns the engine, so a retrying caller cannot
+                    # start a new pump that races the reset.
+                    reset_ok = True
+                    try:
+                        self.engine.reset()
+                    except Exception:
+                        logger.exception("paged engine reset failed; paged path disabled")
+                        reset_ok = False
+                    casualties: list[_Ticket] = []
+                    with self._mutex:
+                        self._tick_failures += 1
+                        if not reset_ok:
+                            self._pump_running = False
+                            self._broken = True
+                            self._fail_all_locked(
+                                "decode tick failed; engine reset failed")
+                            return
+                        # crash containment: the reset brought the engine back —
+                        # requeue innocent waiters instead of failing every one
+                        # of them. ADMITTED tickets were part of the failed tick
+                        # and burn one retry; inbox tickets never dispatched, so
+                        # they requeue for free (charging them would let a
+                        # request exhaust its budget with zero execution
+                        # attempts). Only exhausted-budget tickets — or streams
+                        # that already delivered tokens, which cannot restart
+                        # without duplicating output — get the error result.
+                        survivors: list[_Ticket] = []
+                        requeued = 0
+                        for ticket in self._tickets.values():
+                            if ticket.event.is_set():
+                                continue
+                            if ticket.cancelled:
+                                # abandoned caller swept up in the crash
+                                self._close_cancelled_locked(ticket)
+                                continue
+                            resumable = (
+                                ticket.stream_q is None or ticket.sent_tokens == 0
+                            )
+                            if resumable and ticket.retries_left > 0:
+                                ticket.retries_left -= 1
+                                requeued += 1
+                                survivors.append(ticket)
+                            else:
+                                casualties.append(ticket)
+                        for ticket in self._inbox:
+                            if ticket.event.is_set():
+                                continue
+                            if ticket.cancelled:
+                                self._close_cancelled_locked(ticket)
+                                continue
+                            survivors.append(ticket)  # free: never dispatched
+                        self._tickets.clear()
+                        self._inbox.clear()
+                        self._inbox.extend(survivors)
+                        self._requeued += requeued
+                        for ticket in casualties:
+                            self._fail_ticket_locked(ticket, "decode tick failed")
+                        if casualties:
+                            # counted BEFORE the early returns below, or pump
+                            # exits (no survivors / closed) would drop exactly
+                            # the sheds where waiters actually failed
+                            metrics.record_shed("crash", len(casualties))
+                        if self._closed:
+                            self._pump_running = False
+                            self._fail_all_locked("service closed")
+                            return
+                        if not self._inbox:
+                            self._pump_running = False
+                            return
+                    # requeued tickets resubmit at the top of the loop; THIS
+                    # pump keeps engine ownership across the reset (no handoff)
+                    continue
+                # in-tick occupancy from the engine: rows that shared the fused
+                # decode dispatch (post-tick slot counts would miss requests that
+                # retired inside the tick)
+                active = getattr(self.engine, "last_tick_active", None)
+                if active is None:
+                    active = sum(s.active for s in self.engine.slots)
+                t_step_end = time.perf_counter()
+                # flight-recorder tick event BEFORE delivery: finish_engine in
+                # the deliver section stamps tick_last from the recorder's
+                # sequence, and the request-window filter (first < tick <=
+                # last) must include the tick a request FINISHED in — recording
+                # after delivery would silently drop every request's final tick
+                # from /debug/flight. The completed phase decomposition cannot
+                # exist yet (delivery hasn't happened); it is AMENDED onto this
+                # event below. Telemetry is strictly best-effort — an exception
+                # here must never kill the pump (waiters would hang).
+                tick_seq = None
+                try:
+                    engine = self.engine
+                    queued = len(engine._queue)
+                    inbox = len(self._inbox)  # lint: allow(lock-discipline) — GIL-atomic depth hint
+                    free = engine.allocator.free_pages
+                    radix = getattr(engine, "_radix", None)
+                    # XLA compiles this tick triggered (jit-family cache growth,
+                    # analysis/audit/fence.py) — steady-state serving should
+                    # record 0 here; the event list names the offending family
+                    # and abstract signature when it does not
+                    compiles_now = paged_compiles()
+                    compile_fields: dict = {
+                        "xla_compiles": compiles_now - last_compiles,
+                    }
+                    if compiles_now != last_compiles:
+                        # the event ring is process-global and drained
+                        # destructively — with several engines alive the
+                        # family filter keeps foreign events off this tick,
+                        # but a second paged pump may consume events first
+                        # (counts above stay exact either way)
+                        compile_fields["compile_events"] = [
+                            e for e in fence.drain_events()
+                            if e["family"].startswith(("paged.", "paged_spec."))
+                        ]
+                    last_compiles = compiles_now
+                    row_steps = self._row_steps()
+                    tick_seq = recorder.record_tick(
+                        **compile_fields,
+                        **row_steps,
+                        replica=self.replica_id,
+                        # the number this iteration's decode_tick annotation
+                        # carries in a profiler window (== tick unless another
+                        # pump shares the recorder)
+                        step=step_num,
+                        dur_ms=round(tick_dur_s * 1e3, 3),
+                        active_slots=int(active),
+                        queue_depth=queued,
+                        inbox_depth=inbox,
+                        prefill_tokens=engine.prefill_tokens_total - last_prefill,
+                        decode_tokens=engine.decode_tokens_total - last_decode,
+                        spec_accepted=engine.spec_emitted_total - last_spec,
+                        # prompt tokens this tick served read-only from the radix
+                        # prefix cache vs actually forwarded, plus the cache's
+                        # page occupancy — the per-tick evidence of prefill
+                        # skipped (replaces the old boolean hit/miss counts)
+                        prefix_hit_tokens=(
+                            engine.prefix_hit_tokens_total - last_hit_toks),
+                        prefix_miss_tokens=(
+                            engine.prefix_miss_tokens_total - last_miss_toks),
+                        prefix_cache_pages=(radix.pages_held if radix else 0),
+                        free_pages=free,
+                        used_pages=engine.allocator.num_pages - 1 - free,
+                        # overload counters (lifetime totals — diffs between
+                        # consecutive ticks attribute sheds to a tick window)
+                        shed_total=self._shed,  # lint: allow(lock-discipline) — GIL-atomic total
+                        expired_total=self._expired,  # lint: allow(lock-discipline) — GIL-atomic total
+                        cancelled_total=self._cancelled,  # lint: allow(lock-discipline) — GIL-atomic total
                     )
-                metrics.record_tick_phases(phase_s)
-            except Exception:  # noqa: BLE001
-                logger.debug("phase telemetry failed", exc_info=True)
-            # the amend/metrics cost itself rides the duty-cycle totals as
-            # "other" (it cannot ride the record it just amended). Totals
-            # are pump-thread-owned floats; readers snapshot them
-            # GIL-atomically (see duty_cycle()).
-            phase_s["other"] += time.perf_counter() - t_deliver_end
-            for key, val in phase_s.items():
-                self._phase_totals[key] = self._phase_totals.get(key, 0.0) + val
+                    last_prefill = engine.prefill_tokens_total
+                    last_decode = engine.decode_tokens_total
+                    last_spec = engine.spec_emitted_total
+                    last_hit_toks = engine.prefix_hit_tokens_total
+                    last_miss_toks = engine.prefix_miss_tokens_total
+                    metrics.record_tick(tick_dur_s, int(active), queued + inbox)
+                    metrics.record_row_steps(row_steps["row_steps"])
+                except Exception:  # noqa: BLE001
+                    logger.debug("tick telemetry failed", exc_info=True)
+                t_deliver_start = time.perf_counter()
+                now = t_deliver_start
+                with tracing.annotation("tick.deliver"), self._mutex:
+                    self._heartbeat_ts = now  # tick survived: fresh liveness
+                    self._ticks += 1
+                    self._active_sum += active
+                    self._max_active = max(self._max_active, active)
+                    # push newly emitted tokens to streaming tickets still in
+                    # flight (the engine's slot.emitted grows by up to
+                    # steps_per_tick per tick)
+                    for slot in self.engine.slots:
+                        if not slot.active:
+                            continue
+                        ticket = self._tickets.get(slot.request_id)
+                        if ticket is None:
+                            continue
+                        if ticket.tick_admit == 0:
+                            ticket.tick_admit = tick_seq or 0
+                        # TTFT: first tick where this sequence's sampled tokens
+                        # became host-visible (finish-inside-first-tick requests
+                        # are stamped at completion below instead)
+                        if slot.emitted and ticket.t_first == 0.0:
+                            ticket.t_first = now
+                            ticket.tokens_first = len(slot.emitted)
+                            ticket.tick_first = tick_seq or 0
+                            metrics.record_ttft(now - ticket.t_submit,
+                                                path=ticket.path)
+                            self._note_ttft_locked(now - ticket.t_submit)
+                            self._note_first_token(
+                                ticket, slot.admit_t, slot.prefill_segments,
+                                slot.prompt_tokens, slot.shared_tokens)
+                        if ticket.stream_q is None:
+                            continue
+                        if len(slot.emitted) > ticket.sent_tokens:
+                            if ticket.request_id:
+                                recorder.note_stream_put(ticket.request_id, now)
+                            ticket.stream_q.put(
+                                ("toks", list(slot.emitted[ticket.sent_tokens:]))
+                            )
+                            ticket.sent_tokens = len(slot.emitted)
+                    for result in finished:
+                        # which replica produced this result, for stats sinks
+                        # and tracing spans downstream (PagedResult defaults -1)
+                        result.replica_id = self.replica_id
+                        ticket = self._tickets.pop(result.request_id, None)
+                        if ticket is None:
+                            continue
+                        if result.finish_reason == "expired":
+                            # the ENGINE dropped it (deadline passed while in
+                            # its queue) — same typed error as a pump-side drop
+                            self._expired += 1
+                            metrics.record_shed("expired")
+                            self._finish_error_locked(
+                                ticket,
+                                DeadlineExceededError(
+                                    "deadline expired while queued for a slot"),
+                                "expired",
+                            )
+                            continue
+                        self._completed += 1
+                        if ticket.t_first == 0.0:
+                            # finished inside its first tick: _note_finished will
+                            # stamp TTFT=now − submit; fold the same sample into
+                            # the admission-control EMA here (mutex held)
+                            self._note_ttft_locked(now - ticket.t_submit)
+                        if ticket.tick_admit == 0:
+                            ticket.tick_admit = tick_seq or 0
+                        self._note_finished(ticket, result, now, metrics, recorder,
+                                            tick_seq or 0)
+                        ticket.result = result
+                        if ticket.stream_q is not None:
+                            if ticket.request_id:
+                                recorder.note_stream_put(ticket.request_id, now)
+                            ticket.stream_q.put(("done", result))
+                        ticket.event.set()
+                t_deliver_end = time.perf_counter()
+                # tick-phase decomposition (infra/phases.py): the engine's own
+                # section timings plus this pump's inbox_drain/deliver spans.
+                # Residual (the telemetry block above, mutex waits, call
+                # overhead) folds into "other", so sum(phase_ms) == pump_ms
+                # holds by CONSTRUCTION — the tier-1 conservation test pins it,
+                # and Perfetto slices built from phase_ms nest exactly inside
+                # their tick. The dict is AMENDED onto the already-recorded
+                # tick event (amend_tick restamps t_s to this span's end, the
+                # convention the Chrome exporter subtracts pump_ms from).
+                phase_s = dict(self.engine.last_step_phases)
+                phase_s["inbox_drain"] = t_drain - t_iter
+                phase_s["deliver"] = t_deliver_end - t_deliver_start
+                pump_s = t_deliver_end - t_iter
+                phase_s["other"] = phase_s.get("other", 0.0) + max(
+                    pump_s - sum(phase_s.values()), 0.0
+                )
+                try:
+                    if tick_seq is not None:
+                        recorder.amend_tick(
+                            tick_seq,
+                            pump_ms=round(pump_s * 1e3, 3),
+                            phase_ms=phases_to_ms(phase_s),
+                        )
+                    metrics.record_tick_phases(phase_s)
+                except Exception:  # noqa: BLE001
+                    logger.debug("phase telemetry failed", exc_info=True)
+                # the amend/metrics cost itself rides the duty-cycle totals as
+                # "other" (it cannot ride the record it just amended). Totals
+                # are pump-thread-owned floats; readers snapshot them
+                # GIL-atomically (see duty_cycle()).
+                phase_s["other"] += time.perf_counter() - t_deliver_end
+                for key, val in phase_s.items():
+                    self._phase_totals[key] = self._phase_totals.get(key, 0.0) + val
+
+    def _row_steps(self) -> dict:
+        """The tick ring's row-step fields for the tick(s) the latest
+        ``engine.step()`` harvested (runtime/paged.py counts them)."""
+        return {"sub_steps": self.engine.last_tick_sub_steps,
+                "row_steps": dict(self.engine.last_tick_row_steps)}
 
     def _note_ttft_locked(self, ttft_s: float) -> None:  # lock-held: _mutex
         """Fold one observed TTFT into the EMA admission control projects
@@ -1482,11 +1513,37 @@ class PagedGenerationService:
             self._ttft_ema = 0.8 * self._ttft_ema + 0.2 * ttft_s
 
     @staticmethod
+    def _note_first_token(ticket: _Ticket, admit_t: float, segments: int,
+                          prompt_tokens: int, prefix_hit_tokens: int) -> None:
+        """The admission's first token is host-visible (``ticket.t_first``
+        is stamped): close its inbox_wait, slot_wait and prefill stages and,
+        for the user-facing admission, the request's receipt → first token
+        tile (infra/tracing.close_ttft). The engine's stamps come from the
+        slot, or from the result of a request that finished inside its
+        first tick. Best-effort — never raises."""
+        try:
+            t_engine = ticket.t_engine or ticket.t_submit
+            t_admit = admit_t or t_engine
+            tracing.close_ttft(ticket.request_id, ticket.t_first, [
+                ("inbox_wait", ticket.t_submit, t_engine, {}),
+                ("slot_wait", t_engine, t_admit, {}),
+                ("prefill", t_admit, ticket.t_first, {
+                    "segments": segments,
+                    "ticks": [ticket.tick_admit, ticket.tick_first],
+                    "prompt_tokens": prompt_tokens,
+                    "prefix_hit_tokens": prefix_hit_tokens,
+                }),
+            ], parent=ticket.parent)
+        except Exception:  # noqa: BLE001
+            logger.debug("first-token stage telemetry failed", exc_info=True)
+
+    @staticmethod
     def _note_finished(ticket: _Ticket, result: PagedResult, now: float,
-                       metrics, recorder) -> None:
+                       metrics, recorder, tick: int = 0) -> None:
         """Per-sequence completion telemetry: TTFT (if the whole generation
-        fit inside one tick), TPOT over the post-first-tick tokens, and the
-        flight record's engine section. Best-effort — never raises."""
+        fit inside one tick), TPOT over the post-first-tick tokens, the
+        decode stage, and the flight record's engine section. Best-effort —
+        never raises."""
         try:
             n = len(result.tokens)
             if ticket.t_first == 0.0:
@@ -1496,7 +1553,14 @@ class PagedGenerationService:
                 # toward zero and fake a throughput the engine doesn't have
                 ticket.t_first = now
                 ticket.tokens_first = n
+                ticket.tick_first = tick
                 metrics.record_ttft(now - ticket.t_submit, path=ticket.path)
+                PagedGenerationService._note_first_token(
+                    ticket, result.admit_t, result.prefill_segments,
+                    result.prompt_tokens, result.prefix_hit_tokens)
+            tracing.stamp("decode", ticket.t_first, now, ticket.request_id,
+                          ticket.parent, tokens=n,
+                          ticks=[ticket.tick_first, tick])
             tail = n - ticket.tokens_first
             tpot_s = (now - ticket.t_first) / tail if tail > 0 else None
             if tpot_s is not None:

@@ -218,18 +218,7 @@ def _make_observability_middleware(container: DependencyContainer):
                 endpoint = "/embed" if path in ("/embed", "/upload") else "*"
                 ip = _client_ip(request, trust_proxy=container.settings.serve.trust_proxy_headers)
                 container.rate_limiter.check(ip, endpoint)
-            # request-level OTel span (infra/tracing.py), joining the graph
-            # node spans under one trace. The single `enabled` bool keeps
-            # the tracing-off path free of span/context overhead.
-            from sentio_tpu.infra.tracing import get_tracing
-
-            tracing = get_tracing()
-            if tracing.enabled and work:
-                with tracing.span(f"http {request.method} {path}",
-                                  path=path, method=request.method):
-                    response = await handler(request)
-            else:
-                response = await handler(request)
+            response = await handler(request)
             status = response.status
             return response
         except SchemaError:
@@ -368,6 +357,8 @@ def _request_tenant(request: web.Request) -> tuple[str, str]:
 
 
 async def chat(request: web.Request) -> web.Response:
+    # receipt: where the request's span tree and its pool_wait stage start
+    t_received = time.perf_counter()
     container: DependencyContainer = request.app["container"]
     body = await _json_body(request)
     req = parse_chat_request(body, container.settings.serve)
@@ -396,7 +387,8 @@ async def chat(request: web.Request) -> web.Response:
                 logger.debug("stream admission pre-check skipped", exc_info=True)
         return await _chat_stream(request, container, req, deadline_ts,
                                   tenant=tenant, priority=priority,
-                                  resumable=_resolve_resumable(request, req))
+                                  resumable=_resolve_resumable(request, req),
+                                  t_received=t_received)
     result = await container.chat_handler.process_chat_request(
         question=req.question,
         top_k=req.top_k,
@@ -406,6 +398,7 @@ async def chat(request: web.Request) -> web.Response:
         deadline_ts=deadline_ts,
         tenant=tenant,
         priority=priority,
+        t_received=t_received,
     )
     return web.json_response(result)
 
@@ -414,7 +407,8 @@ async def _chat_stream(request: web.Request, container: DependencyContainer, req
                        deadline_ts: Optional[float] = None,
                        tenant: Optional[str] = None,
                        priority: Optional[str] = None,
-                       resumable: bool = True) -> web.StreamResponse:
+                       resumable: bool = True,
+                       t_received: Optional[float] = None) -> web.StreamResponse:
     """SSE token streaming (reference generator.py:298-333 / openai SSE).
     Retrieval + selection run first (blocking stage on a thread), then the
     generator's token iterator is pumped from a worker thread into the
@@ -432,6 +426,8 @@ async def _chat_stream(request: web.Request, container: DependencyContainer, req
     typed mid-stream error event (wire format unchanged)."""
     import re
     import uuid
+
+    from sentio_tpu.infra.tracing import stream_written
 
     # the id is reflected into a response header — a client-supplied
     # thread_id only pins it when header-safe (no CR/LF/control/unicode),
@@ -492,6 +488,7 @@ async def _chat_stream(request: web.Request, container: DependencyContainer, req
             tenant=tenant,
             priority=priority,
             resumable=resumable,
+            t_received=t_received,
         ):
             if not put((kind, payload)):
                 return
@@ -527,6 +524,9 @@ async def _chat_stream(request: web.Request, container: DependencyContainer, req
                     await response.write(b"data: [DONE]\n\n")
                 break
             await response.write(f"data: {json.dumps({kind: payload})}\n\n".encode())
+            if kind == "token":
+                # the pump queued these tokens a while ago: one stream_lag sample
+                stream_written(request_id)
     finally:
         stop.set()
         # drain so a producer blocked mid-put resolves, then join it
@@ -872,6 +872,8 @@ def _stitch_flight_record(container: DependencyContainer, request_id: str,
     if not fetchable:
         record["engine_window"] = "local"
         return record
+    from sentio_tpu.infra.flight import shift_spans
+
     router_origin = get_flight_recorder().origin()
     unavailable: list[dict] = []
     stitched = False
@@ -903,6 +905,11 @@ def _stitch_flight_record(container: DependencyContainer, request_id: str,
             ticks.append(shifted)
         if ticks:
             record["ticks"] = ticks
+        # the worker wrote the engine-side stages: they ride in on the
+        # same shift, under this record's root
+        record["spans"] = (record.get("spans") or []) + shift_spans(
+            [sp for sp in wrec.get("spans") or [] if sp["parent"] is not None],
+            shift)
         if wrec.get("ticks_truncated"):
             record["ticks_truncated"] = True
         record["engine_window"] = "stitched"
@@ -922,9 +929,12 @@ def _stitch_flight_record(container: DependencyContainer, request_id: str,
 
 
 async def debug_flight(request: web.Request) -> web.Response:
-    """One completed (or in-flight) request's flight record: graph node
-    timings joined with the engine-tick window its decode rode (occupancy,
-    queue depth, prefill/decode splits, page-pool levels) plus TTFT/TPOT.
+    """One completed (or in-flight) request's flight record: its span tree
+    (``spans``: one ``request`` root, every other span naming its parent;
+    the request stages among them, ``stages_ms`` tiling receipt → first
+    token), graph node timings, and the engine-tick window its decode rode
+    (occupancy, queue depth, prefill/decode splits, row-steps, page-pool
+    levels) plus TTFT/TPOT.
     In process/socket replica mode the engine tick window lives in the
     worker process — it is fetched on demand and clock-rebased into the
     router record (``engine_window`` says which view you got: ``local`` /
@@ -955,15 +965,42 @@ async def debug_flight(request: web.Request) -> web.Response:
     return web.json_response(record)
 
 
+async def debug_flight_summary(request: web.Request) -> web.Response:
+    """Where the retained finished requests waited: count, mean and median
+    per request stage (infra/phases.py), the conservation residual (the
+    stages sum to each request's server-side TTFT by construction) and the
+    retained ticks' counted row-steps; ``?last=N`` keeps the N requests
+    that finished last (a load window without its warm-up).
+    ``?format=chrome`` returns the whole ring — every retained tick and
+    request lane — as one Chrome/Perfetto trace. This process's recorder
+    only: with process or socket replicas the engine-side stages live in
+    the workers' records."""
+    from sentio_tpu.infra.flight import get_flight_recorder
+
+    if request.query.get("format") == "chrome":
+        from sentio_tpu.infra.chrome_trace import flight_to_chrome
+
+        return web.json_response(flight_to_chrome())
+    try:
+        last = int(request.query.get("last", "0"))
+    except ValueError:
+        raise SchemaError([{"field": "last", "error": "must be a whole number"}]) from None
+    return web.json_response(get_flight_recorder().stage_summary(last=max(last, 0)))
+
+
 async def debug_profile(request: web.Request) -> web.Response:
     """On-demand windowed XLA profiling: arm ``jax.profiler`` for
     ``?seconds=N`` (0.1–60, default 3) and stop it, writing the device
-    trace under ``?dir=`` / ``JAX_PROFILER_DIR`` / a tmp directory. The
-    decode pump wraps every tick in a ``StepTraceAnnotation`` when tracing
-    is enabled, so the XLA timeline lines up with flight ticks by step
-    number. Single-flight (the profiler is process-global); auth-gated
-    like every /debug route. Blocking work runs on a worker thread — the
-    event loop keeps serving while the window is open."""
+    trace under ``?dir=`` / ``JAX_PROFILER_DIR`` / a tmp directory. Every
+    pump iteration runs under a ``decode_tick`` step annotation carrying
+    the flight tick number, its phases under ``tick.<phase>`` and the
+    request stages under their names (infra/tracing.py), so the host plane
+    of the trace says what the program was doing, on the device's clock.
+    The Python tracer is off (a 4 s window with it holds 176k frame
+    events); ``?python=1`` brings the frames back. Single-flight (the
+    profiler is process-global); auth-gated like every /debug route.
+    Blocking work runs on a worker thread — the event loop keeps serving
+    while the window is open."""
     import tempfile
 
     from sentio_tpu.infra.tracing import profile_window
@@ -982,7 +1019,8 @@ async def debug_profile(request: web.Request) -> web.Response:
         or container.settings.observability.profiler_dir
         or tempfile.mkdtemp(prefix="sentio-xla-profile-")
     )
-    outcome = await asyncio.to_thread(profile_window, seconds, log_dir)
+    python_tracer = request.query.get("python", "0").lower() in ("1", "true", "yes")
+    outcome = await asyncio.to_thread(profile_window, seconds, log_dir, python_tracer)
     status = 200 if outcome.get("started") else 409
     return web.json_response(outcome, status=status)
 
@@ -1041,6 +1079,7 @@ def create_app(
     app.router.add_get("/info", info)
     app.router.add_get("/metrics", metrics_endpoint)
     app.router.add_get("/metrics/performance", metrics_performance)
+    app.router.add_get("/debug/flight", debug_flight_summary)
     app.router.add_get("/debug/flight/{request_id}", debug_flight)
     app.router.add_get("/debug/profile", debug_profile)
     app.router.add_post("/auth/token", auth_token)

@@ -19,6 +19,7 @@ import uuid
 from typing import Any, Optional
 
 from sentio_tpu.graph.state import create_initial_state
+from sentio_tpu.infra import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -43,6 +44,24 @@ class ChatHandler:
 
     # ----------------------------------------------------------------- sync
 
+    @staticmethod
+    def _open_record(request_id: Optional[str], t_received: Optional[float],
+                     deadline_ts: Optional[float], **fields: Any) -> float:
+        """Open the request's flight record on the thread that runs its
+        pipeline and return the clock its latency counts from: the receipt
+        the HTTP handler stamped, when it did. The wait for this thread is
+        the request's ``pool_wait`` stage."""
+        from sentio_tpu.infra.flight import get_flight_recorder
+
+        t_thread = time.perf_counter()
+        t0 = t_received if t_received is not None else t_thread
+        if request_id:
+            if deadline_ts is not None:
+                fields["deadline_ms"] = round((deadline_ts - t0) * 1e3, 1)
+            get_flight_recorder().start_request(request_id, t_received=t0, **fields)
+            tracing.stamp("pool_wait", t0, t_thread, request_id)
+        return t0
+
     def process_chat_request_sync(
         self,
         question: str,
@@ -53,8 +72,8 @@ class ChatHandler:
         deadline_ts: Optional[float] = None,
         tenant: Optional[str] = None,
         priority: Optional[str] = None,
+        t_received: Optional[float] = None,
     ) -> dict[str, Any]:
-        t0 = time.perf_counter()
         query_id = thread_id or uuid.uuid4().hex[:12]
         metadata: dict[str, Any] = {"query_id": query_id, "mode": mode}
         if top_k is not None:
@@ -77,11 +96,9 @@ class ChatHandler:
         from sentio_tpu.infra.flight import get_flight_recorder
 
         recorder = get_flight_recorder()
-        recorder.start_request(
-            query_id, endpoint="/chat", mode=mode, question_chars=len(question),
-            **({"deadline_ms": round((deadline_ts - t0) * 1e3, 1)}
-               if deadline_ts is not None else {}),
-        )
+        t0 = self._open_record(query_id, t_received, deadline_ts,
+                               endpoint="/chat", mode=mode,
+                               question_chars=len(question))
 
         cache = self.container.cache_manager
         try:
@@ -198,12 +215,15 @@ class ChatHandler:
         tenant: Optional[str] = None,
         priority: Optional[str] = None,
         resumable: bool = True,
+        t_received: Optional[float] = None,
     ):
         """Typed-event generator for SSE, with FULL graph-stage parity
         (reference factory.py:191-208 — streaming traverses the same graph):
         retrieve → rerank → select (dedup + token budget) → stream decode →
         verify. Yields ("sources", [...]) once, ("token", str) per increment,
-        and ("verdict", {...}) after the stream when the verifier is on.
+        ("verdict", {...}) after the stream when the verifier is on, and
+        ("usage", {prompt_tokens, answer_tokens}) last before the stream's end
+        when the provider reports the engine's counts.
         Failures degrade to the ladder text instead of raw errors. The
         ``request_id`` opens a flight record whose stage timings mirror the
         stream's stages (streams bypass the graph executor, so the stages
@@ -211,14 +231,9 @@ class ChatHandler:
         from sentio_tpu.infra.flight import get_flight_recorder
 
         recorder = get_flight_recorder()
-        t0 = time.perf_counter()
-        if request_id:
-            recorder.start_request(
-                request_id, endpoint="/chat?stream", mode=mode,
-                question_chars=len(question),
-                **({"deadline_ms": round((deadline_ts - t0) * 1e3, 1)}
-                   if deadline_ts is not None else {}),
-            )
+        t0 = self._open_record(request_id, t_received, deadline_ts,
+                               endpoint="/chat?stream", mode=mode,
+                               question_chars=len(question))
         timings: dict[str, float] = {}
         # set once the ANSWER's flight record has been finished (async/gated
         # close it at [DONE] time): the disconnect/degrade handlers below
@@ -226,23 +241,29 @@ class ChatHandler:
         # 'done' record with an audit-inclusive 'disconnected'/'degraded'
         record_closed = False
         try:
+            # the node spans the graph executor would write: the stages
+            # inside (embed, sparse_fuse, rerank, select) find the request
+            # through them
             t = time.perf_counter()
-            docs = self.container.retriever.retrieve(
-                question, top_k=top_k or self.settings.retrieval.top_k
-            )
+            with tracing.span("graph.retrieve", request_id=request_id):
+                docs = self.container.retriever.retrieve(
+                    question, top_k=top_k or self.settings.retrieval.top_k
+                )
             timings["retrieve"] = round((time.perf_counter() - t) * 1e3, 3)
             reranker = self.container.reranker
             if reranker is not None and docs:
                 t = time.perf_counter()
-                docs = reranker.rerank(
-                    question, docs, top_k=self.settings.rerank.top_k
-                ).documents
+                with tracing.span("graph.rerank", request_id=request_id):
+                    docs = reranker.rerank(
+                        question, docs, top_k=self.settings.rerank.top_k
+                    ).documents
                 timings["rerank"] = round((time.perf_counter() - t) * 1e3, 3)
             from sentio_tpu.graph.nodes import select_documents
 
-            selected, _used = select_documents(
-                list(docs), self.settings.generator.context_token_budget
-            )
+            with tracing.span("graph.select", request_id=request_id):
+                selected, _used = select_documents(
+                    list(docs), self.settings.generator.context_token_budget
+                )
             yield ("sources", [
                 {"id": d.id, "source": d.metadata.get("source", d.id),
                  "score": d.score()} for d in selected
@@ -259,6 +280,12 @@ class ChatHandler:
                 chunks.append(piece)
                 yield ("token", piece)
             timings["generate"] = round((time.perf_counter() - t) * 1e3, 3)
+            # the engine's own counts, as the last data event before [DONE]:
+            # a client need not re-tokenize the text to count what it got
+            usage = None
+            if gen_stats.get("tokens") is not None:
+                usage = {"prompt_tokens": gen_stats.get("prompt_tokens"),
+                         "answer_tokens": gen_stats["tokens"]}
             verifier = self.container.verifier
             answer = "".join(chunks)
             # same deadline discipline as the graph verify node: skip the
@@ -297,6 +324,8 @@ class ChatHandler:
                     # flight record closes at ANSWER latency; the audit
                     # decodes while the connection idles (keepalives keep
                     # it warm) and the verdict trails as a `verify` event
+                    if usage:
+                        yield ("usage", usage)
                     yield ("done", "")
                     if request_id:
                         recorder.add_node_timings(request_id, timings)
@@ -343,6 +372,8 @@ class ChatHandler:
                     _record_verify(request_id, "sync", result.verdict,
                                    verdict_ms=verdict_ms)
                     yield ("verdict", result.to_dict())
+            if usage:
+                yield ("usage", usage)
             if request_id:
                 recorder.add_node_timings(request_id, timings)
                 recorder.finish_request(

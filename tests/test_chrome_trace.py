@@ -64,6 +64,24 @@ FAKE_RECORDS = [
             "mode": "async", "outcome": "pass", "confidence": 0.9,
             "verdict_ms": 12.0,
         },
+        # as FlightRecorder stores them: a span without a parent hangs
+        # under the request root the exporter synthesizes
+        "ttft_server_ms": 11.0,
+        "spans": [
+            {"name": "pool_wait", "t0_s": 0.001, "t1_s": 0.002, "parent": None},
+            {"name": "embed", "t0_s": 0.002, "t1_s": 0.003,
+             "parent": "graph.retrieve"},
+            {"name": "graph.retrieve", "t0_s": 0.002, "t1_s": 0.0035,
+             "parent": None},
+            {"name": "inbox_wait", "t0_s": 0.004, "t1_s": 0.0045, "parent": None},
+            {"name": "slot_wait", "t0_s": 0.0045, "t1_s": 0.005, "parent": None},
+            {"name": "prefill", "t0_s": 0.005, "t1_s": 0.012, "parent": None,
+             "fields": {"segments": 1, "ticks": [1, 1], "prompt_tokens": 16,
+                        "prefix_hit_tokens": 0}},
+            {"name": "decode", "t0_s": 0.012, "t1_s": 0.031, "parent": None,
+             "fields": {"tokens": 8, "ticks": [1, 2]}},
+            {"name": "verify", "t0_s": 0.031, "t1_s": 0.043, "parent": None},
+        ],
     },
 ]
 
@@ -131,25 +149,34 @@ class TestSchema:
                 f"{tick['name']}: phase sum {total}µs vs wall {tick['dur']}µs"
             )
 
-    def test_request_span_and_marks(self):
+    def test_request_lane_is_laid_out_from_the_spans(self):
         events = self._events()
         req = [e for e in events if e["name"] == "request req-1"]
         assert len(req) == 1 and req[0]["ph"] == "X"
         assert req[0]["ts"] == 1000.0  # 0.001 s → µs
         assert req[0]["dur"] == 30000.0
-        engine = [e for e in events if e["name"] == "engine"]
-        assert len(engine) == 1
-        assert engine[0]["tid"] == req[0]["tid"]
-        first = [e for e in events if e["name"] == "first_token"]
-        assert len(first) == 1 and first[0]["ph"] == "i"
-        # submit 0.004 s + ttft 8 ms = 12 ms
-        assert first[0]["ts"] == 12000.0
-        verify = [e for e in events if e["name"].startswith("verify:")]
-        assert len(verify) == 1
-        assert verify[0]["name"] == "verify:pass"
-        # async verdict trails the answer: starts at request end
-        assert verify[0]["ts"] == req[0]["ts"] + req[0]["dur"]
-        assert verify[0]["dur"] == 12000.0
+        assert req[0]["args"]["ttft_server_ms"] == 11.0
+        lane = {e["name"]: e for e in events
+                if e["tid"] == req[0]["tid"] and e["pid"] == req[0]["pid"]
+                and e["ph"] == "X"}
+        assert set(lane) == {"request req-1", "pool_wait", "embed", "graph.retrieve",
+                             "inbox_wait", "slot_wait", "prefill", "decode", "verify"}
+        # a span names the span that caused it; one without hangs under the root
+        assert lane["embed"]["args"]["parent"] == "graph.retrieve"
+        assert lane["prefill"]["args"]["parent"] == "request"
+        assert lane["prefill"]["ts"] == 5000.0 and lane["prefill"]["dur"] == 7000.0
+        assert lane["prefill"]["args"]["segments"] == 1
+        # first token = where prefill ends and decode starts
+        assert lane["decode"]["ts"] == lane["prefill"]["ts"] + lane["prefill"]["dur"]
+        # the async audit trails the answer: it overhangs the request slice
+        assert lane["verify"]["args"]["outcome"] == "pass"
+        assert lane["verify"]["ts"] == req[0]["ts"] + req[0]["dur"]
+        assert lane["verify"]["dur"] == 12000.0
+
+    def test_a_record_without_spans_still_gets_its_root(self):
+        record = {k: v for k, v in FAKE_RECORDS[0].items() if k != "spans"}
+        events = build_chrome_trace([], [record])["traceEvents"]
+        assert [e["name"] for e in events if e["ph"] == "X"] == ["request req-1"]
 
     def test_health_instant(self):
         events = self._events()

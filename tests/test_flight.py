@@ -5,6 +5,7 @@ endpoint's 404 + auth behavior."""
 from __future__ import annotations
 
 import asyncio
+import json
 import threading
 
 import pytest
@@ -414,6 +415,146 @@ class TestFlightEndpoint:
             assert record["engine"]["ttft_ms"] >= 0.0
 
         asyncio.run(self._with_client(self._settings(), body))
+
+    def _rag_settings(self):
+        """The whole pipeline on device models: encoder, cross-encoder,
+        generator and a synchronous audit."""
+        from sentio_tpu.config import (
+            EmbedderConfig,
+            GeneratorConfig,
+            RerankConfig,
+            Settings,
+        )
+
+        return Settings(
+            embedder=EmbedderConfig(provider="tpu", model_preset="tiny", coalesce=False),
+            generator=GeneratorConfig(
+                provider="tpu", model_preset="tiny", use_verifier=True,
+                max_new_tokens=12, verifier_max_tokens=6, mode="fast",
+                use_paged_decode=True, kv_page_size=16, kv_max_pages_per_seq=24,
+                max_batch_size=4, decode_steps_per_tick=4, decode_max_tick_steps=4,
+            ),
+            rerank=RerankConfig(enabled=True, kind="cross_encoder"),
+        )
+
+    @staticmethod
+    def _check_tree(record, expect_parent):
+        spans = record["spans"]
+        roots = [sp for sp in spans if sp["parent"] is None]
+        assert len(roots) == 1 and roots[0]["name"] == "request" and spans[0] is roots[0]
+        names = {sp["name"] for sp in spans}
+        assert all(sp["parent"] in names for sp in spans[1:]), spans
+        for name, parent in expect_parent.items():
+            got = {sp["parent"] for sp in spans if sp["name"] == name}
+            assert got == (parent if isinstance(parent, set) else {parent}), (name, got)
+        # the audit's admission hangs under `verify`, whole
+        audit = [sp["name"] for sp in spans if sp["parent"] == "verify"]
+        assert audit == ["inbox_wait", "slot_wait", "prefill", "decode"]
+        # the stages tile receipt -> first token exactly
+        assert sum(record["stages_ms"].values()) == pytest.approx(
+            record["ttft_server_ms"], abs=1e-6)
+        for stage in ("embed", "rerank", "prefill"):
+            assert record["stages_ms"][stage] > 0.0, record["stages_ms"]
+        prefill = next(sp for sp in spans
+                       if sp["name"] == "prefill" and sp["parent"] != "verify")
+        assert prefill["fields"]["segments"] == 1
+        assert prefill["fields"]["prompt_tokens"] > 0
+        assert prefill["fields"]["ticks"][0] <= prefill["fields"]["ticks"][1]
+
+    def test_span_tree_of_a_streamed_and_a_graph_request(self, recorder):
+        """/debug/flight/{id} returns the span tree: one root, every parent
+        resolves, the request stages hang where their work ran and the
+        audit's stages under ``verify`` — on the SSE path and through the
+        graph executor alike. /debug/flight without an id sums the stages
+        up; ?format=chrome returns the whole ring."""
+
+        async def body(client, container):
+            for i in range(3):
+                resp = await client.post("/embed", json={
+                    "content": f"systolic arrays multiply matrices, note {i}"})
+                assert resp.status == 200
+            resp = await client.post("/chat", json={
+                "question": "what multiplies matrices?", "stream": True,
+                "thread_id": "tree-sse"})
+            text = (await resp.read()).decode()
+            events = [json.loads(line[6:]) for line in text.splitlines()
+                      if line.startswith("data: {")]
+            assert "usage" in events[-1], events[-1]  # last data event before [DONE]
+            assert text.rstrip().endswith("data: [DONE]")
+            record = await (await client.get("/debug/flight/tree-sse")).json()
+            assert events[-1]["usage"] == {
+                "prompt_tokens": record["engine"]["prompt_tokens"],
+                "answer_tokens": record["engine"]["tokens"]}
+            self._check_tree(record, {
+                "pool_wait": "request", "graph.retrieve": "request",
+                "embed": "graph.retrieve", "sparse_fuse": "graph.retrieve",
+                "rerank": "graph.rerank", "select": "graph.select",
+                "verify": "request"})
+            assert record["stream_lag_max_ms"] >= 0.0
+
+            resp = await client.post("/chat", json={
+                "question": "what multiplies matrices?", "thread_id": "tree-graph"})
+            assert resp.status == 200
+            record = await (await client.get("/debug/flight/tree-graph")).json()
+            self._check_tree(record, {
+                "pool_wait": "request", "graph.retrieve": "request",
+                "embed": "graph.retrieve", "rerank": "graph.rerank",
+                "select": "graph.select", "graph.generate": "request",
+                "decode": {"graph.generate", "verify"}, "verify": "graph.verify"})
+
+            latest = await (await client.get("/debug/flight?last=1")).json()
+            assert latest["requests"] == 1  # the graph request finished last
+            assert "stream_lag" not in latest["stages_ms"]
+            assert (await client.get("/debug/flight?last=x")).status == 422
+            summary = await (await client.get("/debug/flight")).json()
+            assert summary["requests"] == 2
+            assert summary["residual_ms_max"] < 1e-6
+            assert summary["stages_ms"]["prefill"]["count"] == 2
+            assert summary["stages_ms"]["verify"]["count"] == 2
+            assert summary["stages_ms"]["stream_lag"]["count"] == 1
+            tiles = sum(v["mean"] for k, v in summary["stages_ms"].items()
+                        if k not in ("decode", "verify", "stream_lag"))
+            assert tiles == pytest.approx(summary["ttft_server_ms"]["mean"], abs=0.01)
+            steps = summary["row_steps"]
+            assert steps["useful"] > 0 and steps["empty"] > 0
+            ticks = [e for e in recorder.timeline() if "row_steps" in e]
+            assert sum(steps.values()) == 4 * sum(e["sub_steps"] for e in ticks)
+
+            ring = await (await client.get("/debug/flight?format=chrome")).json()
+            names = {e["name"] for e in ring["traceEvents"]}
+            assert {"request tree-sse", "request tree-graph", "embed", "prefill"} <= names
+            assert any(n.startswith("tick ") for n in names)
+
+            metrics_text = await (await client.get("/metrics")).text()
+            for stage in ("pool_wait", "embed", "sparse_fuse", "rerank", "select",
+                          "inbox_wait", "slot_wait", "prefill", "other"):
+                assert f'sentio_tpu_request_stage_seconds_count{{stage="{stage}"}} 2.0' \
+                    in metrics_text, stage
+
+        from sentio_tpu.infra.metrics import MetricsCollector, set_metrics
+
+        set_metrics(MetricsCollector())  # the counts below are this test's alone
+        try:
+            asyncio.run(self._with_client(self._rag_settings(), body))
+        finally:
+            set_metrics(None)
+
+    def test_debug_profile_python_tracer_is_opt_in(self, recorder, tmp_path, monkeypatch):
+        import sentio_tpu.infra.tracing as tracing_mod
+
+        calls = []
+        monkeypatch.setattr(
+            tracing_mod, "profile_window",
+            lambda seconds, log_dir, python_tracer=False: calls.append(python_tracer)
+            or {"started": True})
+
+        async def body(client, container):
+            assert (await client.get(f"/debug/profile?seconds=0.1&dir={tmp_path}")).status == 200
+            assert (await client.get(
+                f"/debug/profile?seconds=0.1&dir={tmp_path}&python=1")).status == 200
+
+        asyncio.run(self._with_client(self._settings(), body))
+        assert calls == [False, True]
 
     def test_debug_flight_is_auth_gated(self, recorder):
         """With auth enabled, /debug/flight requires credentials (unlike

@@ -479,13 +479,18 @@ class TestMetrics:
         m = MetricsCollector()
         m.record_request("/chat", 200, 0.12)
         m.record_llm("generate", 0.5, tokens=64)
-        m.record_breaker("tpu", "open")
-        m.record_batch_occupancy("chat", 0.75)
+        m.record_row_steps({"useful": 3, "halted": 4, "empty": 9})
         snap = m.export_json()
         assert any("requests" in k for k in snap["counters"])
-        assert snap["gauges"]["breaker_state('tpu',)"] == 2.0
+        assert snap["gauges"]["tokens_per_s()"] == 128.0
+        assert snap["counters"]["row_steps('empty',)"] == 9.0
         text = m.export_prometheus()
         assert b"sentio_requests_total" in text
+        assert b'sentio_tpu_decode_row_steps_total{kind="halted"} 4.0' in text
+        # series no code wrote are gone, not exported empty
+        for dead in (b"sentio_retrieval_latency", b"sentio_circuit_breaker_state",
+                     b"sentio_tpu_batch_occupancy", b"sentio_tpu_hbm_bytes_in_use"):
+            assert dead not in text
 
     def test_track_request_context(self):
         from sentio_tpu.infra.metrics import MetricsCollector
@@ -499,39 +504,6 @@ class TestMetrics:
         snap = m.export_json()
         assert snap["counters"]["requests('/info', '200')"] == 1.0
         assert snap["counters"]["requests('/info', '500')"] == 1.0
-
-
-class TestTracing:
-    def test_mock_spans_when_disabled(self):
-        from sentio_tpu.config import ObservabilityConfig
-        from sentio_tpu.infra.tracing import TracingManager, trace_function
-
-        mgr = TracingManager(ObservabilityConfig(tracing_enabled=False))
-        with mgr.span("op", key="value") as span:
-            span.set_attribute("more", 1)
-
-        @trace_function("custom", manager=mgr)
-        def traced():
-            return 42
-
-        assert traced() == 42
-
-    def test_otel_spans_when_enabled(self):
-        from sentio_tpu.config import ObservabilityConfig
-        from sentio_tpu.infra.tracing import TracingManager
-
-        mgr = TracingManager(ObservabilityConfig(tracing_enabled=True))
-        with mgr.span("real-op", component="test"):
-            pass
-        mgr.shutdown()
-
-    def test_profile_step_works_without_profiler(self):
-        from sentio_tpu.config import ObservabilityConfig
-        from sentio_tpu.infra.tracing import TracingManager
-
-        mgr = TracingManager(ObservabilityConfig(tracing_enabled=False))
-        with mgr.profile_step("decode", step=3):
-            pass
 
 
 def test_csrf_malformed_timestamp_returns_false():

@@ -16,9 +16,13 @@ from sentio_tpu.infra.metrics import MetricsCollector, set_metrics
 from sentio_tpu.infra.phases import (
     DUTY_STATES,
     HOST_PHASES,
+    REQUEST_STAGES,
+    ROW_STEP_KINDS,
     TICK_PHASES,
+    TTFT_STAGES,
     PhaseTimer,
     duty_fractions,
+    tile_ttft,
 )
 from sentio_tpu.runtime.paged import ContinuousBatchingEngine
 from sentio_tpu.runtime.service import PagedGenerationService
@@ -237,6 +241,130 @@ class TestConservation:
         phases = eng.last_step_phases
         assert set(phases) <= set(TICK_PHASES)
         assert sum(phases.values()) > 0.0
+
+
+class TestRequestStages:
+    """The same contract one level up: request stages tile the server-side
+    time to first token as tick phases tile ``pump_ms``."""
+
+    def test_the_stage_set_is_fixed_and_the_tile_is_its_head(self):
+        assert REQUEST_STAGES[:len(TTFT_STAGES)] == TTFT_STAGES
+        assert TTFT_STAGES[-1] == "other"
+        assert set(REQUEST_STAGES) - set(TTFT_STAGES) == {"decode", "verify", "stream_lag"}
+        assert not set(REQUEST_STAGES) & {f"tick.{p}" for p in TICK_PHASES}
+
+    @pytest.mark.parametrize("stage_s, ttft_s", [
+        ({}, 0.25),
+        ({"prefill": 0.2, "inbox_wait": 0.04}, 0.25),
+        ({"embed": 0.3, "rerank": 0.3, "prefill": 0.5}, 1.0),  # overlap: other < 0
+    ])
+    def test_tile_conserves_by_construction(self, stage_s, ttft_s):
+        tile = tile_ttft(stage_s, ttft_s)
+        assert tuple(tile) == TTFT_STAGES
+        assert sum(tile.values()) == pytest.approx(ttft_s, abs=1e-12)
+        assert tile["other"] == pytest.approx(ttft_s - sum(stage_s.values()), abs=1e-12)
+
+    def test_every_request_of_a_run_tiles_its_ttft(self, recorder, metrics):
+        """More callers than slots: slot_wait is real, and every request's
+        nine stages still sum to its server-side TTFT; the histogram holds
+        each stage once a request, the sums add up to the TTFTs'."""
+        svc = PagedGenerationService(_engine(max_slots=2))
+        n = 6
+        try:
+            threads = [
+                threading.Thread(
+                    target=svc.generate, args=(f"stage probe request {i} ",),
+                    kwargs={"max_new_tokens": 8, "request_id": f"stage-{i}"})
+                for i in range(n)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            svc.close()
+        records = [recorder.get(f"stage-{i}") for i in range(n)]
+        for record in records:
+            assert tuple(record["stages_ms"]) == TTFT_STAGES
+            assert sum(record["stages_ms"].values()) == pytest.approx(
+                record["ttft_server_ms"], abs=1e-6)
+            # a bare service call: nothing before the ticket, so the three
+            # engine stages are the whole of it
+            engine = sum(record["stages_ms"][k]
+                         for k in ("inbox_wait", "slot_wait", "prefill"))
+            assert engine == pytest.approx(record["ttft_server_ms"], abs=1.0)
+            names = [sp["name"] for sp in record["spans"]]
+            assert names == ["request", "inbox_wait", "slot_wait", "prefill", "decode"]
+        assert max(r["stages_ms"]["slot_wait"] for r in records) > 0.0
+        histos = {k.split("'")[1]: v for k, v in
+                  metrics.export_json()["histograms"].items()
+                  if k.startswith("request_stage")}
+        assert {k: v["count"] for k, v in histos.items()} == {
+            **dict.fromkeys(TTFT_STAGES, n), "decode": n}
+        observed = sum(histos[s]["mean"] * n for s in TTFT_STAGES)
+        assert observed * 1e3 == pytest.approx(
+            sum(r["ttft_server_ms"] for r in records), abs=0.01)
+
+
+class TestRowSteps:
+    """Counted, not sampled: what the slots did with the sub-steps the
+    device ran. useful + halted + empty == slots x sub-steps on every tick."""
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_every_tick_conserves_failed_ticks_included(self, recorder, metrics, depth):
+        from sentio_tpu.infra import faults
+
+        engine = _engine(pipeline_depth=depth)
+        svc = PagedGenerationService(engine, retry_budget=2)
+        try:
+            # the third step dies: at depth 2 with a tick in flight, which
+            # the reset then books as delivered to nobody
+            with faults.inject("paged.step", error=RuntimeError("row-step probe"),
+                               times=1, skip=2) as rule:
+                threads = [
+                    threading.Thread(
+                        target=svc.generate, args=(f"row step probe {i} ",),
+                        kwargs={"max_new_tokens": 5 + 3 * i, "timeout_s": 120})
+                    for i in range(3)
+                ]
+                for t in threads:
+                    t.start()
+                for t in threads:
+                    t.join(timeout=120)
+                assert not any(t.is_alive() for t in threads)
+            assert rule.fired == 1
+        finally:
+            faults.reset()
+            svc.close()
+        ticks = [e for e in recorder.timeline() if "row_steps" in e]
+        assert any(e.get("event") == "tick_failure" for e in ticks)
+        assert len(ticks) >= 4
+        for tick in ticks:
+            assert tuple(tick["row_steps"]) == ROW_STEP_KINDS
+            assert all(n >= 0 for n in tick["row_steps"].values())
+            assert sum(tick["row_steps"].values()) == engine.max_slots * tick["sub_steps"], tick
+        ring = {k: sum(t["row_steps"][k] for t in ticks) for k in ROW_STEP_KINDS}
+        assert ring["useful"] > 0 and ring["halted"] > 0 and ring["empty"] > 0
+        # the engine's lifetime totals conserve too; they also hold what a
+        # reset flushed (dispatched, never harvested), which no tick saw
+        total = engine.row_steps_total
+        assert all(total[k] >= ring[k] for k in ROW_STEP_KINDS)
+        assert sum(total.values()) % engine.max_slots == 0
+        if depth == 1:
+            assert total == ring
+        counters = metrics.export_json()["counters"]
+        assert {k: counters[f"row_steps('{k}',)"] for k in ROW_STEP_KINDS} == ring
+
+    def test_useful_row_steps_are_the_tokens_decoded_after_the_first(self, recorder):
+        """A bare engine run: every delivered token but each request's
+        first (prefill samples it) is one useful row-step."""
+        engine = _engine(pipeline_depth=1)
+        results = engine.run_all(["alpha beta", "gamma"], max_new_tokens=7)
+        delivered = sum(len(r.tokens) for r in results)
+        assert engine.row_steps_total["useful"] == delivered - len(results)
+        assert sum(engine.row_steps_total.values()) == (
+            engine.max_slots * engine.total_sub_steps)
 
 
 class TestReplicaAggregation:

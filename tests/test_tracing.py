@@ -1,197 +1,287 @@
-"""infra/tracing.py — previously dead code, now load-bearing (ISSUE 12):
-mock-span fallback when OTel is absent, the single `enabled` hot-path
-guard, profile_step's exception path, trace_function sync+async, the
-set_tracing reset seam, the windowed profiler's single-flight guard, and
-the graph-executor node-span wiring."""
+"""infra/tracing.py — the span layer: a span is a profiler annotation and,
+with a request id, a span on that request's flight record, naming the span
+that caused it; request stages tile the time to first token; a profile
+window without the Python tracer holds the pump's step and phase events.
+One module holds every test that arms the profiler: it is process-global."""
 
 import asyncio
 import sys
 import threading
-from contextlib import contextmanager
+from pathlib import Path
 
 import pytest
 
-from sentio_tpu.config import ObservabilityConfig
-from sentio_tpu.infra.tracing import (
-    MockSpan,
-    TracingManager,
-    get_tracing,
-    profile_window,
-    set_tracing,
-    trace_function,
-)
+from sentio_tpu.infra import tracing
+from sentio_tpu.infra.flight import FlightRecorder, set_flight_recorder
+from sentio_tpu.infra.metrics import MetricsCollector, set_metrics
+from sentio_tpu.infra.phases import TICK_PHASES, TTFT_STAGES, tile_ttft
+from sentio_tpu.infra.tracing import profile_window, span, stamp
+
+REPO = Path(__file__).resolve().parents[1]
 
 
-@pytest.fixture(autouse=True)
-def _reset_tracing():
-    """Every test starts and ends with a clean singleton — the set_tracing
-    reset seam the module exposes for exactly this purpose."""
-    set_tracing(None)
-    yield
-    set_tracing(None)
+@pytest.fixture
+def recorder():
+    rec = FlightRecorder()
+    set_flight_recorder(rec)
+    yield rec
+    set_flight_recorder(None)
 
 
-class RecordingManager:
-    """Duck-typed manager capturing span/profile_step calls — what the
-    executor and pump wiring tests assert against."""
-
-    def __init__(self, enabled: bool = True):
-        self.enabled = enabled
-        self.spans: list[tuple[str, dict]] = []
-        self.steps: list[tuple[str, int]] = []
-        self._lock = threading.Lock()
-
-    @contextmanager
-    def span(self, name, **attrs):
-        with self._lock:
-            self.spans.append((name, attrs))
-        yield MockSpan()
-
-    @contextmanager
-    def profile_step(self, name, step=0):
-        with self._lock:
-            self.steps.append((name, step))
-        yield
+@pytest.fixture
+def metrics():
+    collector = MetricsCollector()
+    set_metrics(collector)
+    yield collector
+    set_metrics(None)
 
 
-class TestMockFallback:
-    def test_disabled_by_default(self):
-        mgr = TracingManager(ObservabilityConfig())
-        assert mgr.enabled is False
-        with mgr.span("anything", a=1) as span:
-            # the mock span accepts the full OTel surface
-            assert span.set_attribute("k", "v") is span
-            span.record_exception(ValueError("x"))
-            span.set_status("ok")
-
-    def test_otel_absent_is_noop_and_disabled(self, monkeypatch):
-        """tracing_enabled=True but no opentelemetry installed: setup
-        degrades to the mock path AND the hot-path guard stays False —
-        serving code pays nothing to feed a mock."""
-        monkeypatch.setitem(sys.modules, "opentelemetry", None)
-        mgr = TracingManager(
-            ObservabilityConfig(tracing_enabled=True))
-        assert mgr.enabled is False
-        ran = []
-        with mgr.span("n") as span:
-            ran.append(span)
-        assert isinstance(ran[0], MockSpan)
-
-    def test_enabled_with_real_otel(self):
-        # the base image ships only opentelemetry-api; the SDK (and thus a
-        # real tracer) is a deploy-time install — skip, don't fake it
-        pytest.importorskip("opentelemetry.sdk")
-        mgr = TracingManager(ObservabilityConfig(tracing_enabled=True))
-        assert mgr.enabled is True
-        with mgr.span("real", request_id="r1") as span:
-            assert span is not None
-        mgr.shutdown()
+def _stage_counts(collector) -> dict:
+    histos = collector.export_json()["histograms"]
+    return {key.split("'")[1]: val["count"] for key, val in histos.items()
+            if key.startswith("request_stage")}
 
 
-class TestProfileStep:
-    def test_profile_step_wraps_body(self):
-        mgr = TracingManager(ObservabilityConfig())
-        ran = []
-        with mgr.profile_step("tick", step=7):
-            ran.append(True)
-        assert ran == [True]
+def _tiny_service():
+    from sentio_tpu.runtime.paged import ContinuousBatchingEngine
+    from sentio_tpu.runtime.service import PagedGenerationService
 
-    def test_profile_step_exception_path(self, monkeypatch):
-        """A broken StepTraceAnnotation (e.g. profiler unsupported on the
-        backend) must degrade to the plain span, never fail the tick."""
-        import jax
+    return PagedGenerationService(ContinuousBatchingEngine(
+        max_slots=2, page_size=16, max_pages_per_seq=4,
+        steps_per_tick=4, max_tick_steps=4))
 
-        class Boom:
-            def __init__(self, *a, **k):
-                raise RuntimeError("no profiler here")
 
-        monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", Boom)
-        mgr = TracingManager(ObservabilityConfig())
-        ran = []
-        with mgr.profile_step("tick", step=1):
-            ran.append(True)
-        assert ran == [True]
-
-    def test_profile_step_body_exception_propagates_unmangled(self):
-        """An exception from the TRACED BODY (a failed device tick) must
-        surface as itself: the pump's crash containment and the chaos
-        drills key off the original type. The old broad except around the
-        yield replaced it with contextlib's 'generator didn't stop after
-        throw()' RuntimeError."""
-        mgr = TracingManager(ObservabilityConfig())
-        with pytest.raises(ValueError, match="tick blew up"):
-            with mgr.profile_step("tick", step=2):
-                raise ValueError("tick blew up")
-
-    def test_profile_step_body_exception_with_broken_annotation(
-            self, monkeypatch):
-        import jax
-
-        class ExitBoom:
-            def __init__(self, *a, **k):
+class TestSpan:
+    def test_span_lands_on_the_record_with_parent_and_fields(self, recorder):
+        with span("graph.rerank", request_id="r1", replica_id=0):
+            with span("rerank", pairs=3):  # id and parent come from the context
                 pass
+        spans = recorder.get("r1")["spans"]
+        assert [sp["name"] for sp in spans] == ["request", "rerank", "graph.rerank"]
+        rerank = spans[1]
+        assert rerank["parent"] == "graph.rerank" and rerank["fields"] == {"pairs": 3}
+        assert spans[2]["parent"] == "request"  # no parent given: under the root
+        assert spans[2]["t0_s"] <= rerank["t0_s"] <= rerank["t1_s"] <= spans[2]["t1_s"]
+        assert tracing.current() == (None, None)  # the context is restored
 
-            def __enter__(self):
-                return self
+    def test_no_request_id_writes_no_record(self, recorder):
+        with span("embed"):
+            assert tracing.current() == (None, "embed")
+        assert recorder.records() == []
 
-            def __exit__(self, *exc):
-                raise RuntimeError("exit failed")
+    def test_another_requests_span_is_not_a_parent(self, recorder):
+        with span("graph.retrieve", request_id="a"):
+            with span("embed", request_id="b"):
+                assert tracing.parent_for("b") == "embed"
+                assert tracing.parent_for("a") is None
+        assert recorder.get("b")["spans"][1]["parent"] == "request"
 
-        monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", ExitBoom)
-        mgr = TracingManager(ObservabilityConfig())
-        # a broken annotation EXIT must neither mask the body's exception
-        # nor raise its own
-        with pytest.raises(ValueError, match="original"):
-            with mgr.profile_step("tick", step=3):
-                raise ValueError("original")
-        ran = []
-        with mgr.profile_step("tick", step=4):
-            ran.append(True)
-        assert ran == [True]
+    def test_context_crosses_to_thread_but_not_a_bare_thread(self, recorder):
+        seen = {}
+
+        async def hop():
+            with span("graph.retrieve", request_id="hop"):
+                seen["to_thread"] = await asyncio.to_thread(tracing.current)
+                thread = threading.Thread(
+                    target=lambda: seen.update(bare=tracing.current()), name="bare")
+                thread.start()
+                thread.join(timeout=10)
+
+        asyncio.run(hop())
+        assert seen["to_thread"] == ("hop", "graph.retrieve")
+        assert seen["bare"] == (None, None)
+
+    def test_body_exception_leaves_as_itself(self, recorder):
+        """The pump's crash containment and the chaos drills key off the
+        original exception type: neither a span nor the step annotation may
+        wrap, swallow or replace it — and the span is still recorded."""
+        with pytest.raises(KeyError, match="tick blew up"):
+            with tracing.tick_annotation(7):
+                with span("prefill", request_id="boom"):
+                    raise KeyError("tick blew up")
+        assert [sp["name"] for sp in recorder.get("boom")["spans"]] == ["request", "prefill"]
+        assert tracing.current() == (None, None)
+
+    def test_spans_per_record_are_bounded(self, recorder):
+        from sentio_tpu.infra.flight import MAX_SPANS_PER_RECORD
+
+        for i in range(MAX_SPANS_PER_RECORD + 5):
+            stamp("graph.loop", float(i), float(i) + 0.5, "many")
+        record = recorder.get("many")
+        assert len(record["spans"]) == MAX_SPANS_PER_RECORD + 1  # + the root
+        assert record["spans_dropped"] == 5
 
 
-class TestTraceFunction:
-    def test_sync(self):
-        mgr = RecordingManager()
-        set_tracing(mgr)
+class TestStages:
+    def test_late_stages_are_observed_at_close_the_audits_children_not(
+            self, recorder, metrics):
+        stamp("decode", 1.0, 1.5, "r")                    # the answer's
+        stamp("decode", 2.0, 2.25, "r", parent="verify")  # the audit's
+        with span("verify", request_id="r"):
+            pass
+        stamp("embed", 0.1, 0.2, "r")  # a tile stage waits for the first token
+        assert _stage_counts(metrics) == {"decode": 1, "verify": 1}
+        assert len(recorder.get("r")["spans"]) == 5  # all four are spans
 
-        @trace_function("my.sync")
-        def add(a, b):
-            return a + b
+    def test_first_token_tiles_receipt_to_now_exactly(self, recorder, metrics):
+        origin = recorder.origin()
+        recorder.start_request("t", t_received=origin + 1.0)
+        stamp("pool_wait", origin + 1.0, origin + 1.1, "t")
+        stamp("embed", origin + 1.2, origin + 1.5, "t", parent="graph.retrieve")
+        stamp("rerank", origin + 1.5, origin + 1.7, "t")
+        tracing.close_ttft("t", origin + 3.0, [
+            ("inbox_wait", origin + 1.8, origin + 1.9, {}),
+            ("slot_wait", origin + 1.9, origin + 1.9, {}),
+            ("prefill", origin + 1.9, origin + 3.0, {"segments": 2}),
+        ])
+        record = recorder.get("t")
+        stages = record["stages_ms"]
+        assert tuple(stages) == TTFT_STAGES
+        assert sum(stages.values()) == pytest.approx(record["ttft_server_ms"], abs=1e-6)
+        assert record["ttft_server_ms"] == pytest.approx(2000.0, abs=1e-3)
+        assert stages["other"] == pytest.approx(200.0, abs=1e-3)  # 1.1-1.2 and 1.7-1.8
+        assert stages["sparse_fuse"] == 0.0 and stages["slot_wait"] == 0.0
+        # every tile stage observed once, the zeros included
+        assert _stage_counts(metrics) == dict.fromkeys(TTFT_STAGES, 1)
+        sums = {k.split("'")[1]: v["mean"] for k, v in
+                metrics.export_json()["histograms"].items()}
+        assert sum(sums.values()) == pytest.approx(2.0, abs=1e-6)
 
-        assert add(2, 3) == 5
-        assert mgr.spans[0][0] == "my.sync"
+    def test_a_request_is_tiled_once_and_the_audit_never(self, recorder, metrics):
+        origin = recorder.origin()
+        recorder.start_request("once", t_received=origin)
+        engine = [("inbox_wait", origin, origin + 0.1, {}),
+                  ("slot_wait", origin + 0.1, origin + 0.1, {}),
+                  ("prefill", origin + 0.1, origin + 0.5, {})]
+        tracing.close_ttft("once", origin + 0.5, engine)
+        tracing.close_ttft("once", origin + 0.9, engine, parent="verify")
+        tracing.close_ttft("once", origin + 0.9, engine)  # a second admission
+        assert _stage_counts(metrics) == dict.fromkeys(TTFT_STAGES, 1)
+        assert recorder.get("once")["ttft_server_ms"] == pytest.approx(500.0, abs=1e-3)
+        parents = [sp["parent"] for sp in recorder.get("once")["spans"]
+                   if sp["name"] == "prefill"]
+        assert parents == ["request", "verify", "request"]
 
-    def test_async(self):
-        mgr = RecordingManager()
-        set_tracing(mgr)
+    def test_untraced_caller_is_tiled_from_its_engine_stages(self, recorder, metrics):
+        tracing.close_ttft(None, 2.0, [("inbox_wait", 1.0, 1.25, {}),
+                                       ("slot_wait", 1.25, 1.5, {}),
+                                       ("prefill", 1.5, 2.0, {})])
+        assert _stage_counts(metrics) == dict.fromkeys(TTFT_STAGES, 1)
+        assert recorder.records() == []
 
-        @trace_function("my.async")
-        async def mul(a, b):
-            return a * b
+    def test_unknown_stage_raises_at_the_writer(self, metrics):
+        with pytest.raises(KeyError, match="unknown stage"):
+            tile_ttft({"pool_wait": 0.1, "emebd": 0.2}, 1.0)
+        with pytest.raises(KeyError, match="unknown stage"):
+            tile_ttft({"other": 0.1}, 1.0)  # the residual is never written
+        with pytest.raises(KeyError, match="unknown stage"):
+            metrics.record_request_stage("queue", 0.1)
+        assert _stage_counts(metrics) == {}
 
-        assert asyncio.run(mul(2, 3)) == 6
-        assert mgr.spans[0][0] == "my.async"
+    def test_stream_lag_counts_from_the_oldest_uncovered_put(self, recorder, metrics):
+        recorder.start_request("s")
+        recorder.note_stream_put("s", 10.0)
+        recorder.note_stream_put("s", 10.2)  # coalesces into the same write
+        assert recorder.take_stream_lag("s", 10.5) == pytest.approx(0.5)
+        assert recorder.take_stream_lag("s", 10.6) is None  # nothing pending
+        recorder.note_stream_put("s", 11.0)
+        assert recorder.take_stream_lag("s", 11.1) == pytest.approx(0.1)
+        assert recorder.get("s")["stream_lag_max_ms"] == pytest.approx(500.0)
+        recorder.note_stream_put("s", 12.0)
+        tracing.stream_written("s")
+        assert _stage_counts(metrics) == {"stream_lag": 1}
+        recorder.note_stream_put("s", 13.0)
+        recorder.finish_request("s")  # an unwritten put does not outlive the request
+        assert recorder.take_stream_lag("s", 14.0) is None
 
-    def test_default_name_and_explicit_manager(self):
-        mgr = RecordingManager()
 
-        @trace_function(manager=mgr)
-        def named():
-            return 1
+class TestExecutorSpans:
+    def _graph(self, **kw):
+        from sentio_tpu.graph.executor import END, GraphBuilder
 
-        assert named() == 1
-        assert named.__name__ == "named"
-        assert "named" in mgr.spans[0][0]
+        return (
+            GraphBuilder()
+            .add_node("alpha", lambda state: {"metadata": {"replica_id": 1}})
+            .add_node("beta", lambda state: {"metadata": {"b": tracing.current()}}, **kw)
+            .add_edge("alpha", "beta")
+            .add_edge("beta", END)
+            .set_entry("alpha")
+            .compile()
+        )
 
-    def test_set_tracing_reset(self):
-        mgr = RecordingManager()
-        set_tracing(mgr)
-        assert get_tracing() is mgr
-        set_tracing(None)
-        fresh = get_tracing()
-        assert fresh is not mgr
-        assert isinstance(fresh, TracingManager)
+    def test_node_spans_carry_the_request_and_the_replica(self, recorder):
+        state = self._graph().invoke({"metadata": {"query_id": "req-42"}})
+        assert state["metadata"]["b"] == ("req-42", "graph.beta")
+        spans = recorder.get("req-42")["spans"]
+        assert [sp["name"] for sp in spans] == ["request", "graph.alpha", "graph.beta"]
+        assert spans[1]["fields"] == {"replica_id": -1}
+        assert spans[2]["fields"] == {"replica_id": 1}  # stamped upstream
+
+    def test_no_query_id_no_record(self, recorder):
+        state = self._graph().invoke({"metadata": {}})
+        assert state["metadata"]["b"] == (None, "graph.beta")
+        assert recorder.records() == []
+
+    def test_detached_node_span(self, recorder):
+        from sentio_tpu.graph.executor import wait_detached
+
+        self._graph(detached=True).invoke({"metadata": {"query_id": "req-44"}})
+        assert wait_detached(timeout_s=10)
+        beta = [sp for sp in recorder.get("req-44")["spans"] if sp["name"] == "graph.beta"]
+        assert len(beta) == 1 and beta[0]["fields"]["detached"] is True
+
+    def test_node_failure_keeps_its_type_through_the_span(self, recorder):
+        from sentio_tpu.graph.executor import END, GraphBuilder
+
+        class Shed(Exception):
+            soft_fail_exempt = True
+
+        def boom(state):
+            raise Shed("typed")
+
+        graph = (GraphBuilder().add_node("boom", boom).add_edge("boom", END)
+                 .set_entry("boom").compile())
+        with pytest.raises(Shed):
+            graph.invoke({"metadata": {"query_id": "req-45"}})
+        assert recorder.get("req-45")["spans"][1]["name"] == "graph.boom"
+
+
+class TestPump:
+    def test_failed_tick_propagates_the_original_type_to_containment(
+            self, recorder, metrics, caplog):
+        """A tick that raises inside the decode_tick annotation reaches the
+        pump's crash containment as itself (the log names the type), the
+        failed tick is recorded, and the requeued ticket still finishes
+        with its stages closed."""
+        from sentio_tpu.infra import faults
+
+        class DeviceFault(RuntimeError):
+            pass
+
+        svc = _tiny_service()
+        try:
+            with faults.inject("paged.step", error=DeviceFault("hbm"), times=1):
+                with caplog.at_level("ERROR", logger="sentio_tpu.runtime.service"):
+                    result = svc.generate("pump probe", max_new_tokens=3,
+                                          request_id="pump-1", timeout_s=120)
+        finally:
+            faults.reset()
+            svc.close()
+        assert result.finish_reason in ("stop", "length")
+        raised = [r.exc_info[0] for r in caplog.records if r.exc_info]
+        assert DeviceFault in raised
+        assert [e for e in recorder.timeline() if e.get("event") == "tick_failure"]
+        names = [sp["name"] for sp in recorder.get("pump-1")["spans"]]
+        assert names == ["request", "inbox_wait", "slot_wait", "prefill", "decode"]
+
+    def test_tick_event_names_its_annotations_step(self, recorder, metrics):
+        svc = _tiny_service()
+        try:
+            svc.generate("step probe", max_new_tokens=6, timeout_s=120)
+        finally:
+            svc.close()
+        ticks = [e for e in recorder.timeline() if "phase_ms" in e]
+        assert ticks and all(e["step"] == e["tick"] for e in ticks)
 
 
 class TestProfileWindow:
@@ -199,6 +289,7 @@ class TestProfileWindow:
         out = profile_window(0.01, str(tmp_path))
         assert out["started"] is True
         assert out["log_dir"] == str(tmp_path)
+        assert out["python_tracer"] is False
 
     def test_single_flight(self, tmp_path, monkeypatch):
         """The jax profiler is process-global: a second concurrent window
@@ -214,102 +305,45 @@ class TestProfileWindow:
         monkeypatch.setattr(tracing_mod, "_profile_active", False)
         assert profile_window(0.01, str(tmp_path))["started"] is True
 
+    def test_window_holds_ticks_phases_and_stages_without_python_frames(
+            self, tmp_path, recorder, metrics):
+        """What a chip run's ``/debug/profile`` window must hold, on the
+        CPU: ``decode_tick`` steps carrying the flight tick number,
+        ``tick.<phase>`` events and request-stage events on ``/host:CPU``,
+        no Python frame, and ``benchmark/trace.py::load_events`` (as it
+        stands) reads them — so ``label_gap`` names idle gaps by them."""
+        from jax.profiler import ProfileData
 
-class TestExecutorSpans:
-    def _graph(self):
-        from sentio_tpu.graph.executor import END, GraphBuilder
+        sys.path.insert(0, str(REPO))
+        from benchmark.trace import find_xplane, load_events
 
-        def a(state):
-            return {"metadata": {"a_ran": True}}
-
-        def b(state):
-            return {"metadata": {"replica_id": 1}}
-
-        return (
-            GraphBuilder()
-            .add_node("alpha", a)
-            .add_node("beta", b)
-            .add_edge("alpha", "beta")
-            .add_edge("beta", END)
-            .set_entry("alpha")
-            .compile()
-        )
-
-    def test_node_spans_with_request_id(self):
-        mgr = RecordingManager()
-        set_tracing(mgr)
-        graph = self._graph()
-        state = graph.invoke({"metadata": {"query_id": "req-42"}})
-        assert state["metadata"]["a_ran"] is True
-        names = [n for n, _ in mgr.spans]
-        assert names == ["graph.alpha", "graph.beta"]
-        for _, attrs in mgr.spans:
-            assert attrs["request_id"] == "req-42"
-        # replica_id stamped by an upstream node rides later spans
-        assert mgr.spans[0][1]["replica_id"] == -1
-
-    def test_tracing_off_no_spans(self):
-        mgr = RecordingManager(enabled=False)
-        set_tracing(mgr)
-        graph = self._graph()
-        graph.invoke({"metadata": {"query_id": "req-43"}})
-        assert mgr.spans == []
-
-    def test_detached_node_span(self):
-        from sentio_tpu.graph.executor import (
-            END,
-            GraphBuilder,
-            wait_detached,
-        )
-
-        mgr = RecordingManager()
-        set_tracing(mgr)
-        done = threading.Event()
-
-        def audit(state):
-            done.set()
-            return None
-
-        graph = (
-            GraphBuilder()
-            .add_node("audit", audit, detached=True)
-            .add_edge("audit", END)
-            .set_entry("audit")
-            .compile()
-        )
-        graph.invoke({"metadata": {"query_id": "req-44"}})
-        assert wait_detached(timeout_s=10)
-        assert done.wait(1)
-        names = [n for n, _ in mgr.spans]
-        assert "graph.audit" in names
-        attrs = dict(mgr.spans)["graph.audit"]
-        assert attrs["detached"] is True
-        assert attrs["request_id"] == "req-44"
-
-
-class TestPumpProfileStep:
-    def test_tick_step_annotation_when_enabled(self):
-        """With tracing enabled the pump wraps every engine tick in
-        profile_step (step = tick number) so XLA device traces line up
-        with flight ticks; with tracing off (the default elsewhere in this
-        suite) the pump never touches the manager."""
-        from sentio_tpu.runtime.paged import ContinuousBatchingEngine
-        from sentio_tpu.runtime.service import PagedGenerationService
-
-        mgr = RecordingManager()
-        set_tracing(mgr)
-        eng = ContinuousBatchingEngine(
-            max_slots=2, page_size=16, max_pages_per_seq=4,
-            steps_per_tick=4, max_tick_steps=4,
-        )
-        svc = PagedGenerationService(eng)
+        svc = _tiny_service()
         try:
-            result = svc.generate("hello", max_new_tokens=4)
-            assert result.tokens is not None
+            svc.generate("warm the programs", max_new_tokens=6, timeout_s=300)
+            window = threading.Thread(
+                target=profile_window, args=(1.5, str(tmp_path)), name="profile-window")
+            window.start()
+            import time
+
+            time.sleep(0.3)
+            svc.generate("inside the window", max_new_tokens=6,
+                         request_id="in-window", timeout_s=120)
+            window.join(timeout=60)
+            assert not window.is_alive()
         finally:
             svc.close()
-        assert mgr.steps, "no profile_step annotations recorded"
-        names = {n for n, _ in mgr.steps}
-        assert names == {"decode_tick"}
-        steps = [s for _, s in mgr.steps]
-        assert steps == sorted(steps)  # step numbers are the tick sequence
+        xplane = find_xplane(tmp_path)
+        host = [p for p in ProfileData.from_file(str(xplane)).planes
+                if p.name == "/host:CPU"]
+        assert host, "no /host:CPU plane"
+        events = [ev for line in host[0].lines for ev in line.events]
+        steps = [dict(ev.stats) for ev in events if ev.name == "decode_tick"]
+        assert steps and all("step_num" in s for s in steps)
+        flight_ticks = {e["tick"] for e in recorder.timeline()}
+        assert {int(s["step_num"]) for s in steps} <= flight_ticks | {max(flight_ticks) + 1}
+        prefill = [dict(ev.stats) for ev in events if ev.name == "prefill"]
+        assert any(s.get("request_id") == "in-window" for s in prefill)
+        assert not [ev.name for ev in events if ev.name.startswith("$")], "Python frames"
+        names = {name for name, _s, _d, _line in load_events(xplane)["host"]}
+        assert "decode_tick" in names
+        assert {f"tick.{p}" for p in TICK_PHASES if p != "other"} <= names
