@@ -1974,20 +1974,21 @@ def phase_d_kernels():
         flash_fn = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
         out["prefill_attn_flash_ms"] = round(timeit(flash_fn, q, k, v), 2)
 
-    # paged decode attention: 8 rows, 128-page pool, 16-token pages
+    # paged decode attention: 8 rows, layer 1 of a 2-layer 513-page pool,
+    # 16-token pages
     bb, hh, hkv, dd, page, nb, pool = 8, 8, 4, 64, 16, 64, 513
     qd = jnp.asarray(rng.standard_normal((bb, hh, dd)), jnp.bfloat16)
-    kp = jnp.asarray(rng.standard_normal((pool, page, hkv, dd)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((pool, page, hkv, dd)), jnp.bfloat16)
+    kp = jnp.asarray(rng.standard_normal((2, pool, page, hkv, dd)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((2, pool, page, hkv, dd)), jnp.bfloat16)
     pt = jnp.asarray(rng.integers(1, pool, (bb, nb)), jnp.int32)
     lens = jnp.asarray(rng.integers(64, nb * page - 1, (bb,)), jnp.int32)
     gather_fn = jax.jit(
-        lambda q, k, v, t_, l_: _paged_attn_xla(q[:, None], k, v, t_, l_, hh // hkv)
+        lambda q, k, v, t_, l_: _paged_attn_xla(q[:, None], k, v, 1, t_, l_, hh // hkv)
     )
     out["paged_attn_xla_gather_ms"] = round(timeit(gather_fn, qd, kp, vp, pt, lens), 2)
     if on_tpu:
         out["paged_attn_pallas_ms"] = round(
-            timeit(lambda q, k, v, t_, l_: paged_attention(q, k, v, t_, l_),
+            timeit(lambda q, k, v, t_, l_: paged_attention(q, k, v, 1, t_, l_),
                    qd, kp, vp, pt, lens), 2,
         )
     log(f"phase D kernels: {out}")

@@ -1,16 +1,21 @@
 """Pallas paged attention (decode): attention over a page-table KV cache.
 
-The continuous-batching engine (runtime/paged.py) stores KV in a pool of
-fixed-size pages; at decode each row attends over its own scattered page
-list. The XLA fallback gathers pages into a contiguous window first — an
-HBM round-trip proportional to the whole window. This kernel instead walks
-the page table directly:
+The continuous-batching engine (runtime/paged.py) stores KV in ONE pool of
+fixed-size pages for all layers, ``[L, P, page, Hkv, D]``; at decode each
+row attends over its own scattered page list in one layer of it. The XLA
+path gathers those pages into a contiguous window first — an HBM round-trip
+proportional to the whole window. This kernel instead reads the pool where
+it lies:
 
-* the page table and row lengths ride **scalar prefetch**
-  (``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index_map picks the
-  *physical* page to DMA for grid step (row b, logical block i) —
-  ``page_table[b, i]`` — and only pages the row actually owns ever leave
-  HBM;
+* the page table, the row lengths and the layer index ride **scalar
+  prefetch** (``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index_map
+  picks layer AND *physical* page to DMA for grid step (row b, logical
+  block i) — ``pool[layer, page_table[b, i]]`` — and only pages the row
+  actually owns ever leave HBM. The kernel takes the whole pool: a
+  ``pool[layer]`` operand would be materialised by XLA (a custom call
+  cannot fuse its operand), a copy of every page of the layer per call.
+  The layer is a traced int32, not a constant closed over by the
+  index_map, so every layer of a decode program shares one kernel body;
 * grid ``(B, NB)`` with the page axis sequential, carrying the classic
   online-softmax (m, l, acc) recurrence in fp32 VMEM scratch;
 * GQA stays folded: q is viewed [Hkv, rep, D] and both dots batch over the
@@ -24,7 +29,7 @@ Two kernel variants share the grid/recurrence:
 * **bf16 pages** (``paged_attention``) — K/V page blocks DMA as-is;
 * **int8 pages** (``paged_attention_quant``) — the BlockSpecs DMA int8
   page blocks PLUS their bf16 per-vector scales (stored page-minor,
-  ``[Hkv, page]``, so a scale block is one lane-dense tile and needs no
+  ``[L, P, Hkv, page]``, so a scale block is one lane-dense tile and needs no
   in-kernel transpose; Mosaic has no float16 vector type on v5e) through
   the same scalar-prefetch index_map, and dequantization happens
   in-register in VMEM: q·(s·K) folds as (q·K)·s on the kv-head-batched score dot, and
@@ -55,8 +60,9 @@ __all__ = ["paged_attention", "paged_attention_quant", "make_paged_attn_impl"]
 def _paged_kernel(
     pt_ref,    # [B, NB] int32 scalar-prefetch — page table
     lens_ref,  # [B] int32 scalar-prefetch — current token index per row
+    layer_ref,  # [1] int32 scalar-prefetch — read by the index_maps only
     q_ref,     # [Hkv, rep, D]
-    k_ref,     # [page, Hkv, D] — the physical page chosen by index_map
+    k_ref,     # [page, Hkv, D] — the layer's physical page chosen by index_map
     v_ref,     # [page, Hkv, D]
     o_ref,     # [Hkv, rep, D]
     m_ref,     # [Hkv, rep, 1] fp32 scratch
@@ -113,36 +119,30 @@ def _paged_kernel(
         o_ref[:] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_attention(
-    q: jax.Array,           # [B, H, D] — one decode token per row
-    k_pages: jax.Array,     # [P, page, Hkv, D] — one layer's page pool
-    v_pages: jax.Array,     # [P, page, Hkv, D]
-    page_table: jax.Array,  # [B, NB] int32 physical page ids
-    lens: jax.Array,        # [B] int32 — index of the current token
-    *,
-    interpret: bool = False,
-) -> jax.Array:
-    """Decode attention over the paged pool → [B, H, D]."""
+def _row_block(bb, i, pt, ln, layer):
+    return (bb, 0, 0, 0)
+
+
+def _page_block(bb, i, pt, ln, layer):
+    return (layer[0], pt[bb, i], 0, 0, 0)
+
+
+def _scale_block(bb, i, pt, ln, layer):
+    return (layer[0], pt[bb, i], 0, 0)
+
+
+def _walk_pool(kernel, q, pools, pool_specs, layer, page_table, lens, interpret):
+    """The grid both variants share: (row, logical block) over ``pools``
+    (whole-pool operands with their BlockSpecs), q [B, H, D] → [B, H, D]."""
     b, h, d = q.shape
-    _, page, hkv, _ = k_pages.shape
+    hkv = pools[0].shape[3]
     rep = h // hkv
-    nb = page_table.shape[1]
-    sm_scale = 1.0 / float(np.sqrt(d))
-
-    # [B, H, D] → [B, Hkv, rep, D]: group query heads under their kv head
-    q4 = q.reshape(b, hkv, rep, d)
-
-    kernel = functools.partial(_paged_kernel, page=page, sm_scale=sm_scale)
+    row = pl.BlockSpec((None, hkv, rep, d), _row_block)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nb),
-        in_specs=[
-            pl.BlockSpec((None, hkv, rep, d), lambda bb, i, pt, ln: (bb, 0, 0, 0)),
-            pl.BlockSpec((None, page, hkv, d), lambda bb, i, pt, ln: (pt[bb, i], 0, 0, 0)),
-            pl.BlockSpec((None, page, hkv, d), lambda bb, i, pt, ln: (pt[bb, i], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, hkv, rep, d), lambda bb, i, pt, ln: (bb, 0, 0, 0)),
+        num_scalar_prefetch=3,
+        grid=(b, page_table.shape[1]),
+        in_specs=[row, *pool_specs],
+        out_specs=row,
         scratch_shapes=[
             pltpu.VMEM((hkv, rep, 1), jnp.float32),
             pltpu.VMEM((hkv, rep, 1), jnp.float32),
@@ -150,22 +150,47 @@ def paged_attention(
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(
+            kernel, page=pools[0].shape[2], sm_scale=1.0 / float(np.sqrt(d))),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), lens.astype(jnp.int32), q4, k_pages, v_pages)
+    )(
+        page_table.astype(jnp.int32), lens.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        # [B, H, D] → [B, Hkv, rep, D]: group query heads under their kv head
+        q.reshape(b, hkv, rep, d), *pools,
+    )
     return out.reshape(b, h, d)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_attention(
+    q: jax.Array,           # [B, H, D] — one decode token per row
+    k_pages: jax.Array,     # [L, P, page, Hkv, D] — the page pool, all layers
+    v_pages: jax.Array,     # [L, P, page, Hkv, D]
+    layer: jax.Array,       # int32 scalar — the layer whose pages are read
+    page_table: jax.Array,  # [B, NB] int32 physical page ids
+    lens: jax.Array,        # [B] int32 — index of the current token
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """Decode attention over one layer of the paged pool → [B, H, D]."""
+    _, _, page, hkv, d = k_pages.shape
+    pages = pl.BlockSpec((None, None, page, hkv, d), _page_block)
+    return _walk_pool(_paged_kernel, q, (k_pages, v_pages), (pages, pages),
+                      layer, page_table, lens, interpret)
 
 
 def _paged_kernel_quant(
     pt_ref,    # [B, NB] int32 scalar-prefetch — page table
     lens_ref,  # [B] int32 scalar-prefetch — current token index per row
+    layer_ref,  # [1] int32 scalar-prefetch — read by the index_maps only
     q_ref,     # [Hkv, rep, D]
-    kq_ref,    # [page, Hkv, D] int8 — the physical page chosen by index_map
+    kq_ref,    # [page, Hkv, D] int8 — the layer's physical page chosen by index_map
     ks_ref,    # [Hkv, page] bf16 — per-vector absmax scales for that page
     vq_ref,    # [page, Hkv, D] int8
     vs_ref,    # [Hkv, page] bf16
@@ -236,69 +261,38 @@ def _paged_kernel_quant(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_quant(
     q: jax.Array,           # [B, H, D] — one decode token per row
-    k_pages_q: jax.Array,   # [P, page, Hkv, D] int8 — one layer's page pool
-    k_scales: jax.Array,    # [P, Hkv, page] bf16 per-vector absmax scales
-    v_pages_q: jax.Array,   # [P, page, Hkv, D] int8
-    v_scales: jax.Array,    # [P, Hkv, page] bf16
+    k_pages_q: jax.Array,   # [L, P, page, Hkv, D] int8 — the page pool, all layers
+    k_scales: jax.Array,    # [L, P, Hkv, page] bf16 per-vector absmax scales
+    v_pages_q: jax.Array,   # [L, P, page, Hkv, D] int8
+    v_scales: jax.Array,    # [L, P, Hkv, page] bf16
+    layer: jax.Array,       # int32 scalar — the layer whose pages are read
     page_table: jax.Array,  # [B, NB] int32 physical page ids
     lens: jax.Array,        # [B] int32 — index of the current token
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """Decode attention over the int8-quantized paged pool → [B, H, D].
+    """Decode attention over one layer of the int8-quantized paged pool →
+    [B, H, D].
 
     Same grid/scalar-prefetch walk as :func:`paged_attention`; the int8
     payload and its scale pages DMA per grid step and dequantize in VMEM.
     """
-    b, h, d = q.shape
-    _, page, hkv, _ = k_pages_q.shape
-    rep = h // hkv
-    nb = page_table.shape[1]
-    sm_scale = 1.0 / float(np.sqrt(d))
-
-    q4 = q.reshape(b, hkv, rep, d)
-
-    kernel = functools.partial(_paged_kernel_quant, page=page, sm_scale=sm_scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nb),
-        in_specs=[
-            pl.BlockSpec((None, hkv, rep, d), lambda bb, i, pt, ln: (bb, 0, 0, 0)),
-            pl.BlockSpec((None, page, hkv, d), lambda bb, i, pt, ln: (pt[bb, i], 0, 0, 0)),
-            pl.BlockSpec((None, hkv, page), lambda bb, i, pt, ln: (pt[bb, i], 0, 0)),
-            pl.BlockSpec((None, page, hkv, d), lambda bb, i, pt, ln: (pt[bb, i], 0, 0, 0)),
-            pl.BlockSpec((None, hkv, page), lambda bb, i, pt, ln: (pt[bb, i], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, hkv, rep, d), lambda bb, i, pt, ln: (bb, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((hkv, rep, 1), jnp.float32),
-            pltpu.VMEM((hkv, rep, 1), jnp.float32),
-            pltpu.VMEM((hkv, rep, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(
-        page_table.astype(jnp.int32), lens.astype(jnp.int32),
-        q4, k_pages_q, k_scales, v_pages_q, v_scales,
-    )
-    return out.reshape(b, h, d)
+    _, _, page, hkv, d = k_pages_q.shape
+    pages = pl.BlockSpec((None, None, page, hkv, d), _page_block)
+    scales = pl.BlockSpec((None, None, hkv, page), _scale_block)
+    return _walk_pool(
+        _paged_kernel_quant, q, (k_pages_q, k_scales, v_pages_q, v_scales),
+        (pages, scales, pages, scales), layer, page_table, lens, interpret)
 
 
 def make_paged_attn_impl(interpret: bool | None = None, mesh=None):
     """Adapter with the ``paged_decode_forward(attn_impl=...)`` signature:
-    (q [B,1,H,D], k_pages_l, v_pages_l, page_table, lens, n_rep) → [B,1,H,D].
+    (q [B,1,H,D], k_pages, v_pages, layer, page_table, lens, n_rep) →
+    [B,1,H,D], the pools whole as ``runtime.paged.init_pool`` made them.
 
     Representation-aware: a plain array routes to the bf16 kernel, a
-    ``{"q", "s"}`` pytree (the ``kv_quant="int8"`` pool layer from
-    ``runtime.paged._layer_pages``) routes to the int8 kernel — so one
-    engine attn seam serves both pool representations.
+    ``{"q", "s"}`` pytree (the ``kv_quant="int8"`` pool) routes to the int8
+    kernel — so one engine attn seam serves both pool representations.
 
     With a ``mesh`` the kernel runs INSIDE ``shard_map`` over ``tp``: the
     pool is kv-head-sharded there (``runtime.paged.init_pool``) and query
@@ -309,16 +303,16 @@ def make_paged_attn_impl(interpret: bool | None = None, mesh=None):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
-    def impl(q, k_pages_l, v_pages_l, page_table, lens, n_rep):
-        if isinstance(k_pages_l, dict):
+    def impl(q, k_pages, v_pages, layer, page_table, lens, n_rep):
+        if isinstance(k_pages, dict):
             out = paged_attention_quant(
-                q[:, 0], k_pages_l["q"], k_pages_l["s"],
-                v_pages_l["q"], v_pages_l["s"],
-                page_table, lens, interpret=interpret,
+                q[:, 0], k_pages["q"], k_pages["s"],
+                v_pages["q"], v_pages["s"],
+                layer, page_table, lens, interpret=interpret,
             )
         else:
             out = paged_attention(
-                q[:, 0], k_pages_l, v_pages_l, page_table, lens,
+                q[:, 0], k_pages, v_pages, layer, page_table, lens,
                 interpret=interpret,
             )
         return out[:, None]
@@ -330,18 +324,19 @@ def make_paged_attn_impl(interpret: bool | None = None, mesh=None):
 
     from sentio_tpu.parallel.mesh import AXIS_TP
 
-    heads = P(None, None, AXIS_TP, None)  # q/out [B,1,H,D], pages [P,page,Hkv,D]
-    scales = P(None, AXIS_TP, None)       # [P, Hkv, page]
+    heads = P(None, None, AXIS_TP, None)         # q/out [B, 1, H, D]
+    pages = P(None, None, None, AXIS_TP, None)   # [L, P, page, Hkv, D]
+    scales = P(None, None, AXIS_TP, None)        # [L, P, Hkv, page]
 
-    def pages_spec(pages_l):
-        return {"q": heads, "s": scales} if isinstance(pages_l, dict) else heads
+    def pool_spec(pool):
+        return {"q": pages, "s": scales} if isinstance(pool, dict) else pages
 
-    def sharded_impl(q, k_pages_l, v_pages_l, page_table, lens, n_rep):
+    def sharded_impl(q, k_pages, v_pages, layer, page_table, lens, n_rep):
         return jax.shard_map(
             functools.partial(impl, n_rep=n_rep), mesh=mesh,
-            in_specs=(heads, pages_spec(k_pages_l), pages_spec(v_pages_l),
-                      P(), P()),
+            in_specs=(heads, pool_spec(k_pages), pool_spec(v_pages),
+                      P(), P(), P()),
             out_specs=heads, check_vma=False,
-        )(q, k_pages_l, v_pages_l, page_table, lens)
+        )(q, k_pages, v_pages, jnp.asarray(layer, jnp.int32), page_table, lens)
 
     return sharded_impl

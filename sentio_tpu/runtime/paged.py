@@ -149,25 +149,20 @@ def _page_write(pages, layer, page_ids, offsets, val):
     return pages.at[layer, page_ids, offsets].set(val)
 
 
-def _layer_pages(pages, layer):
-    if isinstance(pages, dict):
-        return {"q": pages["q"][layer], "s": pages["s"][layer]}
-    return pages[layer]
-
-
 def _page_dim(pages) -> int:
     return (pages["q"] if isinstance(pages, dict) else pages).shape[-3]
 
 
-def _gather_pages(pages_l, page_table, dtype):
-    """[P, page, Hkv, D](-repr) + table [B, NB] → dense [B, NB*page, Hkv, D]."""
-    if isinstance(pages_l, dict):
-        b, nb = page_table.shape
-        out = dequantize_pages(
-            pages_l["q"][page_table], pages_l["s"][page_table], dtype)
-        return out.reshape(b, nb * out.shape[2], *out.shape[3:])
+def _gather_pages(pages, layer, page_table, dtype):
+    """Pool [L, P, page, Hkv, D](-repr), a layer + table [B, NB] → that
+    layer's pages, dense [B, NB*page, Hkv, D]. One gather on (layer, page):
+    no ``pages[layer]`` is formed on the way."""
     b, nb = page_table.shape
-    kc = pages_l[page_table]
+    if isinstance(pages, dict):
+        kc = dequantize_pages(
+            pages["q"][layer, page_table], pages["s"][layer, page_table], dtype)
+    else:
+        kc = pages[layer, page_table]
     return kc.reshape(b, nb * kc.shape[2], *kc.shape[3:])
 
 
@@ -244,21 +239,22 @@ class PageAllocator:
 # ------------------------------------------------------------ device kernels
 
 
-def _paged_attn_xla(q, k_pages_l, v_pages_l, page_table, lens, n_rep):
+def _paged_attn_xla(q, k_pages, v_pages, layer, page_table, lens, n_rep):
     """Decode attention over a page table, XLA gather path.
 
-    q [B,1,H,D]; k/v_pages_l [P,page,Hkv,D]; page_table [B,NB]; lens [B].
-    Gathers each row's pages into a contiguous [B, NB*page, Hkv, D] window —
-    XLA fuses the gather into the attention when the window is modest; the
-    Pallas kernel in kernels/paged_attention.py walks the table in VMEM
-    instead and is preferred on TPU for large windows.
+    q [B,1,H,D]; k/v_pages [L,P,page,Hkv,D] (the whole pool, either repr);
+    layer int; page_table [B,NB]; lens [B]. Gathers each row's pages of that
+    layer into a contiguous [B, NB*page, Hkv, D] window — XLA fuses the
+    gather into the attention when the window is modest; the Pallas kernel
+    in kernels/paged_attention.py walks the table in VMEM instead and is
+    what runs on TPU.
     """
     import jax.numpy as jnp
 
     from sentio_tpu.models import layers as L
 
-    kc = _gather_pages(k_pages_l, page_table, q.dtype)
-    vc = _gather_pages(v_pages_l, page_table, q.dtype)
+    kc = _gather_pages(k_pages, layer, page_table, q.dtype)
+    vc = _gather_pages(v_pages, layer, page_table, q.dtype)
     window = kc.shape[1]
     kc = L.repeat_kv(kc, n_rep)
     vc = L.repeat_kv(vc, n_rep)
@@ -311,11 +307,10 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
         k_pages = _page_write(k_pages, i, page_ids, offsets, k[:, 0].astype(dt))
         v_pages = _page_write(v_pages, i, page_ids, offsets, v[:, 0].astype(dt))
 
+        # the attention takes the pool whole and the layer's index: a
+        # pages[i] handed to a kernel is a copy of the layer's every page
         impl = attn_impl or _paged_attn_xla
-        out = impl(
-            q, _layer_pages(k_pages, i), _layer_pages(v_pages, i),
-            page_table, lens, h // hkv,
-        )
+        out = impl(q, k_pages, v_pages, i, page_table, lens, h // hkv)
         x = x + L.dense(lp["attn"]["wo"], out.reshape(b, 1, cfg.dim), dt)
 
         xm = L.rmsnorm(lp["mlp_norm"], x, cfg.norm_eps)
@@ -762,14 +757,16 @@ class ContinuousBatchingEngine:
         self._lp_sum = np.zeros(max_slots, np.float32)
         self._lp_min = np.zeros(max_slots, np.float32)
         self._lp_cnt = np.zeros(max_slots, np.int32)
-        # Pallas paged-attention kernel walks page tables in VMEM on TPU;
-        # the XLA gather path is what runs elsewhere (the CPU test path).
-        # This is a SELECTION by backend, made once: nothing catches a
-        # kernel failure and carries on with the other path. Under a mesh
-        # the kernel runs inside shard_map over tp (heads-sharded pool).
-        # The kernel is representation-aware: int8 pools route to the quant
-        # variant (int8 pages + bf16 scales DMA'd per block, dequantized
-        # in-register), so kv_quant="int8" keeps the fast path
+        # Pallas paged-attention kernel walks page tables in VMEM on TPU,
+        # reading the [L, P, ...] pool where it lies (layer and physical
+        # page picked per block); the XLA gather path is what runs
+        # elsewhere (the CPU test path). This is a SELECTION by backend,
+        # made once: nothing catches a kernel failure and carries on with
+        # the other path. Under a mesh the kernel runs inside shard_map
+        # over tp (heads-sharded pool). The kernel is representation-aware:
+        # int8 pools route to the quant variant (int8 pages + bf16 scales
+        # DMA'd per block, dequantized in-register), so kv_quant="int8"
+        # keeps the fast path
         if use_pallas is None:
             use_pallas = jax.default_backend() == "tpu"
         self._attn_impl = None

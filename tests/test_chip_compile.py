@@ -11,6 +11,7 @@ compiler accepts the program.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +25,12 @@ from sentio_tpu.kernels.paged_attention import (
     paged_attention,
     paged_attention_quant,
 )
+from sentio_tpu.models.llama import LlamaConfig, init_llama
 from sentio_tpu.parallel.mesh import MESH_AXES
+from sentio_tpu.runtime.paged import paged_decode_forward
 
 H, HKV, D = 32, 8, 128  # LlamaConfig.llama3_8b: 32 query / 8 KV heads of 128
+LAYERS = 2  # of the pool: the kernel takes it whole and a layer's index
 
 
 @pytest.fixture(scope="module")
@@ -56,13 +60,14 @@ def _paged_args(place, quant: bool, page: int, slots: int = 8, nb: int = 32,
     """Abstract arguments of one decode-attention call at serve geometry."""
     num_pages = 1 + slots * nb
     q = place((slots, H, D), jnp.bfloat16)
+    layer = place((), jnp.int32)
     table, lens = place((slots, nb), jnp.int32), place((slots,), jnp.int32)
     if not quant:
-        pages = place((num_pages, page, HKV, D), jnp.bfloat16, hkv_spec)
-        return q, pages, pages, table, lens
-    pages = place((num_pages, page, HKV, D), jnp.int8, hkv_spec)
-    scales = place((num_pages, HKV, page), jnp.bfloat16, scale_spec)
-    return q, pages, scales, pages, scales, table, lens
+        pages = place((LAYERS, num_pages, page, HKV, D), jnp.bfloat16, hkv_spec)
+        return q, pages, pages, layer, table, lens
+    pages = place((LAYERS, num_pages, page, HKV, D), jnp.int8, hkv_spec)
+    scales = place((LAYERS, num_pages, HKV, page), jnp.bfloat16, scale_spec)
+    return q, pages, scales, pages, scales, layer, table, lens
 
 
 def _on_one_chip(topo):
@@ -102,18 +107,18 @@ def _tp4_case(quant: bool):
                 shape, dtype, sharding=NamedSharding(mesh, spec or P()))
 
         args = _paged_args(place, quant, 128,
-                           hkv_spec=P(None, None, "tp", None),
-                           scale_spec=P(None, "tp", None))
-        q, *pool, table, lens = args
+                           hkv_spec=P(None, None, None, "tp", None),
+                           scale_spec=P(None, None, "tp", None))
+        q, *pool, layer, table, lens = args
         q = place((q.shape[0], 1, H, D), q.dtype, P(None, None, "tp", None))
         impl = make_paged_attn_impl(interpret=False, mesh=mesh)
         if quant:
             kq, ks, vq, vs = pool
-            return (lambda q, kq, ks, vq, vs, t, n: impl(
-                q, {"q": kq, "s": ks}, {"q": vq, "s": vs}, t, n, H // HKV),
-                (q, kq, ks, vq, vs, table, lens))
-        return (lambda q, k, v, t, n: impl(q, k, v, t, n, H // HKV),
-                (q, *pool, table, lens))
+            return (lambda q, kq, ks, vq, vs, ly, t, n: impl(
+                q, {"q": kq, "s": ks}, {"q": vq, "s": vs}, ly, t, n, H // HKV),
+                (q, kq, ks, vq, vs, layer, table, lens))
+        return (lambda q, k, v, ly, t, n: impl(q, k, v, ly, t, n, H // HKV),
+                (q, *pool, layer, table, lens))
 
     return build
 
@@ -138,3 +143,74 @@ def test_kernel_compiles_for_v5e(v5e, case):
     compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip would
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{case}: the compiled program holds no Pallas kernel")
+
+
+def _pool_shaped(hlo_text: str, shapes: tuple) -> list:
+    """(name, shape, what made it) of every instruction of the compiled text
+    whose result is an array of one of ``shapes``. A fusion is named for its
+    body's root: ``fusion:scatter`` is the update in place."""
+    roots, body = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            body = head.group(1)
+        root = re.match(r"\s+ROOT %?[\w.\-]+ = \S+ ([\w\-]+)\(", line)
+        if root and body:
+            roots[body] = root.group(1)
+    found = []
+    for line in hlo_text.splitlines():
+        inst = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = (\w+\[[\d,]*\])\S* ([\w\-]+)\(", line)
+        if not inst or inst.group(2) not in shapes:
+            continue
+        name, shape, op = inst.groups()
+        if op == "fusion":
+            op = "fusion:" + roots.get(re.search(r"calls=%?([\w.\-]+)", line).group(1), "?")
+        found.append((name, shape, op))
+    return found
+
+
+def test_decode_step_reads_the_pool_where_it_lies(v5e):
+    """Two layers of ``paged_decode_forward`` in a scan over a donated pool,
+    as ``step_n`` runs them: beside the kernel, the only thing that may make
+    an array the size of the pool is the scatter that updates it in place,
+    and nothing makes one the size of a layer of it. The Pallas call cannot
+    fuse its operands, so a ``pages[layer]`` handed to it is a copy of every
+    page of the layer, per layer, per sub-step (35 % of the device's time
+    until PR 26)."""
+    cfg = LlamaConfig(n_layers=2)
+    # 16 slots of 18 pages, as the benchmark's mistral cell serves: 151 MB a
+    # pool. (One of 34 MB the compiler prefetches whole into fast memory.)
+    slots, nb, page = 16, 18, 128
+    place = _on_one_chip(v5e)
+    params = jax.tree_util.tree_map(
+        lambda a: place(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_llama(jax.random.PRNGKey(0), cfg)))
+    pool_shape = (cfg.n_layers, 1 + slots * nb, page, cfg.n_kv_heads, cfg.head_dim)
+    pool = place(pool_shape, jnp.bfloat16)
+    impl = make_paged_attn_impl(interpret=False)
+
+    def steps(params, tok, lens, table, k_pages, v_pages):
+        def body(carry, _):
+            tok, lens, k_pages, v_pages = carry
+            logits, k_pages, v_pages = paged_decode_forward(
+                params, cfg, tok, lens, table, k_pages, v_pages,
+                attn_impl=impl, write_mask=lens < nb * page - 1)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
+                    k_pages, v_pages), None
+
+        return jax.lax.scan(body, (tok, lens, k_pages, v_pages), None, length=2)[0]
+
+    text = jax.jit(steps, donate_argnums=(4, 5)).lower(
+        params, place((slots,), jnp.int32), place((slots,), jnp.int32),
+        place((slots, nb), jnp.int32), pool, pool).compile().as_text()
+
+    assert text.count('custom_call_target="tpu_custom_call"') == cfg.n_layers
+
+    def hlo_shape(dims):
+        return "bf16[" + ",".join(map(str, dims)) + "]"
+
+    made = _pool_shaped(text, (hlo_shape(pool_shape), hlo_shape(pool_shape[1:])))
+    in_place = {"parameter", "get-tuple-element", "scatter", "fusion:scatter"}
+    assert [m for m in made if m[2] not in in_place] == []
+    # K and V, each layer: the parse found the updates it is there to allow
+    assert sum(m[2] == "fusion:scatter" for m in made) == 2 * cfg.n_layers

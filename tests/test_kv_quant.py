@@ -11,7 +11,6 @@ from sentio_tpu.models.llama import LlamaConfig
 from sentio_tpu.runtime.paged import (
     ContinuousBatchingEngine,
     _gather_pages,
-    _layer_pages,
     _page_write,
     _paged_attn_xla,
     dequantize_kv,
@@ -59,7 +58,10 @@ class TestAttentionParity:
         lens = jnp.asarray([30, 55], jnp.int32)
 
         k16, v16, k8, v8 = pool16.k, pool16.v, pool8.k, pool8.v
-        # fill the referenced pages via the write helper (layer 0 suffices)
+        # fill the referenced pages of the LAST layer via the write helper;
+        # layer 0 stays zero, so reading the wrong layer shows
+        layer = cfg.n_layers - 1
+        assert layer > 0
         for row in range(b):
             for pos in range(int(lens[row]) + 1):
                 pid = table[row, pos // 16][None]
@@ -68,30 +70,33 @@ class TestAttentionParity:
                                  jnp.bfloat16)
                 vv = jnp.asarray(rng.standard_normal((1, cfg.n_kv_heads, cfg.head_dim)),
                                  jnp.bfloat16)
-                k16 = _page_write(k16, 0, pid, off, kv)
-                v16 = _page_write(v16, 0, pid, off, vv)
-                k8 = _page_write(k8, 0, pid, off, kv)
-                v8 = _page_write(v8, 0, pid, off, vv)
+                k16 = _page_write(k16, layer, pid, off, kv)
+                v16 = _page_write(v16, layer, pid, off, vv)
+                k8 = _page_write(k8, layer, pid, off, kv)
+                v8 = _page_write(v8, layer, pid, off, vv)
 
         q = jnp.asarray(rng.standard_normal((b, 1, cfg.n_heads, cfg.head_dim)),
                         jnp.bfloat16)
         n_rep = cfg.n_heads // cfg.n_kv_heads
-        out16 = _paged_attn_xla(q, _layer_pages(k16, 0), _layer_pages(v16, 0),
-                                table, lens, n_rep)
-        out8 = _paged_attn_xla(q, _layer_pages(k8, 0), _layer_pages(v8, 0),
-                               table, lens, n_rep)
+        out16 = _paged_attn_xla(q, k16, v16, layer, table, lens, n_rep)
+        out8 = _paged_attn_xla(q, k8, v8, layer, table, lens, n_rep)
         diff = float(jnp.abs(out16.astype(jnp.float32) - out8.astype(jnp.float32)).max())
         assert diff < 0.05, diff
+        assert float(jnp.abs(out16).max()) > 0.1
+        for k, v in ((k16, v16), (k8, v8)):
+            empty = _paged_attn_xla(q, k, v, 0, table, lens, n_rep)
+            assert float(jnp.abs(empty).max()) == 0.0
 
     def test_gather_dequantizes(self):
         cfg = LlamaConfig.tiny()
         pool8 = init_pool(cfg, num_pages=5, page_size=16, quantized=True)
         val = jnp.full((1, cfg.n_kv_heads, cfg.head_dim), 0.5, jnp.bfloat16)
-        k8 = _page_write(pool8.k, 0, jnp.asarray([2]), jnp.asarray([3]), val)
+        k8 = _page_write(pool8.k, 1, jnp.asarray([2]), jnp.asarray([3]), val)
         table = jnp.asarray([[2]], jnp.int32)
-        dense = _gather_pages(_layer_pages(k8, 0), table, jnp.bfloat16)
+        dense = _gather_pages(k8, 1, table, jnp.bfloat16)
         got = float(dense[0, 3, 0, 0])
         assert abs(got - 0.5) < 0.01
+        assert float(jnp.abs(_gather_pages(k8, 0, table, jnp.bfloat16)).max()) == 0.0
 
 
 class TestEngineWithInt8KV:

@@ -148,16 +148,23 @@ class TestPagedAttentionKernel:
 
         rng = np.random.default_rng(0)
         b, h, hkv, d, page, num_pages, nb = 3, 4, 2, 16, 8, 13, 4
+        layers, layer = 3, 2  # each layer its own values; the one read is not 0
         q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((num_pages, page, hkv, d)), jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((num_pages, page, hkv, d)), jnp.float32)
+        kp = jnp.asarray(
+            rng.standard_normal((layers, num_pages, page, hkv, d)), jnp.float32)
+        vp = jnp.asarray(
+            rng.standard_normal((layers, num_pages, page, hkv, d)), jnp.float32)
         # each row owns a distinct shuffled set of pages; varied lengths
         table = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8], [9, 10, 11, 12]], jnp.int32)
         lens = jnp.asarray([5, 17, 30], jnp.int32)
 
-        ref = _paged_attn_xla(q, kp, vp, table, lens, h // hkv)[:, 0]
-        got = paged_attention(q[:, 0], kp, vp, table, lens, interpret=True)
+        # the reference sees that layer alone, as a pool of one layer
+        ref = _paged_attn_xla(
+            q, kp[layer:layer + 1], vp[layer:layer + 1], 0, table, lens, h // hkv)[:, 0]
+        got = paged_attention(q[:, 0], kp, vp, layer, table, lens, interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
+        whole = _paged_attn_xla(q, kp, vp, layer, table, lens, h // hkv)[:, 0]
+        np.testing.assert_array_equal(np.asarray(whole), np.asarray(ref))
 
     def test_engine_with_kernel_matches_contiguous(self, cfg, contiguous):
         eng = ContinuousBatchingEngine(

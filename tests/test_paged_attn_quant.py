@@ -21,12 +21,17 @@ from sentio_tpu.kernels.paged_attention import (
 from sentio_tpu.runtime.paged import _paged_attn_xla, quantize_kv
 
 
+LAYERS, LAYER = 3, 2  # every pool has its own values in each layer; the
+# tests read one that is not 0, so a kernel that ignored its layer fails
+
+
 def _quant_pool(rng, num_pages, page, hkv, d):
-    k = jnp.asarray(rng.standard_normal((num_pages, page, hkv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((num_pages, page, hkv, d)), jnp.float32)
+    shape = (LAYERS, num_pages, page, hkv, d)
+    k = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    v = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
-    # the pool stores scales page-minor: [P, Hkv, page]
+    # the pool stores scales page-minor: [L, P, Hkv, page]
     return k, v, kq, ks.swapaxes(-1, -2), vq, vs.swapaxes(-1, -2)
 
 
@@ -50,10 +55,10 @@ class TestInt8KernelParity:
 
         ref = _paged_attn_xla(
             q[:, None], {"q": kq, "s": ks}, {"q": vq, "s": vs},
-            table, lens, h // hkv,
+            LAYER, table, lens, h // hkv,
         )[:, 0]
         got = paged_attention_quant(
-            q, kq, ks, vq, vs, table, lens, interpret=True)
+            q, kq, ks, vq, vs, LAYER, table, lens, interpret=True)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
@@ -67,9 +72,9 @@ class TestInt8KernelParity:
             jnp.int32)
         lens = jnp.asarray([13, 27], jnp.int32)
 
-        ref = paged_attention(q, k, v, table, lens, interpret=True)
+        ref = paged_attention(q, k, v, LAYER, table, lens, interpret=True)
         got = paged_attention_quant(
-            q, kq, ks, vq, vs, table, lens, interpret=True)
+            q, kq, ks, vq, vs, LAYER, table, lens, interpret=True)
         diff = float(jnp.abs(got - ref).max())
         assert diff < 0.05, diff  # absmax int8: ~1e-2 worst-case here
 
@@ -84,13 +89,13 @@ class TestInt8KernelParity:
         lens = jnp.asarray([10], jnp.int32)  # 3rd token of page 3
 
         clean = paged_attention_quant(
-            q, kq, ks, vq, vs, table, lens, interpret=True)
-        kq2 = kq.at[3, 4:].set(127)
-        ks2 = ks.at[3, :, 4:].set(100.0)
-        vq2 = vq.at[3, 4:].set(127)
-        vs2 = vs.at[3, :, 4:].set(100.0)
+            q, kq, ks, vq, vs, LAYER, table, lens, interpret=True)
+        kq2 = kq.at[LAYER, 3, 4:].set(127)
+        ks2 = ks.at[LAYER, 3, :, 4:].set(100.0)
+        vq2 = vq.at[LAYER, 3, 4:].set(127)
+        vs2 = vs.at[LAYER, 3, :, 4:].set(100.0)
         poisoned = paged_attention_quant(
-            q, kq2, ks2, vq2, vs2, table, lens, interpret=True)
+            q, kq2, ks2, vq2, vs2, LAYER, table, lens, interpret=True)
         np.testing.assert_array_equal(np.asarray(clean), np.asarray(poisoned))
 
     def test_single_row_single_page(self):
@@ -104,10 +109,10 @@ class TestInt8KernelParity:
 
         ref = _paged_attn_xla(
             q[:, None], {"q": kq, "s": ks}, {"q": vq, "s": vs},
-            table, lens, h // hkv,
+            LAYER, table, lens, h // hkv,
         )[:, 0]
         got = paged_attention_quant(
-            q, kq, ks, vq, vs, table, lens, interpret=True)
+            q, kq, ks, vq, vs, LAYER, table, lens, interpret=True)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
