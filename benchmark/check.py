@@ -1,4 +1,5 @@
-"""The reference check: the program's serving path against ``reference.py``.
+"""The reference check: the program's serving path against the plain
+reference of the configuration's family.
 
 ``python benchmark/check.py --config <file> --seed <n> [--rehearsal]`` runs
 as a child of ``run.py`` AFTER the server has exited (it takes the chip) and
@@ -6,33 +7,35 @@ prints one JSON object: ``{"ok": ..., "prefill_rel_rms": ..., ...}``.
 
 The program's own ``ContinuousBatchingEngine`` is built at the
 configuration's PUBLISHED WIDTHS with ``check.layers`` layers (2): every
-later check of every PR pays this, and its purpose is shapes and arithmetic —
-head geometry, the GQA ratio, RoPE's theta, the vocabulary, the page walk —
-which depth only compounds. Weights are made on the device from ``--seed``
+later check of every PR pays this, and its purpose is shapes and arithmetic,
+which depth only compounds. Everything that depends on the architecture is
+asked of ``families/<family>.py`` (its docstring has the contract): the
+program's config object, seeded parameters and which of their leaves are
+matrices, the reference module with its keyword arguments and parameter
+names, and the teacher-forced pieces. This file keeps the sequences, the
+comparison and the tolerances. Weights are made on the device from ``--seed``
 and rounded to bf16, as a checkpoint holds them. Two comparisons, both
 against the reference's full float32 forward over the whole sequence:
 
 1. LOGITS (``logits_part``). The served programs return sampled tokens and
    their log-probabilities, never logits, so the logits come from the pieces
-   those programs are made of, called with the engine's own kernel selection:
-   the admission forward writing a fresh cache, scattered into the engine's
-   page pool, and ``paged_decode_forward`` over that pool (the Pallas
-   page-table walk on a TPU), teacher-forced for a few steps, over token ids
-   drawn from the whole vocabulary. Held to ``rel_rms_tol`` (root-mean-square
-   error over the logits' own root mean square), and decode through the
-   pages may not be worse than ``decode_over_prefill_max`` times prefill: the
-   pages hold what prefill computed, so reading them back adds nothing but
-   a coarser page type.
+   those programs are made of (``family.paged_pieces``): a prefill that fills
+   the engine's pool and a decode step through the pool, teacher-forced for a
+   few steps, over token ids drawn from the whole vocabulary. Held to
+   ``rel_rms_tol`` (root-mean-square error over the logits' own root mean
+   square), and decode through the pool may not be worse than
+   ``decode_over_prefill_max`` times prefill: the pool holds what prefill
+   computed, so reading it back adds nothing but a coarser pool type.
 2. SERVED ANSWERS (``served_part``). Requests go through ``submit`` and
-   ``step``: admission, chunked prefill (``prior_prefill_scatter``, cold and
-   over a prior the radix cache served), ``merge_admitted`` and the fused
-   ``step_n`` ticks, two rows decoding together. Each answer's greedy tokens
-   are replayed through the reference: every served token must be the
-   reference's own choice or within ``token_gap_tol`` of it (logit gap over
-   the mean top logit; with random weights near-ties flip on rounding, so
-   tokens are never compared for equality), and the mean and the least
-   log-probability the engine reports for its tokens must agree with the
-   reference's within ``logprob_tol`` on the same scale.
+   ``step``: admission, chunked prefill (cold and over a prior the radix
+   cache served), the merge into the decode batch and the fused ticks, two
+   rows decoding together. Each answer's greedy tokens are replayed through
+   the reference: every served token must be the reference's own choice or
+   within ``token_gap_tol`` of it (logit gap over the mean top logit; with
+   random weights near-ties flip on rounding, so tokens are never compared
+   for equality), and the mean and the least log-probability the engine
+   reports for its tokens must agree with the reference's within
+   ``logprob_tol`` on the same scale.
 
 The tolerances stand in the configuration file's ``check`` block. Their
 reason is what the chip gave at published widths, both configurations alike
@@ -79,8 +82,9 @@ def rel_rms(got, want) -> float:
     return float(np.sqrt(np.mean((got - want) ** 2)) / (np.sqrt(np.mean(want ** 2)) + 1e-30))
 
 
-def degrade(tree, how: str):
-    """The program's matrices through a coarser type and back to bf16."""
+def degrade(tree, how: str, is_matrix):
+    """The program's matrices (the leaves the family calls so) through a
+    coarser type and back to bf16."""
     import jax
     import jax.numpy as jnp
 
@@ -93,21 +97,17 @@ def degrade(tree, how: str):
         return jax.lax.bitcast_convert_type(bits, jnp.bfloat16)
 
     def int8(a):
-        scale = jnp.max(jnp.abs(a.astype(jnp.float32)), axis=0, keepdims=True) / 127.0
+        # per column of each matrix (a stack of experts is matrices on its last two axes)
+        scale = jnp.max(jnp.abs(a.astype(jnp.float32)), axis=-2, keepdims=True) / 127.0
         return (jnp.round(a.astype(jnp.float32) / scale) * scale).astype(jnp.bfloat16)
 
     fn = {"weights_fp8": fp8, "weights_int8": int8}[how]
     return jax.jit(lambda t: jax.tree_util.tree_map(
-        lambda a: fn(a) if a.ndim == 2 else a, t))(tree)
+        lambda a: fn(a) if is_matrix(a) else a, t))(tree)
 
 
-def logits_part(engine, cfg, params, spec: dict, seed: int, ref_forward) -> dict:
-    import jax
-    import jax.numpy as jnp
+def logits_part(engine, cfg, params, spec: dict, seed: int, ref_forward, family) -> dict:
     import numpy as np
-
-    from sentio_tpu.models.llama import init_cache
-    from sentio_tpu.runtime.paged import paged_decode_forward, scatter_prefill
 
     page, steps = engine.page_size, int(spec["decode_steps"])
     prompt_lens = [int(n) for n in spec["prompt_tokens"]]
@@ -124,29 +124,14 @@ def logits_part(engine, cfg, params, spec: dict, seed: int, ref_forward) -> dict
     for r in range(rows):  # page 0 is the engine's scratch page
         table[r] = 1 + r * pages_per_seq + np.arange(pages_per_seq)
     positions = np.broadcast_to(np.arange(width, dtype=np.int32), ids.shape)
-    forward_fn, attn_impl = engine.forward_fn, engine._attn_impl
 
-    @jax.jit
-    def prefill(params, ids, positions, lens, scat, k_pages, v_pages):
-        cache = init_cache(cfg, rows, width)
-        pad = jnp.arange(width)[None, :] < lens[:, None]
-        logits, cache = forward_fn(params, cfg, ids, positions=positions, cache=cache,
-                                   cache_index=0, pad_mask=pad)
-        k_pages, v_pages = scatter_prefill(k_pages, v_pages, cache["k"], cache["v"], scat)
-        return logits, k_pages, v_pages
-
-    @jax.jit
-    def decode(params, tok, lens, table, k_pages, v_pages):
-        return paged_decode_forward(params, cfg, tok, lens, table, k_pages, v_pages,
-                                    attn_impl=attn_impl)
-
-    got_prefill, k_pages, v_pages = prefill(
-        params, ids, positions, lens, table[:, : width // page], engine.pool.k, engine.pool.v)
+    state, prefill, decode = family.paged_pieces(engine, cfg, rows, width)
+    got_prefill, state = prefill(params, ids, positions, lens, table[:, : width // page], state)
     got_prefill = np.asarray(got_prefill)
     got_decode = []
     for t in range(steps):
         tok = np.asarray([seq[n + t] for seq, n in zip(seqs, prompt_lens)], np.int32)
-        logits, k_pages, v_pages = decode(params, tok, lens + t, table, k_pages, v_pages)
+        logits, state = decode(params, tok, lens + t, table, state)
         got_decode.append(np.asarray(logits))
 
     got_p, want_p, got_d, want_d = [], [], [], []
@@ -223,18 +208,17 @@ def run_check(model: dict, spec: dict, seed: int, kv_quant: str = "none",
               tamper=None, variant: str = "none") -> dict:
     """``tamper(reference_kwargs)`` lets a test break the reference on
     purpose (wrong theta, ...); ``variant`` degrades the program's side."""
+    import importlib
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark import reference
     from benchmark.families import load_family
-    from sentio_tpu.models.llama import LlamaConfig, init_llama
     from sentio_tpu.runtime.paged import ContinuousBatchingEngine
 
     family = load_family(model)
-    model = {**model, "num_hidden_layers": int(spec["layers"])}
-    page = int(spec["page_size"])
+    layers, page = int(spec["layers"]), int(spec["page_size"])
     served = spec["served"]
     # the page window holds the longest sequence of either part; the engine
     # keeps the answer's length again in reserve before it truncates a prompt
@@ -244,44 +228,46 @@ def run_check(model: dict, spec: dict, seed: int, kv_quant: str = "none",
     # ``max_len`` positions into every decode program as a constant, 45 MB a
     # program at 32k positions, and a run's programs then outgrow a compile
     # cache held to 192 MiB, so that every run compiles anew (my chip runs, PR 24)
-    cfg = LlamaConfig(**{**family.program_config(model), "max_len": (longest // page + 1) * page})
+    max_len = (longest // page + 1) * page
+    cfg = family.check_config(model, layers, max_len)
 
     @jax.jit
     def make(key):
-        tree = init_llama(key, cfg)
+        tree = family.init_params(key, cfg)
         # what a checkpoint holds: matrices in bf16, norm scales in float32
         return jax.tree_util.tree_map(
-            lambda a: a.astype(jnp.bfloat16) if a.ndim == 2 else a, tree)
+            lambda a: a.astype(jnp.bfloat16) if family.is_matrix(a) else a, tree)
 
     params = make(jax.random.PRNGKey(seed % (2 ** 31)))
     engine = ContinuousBatchingEngine(
-        model_config=cfg, params=degrade(params, variant) if variant.startswith("weights") else params,
+        model_config=cfg,
+        params=degrade(params, variant, family.is_matrix) if variant.startswith("weights") else params,
         max_slots=max(len(spec["prompt_tokens"]), len(served["prompt_chars"]) - 1),
         page_size=page, max_pages_per_seq=longest // page + 1,
         steps_per_tick=int(served["steps_per_tick"]), prefill_chunk=int(served["prefill_chunk"]),
         prefix_cache=True, kv_quant="int8" if variant == "kv_int8" else kv_quant)
 
-    ref_kwargs = dict(n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
-                      rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps)
+    reference = importlib.import_module(family.REFERENCE)
+    ref_kwargs = family.reference_kwargs(model)
     if tamper is not None:
         ref_kwargs = tamper(ref_kwargs)
     ref_params = jax.tree_util.tree_map(
-        jnp.asarray, family.reference_params(jax.device_get(params), cfg.n_layers))
+        jnp.asarray, family.reference_params(jax.device_get(params), layers))
     ref_jit = jax.jit(lambda p, x: reference.forward(p, x, **ref_kwargs))
 
     def ref_forward(ids):
         # one compiled length for every sequence: attention is causal, so
         # what follows a sequence changes nothing before its end
-        padded = np.zeros(cfg.max_len, np.int32)
+        padded = np.zeros(max_len, np.int32)
         padded[: len(ids)] = ids
         return np.asarray(ref_jit(ref_params, jnp.asarray(padded)))[: len(ids)]
 
-    logits = logits_part(engine, cfg, engine.params, spec, seed, ref_forward)
+    logits = logits_part(engine, cfg, engine.params, spec, seed, ref_forward, family)
     answers = served_part(engine, served, seed, ref_forward)
     return {
         "ok": bool(logits.pop("ok") & answers.pop("ok")), **logits,
         **{f"served_{k}": v for k, v in answers.items()},
-        "layers": cfg.n_layers, "variant": variant, "kv_quant": engine.kv_quant,
+        "layers": layers, "variant": variant, "kv_quant": engine.kv_quant,
         "paged_attention": engine.stats().get("paged_attention"),
         "platform": jax.devices()[0].platform,
     }

@@ -1,20 +1,49 @@
 """Checkpoint family ``llama``: dense decoders that the program runs through
 ``LlamaConfig`` (RMSNorm, RoPE, GQA, SwiGLU, untied head) — Mistral, Yi.
 
-A family file gives the harness everything that depends on the architecture:
+A family file gives the harness everything that depends on the architecture;
+``check.py``, ``run.py``, ``readers.py`` and ``trace.py`` name no model, no
+config class and no kind of state a token leaves behind. The contract:
 
+for the served run (``run.py``)
 * ``program_config(model)`` — the published ``config.json`` keys of a
-  configuration file mapped onto the program's own config fields;
+  configuration file mapped onto the program's own config fields (``/info``
+  must report them);
 * ``write_checkpoint(path, model, seed)`` — seeded bf16 weights in the
   program's checkpoint format (``LLM_CHECKPOINT`` is the surface a user has);
-* ``decode_substep_cost`` — the bytes and floating-point operations the
-  algorithm needs, from shapes alone (the yardstick for the roofline share;
-  a later PR cannot edit it);
-* ``reference_params(tree)`` — the program's parameter tree renamed into the
-  flat names ``benchmark/reference.py`` takes.
+* ``pool_bytes(model, env)`` — the bytes ``/info`` must report for the pool
+  the server's environment asks for (here pages of K and V; a family with
+  state per slot, or a third stream per token, counts it here);
+
+for the reference check (``check.py``)
+* ``check_config(model, layers, max_len)`` — the program's config object (it
+  has a ``vocab_size``) at the check's depth and positions;
+* ``init_params(key, cfg)`` — the program's own seeded initialiser, and
+  ``is_matrix(leaf)`` — which leaves a checkpoint holds in bf16 (the cast,
+  and ``degrade``'s coarser types, follow it);
+* ``REFERENCE`` — the module of the plain reference (``forward(params, ids,
+  **kwargs)``), ``reference_kwargs(model)`` — what it takes from the
+  configuration (a test tampers with these), ``reference_params(tree,
+  n_layers)`` — the program's tree under the reference's flat names;
+* ``paged_pieces(engine, cfg, rows, width)`` — the teacher-forced pieces the
+  served programs are made of: what the engine's pool holds, a prefill that
+  fills it, a decode step that reads and extends it;
+
+for the rooflines (``readers.py``; the yardstick a later PR cannot edit)
+* ``decode_substep_cost(model, rows, context_tokens)`` — bytes and
+  operations of one decode sub-step of the whole model;
+* ``KERNEL_COSTS[name](model, rows, context_tokens)`` — bytes and operations
+  of ONE call of a named kernel (a metric file's ``cost`` names the entry).
+
+For this family the check's pieces are: the admission forward
+(``engine.forward_fn``) writing a fresh ``init_cache``, ``scatter_prefill`` of
+its K and V into the engine's page pool (``engine.pool.k``, ``engine.pool.v``),
+and ``paged_decode_forward`` over that pool with the engine's own kernel
+selection (the Pallas page-table walk on a TPU). The state a token leaves is
+K and V and nothing else. The reference is ``benchmark/reference.py``.
 
 Another family (``moe``) is another file beside this one, chosen by the
-``family`` key of the configuration file.
+``family`` key of the configuration file, with its own reference file.
 """
 
 from __future__ import annotations
@@ -156,26 +185,118 @@ def decode_substep_cost(model: dict, rows: int, context_tokens: float) -> dict:
     return {"bytes": float(bytes_), "flops": float(matmul + attn)}
 
 
-# -------------------------------------------------------------- reference
+def pool_bytes(model: dict, env: dict) -> int:
+    """The page pool the server's environment asks for: the scratch page and
+    ``slots x pages`` more, ``page`` tokens each, K and V of every layer."""
+    pages = 1 + int(env["LLM_MAX_BATCH"]) * int(env["KV_MAX_PAGES_PER_SEQ"])
+    return pages * int(env["KV_PAGE_SIZE"]) * kv_bytes_per_token(model)
+
+
+def paged_attention_cost(model: dict, rows: int, context_tokens: float) -> dict:
+    """ONE call of the decode attention kernel: one layer of one sub-step,
+    over ``rows`` slots whose occupied rows hold ``context_tokens`` tokens.
+
+    bytes: K and V of those tokens in this layer — what the rows HOLD, not
+    the pages their tables name. The queries in and the result out
+    (``rows x heads x head_dim``, twice, under 2 % of the K and V at the
+    cells' sizes) are left out, so the share reads a little low, never high.
+    flops: QK and PV over the context, 2 per multiply-add.
+    """
+    q = model["num_attention_heads"] * model["head_dim"]
+    bytes_ = context_tokens * kv_bytes_per_token(model) / model["num_hidden_layers"]
+    return {"bytes": float(bytes_), "flops": float(4 * context_tokens * q)}
+
+
+KERNEL_COSTS = {"paged_attention": paged_attention_cost}
+
+
+# ------------------------------------------------------ the reference check
+
+REFERENCE = "benchmark.reference"
+
+
+def check_config(model: dict, layers: int, max_len: int):
+    from sentio_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig(**{**program_config(model), "n_layers": layers, "max_len": max_len})
+
+
+def init_params(key, cfg) -> dict:
+    from sentio_tpu.models.llama import init_llama
+
+    return init_llama(key, cfg)
+
+
+def is_matrix(leaf) -> bool:
+    """What a checkpoint holds in bf16; norm scales stay float32."""
+    return leaf.ndim == 2
+
+
+def reference_kwargs(model: dict) -> dict:
+    return dict(n_heads=int(model["num_attention_heads"]),
+                n_kv_heads=int(model["num_key_value_heads"]),
+                head_dim=int(model["head_dim"]), rope_theta=float(model["rope_theta"]),
+                norm_eps=float(model["rms_norm_eps"]))
+
+
+def paged_pieces(engine, cfg, rows: int, width: int):
+    """→ ``(state, prefill, decode)``. ``state`` is what the engine's pool
+    holds (K pages, V pages); ``prefill(params, ids, positions, lens, blocks,
+    state)`` runs the admission forward over ``[rows, width]`` ids into a
+    fresh cache and scatters it into the pages ``blocks [rows, width/page]``
+    names, → ``(logits [rows, width, V], state)``; ``decode(params, tok,
+    lens, table, state)`` is one step through the pool with the engine's
+    kernel selection, → ``(logits [rows, V], state)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from sentio_tpu.models.llama import init_cache
+    from sentio_tpu.runtime.paged import paged_decode_forward, scatter_prefill
+
+    forward_fn, attn_impl = engine.forward_fn, engine._attn_impl
+
+    @jax.jit
+    def prefill(params, ids, positions, lens, blocks, state):
+        k_pages, v_pages = state
+        cache = init_cache(cfg, rows, width)
+        pad = jnp.arange(width)[None, :] < lens[:, None]
+        logits, cache = forward_fn(params, cfg, ids, positions=positions, cache=cache,
+                                   cache_index=0, pad_mask=pad)
+        return logits, scatter_prefill(k_pages, v_pages, cache["k"], cache["v"], blocks)
+
+    @jax.jit
+    def decode(params, tok, lens, table, state):
+        k_pages, v_pages = state
+        logits, k_pages, v_pages = paged_decode_forward(
+            params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=attn_impl)
+        return logits, (k_pages, v_pages)
+
+    return (engine.pool.k, engine.pool.v), prefill, decode
+
+
+def _f32(a) -> np.ndarray:
+    return np.asarray(a, np.float32)
+
+
+def reference_trunk(tree: dict) -> dict:
+    """Embedding, head and final norm under the reference's names, and an
+    empty ``layers`` (a family with this trunk and other layers fills it)."""
+    return {"embed": _f32(tree["embed_tokens"]["embedding"]),
+            "head": _f32(tree["lm_head"]["kernel"]),
+            "final_norm": _f32(tree["final_norm"]["scale"]), "layers": []}
+
+
+def reference_attention(lp: dict) -> dict:
+    """One layer's norms and attention matrices under the reference's names."""
+    return {"attn_norm": _f32(lp["attn_norm"]["scale"]), "mlp_norm": _f32(lp["mlp_norm"]["scale"]),
+            **{k: _f32(lp["attn"][k]["kernel"]) for k in ("wq", "wk", "wv", "wo")}}
 
 
 def reference_params(tree: dict, n_layers: int) -> dict:
     """The program's tree → the flat float32 names of ``reference.py``."""
-    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
-    out = {
-        "embed": f32(tree["embed_tokens"]["embedding"]),
-        "head": f32(tree["lm_head"]["kernel"]),
-        "final_norm": f32(tree["final_norm"]["scale"]),
-        "layers": [],
-    }
+    out = reference_trunk(tree)
     for i in range(n_layers):
         lp = tree[f"layers_{i}"]
-        out["layers"].append({
-            "attn_norm": f32(lp["attn_norm"]["scale"]),
-            "wq": f32(lp["attn"]["wq"]["kernel"]), "wk": f32(lp["attn"]["wk"]["kernel"]),
-            "wv": f32(lp["attn"]["wv"]["kernel"]), "wo": f32(lp["attn"]["wo"]["kernel"]),
-            "mlp_norm": f32(lp["mlp_norm"]["scale"]),
-            "w_gate": f32(lp["mlp"]["w_gate"]["kernel"]), "w_up": f32(lp["mlp"]["w_up"]["kernel"]),
-            "w_down": f32(lp["mlp"]["w_down"]["kernel"]),
-        })
+        out["layers"].append({**reference_attention(lp), **{
+            k: _f32(lp["mlp"][k]["kernel"]) for k in ("w_gate", "w_up", "w_down")}})
     return out

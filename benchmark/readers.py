@@ -107,6 +107,20 @@ def read_info(spec: dict, obs: Observations):
     return spec.get("scale", 1.0) * float(node)
 
 
+def decode_rows_and_context(obs: Observations):
+    """What the decode programs of the window worked on: the slots a
+    sub-step computes (all of them, occupied or not) and the tokens of
+    context the occupied rows held — the rows that shared a tick (sampled
+    at 2 Hz) times the mix's mean prompt plus half an answer; ``None`` where
+    no sample saw a tick. Every roofline of a decode program or kernel takes
+    its context from here, so two of them cannot disagree about it."""
+    occupied = read_prom_sample({"series": "sentio_tpu_serving_stat", "label": "stat",
+                                 "value": "tick_active_slots"}, obs)
+    lo, hi = obs.mix["shapes"]["prompt_tokens"]
+    per_row = (lo + hi) / 2 + int(obs.server_env["LLM_MAX_TOKENS"]) / 2
+    return int(obs.server_env["LLM_MAX_BATCH"]), None if occupied is None else occupied * per_row
+
+
 def read_trace(spec: dict, obs: Observations):
     if not obs.trace:
         return None
@@ -116,6 +130,18 @@ def read_trace(spec: dict, obs: Observations):
     stat = spec["stat"]
     if stat == "per_exec_p50_ms":
         return prog["p50_ms"]
+    if stat == "kernel_roofline":
+        from benchmark.roofline import least_time_s
+
+        # least time of ONE call of the named kernel, by the family's count of
+        # what the call must move and compute, over the kernel's median call
+        kernel = prog.get("kernels", {}).get(spec["kernel"])
+        cost_of = load_family(obs.model).KERNEL_COSTS.get(spec["cost"])
+        rows, context = decode_rows_and_context(obs)
+        if not kernel or cost_of is None or not context:
+            return None
+        least = least_time_s(cost_of(obs.model, rows, context), obs.device_kind)
+        return 100.0 * least["seconds"] * 1e6 / kernel["p50_us"]
     sub_steps = prog.get("sub_steps")
     if not sub_steps:
         return None
@@ -125,14 +151,8 @@ def read_trace(spec: dict, obs: Observations):
     if stat == "substep_roofline":
         from benchmark.roofline import least_time_s
 
-        # KV the kernel must read: the rows that shared a tick (sampled) times
-        # the mix's mean prompt plus half an answer
-        occupied = read_prom_sample({"series": "sentio_tpu_serving_stat", "label": "stat",
-                                     "value": "tick_active_slots"}, obs) or 0.0
-        lo, hi = obs.mix["shapes"]["prompt_tokens"]
-        context = occupied * ((lo + hi) / 2 + int(obs.server_env["LLM_MAX_TOKENS"]) / 2)
-        rows = int(obs.server_env["LLM_MAX_BATCH"])
-        cost = load_family(obs.model).decode_substep_cost(obs.model, rows, context)
+        rows, context = decode_rows_and_context(obs)
+        cost = load_family(obs.model).decode_substep_cost(obs.model, rows, context or 0.0)
         least = least_time_s(cost, obs.device_kind)
         return 100.0 * least["seconds"] * 1e3 / step_ms
     raise ValueError(f"unknown trace stat {stat!r}")

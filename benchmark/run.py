@@ -43,7 +43,7 @@ HERE = Path(__file__).resolve().parent
 REPO = HERE.parent
 sys.path.insert(0, str(REPO))
 
-from benchmark import readers, server, traffic  # noqa: E402
+from benchmark import readers, server, trace, traffic  # noqa: E402
 from benchmark.families import load_family  # noqa: E402
 from benchmark.server import BenchFailure  # noqa: E402
 
@@ -130,12 +130,6 @@ def server_environment(base_env: dict, config: dict, mix: dict, paths: dict) -> 
     return env
 
 
-def expected_pool_bytes(config: dict, env: dict) -> int:
-    family = load_family(config)
-    pages = 1 + int(env["LLM_MAX_BATCH"]) * int(env["KV_MAX_PAGES_PER_SEQ"])
-    return pages * int(env["KV_PAGE_SIZE"]) * family.kv_bytes_per_token(config)
-
-
 def family_compiles(rows) -> dict[str, int]:
     return {lab["family"]: int(val) for name, lab, val in rows
             if name == "sentio_tpu_xla_compiles_total" and "family" in lab}
@@ -146,7 +140,9 @@ def warm_up(srv: server.Server, mix: dict, seed: int) -> int:
     program the mix declares. Returns the requests sent."""
     send = lambda payload: server.chat_stream(srv.port, payload, mix["verifier"])  # noqa: E731
     sent = 0
-    for round_no in range(3):
+    # one round does it in 20 runs of 21 (my chip runs, PR 27): which row
+    # bucket an admission takes depends on how a burst falls across ticks
+    for round_no in range(5):
         for size in mix["warmup_bursts"]:
             # files from the far end of the corpus: the window starts at file 0
             requests = traffic.make_requests(
@@ -162,8 +158,13 @@ def warm_up(srv: server.Server, mix: dict, seed: int) -> int:
                  if have.get(k, 0) < v}
         if not short:
             break
+        note(phase="warm-up-short", round=round_no + 1, requests=sent, short=short)
     else:
-        raise BenchFailure(f"warm-up did not reach the mix's program set (have, want): {short}")
+        # what the server compiled, by shape: which bucket the bursts never made
+        lines = [ln[:300] for ln in srv.log_since(0).splitlines()
+                 if "ompil" in ln and any(k.split(".")[-1] in ln for k in short)]
+        raise BenchFailure(f"warm-up did not reach the mix's program set (have, want): {short}; "
+                           f"the server's compile lines for them: {lines[-12:]}")
     note(phase="warm-up", requests=sent, rounds=round_no + 1, compiled=have)
     return sent
 
@@ -276,7 +277,7 @@ def run(args) -> int:
                 "reranker": config["encoders"]["reranker"],
                 "embedder_dim": config["encoders"]["embedder_dim"],
                 "kv_quant": env["KV_QUANT"], "platform": probe["platform"],
-                "pool_hbm_bytes": expected_pool_bytes(config, env),
+                "pool_hbm_bytes": family.pool_bytes(config, env),
                 "corpus_size": len(docs), "chips": cell["chips"]}
         _info, info_problems = server.check_info(srv.port, want)
         obs.setup_s = time.perf_counter() - T_PROCESS_START
@@ -344,10 +345,9 @@ def run(args) -> int:
     if args.trace:
         if profile.get("status") != 200:
             raise BenchFailure(f"/debug/profile did not run: {profile}")
-        kernel = config.get("trace", {}).get("decode_kernel", "")
         trc, reduced = child_json(
             [sys.executable, str(HERE / "trace.py"), str(trace_dir),
-             "--layers", str(config["num_hidden_layers"]), "--kernel", kernel],
+             "--block", json.dumps(trace.kernel_block(config))],
             {**base_env, "JAX_PLATFORMS": "cpu"}, timeout=600.0)
         if trc != 0 or "programs" not in reduced:
             raise BenchFailure(f"trace reduction failed: {reduced}")
@@ -366,7 +366,8 @@ def run(args) -> int:
          ttft_p50_ms=traffic.percentile(client["ttft_ms"], 50),
          ttft_p90_ms=traffic.percentile(client["ttft_ms"], 90),
          ttft_max_ms=max(client["ttft_ms"], default=None),
-         stream_gap_max_ms=client["stream_gap_max_ms"], problems=problems)
+         stream_gap_max_ms=client["stream_gap_max_ms"],
+         answer_tokens_in_window=client["answer_tokens_in_window"], problems=problems)
 
     metrics: dict[str, dict] = {}
     kind = "per_layer" if args.trace else "end_to_end"

@@ -1,7 +1,8 @@
 """From a profiler trace (``*.xplane.pb``) to numbers.
 
-``python benchmark/trace.py <dir-or-file> [--layers N] [--kernel PATTERN]``
-prints one JSON object. The harness runs it as a child with
+``python benchmark/trace.py <dir-or-file> [--block JSON]`` prints one JSON
+object; the block is a configuration's ``trace`` block as ``kernel_block``
+reads it. The harness runs it as a child with
 ``JAX_PLATFORMS=cpu`` so that reading a trace can never touch the chip.
 
 What a trace written by ``GET /debug/profile`` on a TPU holds (looked at by
@@ -25,9 +26,11 @@ Reduction:
   averaged over the devices; window: first to last event of the trace;
 * programs: executions, total and median device time per program name;
   for a program whose executions hold a loop of sub-steps, the sub-steps
-  are counted from the kernel events inside each execution (``--kernel``
-  pattern, ``--layers`` kernel calls to a sub-step), over the executions
-  that lie whole inside the trace;
+  are counted from the kernel events inside each execution (the block's
+  ``substep_kernel`` pattern, ``calls_per_substep`` kernel calls to a
+  sub-step), over the executions that lie whole inside the trace; and for
+  each kernel the block names (``kernels``: name → pattern) its calls, their
+  total and their median time inside the program's executions;
 * breakdown: the device operations that took most time, grouped by
   operation name without its number (loops that contain other operations
   left out), and the longest idle gaps, each labelled with the innermost
@@ -160,7 +163,22 @@ def label_gap(gap: tuple[float, float], host: list[tuple]) -> str:
     return inner or best
 
 
-def reduce_events(events: dict, layers: int = 0, kernel: str = "") -> dict:
+def kernel_block(config: dict) -> dict:
+    """A configuration's ``trace`` block with its defaults: ``substep_kernel``
+    (the pattern of the kernel that counts sub-steps), ``calls_per_substep``
+    (that kernel's calls to one sub-step: one a layer unless the file says
+    otherwise — a model whose layers are not all of one kind says so) and
+    ``kernels`` (name → pattern: those reported one by one)."""
+    block = config.get("trace", {})
+    return {"substep_kernel": block.get("substep_kernel", ""),
+            "calls_per_substep": int(block.get("calls_per_substep",
+                                               config.get("num_hidden_layers", 0))),
+            "kernels": dict(block.get("kernels", {}))}
+
+
+def reduce_events(events: dict, block: dict | None = None) -> dict:
+    block = block or {}
+    per_step, kernel = int(block.get("calls_per_substep", 0)), block.get("substep_kernel", "")
     devices, host = events["devices"], events["host"]
     all_spans = [(s, s + d) for dev in devices.values() for _n, s, d in dev["ops"]]
     if not all_spans:
@@ -176,17 +194,27 @@ def reduce_events(events: dict, layers: int = 0, kernel: str = "") -> dict:
     first = next(iter(devices.values()))
 
     programs: dict[str, dict] = {}
-    kernel_re = re.compile(kernel) if kernel else None
-    kernel_starts = sorted(s for n, s, _d in first["ops"] if kernel_re and kernel_re.search(n))
+
+    def calls_of(pattern: str) -> tuple[list, list]:
+        calls = sorted((s, d) for n, s, d in first["ops"] if re.search(pattern, n))
+        return [s for s, _d in calls], [d for _s, d in calls]
+
+    kernel_starts = calls_of(kernel)[0] if kernel else []
+    named = {kname: calls_of(pattern) for kname, pattern in block.get("kernels", {}).items()}
     for name, start, dur in first["modules"]:
-        prog = programs.setdefault(program_name(name), {"durations_ms": [], "stepped": []})
+        prog = programs.setdefault(program_name(name),
+                                   {"durations_ms": [], "stepped": [], "kernel_ns": {}})
         prog["durations_ms"].append(dur / 1e6)
         whole = start > t_lo and start + dur < t_hi
-        if kernel_starts and layers and whole:
+        if kernel_starts and per_step and whole:
             lo, hi = bisect.bisect_left(kernel_starts, start), bisect.bisect_left(kernel_starts, start + dur)
-            if hi > lo and (hi - lo) % layers == 0:
-                prog["stepped"].append((dur / 1e6, (hi - lo) // layers))
+            if hi > lo and (hi - lo) % per_step == 0:
+                prog["stepped"].append((dur / 1e6, (hi - lo) // per_step))
+        for kname, (starts, durs) in named.items():
+            lo, hi = bisect.bisect_left(starts, start), bisect.bisect_left(starts, start + dur)
+            prog["kernel_ns"].setdefault(kname, []).extend(durs[lo:hi])
     for prog in programs.values():
+        kernel_ns = prog.pop("kernel_ns")
         durations = prog.pop("durations_ms")
         prog["count"] = len(durations)
         prog["total_ms"] = sum(durations)
@@ -195,6 +223,10 @@ def reduce_events(events: dict, layers: int = 0, kernel: str = "") -> dict:
         if stepped:
             prog["sub_steps"] = sum(n for _d, n in stepped)
             prog["sub_steps_ms"] = sum(d for d, _n in stepped)
+        kernels = {kname: {"calls": len(ns), "total_ms": sum(ns) / 1e6, "p50_us": _median(ns) / 1e3}
+                   for kname, ns in kernel_ns.items() if ns}
+        if kernels:
+            prog["kernels"] = kernels
 
     op_totals: dict[str, float] = {}
     for name, _s, dur in first["ops"]:
@@ -238,15 +270,16 @@ def cut_events(events: dict, start_ms: float, length_ms: float, min_op_us: float
     return {"devices": devices, "host": host}
 
 
-def reduce_xplane(path: Path, layers: int = 0, kernel: str = "") -> dict:
-    return reduce_events(load_events(find_xplane(path)), layers, kernel)
+def reduce_xplane(path: Path, block: dict | None = None) -> dict:
+    return reduce_events(load_events(find_xplane(path)), block)
 
 
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("path", type=Path)
-    parser.add_argument("--layers", type=int, default=0)
-    parser.add_argument("--kernel", default="")
+    parser.add_argument("--block", type=json.loads, default={},
+                        help='a configuration\'s trace block as JSON: {"substep_kernel": PATTERN, '
+                             '"calls_per_substep": N, "kernels": {NAME: PATTERN}}')
     parser.add_argument("--dump", type=int, default=0,
                         help="print the first N events of every line instead (looking by hand)")
     parser.add_argument("--cut", default="",
@@ -268,7 +301,7 @@ def main() -> int:
                 for ev in events[: args.dump]:
                     print("     ", ev.name[:90], ev.start_ns, ev.duration_ns, dict(ev.stats))
         return 0
-    print(json.dumps(reduce_xplane(args.path, args.layers, args.kernel)))
+    print(json.dumps(reduce_xplane(args.path, args.block)))
     return 0
 
 

@@ -240,7 +240,7 @@ def reduce_requests(requests: list[Request], t0: float, seconds: float) -> dict:
     ``failed`` and in no latency."""
     sent = [r for r in requests if r.result]
     ok = [r for r in sent if not r.result["problem"]]
-    ttft, tpot, pre, pairs = [], [], [], []
+    ttft, tpot, pre, pairs, answer = [], [], [], [], []
     tokens_in_window = 0
     longest_gap = 0.0
     for r in ok:
@@ -252,6 +252,7 @@ def reduce_requests(requests: list[Request], t0: float, seconds: float) -> dict:
         n_total = sum(token_count(text) for _t, text in pieces)
         n_after_first = n_total - token_count(first_text)
         last_t = pieces[-1][0]
+        answer.append((last_t - r.t_due) * 1e3)
         # tokens delivered AFTER the first event over the time after it: the
         # pump delivers a whole tick of tokens in one event, so the first
         # event's own tokens were produced before its timestamp
@@ -269,6 +270,7 @@ def reduce_requests(requests: list[Request], t0: float, seconds: float) -> dict:
         "failed": len(sent) - len(ok),
         "problems": sorted({r.result["problem"] for r in sent if r.result["problem"]})[:5],
         "ttft_ms": ttft, "tpot_ms": tpot, "pre_generate_ms": pre, "ttft_tpot_pairs": pairs,
+        "answer_ms": answer,
         "answer_tokens_in_window": tokens_in_window,
         "stream_gap_max_ms": longest_gap,
         "answer_tokens_per_request": percentile(

@@ -116,6 +116,9 @@ def test_end_to_end_metric(metric):
 @pytest.mark.parametrize("metric", BENCH["per_layer"], ids=lambda m: m["name"])
 def test_per_layer_metric_has_a_reader_and_moves_a_reported_metric(metric):
     assert set(metric) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
+    # stated, never implied: a metric without the list would be asked of every
+    # cell a later PR adds, and that PR refused where its cell cannot read it
+    assert metric.get("workloads") and all(c in CELLS for c in metric["workloads"])
     assert_reader_file("layer_metrics", metric["name"])
     moved = next(m for m in BENCH["end_to_end"] if m["name"] == metric["moves"])
     assert set(cells_of(metric)) <= set(cells_of(moved))

@@ -47,6 +47,23 @@ def test_mistral_bytes_and_flops_by_hand():
     assert bound["seconds"] == pytest.approx(cost["bytes"] / 819e9) and 0.0095 < bound["seconds"] < 0.0097
 
 
+def test_pool_and_one_kernel_call_by_hand():
+    m, y = CONFIGS["mistral-7b-v0.3-l16"], CONFIGS["yi-1.5-6b-l16"]
+    # the scratch page and 16 slots x 18 pages of 128 tokens, 64 KB a token
+    assert family.pool_bytes(m, {**m["serve_env"]}) == (1 + 16 * 18) * 128 * 65_536 == 2_424_307_712
+    assert family.pool_bytes(y, {**y["serve_env"]}) == (1 + 32 * 10) * 128 * 32_768
+    # one layer's K and V of the tokens the rows hold, and nothing of the table
+    cost = family.KERNEL_COSTS["paged_attention"](m, rows=16, context_tokens=4 * 1148)
+    assert cost == {"bytes": 4592 * 2 * 8 * 128 * 2, "flops": 4 * 4592 * 4096}
+    assert cost["bytes"] * m["num_hidden_layers"] == 4592 * family.kv_bytes_per_token(m)
+    bound = least_time_s(cost, "TPU v5 lite")
+    assert bound["bound"] == "bandwidth" and bound["seconds"] == pytest.approx(22.97e-6, rel=1e-3)
+    # 16 calls a sub-step hold exactly the context bytes the whole step counts
+    step = family.decode_substep_cost(m, 16, 4592)["bytes"] - family.decode_substep_cost(m, 16, 0)["bytes"]
+    assert step == 16 * cost["bytes"]
+    assert family.KERNEL_COSTS["paged_attention"](y, 32, 11 * 830)["bytes"] == 9130 * 2 * 4 * 128 * 2
+
+
 def test_yi_bytes_and_flops_by_hand():
     m = CONFIGS["yi-1.5-6b-l16"]
     params = 2 * 4096 * 4096 + 2 * 4096 * 512 + 3 * 4096 * 11008

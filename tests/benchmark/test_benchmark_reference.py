@@ -12,6 +12,9 @@ from benchmark.check import run_check
 
 REPO = Path(__file__).resolve().parents[2]
 CONFIGS = sorted((REPO / "benchmark" / "configs").glob("*.json"))
+# every configuration goes through its OWN family's pieces and reference; the
+# tampered terms below are the dense reference's
+DENSE = next(p for p in CONFIGS if json.loads(p.read_text())["family"] == "llama")
 
 
 def tiny(path):
@@ -41,7 +44,7 @@ def test_paged_engine_agrees_with_the_reference(path):
     ("norm eps", lambda kw: {**kw, "norm_eps": 1e-2}),
 ])
 def test_the_tolerance_catches_a_wrong_term(what, tamper):
-    model = tiny(CONFIGS[0])
+    model = tiny(DENSE)
     out = run_check(model, model["check"], seed=11, tamper=tamper)
     assert not out["ok"], (what, out)
     assert max(out["prefill_rel_rms"], out["decode_rel_rms"]) > 5 * out["tolerance"]
@@ -61,7 +64,7 @@ def test_the_tolerance_catches_weights_in_a_coarser_type(path):
 
 
 def test_int8_pages_are_a_variant_of_the_program_side_only():
-    model = tiny(CONFIGS[0])
+    model = tiny(DENSE)
     out = run_check(model, model["check"], seed=11, variant="kv_int8")
     plain = run_check(model, model["check"], seed=11)
     assert out["kv_quant"] == "int8" and plain["kv_quant"] == "none"

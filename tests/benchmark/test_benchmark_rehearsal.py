@@ -14,9 +14,9 @@ REPO = Path(__file__).resolve().parents[2]
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 
 
-def run_cell(cell: str, trace: int, extra: list[str], xla_flags: str):
+def run_cell(cell: str, trace: int, extra: list[str], xla_flags: str, more_env: dict | None = None):
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": xla_flags,
-           "JAX_ENABLE_COMPILATION_CACHE": "false", "BENCH_RUN": "ignored"}
+           "JAX_ENABLE_COMPILATION_CACHE": "false", "BENCH_RUN": "ignored", **(more_env or {})}
     proc = subprocess.run(
         [sys.executable, str(REPO / "benchmark" / "run.py"), "--workload", cell,
          "--seed", "2147483659", "--seconds", "3", "--trace", str(trace), *extra],
@@ -47,6 +47,59 @@ def test_committed_cell_on_one_virtual_device():
     # inside the window, /info equalled the file, the reference agreed
     assert window["problems"] == ["platform is cpu, not tpu (rehearsal)"], window
     assert window["answer_tokens_per_request"] == 96
+
+
+# loaded by every Python process of the run through PYTHONPATH: when the
+# program's paged engine is imported, the answers ``run_all`` hands back get
+# their first token altered — where the check's served answers are produced
+BREAK_THE_SERVED_TOKENS = '''
+import importlib.abc, importlib.util, sys
+
+class Hook(importlib.abc.MetaPathFinder):
+    def find_spec(self, name, path, target=None):
+        if name != "sentio_tpu.runtime.paged":
+            return None
+        sys.meta_path.remove(self)
+        spec = importlib.util.find_spec(name)
+        load = spec.loader.exec_module
+
+        def exec_module(module):
+            load(module)
+            engine = module.ContinuousBatchingEngine
+            run_all = engine.run_all
+
+            def altered(self, *args, **kwargs):
+                results = run_all(self, *args, **kwargs)
+                for res in results:
+                    res.tokens[0] = (res.tokens[0] + 1) % self.cfg.vocab_size
+                return results
+
+            engine.run_all = altered
+
+        spec.loader.exec_module = exec_module
+        return spec
+
+sys.meta_path.insert(0, Hook())
+'''
+
+
+def test_a_broken_timed_path_makes_the_run_incorrect(tmp_path):
+    """The whole run but the look for a chip, with the engine's answers
+    altered underneath: the reference check fails, the run names it among
+    its problems and on the error stream, and ``correct`` is false for THAT
+    reason (the unbroken rehearsal above has the platform as its only one)."""
+    (tmp_path / "sitecustomize.py").write_text(BREAK_THE_SERVED_TOKENS)
+    path = os.pathsep.join(filter(None, [str(tmp_path), os.environ.get("PYTHONPATH", "")]))
+    line, out = run_cell("yi6b-chat-closed", 0, [], "--xla_force_host_platform_device_count=1",
+                         {"PYTHONPATH": path})
+    assert line["correct"] is False and line["failed"] == 0 and line["attempted"] > 0
+    notes = [json.loads(ln) for ln in out.splitlines()[:-1] if ln.startswith("{")]
+    check = next(n for n in notes if n.get("phase") == "reference-check")
+    assert check["ok"] is False and check["served_token_gap"] > check["served_token_gap_tol"]
+    # the logits part never went through ``run_all``: it still agrees
+    assert max(check["prefill_rel_rms"], check["decode_rel_rms"]) <= check["tolerance"]
+    problems = next(n for n in notes if n.get("phase") == "window")["problems"]
+    assert len(problems) == 2 and any(p.startswith("reference check failed") for p in problems), problems
 
 
 def test_four_chip_cell_from_scratch_files_only(tmp_path):

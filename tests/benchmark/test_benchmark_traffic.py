@@ -69,6 +69,8 @@ def test_latency_is_taken_from_the_due_time_not_the_send_time():
     assert out["generator_late_ms_max"] == pytest.approx(3000.0)
     # 3 tokens after the first event's 2, one second later
     assert out["tpot_ms"] == pytest.approx([1000.0 / 3, 1000.0 / 3])
+    # due time -> last token event: the first token's wait and a second more
+    assert out["answer_ms"] == pytest.approx([6000.0, 4250.0])
     assert out["attempted"] == 2 and out["failed"] == 0
 
 
@@ -81,6 +83,7 @@ def test_failed_requests_count_in_no_latency():
                                   "problem": "no verifier verdict", "t_done": 9.0}
     out = traffic.reduce_requests([ok, bad], 0.0, 2.0)
     assert out["attempted"] == 2 and out["failed"] == 1 and len(out["ttft_ms"]) == 1
+    assert out["answer_ms"] == pytest.approx([1500.0])
     assert out["answer_tokens_in_window"] == 4  # the second piece came after the window
 
 
