@@ -29,6 +29,34 @@ for the reference check (``check.py``)
   served programs are made of: what the engine's pool holds, a prefill that
   fills it, a decode step that reads and extends it;
 
+* ``CHOICES`` (only a family whose forward decides something by RANK: the
+  experts a token goes to, the positions a query attends to) — ``{name:
+  keyword}``: each kind of choice, and the keyword of ``reference_kwargs``
+  that says how many the reference takes (``{"experts":
+  "experts_per_token"}``). A family without it, like this one, goes through
+  the check as it always did. With it (``check.py``'s docstring says why):
+  each piece of ``paged_pieces`` returns ``(logits, state, chosen)``,
+  ``chosen[name]`` int32 being what the PROGRAM chose in that call, a layer,
+  a position and ``k`` picks deep — prefill ``[layers, rows, width, k]``,
+  decode ``[layers, rows, k]``, a negative pick for none — read out of the
+  timed code's own routing, not computed beside it; ``REFERENCE.forward(
+  params, ids, forced=..., **kwargs)`` returns ``(logits, scores)``: with
+  ``forced[name] [layers, T, k]`` it takes those picks in place of its own
+  (gates renormalised over them, as published), with ``forced=None`` it is
+  left to its own, and ``scores[name] [layers, T, candidates]`` are its own
+  float32 scores on the trajectory it ran (``-inf`` for a candidate a
+  position may not take; any monotone form of what it ranks). Several names
+  a layer and any depth go through the same code; experts are what is
+  proven (``tests/benchmark``). The configuration's ``check`` block then
+  holds ``choice_disagree_max`` and ``choice_margin_max`` with the readings
+  they came from;
+* ``served(engine, prompts, max_new_tokens)`` (optional, with ``CHOICES``) →
+  ``(results, picks)``: the requests through ``engine.run_all`` and nothing
+  else of the engine, asking it for what each REQUEST was routed by, one
+  ``{name: [layers, prompt + answer tokens - 1, k]}`` a result, negative
+  where it cannot say. Without it the check replays each answer through the
+  prefill piece, which one seed in twelve does not survive (``check.py``);
+
 for the rooflines (``readers.py``; the yardstick a later PR cannot edit)
 * ``decode_substep_cost(model, rows, context_tokens)`` — bytes and
   operations of one decode sub-step of the whole model;

@@ -329,12 +329,19 @@ def run(args) -> int:
                         f"answers x {per_request}: some came from the contiguous engine")
     if rc != 0:
         problems.append(f"server exit code {rc} on SIGTERM")
+    if args.trace:  # for a reader of ``tick_host_share``: which phase its seconds were
+        series = "sentio_tpu_tick_phase_seconds_sum"
+        note(phase="tick-phases", seconds={
+            phase: round(server.series_value(obs.prom_after, series, {"phase": phase})
+                         - (server.series_value(obs.prom_before, series, {"phase": phase}) or 0.0), 4)
+            for phase in sorted({labels["phase"] for name, labels, _ in obs.prom_after if name == series})})
     if client["failed"]:
         note(phase="failed-requests", count=client["failed"], examples=client["problems"])
 
     check_cmd = [sys.executable, str(HERE / "check.py"), "--config", str(resolved["config_path"]),
                  "--seed", str(args.seed)] + (["--rehearsal"] if rehearsal else [])
     check_rc, check = child_json(check_cmd, base_env, timeout=900.0)
+    compared = check.pop("compared", {})
     note(phase="reference-check", rc=check_rc, **check)
     if check_rc != 0 or not check.get("ok"):
         problems.append(f"reference check failed: {check}")
@@ -379,9 +386,15 @@ def run(args) -> int:
               "failed": client["failed"], "metrics": metrics, "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
-    if problems:  # where a caller keeps only the end of the error stream
+    # each number the reference check compared, beside its limit: last in the
+    # result and last on the error stream, where a caller keeps only the ends
+    result["compared"] = {name: {"value": value, "limit": limit}
+                          for name, (value, limit) in compared.items()}
+    if problems:
         print(f"benchmark: incorrect run of {cell['name']}, seed {args.seed}: {problems}",
               file=sys.stderr, flush=True)
+    for name, (value, limit) in compared.items():
+        print(f"compared {name} {value} limit {limit}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

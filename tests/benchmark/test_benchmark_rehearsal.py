@@ -47,6 +47,10 @@ def test_committed_cell_on_one_virtual_device():
     # inside the window, /info equalled the file, the reference agreed
     assert window["problems"] == ["platform is cpu, not tpu (rehearsal)"], window
     assert window["answer_tokens_per_request"] == 96
+    # every number the reference check held to a limit, beside it, comes last
+    assert list(line)[-1] == "compared" and set(line["compared"]) == {
+        "prefill_rel_rms", "decode_rel_rms", "decode_over_prefill", "served_token_gap", "served_logprob_err"}
+    assert all(0 <= entry["value"] <= entry["limit"] for entry in line["compared"].values())
 
 
 # loaded by every Python process of the run through PYTHONPATH: when the
