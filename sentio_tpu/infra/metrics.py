@@ -305,6 +305,16 @@ class MetricsCollector:
                 "decode row-steps by what they produced",
                 ["kind"], registry=r,
             ),
+            # K/V page blocks of those sub-steps: held = what the decode
+            # kernel's walk copies and computes (a row's lens // page + 1,
+            # one for a row that holds nothing), tabled = every cell of
+            # every page table. held / tabled is the share of a walk of the
+            # table that is work
+            "kv_pages": Counter(
+                "sentio_tpu_decode_kv_pages_total",
+                "K/V page blocks of the decode sub-steps, held by rows vs tabled",
+                ["kind"], registry=r,
+            ),
             # process-mode replica tier (runtime/worker.py): worker
             # process deaths observed by the router-side shim (SIGKILL,
             # OOM-kill, crash, broken RPC pipe). A steadily increasing
@@ -545,18 +555,21 @@ class MetricsCollector:
         if hist is not None:
             hist.labels(stage=stage).observe(float(seconds))
 
-    def record_row_steps(self, counts: dict) -> None:
-        """One harvested tick's row-steps by kind (useful / halted / empty)."""
+    def record_row_steps(self, counts: dict, kv_pages: Optional[dict] = None) -> None:
+        """One harvested tick's row-steps by kind (useful / halted / empty)
+        and the K/V page blocks of its sub-steps (held / tabled)."""
         if not self.enabled:
             return
-        from sentio_tpu.infra.phases import ROW_STEP_KINDS
+        from sentio_tpu.infra.phases import KV_PAGE_KINDS, ROW_STEP_KINDS
 
-        counter = self._prom.get("row_steps")
-        for kind in ROW_STEP_KINDS:
-            n = int(counts.get(kind, 0))
-            self.memory.inc("row_steps", (kind,), n)
-            if counter is not None:
-                counter.labels(kind=kind).inc(n)
+        for name, kinds, tick in (("row_steps", ROW_STEP_KINDS, counts),
+                                  ("kv_pages", KV_PAGE_KINDS, kv_pages or {})):
+            counter = self._prom.get(name)
+            for kind in kinds:
+                n = int(tick.get(kind, 0))
+                self.memory.inc(name, (kind,), n)
+                if counter is not None:
+                    counter.labels(kind=kind).inc(n)
 
     def record_duty_cycle(self, replica: int, fractions: dict) -> None:
         """Publish one replica's host/device/idle duty-cycle fractions

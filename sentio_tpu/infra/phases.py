@@ -100,6 +100,7 @@ import time
 __all__ = [
     "REQUEST_STAGES",
     "ROW_STEP_KINDS",
+    "KV_PAGE_KINDS",
     "TTFT_STAGES",
     "tile_ttft",
     "TICK_PHASES",
@@ -160,6 +161,13 @@ REQUEST_STAGES = TTFT_STAGES + ("decode", "verify", "stream_lag")
 # token into an answer, `halted` held a request that had finished, spent its
 # budget or was still prefilling, `empty` held none
 ROW_STEP_KINDS = ("useful", "halted", "empty")
+
+# K/V page blocks of the sub-steps the device ran (runtime/paged.py counts
+# them per dispatched tick, by the decode kernel's own rule): `held` the
+# blocks the kernel's walk copies and computes — a row's ``lens // page + 1``,
+# one for a row that holds no request or does not advance — and `tabled`
+# every cell of every page table, what a walk of the table would touch
+KV_PAGE_KINDS = ("held", "tabled")
 
 
 def tile_ttft(stage_s: dict, ttft_s: float) -> dict:

@@ -5,41 +5,58 @@ fixed-size pages for all layers, ``[L, P, page, Hkv, D]``; at decode each
 row attends over its own scattered page list in one layer of it. The XLA
 path gathers those pages into a contiguous window first — an HBM round-trip
 proportional to the whole window. This kernel instead reads the pool where
-it lies:
+it lies, and its time follows what the rows HOLD:
 
-* the page table, the row lengths and the layer index ride **scalar
-  prefetch** (``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index_map
-  picks layer AND *physical* page to DMA for grid step (row b, logical
-  block i) — ``pool[layer, page_table[b, i]]`` — and only pages the row
-  actually owns ever leave HBM. The kernel takes the whole pool: a
-  ``pool[layer]`` operand would be materialised by XLA (a custom call
-  cannot fuse its operand), a copy of every page of the layer per call.
-  The layer is a traced int32, not a constant closed over by the
-  index_map, so every layer of a decode program shares one kernel body;
-* grid ``(B, NB)`` with the page axis sequential, carrying the classic
-  online-softmax (m, l, acc) recurrence in fp32 VMEM scratch;
-* GQA stays folded: q is viewed [Hkv, rep, D] and both dots batch over the
-  kv-head axis, so pages are never expanded to query heads;
-* pages past a row's length are skipped wholesale (``pl.when``), the
-  current page masks per-position (key pos ≤ len — the new token's KV was
-  scattered at index ``len`` before the call).
+* the kernel takes the whole pool, left in HBM (``memory_space=pl.ANY``): a
+  ``pool[layer]`` operand would be materialised by XLA (a custom call cannot
+  fuse its operand), a copy of every page of the layer per call. The page
+  table, the row lengths and the layer index ride **scalar prefetch**; the
+  layer is a traced int32, so every layer of a decode program shares one
+  kernel body;
+* grid ``(B,)``, and inside row ``b`` a loop over the blocks the row holds —
+  ``lens[b] // page + 1``, one for a free slot (``blocks_walked``). Each
+  block is ONE copy by the kernel's own DMA (``pool[layer, table[b, j]]`` →
+  VMEM) and one step of the online-softmax (m, l, acc) recurrence in fp32
+  scratch; a table cell past a row's length is never read, copied or
+  stepped over. (Until PR 29 the grid was ``(B, NB)``: a step a table CELL.)
+* the copies run in a ring of ``pages_in_flight`` VMEM buffers that does not
+  stop at a row's end: the fetch pointer is that many pages ahead of the
+  compute, so the next row's first pages land while this row's last are
+  computed;
+* a page ``[page, Hkv, D]`` is copied as the matrix ``[page * Hkv, D]`` it is
+  in memory (keys of all kv heads interleaved) and goes to the MXU as it
+  lies: ``q [H, D] · Kᵀ`` gives every query head its scores against every kv
+  head's keys, and the columns of another head's keys are masked like
+  positions past the length (key pos ≤ len — the new token's KV was
+  scattered at index ``len`` before the call). That spends vector work on
+  scores nobody wants (Hkv times the needed) and none on a relayout: a
+  ``[page, Hkv, D] → [Hkv, page, D]`` swap of every page cost more than its
+  copy (PERF.md §5, PR 29). GQA stays folded: pages are never expanded to
+  query heads.
 
-Two kernel variants share the grid/recurrence:
+ONE walk and ONE block arithmetic serve both representations:
 
-* **bf16 pages** (``paged_attention``) — K/V page blocks DMA as-is;
-* **int8 pages** (``paged_attention_quant``) — the BlockSpecs DMA int8
-  page blocks PLUS their bf16 per-vector scales (stored page-minor,
-  ``[L, P, Hkv, page]``, so a scale block is one lane-dense tile and needs no
-  in-kernel transpose; Mosaic has no float16 vector type on v5e) through
-  the same scalar-prefetch index_map, and dequantization happens
-  in-register in VMEM: q·(s·K) folds as (q·K)·s on the kv-head-batched score dot, and
-  p·(s·V) as (p·s)·V on the value dot, so quantized pages never
-  round-trip through a dense bf16 gather in HBM. Page reads shrink to
-  ~half the bytes of bf16 — the point of quantizing a bandwidth-bound
-  decode.
+* **bf16 pages** (``paged_attention``);
+* **int8 pages** (``paged_attention_quant``) — the int8 page PLUS its bf16
+  per-vector scales (stored page-minor, ``[L, P, Hkv, page]``, so a scale
+  block is one lane-dense tile; Mosaic has no float16 vector type on v5e)
+  are copied together. The page is the same matrix cast to q's dtype (exact:
+  an int8 is a bf16); q·(s·K) folds as (q·K)·s and p·(s·V) as (p·s)·V, the
+  scales spread over the columns by a matmul against a 0/1 matrix, so
+  quantized pages never round-trip through a dense bf16 gather in HBM and
+  no page is dequantized element by element. Page reads shrink to ~half
+  the bytes of bf16.
 
-Runs in interpret mode on CPU (tests); on TPU it is the decode fast path
-once windows are long enough to beat the fused XLA gather.
+The DMA engine moves whole tiles of the pool AS IT LIES IN HBM, and XLA lays
+a pool out by its shape. Where a position's kv heads do not fill 32-bit
+sublanes XLA stores the page head-major and the walk reads it so
+(``_head_major``: a bitcast either way). Where a page cannot fill tiles at
+all (``untiled``: head_dim under 128, int8 scale pages under 128 positions
+or of one kv head) the walk refuses and the engine serves the geometry
+through the XLA gather path: nothing pads or copies a pool on the way to the
+kernel.
+
+Runs in interpret mode on CPU (tests); on TPU it is the decode path.
 """
 
 from __future__ import annotations
@@ -54,117 +71,298 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(np.finfo(np.float32).min)
 
-__all__ = ["paged_attention", "paged_attention_quant", "make_paged_attn_impl"]
+__all__ = ["paged_attention", "paged_attention_quant", "make_paged_attn_impl",
+           "blocks_walked", "pages_in_flight", "untiled"]
 
 
-def _paged_kernel(
-    pt_ref,    # [B, NB] int32 scalar-prefetch — page table
-    lens_ref,  # [B] int32 scalar-prefetch — current token index per row
-    layer_ref,  # [1] int32 scalar-prefetch — read by the index_maps only
-    q_ref,     # [Hkv, rep, D]
-    k_ref,     # [page, Hkv, D] — the layer's physical page chosen by index_map
-    v_ref,     # [page, Hkv, D]
-    o_ref,     # [Hkv, rep, D]
-    m_ref,     # [Hkv, rep, 1] fp32 scratch
-    l_ref,     # [Hkv, rep, 1] fp32 scratch
-    acc_ref,   # [Hkv, rep, D] fp32 scratch
-    *,
+# VMEM the call's scratch may take with no limit of its own: under the 16 MiB
+# a kernel is given on every TPU generation when it asks for nothing, with
+# room for q and out; and how much of that the ring of page buffers may take
+_UNASKED_VMEM_BYTES = 12 * 1024 * 1024
+_PAGE_BUFFER_BYTES = 10 * 1024 * 1024
+# pages in VMEM at once: one computed, two on their way. A ring 2, 3 and 8
+# deep were timed on the chip at both cells' geometries (PERF.md §5, PR 29):
+# 3 is the smallest that read as fast as 8
+_PAGES_IN_FLIGHT = 3
+# a position no row reaches: marks a score against another kv head's key
+_NEVER = 1 << 30
+
+
+def _packs(dtype) -> int:
+    """Rows of ``dtype`` one 32-bit sublane packs: 2 of bf16, 4 of int8."""
+    return max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _roundup(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """Bytes a block takes in VMEM: its last two dims pad to the dtype's
+    tile (8 sublanes of 32 bits — 16 rows of bf16, 32 of int8 — by 128
+    lanes). An upper bound: Mosaic may pick a smaller tile for few rows."""
+    *lead, rows, lanes = shape
+    return (int(np.prod(lead, dtype=np.int64)) * jnp.dtype(dtype).itemsize
+            * _roundup(rows, 8 * _packs(dtype)) * _roundup(lanes, 128))
+
+
+def _page_bytes(pools) -> int:
+    """VMEM one page of every pool takes (K, V and, quantized, their scales)."""
+    return sum(_vmem_bytes(pool.shape[2:], pool.dtype) for pool in pools)
+
+
+def pages_in_flight(pools) -> int:
+    """How many pages of ``pools`` the walk keeps in VMEM at once:
+    ``_PAGES_IN_FLIGHT``, or two (one computed while one lands) where three
+    overrun the buffer budget. From static shapes alone — the same rule for
+    every geometry."""
+    return int(max(2, min(_PAGES_IN_FLIGHT,
+                          _PAGE_BUFFER_BYTES // _page_bytes(pools))))
+
+
+def blocks_walked(lens, page: int, nb: int):
+    """Blocks the walk copies and computes for rows at ``lens`` (a numpy or
+    jax array): the new token sits at index ``lens``, so ``lens // page +
+    1``, never past the table. A free slot (``lens`` 0) costs its one block
+    of the scratch page. The host's counter (``runtime/paged.py``) counts
+    by this same rule."""
+    return (lens // page).clip(0, nb - 1) + 1
+
+
+def _head_major(hkv: int, dtype) -> bool:
+    """Whether a page of ``hkv`` kv heads is read as ``[Hkv * page, D]``
+    (row = head * page + position) and not ``[page * Hkv, D]`` (row =
+    position * Hkv + head). XLA lays a pool out in HBM by its shape: where
+    the kv heads of a position fill whole 32-bit sublanes (or there is one)
+    the pool lies position-major as its shape says, and the second view is a
+    bitcast; where they do not (2 heads of int8: a device's share of 8 under
+    ``tp=4``) XLA stores the page head-major, the first view is the bitcast,
+    and ANY kernel that asks for ``[page, Hkv, D]`` blocks is handed a copy
+    of the pool by XLA first (the grid-of-cells kernel was, PERF.md §5).
+    ``tests/test_chip_compile.py`` holds that neither view is a copy."""
+    return hkv > 1 and hkv % _packs(dtype) != 0
+
+
+def untiled(page: int, hkv: int, head_dim: int, quant: bool) -> str | None:
+    """Why the chip's DMA cannot bring the pages of this geometry (``hkv``
+    kv heads on ONE device), or None where it can. The DMA engine moves
+    whole tiles of the pool as it lies in HBM — 8 sublanes of 32 bits by
+    128 lanes. Where a page does not fill them XLA does not even store the
+    pool in the order of its shape (see ``_head_major``), and a page is not
+    a slice of it: such a pool needs another layout from ``init_pool``, not
+    a padded copy a call. The engine reads this when it is built and serves
+    such a geometry through the XLA gather path; the kernel raises."""
+    dtype = jnp.int8 if quant else jnp.bfloat16
+    if head_dim % 128:
+        return f"head_dim {head_dim} is not a multiple of the 128 lanes of a tile"
+    if (page * hkv) % (8 * _packs(dtype)):
+        return (f"a page of {page} x {hkv} {jnp.dtype(dtype).name} vectors is not "
+                f"a whole number of {8 * _packs(dtype)}-row tiles")
+    if quant and page % 128:
+        return f"a scale page of {page} positions does not fill the 128 lanes of a tile"
+    if quant and hkv % 2:
+        return f"the scale pages of {hkv} kv head(s) do not fill a 32-bit sublane"
+    return None
+
+
+def _walk_kernel(
+    pt_ref,     # [B, NB] int32 scalar-prefetch — page table
+    lens_ref,   # [B] int32 scalar-prefetch — current token index per row
+    layer_ref,  # [1] int32 scalar-prefetch — the layer whose pages are read
+    q_ref,      # [H, D] — row b's query heads
+    *refs,      # pools (HBM) | o_ref | m, l, acc, pos, (spread) | page buffers | sems, walk
+    quant: bool,
     page: int,
+    hkv: int,
+    head_major: bool,
+    depth: int,
     sm_scale: float,
 ):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
+    """Row ``b`` of the walk: one loop step a block the row HOLDS.
 
-    @pl.when(i == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    The pools stay in HBM; every page is brought by the kernel's own DMA
+    into a ring of ``depth`` VMEM buffers. The ring runs ACROSS rows: the
+    fetch pointer (``walk``: row, block, pages issued, pages consumed) lives
+    in SMEM for the whole call and is ``depth - 1`` pages ahead of the
+    compute, so the next row's first pages land while this row's last are
+    computed. Every row holds at least one block (``blocks_walked``), so the
+    pointer steps from a row's last block to the next row's first with no
+    search, and every block computed holds a key every head may see (its
+    first): the running maximum is finite from the first block on.
 
-    cur = lens_ref[b]  # the new token sits at absolute index ``cur``
-
-    @pl.when(i * page <= cur)
-    def _block():
-        q = q_ref[:]  # [Hkv, rep, D]
-        # [page, Hkv, D] → [Hkv, page, D]: Mosaic's tpu.matmul requires the
-        # batch dims of both operands at the SAME index ("batch dims must be
-        # equal" compile error on real chips otherwise; interpret mode on CPU
-        # accepted the mismatched layout)
-        k = k_ref[:].swapaxes(0, 1)
-        # s[g, r, p] = q[g, r, :] · k[g, p, :]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-        ) * sm_scale  # [Hkv, rep, page]
-
-        pos = i * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos <= cur, s, NEG_INF)
-
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.exp(jnp.where(m_new > NEG_INF / 2, s - m_new, NEG_INF))
-        alpha = jnp.exp(jnp.where(m_new > NEG_INF / 2, m_prev - m_new, 0.0))
-
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=2, keepdims=True)
-        v = v_ref[:].swapaxes(0, 1)  # [Hkv, page, D], same batch-dim rule
-        # acc[g, r, :] += p[g, r, :] @ v[g, :, :]
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = m_new
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _finalize():
-        l = l_ref[:]
-        o_ref[:] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
-
-
-def _row_block(bb, i, pt, ln, layer):
-    return (bb, 0, 0, 0)
-
-
-def _page_block(bb, i, pt, ln, layer):
-    return (layer[0], pt[bb, i], 0, 0, 0)
-
-
-def _scale_block(bb, i, pt, ln, layer):
-    return (layer[0], pt[bb, i], 0, 0)
-
-
-def _walk_pool(kernel, q, pools, pool_specs, layer, page_table, lens, interpret):
-    """The grid both variants share: (row, logical block) over ``pools``
-    (whole-pool operands with their BlockSpecs), q [B, H, D] → [B, H, D]."""
-    b, h, d = q.shape
-    hkv = pools[0].shape[3]
+    A page goes to the MXU as the matrix ``[page * Hkv, D]`` it is in
+    memory: ``q [H, D] · Kᵀ`` scores every query head against every kv
+    head's keys, and ``pos`` — the position each column's key holds, for the
+    query heads of ITS kv head, ``_NEVER`` for the others — masks another
+    head's columns like positions past the length. int8 pages are the same
+    matrix cast to q's dtype; their scales ``[Hkv, page]`` are spread over
+    the columns by one more matmul against a 0/1 matrix (``spread``), so
+    q·(s·K) folds as (q·K)·s and p·(s·V) as (p·s)·V and no page is
+    dequantized element by element."""
+    n_pools = 4 if quant else 2
+    pools = refs[:n_pools]
+    o_ref, m_ref, l_ref, acc_ref, pos_ref = refs[n_pools:n_pools + 5]
+    rest = refs[n_pools + 5:]
+    spread_ref, rest = (rest[0], rest[1:]) if quant else (None, rest)
+    buffers, (sems, walk) = rest[:n_pools], rest[n_pools:]
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    nb = pt_ref.shape[1]
+    layer = layer_ref[0]
+    h = q_ref.shape[0]
     rep = h // hkv
-    row = pl.BlockSpec((None, hkv, rep, d), _row_block)
+
+    def held(row):
+        return blocks_walked(lens_ref[row], page, nb)
+
+    def copies(row, j, slot):
+        pid = pt_ref[row, j]
+        return [pltpu.make_async_copy(pool.at[layer, pid], buf.at[slot], sems.at[n, slot])
+                for n, (pool, buf) in enumerate(zip(pools, buffers))]
+
+    def fetch_next():
+        row, j, issued = walk[0], walk[1], walk[2]
+
+        @pl.when(row < rows)
+        def _():
+            for copy in copies(row, j, issued % depth):
+                copy.start()
+            last = j + 1 >= held(row)
+            walk[0] = jnp.where(last, row + 1, row)
+            walk[1] = jnp.where(last, 0, j + 1)
+            walk[2] = issued + 1
+
+    def column(shape):
+        """(kv head, position in the page) of each column of a page matrix."""
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        return (col // page, col % page) if head_major else (col % hkv, col // hkv)
+
+    @pl.when(b == 0)
+    def _first_row():
+        for n in range(4):
+            walk[n] = 0
+        jax.lax.fori_loop(0, depth - 1, lambda _, c: (fetch_next(), c)[1], 0)
+        col_head, col_pos = column(pos_ref.shape)
+        own = jax.lax.broadcasted_iota(jnp.int32, pos_ref.shape, 0) // rep
+        pos_ref[:] = jnp.where(col_head == own, col_pos, _NEVER)
+        if quant:  # spread[p, c] = 1 where column c holds position p
+            _, col_pos = column(spread_ref.shape)
+            at = jax.lax.broadcasted_iota(jnp.int32, spread_ref.shape, 0)
+            spread_ref[:] = jnp.where(col_pos == at, 1.0, 0.0).astype(spread_ref.dtype)
+
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    cur = lens_ref[b]  # the new token sits at absolute index ``cur``
+    q = q_ref[:]
+
+    def over_columns(scales):
+        """[Hkv, page] scales → [H, page * Hkv]: query head h's row holds, at
+        every column, the scale of ITS kv head at that column's position
+        (another head's columns are masked, whatever they read)."""
+        own = jax.lax.broadcasted_iota(jnp.int32, (h, page), 0) // rep
+        mine = jnp.zeros((h, page), jnp.float32)
+        for g in range(hkv):
+            mine = jnp.where(own == g, scales[g:g + 1, :].astype(jnp.float32), mine)
+        return jax.lax.dot_general(
+            mine.astype(q.dtype), spread_ref[:], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    def page_step(j, carry):
+        # the slot this fetch fills is the one the previous step computed on
+        fetch_next()
+        slot = walk[3] % depth
+        for copy in copies(b, j, slot):
+            copy.wait()
+        if quant:
+            kq_buf, ks_buf, vq_buf, vs_buf = buffers
+            k, v = kq_buf[slot].astype(q.dtype), vq_buf[slot].astype(q.dtype)
+        else:
+            k, v = buffers[0][slot], buffers[1][slot]
+        # s[h, c] = q[h, :] · k[c, :] — the page as it lies, no relayout
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        if quant:
+            s = s * over_columns(ks_buf[slot])
+        s = jnp.where(pos_ref[:] <= cur - j * page, s * sm_scale, NEG_INF)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if quant:
+            p = p * over_columns(vs_buf[slot])
+        # p is 0 against another head's keys: acc[h, :] += p[h, :] @ v[:, :]
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+        walk[3] = walk[3] + 1
+        return carry
+
+    jax.lax.fori_loop(0, held(b), page_step, 0)
+    o_ref[:] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+
+
+def _walk_pool(q, pools, layer, page_table, lens, interpret):
+    """The walk both representations share: grid ``(B,)``, ``pools`` whole
+    in HBM — K and V pages ``[L, P, page, Hkv, D]`` and, quantized (four
+    pools: K pages, K scales, V pages, V scales), their scales ``[L, P, Hkv,
+    page]`` — q [B, H, D] → [B, H, D]."""
+    b, h, d = q.shape
+    quant = len(pools) == 4
+    layers, num_pages, page, hkv, _ = pools[0].shape
+    if not interpret:  # the interpreter has no tiles; the chip's DMA has
+        why = untiled(page, hkv, d, quant)
+        if why:
+            raise ValueError(f"paged attention on this device: {why}")
+    head_major = _head_major(hkv, pools[0].dtype)
+
+    def matrix(pages):
+        # a page as the matrix it is in HBM: a bitcast (see ``_head_major``)
+        if head_major:
+            pages = pages.transpose(0, 1, 3, 2, 4)
+        return pages.reshape(layers, num_pages, page * hkv, d)
+
+    pools = tuple(matrix(pool) if pool.ndim == 5 else pool for pool in pools)
+    depth = pages_in_flight(pools)
+    tables = [((h, page * hkv), jnp.int32)] + [((page, page * hkv), q.dtype)] * quant
+    # the page ring and the column tables (which grow with the page size
+    # squared): only where a very large page size overruns what a kernel is
+    # given unasked does the call ask for more VMEM
+    scratch = depth * _page_bytes(pools) + sum(_vmem_bytes(*t) for t in tables)
+    vmem_limit = None if scratch <= _UNASKED_VMEM_BYTES else scratch + (6 << 20)
+    row = pl.BlockSpec((None, h, d), lambda bb, *_: (bb, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, page_table.shape[1]),
-        in_specs=[row, *pool_specs],
+        grid=(b,),
+        in_specs=[row, *[pl.BlockSpec(memory_space=pl.ANY)] * len(pools)],
         out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((hkv, rep, 1), jnp.float32),
-            pltpu.VMEM((hkv, rep, 1), jnp.float32),
-            pltpu.VMEM((hkv, rep, d), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
+            *[pltpu.VMEM(shape, dtype) for shape, dtype in tables],
+            *[pltpu.VMEM((depth, *pool.shape[2:]), pool.dtype) for pool in pools],
+            pltpu.SemaphoreType.DMA((len(pools), depth)),
+            pltpu.SMEM((4,), jnp.int32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
-            kernel, page=pools[0].shape[2], sm_scale=1.0 / float(np.sqrt(d))),
+            _walk_kernel, quant=quant, page=page, hkv=hkv, head_major=head_major,
+            depth=depth, sm_scale=1.0 / float(np.sqrt(d))),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # rows in order: the ring of page buffers runs across them
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit,
         ),
         interpret=interpret,
     )(
         page_table.astype(jnp.int32), lens.astype(jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        # [B, H, D] → [B, Hkv, rep, D]: group query heads under their kv head
-        q.reshape(b, hkv, rep, d), *pools,
+        jnp.asarray(layer, jnp.int32).reshape(1), q, *pools,
     )
-    return out.reshape(b, h, d)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -179,83 +377,7 @@ def paged_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Decode attention over one layer of the paged pool → [B, H, D]."""
-    _, _, page, hkv, d = k_pages.shape
-    pages = pl.BlockSpec((None, None, page, hkv, d), _page_block)
-    return _walk_pool(_paged_kernel, q, (k_pages, v_pages), (pages, pages),
-                      layer, page_table, lens, interpret)
-
-
-def _paged_kernel_quant(
-    pt_ref,    # [B, NB] int32 scalar-prefetch — page table
-    lens_ref,  # [B] int32 scalar-prefetch — current token index per row
-    layer_ref,  # [1] int32 scalar-prefetch — read by the index_maps only
-    q_ref,     # [Hkv, rep, D]
-    kq_ref,    # [page, Hkv, D] int8 — the layer's physical page chosen by index_map
-    ks_ref,    # [Hkv, page] bf16 — per-vector absmax scales for that page
-    vq_ref,    # [page, Hkv, D] int8
-    vs_ref,    # [Hkv, page] bf16
-    o_ref,     # [Hkv, rep, D]
-    m_ref,     # [Hkv, rep, 1] fp32 scratch
-    l_ref,     # [Hkv, rep, 1] fp32 scratch
-    acc_ref,   # [Hkv, rep, D] fp32 scratch
-    *,
-    page: int,
-    sm_scale: float,
-):
-    """Online-softmax over int8 pages, dequantized in-register.
-
-    The scale never expands to [page, D]: q·(s_p·K_p) == (q·K_p)·s_p per key
-    vector, so the score dot runs on the raw int8 block (cast to f32 on the
-    VPU) and the scalar scale multiplies the [Hkv, rep, page] score tile.
-    Same fold on the value side: p·(s·V) == (p·s)·V."""
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    cur = lens_ref[b]  # the new token sits at absolute index ``cur``
-
-    @pl.when(i * page <= cur)
-    def _block():
-        q = q_ref[:].astype(jnp.float32)  # [Hkv, rep, D]
-        # [page, Hkv, D] → [Hkv, page, D]: batch dims of both matmul
-        # operands must sit at the SAME index (see _paged_kernel)
-        k = kq_ref[:].astype(jnp.float32).swapaxes(0, 1)   # [Hkv, page, D]
-        ks = ks_ref[:].astype(jnp.float32)                 # [Hkv, page]
-        # s[g, r, p] = (q[g, r, :] · kq[g, p, :]) * ks[g, p] — the (q·K)·s
-        # fold: one scalar multiply per score instead of page*D dequants
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-        ) * ks[:, None, :] * sm_scale  # [Hkv, rep, page]
-
-        pos = i * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos <= cur, s, NEG_INF)
-
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.exp(jnp.where(m_new > NEG_INF / 2, s - m_new, NEG_INF))
-        alpha = jnp.exp(jnp.where(m_new > NEG_INF / 2, m_prev - m_new, 0.0))
-
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=2, keepdims=True)
-        v = vq_ref[:].astype(jnp.float32).swapaxes(0, 1)   # [Hkv, page, D]
-        vs = vs_ref[:].astype(jnp.float32)                 # [Hkv, page]
-        # acc[g, r, :] += (p[g, r, :] * vs[g, :]) @ vq[g, :, :] — the (p·s)·V
-        # fold on the value dot
-        pv = p * vs[:, None, :]
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            pv, v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = m_new
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _finalize():
-        l = l_ref[:]
-        o_ref[:] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+    return _walk_pool(q, (k_pages, v_pages), layer, page_table, lens, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -272,17 +394,11 @@ def paged_attention_quant(
     interpret: bool = False,
 ) -> jax.Array:
     """Decode attention over one layer of the int8-quantized paged pool →
-    [B, H, D].
-
-    Same grid/scalar-prefetch walk as :func:`paged_attention`; the int8
-    payload and its scale pages DMA per grid step and dequantize in VMEM.
+    [B, H, D]. The same walk as :func:`paged_attention`; a page's int8
+    payload and its scale page are copied together and dequantized in VMEM.
     """
-    _, _, page, hkv, d = k_pages_q.shape
-    pages = pl.BlockSpec((None, None, page, hkv, d), _page_block)
-    scales = pl.BlockSpec((None, None, hkv, page), _scale_block)
-    return _walk_pool(
-        _paged_kernel_quant, q, (k_pages_q, k_scales, v_pages_q, v_scales),
-        (pages, scales, pages, scales), layer, page_table, lens, interpret)
+    return _walk_pool(q, (k_pages_q, k_scales, v_pages_q, v_scales),
+                      layer, page_table, lens, interpret)
 
 
 def make_paged_attn_impl(interpret: bool | None = None, mesh=None):

@@ -48,6 +48,7 @@ so masked lanes in the fused decode step write garbage somewhere harmless.
 from __future__ import annotations
 
 import itertools
+import logging
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -58,7 +59,9 @@ import numpy as np
 from sentio_tpu.analysis.audit.registry import jit_family
 from sentio_tpu.analysis.sanitizer import check_engine_invariants, engine_guard
 from sentio_tpu.infra import faults
-from sentio_tpu.infra.phases import ENGINE_PHASES, ROW_STEP_KINDS, PhaseTimer
+from sentio_tpu.infra.phases import (
+    ENGINE_PHASES, KV_PAGE_KINDS, ROW_STEP_KINDS, PhaseTimer,
+)
 from sentio_tpu.infra.tracing import annotation
 from sentio_tpu.models.llama import LlamaConfig
 from sentio_tpu.parallel.batcher import bucket_size
@@ -290,9 +293,15 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
 
     page_ids = jnp.take_along_axis(page_table, (lens // page)[:, None], axis=1)[:, 0]
     offsets = lens % page
+    attn_lens = lens
     if write_mask is not None:
         page_ids = jnp.where(write_mask, page_ids, 0)
         offsets = jnp.where(write_mask, offsets, 0)
+        # a row that does not advance — a free slot, whose carried ``lens``
+        # is its last request's, or one frozen mid-tick — keeps nothing of
+        # this step: its attention reads one block, not its whole length
+        # (the decode kernel's cost is the blocks a row's ``lens`` names)
+        attn_lens = jnp.where(write_mask, lens, 0)
 
     x = L.embed(params["embed_tokens"], tok[:, None], dt)  # [B,1,d]
     for i in range(cfg.n_layers):
@@ -310,7 +319,7 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
         # the attention takes the pool whole and the layer's index: a
         # pages[i] handed to a kernel is a copy of the layer's every page
         impl = attn_impl or _paged_attn_xla
-        out = impl(q, k_pages, v_pages, i, page_table, lens, h // hkv)
+        out = impl(q, k_pages, v_pages, i, page_table, attn_lens, h // hkv)
         x = x + L.dense(lp["attn"]["wo"], out.reshape(b, 1, cfg.dim), dt)
 
         xm = L.rmsnorm(lp["mlp_norm"], x, cfg.norm_eps)
@@ -687,6 +696,13 @@ class ContinuousBatchingEngine:
         self.row_steps_total = dict.fromkeys(ROW_STEP_KINDS, 0)
         self.last_tick_row_steps = dict.fromkeys(ROW_STEP_KINDS, 0)
         self.last_tick_sub_steps = 0
+        # K/V page blocks of those sub-steps, counted at dispatch by the
+        # decode kernel's own rule (``_kv_pages``) and booked at harvest:
+        # ``held`` what its walk copies and computes, ``tabled`` every cell
+        # of every page table. held / tabled is the share of a walk of the
+        # table that is work
+        self.kv_pages_total = dict.fromkeys(KV_PAGE_KINDS, 0)
+        self.last_tick_kv_pages = dict.fromkeys(KV_PAGE_KINDS, 0)
         self._queue: list[_Request] = []  # guarded-by: engine-thread
         # skip-ahead admission: a request too large for the current free
         # pages may be jumped by later, smaller requests — but only
@@ -757,18 +773,39 @@ class ContinuousBatchingEngine:
         self._lp_sum = np.zeros(max_slots, np.float32)
         self._lp_min = np.zeros(max_slots, np.float32)
         self._lp_cnt = np.zeros(max_slots, np.int32)
-        # Pallas paged-attention kernel walks page tables in VMEM on TPU,
-        # reading the [L, P, ...] pool where it lies (layer and physical
-        # page picked per block); the XLA gather path is what runs
-        # elsewhere (the CPU test path). This is a SELECTION by backend,
-        # made once: nothing catches a kernel failure and carries on with
-        # the other path. Under a mesh the kernel runs inside shard_map
-        # over tp (heads-sharded pool). The kernel is representation-aware:
-        # int8 pools route to the quant variant (int8 pages + bf16 scales
-        # DMA'd per block, dequantized in-register), so kv_quant="int8"
+        # On TPU the Pallas paged-attention kernel reads the [L, P, ...] pool
+        # where it lies: it walks the blocks each row HOLDS (its ``lens``
+        # names them; one for a free slot) and copies each by its own DMA,
+        # so its time follows what the rows hold, not the size of the page
+        # table. The XLA gather path is what runs elsewhere: the CPU test
+        # path, and on TPU a geometry whose pages the DMA cannot bring
+        # (``untiled``: a head_dim under 128, int8 pages under 128 tokens or
+        # with one kv head a device — XLA does not store such a pool in the
+        # order of its shape, and until PR 29 every layer-call was handed a
+        # copy of it). This is a SELECTION from static facts, made once and
+        # logged: nothing catches a kernel failure and carries on with the
+        # other path, and a caller who ASKS for the kernel at such a geometry
+        # is refused. Under a mesh the kernel runs inside shard_map over tp
+        # (heads-sharded pool). int8 pools go through the same walk with
+        # their scale pages copied beside the int8 pages, so kv_quant="int8"
         # keeps the fast path
+        asked = use_pallas
         if use_pallas is None:
             use_pallas = jax.default_backend() == "tpu"
+        if use_pallas and jax.default_backend() == "tpu":
+            from sentio_tpu.kernels.paged_attention import untiled
+            from sentio_tpu.parallel.mesh import AXIS_TP
+
+            tp = mesh.shape[AXIS_TP] if mesh is not None else 1
+            why = untiled(page_size, self.cfg.n_kv_heads // tp, self.cfg.head_dim,
+                          kv_quant == "int8")
+            if why and asked:
+                raise ValueError(f"use_pallas=True, but {why}")
+            if why:
+                logging.getLogger(__name__).warning(
+                    "decode attention runs the XLA gather path, not the "
+                    "paged kernel: %s", why)
+                use_pallas = False
         self._attn_impl = None
         if use_pallas:
             from sentio_tpu.kernels.paged_attention import make_paged_attn_impl
@@ -1297,6 +1334,7 @@ class ContinuousBatchingEngine:
         acc = self._phase.acc
         self._phase.reset()
         self.last_tick_row_steps = dict.fromkeys(ROW_STEP_KINDS, 0)
+        self.last_tick_kv_pages = dict.fromkeys(KV_PAGE_KINDS, 0)
         self.last_tick_sub_steps = 0
         # chaos-drill injection point: a raised fault propagates exactly like
         # a real failed device dispatch (the serving pump resets + requeues)
@@ -1959,6 +1997,7 @@ class ContinuousBatchingEngine:
                     k=self.spec_k, out_w=int(steps) + self.spec_k + 1,
                 )
             spec = True
+            kv_pages = None  # the spec tick does not run the decode kernel
             # the spec tick has its own accept/correct rule and samples no
             # per-token logprobs; the accumulators thread through UNCHANGED
             # (stale first-token seeds) and the host mirrors stay zeroed, so
@@ -1988,6 +2027,7 @@ class ContinuousBatchingEngine:
             )
             self.total_sub_steps += steps
             spec = False
+            kv_pages = self._kv_pages(budgets, int(steps))
         self._dev_state = (tok_out, lens_out, halted_out,
                            lp_sum_out, lp_min_out, lp_cnt_out)
         for i, slot in enumerate(self.slots):
@@ -1999,6 +2039,7 @@ class ContinuousBatchingEngine:
                 # the rows that held a request when it was dispatched
                 "steps": int(steps),
                 "live": sum(s.active for s in self.slots),
+                "kv_pages": kv_pages,
                 "pending_slots": set(pending_slots),
                 # request ids pin each lane: a slot retired at harvest time
                 # and re-admitted before THIS record is harvested must not
@@ -2080,6 +2121,29 @@ class ContinuousBatchingEngine:
             self.row_steps_total[kind] += n
             self.last_tick_row_steps[kind] += n
         self.last_tick_sub_steps += steps
+        for kind, n in (record.get("kv_pages") or {}).items():
+            self.kv_pages_total[kind] += n
+            self.last_tick_kv_pages[kind] += n
+
+    def _kv_pages(self, budgets, steps: int) -> dict:
+        """K/V page blocks of the ``steps`` sub-steps being dispatched, by
+        the decode kernel's own rule (``kernels.paged_attention.
+        blocks_walked``): a row advancing in sub-step ``s`` (``s`` under its
+        budget) is walked at its length then — the host mirror plus what an
+        unharvested tick already granted plus ``s`` — every other row for its
+        one block. An EOS inside the tick is not known here; the row is
+        counted as advancing to its budget. A few integers a slot, with no
+        device fetch."""
+        from sentio_tpu.kernels.paged_attention import blocks_walked
+
+        sub = np.arange(steps)[None, :]
+        at = np.asarray([s.length + s.inflight_steps if s.active else 0
+                         for s in self.slots])[:, None] + sub
+        held = np.where(
+            sub < np.asarray(budgets)[:, None],
+            blocks_walked(at, self.page_size, self.max_pages_per_seq), 1)
+        return {"held": int(held.sum()),
+                "tabled": steps * self.max_slots * self.max_pages_per_seq}
 
     def _fold_and_maybe_retire(self, i: int) -> Optional[PagedResult]:
         """Fold ``_last_tok[i]`` (sampled, not yet forwarded) into slot ``i``;

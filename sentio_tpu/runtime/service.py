@@ -1218,7 +1218,7 @@ class PagedGenerationService:
                             **row_steps,
                         )
                         metrics.record_tick_phases(phase_s)
-                        metrics.record_row_steps(row_steps["row_steps"])
+                        metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"])
                         for key, val in phase_s.items():
                             self._phase_totals[key] = (
                                 self._phase_totals.get(key, 0.0) + val
@@ -1384,7 +1384,7 @@ class PagedGenerationService:
                     last_hit_toks = engine.prefix_hit_tokens_total
                     last_miss_toks = engine.prefix_miss_tokens_total
                     metrics.record_tick(tick_dur_s, int(active), queued + inbox)
-                    metrics.record_row_steps(row_steps["row_steps"])
+                    metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"])
                 except Exception:  # noqa: BLE001
                     logger.debug("tick telemetry failed", exc_info=True)
                 t_deliver_start = time.perf_counter()
@@ -1498,10 +1498,11 @@ class PagedGenerationService:
                     self._phase_totals[key] = self._phase_totals.get(key, 0.0) + val
 
     def _row_steps(self) -> dict:
-        """The tick ring's row-step fields for the tick(s) the latest
-        ``engine.step()`` harvested (runtime/paged.py counts them)."""
+        """The tick ring's row-step and K/V-page fields for the tick(s) the
+        latest ``engine.step()`` harvested (runtime/paged.py counts them)."""
         return {"sub_steps": self.engine.last_tick_sub_steps,
-                "row_steps": dict(self.engine.last_tick_row_steps)}
+                "row_steps": dict(self.engine.last_tick_row_steps),
+                "kv_pages": dict(self.engine.last_tick_kv_pages)}
 
     def _note_ttft_locked(self, ttft_s: float) -> None:  # lock-held: _mutex
         """Fold one observed TTFT into the EMA admission control projects

@@ -56,17 +56,17 @@ def v5e():
 
 
 def _paged_args(place, quant: bool, page: int, slots: int = 8, nb: int = 32,
-                hkv_spec=None, scale_spec=None):
+                hkv: int = HKV, hkv_spec=None, scale_spec=None):
     """Abstract arguments of one decode-attention call at serve geometry."""
     num_pages = 1 + slots * nb
     q = place((slots, H, D), jnp.bfloat16)
     layer = place((), jnp.int32)
     table, lens = place((slots, nb), jnp.int32), place((slots,), jnp.int32)
     if not quant:
-        pages = place((LAYERS, num_pages, page, HKV, D), jnp.bfloat16, hkv_spec)
+        pages = place((LAYERS, num_pages, page, hkv, D), jnp.bfloat16, hkv_spec)
         return q, pages, pages, layer, table, lens
-    pages = place((LAYERS, num_pages, page, HKV, D), jnp.int8, hkv_spec)
-    scales = place((LAYERS, num_pages, HKV, page), jnp.bfloat16, scale_spec)
+    pages = place((LAYERS, num_pages, page, hkv, D), jnp.int8, hkv_spec)
+    scales = place((LAYERS, num_pages, hkv, page), jnp.bfloat16, scale_spec)
     return q, pages, scales, pages, scales, layer, table, lens
 
 
@@ -76,10 +76,10 @@ def _on_one_chip(topo):
         shape, dtype, sharding=chip)
 
 
-def _paged_case(quant: bool, page: int):
+def _paged_case(quant: bool, page: int, **geometry):
     def build(topo):
         fn = paged_attention_quant if quant else paged_attention
-        return fn, _paged_args(_on_one_chip(topo), quant, page)
+        return fn, _paged_args(_on_one_chip(topo), quant, page, **geometry)
 
     return build
 
@@ -94,7 +94,7 @@ def _flash_case(shape: tuple, causal: bool):
     return build
 
 
-def _tp4_case(quant: bool):
+def _tp4_case(quant: bool, hkv: int = HKV):
     """The decode kernel inside shard_map over a tp=4 mesh of the four
     described chips: pool and query heads sharded the way init_pool and the
     wq column rule place them."""
@@ -106,7 +106,7 @@ def _tp4_case(quant: bool):
             return jax.ShapeDtypeStruct(
                 shape, dtype, sharding=NamedSharding(mesh, spec or P()))
 
-        args = _paged_args(place, quant, 128,
+        args = _paged_args(place, quant, 128, hkv=hkv,
                            hkv_spec=P(None, None, None, "tp", None),
                            scale_spec=P(None, None, "tp", None))
         q, *pool, layer, table, lens = args
@@ -115,9 +115,9 @@ def _tp4_case(quant: bool):
         if quant:
             kq, ks, vq, vs = pool
             return (lambda q, kq, ks, vq, vs, ly, t, n: impl(
-                q, {"q": kq, "s": ks}, {"q": vq, "s": vs}, ly, t, n, H // HKV),
+                q, {"q": kq, "s": ks}, {"q": vq, "s": vs}, ly, t, n, H // hkv),
                 (q, kq, ks, vq, vs, layer, table, lens))
-        return (lambda q, k, v, ly, t, n: impl(q, k, v, ly, t, n, H // HKV),
+        return (lambda q, k, v, ly, t, n: impl(q, k, v, ly, t, n, H // hkv),
                 (q, *pool, layer, table, lens))
 
     return build
@@ -134,15 +134,52 @@ CASES = {
     "flash-bidirectional-d64": _flash_case((16, 512, 16, 64), causal=False),
     "paged-bf16-tp4-mesh": _tp4_case(quant=False),
     "paged-int8-tp4-mesh": _tp4_case(quant=True),
+    # the benchmark's cells as they are served (benchmark/configs/*.json):
+    # 16 slots of 18 pages at 8 kv heads, 32 slots of 10 pages at 4
+    "paged-bf16-cell-mistral": _paged_case(quant=False, page=128, slots=16, nb=18, hkv=8),
+    "paged-bf16-cell-yi": _paged_case(quant=False, page=128, slots=32, nb=10, hkv=4),
+    # 4 kv heads over tp=4: one a device
+    "paged-bf16-tp4-mesh-1kv": _tp4_case(quant=False, hkv=4),
+    "paged-int8-tp4-mesh-1kv": _tp4_case(quant=True, hkv=4),
 }
+# the geometries whose pages the chip's DMA cannot bring (kernels/
+# paged_attention.py ``untiled``): XLA does not store such a pool in the
+# order of its shape, and the grid-of-cells kernel (until PR 29) compiled
+# there only because XLA handed it a copy of the pool, every layer-call.
+# The walk refuses them; the engine serves them through the XLA gather path
+REFUSED = {"paged-int8-page16": "scale page", "paged-int8-tp4-mesh-1kv": "kv head"}
+
+
+def _copied_pools(hlo_text: str, args) -> list:
+    """Instructions of the compiled text that MAKE an array as large as one
+    device's share of the smallest pool among ``args`` (anything with more
+    than three dims): a copy, a pad, a transpose or a fusion. A bitcast — the
+    view the walk takes of a pool — makes nothing."""
+    pools = [a for a in args if len(a.shape) > 3 and a.shape[1] > 8]
+    share = min(int(np.prod(a.shape)) // len(a.sharding.device_set) for a in pools)
+    found = []
+    for line in hlo_text.splitlines():
+        inst = re.match(
+            r"\s+(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* (copy|pad|transpose|fusion|copy-start)\(",
+            line)
+        if inst and np.prod([int(n) for n in inst.group(1).split(",")]) >= share:
+            found.append(line.strip()[:160])
+    return found
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(v5e, case):
     fn, args = CASES[case](v5e)
+    if case in REFUSED:
+        with pytest.raises(ValueError, match=REFUSED[case]):
+            jax.jit(fn).lower(*args)
+        return
     compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip would
-    assert "tpu_custom_call" in compiled.as_text(), (
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, (
         f"{case}: the compiled program holds no Pallas kernel")
+    if case.startswith("paged"):
+        assert _copied_pools(text, args) == [], f"{case}: a pool is copied on the way"
 
 
 def _pool_shaped(hlo_text: str, shapes: tuple) -> list:

@@ -166,6 +166,34 @@ class TestPagedAttentionKernel:
         whole = _paged_attn_xla(q, kp, vp, layer, table, lens, h // hkv)[:, 0]
         np.testing.assert_array_equal(np.asarray(whole), np.asarray(ref))
 
+    @pytest.mark.parametrize("h, hkv, nb", [(32, 8, 18), (32, 4, 10), (8, 2, 18), (8, 1, 10)])
+    def test_walk_of_held_pages_matches_xla_gather(self, h, hkv, nb):
+        """The cells' head shapes (and a tp=4 device's share of them) with
+        ``lens`` at 0, page − 1, page, page + 1 and a full table in one call,
+        empty rows between full ones; cells past a row's length name a
+        NaN-filled page that must never be read. (The int8 side of the same
+        walk, and more edges: tests/test_paged_attn_quant.py.)"""
+        import jax.numpy as jnp
+
+        from sentio_tpu.kernels.paged_attention import paged_attention
+        from sentio_tpu.runtime.paged import _paged_attn_xla
+
+        rng = np.random.default_rng(h + hkv)
+        b, d, page, layer = 7, 16, 8, 1
+        nan_page = 1 + b * nb
+        shape = (2, nan_page + 1, page, hkv, d)
+        kp = jnp.asarray(rng.standard_normal(shape), jnp.float32).at[:, nan_page].set(jnp.nan)
+        vp = jnp.asarray(rng.standard_normal(shape), jnp.float32).at[:, nan_page].set(jnp.nan)
+        q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
+        lens = np.asarray([0, page - 1, page, 0, page + 1, nb * page - 1, 0], np.int32)
+        own = 1 + np.arange(b * nb, dtype=np.int32).reshape(b, nb)
+        held = np.arange(nb)[None, :] < (lens // page + 1)[:, None]
+        got = paged_attention(q, kp, vp, layer, jnp.asarray(np.where(held, own, nan_page)),
+                              jnp.asarray(lens), interpret=True)
+        ref = _paged_attn_xla(q[:, None], kp, vp, layer, jnp.asarray(np.where(held, own, 0)),
+                              jnp.asarray(lens), h // hkv)[:, 0]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
     def test_engine_with_kernel_matches_contiguous(self, cfg, contiguous):
         eng = ContinuousBatchingEngine(
             model_config=cfg, params=contiguous.params, tokenizer=contiguous.tokenizer,

@@ -16,6 +16,7 @@ from sentio_tpu.infra.metrics import MetricsCollector, set_metrics
 from sentio_tpu.infra.phases import (
     DUTY_STATES,
     HOST_PHASES,
+    KV_PAGE_KINDS,
     REQUEST_STAGES,
     ROW_STEP_KINDS,
     TICK_PHASES,
@@ -365,6 +366,93 @@ class TestRowSteps:
         assert engine.row_steps_total["useful"] == delivered - len(results)
         assert sum(engine.row_steps_total.values()) == (
             engine.max_slots * engine.total_sub_steps)
+
+
+class TestKvPages:
+    """``sentio_tpu_decode_kv_pages_total``: the K/V page blocks of the
+    sub-steps the device ran — ``held`` what the decode kernel's walk copies
+    and computes, by its own rule, ``tabled`` every cell of every table."""
+
+    def test_a_hand_counted_tick(self):
+        engine = _engine(max_slots=4, page_size=16, max_pages_per_seq=8)
+        a, b = engine.slots[0], engine.slots[1]
+        a.active, a.length, a.inflight_steps = True, 20, 0
+        b.active, b.length, b.inflight_steps = True, 15, 4
+        # 4 sub-steps, budgets 3 and 2; slots 2 and 3 hold no request.
+        # slot 0 advances at lens 20, 21, 22 (2 blocks each), then stands: 7
+        # slot 1 is at 15 + 4 in flight: 19, 20 (2 blocks each), then 1, 1: 6
+        # slots 2, 3: one block (the scratch page) a sub-step: 4 + 4
+        got = engine._kv_pages([3, 2, 0, 0], 4)
+        assert got == {"held": 7 + 6 + 4 + 4, "tabled": 4 * 4 * 8}
+
+    @pytest.mark.parametrize("lens, blocks", [
+        (0, 1), (15, 1), (16, 2), (17, 2), (8 * 16 - 1, 8), (8 * 16 + 40, 8)])
+    def test_the_rule_is_the_kernels(self, lens, blocks):
+        """lens // page + 1, never past the table: the host counts by the
+        function the kernel's docstring names as its own rule."""
+        import numpy as np
+
+        from sentio_tpu.kernels.paged_attention import blocks_walked
+
+        assert int(blocks_walked(np.asarray([lens]), 16, 8)[0]) == blocks
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_ticks_and_metrics_carry_it(self, recorder, metrics, depth):
+        engine = _engine(pipeline_depth=depth)
+        svc = PagedGenerationService(engine)
+        try:
+            svc.generate("kv pages probe " * 3, max_new_tokens=9, timeout_s=120)
+        finally:
+            svc.close()
+        ticks = [e for e in recorder.timeline() if e.get("sub_steps")]
+        assert ticks
+        cells = engine.max_slots * engine.max_pages_per_seq
+        for tick in ticks:
+            assert tuple(tick["kv_pages"]) == KV_PAGE_KINDS
+            assert tick["kv_pages"]["tabled"] == cells * tick["sub_steps"], tick
+            # every row costs its one block; none more than its table
+            assert (engine.max_slots * tick["sub_steps"] <= tick["kv_pages"]["held"]
+                    <= tick["kv_pages"]["tabled"]), tick
+        ring = {k: sum(t["kv_pages"][k] for t in ticks) for k in KV_PAGE_KINDS}
+        # one request in four slots of four pages: most of a walk of the
+        # table is no work
+        assert ring["held"] < 0.5 * ring["tabled"]
+        assert engine.kv_pages_total == ring
+        counters = metrics.export_json()["counters"]
+        assert {k: counters[f"kv_pages('{k}',)"] for k in KV_PAGE_KINDS} == ring
+        text = metrics.export_prometheus().decode()
+        for kind in KV_PAGE_KINDS:
+            assert (f'sentio_tpu_decode_kv_pages_total{{kind="{kind}"}} '
+                    f'{float(ring[kind])}') in text
+
+    def test_a_row_that_does_not_advance_is_read_as_one_block(self):
+        """A free slot carries its last request's ``lens`` on the device and
+        a frozen row its own: the attention gets 0 for both, so the kernel
+        walks one block for them (its cost is the blocks ``lens`` names)."""
+        import jax
+        import jax.numpy as jnp
+
+        from sentio_tpu.models.llama import LlamaConfig, init_llama
+        from sentio_tpu.runtime.paged import (
+            _paged_attn_xla, init_pool, paged_decode_forward)
+
+        cfg = LlamaConfig.tiny()
+        params = init_llama(jax.random.PRNGKey(0), cfg)
+        pool = init_pool(cfg, 9, 16)
+        seen = []
+
+        def spy(q, k_pages, v_pages, layer, table, lens, n_rep):
+            seen.append(lens)
+            return _paged_attn_xla(q, k_pages, v_pages, layer, table, lens, n_rep)
+
+        lens = jnp.asarray([37, 21, 5], jnp.int32)
+        table = jnp.asarray([[1, 2, 3, 4], [0, 0, 0, 0], [5, 6, 7, 8]], jnp.int32)
+        logits, _, _ = paged_decode_forward(
+            params, cfg, jnp.zeros(3, jnp.int32), lens, table, pool.k, pool.v,
+            attn_impl=spy, write_mask=jnp.asarray([True, False, True]))
+        assert len(seen) == cfg.n_layers
+        assert all(got.tolist() == [37, 0, 5] for got in seen)
+        assert bool(jnp.isfinite(logits).all())
 
 
 class TestReplicaAggregation:
