@@ -20,7 +20,6 @@ RUN python -c "import jax, aiohttp, httpx, einops, optax" 2>/dev/null \
 
 COPY sentio_tpu/ sentio_tpu/
 COPY prompts/ prompts/
-COPY bench.py ./
 
 # the C++ BM25 core builds on first use when a toolchain exists; bake it at
 # image build time so runtime containers need no compiler

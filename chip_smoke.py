@@ -456,7 +456,7 @@ def check_metrics(port: int, n_chats: int) -> dict:
         if events.get(event, 0.0) != 0.0:
             problems.append(f"{event}={events[event]}")
     # generate + verify per /chat, all through the paged service: fewer
-    # means some answer came from the contiguous escape hatch
+    # means some answer was not decoded at all (an echo or a canned reply)
     if events.get("completed", 0.0) < 2 * n_chats:
         problems.append(f"paged completed={events.get('completed')} < {2 * n_chats}")
     for name, lab, val in rows:

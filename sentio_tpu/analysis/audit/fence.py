@@ -6,9 +6,9 @@ tail latencies cannot hide. The fence makes that class of regression LOUD:
 
 * every compile observed at a registered ``jit_family`` site is counted
   here (per-family totals + a bounded recent-event ring), feeding the
-  ``sentio_tpu_xla_compiles_total`` counter, the flight recorder's per-tick
-  ``xla_compiles`` field, and bench.py's phase-A compile count;
-* with ``SENTIO_COMPILE_FENCE=1``, serving/bench warmup ends with
+  ``sentio_tpu_xla_compiles_total`` counter and the flight recorder's
+  per-tick ``xla_compiles`` field;
+* with ``SENTIO_COMPILE_FENCE=1``, serving warmup ends with
   :func:`arm` — any LATER compile raises :class:`CompileFenceError`
   carrying the offending family and the abstract signature that compiled.
 

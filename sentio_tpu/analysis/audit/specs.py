@@ -8,17 +8,13 @@ paths call), and abstractly lowers every declared variant through the
 engine's OWN jitted functions. Nothing here re-implements a signature: the
 args handed to ``.lower()`` are the engine's live state arrays plus
 host-numpy call args shaped exactly like ``_dispatch_tick`` /
-``_prefill_chunk`` / ``generate`` would shape them.
+``_prefill_chunk`` would shape them.
 
 Variant-space honesty notes:
 
 * the spaces scale with engine config — the micro configs here keep the
   tier-1 lowering count at ~100; a production-config audit enumerates the
   production bucket sets with the same code;
-* ``speculative.spec_generate`` (the contiguous fallback path) shares its
-  batch/width/window axes with ``engine.generate_fused`` (audited there);
-  its variants here sweep the static axes (steps x sampled) at one
-  representative shape point;
 * mesh variants lower the same families with 2-device tp-sharded state and
   record the ``sdy.sharding`` argument signatures; the live params/pool
   sharding specs land in the report's ``sharding`` section.
@@ -79,27 +75,6 @@ def _paged_engine(prefill_chunk: Optional[int] = 8, draft: bool = False,
         max_pages_per_seq=4, steps_per_tick=4, max_tick_steps=8,
         use_pallas=False, kv_quant=kv_quant, **kwargs,
     )
-
-
-def _generator_engine(mesh=None):
-    from sentio_tpu.config import GeneratorConfig
-    from sentio_tpu.runtime.engine import GeneratorEngine
-
-    eng = GeneratorEngine(
-        config=GeneratorConfig(
-            provider="tpu", model_preset="tiny",
-            max_prompt_tokens=24, max_new_tokens=8,
-        ),
-        model_config=_micro_cfg(), mesh=mesh,
-    )
-    # instance-level bucket sets: the audit engine's variant space is the
-    # product of these, and lowering ~100 variants must stay inside a
-    # tier-1 budget. compile_variant_space()/_encode_batch/_stable_steps
-    # all read self.*, so the instance stays self-consistent — a
-    # production-config audit simply skips these overrides.
-    eng.BATCH_BUCKETS = (1, 4)
-    eng.STEP_BUCKETS = (1, 8, 32)
-    return eng
 
 
 # ------------------------------------------------------- per-family lowering
@@ -197,51 +172,6 @@ def _paged_fn(eng, family: str):
     }[family]
 
 
-def _generator_args(eng, family: str, desc: dict):
-    import numpy as np
-
-    from sentio_tpu.models.llama import init_cache
-
-    cfg = eng.model_config
-    rows = desc["rows"]
-    window = desc["window"]
-    cache = init_cache(cfg, rows, window)
-
-    def ids_pos_mask(width: int):
-        ids = np.full((rows, width), eng.tokenizer.pad_id, np.int32)
-        positions = np.zeros((rows, width), np.int32)
-        pad_mask = np.zeros((rows, width), bool)
-        return ids, positions, pad_mask
-
-    if family == "engine.prefill":
-        ids, positions, pad_mask = ids_pos_mask(desc["width"])
-        return (eng.params, ids, positions, cache, pad_mask), {}
-    if family == "engine.decode_step":
-        return (
-            (eng.params, np.zeros((rows, 1), np.int32),
-             np.zeros(rows, np.int32), cache, eng._rng, np.float32(0.0),
-             np.int32(0)),
-            {},
-        )
-    if family == "engine.generate_fused":
-        ids, positions, pad_mask = ids_pos_mask(desc["width"])
-        return (
-            (eng.params, ids, positions, np.ones(rows, np.int32), cache,
-             eng._rng, np.float32(0.0)),
-            {"steps": desc["steps"], "top_k": np.int32(0),
-             "eos_id": eng.tokenizer.eos_id, "pad_mask": pad_mask},
-        )
-    raise KeyError(f"no arg builder for generator family {family!r}")
-
-
-def _generator_fn(eng, family: str):
-    return {
-        "engine.prefill": eng._prefill,
-        "engine.decode_step": eng._decode_step,
-        "engine.generate_fused": eng._generate_fused,
-    }[family]
-
-
 # --------------------------------------------------------------- the report
 
 
@@ -272,26 +202,23 @@ def _sharding_section(mesh) -> dict:
     replicating a tp-sharded weight) fails the manifest diff."""
     import jax
 
+    from sentio_tpu.runtime.paged import ContinuousBatchingEngine
+
     out: dict = {}
-    gen = _generator_engine(mesh=mesh)
-    for path, leaf in jax.tree_util.tree_flatten_with_path(gen.params)[0]:
+    paged = ContinuousBatchingEngine(
+        model_config=_micro_cfg(), max_slots=2, page_size=8,
+        max_pages_per_seq=4, mesh=mesh, use_pallas=False,
+    )
+    for path, leaf in jax.tree_util.tree_flatten_with_path(paged.params)[0]:
         key = "params" + jax.tree_util.keystr(path)
         sharding = getattr(leaf, "sharding", None)
         spec = getattr(sharding, "spec", None)
         out[key] = str(spec)
-
-    from sentio_tpu.runtime.paged import ContinuousBatchingEngine
-
-    paged = ContinuousBatchingEngine(
-        model_config=gen.model_config, params=gen.params,
-        tokenizer=gen.tokenizer, max_slots=2, page_size=8,
-        max_pages_per_seq=4, mesh=mesh, use_pallas=False,
-    )
     out["paged.pool.k"] = str(paged.pool.k.sharding.spec)
     out["paged.pool.v"] = str(paged.pool.v.sharding.spec)
 
-    # mesh lowerings: the sdy.sharding argument signature of the two
-    # hottest families — replication creep inside the COMPILED artifact
+    # mesh lowering: the sdy.sharding argument signature of the hottest
+    # family — replication creep inside the COMPILED artifact
     from sentio_tpu.analysis.audit.lowering import audit_variant
 
     mesh_variants: dict = {}
@@ -302,13 +229,6 @@ def _sharding_section(mesh) -> dict:
                       collect_shardings=True),
         variant=f"steps={steps}",
     )
-    ids, positions, lens, cache, _n, window, pad_mask = gen._encode_batch(
-        ["warm"], 4)
-    low = audit_variant(
-        gen._prefill, (), (gen.params, ids, positions, cache, pad_mask), {},
-        collect_shardings=True,
-    )
-    mesh_variants["engine.prefill"] = dict(low, variant=f"window={window}")
     return {"state": out, "lowered": mesh_variants}
 
 
@@ -317,7 +237,7 @@ def build_audit_report(include_mesh: bool = True) -> dict:
     the manifest-shaped report dict."""
     import jax
 
-    from sentio_tpu.models.llama import init_llama, llama_forward, llama_loss
+    from sentio_tpu.models.llama import init_llama, llama_loss
 
     report: dict = {"version": 1, "families": {}, "sharding": None}
 
@@ -375,55 +295,16 @@ def build_audit_report(include_mesh: bool = True) -> dict:
             lambda desc, _n=name: _paged_args(spec, _n, desc),
         )
 
-    gen = _generator_engine()
-    gen_space = gen.compile_variant_space()
-    for name in ("engine.prefill", "engine.decode_step",
-                 "engine.generate_fused"):
-        report["families"][name] = _audit_family(
-            name, _generator_fn(gen, name), gen_space[name],
-            lambda desc, _n=name: _generator_args(gen, _n, desc),
-        )
-
-    # contiguous speculative fallback: static axes at one shape point (the
-    # batch/width/window axes are the generator's, audited above)
-    from sentio_tpu.models.llama import init_cache
-    from sentio_tpu.runtime.speculative import build_spec_generate
-
+    # training objective (multi-chip dry-run train step): one canonical shape
     import numpy as np
 
-    cfg, dcfg = gen.model_config, _micro_draft_cfg()
-    spec_fn = build_spec_generate(
-        llama_forward, cfg, llama_forward, dcfg,
-        eos_id=gen.tokenizer.eos_id, attn_fn=None,
-    )
-    draft_params = init_llama(jax.random.PRNGKey(11), dcfg)
-    spec_k = 2
-    rows, width, window = 1, 32, 64
-    steps_set = [b for b in gen.STEP_BUCKETS if b <= cfg.max_len - 1]
+    cfg = _micro_cfg()
+    loss_params = init_llama(jax.random.PRNGKey(0), cfg)
 
-    def spec_args(desc):
-        ids = np.full((rows, width), gen.tokenizer.pad_id, np.int32)
-        return (
-            (gen.params, draft_params, ids, np.zeros((rows, width), np.int32),
-             np.ones(rows, np.int32), init_cache(cfg, rows, window),
-             init_cache(dcfg, rows, window)),
-            {"steps": desc["steps"], "k": spec_k,
-             "pad_mask": np.zeros((rows, width), bool), "rng": gen._rng,
-             "temperature": np.float32(0.0), "sampled": desc["sampled"]},
-        )
-
-    report["families"]["speculative.spec_generate"] = _audit_family(
-        "speculative.spec_generate", spec_fn,
-        [{"steps": s, "sampled": smp}
-         for s in steps_set for smp in (False, True)],
-        spec_args,
-    )
-
-    # training objective (multi-chip dry-run train step): one canonical shape
     def loss_args(desc):
         b, t = desc["b"], desc["t"]
         return (
-            (gen.params, cfg, np.zeros((b, t + 1), np.int32),
+            (loss_params, cfg, np.zeros((b, t + 1), np.int32),
              np.ones((b, t + 1), np.int32)),
             {},
         )

@@ -944,7 +944,7 @@ def collect_fault_points(
 def collect_armed_points(
     files: list[tuple[ast.Module, SourceFile]],
 ) -> dict[str, list[str]]:
-    """Every fault point a test or bench mode arms: ``faults.arm(...)``,
+    """Every fault point a test arms: ``faults.arm(...)``,
     ``faults.inject(...)`` context managers, and worker-RPC
     ``inject_fault(...)`` calls. Scoped arms (``transport.recv.r0``)
     count toward their ``transport.recv`` base point."""
@@ -973,16 +973,15 @@ def collect_armed_points(
 
 def fault_point_inventory() -> dict:
     """The committed chaos-coverage map (``analysis/fault_points.json``):
-    every injection point planted in the package, and the test/bench files
-    that arm it. File-level (line numbers churn too fast to commit); the
+    every injection point planted in the package, and the test files that
+    arm it. File-level (line numbers churn too fast to commit); the
     tier-1 cross-reference test regenerates and compares."""
     import json as _json  # noqa: F401 — re-exported for the __main__ dump
 
     from sentio_tpu.analysis.runner import PACKAGE_ROOT, REPO_ROOT, parse_paths
 
     pkg, _errs = parse_paths([PACKAGE_ROOT])
-    arming_roots = [REPO_ROOT / "tests", REPO_ROOT / "bench.py"]
-    tests, _errs = parse_paths([p for p in arming_roots if p.exists()])
+    tests, _errs = parse_paths([REPO_ROOT / "tests"])
     points = collect_fault_points(pkg)
     armed = collect_armed_points(tests)
     return {

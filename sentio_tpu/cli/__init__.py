@@ -1,4 +1,4 @@
-"""Command-line interface: ingest / serve / bench / info / trace / convert /
+"""Command-line interface: ingest / serve / info / trace / convert /
 lint / audit / check.
 
 Parity with /root/reference/src/cli/ (Typer app with ``ingest``/``api``/
@@ -56,18 +56,6 @@ def _cmd_serve(args: argparse.Namespace) -> int:
     if args.index:
         settings.retrieval.index_path = args.index
     run_server(settings)
-    return 0
-
-
-def _cmd_bench(args: argparse.Namespace) -> int:
-    import os
-    import runpy
-    from pathlib import Path
-
-    if args.fast:
-        os.environ["BENCH_FAST"] = "1"
-    bench = Path(__file__).resolve().parents[2] / "bench.py"
-    runpy.run_path(str(bench), run_name="__main__")
     return 0
 
 
@@ -412,9 +400,6 @@ def main(argv: list[str] | None = None) -> int:
     p_serve.add_argument("--index", default="", help="load a persisted dense index (from ingest --save)")
     p_serve.set_defaults(fn=_cmd_serve)
 
-    p_bench = sub.add_parser("bench", help="run the end-to-end benchmark")
-    p_bench.add_argument("--fast", action="store_true")
-    p_bench.set_defaults(fn=_cmd_bench)
 
     p_trace = sub.add_parser("trace", help="run one query and dump the graph execution trace")
     p_trace.add_argument("query")

@@ -218,9 +218,9 @@ class GeneratorConfig:
     model_preset: str = "llama3-8b"  # llama3-8b | tiny
     checkpoint_path: str = ""  # converted checkpoint (cli convert llama ...)
     tokenizer_path: str = ""  # local HF tokenizer dir
-    # speculative decoding: a small same-vocab draft checkpoint accelerates
-    # temperature-0 generation on the contiguous path (greedy-exact —
-    # runtime/speculative.py); empty = disabled
+    # speculative decoding: a small same-vocab draft checkpoint turns each
+    # decode tick into draft/verify rounds (runtime/paged_spec.py: greedy
+    # rows bit-exact, sampled rows marginally exact); empty = disabled
     draft_checkpoint_path: str = ""
     speculative_k: int = 4
     # remote OpenAI-compatible endpoint (provider="openai" — the reference's
@@ -257,9 +257,6 @@ class GeneratorConfig:
     # admission byte-for-byte
     prefix_cache: bool = True
     max_batch_size: int = 8
-    # paged KV + continuous batching as the live /chat decode path; the
-    # contiguous engine remains for streaming and as an escape hatch
-    use_paged_decode: bool = True
     # decode sub-steps fused into one device dispatch per engine tick —
     # amortizes host round trips; admission waits at most one tick. With an
     # empty queue the engine grows ticks toward the max so long generations
@@ -314,7 +311,6 @@ class GeneratorConfig:
             kv_quant=_env_str(["KV_QUANT"], "none"),
             prefix_cache=_env_bool(["PREFIX_CACHE"], True),
             max_batch_size=_env_int(["LLM_MAX_BATCH"], 8),
-            use_paged_decode=_env_bool(["USE_PAGED_KV", "USE_PAGED_DECODE"], True),
             decode_steps_per_tick=_env_int(["DECODE_STEPS_PER_TICK"], 16),
             decode_max_tick_steps=_env_int(["DECODE_MAX_TICK_STEPS"], 64),
             decode_pipeline_depth=_env_int(["DECODE_PIPELINE_DEPTH"], 2),

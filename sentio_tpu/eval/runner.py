@@ -43,8 +43,7 @@ def _build_models(scale: str):
 
     if scale == "tiny":
         return EncoderConfig.tiny(), LlamaConfig.tiny()
-    # "bench": MXU-friendly mini models (dims multiples of 128, bf16) — the
-    # same shapes bench.py serves, so EVAL and BENCH numbers are comparable
+    # "bench": MXU-friendly mini models (dims multiples of 128, bf16)
     enc = EncoderConfig(
         vocab_size=512, dim=512, n_layers=8, n_heads=8, mlp_dim=2048, max_len=512
     )
@@ -74,9 +73,7 @@ def run_eval(
     """Run the eval matrix; returns the EVAL.json payload (pure dict)."""
     import jax
 
-    from sentio_tpu.config import (
-        EmbedderConfig, GeneratorConfig, RerankConfig, Settings,
-    )
+    from sentio_tpu.config import EmbedderConfig, RerankConfig, Settings
     from sentio_tpu.graph.factory import GraphConfig, build_basic_graph
     from sentio_tpu.graph.state import create_initial_state
     from sentio_tpu.ops.bm25 import BM25Index
@@ -88,7 +85,6 @@ def run_eval(
         DenseRetriever, HybridRetriever, SparseRetriever,
     )
     from sentio_tpu.ops.verifier import AnswerVerifier
-    from sentio_tpu.runtime.engine import GeneratorEngine
     from sentio_tpu.runtime.paged import ContinuousBatchingEngine
     from sentio_tpu.runtime.replica import ReplicaSet
     from sentio_tpu.runtime.service import PagedGenerationService
@@ -219,14 +215,8 @@ def run_eval(
     service = None
     try:
         if want & {"full_paged", "batched"}:
-            engine = GeneratorEngine(
-                config=GeneratorConfig(model_preset="eval", max_new_tokens=new_tokens),
-                model_config=llm_cfg,
-            )
             paged = ContinuousBatchingEngine(
                 model_config=llm_cfg,
-                params=engine.params,
-                tokenizer=engine.tokenizer,
                 max_slots=max(concurrency, 4),
                 page_size=16,
                 # per-sequence window = the model's full context — prompts
@@ -251,7 +241,7 @@ def run_eval(
             service = ReplicaSet([PagedGenerationService(paged)],
                                  supervise=False)
             generator = LLMGenerator(
-                provider=TpuProvider(engine=engine, service=service),
+                provider=TpuProvider(service=service),
                 config=settings.generator,
             )
             verifier = AnswerVerifier(generator=generator, config=settings.generator)

@@ -28,7 +28,7 @@ DEFAULT_DIR = Path(__file__).resolve().parents[2] / ".jax_compile_cache"
 
 def ensure_compile_cache() -> str:
     """Call first thing in every entry point that compiles (``cli`` main,
-    ``bench.py``, the replica worker entries). Returns the directory in
+    the replica worker entries, the smoke). Returns the directory in
     effect. Child processes inherit the environment, so a whole process
     tree — router, workers, a smoke's server child — shares one cache."""
     placed = os.environ.get(ENV_VAR)

@@ -12,7 +12,7 @@ Two bounded, thread-safe stores:
 * a **tick ring buffer** — one event per engine pump tick (wall time, batch
   occupancy, queue depth, prefill/decode token counts, speculative accepts,
   prefix-cache hits, page-pool free/used), appended by the decode pump and
-  read by ``/debug/flight``, ``sentio trace``, and ``bench.py``. The same
+  read by ``/debug/flight`` and ``sentio trace``. The same
   ring carries the replica-supervision vocabulary: ``replica_health``,
   ``pump_stall``, ``inbox_handoff``, ``tick_failure``, and
   ``stream_resumed`` (a delivered-token stream spliced onto a survivor —

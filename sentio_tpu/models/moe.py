@@ -241,7 +241,7 @@ def moe_serving_forward(
     attn_fn=None,
 ) -> tuple[Array, Optional[Cache]]:
     """Two-tuple adapter matching ``llama_forward``'s serving contract
-    (runtime/engine.py, runtime/paged.py unpack ``logits, cache``); the
+    (runtime/paged.py unpacks ``logits, cache``); the
     router aux loss is a training-only signal and is dropped here."""
     logits, cache, _ = moe_forward(
         params, cfg, ids, positions, cache, cache_index, pad_mask, attn_fn

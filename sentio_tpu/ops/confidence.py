@@ -1,8 +1,9 @@
 """Answer-confidence scoring for the verify gate.
 
-The LLM self-audit (ops/verifier.py) costs a full decode round-trip —
-BENCH_r06 measured it at 482 ms p50, MORE than generation itself. Most of
-that spend buys nothing: when the model decoded its answer with uniformly
+The LLM self-audit (ops/verifier.py) costs a full decode round-trip: a
+second prompt (question, sources and the answer) prefilled and a verdict
+decoded, on the engine the answers decode on. What it costs on the chip is
+not measured (ROADMAP S6). Most of that spend buys nothing: when the model decoded its answer with uniformly
 high token probability AND retrieval produced a clearly-separated top
 document, the audit almost always returns ``pass``. This module turns the
 two signals the serving path already computes for free into one calibrated

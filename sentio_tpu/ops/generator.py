@@ -5,8 +5,9 @@ chat_adapter.py:29-94: numbered ``[n] Source … score`` context assembly with
 an instruction footer, temperature-by-mode (fast/balanced/quality/creative =
 0.0/0.3/0.2/0.7), sync + streaming paths, and a provider seam — the exact
 swap point the reference used for OpenAI-compatible APIs — now dispatching
-to the in-process :class:`GeneratorEngine`. An ``echo`` provider is the
-deterministic offline fake (the reference's mock-mode test pattern).
+to the in-process continuous-batching service (runtime/service.py behind the
+replica tier). An ``echo`` provider is the deterministic offline fake (the
+reference's mock-mode test pattern).
 """
 
 from __future__ import annotations
@@ -69,19 +70,13 @@ class EchoProvider:
 
 @dataclass
 class TpuProvider:
-    """Dispatches to the in-process TPU runtime. With a ``service`` (the
-    continuous-batching pump over the paged KV pool) attached, every chat
-    call joins the SHARED decode batch — concurrent requests coalesce on
-    device instead of serializing (closes the round-1 gap where
-    runtime/paged.py was dead code). The contiguous ``engine`` remains the
-    streaming path and the fallback when paged decode is disabled."""
+    """Dispatches to the in-process TPU runtime: every chat call joins the
+    SHARED decode batch of ``service`` (the continuous-batching pump over
+    the paged KV pool, behind the replica tier) — concurrent requests
+    coalesce on device instead of serializing. A failure of the service is
+    the caller's to see: typed errors as they are, anything else as raised."""
 
-    engine: object = None  # GeneratorEngine
-    service: object = None  # PagedGenerationService (continuous batching)
-    # SpeculativeDecoder: draft-accelerated decode on the contiguous path
-    # (greedy calls bit-exact, sampled calls distribution-exact via
-    # rejection-sampling acceptance)
-    speculative: object = None
+    service: object  # ReplicaSet | PagedGenerationService
     name: str = "tpu"
 
     def _tenant_kwargs(self, tenant: Optional[str],
@@ -133,42 +128,14 @@ class TpuProvider:
              tenant: Optional[str] = None,
              priority: Optional[str] = None,
              stats: Optional[dict] = None) -> str:
-        if self.service is not None:
-            try:
-                result = self.service.generate(
-                    prompt, max_new_tokens=max_new_tokens, temperature=temperature,
-                    request_id=request_id, deadline_ts=deadline_ts,
-                    **self._tenant_kwargs(tenant, priority),
-                )
-                if result.finish_reason != "error":
-                    self._fill_stats(stats, result)
-                    return result.text
-            except Exception as exc:  # noqa: BLE001 — contiguous engine is the escape hatch
-                if getattr(exc, "soft_fail_exempt", False):
-                    # shed / expired deadline: retrying on the contiguous
-                    # engine would serve a caller that gave up (or double
-                    # the load the shed was protecting against) — fail fast
-                    raise
-                if self.engine is None:
-                    raise
-                # loud: the answer that follows did NOT come from the paged
-                # path (its Pallas kernel, its radix cache) — a 200 must not
-                # be the only trace of a decode path the device refused
-                logger.warning("paged decode failed (%s); the contiguous "
-                               "engine answers this request", exc,
-                               exc_info=True)
-            if self.engine is None:
-                raise RuntimeError("paged decode failed and no contiguous engine")
-        if self.speculative is not None:
-            # greedy calls are bit-exact, sampled calls distribution-exact
-            # (rejection-sampling acceptance) — both legitimately served by
-            # the draft-accelerated path
-            return self.speculative.generate(
-                [prompt], max_new_tokens=max_new_tokens, temperature=temperature
-            )[0].text
-        result = self.engine.generate(
-            [prompt], max_new_tokens=max_new_tokens, temperature=temperature
-        )[0]
+        result = self.service.generate(
+            prompt, max_new_tokens=max_new_tokens, temperature=temperature,
+            request_id=request_id, deadline_ts=deadline_ts,
+            **self._tenant_kwargs(tenant, priority),
+        )
+        if result.finish_reason == "error":
+            raise RuntimeError("paged decode failed")
+        self._fill_stats(stats, result)
         return result.text
 
     def stream(self, prompt: str, max_new_tokens: int, temperature: float,
@@ -178,40 +145,22 @@ class TpuProvider:
                priority: Optional[str] = None,
                stats: Optional[dict] = None,
                resumable: Optional[bool] = None) -> Iterator[str]:
-        if self.service is not None and hasattr(self.service, "generate_stream"):
-            yielded_any = False
-            stream_kwargs = self._tenant_kwargs(tenant, priority)
-            if stats is not None and self._stream_takes_stats():
-                # only our own service implementations take stats_out; a
-                # test fake with the bare generate_stream signature keeps
-                # working (the gate then sees no logprobs and never skips)
-                stream_kwargs["stats_out"] = stats
-            if resumable is False and self._stream_takes("resumable"):
-                # per-request opt-out of resume-by-replay (PR 14's knob,
-                # ReplicaSet.generate_stream): a mid-stream replica death
-                # then keeps the typed mid-stream error. Only the replica
-                # tier takes it; bare services have nothing to resume.
-                stream_kwargs["resumable"] = False
-            try:
-                for piece in self.service.generate_stream(
-                    prompt, max_new_tokens=max_new_tokens, temperature=temperature,
-                    request_id=request_id, deadline_ts=deadline_ts,
-                    **stream_kwargs,
-                ):
-                    yielded_any = True
-                    yield piece
-                return
-            except Exception as exc:  # noqa: BLE001 — contiguous engine is the escape hatch
-                # restarting after partial output would duplicate the
-                # answer; typed shed/deadline errors must not be retried
-                if (yielded_any or self.engine is None
-                        or getattr(exc, "soft_fail_exempt", False)):
-                    raise
-                logger.warning("paged stream failed (%s); the contiguous "
-                               "engine answers this request", exc,
-                               exc_info=True)
-        yield from self.engine.stream(
-            prompt, max_new_tokens=max_new_tokens, temperature=temperature
+        stream_kwargs = self._tenant_kwargs(tenant, priority)
+        if stats is not None and self._stream_takes_stats():
+            # only our own service implementations take stats_out; a
+            # test fake with the bare generate_stream signature keeps
+            # working (the gate then sees no logprobs and never skips)
+            stream_kwargs["stats_out"] = stats
+        if resumable is False and self._stream_takes("resumable"):
+            # per-request opt-out of resume-by-replay (PR 14's knob,
+            # ReplicaSet.generate_stream): a mid-stream replica death
+            # then keeps the typed mid-stream error. Only the replica
+            # tier takes it; bare services have nothing to resume.
+            stream_kwargs["resumable"] = False
+        yield from self.service.generate_stream(
+            prompt, max_new_tokens=max_new_tokens, temperature=temperature,
+            request_id=request_id, deadline_ts=deadline_ts,
+            **stream_kwargs,
         )
 
 
@@ -634,19 +583,14 @@ class LLMGenerator:
         )
 
 
-def create_generator(
-    settings=None,
-    engine=None,
-    service=None,
-    speculative=None,
-) -> LLMGenerator:
+def create_generator(settings=None, service=None) -> LLMGenerator:
     """env→generator wiring (reference: llm/factory.py:14-69)."""
     settings = settings or get_settings()
     cfg = settings.generator
-    if cfg.provider == "tpu" and engine is not None:
-        provider = TpuProvider(engine=engine, service=service, speculative=speculative)
+    if cfg.provider == "tpu" and service is not None:
+        provider = TpuProvider(service=service)
     elif cfg.provider == "tpu":
-        # no engine supplied (tests, host-only dev) → deterministic echo
+        # no service supplied (tests, host-only dev) → deterministic echo
         provider = EchoProvider()
     elif cfg.provider == "openai":
         provider = OpenAIProvider.from_config(cfg)

@@ -541,34 +541,30 @@ class ContinuousBatchingEngine:
     ) -> None:
         """``forward_fn`` swaps the prefill model family (llama_forward
         contract); the fused decode tick detects the family per layer (a
-        ``moe`` subtree routes through models/moe.py). See
-        runtime/engine.py's identical seam."""
+        ``moe`` subtree routes through models/moe.py)."""
         import jax
 
-        from sentio_tpu.models.llama import init_llama
-        from sentio_tpu.models.tokenizer import ByteTokenizer
-
-        self.cfg = model_config or LlamaConfig.tiny()
-        self.tokenizer = tokenizer or ByteTokenizer(self.cfg.vocab_size)
         from sentio_tpu.models.llama import llama_forward
         from sentio_tpu.models.moe import MoeConfig, moe_serving_forward
 
-        is_moe = isinstance(self.cfg, MoeConfig)
+        from sentio_tpu.models.tokenizer import ByteTokenizer
+
+        self.cfg = model_config or LlamaConfig.tiny()
         explicit_params = params
         if params is None:
-            if is_moe:
-                from sentio_tpu.models.moe import init_moe
+            # seeded init of the configuration's family, placed by its rules
+            from sentio_tpu.runtime.weights import load_decoder
 
-                params = init_moe(jax.random.PRNGKey(rng_seed), self.cfg)
-            else:
-                params = init_llama(jax.random.PRNGKey(rng_seed), self.cfg)
+            params = load_decoder(
+                mesh=mesh, model_config=self.cfg, rng_seed=rng_seed).params
+        self.tokenizer = tokenizer or ByteTokenizer(self.cfg.vocab_size)
+        is_moe = isinstance(self.cfg, MoeConfig)
         if mesh is None and not all(
                 isinstance(leaf, jax.Array)
                 for leaf in jax.tree_util.tree_leaves(params)):
-            # a checkpoint tree handed over as host numpy (worker replicas
-            # load it memory-mapped) goes to the device ONCE, here — as jit
-            # arguments its leaves would be uploaded again on every
-            # dispatch. A tree already on the device is kept as it is
+            # a tree handed over as host numpy goes to the device ONCE,
+            # here — as jit arguments its leaves would be uploaded again on
+            # every dispatch. A tree already on the device is kept as it is
             # (replicas share ONE copy of the weights by identity). Under a
             # mesh the caller has placed the tree by its sharding rules.
             params = jax.device_put(params)

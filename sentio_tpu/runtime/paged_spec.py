@@ -1,13 +1,14 @@
 """Speculative decoding INSIDE the paged continuous-batching engine.
 
-Round 4 shipped draft-and-verify speculation for the contiguous engine only
-(runtime/speculative.py) — which meant the documented LLM_DRAFT_CHECKPOINT
-knob was dead in the default deployment (USE_PAGED_KV=1; flagged by the
-round-4 advisor). This module fuses the same exact-by-construction
-draft/verify/accept math into the paged engine's tick protocol, so
-continuous batching and speculation compose: every live slot drafts and
-verifies in the same fused dispatch, page tables stay the source of truth,
-and requests still join/leave without recompilation.
+Decode is bandwidth-bound — every step streams the full target weights for
+one token per row. A small draft model proposes ``k`` tokens, then the target
+scores all of them in ONE forward of T = k+1, amortizing its weight stream
+over up to k+1 emitted tokens. The draft only changes HOW FAST tokens appear,
+never the output's law. This module fuses that draft/verify/accept math into
+the paged engine's tick protocol, so continuous batching and speculation
+compose: every live slot drafts and verifies in the same fused dispatch,
+page tables stay the source of truth, and requests still join/leave without
+recompilation.
 
 Design (one compiled ``spec_tick`` per (k, out_w) pair):
 
@@ -16,11 +17,10 @@ Design (one compiled ``spec_tick`` per (k, out_w) pair):
    attention reads the whole past KV anyway, so the extra densification
    traffic is second-order next to the target's weight stream — the thing
    speculation amortizes.
-2. **Rounds** — a ``lax.while_loop`` of draft(k)+verify(k+1)+accept rounds,
-   identical math to runtime/speculative.py (greedy rows: longest
-   agree-prefix, bit-exact vs plain decode; sampled rows: rejection
-   sampling via :func:`runtime.speculative.accept_and_correct`, marginally
-   exact). Both rules are computed and selected PER ROW by temperature, so
+2. **Rounds** — a ``lax.while_loop`` of draft(k)+verify(k+1)+accept rounds
+   (greedy rows: longest agree-prefix then the target's own correction
+   token, bit-exact vs plain decode; sampled rows: rejection sampling via
+   :func:`accept_and_correct`, marginally exact). Both rules are computed and selected PER ROW by temperature, so
    mixed batches serve correctly. Per-row tick budgets bound emissions;
    EOS halts rows (unless ignore_eos).
 3. **Scatter back** — the dense cache writes back through the same
@@ -48,16 +48,55 @@ The host fetches ONE packed buffer per tick — ``[S, out_w + 3]`` rows of
 ``[echo, emitted_count, verify_count, tokens...]`` — preserving the
 engine's one-fetch-per-tick cost model.
 
-Cache discipline is inherited from speculative.py: both models write k/v at
-absolute positions; entries beyond a row's accepted length are stale but
-never attended (position-based causal masks) and are overwritten by later
-rounds/ticks at the same offsets.
+Cache discipline: both models write k/v at absolute positions; entries
+beyond a row's accepted length are stale but never attended (position-based
+causal masks) and are overwritten by later rounds/ticks at the same offsets.
 """
 
 from __future__ import annotations
 
 from sentio_tpu.analysis.audit.registry import jit_family
-from sentio_tpu.runtime.speculative import accept_and_correct
+
+
+def accept_and_correct(rng, drafts, qdists, tprobs):
+    """Rejection-sampling acceptance for sampled speculation.
+
+    drafts [B, k] proposed tokens; qdists [B, k, V] the draft's sampling
+    distributions; tprobs [B, k+1, V] the target's distributions at the
+    verified positions. Accept d_j with probability min(1, p_t(d_j)/q(d_j))
+    while the prefix holds; at the first rejection sample the correction
+    from the residual ``norm(relu(p_t - q))``, and after a full accept
+    sample the bonus token from the target's (k+1)-th distribution. The
+    emitted marginal equals sampling from the target alone — the standard
+    speculative-sampling guarantee (tested empirically in
+    tests/test_paged_spec.py).
+
+    Returns (n_accept [B], correction [B]).
+    """
+    import jax
+    import jax.numpy as jnp
+
+    b, k = drafts.shape
+    rng_u, rng_c = jax.random.split(rng)
+    u = jax.random.uniform(rng_u, (b, k))
+    p_chosen = jnp.take_along_axis(tprobs[:, :k], drafts[..., None], axis=2)[..., 0]
+    q_chosen = jnp.take_along_axis(qdists, drafts[..., None], axis=2)[..., 0]
+    ratio = p_chosen / jnp.maximum(q_chosen, 1e-20)
+    acc = u < jnp.minimum(ratio, 1.0)
+    n_accept = jnp.cumprod(acc.astype(jnp.int32), axis=1).sum(axis=1)
+
+    # correction distribution at position j* = n_accept
+    resid = jnp.maximum(tprobs[:, :k] - qdists, 0.0)          # [B, k, V]
+    resid_full = jnp.concatenate([resid, tprobs[:, k:]], axis=1)
+    sel = jnp.take_along_axis(
+        resid_full, n_accept[:, None, None], axis=1
+    )[:, 0]                                                    # [B, V]
+    norm = sel.sum(-1, keepdims=True)
+    tsel = jnp.take_along_axis(tprobs, n_accept[:, None, None], axis=1)[:, 0]
+    # identical target/draft distributions → zero residual → target dist
+    dist = jnp.where(norm > 1e-9, sel / jnp.maximum(norm, 1e-9), tsel)
+    correction = jax.random.categorical(rng_c, jnp.log(dist + 1e-20), axis=-1)
+    return n_accept, correction.astype(jnp.int32)
 
 
 def build_spec_tick(target_fwd, cfg, draft_fwd, dcfg, eos_id: int,

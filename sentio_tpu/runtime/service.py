@@ -407,8 +407,7 @@ class PagedGenerationService:
     ) -> Iterator[str]:
         """Streaming variant: yields decoded text increments as the shared
         decode batch produces them (chunks of up to steps_per_tick tokens —
-        the streaming request STAYS in the continuous batch instead of
-        monopolizing a contiguous-cache engine). UTF-8 safe: bytes buffer
+        the streaming request STAYS in the continuous batch). UTF-8 safe: bytes buffer
         until they decode cleanly. Deadline semantics match
         :meth:`generate`; a deadline that passes mid-stream raises
         :class:`DeadlineExceededError` from the iterator.

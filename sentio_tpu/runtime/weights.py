@@ -6,7 +6,8 @@ equivalent configuration surface is a *checkpoint path* per model family
 (generator, embedder, reranker): ``cli convert`` writes framework
 checkpoints (runtime/checkpoint.py format, meta carrying the model family
 and config), and this module loads them back into (params, model_config,
-tokenizer) triples for the constructors in ops/ and runtime/engine.py.
+tokenizer) triples for the constructors in ops/; :func:`load_decoder` is
+the one place the served decoder is resolved, initialised and placed.
 
 Resolution order per model (mirrors the reference's provider-selection
 semantics, factory.py:20-27 there, with its mock-mode fallback):
@@ -21,6 +22,7 @@ semantics, factory.py:20-27 there, with its mock-mode fallback):
 from __future__ import annotations
 
 import logging
+from dataclasses import dataclass
 from typing import Any, Optional
 
 from sentio_tpu.runtime.checkpoint import CheckpointError, load_pytree
@@ -101,3 +103,97 @@ def load_model(
         getattr(model_config, "n_layers", "?"),
     )
     return params, model_config, tokenizer
+
+
+@dataclass(frozen=True)
+class Decoder:
+    """The served decoder as every caller needs it: weights on the device
+    (sharded under a mesh), the configuration they were built for, and the
+    tokenizer that goes with them."""
+
+    params: Any
+    model_config: Any
+    tokenizer: Any
+
+
+def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
+                 mmap: bool = False) -> Decoder:
+    """Checkpoint or seeded init → which family → placed on the device.
+
+    With ``cfg.checkpoint_path`` the weights, their configuration (llama or
+    moe, from the checkpoint's meta) and, with ``cfg.tokenizer_path``, the
+    tokenizer come from the checkpoint. Without one the weights are the
+    seeded random init of ``model_config``'s family (the deterministic
+    fake-model mode of tests and offline development; ``cfg.model_preset``
+    picks the configuration when none is given) under a byte tokenizer.
+    ``cfg`` is a ``GeneratorConfig``; None means no checkpoint. The tree goes
+    to its final placement ONCE: by ``LLAMA_TP_RULES`` / ``MOE_EP_RULES``
+    under a mesh, onto the default device without one. ``mmap`` maps the
+    checkpoint's leaves in place (worker processes on one host share one
+    page-cache copy)."""
+    import jax
+
+    from sentio_tpu.models.llama import LlamaConfig, init_llama
+    from sentio_tpu.models.moe import MoeConfig, init_moe
+    from sentio_tpu.models.tokenizer import ByteTokenizer
+    from sentio_tpu.parallel.sharding import (
+        LLAMA_TP_RULES,
+        MOE_EP_RULES,
+        shard_params,
+    )
+
+    params = tokenizer = None
+    if cfg is not None and cfg.checkpoint_path:
+        params, model_config, tokenizer = load_model(
+            cfg.checkpoint_path, tokenizer_path=cfg.tokenizer_path, mmap=mmap,
+        )
+        if not isinstance(model_config, LlamaConfig):
+            raise WeightsError(
+                f"checkpoint {cfg.checkpoint_path!r} holds a "
+                f"{type(model_config).__name__} model — the generator "
+                "serves decoder families (llama, moe)"
+            )
+    if model_config is None:
+        preset = cfg.model_preset if cfg is not None else "tiny"
+        model_config = (LlamaConfig.tiny() if preset == "tiny"
+                        else LlamaConfig.llama3_8b())
+    is_moe = isinstance(model_config, MoeConfig)
+    if params is None:
+        init = init_moe if is_moe else init_llama
+        params = init(jax.random.PRNGKey(rng_seed), model_config)
+    params = shard_params(
+        params, mesh, MOE_EP_RULES if is_moe else LLAMA_TP_RULES)
+    return Decoder(
+        params=params, model_config=model_config,
+        tokenizer=tokenizer or ByteTokenizer(model_config.vocab_size),
+    )
+
+
+def device_stats(mesh, model_config) -> dict:
+    """Health-endpoint payload: device kind, count, mesh shape, the served
+    model's size and, where the backend reports it, device memory."""
+    import jax
+
+    devices = jax.devices()
+    stats = {
+        "platform": devices[0].platform if devices else "none",
+        "kind": devices[0].device_kind if devices else "none",
+        "n_devices": len(devices),
+        "mesh": dict(mesh.shape) if mesh is not None else None,
+        "model": {
+            "layers": model_config.n_layers,
+            "dim": model_config.dim,
+            "vocab": model_config.vocab_size,
+        },
+    }
+    try:  # HBM headroom where the backend exposes it
+        m = devices[0].memory_stats()
+        if m:
+            stats["memory"] = {
+                "bytes_in_use": m.get("bytes_in_use"),
+                "peak_bytes_in_use": m.get("peak_bytes_in_use"),
+                "bytes_limit": m.get("bytes_limit"),
+            }
+    except Exception:  # noqa: BLE001 — device stats are best-effort diagnostics
+        pass
+    return stats

@@ -3,12 +3,11 @@
 The thread-mode ReplicaSet (runtime/replica.py) made the replica a complete
 *logical* failure domain — health state machine, breakers, watchdog, inbox
 handoff — but all N pumps share one Python process, so a "replica kill" is
-an injected exception and N dispatches contend for one GIL (BENCH_r08's GIL
-probe measured a 0.978 scaling ratio at 1→2 in-process replicas). This
-module promotes the replica to a real **OS-level** failure domain, the way
-production inference stacks isolate engine crashes from the frontend
-(vLLM's engine-per-process serving, Orca-style continuous-batching
-workers):
+an injected exception and N dispatches contend for one GIL (what that
+costs on a TPU host is not measured). This module promotes the replica to a
+real **OS-level** failure domain, the way production inference stacks
+isolate engine crashes from the frontend (vLLM's engine-per-process
+serving, Orca-style continuous-batching workers):
 
 * :func:`worker_main` runs in a child process (**spawn** start method —
   JAX is not fork-safe: a fork duplicates its runtime threads' locks in a
@@ -262,39 +261,32 @@ def default_service_factory(
     from sentio_tpu.runtime.paged import ContinuousBatchingEngine
     from sentio_tpu.runtime.service import PagedGenerationService
 
-    params = tokenizer = None
+    from sentio_tpu.config import GeneratorConfig
+    from sentio_tpu.models.llama import LlamaConfig
+    from sentio_tpu.models.moe import MoeConfig
+    from sentio_tpu.runtime.weights import load_decoder, load_model
+
     cfg = None
-    if checkpoint_path:
-        from sentio_tpu.runtime.weights import load_model
-
-        params, cfg, tokenizer = load_model(
-            checkpoint_path,
-            expect_family=model_family,
-            tokenizer_path=tokenizer_path,
-            mmap=True,
-        )
-    elif model_config is not None:
-        if model_family == "moe":
-            from sentio_tpu.models.moe import MoeConfig
-
-            cfg = MoeConfig(**model_config)
-        else:
-            from sentio_tpu.models.llama import LlamaConfig
-
-            cfg = LlamaConfig(**model_config)
+    if not checkpoint_path:
+        family = MoeConfig if model_family == "moe" else LlamaConfig
+        cfg = (family(**model_config) if model_config is not None
+               else LlamaConfig.tiny())
+    decoder = load_decoder(
+        GeneratorConfig(checkpoint_path=checkpoint_path,
+                        tokenizer_path=tokenizer_path),
+        model_config=cfg, rng_seed=rng_seed, mmap=True,
+    )
     engine_kwargs = dict(engine_kwargs or {})
     if draft_checkpoint_path:
-        from sentio_tpu.runtime.weights import load_model
-
         draft_params, draft_cfg, _ = load_model(
             draft_checkpoint_path, expect_family="llama", mmap=True,
         )
         engine_kwargs.setdefault("draft_params", draft_params)
         engine_kwargs.setdefault("draft_config", draft_cfg)
     engine = ContinuousBatchingEngine(
-        model_config=cfg,
-        params=params,
-        tokenizer=tokenizer,
+        model_config=decoder.model_config,
+        params=decoder.params,
+        tokenizer=decoder.tokenizer,
         rng_seed=rng_seed,
         **engine_kwargs,
     )

@@ -33,6 +33,7 @@ from sentio_tpu.config import Settings, get_settings
 from sentio_tpu.infra.exceptions import ErrorHandler, RateLimitError, SentioError
 from sentio_tpu.infra.metrics import get_metrics
 from sentio_tpu.infra.security import SECURITY_HEADERS, setup_log_sanitization
+from sentio_tpu.runtime.weights import device_stats
 from sentio_tpu.serve.dependencies import DependencyContainer, get_container, set_container
 from sentio_tpu.serve.schemas import (
     MAX_DEADLINE_MS,
@@ -382,8 +383,9 @@ async def chat(request: web.Request) -> web.Response:
             except SentioError:
                 raise  # typed shed/deadline → 429/503/504 with Retry-After
             except Exception:  # noqa: BLE001 — closed/broken paged path
-                # the provider still has its contiguous-engine escape hatch;
-                # pre-blocking here would 500 a servable stream
+                # this pre-check only turns a typed shed into a status
+                # before the SSE headers go out; what else the service
+                # raises, the stream itself reports
                 logger.debug("stream admission pre-check skipped", exc_info=True)
         return await _chat_stream(request, container, req, deadline_ts,
                                   tenant=tenant, priority=priority,
@@ -678,22 +680,11 @@ def _speculative_info(container: DependencyContainer) -> dict:
         out["active"] = False
         return out
     reason = ""
-    if gen.use_paged_decode:
-        if container.mesh is not None:
-            reason = "device mesh configured (paged speculation is single-chip)"
-        elif gen.prefill_chunk:
-            reason = ("PREFILL_CHUNK set (chunked prefill excludes paged "
-                      "speculation)")
-    else:
-        # contiguous path (USE_PAGED_KV=0): the SpeculativeDecoder is built
-        # only for a single-chip in-process engine — mirror that gating
-        # (serve/dependencies.py speculative property) so /info never
-        # reports active=true for a decoder that was never constructed
-        if container.mesh is not None:
-            reason = ("device mesh configured (contiguous speculation is "
-                      "single-chip)")
-        elif container.engine is None:
-            reason = "no in-process engine (contiguous speculation needs one)"
+    if container.mesh is not None:
+        reason = "device mesh configured (paged speculation is single-chip)"
+    elif gen.prefill_chunk:
+        reason = ("PREFILL_CHUNK set (chunked prefill excludes paged "
+                  "speculation)")
     out["active"] = not reason
     if reason:
         out["ignored_reason"] = reason
@@ -711,7 +702,7 @@ def _model_config_of(component) -> Optional[dict]:
 async def info(request: web.Request) -> web.Response:
     container: DependencyContainer = request.app["container"]
     settings = container.settings
-    engine = container.engine
+    decoder = container.decoder
     service = container.peek("generation_service")
     serving = service.stats() if service is not None else {}
     return web.json_response(
@@ -737,7 +728,7 @@ async def info(request: web.Request) -> web.Response:
             "generator": {
                 "provider": settings.generator.provider,
                 "preset": settings.generator.model_preset,
-                "model": _model_config_of(engine),
+                "model": _model_config_of(decoder),
                 "verifier": settings.generator.use_verifier,
                 # the paged decode path as the engine resolved it: page
                 # representation, and whether decode attention is the
@@ -745,13 +736,13 @@ async def info(request: web.Request) -> web.Response:
                 "kv_quant": serving.get("kv_quant"),
                 "paged_attention": serving.get("paged_attention"),
                 "pool_hbm_bytes": serving.get("pool_hbm_bytes"),
-                # a configured draft accelerates BOTH serving paths now —
-                # paged (runtime/paged_spec.py, the default) and contiguous
-                # (runtime/speculative.py); the genuine exclusions (chunked
-                # prefill, device mesh) are surfaced here for operators
+                # a configured draft accelerates the decode tick
+                # (runtime/paged_spec.py); its exclusions (chunked prefill,
+                # device mesh) are surfaced here for operators
                 "speculative": _speculative_info(container),
             },
-            "device": engine.device_stats() if engine is not None else None,
+            "device": (device_stats(container.mesh, decoder.model_config)
+                       if decoder is not None else None),
             # where this process keeps JAX's persistent compile cache
             # (infra/compile_cache.py; None = not placed, e.g. under tests)
             "compile_cache_dir": os.environ.get("JAX_COMPILATION_CACHE_DIR"),

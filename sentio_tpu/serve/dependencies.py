@@ -192,56 +192,28 @@ class DependencyContainer:
         return self._get("reranker", build)
 
     @property
-    def engine(self):
+    def decoder(self):
+        """The served decoder — weights on the device, their configuration
+        and tokenizer (runtime/weights.py::load_decoder) — or None when the
+        provider is not the in-process one."""
+
         def build():
             cfg = self.settings.generator
             if cfg.provider != "tpu":
                 return None
-            from sentio_tpu.models.llama import LlamaConfig
-            from sentio_tpu.runtime.engine import GeneratorEngine
+            from sentio_tpu.runtime.weights import load_decoder
 
-            model_cfg = LlamaConfig.tiny() if cfg.model_preset == "tiny" else None
-            return GeneratorEngine(config=cfg, model_config=model_cfg, mesh=self.mesh)
+            return load_decoder(cfg, mesh=self.mesh)
 
-        return self._get("engine", build)
-
-    @property
-    def speculative(self):
-        """Draft-accelerated decoder over the contiguous engine
-        (runtime/speculative.py) — built when a draft checkpoint is
-        configured. Greedy calls are bit-exact and sampled calls
-        distribution-exact, so it transparently serves all non-paged
-        requests."""
-
-        def build():
-            cfg = self.settings.generator
-            if cfg.provider != "tpu" or not cfg.draft_checkpoint_path:
-                return None
-            if cfg.use_paged_decode:
-                # the PAGED engine itself speculates now (generation_service
-                # loads the draft into the continuous-batching tick) — the
-                # contiguous SpeculativeDecoder would be dead weight here
-                return None
-            engine = self.engine
-            if engine is None or self.mesh is not None:
-                return None  # mesh-backed engines: spec not wired yet
-            from sentio_tpu.runtime.speculative import SpeculativeDecoder
-            from sentio_tpu.runtime.weights import load_model
-
-            draft_params, draft_cfg, _ = load_model(cfg.draft_checkpoint_path)
-            return SpeculativeDecoder(
-                engine, draft_params, draft_cfg, k=cfg.speculative_k
-            )
-
-        return self._get("speculative", build)
+        return self._get("decoder", build)
 
     @property
     def generation_service(self):
         """Multi-replica continuous-batching tier over the paged KV pool —
         the default decode path for /chat. A :class:`ReplicaSet` owns
         REPLICAS independent engine+service replicas (private pool, radix
-        tree, and pump each; weights/tokenizer shared with the contiguous
-        engine, which keeps escape-hatch duty), routes by radix-prefix
+        tree, and pump each; one copy of the weights and one tokenizer
+        shared by all), routes by radix-prefix
         affinity then least-loaded, and applies per-tenant weighted fair
         queueing in front. REPLICAS=1 degenerates to the single-engine
         behavior every existing test pins."""
@@ -249,10 +221,8 @@ class DependencyContainer:
         def build():
             cfg = self.settings.generator
             serve = self.settings.serve
-            if cfg.provider != "tpu" or not cfg.use_paged_decode:
-                return None
-            engine = self.engine
-            if engine is None:
+            decoder = self.decoder
+            if decoder is None:
                 return None
             from sentio_tpu.runtime.paged import ContinuousBatchingEngine
             from sentio_tpu.runtime.replica import ReplicaSet
@@ -286,7 +256,7 @@ class DependencyContainer:
                     and not (replica_mode == "socket"
                              and serve.parsed_replica_workers())):
                 # workers are spawned on THIS host, and a chip belongs to
-                # one process: this router already holds it (the engine's
+                # one process: this router already holds it (the decoder's
                 # weights above, the embedder, the reranker), so a worker
                 # that needs the same chip hangs in start-up until
                 # warmup_budget_s. Refuse now, and say why.
@@ -305,9 +275,8 @@ class DependencyContainer:
                         "their chips on other hosts."
                     )
 
-            # paged speculative decoding: a configured draft checkpoint now
-            # accelerates the DEFAULT serving path (runtime/paged_spec.py)
-            # instead of being dead under USE_PAGED_KV=1 (round-4 advisor)
+            # paged speculative decoding (runtime/paged_spec.py): a
+            # configured draft checkpoint accelerates the decode tick
             draft_params = draft_cfg = None
             if cfg.draft_checkpoint_path and self.mesh is not None:
                 logger.warning(
@@ -459,12 +428,12 @@ class DependencyContainer:
                     # one spec recipe, three registration paths
                     return WorkerSpec(factory_kwargs=dict(
                         model_family=(
-                            "moe" if type(engine.model_config).__name__
+                            "moe" if type(decoder.model_config).__name__
                             == "MoeConfig" else "llama"
                         ),
                         model_config=(
                             None if cfg.checkpoint_path
-                            else _dc.asdict(engine.model_config)
+                            else _dc.asdict(decoder.model_config)
                         ),
                         checkpoint_path=cfg.checkpoint_path,
                         tokenizer_path=cfg.tokenizer_path,
@@ -496,7 +465,7 @@ class DependencyContainer:
                                 heal_grace_s=serve.socket_heal_grace_s,
                             ))
                         services.append(ProcessReplica(
-                            spec, engine.tokenizer, replica_id=i,
+                            spec, decoder.tokenizer, replica_id=i,
                             **transport_kwargs,
                         ))
                     logger.info(
@@ -571,7 +540,7 @@ class DependencyContainer:
                         joined = []
                         for slot in registry.drain_joins():
                             svc = ProcessReplica(
-                                make_spec(slot), engine.tokenizer,
+                                make_spec(slot), decoder.tokenizer,
                                 replica_id=slot,
                                 transport_mode="socket",
                                 registry=registry,
@@ -616,9 +585,9 @@ class DependencyContainer:
             services = []
             for i in range(n_replicas):
                 paged = ContinuousBatchingEngine(
-                    model_config=engine.model_config,
-                    params=engine.params,
-                    tokenizer=engine.tokenizer,
+                    model_config=decoder.model_config,
+                    params=decoder.params,
+                    tokenizer=decoder.tokenizer,
                     max_slots=cfg.max_batch_size,
                     page_size=cfg.kv_page_size,
                     max_pages_per_seq=cfg.kv_max_pages_per_seq,
@@ -693,9 +662,7 @@ class DependencyContainer:
 
             return create_generator(
                 settings=self.settings,
-                engine=self.engine,
                 service=self.generation_service,
-                speculative=self.speculative,
             )
 
         return self._get("generator", build)
@@ -811,7 +778,7 @@ class DependencyContainer:
             t0 = time.perf_counter()
             order = [
                 "mesh", "embedder", "dense_index", "sparse_index", "retriever",
-                "reranker", "engine", "generation_service", "generator",
+                "reranker", "decoder", "generation_service", "generator",
                 "verifier", "graph", "ingestor", "cache_manager",
                 "auth_manager", "rate_limiter", "metrics", "chat_handler",
                 "health_handler",
@@ -856,9 +823,13 @@ class DependencyContainer:
         except Exception as exc:  # noqa: BLE001
             out["embedder"] = {"healthy": False, "error": str(exc)}
         try:
-            engine = self.engine
+            from sentio_tpu.runtime.weights import device_stats
+
+            decoder = self.decoder
             out["engine"] = (
-                {"healthy": True, **engine.device_stats()} if engine is not None
+                {"healthy": True,
+                 **device_stats(self.mesh, decoder.model_config)}
+                if decoder is not None
                 else {"healthy": True, "provider": self.settings.generator.provider}
             )
         except Exception as exc:  # noqa: BLE001

@@ -87,3 +87,77 @@ def docs():
         ("d8", "A lazy dog and a quick fox are common in typing exercises."),
     ]
     return [Document(text=t, id=i, metadata={"source": f"{i}.txt"}) for i, t in corpus]
+
+
+class CacheFreeGreedy:
+    """The oracle for "paging is a layout, not a model change": greedy
+    decoding with NO cache at all. Every token re-runs the whole sequence
+    through the family's cache-free forward and takes the argmax of the last
+    real position — no pages, no buckets, no sampler, nothing the engine
+    under test could share a fault with. Prompts are tokenised (BOS, no
+    truncation) and stopped (EOS dropped, ``stop``; budget, ``length``) the
+    way ``ContinuousBatchingEngine`` does it; every sequence is right-padded
+    to one ``width`` so the forward compiles once a module.
+
+    Holds ``params`` / ``model_config`` / ``tokenizer`` so a test hands the
+    engine under test the very same weights."""
+
+    def __init__(self, model_config=None, params=None, tokenizer=None,
+                 rng_seed: int = 0, width: int = 256):
+        import jax
+        import jax.numpy as jnp
+
+        from sentio_tpu.models.llama import llama_forward
+        from sentio_tpu.models.moe import MoeConfig, moe_serving_forward
+        from sentio_tpu.models.tokenizer import ByteTokenizer
+        from sentio_tpu.runtime.weights import load_decoder
+
+        if params is None:
+            decoder = load_decoder(model_config=model_config, rng_seed=rng_seed)
+            params, model_config = decoder.params, decoder.model_config
+        self.params = params
+        self.model_config = cfg = model_config
+        self.tokenizer = tokenizer or ByteTokenizer(cfg.vocab_size)
+        self.width = width
+        forward = (moe_serving_forward if isinstance(cfg, MoeConfig)
+                   else llama_forward)
+
+        @jax.jit
+        def last_logits(params, ids, n):
+            # right padding sits after every real token, so causal attention
+            # never reads it; the mask keeps it out of expert capacity
+            real = jnp.arange(ids.shape[1])[None, :] < n
+            logits, _ = forward(params, cfg, ids, pad_mask=real)
+            return logits[0, n - 1]
+
+        self._last_logits = last_logits
+
+    def logits(self, token_ids) -> "np.ndarray":
+        """float32 next-token logits after ``token_ids``."""
+        import numpy as np
+
+        n = len(token_ids)
+        assert n <= self.width, f"{n} tokens exceed the oracle's width {self.width}"
+        ids = np.full((1, self.width), self.tokenizer.pad_id, np.int32)
+        ids[0, :n] = token_ids
+        return np.asarray(self._last_logits(self.params, ids, np.int32(n)))
+
+    def generate(self, prompts, max_new_tokens: int, temperature: float = 0.0):
+        from types import SimpleNamespace
+
+        assert temperature == 0.0, "the oracle is greedy"
+        out = []
+        for prompt in prompts:
+            seq = list(self.tokenizer.encode(prompt, add_bos=True))
+            n_prompt, emitted, reason = len(seq), [], "length"
+            for _ in range(max_new_tokens):
+                tok = int(self.logits(seq).argmax())
+                if tok == self.tokenizer.eos_id:
+                    reason = "stop"
+                    break
+                emitted.append(tok)
+                seq.append(tok)
+            out.append(SimpleNamespace(
+                tokens=emitted, text=self.tokenizer.decode(emitted),
+                prompt_tokens=n_prompt, finish_reason=reason))
+        return out

@@ -42,6 +42,13 @@ def engine():
     )
 
 
+# the seeded tiny model greedy-samples EOS two tokens in; the drills that
+# kill "tick 2" of a stream need an answer that spans at least three ticks,
+# which only a fixed-length one does (2 tokens a tick)
+_MULTI_TICK = dict(max_slots=2, page_size=8, max_pages_per_seq=4,
+                   steps_per_tick=2, ignore_eos=True)
+
+
 @pytest.fixture(autouse=True)
 def _disarm_faults():
     yield
@@ -199,7 +206,11 @@ class TestChaosDrill:
         Determinism: phase 1 arms a delay-only rule (every tick sleeps, so
         the short stream cannot outrun the test), phase 2 swaps in the
         one-shot error once BOTH requests are observably in flight."""
-        svc = PagedGenerationService(engine, retry_budget=1)
+        svc = PagedGenerationService(
+            ContinuousBatchingEngine(
+                params=engine.params, tokenizer=engine.tokenizer,
+                **_MULTI_TICK),
+            retry_budget=1)
         stream_err: list = []
         stream_text: list[str] = []
         faults.arm("paged.step", faults.FaultRule(delay_s=0.1))
@@ -708,6 +719,129 @@ class TestChaosDrill:
         )
         _assert_no_pump_threads()
 
+    def test_process_replica_stall_drill_reaps_wedged_worker(self):
+        """The stall drill against PROCESS-mode replicas: one of 2 workers
+        wedges inside a decode tick (an in-worker stall fault armed over the
+        RPC fault surface) — nothing raises, nothing returns, the process
+        stays alive. The contract:
+
+        * the wedge is detected from the OUTSIDE, by the heartbeat age its
+          status frames carry, and the replica leaves HEALTHY within budget;
+        * every caller terminates with a typed outcome and the survivor
+          serves during the outage;
+        * the rebuild reaps the whole wedged process (there is no thread to
+          abandon) and respawns it; the new worker serves;
+        * zero orphan worker processes at teardown."""
+        import dataclasses
+        import multiprocessing
+
+        from sentio_tpu.models.llama import LlamaConfig
+        from sentio_tpu.models.tokenizer import ByteTokenizer
+        from sentio_tpu.runtime.replica import ReplicaSet
+        from sentio_tpu.runtime.worker import ProcessReplica, WorkerSpec
+
+        # generous next to a warmed tick on a machine that six test
+        # workers share, small next to the test
+        budget_s = 8.0
+        cfg = LlamaConfig.tiny()
+        spec = WorkerSpec(factory_kwargs=dict(
+            model_config=dataclasses.asdict(cfg),
+            engine_kwargs=dict(max_slots=2, page_size=8, max_pages_per_seq=4,
+                               steps_per_tick=2),
+            service_kwargs=dict(retry_budget=1,
+                                tick_stall_budget_s=budget_s),
+        ))
+        tok = ByteTokenizer(cfg.vocab_size)
+        p0, p1 = _build_parallel(lambda i: ProcessReplica(
+            spec, tok, replica_id=i, build_timeout_s=300.0))
+        # both workers compile what the drill will ask of them (drill-shaped
+        # prompts, one row and two rows an admission), so that no tick of
+        # the SURVIVOR spends the stall budget inside a cold compile
+        _build_parallel(lambda i: [p0, p1][i % 2].generate(
+            f"stall drill generate w{i}", max_new_tokens=8,
+            temperature=0.0, timeout_s=180), n=4)
+        rs = ReplicaSet(
+            [p0, p1],
+            probe_interval_s=0.05, quarantine_backoff_s=0.1,
+            failover_budget=2, rebuild_drain_s=0.5,
+        )
+        outcomes: dict[str, object] = {}
+
+        def call(i):
+            # caller 0 goes to the victim itself, so the wedge does not
+            # hang on how the router breaks a tie between two idle replicas
+            target = rs if i else p1
+            try:
+                outcomes[f"g{i}"] = target.generate(
+                    f"stall drill generate {i}", max_new_tokens=8,
+                    temperature=0.0, timeout_s=180,
+                )
+            except Exception as exc:  # noqa: BLE001 — typed errors terminal
+                outcomes[f"g{i}"] = exc
+
+        try:
+            # the victim's next decode tick blocks far past the test
+            p1.inject_fault("paged.step", stall_s=600.0, times=1)
+            t_wedge = time.monotonic()
+            threads = [threading.Thread(target=call, args=(i,))
+                       for i in range(6)]
+            for t in threads:
+                t.start()
+            t_detect = None
+            deadline = time.monotonic() + 60
+            while time.monotonic() < deadline:
+                if rs.health_summary()["replicas"][1]["state"] != "HEALTHY":
+                    t_detect = time.monotonic()
+                    break
+                time.sleep(0.01)
+            assert t_detect is not None, "wedged worker never left HEALTHY"
+            assert t_detect - t_wedge <= 2 * budget_s + 10.0, (
+                f"detection took {t_detect - t_wedge:.1f}s"
+            )
+            for t in threads:
+                t.join(timeout=240)
+            assert not any(t.is_alive() for t in threads), (
+                "caller thread hung on the wedged worker"
+            )
+            assert len(outcomes) == 6
+            successes = 0
+            for name, out in outcomes.items():
+                if isinstance(out, Exception):
+                    assert isinstance(out, SentioError), (
+                        f"{name}: untyped {type(out).__name__}: {out}"
+                    )
+                else:
+                    assert out.finish_reason in ("stop", "length"), (name, out)
+                    successes += 1
+            assert successes >= 1, (
+                f"survivor never served during the outage: {outcomes}"
+            )
+            deadline = time.monotonic() + 120
+            while time.monotonic() < deadline:
+                if rs.health_summary()["status"] == "healthy":
+                    break
+                time.sleep(0.05)
+            summary = rs.health_summary()
+            assert summary["status"] == "healthy", summary
+            assert summary["replicas"][1]["rebuilds"] == 1, summary
+            rebuilt = rs._services[1]
+            assert rebuilt is not p1 and rebuilt.pid != p1.pid, (
+                "the wedged worker was not respawned"
+            )
+            ok = rebuilt.generate("respawned replica serves again",
+                                  max_new_tokens=3, timeout_s=180)
+            assert ok.finish_reason in ("stop", "length")
+        finally:
+            rs.close()
+        # the wedged process is reaped too, not left sleeping in its stall
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline and multiprocessing.active_children():
+            time.sleep(0.05)
+        assert multiprocessing.active_children() == [], (
+            "orphan replica worker processes leaked"
+        )
+        _assert_no_pump_threads()
+
     def test_warmup_stall_quarantined_by_budget(self):
         """ISSUE 13 satellite: a wedge DURING warmup. WARMING is
         watchdog-exempt (cold compiles legitimately dwarf any stall
@@ -869,13 +1003,9 @@ class TestResumableStreams:
         the survivor's page pool conserved (sanitizer armed throughout)."""
         from sentio_tpu.runtime.replica import ReplicaSet
 
-        e0 = ContinuousBatchingEngine(
-            max_slots=2, page_size=8, max_pages_per_seq=4, steps_per_tick=2,
-        )
+        e0 = ContinuousBatchingEngine(**_MULTI_TICK)
         e1 = ContinuousBatchingEngine(
-            params=e0.params, tokenizer=e0.tokenizer,
-            max_slots=2, page_size=8, max_pages_per_seq=4, steps_per_tick=2,
-        )
+            params=e0.params, tokenizer=e0.tokenizer, **_MULTI_TICK)
         svc0 = PagedGenerationService(e0, retry_budget=1)
         svc1 = PagedGenerationService(e1, retry_budget=1)
         svc1.generate("drill warm one", max_new_tokens=2, timeout_s=180)
@@ -887,20 +1017,23 @@ class TestResumableStreams:
         assert len(expected.tokens) >= 4, "drill needs a multi-chunk answer"
         rs = ReplicaSet([svc0, svc1], supervise=False, failover_budget=1)
         try:
-            # armed BEFORE the stream starts: tick 1 delivers a chunk
-            # (skip=1), tick 2 dies — at least one token is ALWAYS
-            # delivered before the death, no consumer-timing race. The
-            # reset succeeds, so this is a pure mid-stream casualty (the
-            # service requeues fresh work but can never restart a
-            # delivered-token stream itself).
-            faults.arm("paged.step", faults.FaultRule(
-                error=RuntimeError("drill: midstream death"),
-                times=1, skip=1))
+            # every tick sleeps, so the stream cannot outrun the test: the
+            # death arms once the client HOLDS a piece (a seeded model's
+            # first bytes may be half a UTF-8 sequence, which the stream
+            # withholds — tokens the client never saw restart, not resume)
+            # and lands on the next tick. The reset succeeds, so this is a
+            # pure mid-stream casualty (the service requeues fresh work
+            # but can never restart a delivered-token stream itself).
+            faults.arm("paged.step", faults.FaultRule(delay_s=0.05))
             stats_out: dict = {}
-            pieces = list(rs.generate_stream(
+            stream = rs.generate_stream(
                 self.PROMPT, max_new_tokens=16, temperature=0.0,
                 timeout_s=120, stats_out=stats_out,
-            ))
+            )
+            pieces = [next(stream)]
+            faults.arm("paged.step", faults.FaultRule(
+                error=RuntimeError("drill: midstream death"), times=1))
+            pieces.extend(stream)
             faults.reset()
             # token-exact vs the no-fault run: zero duplicated, zero
             # missing tokens, one uninterrupted stream
@@ -957,8 +1090,7 @@ class TestResumableStreams:
         cfg = LlamaConfig.tiny()
         spec = WorkerSpec(factory_kwargs=dict(
             model_config=dataclasses.asdict(cfg),
-            engine_kwargs=dict(max_slots=2, page_size=8, max_pages_per_seq=4,
-                               steps_per_tick=2),
+            engine_kwargs=dict(_MULTI_TICK),
             service_kwargs=dict(retry_budget=1),
         ))
         tok = ByteTokenizer(cfg.vocab_size)
@@ -1087,8 +1219,7 @@ class TestResumableStreams:
         spec = WorkerSpec(
             factory_kwargs=dict(
                 model_config=dataclasses.asdict(cfg),
-                engine_kwargs=dict(max_slots=2, page_size=8,
-                                   max_pages_per_seq=4, steps_per_tick=2),
+                engine_kwargs=dict(_MULTI_TICK),
                 service_kwargs=dict(retry_budget=1),
             ),
             auth_token="partition-drill", status_interval_s=0.05,

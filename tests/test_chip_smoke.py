@@ -157,7 +157,7 @@ def test_local_worker_processes_are_refused_on_a_tpu(monkeypatch):
         serve=ServeConfig(replicas=2, replica_mode="process"),
     )
     container = DependencyContainer(settings=settings)
-    assert container.engine is not None  # built on the CPU, as the router would
+    assert container.decoder is not None  # loaded on the CPU, as the router would
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(DeviceError, match="one process"):
         container.generation_service

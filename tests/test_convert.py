@@ -142,15 +142,15 @@ class TestMoeConversion:
         assert cfg.dim == 32 and cfg.n_kv_heads == 2
 
     def test_checkpoint_roundtrip_serves(self, tiny_hf_mixtral, tmp_path):
-        """convert → save_pytree → load_model → GeneratorEngine greedy."""
+        """convert → save_pytree → load_model → paged engine greedy."""
         from dataclasses import replace
 
         from sentio_tpu.config import GeneratorConfig
         from sentio_tpu.models.convert import convert_moe, moe_config_from_hf
         from sentio_tpu.models.moe import moe_serving_forward
         from sentio_tpu.runtime.checkpoint import save_pytree
-        from sentio_tpu.runtime.engine import GeneratorEngine
-        from sentio_tpu.runtime.weights import load_model
+        from sentio_tpu.runtime.paged import ContinuousBatchingEngine
+        from sentio_tpu.runtime.weights import load_decoder, load_model
 
         model, hf_cfg = tiny_hf_mixtral
         cfg = replace(moe_config_from_hf(hf_cfg, dtype="float32"))
@@ -161,26 +161,26 @@ class TestMoeConversion:
         loaded, loaded_cfg, _ = load_model(ck, expect_family="moe")
         assert loaded_cfg.n_experts == cfg.n_experts
 
-        eng = GeneratorEngine(
-            config=GeneratorConfig(model_preset="tiny", max_new_tokens=6),
+        geometry = dict(max_slots=2, page_size=16, max_pages_per_seq=4)
+        eng = ContinuousBatchingEngine(
             model_config=loaded_cfg, params=loaded,
-            forward_fn=moe_serving_forward,
+            forward_fn=moe_serving_forward, **geometry,
         )
-        out = eng.generate(["hello"], max_new_tokens=6, temperature=0.0)[0]
+        out = eng.run_all(["hello"], max_new_tokens=6, temperature=0.0)[0]
         assert len(out.tokens) >= 1
 
         # config-driven path: checkpoint_path alone must auto-select the
         # MoE family from the checkpoint meta (no explicit forward_fn)
-        auto = GeneratorEngine(
-            config=GeneratorConfig(
-                model_preset="tiny", max_new_tokens=6, checkpoint_path=ck
-            ),
-        )
         from sentio_tpu.models.moe import MoeConfig
 
-        assert isinstance(auto.model_config, MoeConfig)
+        decoder = load_decoder(GeneratorConfig(checkpoint_path=ck))
+        assert isinstance(decoder.model_config, MoeConfig)
+        auto = ContinuousBatchingEngine(
+            model_config=decoder.model_config, params=decoder.params,
+            tokenizer=decoder.tokenizer, **geometry,
+        )
         assert auto.forward_fn is moe_serving_forward
-        auto_out = auto.generate(["hello"], max_new_tokens=6, temperature=0.0)[0]
+        auto_out = auto.run_all(["hello"], max_new_tokens=6, temperature=0.0)[0]
         assert auto_out.tokens == out.tokens
 
 

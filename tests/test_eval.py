@@ -40,8 +40,8 @@ class TestOpenAIProvider:
         from sentio_tpu.ops.generator import EchoProvider
 
         assert get_provider("openai").name == "openai"
-        # default settings (provider=tpu, no engine) degrade to echo
-        gen = create_generator(settings=None, engine=None)
+        # default settings (provider=tpu, no service) degrade to echo
+        gen = create_generator(settings=None, service=None)
         assert isinstance(gen.provider, EchoProvider)
         cfg = GeneratorConfig(provider="openai", api_base="http://x/v1", api_model="m")
         s = Settings()

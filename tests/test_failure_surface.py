@@ -1,7 +1,7 @@
 """Failure-surface contracts (tier-1): the RPC exception codec must
 round-trip EVERY SentioError subclass with its full wire surface, and
 every chaos injection point planted in the package must be armed by at
-least one test or bench mode (an orphaned point is dead chaos coverage).
+least one test (an orphaned point is dead chaos coverage).
 
 The static halves of these contracts live in the analyzer
 (sentio_tpu/analysis/failures.py, gated by test_lint.py); this file is
@@ -95,13 +95,13 @@ class TestFaultPointCoverage:
 
         pkg, errs = parse_paths([PACKAGE_ROOT])
         assert errs == []
-        arming, errs = parse_paths([REPO / "tests", REPO / "bench.py"])
+        arming, errs = parse_paths([REPO / "tests"])
         assert errs == []
         points = collect_fault_points(pkg)
         armed = collect_armed_points(arming)
         orphans = sorted(set(points) - set(armed))
         assert not orphans, (
-            f"fault points never armed by any test or bench mode (dead "
+            f"fault points never armed by any test (dead "
             f"chaos coverage): {orphans} — planted at "
             f"{[points[o] for o in orphans]}"
         )

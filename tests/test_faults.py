@@ -160,18 +160,19 @@ class TestDegradationLadder:
         assert rule.fired == 1
         assert out.shape == (1, 32)
 
-    def test_generate_fault_exhausts_then_recovers(self):
+    def test_decode_tick_fault_exhausts_then_recovers(self):
         from sentio_tpu.models.llama import LlamaConfig
-        from sentio_tpu.runtime.engine import GeneratorEngine
+        from sentio_tpu.runtime.paged import ContinuousBatchingEngine
 
-        engine = GeneratorEngine(
-            config=GeneratorConfig(model_preset="tiny", max_new_tokens=4),
-            model_config=LlamaConfig.tiny(),
+        engine = ContinuousBatchingEngine(
+            model_config=LlamaConfig.tiny(), max_slots=2, page_size=16,
+            max_pages_per_seq=4,
         )
-        with faults.inject("engine.generate", error=TimeoutError("deadline"), times=1):
+        with faults.inject("paged.step", error=TimeoutError("deadline"), times=1):
             with pytest.raises(TimeoutError):
-                engine.generate(["hello"])
-            out = engine.generate(["hello"])  # recovered
+                engine.run_all(["hello"], max_new_tokens=4)
+            engine.reset()
+            out = engine.run_all(["hello"], max_new_tokens=4)  # recovered
         assert len(out) == 1
 
 

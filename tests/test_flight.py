@@ -283,7 +283,7 @@ class TestFlightEndpoint:
             embedder=EmbedderConfig(provider="hash", dim=32),
             generator=GeneratorConfig(
                 provider="tpu", model_preset="tiny", use_verifier=False,
-                max_new_tokens=16, mode="fast", use_paged_decode=True,
+                max_new_tokens=16, mode="fast",
                 kv_page_size=16, kv_max_pages_per_seq=8, max_batch_size=4,
             ),
             rerank=RerankConfig(enabled=False),
@@ -431,7 +431,7 @@ class TestFlightEndpoint:
             generator=GeneratorConfig(
                 provider="tpu", model_preset="tiny", use_verifier=True,
                 max_new_tokens=12, verifier_max_tokens=6, mode="fast",
-                use_paged_decode=True, kv_page_size=16, kv_max_pages_per_seq=24,
+                kv_page_size=16, kv_max_pages_per_seq=24,
                 max_batch_size=4, decode_steps_per_tick=4, decode_max_tick_steps=4,
             ),
             rerank=RerankConfig(enabled=True, kind="cross_encoder"),

@@ -18,9 +18,6 @@ from sentio_tpu.runtime.paged import (
     quantize_kv,
 )
 
-pytestmark = pytest.mark.slow
-
-
 class TestQuantPair:
     def test_roundtrip_error_small(self):
         rng = np.random.default_rng(0)

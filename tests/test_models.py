@@ -25,8 +25,6 @@ from sentio_tpu.models.transformer import (
     mean_pool,
 )
 
-pytestmark = pytest.mark.slow
-
 CFG = LlamaConfig.tiny()
 ECFG = EncoderConfig.tiny()
 F32_CFG = LlamaConfig(

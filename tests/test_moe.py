@@ -183,39 +183,38 @@ class TestMoeForward:
 
 
 class TestMoeServing:
-    def test_generator_engine_serves_moe(self, params, cfg):
-        """The model-family seam: GeneratorEngine runs MoE checkpoints
-        through the same prefill/decode/stream paths as Llama."""
-        from sentio_tpu.config import GeneratorConfig
+    def test_service_serves_moe(self, params, cfg):
+        """The model-family seam: the serving path runs MoE checkpoints
+        through the same generate/stream surface as Llama."""
         from sentio_tpu.models.moe import moe_serving_forward
-        from sentio_tpu.runtime.engine import GeneratorEngine
+        from sentio_tpu.runtime.paged import ContinuousBatchingEngine
+        from sentio_tpu.runtime.service import PagedGenerationService
 
-        eng = GeneratorEngine(
-            config=GeneratorConfig(model_preset="tiny", max_new_tokens=8),
-            model_config=cfg,
-            params=params,
-            forward_fn=moe_serving_forward,
-        )
-        r = eng.generate(["hello experts"], max_new_tokens=8, temperature=0.0)[0]
-        r2 = eng.generate(["hello experts"], max_new_tokens=8, temperature=0.0)[0]
-        assert r.tokens == r2.tokens  # greedy decode is deterministic
-        assert r.finish_reason in ("stop", "length")
+        svc = PagedGenerationService(ContinuousBatchingEngine(
+            model_config=cfg, params=params, forward_fn=moe_serving_forward,
+            max_slots=2, page_size=16, max_pages_per_seq=8,
+        ))
+        try:
+            r = svc.generate("hello experts", max_new_tokens=8, temperature=0.0)
+            r2 = svc.generate("hello experts", max_new_tokens=8, temperature=0.0)
+            assert r.tokens == r2.tokens  # greedy decode is deterministic
+            assert r.finish_reason in ("stop", "length")
 
-        streamed = list(eng.stream("hello experts", max_new_tokens=6,
-                                   temperature=0.0))
-        assert len(streamed) >= 1
-
+            streamed = "".join(svc.generate_stream(
+                "hello experts", max_new_tokens=8, temperature=0.0))
+            assert streamed == r.text
+        finally:
+            svc.close()
 
     def test_paged_engine_serves_moe(self, cfg):
         """The DEFAULT serving path (paged continuous batching) runs MoE:
         fused decode ticks route per layer, prefill goes through the family
         seam. Ample capacity makes routing batch-size-independent, so paged
-        greedy must match the dense engine exactly (with tight capacity the
-        two are both valid but can drop different tokens, since capacity is
-        a function of the tokens-per-call)."""
-        from sentio_tpu.config import GeneratorConfig
+        greedy must match cache-free greedy decoding exactly (with tight
+        capacity the two are both valid but can drop different tokens, since
+        capacity is a function of the tokens-per-call)."""
+        from conftest import CacheFreeGreedy
         from sentio_tpu.models.moe import init_moe, moe_serving_forward
-        from sentio_tpu.runtime.engine import GeneratorEngine
         from sentio_tpu.runtime.paged import ContinuousBatchingEngine
 
         acfg = replace(cfg, capacity_factor=8.0)
@@ -228,11 +227,8 @@ class TestMoeServing:
         )
         res = paged.run_all(prompts, max_new_tokens=8, temperature=0.0)
 
-        eng = GeneratorEngine(
-            config=GeneratorConfig(model_preset="tiny", max_new_tokens=8),
-            model_config=acfg, params=params, forward_fn=moe_serving_forward,
-        )
-        dense = eng.generate(prompts, max_new_tokens=8, temperature=0.0)
+        dense = CacheFreeGreedy(acfg, params=params).generate(
+            prompts, max_new_tokens=8, temperature=0.0)
         assert [r.tokens for r in res] == [r.tokens for r in dense]
 
     def test_engines_reject_family_mismatch(self, cfg):

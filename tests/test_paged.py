@@ -1,24 +1,21 @@
 """Paged KV cache + continuous batching (runtime/paged.py).
 
 The correctness bar: a paged, continuously-batched greedy decode must emit
-EXACTLY the tokens the contiguous-cache GeneratorEngine emits for the same
-params — paging is a memory layout, not a model change.
+EXACTLY the tokens that greedy decoding with no cache at all (conftest's
+``CacheFreeGreedy``) emits for the same params — paging is a memory layout,
+not a model change.
 """
 
 import numpy as np
 import pytest
 
-from sentio_tpu.config import GeneratorConfig
+from conftest import CacheFreeGreedy
 from sentio_tpu.models.llama import LlamaConfig
-from sentio_tpu.runtime.engine import GeneratorEngine
 from sentio_tpu.runtime.paged import (
     ContinuousBatchingEngine,
     PageAllocator,
     init_pool,
 )
-
-pytestmark = pytest.mark.slow
-
 
 @pytest.fixture(scope="module")
 def cfg():
@@ -26,21 +23,17 @@ def cfg():
 
 
 @pytest.fixture(scope="module")
-def contiguous(cfg):
-    return GeneratorEngine(
-        config=GeneratorConfig(provider="tpu", model_preset="tiny", max_new_tokens=16),
-        model_config=cfg,
-        rng_seed=0,
-    )
+def oracle(cfg):
+    return CacheFreeGreedy(cfg, rng_seed=0)
 
 
 @pytest.fixture(scope="module")
-def paged(cfg, contiguous):
+def paged(cfg, oracle):
     # share the exact same params so greedy outputs are comparable
     return ContinuousBatchingEngine(
         model_config=cfg,
-        params=contiguous.params,
-        tokenizer=contiguous.tokenizer,
+        params=oracle.params,
+        tokenizer=oracle.tokenizer,
         max_slots=4,
         page_size=16,
         max_pages_per_seq=8,
@@ -75,18 +68,18 @@ class TestPool:
         assert pool.num_pages == 5
 
 
-class TestPagedMatchesContiguous:
-    def test_single_prompt_greedy(self, contiguous, paged):
+class TestPagedMatchesCacheFree:
+    def test_single_prompt_greedy(self, oracle, paged):
         prompt = "paged equivalence check"
-        ref = contiguous.generate([prompt], max_new_tokens=12, temperature=0.0)[0]
+        ref = oracle.generate([prompt], max_new_tokens=12, temperature=0.0)[0]
         got = paged.run_all([prompt], max_new_tokens=12, temperature=0.0)[0]
         assert got.tokens == ref.tokens
         assert got.text == ref.text
         assert got.finish_reason == ref.finish_reason
 
-    def test_mixed_length_batch_greedy(self, contiguous, paged):
+    def test_mixed_length_batch_greedy(self, oracle, paged):
         prompts = ["a", "a much longer prompt that spans several pages of cache " * 2, "mid size"]
-        refs = [contiguous.generate([p], max_new_tokens=10, temperature=0.0)[0] for p in prompts]
+        refs = [oracle.generate([p], max_new_tokens=10, temperature=0.0)[0] for p in prompts]
         got = paged.run_all(prompts, max_new_tokens=10, temperature=0.0)
         for r, g in zip(refs, got):
             assert g.tokens == r.tokens
@@ -99,12 +92,12 @@ class TestPagedMatchesContiguous:
 
 
 class TestContinuousAdmission:
-    def test_staggered_arrivals_match_isolated_runs(self, contiguous, paged):
+    def test_staggered_arrivals_match_isolated_runs(self, oracle, paged):
         """Requests joining mid-flight must not perturb rows already decoding."""
         early = "first request decoding"
         late = "latecomer joins the batch"
-        ref_early = contiguous.generate([early], max_new_tokens=12, temperature=0.0)[0]
-        ref_late = contiguous.generate([late], max_new_tokens=12, temperature=0.0)[0]
+        ref_early = oracle.generate([early], max_new_tokens=12, temperature=0.0)[0]
+        ref_late = oracle.generate([late], max_new_tokens=12, temperature=0.0)[0]
 
         rid_early = paged.submit(early, max_new_tokens=12, temperature=0.0)
         done = {}
@@ -194,24 +187,24 @@ class TestPagedAttentionKernel:
                               jnp.asarray(lens), h // hkv)[:, 0]
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
-    def test_engine_with_kernel_matches_contiguous(self, cfg, contiguous):
+    def test_engine_with_kernel_matches_cache_free(self, cfg, oracle):
         eng = ContinuousBatchingEngine(
-            model_config=cfg, params=contiguous.params, tokenizer=contiguous.tokenizer,
+            model_config=cfg, params=oracle.params, tokenizer=oracle.tokenizer,
             max_slots=2, page_size=16, max_pages_per_seq=8, use_pallas=True,
         )
         prompt = "kernel path equivalence"
-        ref = contiguous.generate([prompt], max_new_tokens=8, temperature=0.0)[0]
+        ref = oracle.generate([prompt], max_new_tokens=8, temperature=0.0)[0]
         got = eng.run_all([prompt], max_new_tokens=8, temperature=0.0)[0]
         assert got.tokens == ref.tokens
 
-    def test_int8_engine_with_kernel_churn_conserves_pages(self, cfg, contiguous):
+    def test_int8_engine_with_kernel_churn_conserves_pages(self, cfg, oracle):
         """KV_QUANT=int8 + the quantization-native Pallas kernel (interpret
         on CPU) through an admission-churn workload, with the sanitizer
         (armed for this module) checking pool conservation on the dict-repr
         pool every tick."""
         eng = ContinuousBatchingEngine(
-            model_config=cfg, params=contiguous.params,
-            tokenizer=contiguous.tokenizer, max_slots=2, page_size=16,
+            model_config=cfg, params=oracle.params,
+            tokenizer=oracle.tokenizer, max_slots=2, page_size=16,
             max_pages_per_seq=4, use_pallas=True, kv_quant="int8",
         )
         before = eng.allocator.free_pages + (
@@ -233,13 +226,13 @@ class TestBudgets:
         r = paged.run_all(["short budget"], max_new_tokens=3)[0]
         assert len(r.tokens) <= 3
 
-    def test_per_row_temperatures(self, cfg, contiguous):
+    def test_per_row_temperatures(self, cfg, oracle):
         """Greedy and hot rows coexist in one batch; greedy row stays exact."""
         eng = ContinuousBatchingEngine(
-            model_config=cfg, params=contiguous.params, tokenizer=contiguous.tokenizer,
+            model_config=cfg, params=oracle.params, tokenizer=oracle.tokenizer,
             max_slots=2, page_size=16, max_pages_per_seq=8, rng_seed=7,
         )
-        ref = contiguous.generate(["cold row"], max_new_tokens=8, temperature=0.0)[0]
+        ref = oracle.generate(["cold row"], max_new_tokens=8, temperature=0.0)[0]
         rid_cold = eng.submit("cold row", max_new_tokens=8, temperature=0.0)
         eng.submit("hot row", max_new_tokens=8, temperature=1.5)
         done = {}
@@ -250,15 +243,15 @@ class TestBudgets:
 
 
 class TestMultiStepTick:
-    def test_steps_per_tick_greedy_equivalence(self, cfg, contiguous):
+    def test_steps_per_tick_greedy_equivalence(self, cfg, oracle):
         """Fusing N decode sub-steps into one dispatch is a scheduling
         change, not a model change: greedy tokens must be bit-identical."""
         prompts = ["alpha prompt", "a", "gamma prompt with a longer tail of text"]
         outs = {}
         for steps in (1, 4, 8):
             eng = ContinuousBatchingEngine(
-                model_config=cfg, params=contiguous.params,
-                tokenizer=contiguous.tokenizer, max_slots=4, page_size=16,
+                model_config=cfg, params=oracle.params,
+                tokenizer=oracle.tokenizer, max_slots=4, page_size=16,
                 max_pages_per_seq=8, steps_per_tick=steps,
             )
             outs[steps] = [
@@ -266,11 +259,11 @@ class TestMultiStepTick:
             ]
         assert outs[1] == outs[4] == outs[8]
 
-    def test_fewer_ticks_with_fused_steps(self, cfg, contiguous):
+    def test_fewer_ticks_with_fused_steps(self, cfg, oracle):
         def count_ticks(steps):
             eng = ContinuousBatchingEngine(
-                model_config=cfg, params=contiguous.params,
-                tokenizer=contiguous.tokenizer, max_slots=2, page_size=16,
+                model_config=cfg, params=oracle.params,
+                tokenizer=oracle.tokenizer, max_slots=2, page_size=16,
                 max_pages_per_seq=8, steps_per_tick=steps,
             )
             eng.submit("count the ticks", max_new_tokens=16, temperature=0.0)
@@ -301,12 +294,12 @@ class TestBatchedAdmission:
         finally:
             faults.reset()
 
-    def test_burst_admission_dispatch_count(self, cfg, contiguous):
+    def test_burst_admission_dispatch_count(self, cfg, oracle):
         """Admitting N same-width-bucket requests must cost at most
         ceil(N / max_batch_bucket) prefill dispatches, not N."""
         eng = ContinuousBatchingEngine(
-            model_config=cfg, params=contiguous.params,
-            tokenizer=contiguous.tokenizer, max_slots=8, page_size=16,
+            model_config=cfg, params=oracle.params,
+            tokenizer=oracle.tokenizer, max_slots=8, page_size=16,
             max_pages_per_seq=8,
         )
         calls = []
@@ -330,13 +323,13 @@ class TestBatchedAdmission:
             for r in eng.step():
                 done[r.request_id] = r
         assert set(done) == set(rids)
-        ref = contiguous.generate(["burst request 0"], max_new_tokens=4, temperature=0.0)[0]
+        ref = oracle.generate(["burst request 0"], max_new_tokens=4, temperature=0.0)[0]
         assert done[rids[0]].tokens == ref.tokens
 
-    def test_mixed_width_burst_groups_by_bucket(self, cfg, contiguous):
+    def test_mixed_width_burst_groups_by_bucket(self, cfg, oracle):
         eng = ContinuousBatchingEngine(
-            model_config=cfg, params=contiguous.params,
-            tokenizer=contiguous.tokenizer, max_slots=8, page_size=16,
+            model_config=cfg, params=oracle.params,
+            tokenizer=oracle.tokenizer, max_slots=8, page_size=16,
             max_pages_per_seq=8,
         )
         calls = []
@@ -357,7 +350,7 @@ class TestBatchedAdmission:
 
 
 class TestMeshShardedEngine:
-    def test_tp_sharded_pool_matches_single_device(self, cfg, contiguous):
+    def test_tp_sharded_pool_matches_single_device(self, cfg, oracle):
         import jax
 
         from sentio_tpu.config import MeshConfig
@@ -365,9 +358,9 @@ class TestMeshShardedEngine:
         from sentio_tpu.parallel.sharding import LLAMA_TP_RULES, shard_params
 
         mesh = build_mesh(MeshConfig(dp_size=4, tp_size=2))
-        params = shard_params(contiguous.params, mesh, LLAMA_TP_RULES)
+        params = shard_params(oracle.params, mesh, LLAMA_TP_RULES)
         eng = ContinuousBatchingEngine(
-            model_config=cfg, params=params, tokenizer=contiguous.tokenizer,
+            model_config=cfg, params=params, tokenizer=oracle.tokenizer,
             mesh=mesh, max_slots=4, page_size=16, max_pages_per_seq=8,
             steps_per_tick=4,
         )
@@ -378,29 +371,29 @@ class TestMeshShardedEngine:
         )
         prompts = ["mesh request one", "mesh request two"]
         got = eng.run_all(prompts, max_new_tokens=8, temperature=0.0)
-        ref = contiguous.generate(prompts, max_new_tokens=8, temperature=0.0)
+        ref = oracle.generate(prompts, max_new_tokens=8, temperature=0.0)
         assert [r.tokens for r in got] == [r.tokens for r in ref]
 
-    def test_kv_heads_not_divisible_by_tp_raises(self, cfg, contiguous):
+    def test_kv_heads_not_divisible_by_tp_raises(self, cfg, oracle):
         from sentio_tpu.config import MeshConfig
         from sentio_tpu.parallel.mesh import build_mesh
 
         mesh = build_mesh(MeshConfig(dp_size=1, sp_size=2, tp_size=4))
         with pytest.raises(ValueError, match="n_kv_heads"):
             ContinuousBatchingEngine(
-                model_config=cfg, params=contiguous.params,
-                tokenizer=contiguous.tokenizer, mesh=mesh, max_slots=2,
+                model_config=cfg, params=oracle.params,
+                tokenizer=oracle.tokenizer, mesh=mesh, max_slots=2,
             )
 
-    def test_reset_preserves_pool_sharding(self, cfg, contiguous):
+    def test_reset_preserves_pool_sharding(self, cfg, oracle):
         from sentio_tpu.config import MeshConfig
         from sentio_tpu.parallel.mesh import AXIS_TP, build_mesh
         from sentio_tpu.parallel.sharding import LLAMA_TP_RULES, shard_params
 
         mesh = build_mesh(MeshConfig(dp_size=4, tp_size=2))
-        params = shard_params(contiguous.params, mesh, LLAMA_TP_RULES)
+        params = shard_params(oracle.params, mesh, LLAMA_TP_RULES)
         eng = ContinuousBatchingEngine(
-            model_config=cfg, params=params, tokenizer=contiguous.tokenizer,
+            model_config=cfg, params=params, tokenizer=oracle.tokenizer,
             mesh=mesh, max_slots=2, page_size=16, max_pages_per_seq=8,
         )
         eng.reset()
@@ -413,36 +406,36 @@ class TestPipelinedTicks:
     scheduling change: greedy outputs must be bit-identical to depth 1,
     including under heavy slot churn and staggered admissions."""
 
-    def _run(self, contiguous, cfg, depth, prompts, max_new, slots=4,
+    def _run(self, oracle, cfg, depth, prompts, max_new, slots=4,
              steps=4, max_tick=8):
         eng = ContinuousBatchingEngine(
-            model_config=cfg, params=contiguous.params,
-            tokenizer=contiguous.tokenizer, max_slots=slots, page_size=16,
+            model_config=cfg, params=oracle.params,
+            tokenizer=oracle.tokenizer, max_slots=slots, page_size=16,
             max_pages_per_seq=8, steps_per_tick=steps, max_tick_steps=max_tick,
             pipeline_depth=depth,
         )
         return [r.tokens for r in eng.run_all(prompts, max_new_tokens=max_new,
                                               temperature=0.0)]
 
-    def test_greedy_equivalence(self, cfg, contiguous):
+    def test_greedy_equivalence(self, cfg, oracle):
         prompts = ["alpha prompt", "a", "third prompt with a longer tail of text"]
-        a = self._run(contiguous, cfg, 1, prompts, 20)
-        b = self._run(contiguous, cfg, 2, prompts, 20)
+        a = self._run(oracle, cfg, 1, prompts, 20)
+        b = self._run(oracle, cfg, 2, prompts, 20)
         assert a == b
 
-    def test_slot_churn_equivalence(self, cfg, contiguous):
+    def test_slot_churn_equivalence(self, cfg, oracle):
         # 10 short requests through 2 slots: constant retire + reuse while a
         # speculative tick is in flight — exercises the stale-lane guard
         prompts = [f"churn request {i}" for i in range(10)]
-        a = self._run(contiguous, cfg, 1, prompts, 5, slots=2)
-        b = self._run(contiguous, cfg, 2, prompts, 5, slots=2)
+        a = self._run(oracle, cfg, 1, prompts, 5, slots=2)
+        b = self._run(oracle, cfg, 2, prompts, 5, slots=2)
         assert a == b
 
-    def test_staggered_equivalence(self, cfg, contiguous):
+    def test_staggered_equivalence(self, cfg, oracle):
         def staggered(depth):
             eng = ContinuousBatchingEngine(
-                model_config=cfg, params=contiguous.params,
-                tokenizer=contiguous.tokenizer, max_slots=4, page_size=16,
+                model_config=cfg, params=oracle.params,
+                tokenizer=oracle.tokenizer, max_slots=4, page_size=16,
                 max_pages_per_seq=8, steps_per_tick=4, pipeline_depth=depth,
             )
             rid_a = eng.submit("early request", max_new_tokens=16, temperature=0.0)
@@ -459,11 +452,11 @@ class TestPipelinedTicks:
 
         assert staggered(1) == staggered(2)
 
-    def test_varied_max_new_equivalence(self, cfg, contiguous):
+    def test_varied_max_new_equivalence(self, cfg, oracle):
         def run(depth):
             eng = ContinuousBatchingEngine(
-                model_config=cfg, params=contiguous.params,
-                tokenizer=contiguous.tokenizer, max_slots=4, page_size=16,
+                model_config=cfg, params=oracle.params,
+                tokenizer=oracle.tokenizer, max_slots=4, page_size=16,
                 max_pages_per_seq=8, steps_per_tick=4, max_tick_steps=16,
                 pipeline_depth=depth,
             )
@@ -482,13 +475,13 @@ class TestPipelinedTicks:
 
 
 class TestSingleTokenBurst:
-    def test_max_new_one_burst_no_scan(self, cfg, contiguous):
+    def test_max_new_one_burst_no_scan(self, cfg, oracle):
         """max_new=1 bursts fold deferred first tokens with a direct fetch —
-        no masked decode scan — and still match the contiguous engine."""
+        no masked decode scan — and still match the oracle."""
         for depth in (1, 2):
             eng = ContinuousBatchingEngine(
-                model_config=cfg, params=contiguous.params,
-                tokenizer=contiguous.tokenizer, max_slots=4, page_size=16,
+                model_config=cfg, params=oracle.params,
+                tokenizer=oracle.tokenizer, max_slots=4, page_size=16,
                 max_pages_per_seq=8, steps_per_tick=4, pipeline_depth=depth,
             )
             prompts = [f"one token {i}" for i in range(6)]
@@ -496,7 +489,7 @@ class TestSingleTokenBurst:
             got = eng.run_all(prompts, max_new_tokens=1, temperature=0.0)
             assert eng.total_sub_steps == sub_steps_before, "no scan should run"
             refs = [
-                contiguous.generate([p], max_new_tokens=1, temperature=0.0)[0]
+                oracle.generate([p], max_new_tokens=1, temperature=0.0)[0]
                 for p in prompts
             ]
             assert [r.tokens for r in got] == [r.tokens for r in refs]

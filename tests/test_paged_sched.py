@@ -12,9 +12,6 @@ import pytest
 from sentio_tpu.models.llama import LlamaConfig
 from sentio_tpu.runtime.paged import ContinuousBatchingEngine
 
-pytestmark = pytest.mark.slow
-
-
 def make_engine(**kw):
     kw.setdefault("model_config", LlamaConfig.tiny())
     kw.setdefault("max_slots", 2)

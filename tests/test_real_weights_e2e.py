@@ -207,7 +207,6 @@ def _pipeline_settings(converted_ckpt, char_tokenizer_dir) -> Settings:
             max_new_tokens=64,
             max_prompt_tokens=152,
             mode="fast",  # greedy — deterministic
-            use_paged_decode=True,
             kv_page_size=16,
             kv_max_pages_per_seq=10,  # prompt cap 152 + 56 gen < trained 208
             max_batch_size=4,
@@ -264,18 +263,21 @@ class TestConvertedCheckpointServing:
     ):
         """Engine-level check without the pipeline: converted weights +
         converted tokenizer produce parseable JSON for unseen prompts."""
-        from sentio_tpu.runtime.engine import GeneratorEngine
+        from sentio_tpu.runtime.paged import ContinuousBatchingEngine
+        from sentio_tpu.runtime.weights import load_decoder
 
-        engine = GeneratorEngine(
-            config=GeneratorConfig(
-                provider="tpu", checkpoint_path=converted_ckpt,
-                tokenizer_path=char_tokenizer_dir, max_new_tokens=64,
-                max_prompt_tokens=152, mode="fast",
-            ),
+        decoder = load_decoder(GeneratorConfig(
+            provider="tpu", checkpoint_path=converted_ckpt,
+            tokenizer_path=char_tokenizer_dir,
+        ))
+        engine = ContinuousBatchingEngine(
+            model_config=decoder.model_config, params=decoder.params,
+            tokenizer=decoder.tokenizer, max_slots=2, page_size=16,
+            max_pages_per_seq=16,
         )
-        out = engine.generate(
+        out = engine.run_all(
             ["Audit the answer against the sources; reply with JSON only."],
-            temperature=0.0,
+            max_new_tokens=64, temperature=0.0,
         )[0]
         span = out.text[out.text.index("{") : out.text.rindex("}") + 1]
         parsed = json.loads(span)

@@ -866,8 +866,11 @@ class TestResumableStreamWfq:
 
     @staticmethod
     def _two_replica_set(**svc_kw):
-        e0 = _engine()
-        e1 = _engine(base=e0)
+        # the seeded tiny model greedy-samples EOS two tokens in: only a
+        # fixed-length answer spans the ticks (8 tokens, 2 a tick) that
+        # "tick 2 dies" needs
+        e0 = _engine(ignore_eos=True)
+        e1 = _engine(base=e0, ignore_eos=True)
         svc0 = PagedGenerationService(e0, **svc_kw)
         svc1 = PagedGenerationService(e1, **svc_kw)
         # both warmed BEFORE any fault arms: warmup ticks must not eat a

@@ -7,6 +7,7 @@ validation, rate limits, handlers, graph, indexes.
 """
 
 import asyncio
+import dataclasses
 
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
@@ -20,11 +21,9 @@ from sentio_tpu.config import (
     ServeConfig,
     Settings,
 )
+from sentio_tpu.models.llama import LlamaConfig
 from sentio_tpu.serve.app import create_app
 from sentio_tpu.serve.dependencies import DependencyContainer
-
-pytestmark = pytest.mark.slow
-
 
 def fast_settings(**over) -> Settings:
     s = Settings(
@@ -274,6 +273,53 @@ class TestHealthAndInfo:
 
         run(with_client(fast_settings(), body))
 
+    def test_info_and_health_of_the_served_decoder(self):
+        """What /info and /health say of the decoder and the device is read
+        from outside (the benchmark's server driver checks `generator.model`
+        and `device.n_devices` before it measures): the key set is pinned,
+        and so are the values a tiny preset gives."""
+
+        def leaves(d, prefix=""):
+            for k, v in d.items():
+                yield prefix + k
+                if isinstance(v, dict):
+                    yield from leaves(v, prefix + k + ".")
+
+        async def body(client, container):
+            info = await (await client.get("/info")).json()
+            assert set(info) == {
+                "service", "version", "retrieval", "embedder", "reranker",
+                "generator", "device", "compile_cache_dir"}
+            assert set(leaves(info["generator"])) == {
+                "provider", "preset", "verifier", "kv_quant",
+                "paged_attention", "pool_hbm_bytes", "speculative",
+                "speculative.draft_configured", "speculative.active",
+                "model", *("model." + f.name for f in
+                           dataclasses.fields(LlamaConfig))}
+            assert info["generator"]["model"] == dataclasses.asdict(
+                LlamaConfig.tiny())
+            device = info["device"]
+            assert {"platform", "kind", "n_devices", "mesh", "model"} \
+                <= set(device) <= {"platform", "kind", "n_devices", "mesh",
+                                   "model", "memory"}
+            assert device["model"] == {"layers": 2, "dim": 64, "vocab": 512}
+            assert device["n_devices"] == 8 and device["mesh"] is None
+            detailed = await (await client.get("/health/detailed")).json()
+            engine = detailed["components"]["engine"]
+            assert engine["healthy"] is True
+            assert {k: v for k, v in engine.items()
+                    if k not in ("healthy", "memory")} \
+                == {k: v for k, v in device.items() if k != "memory"}
+
+        settings = fast_settings(generator=GeneratorConfig(
+            provider="tpu", model_preset="tiny", use_verifier=False,
+            max_new_tokens=8, kv_page_size=16, kv_max_pages_per_seq=8,
+            max_batch_size=2,
+        ))
+        run(with_client(settings, body,
+                        container=DependencyContainer(settings=settings,
+                                                      mesh=None)))
+
     def test_metrics_endpoints(self):
         async def body(client, container):
             await client.post("/chat", json={"question": "count this request"})
@@ -309,48 +355,30 @@ class TestHealthAndInfo:
         run(with_client(fast_settings(), body))
 
 
-    def test_info_speculative_resolution(self):
+    @pytest.mark.parametrize("gen_kw, mesh, named", [
+        (dict(prefill_chunk=512), None, "PREFILL_CHUNK"),
+        (dict(), MeshConfig(dp_size=8), "mesh"),
+    ], ids=["prefill-chunk", "mesh"])
+    def test_info_speculative_resolution(self, gen_kw, mesh, named):
         """/info names the exact reason a configured draft is inactive
-        (the round-4 dead-knob gap — operators must never see a dead knob
-        reported as active)."""
-
-        async def body(client, container):
-            data = await (await client.get("/info")).json()
-            spec = data["generator"]["speculative"]
-            assert spec["draft_configured"] is True
-            assert spec["active"] is False
-            assert "PREFILL_CHUNK" in spec["ignored_reason"]
-
-        settings = fast_settings(generator=GeneratorConfig(
-            provider="tpu", model_preset="tiny", use_verifier=False,
-            draft_checkpoint_path="/nonexistent-draft", prefill_chunk=512,
-            use_paged_decode=True,
-        ))
-        run(with_client(settings, body,
-                        container=DependencyContainer(settings=settings,
-                                                      mesh=None)))
-
-    def test_info_speculative_contiguous_mesh_gating(self):
-        """USE_PAGED_KV=0 + a device mesh: the contiguous SpeculativeDecoder
-        is never constructed (dependencies.speculative is single-chip-only),
-        so /info must report active=false with the mesh named as the reason
-        — not a dead knob shown as live."""
+        (operators must never see a dead knob reported as active)."""
 
         async def body(client, container):
             # a mesh is only built when a MESH_* axis asks for one
-            assert container.mesh is not None
+            assert (container.mesh is not None) == (mesh is not None)
             data = await (await client.get("/info")).json()
             spec = data["generator"]["speculative"]
             assert spec["draft_configured"] is True
             assert spec["active"] is False
-            assert "mesh" in spec["ignored_reason"]
+            assert named in spec["ignored_reason"]
 
         settings = fast_settings(generator=GeneratorConfig(
             provider="tpu", model_preset="tiny", use_verifier=False,
-            draft_checkpoint_path="/nonexistent-draft",
-            use_paged_decode=False,
-        ), mesh=MeshConfig(dp_size=8))
-        run(with_client(settings, body))
+            draft_checkpoint_path="/nonexistent-draft", **gen_kw,
+        ), **({"mesh": mesh} if mesh else {}))
+        container = (None if mesh else
+                     DependencyContainer(settings=settings, mesh=None))
+        run(with_client(settings, body, container=container))
 
 
 class TestAuth:
@@ -392,7 +420,7 @@ class TestPagedServing:
             generator=GeneratorConfig(
                 provider="tpu", model_preset="tiny", use_verifier=False,
                 max_new_tokens=24, mode="fast",  # greedy: deterministic
-                use_paged_decode=True, kv_page_size=16,
+                kv_page_size=16,
                 kv_max_pages_per_seq=8, max_batch_size=4,
             ),
         )
@@ -564,7 +592,7 @@ class TestSseStreamResume:
             generator=GeneratorConfig(
                 provider="tpu", model_preset="tiny", use_verifier=False,
                 max_new_tokens=24, mode="fast",  # greedy: deterministic
-                use_paged_decode=True, kv_page_size=16,
+                kv_page_size=16,
                 kv_max_pages_per_seq=8, max_batch_size=4,
                 # a 24-token answer must span several delivered chunks or
                 # there is no "mid-stream" window to kill inside: an idle

@@ -13,10 +13,10 @@ import time
 import numpy as np
 import pytest
 
-from sentio_tpu.config import EmbedderConfig, GeneratorConfig
+from conftest import CacheFreeGreedy
+from sentio_tpu.config import EmbedderConfig
 from sentio_tpu.models.llama import LlamaConfig
 from sentio_tpu.parallel.batcher import BatcherClosed, ThreadBatcher
-from sentio_tpu.runtime.engine import GeneratorEngine
 from sentio_tpu.runtime.paged import ContinuousBatchingEngine
 from sentio_tpu.runtime.service import (
     GenerationTimeout,
@@ -24,24 +24,17 @@ from sentio_tpu.runtime.service import (
     ReplicaUnavailable,
 )
 
-pytestmark = pytest.mark.slow
-
-
 @pytest.fixture(scope="module")
-def contiguous():
-    return GeneratorEngine(
-        config=GeneratorConfig(provider="tpu", model_preset="tiny", max_new_tokens=16),
-        model_config=LlamaConfig.tiny(),
-        rng_seed=0,
-    )
+def oracle():
+    return CacheFreeGreedy(LlamaConfig.tiny(), rng_seed=0)
 
 
 @pytest.fixture()
-def service(contiguous):
+def service(oracle):
     engine = ContinuousBatchingEngine(
-        model_config=contiguous.model_config,
-        params=contiguous.params,
-        tokenizer=contiguous.tokenizer,
+        model_config=oracle.model_config,
+        params=oracle.params,
+        tokenizer=oracle.tokenizer,
         max_slots=4,
         page_size=16,
         max_pages_per_seq=8,
@@ -102,22 +95,22 @@ class TestThreadBatcher:
 
 
 class TestPagedGenerationService:
-    def test_single_request_matches_engine(self, service, contiguous):
+    def test_single_request_matches_engine(self, service, oracle):
         prompt = "service equivalence check"
-        want = contiguous.generate([prompt], max_new_tokens=12, temperature=0.0)[0]
+        want = oracle.generate([prompt], max_new_tokens=12, temperature=0.0)[0]
         got = service.generate(prompt, max_new_tokens=12, temperature=0.0)
         assert got.tokens == want.tokens
         assert got.finish_reason in ("stop", "length")
 
-    def test_int8_engine_service_roundtrip_with_top_k(self, contiguous):
+    def test_int8_engine_service_roundtrip_with_top_k(self, oracle):
         """KV_QUANT=int8 parametrization of the service path under the
         sanitizer: the pump drives a quantized dict-repr pool through
         admit/decode/retire, and per-request top_k rides the ticket into
         the fused tick (traced — no per-k recompile)."""
         engine = ContinuousBatchingEngine(
-            model_config=contiguous.model_config,
-            params=contiguous.params,
-            tokenizer=contiguous.tokenizer,
+            model_config=oracle.model_config,
+            params=oracle.params,
+            tokenizer=oracle.tokenizer,
             max_slots=4,
             page_size=16,
             max_pages_per_seq=8,
@@ -125,7 +118,7 @@ class TestPagedGenerationService:
         )
         svc = PagedGenerationService(engine)
         try:
-            want = contiguous.generate(
+            want = oracle.generate(
                 ["int8 service check"], max_new_tokens=8, temperature=0.0)[0]
             got = svc.generate("int8 service check", max_new_tokens=8,
                                temperature=0.0)
@@ -190,14 +183,14 @@ class TestPagedGenerationService:
         assert s["free_pages"] + s.get("prefix_cache_pages", 0) \
             == s["total_pages"] - 1
 
-    def test_tick_failure_fails_waiters_and_recovers(self, contiguous):
+    def test_tick_failure_fails_waiters_and_recovers(self, oracle):
         """A failing decode tick must (a) fail the in-flight waiters with
         finish_reason='error' and (b) reset the engine so the NEXT request
         works — a transient device error must not poison the pool forever."""
         engine = ContinuousBatchingEngine(
-            model_config=contiguous.model_config,
-            params=contiguous.params,
-            tokenizer=contiguous.tokenizer,
+            model_config=oracle.model_config,
+            params=oracle.params,
+            tokenizer=oracle.tokenizer,
             max_slots=2,
             page_size=16,
             max_pages_per_seq=4,
@@ -222,11 +215,11 @@ class TestPagedGenerationService:
             == s["total_pages"] - 1
         svc.close()
 
-    def test_closed_service_rejects(self, contiguous):
+    def test_closed_service_rejects(self, oracle):
         engine = ContinuousBatchingEngine(
-            model_config=contiguous.model_config,
-            params=contiguous.params,
-            tokenizer=contiguous.tokenizer,
+            model_config=oracle.model_config,
+            params=oracle.params,
+            tokenizer=oracle.tokenizer,
             max_slots=2,
             page_size=16,
             max_pages_per_seq=4,
@@ -245,20 +238,20 @@ class TestRobustness:
     """Deadline propagation, crash-requeue budget, and drain ordering —
     the request-lifecycle robustness surface over the paged pump."""
 
-    def _engine(self, contiguous, **kw):
+    def _engine(self, oracle, **kw):
         kw.setdefault("max_slots", 2)
         kw.setdefault("page_size", 16)
         kw.setdefault("max_pages_per_seq", 8)
         kw.setdefault("steps_per_tick", 1)
         return ContinuousBatchingEngine(
-            model_config=contiguous.model_config, params=contiguous.params,
-            tokenizer=contiguous.tokenizer, **kw,
+            model_config=oracle.model_config, params=oracle.params,
+            tokenizer=oracle.tokenizer, **kw,
         )
 
-    def test_deadline_cancels_mid_decode(self, contiguous):
+    def test_deadline_cancels_mid_decode(self, oracle):
         from sentio_tpu.infra.exceptions import DeadlineExceededError
 
-        svc = PagedGenerationService(self._engine(contiguous))
+        svc = PagedGenerationService(self._engine(oracle))
         try:
             with pytest.raises(DeadlineExceededError):
                 svc.generate("expire me mid decode", max_new_tokens=400,
@@ -277,10 +270,10 @@ class TestRobustness:
         finally:
             svc.close()
 
-    def test_timeout_completion_race_returns_result(self, contiguous):
+    def test_timeout_completion_race_returns_result(self, oracle):
         """event.wait timing out while the pump completes the very same
         ticket must return the finished result, not raise + cancel it."""
-        svc = PagedGenerationService(self._engine(contiguous))
+        svc = PagedGenerationService(self._engine(oracle))
         try:
             # warm so the next generate is fast relative to the timeout
             svc.generate("warm the compile path", max_new_tokens=2)
@@ -297,8 +290,8 @@ class TestRobustness:
         finally:
             svc.close()
 
-    def test_crash_requeue_budget_recovers_single_failure(self, contiguous):
-        engine = self._engine(contiguous)
+    def test_crash_requeue_budget_recovers_single_failure(self, oracle):
+        engine = self._engine(oracle)
         svc = PagedGenerationService(engine, retry_budget=1)
         original_step = engine.step
         calls = {"n": 0}
@@ -320,10 +313,10 @@ class TestRobustness:
             engine.step = original_step
             svc.close()
 
-    def test_queue_full_sheds_with_retry_after(self, contiguous):
+    def test_queue_full_sheds_with_retry_after(self, oracle):
         from sentio_tpu.infra.exceptions import ServiceOverloaded
 
-        svc = PagedGenerationService(self._engine(contiguous), max_queue=0)
+        svc = PagedGenerationService(self._engine(oracle), max_queue=0)
         try:
             with pytest.raises(ServiceOverloaded) as exc_info:
                 svc.generate("no room at the inn", max_new_tokens=2)
@@ -333,13 +326,13 @@ class TestRobustness:
         finally:
             svc.close()
 
-    def test_drain_then_close_ordering(self, contiguous):
+    def test_drain_then_close_ordering(self, oracle):
         """drain() must (1) flip to draining, (2) wait out in-flight work,
         (3) close — a submit observed after drain returns must fail closed,
         and the drained flag must be visible in stats while draining."""
         from sentio_tpu.infra.exceptions import ServiceOverloaded
 
-        svc = PagedGenerationService(self._engine(contiguous))
+        svc = PagedGenerationService(self._engine(oracle))
         result = {}
 
         def call():
@@ -358,7 +351,7 @@ class TestRobustness:
         with pytest.raises((ReplicaUnavailable, ServiceOverloaded)):
             svc.generate("too late")
 
-    def test_drain_deadline_bounds_wedged_pump_join(self, contiguous):
+    def test_drain_deadline_bounds_wedged_pump_join(self, oracle):
         """ISSUE 10 satellite: drain() must honor its deadline against a
         pump wedged inside a device dispatch — the final pump join derives
         from the drain deadline's remainder (not the old hardcoded 10s),
@@ -366,7 +359,7 @@ class TestRobustness:
         close() neither re-joins nor double-counts."""
         from sentio_tpu.infra import faults
 
-        svc = PagedGenerationService(self._engine(contiguous))
+        svc = PagedGenerationService(self._engine(oracle))
         release = threading.Event()
         rule = faults.FaultRule(stall_event=release, stall_s=60.0, times=1)
         faults.arm("paged.step", rule)
@@ -416,10 +409,10 @@ class TestRobustness:
             faults.disarm("paged.step")
             release.set()
 
-    def test_leaked_pump_surfaces_in_stats(self, contiguous):
+    def test_leaked_pump_surfaces_in_stats(self, oracle):
         """A pump that outlives close()'s join shows up as pump_leaked
         instead of being silently dropped."""
-        svc = PagedGenerationService(self._engine(contiguous))
+        svc = PagedGenerationService(self._engine(oracle))
         release = threading.Event()
         started = threading.Event()
 
@@ -483,13 +476,13 @@ class TestEmbedderCoalescing:
 
 
 class TestCancellation:
-    def test_timeout_cancels_engine_request(self, contiguous):
+    def test_timeout_cancels_engine_request(self, oracle):
         from sentio_tpu.runtime.paged import ContinuousBatchingEngine
         from sentio_tpu.runtime.service import GenerationTimeout, PagedGenerationService
 
         eng = ContinuousBatchingEngine(
-            model_config=contiguous.model_config, params=contiguous.params,
-            tokenizer=contiguous.tokenizer, max_slots=2, page_size=16,
+            model_config=oracle.model_config, params=oracle.params,
+            tokenizer=oracle.tokenizer, max_slots=2, page_size=16,
             max_pages_per_seq=8, steps_per_tick=1,
         )
         svc = PagedGenerationService(eng)
@@ -511,13 +504,13 @@ class TestCancellation:
         finally:
             svc.close()
 
-    def test_abandoned_stream_cancels(self, contiguous):
+    def test_abandoned_stream_cancels(self, oracle):
         from sentio_tpu.runtime.paged import ContinuousBatchingEngine
         from sentio_tpu.runtime.service import PagedGenerationService
 
         eng = ContinuousBatchingEngine(
-            model_config=contiguous.model_config, params=contiguous.params,
-            tokenizer=contiguous.tokenizer, max_slots=2, page_size=16,
+            model_config=oracle.model_config, params=oracle.params,
+            tokenizer=oracle.tokenizer, max_slots=2, page_size=16,
             max_pages_per_seq=8, steps_per_tick=1,
         )
         svc = PagedGenerationService(eng)
@@ -540,13 +533,13 @@ class TestCancellation:
 
 
 class TestPipelinedService:
-    def test_concurrent_requests_through_depth2_engine(self, contiguous):
+    def test_concurrent_requests_through_depth2_engine(self, oracle):
         from sentio_tpu.runtime.paged import ContinuousBatchingEngine
         from sentio_tpu.runtime.service import PagedGenerationService
 
         eng = ContinuousBatchingEngine(
-            model_config=contiguous.model_config, params=contiguous.params,
-            tokenizer=contiguous.tokenizer, max_slots=4, page_size=16,
+            model_config=oracle.model_config, params=oracle.params,
+            tokenizer=oracle.tokenizer, max_slots=4, page_size=16,
             max_pages_per_seq=8, steps_per_tick=4, max_tick_steps=8,
             pipeline_depth=2,
         )
@@ -565,7 +558,7 @@ class TestPipelinedService:
                 t.join(timeout=180)
             assert len(out) == 6
             refs = {
-                i: contiguous.generate([f"pipelined service {i}"],
+                i: oracle.generate([f"pipelined service {i}"],
                                        max_new_tokens=10, temperature=0.0)[0]
                 for i in range(6)
             }
@@ -577,18 +570,18 @@ class TestPipelinedService:
         finally:
             svc.close()
 
-    def test_streaming_through_depth2_engine(self, contiguous):
+    def test_streaming_through_depth2_engine(self, oracle):
         from sentio_tpu.runtime.paged import ContinuousBatchingEngine
         from sentio_tpu.runtime.service import PagedGenerationService
 
         eng = ContinuousBatchingEngine(
-            model_config=contiguous.model_config, params=contiguous.params,
-            tokenizer=contiguous.tokenizer, max_slots=2, page_size=16,
+            model_config=oracle.model_config, params=oracle.params,
+            tokenizer=oracle.tokenizer, max_slots=2, page_size=16,
             max_pages_per_seq=8, steps_per_tick=4, pipeline_depth=2,
         )
         svc = PagedGenerationService(eng)
         try:
-            want = contiguous.generate(["stream depth two"], max_new_tokens=12,
+            want = oracle.generate(["stream depth two"], max_new_tokens=12,
                                        temperature=0.0)[0]
             got = "".join(svc.generate_stream("stream depth two",
                                               max_new_tokens=12, temperature=0.0))

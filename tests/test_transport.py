@@ -277,6 +277,12 @@ class TestHandshake:
             assert got is not None and got[0][1] == "hello_reject"
             assert "protocol" in got[0][2]["reason"]
             t2.close()
+            # the registry books a rejection AFTER the reject frame went
+            # out: the dialer can hold the frame before the count moves
+            deadline = time.monotonic() + 5
+            while (reg.stats()["rejections"] < 2
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
             stats = reg.stats()
             assert stats["rejections"] == 2
             assert stats["registrations"] == 0

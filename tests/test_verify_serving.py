@@ -24,7 +24,7 @@ class TestConfidenceGatedVerify:
                 provider="tpu", model_preset="tiny", use_verifier=True,
                 verify_mode="gated", verify_confidence_threshold=threshold,
                 max_new_tokens=8, verifier_max_tokens=4, mode="fast",
-                use_paged_decode=True, kv_page_size=16,
+                kv_page_size=16,
                 kv_max_pages_per_seq=8, max_batch_size=4,
             ),
         )

@@ -89,16 +89,19 @@ class TestLoadModel:
 
 class TestEngineFromCheckpoint:
     def test_generate_with_converted_weights(self, llama_ckpt, hf_tokenizer_dir):
-        from sentio_tpu.runtime.engine import GeneratorEngine
+        from sentio_tpu.runtime.paged import ContinuousBatchingEngine
+        from sentio_tpu.runtime.weights import load_decoder
 
-        engine = GeneratorEngine(
-            config=GeneratorConfig(
-                checkpoint_path=llama_ckpt, tokenizer_path=hf_tokenizer_dir,
-                max_new_tokens=4,
-            ),
+        decoder = load_decoder(GeneratorConfig(
+            checkpoint_path=llama_ckpt, tokenizer_path=hf_tokenizer_dir,
+        ))
+        assert decoder.model_config.dim == 16  # config came from the checkpoint
+        engine = ContinuousBatchingEngine(
+            model_config=decoder.model_config, params=decoder.params,
+            tokenizer=decoder.tokenizer, max_slots=2, page_size=8,
+            max_pages_per_seq=4,
         )
-        assert engine.model_config.dim == 16  # config came from the checkpoint
-        out = engine.generate(["hello world"], max_new_tokens=4)
+        out = engine.run_all(["hello world"], max_new_tokens=4)
         assert len(out) == 1 and isinstance(out[0].text, str)
 
     def test_embedder_from_checkpoint(self, tmp_path, hf_tokenizer_dir):
