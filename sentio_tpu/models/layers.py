@@ -38,6 +38,17 @@ def dense(params: PyTree, x: Array, dtype: jnp.dtype = jnp.bfloat16) -> Array:
     return y
 
 
+def dense_t(params: PyTree, x: Array, dtype: jnp.dtype = jnp.bfloat16) -> Array:
+    """:func:`dense` over a kernel stored ``[out, in]`` (the layout a serving
+    program reads it in: models/llama.py ``serving_layout``)."""
+    y = jax.lax.dot_general(
+        x.astype(dtype), params["kernel"].astype(dtype),
+        (((x.ndim - 1,), (1,)), ((), ())))
+    if "bias" in params:
+        y = y + params["bias"].astype(dtype)
+    return y
+
+
 def embed_init(rng: Array, vocab: int, dim: int) -> PyTree:
     emb = jax.random.normal(rng, (vocab, dim)) * 0.02
     return {"embedding": emb.astype(jnp.float32)}

@@ -24,6 +24,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from sentio_tpu.analysis.audit.registry import jit_family
 from sentio_tpu.models import layers as L
@@ -111,6 +112,66 @@ def _write_cache(cache_layer: Array, kv: Array, index: Array | int) -> Array:
     )(cache_layer, kv, idx)
 
 
+SERVED_AS = {"wq": "wq_t", "wk": "wk_t", "wv": "wv_t"}
+
+
+def serving_layout(params: dict) -> dict:
+    """The tree the serving programs read: every layer's ``attn.wq``, ``wk``
+    and ``wv`` ([in, out], as a checkpoint holds them) stored ``[out, in]``
+    under ``wq_t``, ``wk_t``, ``wv_t``. The TPU compiler fuses each of these
+    three matmuls with the head reshape and RoPE that follow and reads its
+    weight column-major; a parameter lies row-major, so every call of a
+    program that takes the canonical tree begins by transposing them — 0.7-0.8
+    GB read and written a call at the benchmark's widths, at the head of every
+    decode tick and every prefill dispatch. ``[out, in]`` row-major IS that
+    order: the program reads the weight where it lies.
+
+    A layer at a time; the three are left out of the NEW tree, so they die
+    with the caller's. A host array is transposed on the host, a device array
+    on its devices (a column split over ``tp`` becomes the row split of the
+    transposed leaf: ``parallel/sharding.py``). Idempotent: a tree with
+    nothing to turn is returned as it is, the same object. Checkpoints, the
+    initialisers and ``models/convert.py`` keep the canonical names;
+    :func:`qkv_proj` reads either tree."""
+
+    def as_read(w):
+        if w.ndim < 2:  # a bias: one value an output column either way
+            return w
+        if isinstance(w, jax.Array):
+            return jnp.swapaxes(w, -1, -2)
+        # 64 rows at a time: numpy's own transposed copy walks the source by
+        # columns, four times slower (0.12 s a [4096, 4096] bf16 matrix)
+        turned = np.empty((*w.shape[:-2], w.shape[-1], w.shape[-2]), w.dtype)
+        for i in range(0, w.shape[-2], 64):
+            turned[..., i:i + 64] = np.swapaxes(w[..., i:i + 64, :], -1, -2)
+        return turned
+
+    out = params
+    for name, lp in params.items():
+        attn = lp.get("attn") if isinstance(lp, dict) else None
+        if not attn or not any(k in attn for k in SERVED_AS):
+            continue
+        if out is params:
+            out = dict(params)
+        out[name] = {**lp, "attn": {
+            SERVED_AS.get(k, k): jax.tree.map(as_read, w) if k in SERVED_AS else w
+            for k, w in attn.items()}}
+    return out
+
+
+def qkv_proj(lp: dict, cfg: LlamaConfig, x: Array) -> tuple[Array, Array, Array]:
+    """x [B, T, d] → q [B, T, H, D], k and v [B, T, Hkv, D] of one attention
+    block, before RoPE — from the ``[out, in]`` leaves of
+    :func:`serving_layout` where the tree holds them, else from the canonical
+    three. The same bf16 products summed in fp32 over the same terms."""
+    dt = cfg.jdtype
+    b, t, _ = x.shape
+    dense, names = (L.dense_t, SERVED_AS.values()) if "wq_t" in lp else (L.dense, SERVED_AS)
+    return tuple(
+        dense(lp[name], x, dt).reshape(b, t, heads, cfg.head_dim)
+        for name, heads in zip(names, (cfg.n_heads, cfg.n_kv_heads, cfg.n_kv_heads)))
+
+
 def _attn(
     lp: dict,
     cfg: LlamaConfig,
@@ -128,9 +189,7 @@ def _attn(
     b, t, d = x.shape
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
-    q = L.dense(lp["wq"], x, dt).reshape(b, t, h, hd)
-    k = L.dense(lp["wk"], x, dt).reshape(b, t, hkv, hd)
-    v = L.dense(lp["wv"], x, dt).reshape(b, t, hkv, hd)
+    q, k, v = qkv_proj(lp, cfg, x)
     q = L.apply_rope(q, positions, cos, sin)
     k = L.apply_rope(k, positions, cos, sin)
 

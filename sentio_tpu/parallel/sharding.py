@@ -26,8 +26,11 @@ LLAMA_TP_RULES: Rules = (
     # embeddings: shard vocab dim (row) — logits psum'd at the head
     (r".*embed_tokens/embedding$", P(AXIS_TP, None)),
     (r".*lm_head/kernel$", P(None, AXIS_TP)),
-    # attention: q/k/v column-parallel, o row-parallel
+    # attention: q/k/v column-parallel, o row-parallel. The serving tree
+    # (models/llama.py ``serving_layout``) stores q/k/v [out, in]: the same
+    # split of the output features is then a split of the rows
     (r".*attn/(wq|wk|wv)/kernel$", P(None, AXIS_TP)),
+    (r".*attn/(wq_t|wk_t|wv_t)/kernel$", P(AXIS_TP, None)),
     (r".*attn/wo/kernel$", P(AXIS_TP, None)),
     # swiglu mlp: gate/up column-parallel, down row-parallel
     (r".*mlp/(w_gate|w_up)/kernel$", P(None, AXIS_TP)),

@@ -63,7 +63,7 @@ from sentio_tpu.infra.phases import (
     ENGINE_PHASES, KV_PAGE_KINDS, ROW_STEP_KINDS, PhaseTimer,
 )
 from sentio_tpu.infra.tracing import annotation
-from sentio_tpu.models.llama import LlamaConfig
+from sentio_tpu.models.llama import LlamaConfig, qkv_proj, serving_layout
 from sentio_tpu.parallel.batcher import bucket_size
 
 Array = object  # jax.Array — jax imported lazily
@@ -307,9 +307,7 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
     for i in range(cfg.n_layers):
         lp = params[f"layers_{i}"]
         xn = L.rmsnorm(lp["attn_norm"], x, cfg.norm_eps)
-        q = L.dense(lp["attn"]["wq"], xn, dt).reshape(b, 1, h, hd)
-        k = L.dense(lp["attn"]["wk"], xn, dt).reshape(b, 1, hkv, hd)
-        v = L.dense(lp["attn"]["wv"], xn, dt).reshape(b, 1, hkv, hd)
+        q, k, v = qkv_proj(lp["attn"], cfg, xn)
         q = L.apply_rope(q, positions, cos, sin)
         k = L.apply_rope(k, positions, cos, sin)
 
@@ -361,8 +359,14 @@ def scatter_prefill(k_pages, v_pages, k_cache, v_cache, page_table):
                 "q": pages["q"].at[:, page_table].set(q),
                 "s": pages["s"].at[:, page_table].set(sc.swapaxes(-1, -2)),
             }
-        # dims 1 of pages indexed by [B, NB] table → scatter [L,B,NB,page,H,D]
-        return pages.at[:, page_table].set(r)
+        # dim 1 of pages indexed by the [B, NB] table → scatter of [L, B, NB]
+        # pages, each written as the [page·Hkv, D] matrix it is in memory (a
+        # view, as the decode kernel takes it): asked to scatter
+        # [page, Hkv, D] windows at 4 kv heads, the TPU compiler first turns
+        # the WHOLE pool head-major and back, K and V, every call
+        flat = pages.reshape(lcount, -1, page * hkv, hd)
+        return flat.at[:, page_table].set(
+            r.reshape(lcount, b, nb, page * hkv, hd)).reshape(pages.shape)
 
     return scatter_one(k_pages, k_cache), scatter_one(v_pages, v_cache)
 
@@ -559,6 +563,16 @@ class ContinuousBatchingEngine:
                 mesh=mesh, model_config=self.cfg, rng_seed=rng_seed).params
         self.tokenizer = tokenizer or ByteTokenizer(self.cfg.vocab_size)
         is_moe = isinstance(self.cfg, MoeConfig)
+        # the tree the compiled programs read (models/llama.py
+        # ``serving_layout``): made here once from a canonical tree, passed
+        # through untouched — the same object, so replicas and
+        # ``spawn_fresh`` share it — when ``load_decoder`` or another engine
+        # has made it already
+        handed, params = params, serving_layout(params)
+        if params is not handed:
+            logging.getLogger(__name__).info(
+                "attention projections stored as the programs read them: "
+                "wq, wk and wv of %d layers turned [out, in]", self.cfg.n_layers)
         if mesh is None and not all(
                 isinstance(leaf, jax.Array)
                 for leaf in jax.tree_util.tree_leaves(params)):
@@ -644,7 +658,7 @@ class ContinuousBatchingEngine:
                     f"draft vocab {draft_config.vocab_size} != target "
                     f"vocab {self.cfg.vocab_size}"
                 )
-            self.draft_params = draft_params
+            self.draft_params = serving_layout(draft_params)
         if kv_quant not in ("none", "int8"):
             raise ValueError(f"kv_quant must be 'none' or 'int8', got {kv_quant!r}")
         # int8 pages: ~half the pool HBM and decode-read bandwidth; scales

@@ -126,14 +126,17 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
     seeded random init of ``model_config``'s family (the deterministic
     fake-model mode of tests and offline development; ``cfg.model_preset``
     picks the configuration when none is given) under a byte tokenizer.
-    ``cfg`` is a ``GeneratorConfig``; None means no checkpoint. The tree goes
+    ``cfg`` is a ``GeneratorConfig``; None means no checkpoint. The tree is
+    the SERVING tree (``models/llama.py::serving_layout``: ``attn.wq_t``,
+    ``wk_t``, ``wv_t`` stored [out, in] where a checkpoint holds ``wq``,
+    ``wk``, ``wv``) and goes
     to its final placement ONCE: by ``LLAMA_TP_RULES`` / ``MOE_EP_RULES``
     under a mesh, onto the default device without one. ``mmap`` maps the
     checkpoint's leaves in place (worker processes on one host share one
     page-cache copy)."""
     import jax
 
-    from sentio_tpu.models.llama import LlamaConfig, init_llama
+    from sentio_tpu.models.llama import LlamaConfig, init_llama, serving_layout
     from sentio_tpu.models.moe import MoeConfig, init_moe
     from sentio_tpu.models.tokenizer import ByteTokenizer
     from sentio_tpu.parallel.sharding import (
@@ -161,8 +164,12 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
     if params is None:
         init = init_moe if is_moe else init_llama
         params = init(jax.random.PRNGKey(rng_seed), model_config)
+    # q, k and v in the order the serving programs read them, made before
+    # placement: a checkpoint's leaves are turned on the host and no second
+    # copy of a weight ever reaches the device
     params = shard_params(
-        params, mesh, MOE_EP_RULES if is_moe else LLAMA_TP_RULES)
+        serving_layout(params), mesh,
+        MOE_EP_RULES if is_moe else LLAMA_TP_RULES)
     return Decoder(
         params=params, model_config=model_config,
         tokenizer=tokenizer or ByteTokenizer(model_config.vocab_size),

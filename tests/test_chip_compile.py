@@ -25,9 +25,10 @@ from sentio_tpu.kernels.paged_attention import (
     paged_attention,
     paged_attention_quant,
 )
-from sentio_tpu.models.llama import LlamaConfig, init_llama
+from sentio_tpu.models.llama import LlamaConfig, init_llama, llama_forward, serving_layout
 from sentio_tpu.parallel.mesh import MESH_AXES
-from sentio_tpu.runtime.paged import paged_decode_forward
+from sentio_tpu.parallel.sharding import LLAMA_TP_RULES, make_param_shardings
+from sentio_tpu.runtime.paged import paged_decode_forward, scatter_prefill
 
 H, HKV, D = 32, 8, 128  # LlamaConfig.llama3_8b: 32 query / 8 KV heads of 128
 LAYERS = 2  # of the pool: the kernel takes it whole and a layer's index
@@ -206,7 +207,7 @@ def _pool_shaped(hlo_text: str, shapes: tuple) -> list:
     return found
 
 
-def test_decode_step_reads_the_pool_where_it_lies(v5e):
+def test_decode_step_reads_the_pool_where_it_lies(decoder_programs):
     """Two layers of ``paged_decode_forward`` in a scan over a donated pool,
     as ``step_n`` runs them: beside the kernel, the only thing that may make
     an array the size of the pool is the scatter that updates it in place,
@@ -214,19 +215,101 @@ def test_decode_step_reads_the_pool_where_it_lies(v5e):
     fuse its operands, so a ``pages[layer]`` handed to it is a copy of every
     page of the layer, per layer, per sub-step (35 % of the device's time
     until PR 26)."""
-    cfg = LlamaConfig(n_layers=2)
     # 16 slots of 18 pages, as the benchmark's mistral cell serves: 151 MB a
     # pool. (One of 34 MB the compiler prefetches whole into fast memory.)
-    slots, nb, page = 16, 18, 128
-    place = _on_one_chip(v5e)
-    params = jax.tree_util.tree_map(
-        lambda a: place(a.shape, a.dtype),
-        jax.eval_shape(lambda: init_llama(jax.random.PRNGKey(0), cfg)))
-    pool_shape = (cfg.n_layers, 1 + slots * nb, page, cfg.n_kv_heads, cfg.head_dim)
-    pool = place(pool_shape, jnp.bfloat16)
-    impl = make_paged_attn_impl(interpret=False)
+    w = WIDTHS["mistral"]
+    pool_shape = (LAYERS, 1 + w["slots"] * w["nb"], 128, w["n_kv_heads"], D)
+    text = decoder_programs("mistral")[1]["step"]
 
-    def steps(params, tok, lens, table, k_pages, v_pages):
+    assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
+
+    def hlo_shape(dims):
+        return "bf16[" + ",".join(map(str, dims)) + "]"
+
+    made = _pool_shaped(text, (hlo_shape(pool_shape), hlo_shape(pool_shape[1:])))
+    in_place = {"parameter", "get-tuple-element", "scatter", "fusion:scatter"}
+    assert [m for m in made if m[2] not in in_place] == []
+    # K and V, each layer: the parse found the updates it is there to allow
+    assert sum(m[2] == "fusion:scatter" for m in made) == 2 * LAYERS
+
+
+# ---------------------------------------------------------------- weights
+#
+# A parameter lies row-major; the three attention projections, each feeding a
+# head reshape and RoPE, are wanted column-major by the v5e compiler, so every
+# call of a program that takes the canonical tree begins by transposing them
+# (``copy`` in the device trace: 0.7-0.8 GB read and written a call at the
+# benchmark's widths, until PR 31). The tree the engine serves stores them
+# [out, in] (``serving_layout``): that order, read where it lies.
+
+# the benchmark's two configurations (benchmark/configs/*.json), 2 layers, and
+# a prefill segment each cell runs behind 512 prior tokens: mistral's 512, yi's
+# 256 (the rest of its 0.7k-token prompts; 512 rows of 4096 would have the
+# shape of yi's own wk and the parse below could not tell them apart)
+WIDTHS = {
+    "mistral": dict(n_kv_heads=8, mlp_dim=14336, vocab_size=32768, slots=16, nb=18, segment=512),
+    "yi": dict(n_kv_heads=4, mlp_dim=11008, vocab_size=64000, slots=32, nb=10, segment=256),
+}
+WEIGHT_ELEMENTS = 2 ** 21  # the smallest projection (yi's wk) holds exactly this
+
+
+def _weight_copies(hlo_text: str, params) -> list:
+    """Instructions of the compiled text that MAKE an array of a weight's
+    shape (one device's share of it, either way round) of 2**21 elements or
+    more: a copy, a transpose, a pad, or a fusion that neither holds a matmul
+    (what it makes is an activation) nor is a bitcast alone (a view)."""
+    shapes = set()
+    for leaf in jax.tree_util.tree_leaves(params):
+        dims = leaf.sharding.shard_shape(leaf.shape)
+        if len(dims) == 2 and dims[0] * dims[1] >= WEIGHT_ELEMENTS:
+            shapes |= {dims, dims[::-1]}
+    makes_nothing, body = set(), None  # bodies that hold a matmul, or only a view
+    for line in hlo_text.splitlines():
+        head = re.match(r"%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            body = head.group(1)
+        elif body and re.search(r" (dot|convolution)\(|ROOT \S+ = \S+ bitcast\(", line):
+            makes_nothing.add(body)
+    found = []
+    for line in hlo_text.splitlines():
+        inst = re.match(
+            r"\s+(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* (copy|pad|transpose|fusion)\(", line)
+        if not inst or tuple(int(n) for n in inst.group(1).split(",")) not in shapes:
+            continue
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        if inst.group(2) != "fusion" or called.group(1) not in makes_nothing:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _decoder_programs(topo, width: str, served: bool, tp: int = 1):
+    """→ (params, {program: its compiled text}) for two layers at one of the
+    benchmark's widths, weights in bf16 as a checkpoint holds them: the decode
+    step as ``step_n`` runs it (a scan over the donated pool, the engine's
+    Pallas kernel) and one prefill segment behind 512 prior tokens."""
+    w = dict(WIDTHS[width])
+    slots, nb, segment, page = w.pop("slots"), w.pop("nb"), w.pop("segment"), 128
+    cfg = LlamaConfig(n_layers=LAYERS, **w)
+    mesh = Mesh(np.array(topo.devices[:tp]).reshape(1, 1, 1, 1, 1, tp), MESH_AXES)
+
+    def place(shape, dtype, spec=None):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec or P()))
+
+    def tree():
+        canonical = init_llama(jax.random.PRNGKey(0), cfg)
+        return serving_layout(canonical) if served else canonical
+
+    params = jax.eval_shape(tree)
+    params = jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16 if a.ndim == 2 else a.dtype, sharding=s),
+        params, make_param_shardings(params, mesh, LLAMA_TP_RULES))
+    heads = P(None, None, None, "tp", None)
+    pool = place((cfg.n_layers, 1 + slots * nb, page, cfg.n_kv_heads, cfg.head_dim),
+                 jnp.bfloat16, heads)
+    impl = make_paged_attn_impl(interpret=False, mesh=mesh if tp > 1 else None)
+
+    def step(params, tok, lens, table, k_pages, v_pages):
         def body(carry, _):
             tok, lens, k_pages, v_pages = carry
             logits, k_pages, v_pages = paged_decode_forward(
@@ -237,17 +320,99 @@ def test_decode_step_reads_the_pool_where_it_lies(v5e):
 
         return jax.lax.scan(body, (tok, lens, k_pages, v_pages), None, length=2)[0]
 
-    text = jax.jit(steps, donate_argnums=(4, 5)).lower(
-        params, place((slots,), jnp.int32), place((slots,), jnp.int32),
-        place((slots, nb), jnp.int32), pool, pool).compile().as_text()
+    def prefill(params, ids, positions, cache, n_prior):
+        return llama_forward(params, cfg, ids, positions=positions, cache=cache,
+                             cache_index=n_prior)
 
-    assert text.count('custom_call_target="tpu_custom_call"') == cfg.n_layers
+    cache = place((cfg.n_layers, 1, 512 + segment, cfg.n_kv_heads, cfg.head_dim),
+                  jnp.bfloat16, heads)
+    texts = {
+        "step": jax.jit(step, donate_argnums=(4, 5)).lower(
+            params, place((slots,), jnp.int32), place((slots,), jnp.int32),
+            place((slots, nb), jnp.int32), pool, pool).compile().as_text(),
+        "prefill": jax.jit(prefill, donate_argnums=(3,)).lower(
+            params, place((1, segment), jnp.int32), place((1, segment), jnp.int32),
+            {"k": cache, "v": cache}, place((1,), jnp.int32)).compile().as_text(),
+    }
+    return params, texts
 
-    def hlo_shape(dims):
-        return "bf16[" + ",".join(map(str, dims)) + "]"
 
-    made = _pool_shaped(text, (hlo_shape(pool_shape), hlo_shape(pool_shape[1:])))
-    in_place = {"parameter", "get-tuple-element", "scatter", "fusion:scatter"}
-    assert [m for m in made if m[2] not in in_place] == []
-    # K and V, each layer: the parse found the updates it is there to allow
-    assert sum(m[2] == "fusion:scatter" for m in made) == 2 * cfg.n_layers
+@pytest.fixture(scope="module")
+def decoder_programs(v5e):
+    made = {}
+
+    def get(width, served=True, tp=1):
+        key = (width, served, tp)
+        if key not in made:
+            made[key] = _decoder_programs(v5e, width, served, tp)
+        return made[key]
+
+    return get
+
+
+@pytest.mark.parametrize("tp", [1, 4])
+@pytest.mark.parametrize("program", ["step", "prefill"])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_serving_programs_read_their_weights_where_they_lie(
+        decoder_programs, width, program, tp):
+    """The tree the engine serves, at both cells' widths, on one chip and
+    split by ``LLAMA_TP_RULES`` over four: nothing in the compiled decode step
+    or prefill segment makes an array with a weight's shape."""
+    params, texts = decoder_programs(width, tp=tp)
+    assert any("wq_t" in jax.tree_util.keystr(path) for path, _ in
+               jax.tree_util.tree_flatten_with_path(params)[0])
+    assert _weight_copies(texts[program], params) == []
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_canonical_tree_is_copied_every_call(decoder_programs, program):
+    """The control: with ``wq``, ``wk``, ``wv`` apart the same parse finds the
+    transposing copies, two layers of three, so the test above cannot pass by
+    looking in the wrong place."""
+    params, texts = decoder_programs("mistral", served=False)
+    copies = _weight_copies(texts[program], params)
+    assert len(copies) == 6 and all(" copy(" in c for c in copies), copies
+
+
+def _scatter_makes(topo, width: str, scatter) -> list:
+    """What makes a pool-sized array (its shape, or the kernel's view of it)
+    in ``scatter`` compiled over donated pools at one of the cells'
+    geometries: two 512-token rows of fresh K and V into their pages."""
+    w = WIDTHS[width]
+    place = _on_one_chip(topo)
+    shape = (LAYERS, 1 + w["slots"] * w["nb"], 128, w["n_kv_heads"], D)
+    pool = place(shape, jnp.bfloat16)
+    cache = place((LAYERS, 2, 512, w["n_kv_heads"], D), jnp.bfloat16)
+    text = jax.jit(scatter, donate_argnums=(0, 1)).lower(
+        pool, pool, cache, cache, place((2, 4), jnp.int32)).compile().as_text()
+    view = (*shape[:2], shape[2] * shape[3], shape[4])
+    return [op for _, _, op in _pool_shaped(text, tuple(
+        "bf16[" + ",".join(map(str, dims)) + "]" for dims in (shape, view)))]
+
+
+IN_PLACE = {"parameter", "bitcast", "scatter", "fusion:scatter"}
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_prefill_scatter_writes_the_pool_where_it_lies(v5e, width):
+    """What every prefill dispatch ends with. Until PR 31 yi's (4 kv heads)
+    copied both pools twice a call: 4 x 673 MB, 8 ms of a 26 ms call."""
+    made = _scatter_makes(v5e, width, scatter_prefill)
+    assert set(made) <= IN_PLACE, made
+    assert made.count("fusion:scatter") == 2  # K and V: the parse sees the update
+
+
+def test_scatter_of_page_windows_copies_the_pool_at_4_kv_heads(v5e):
+    """The control, and the pitfall: the same scatter written over
+    ``[page, Hkv, D]`` windows makes the compiler turn the whole pool
+    head-major and back, K and V."""
+
+    def windows(k_pages, v_pages, k_cache, v_cache, table):
+        def one(pages, cache):
+            lcount, b, s, hkv, hd = cache.shape
+            return pages.at[:, table].set(cache.reshape(lcount, b, s // 128, 128, hkv, hd))
+
+        return one(k_pages, k_cache), one(v_pages, v_cache)
+
+    assert _scatter_makes(v5e, "yi", windows).count("copy") == 4
+    assert set(_scatter_makes(v5e, "mistral", windows)) <= IN_PLACE  # 8 kv heads: none
