@@ -7,8 +7,22 @@ config class and no kind of state a token leaves behind. The contract:
 
 for the served run (``run.py``)
 * ``program_config(model)`` — the published ``config.json`` keys of a
-  configuration file mapped onto the program's own config fields (``/info``
-  must report them);
+  configuration file mapped onto the program's own config fields, every
+  field of the program's config class (``/info`` must report them, and
+  ``check_config`` builds its object from them);
+* ``WIDTHS`` — ``{published key: field of that object}``: which keys of the
+  file this family holds the program to, unchanged. It is how a
+  configuration is held to ITS family: ``tests/benchmark/
+  test_benchmark_costs.py`` asks ``load_family`` of every file it globs, and
+  asserts that ``program_config`` builds the object ``check_config`` returns
+  at the file's depth and positions and that every key here reads the same
+  in the file and in that object (here: ``hidden_size → dim``,
+  ``intermediate_size → mlp_dim``, ``num_attention_heads → n_heads``,
+  ``num_key_value_heads → n_kv_heads``, ``head_dim``, ``vocab_size``,
+  ``num_hidden_layers → n_layers``, ``rope_theta``, ``rms_norm_eps →
+  norm_eps``, ``max_position_embeddings → max_len``, ``torch_dtype →
+  dtype``). A family whose heads are not ``hidden / heads`` wide, or that
+  has no ``rms_norm_eps``, states its own keys and the test names no other;
 * ``write_checkpoint(path, model, seed)`` — seeded bf16 weights in the
   program's checkpoint format (``LLM_CHECKPOINT`` is the surface a user has);
 * ``pool_bytes(model, env)`` — the bytes ``/info`` must report for the pool
@@ -71,7 +85,12 @@ selection (the Pallas page-table walk on a TPU). The state a token leaves is
 K and V and nothing else. The reference is ``benchmark/reference.py``.
 
 Another family (``moe``) is another file beside this one, chosen by the
-``family`` key of the configuration file, with its own reference file.
+``family`` key of the configuration file, with its own reference file. That
+its files are ENOUGH is proven by laying them into a copy of ``benchmark/``
+and running the copy: ``tests/benchmark/test_benchmark_second_family.py``
+takes a routed family (``tests/benchmark/scratch_moe_family.py``) through
+``run.py``, ``server.py`` and ``check.py`` as child processes, and through
+the tests that glob configurations, mixes and metric files.
 """
 
 from __future__ import annotations
@@ -84,6 +103,17 @@ import ml_dtypes
 import numpy as np
 
 BYTES_BF16 = 2
+
+
+# published key → field of the program's config object (``LlamaConfig``;
+# ``head_dim`` is its property, hidden over heads)
+WIDTHS = {
+    "hidden_size": "dim", "intermediate_size": "mlp_dim",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+    "head_dim": "head_dim", "vocab_size": "vocab_size", "num_hidden_layers": "n_layers",
+    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
+    "max_position_embeddings": "max_len", "torch_dtype": "dtype",
+}
 
 
 def program_config(model: dict) -> dict:
@@ -139,8 +169,10 @@ def parallel_layers(make, cfg: dict, seeds) -> dict:
 TEXT_IDS = 261
 
 
-def make_params(model: dict, seed: int) -> dict:
-    """The tree of the program's ``init_llama``, in bf16, from ``seed``.
+def seeded_tree(cfg: dict, seed: int, layer) -> dict:
+    """Embedding, head, final norm and ``layer(rng, cfg)`` for every layer, in
+    bf16, from ``seed`` (``cfg``: the program's config fields; a family with
+    this trunk and other layers passes its own ``layer``).
 
     The head's columns for ``TEXT_IDS`` are zero, so those logits are 0 and a
     greedy answer never holds one (the largest of the other 32k logits is
@@ -149,7 +181,6 @@ def make_params(model: dict, seed: int) -> dict:
     prompt, which quotes the answer, is then as long as the mix declares for
     EVERY seed; a shorter one lands in a prefill program the warm-up never
     compiled."""
-    cfg = program_config(model)
     head, *layer_seeds = np.random.SeedSequence(seed).spawn(1 + cfg["n_layers"])
     rng = np.random.default_rng(head)
     embedding = normal_bf16(rng, (cfg["vocab_size"], cfg["dim"]), 0.02)
@@ -159,8 +190,13 @@ def make_params(model: dict, seed: int) -> dict:
         "embed_tokens": {"embedding": embedding},
         "lm_head": lm_head,
         "final_norm": {"scale": np.ones((cfg["dim"],), np.float32)},
-        **parallel_layers(_layer, cfg, layer_seeds),
+        **parallel_layers(layer, cfg, layer_seeds),
     }
+
+
+def make_params(model: dict, seed: int) -> dict:
+    """The tree of the program's ``init_llama``, in bf16, from ``seed``."""
+    return seeded_tree(program_config(model), seed, _layer)
 
 
 def write_checkpoint(path: Path, model: dict, seed: int) -> None:

@@ -374,6 +374,10 @@ def run(args) -> int:
          ttft_p90_ms=traffic.percentile(client["ttft_ms"], 90),
          ttft_max_ms=max(client["ttft_ms"], default=None),
          stream_gap_max_ms=client["stream_gap_max_ms"],
+         # every request's gap between tokens, in order: the median of a few
+         # tens of them moves in steps (a tick that carries a prefill segment
+         # or not), and the list shows which step a run's median stood on
+         tpot_ms_sorted=[round(v, 3) for v in sorted(client["tpot_ms"])],
          answer_tokens_in_window=client["answer_tokens_in_window"], problems=problems)
 
     metrics: dict[str, dict] = {}

@@ -4,11 +4,18 @@ SwiGLU experts behind a capacity dispatch) held to the dropless reference
 beside this file. ``capacity_factor`` is experts over experts per token, so
 an expert's buffer holds every token of a call and none is dropped.
 
-Only what ``check.py`` asks of a family is here (``families/llama.py`` has
-the whole contract). The state a token leaves is K and V, as in the dense
-family, and the teacher-forced pieces are the dense family's — but this
-family CHOOSES: which experts a token goes to is decided by rank, so its
-pieces also say what the program chose (``CHOICES``, ``reading_picks``).
+The WHOLE contract is here (``families/llama.py`` has it): the served half
+that ``run.py`` asks for (``program_config``, ``WIDTHS``, ``write_checkpoint``,
+``pool_bytes``), the check's half, and the rooflines' counts. The state a
+token leaves is K and V, as in the dense family, and the teacher-forced
+pieces are the dense family's — but this family CHOOSES: which experts a
+token goes to is decided by rank, so its pieces also say what the program
+chose (``CHOICES``, ``reading_picks``).
+
+Two ways in, one file: registered for a test under the name ``load_family``
+looks up (``test_benchmark_family_seam.py``), or laid into a copy of
+``benchmark/`` as ``families/scratch_moe.py`` with its reference beside it
+(``test_benchmark_second_family.py``: the whole run in child processes).
 """
 
 from __future__ import annotations
@@ -19,18 +26,109 @@ import numpy as np
 
 from benchmark.families import llama as dense
 
-REFERENCE = "scratch_moe_reference"
+# beside this file, wherever that is: a top-level module next to the tests,
+# ``benchmark.families.scratch_moe_reference`` in a tree the file is laid into
+REFERENCE = f"{__package__}.scratch_moe_reference" if __package__ else "scratch_moe_reference"
 # what the forward decides by rank → the reference's keyword for how many it takes
 CHOICES = {"experts": "experts_per_token"}
+
+
+# published key → field of the program's config object (``MoeConfig``)
+WIDTHS = {**dense.WIDTHS, "num_local_experts": "n_experts", "num_experts_per_tok": "experts_per_token"}
+
+
+def program_config(model: dict) -> dict:
+    """Published keys → ``sentio_tpu.models.moe.MoeConfig`` fields, every one
+    (``/info`` reports the whole dataclass). ``capacity_factor`` is experts
+    over experts a token: an expert's buffer then holds every token of a call
+    and the program's capacity dispatch drops none, as the reference drops
+    none; ``router_aux_weight`` is the program's default, a training term."""
+    experts, chosen = int(model["num_local_experts"]), int(model["num_experts_per_tok"])
+    return {**dense.program_config(model), "n_experts": experts, "experts_per_token": chosen,
+            "capacity_factor": experts / chosen, "router_aux_weight": 0.01}
 
 
 def check_config(model: dict, layers: int, max_len: int):
     from sentio_tpu.models.moe import MoeConfig
 
-    experts, chosen = int(model["num_local_experts"]), int(model["num_experts_per_tok"])
-    return MoeConfig(**{**dense.program_config(model), "n_layers": layers, "max_len": max_len},
-                     n_experts=experts, experts_per_token=chosen,
-                     capacity_factor=experts / chosen)
+    return MoeConfig(**{**program_config(model), "n_layers": layers, "max_len": max_len})
+
+
+# ------------------------------------------------------------ seeded weights
+
+
+def _layer(rng, cfg: dict) -> dict:
+    """One layer of the program's ``init_moe`` tree in bf16: the dense
+    family's attention, a router and three stacks of expert matrices."""
+    dim, mlp, experts = cfg["dim"], cfg["mlp_dim"], cfg["n_experts"]
+    kv_dim = cfg["n_kv_heads"] * (dim // cfg["n_heads"])
+    matrix = lambda n_in, n_out: {"kernel": dense.normal_bf16(rng, (n_in, n_out), n_in ** -0.5)}  # noqa: E731
+    stack = lambda n_in, n_out: dense.normal_bf16(rng, (experts, n_in, n_out), n_in ** -0.5)  # noqa: E731
+    return {
+        "attn_norm": {"scale": np.ones((dim,), np.float32)},
+        "attn": {"wq": matrix(dim, dim), "wk": matrix(dim, kv_dim),
+                 "wv": matrix(dim, kv_dim), "wo": matrix(dim, dim)},
+        "mlp_norm": {"scale": np.ones((dim,), np.float32)},
+        "moe": {"router": matrix(dim, experts), "w_gate": stack(dim, mlp),
+                "w_up": stack(dim, mlp), "w_down": stack(mlp, dim)},
+    }
+
+
+def make_params(model: dict, seed: int) -> dict:
+    """The tree of the program's ``init_moe``, in bf16, from ``seed``: the
+    dense family's trunk (its head's text columns zero, for its reason)."""
+    return dense.seeded_tree(program_config(model), seed, _layer)
+
+
+def write_checkpoint(path, model: dict, seed: int) -> None:
+    from sentio_tpu.runtime.checkpoint import save_pytree
+
+    save_pytree(path, make_params(model, seed), meta={"family": "moe", "config": program_config(model)})
+
+
+# ------------------------------------------------ bytes and operations
+
+pool_bytes = dense.pool_bytes  # K and V of every layer and nothing else: the dense family's pool
+
+
+def weight_params(model: dict) -> dict:
+    """Parameters a layer: its attention, its router, ONE expert; the head."""
+    d, f = model["hidden_size"], model["intermediate_size"]
+    q, kv = (model[k] * model["head_dim"] for k in ("num_attention_heads", "num_key_value_heads"))
+    return {"attention": d * q + 2 * d * kv + q * d, "router": d * model["num_local_experts"],
+            "expert": 3 * d * f, "head": model["vocab_size"] * d}
+
+
+def experts_read(model: dict, rows: int) -> float:
+    """The experts of one layer that ``rows`` tokens reach, each taking
+    ``num_experts_per_tok`` of them: E (1 - (1 - k/E)^rows) under a router
+    that spreads evenly, which a seeded one does. The LEAST a routed step
+    must read; the program's capacity dispatch reads every expert."""
+    experts, chosen = model["num_local_experts"], model["num_experts_per_tok"]
+    return experts * (1.0 - (1.0 - chosen / experts) ** rows)
+
+
+def decode_substep_cost(model: dict, rows: int, context_tokens: float) -> dict:
+    """One decode sub-step over ``rows`` slots (all computed) whose occupied
+    rows hold ``context_tokens`` tokens: every layer's attention and router,
+    the experts the rows reach (``experts_read``), the head, the embedding's
+    ``rows`` rows, and the K and V the attention reads; 2 operations per
+    multiply-add of every matmul a row goes through (its own
+    ``num_experts_per_tok`` experts), plus QK and PV over the context."""
+    w, n_layers = weight_params(model), model["num_hidden_layers"]
+    layer = w["attention"] + w["router"] + experts_read(model, rows) * w["expert"]
+    bytes_ = (dense.BYTES_BF16 * (n_layers * layer + w["head"] + rows * model["hidden_size"])
+              + context_tokens * dense.kv_bytes_per_token(model))
+    row = n_layers * (w["attention"] + w["router"] + model["num_experts_per_tok"] * w["expert"]) + w["head"]
+    attn = 4 * context_tokens * model["num_attention_heads"] * model["head_dim"] * n_layers
+    return {"bytes": float(bytes_), "flops": float(2 * rows * row + attn)}
+
+
+# the decode attention kernel is the dense family's, over the same pool
+KERNEL_COSTS = {"paged_attention": dense.paged_attention_cost}
+
+
+# ------------------------------------------------------ the reference check
 
 
 def init_params(key, cfg) -> dict:

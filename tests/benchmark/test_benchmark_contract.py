@@ -3,12 +3,10 @@ against the files the harness finds by that name."""
 
 import json
 import re
-from pathlib import Path
 
 import pytest
+from bench_tree import BENCH, REPO
 
-REPO = Path(__file__).resolve().parents[2]
-BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 LINE = re.compile(r"^[^\t\n]{1,200}$")

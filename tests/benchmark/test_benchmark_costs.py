@@ -1,23 +1,92 @@
-"""Configuration files against the program's config class, and the byte and
-operation counts against numbers worked by hand."""
+"""Configuration files against the program's config class, each through its
+OWN family, and the byte and operation counts against numbers worked by hand."""
 
-import json
-from pathlib import Path
+import dataclasses
 
 import pytest
+from bench_tree import load_dir
 
 from benchmark.families import llama as family
+from benchmark.families import load_family
 from benchmark.roofline import device_peaks, least_time_s
 
-REPO = Path(__file__).resolve().parents[2]
-CONFIGS = {p.stem: json.loads(p.read_text()) for p in (REPO / "benchmark" / "configs").glob("*.json")}
+CONFIGS = load_dir("configs")
+DENSE = ("mistral-7b-v0.3-l16", "yi-1.5-6b-l16")
+
+
+def held_to_its_family(model: dict) -> None:
+    """Whatever family the file names: ``program_config`` builds the object
+    ``check_config`` returns (at the file's depth and positions, every field
+    equal), and each published key the family declares (``WIDTHS``) reads in
+    that object what it reads in the file. No key is named here: a family
+    with heads wider than ``hidden / heads`` or with no ``rms_norm_eps``
+    states its own."""
+    own = load_family(model)
+    for as_run in (model, {**model, **model["rehearsal"]}):
+        cfg = own.check_config(as_run, as_run["num_hidden_layers"], as_run["max_position_embeddings"])
+        assert dataclasses.is_dataclass(cfg) and type(cfg)(**own.program_config(as_run)) == cfg
+        assert dataclasses.asdict(cfg) == own.program_config(as_run)  # what /info reports, field for field
+        assert own.WIDTHS and set(own.WIDTHS) <= set(as_run)
+        for key, field in own.WIDTHS.items():
+            assert getattr(cfg, field) == as_run[key], (key, field)
+    # what was cut is held too: the value that runs is the file's, not the published one
+    assert set(model["reduced"]) <= set(own.WIDTHS)
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_file_widths_are_what_the_program_config_reports(name):
+    held_to_its_family(CONFIGS[name])
+
+
+def test_a_family_with_wider_heads_and_no_rms_eps_is_held_to_its_own_keys(monkeypatch):
+    """A family as a stand-in module, a file as a dict: 128 heads of 128 at
+    hidden 4,096 (attention four times as wide as the model), a LayerNorm's
+    ``layer_norm_eps`` and no ``rms_norm_eps``. At the parent this file read
+    ``assert 32 == 128`` whatever family it named; now it is held to the keys
+    ITS family declares, and still refused where it departs from them."""
+    import sys
+    import types
+
+    @dataclasses.dataclass(frozen=True)
+    class WideConfig:
+        dim: int
+        n_heads: int
+        head_dim: int
+        n_layers: int
+        max_len: int
+        eps: float
+
+    fields = {"hidden_size": "dim", "num_attention_heads": "n_heads", "head_dim": "head_dim",
+              "num_hidden_layers": "n_layers", "max_position_embeddings": "max_len", "layer_norm_eps": "eps"}
+    program_config = lambda model: {field: model[key] for key, field in fields.items()}  # noqa: E731
+    wide = types.ModuleType("benchmark.families.scratch_wide")
+    wide.WIDTHS, wide.program_config = fields, program_config
+    wide.check_config = lambda model, layers, max_len: WideConfig(
+        **{**program_config(model), "n_layers": layers, "max_len": max_len})
+    monkeypatch.setitem(sys.modules, wide.__name__, wide)
+    model = {"family": "scratch_wide", "hidden_size": 4096, "num_attention_heads": 128, "head_dim": 128,
+             "num_hidden_layers": 4, "max_position_embeddings": 8192, "layer_norm_eps": 1e-5,
+             "reduced": ["num_hidden_layers"], "rehearsal": {"hidden_size": 64, "num_attention_heads": 8, "head_dim": 32}}
+    assert model["head_dim"] != model["hidden_size"] // model["num_attention_heads"] and "rms_norm_eps" not in model
+    held_to_its_family(model)
+    # the family's own assumption broken: it would run heads of 64 where the file publishes 128
+    wide.program_config = lambda model: {**program_config(model), "head_dim": 64}
+    with pytest.raises(AssertionError):
+        held_to_its_family(model)
+    wide.program_config = program_config
+    with pytest.raises(AssertionError):  # a cut the family does not hold the program to
+        held_to_its_family({**model, "reduced": ["vocab_size"]})
+
+
+@pytest.mark.parametrize("name", DENSE)
+def test_the_dense_configurations_are_held_to_what_they_were(name):
+    """By name, every assertion these two files were held to before a file
+    was asked for its family: the ten fields, heads of 128, bf16, and a
+    rehearsal whose heads divide."""
     from sentio_tpu.models.llama import LlamaConfig
 
     model = CONFIGS[name]
+    assert model["family"] == "llama" and load_family(model) is family
     cfg = LlamaConfig(**family.program_config(model))
     assert cfg.dim == model["hidden_size"] and cfg.mlp_dim == model["intermediate_size"]
     assert cfg.n_heads == model["num_attention_heads"] and cfg.n_kv_heads == model["num_key_value_heads"]

@@ -4,13 +4,12 @@ prefill and of decode through the pages, and answers served through the
 engine's own admission and step programs."""
 
 import json
-from pathlib import Path
 
 import pytest
+from bench_tree import REPO
 
 from benchmark.check import run_check
 
-REPO = Path(__file__).resolve().parents[2]
 CONFIGS = sorted((REPO / "benchmark" / "configs").glob("*.json"))
 # every configuration goes through its OWN family's pieces and reference; the
 # tampered terms below are the dense reference's

@@ -2,13 +2,13 @@
 chip run), against the numbers written beside it."""
 
 import json
-from pathlib import Path
 
 import pytest
+from bench_tree import REPO
 
 from benchmark import trace
 
-FIXTURES = Path(__file__).resolve().parents[2] / "benchmark" / "fixtures"
+FIXTURES = REPO / "benchmark" / "fixtures"
 SLICE = json.loads((FIXTURES / "v5e_rag_open_slice.json").read_text())
 EXPECTED = json.loads((FIXTURES / "v5e_rag_open_slice.expected.json").read_text())
 # the mistral configuration's trace block as ``kernel_block`` reads it
