@@ -315,6 +315,22 @@ class MetricsCollector:
                 "K/V page blocks of the decode sub-steps, held by rows vs tabled",
                 ["kind"], registry=r,
             ),
+            # a routed family's expert layers, counted on the device inside
+            # the decode tick and the prefill programs (models/moe.py):
+            # pairs of token and pick, routed over all experts vs held by
+            # this process's share of them ...
+            "moe_pairs": Counter(
+                "sentio_tpu_moe_pairs_total",
+                "token-expert pairs the router made, and those whose expert is held here",
+                ["kind"], registry=r,
+            ),
+            # ... and of the held experts x layers x decode sub-steps, those
+            # at least one pair touched (a decode step reads only these)
+            "moe_expert_steps": Counter(
+                "sentio_tpu_moe_expert_steps_total",
+                "held experts x layers x decode sub-steps, and those a pair touched",
+                ["kind"], registry=r,
+            ),
             # process-mode replica tier (runtime/worker.py): worker
             # process deaths observed by the router-side shim (SIGKILL,
             # OOM-kill, crash, broken RPC pipe). A steadily increasing
@@ -555,15 +571,26 @@ class MetricsCollector:
         if hist is not None:
             hist.labels(stage=stage).observe(float(seconds))
 
-    def record_row_steps(self, counts: dict, kv_pages: Optional[dict] = None) -> None:
-        """One harvested tick's row-steps by kind (useful / halted / empty)
-        and the K/V page blocks of its sub-steps (held / tabled)."""
+    def record_row_steps(self, counts: dict, kv_pages: Optional[dict] = None,
+                         moe: Optional[dict] = None) -> None:
+        """One harvested tick's row-steps by kind (useful / halted / empty),
+        the K/V page blocks of its sub-steps (held / tabled) and, of a routed
+        family, its expert layers' pairs (routed / held) and expert-steps
+        (held / touched) — ``MOE_KINDS`` as ``<series>_<kind>``."""
         if not self.enabled:
             return
         from sentio_tpu.infra.phases import KV_PAGE_KINDS, ROW_STEP_KINDS
 
-        for name, kinds, tick in (("row_steps", ROW_STEP_KINDS, counts),
-                                  ("kv_pages", KV_PAGE_KINDS, kv_pages or {})):
+        moe = moe or {}
+        for name, kinds, tick in (
+                ("row_steps", ROW_STEP_KINDS, counts),
+                ("kv_pages", KV_PAGE_KINDS, kv_pages or {}),
+                ("moe_pairs", ("routed", "held"),
+                 {k: moe.get(f"pairs_{k}", 0) for k in ("routed", "held")}),
+                ("moe_expert_steps", ("held", "touched"),
+                 {k: moe.get(f"experts_{k}", 0) for k in ("held", "touched")})):
+            if name.startswith("moe") and not any(tick.values()):
+                continue  # no series where no routed family is served
             counter = self._prom.get(name)
             for kind in kinds:
                 n = int(tick.get(kind, 0))

@@ -101,6 +101,7 @@ __all__ = [
     "REQUEST_STAGES",
     "ROW_STEP_KINDS",
     "KV_PAGE_KINDS",
+    "MOE_KINDS",
     "TTFT_STAGES",
     "tile_ttft",
     "TICK_PHASES",
@@ -168,6 +169,14 @@ ROW_STEP_KINDS = ("useful", "halted", "empty")
 # one for a row that holds no request or does not advance — and `tabled`
 # every cell of every page table, what a walk of the table would touch
 KV_PAGE_KINDS = ("held", "tabled")
+
+# a routed family's expert layers (models/moe.py::expert_layer counts them on
+# the device; runtime/paged.py books them when a tick is harvested): pairs of
+# token and pick routed over ALL experts (`pairs_routed`) and those whose
+# expert this process holds (`pairs_held`) — decode sub-steps and prefill
+# programs alike; and of the held experts x layers x decode sub-steps
+# (`experts_held`) those at least one pair touched (`experts_touched`)
+MOE_KINDS = ("pairs_routed", "pairs_held", "experts_held", "experts_touched")
 
 
 def tile_ttft(stage_s: dict, ttft_s: float) -> dict:

@@ -72,7 +72,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = float(np.finfo(np.float32).min)
 
 __all__ = ["paged_attention", "paged_attention_quant", "make_paged_attn_impl",
-           "blocks_walked", "pages_in_flight", "untiled"]
+           "blocks_walked", "first_block", "pages_in_flight", "untiled"]
 
 
 # VMEM the call's scratch may take with no limit of its own: under the 16 MiB
@@ -86,6 +86,9 @@ _PAGE_BUFFER_BYTES = 10 * 1024 * 1024
 _PAGES_IN_FLIGHT = 3
 # a position no row reaches: marks a score against another kv head's key
 _NEVER = 1 << 30
+# a window is a constant of the model's CONFIGURATION (``sliding_window``, or
+# None for a layer that sees all): static in the kernel, one variant a value
+ConfigWindow = int | None
 
 
 def _packs(dtype) -> int:
@@ -120,13 +123,22 @@ def pages_in_flight(pools) -> int:
                           _PAGE_BUFFER_BYTES // _page_bytes(pools))))
 
 
-def blocks_walked(lens, page: int, nb: int):
+def first_block(lens, page: int, window: int | None):
+    """The first block the walk reads for rows at ``lens``: block 0, or with
+    a ``window`` (the keys a query sees behind itself, itself included) the
+    block that holds position ``lens - window + 1``, the oldest it sees."""
+    if window is None:
+        return lens * 0
+    return (lens - (window - 1)).clip(0) // page
+
+
+def blocks_walked(lens, page: int, nb: int, window: int | None = None):
     """Blocks the walk copies and computes for rows at ``lens`` (a numpy or
     jax array): the new token sits at index ``lens``, so ``lens // page +
-    1``, never past the table. A free slot (``lens`` 0) costs its one block
-    of the scratch page. The host's counter (``runtime/paged.py``) counts
-    by this same rule."""
-    return (lens // page).clip(0, nb - 1) + 1
+    1``, never past the table, less the blocks wholly behind a ``window``. A
+    free slot (``lens`` 0) costs its one block of the scratch page. The
+    host's counter (``runtime/paged.py``) counts by this same rule."""
+    return (lens // page).clip(0, nb - 1) + 1 - first_block(lens, page, window).clip(0, nb - 1)
 
 
 def _head_major(hkv: int, dtype) -> bool:
@@ -177,8 +189,10 @@ def _walk_kernel(
     head_major: bool,
     depth: int,
     sm_scale: float,
+    window: int | None,
 ):
-    """Row ``b`` of the walk: one loop step a block the row HOLDS.
+    """Row ``b`` of the walk: one loop step a block the row HOLDS (with a
+    ``window``: a block that holds a key the row's query still sees).
 
     The pools stay in HBM; every page is brought by the kernel's own DMA
     into a ring of ``depth`` VMEM buffers. The ring runs ACROSS rows: the
@@ -211,8 +225,13 @@ def _walk_kernel(
     h = q_ref.shape[0]
     rep = h // hkv
 
-    def held(row):
-        return blocks_walked(lens_ref[row], page, nb)
+    def first(row):  # the first block of a row's walk, and one past its last
+        if window is None:
+            return 0
+        return jnp.minimum(first_block(lens_ref[row], page, window), nb - 1)
+
+    def end(row):
+        return jnp.clip(lens_ref[row] // page, 0, nb - 1) + 1
 
     def copies(row, j, slot):
         pid = pt_ref[row, j]
@@ -226,9 +245,10 @@ def _walk_kernel(
         def _():
             for copy in copies(row, j, issued % depth):
                 copy.start()
-            last = j + 1 >= held(row)
+            last = j + 1 >= end(row)
             walk[0] = jnp.where(last, row + 1, row)
-            walk[1] = jnp.where(last, 0, j + 1)
+            # the next row's first block; past the last row nothing is fetched
+            walk[1] = jnp.where(last, first(jnp.minimum(row + 1, rows - 1)), j + 1)
             walk[2] = issued + 1
 
     def column(shape):
@@ -240,6 +260,8 @@ def _walk_kernel(
     def _first_row():
         for n in range(4):
             walk[n] = 0
+        if window is not None:
+            walk[1] = first(0)
         jax.lax.fori_loop(0, depth - 1, lambda _, c: (fetch_next(), c)[1], 0)
         col_head, col_pos = column(pos_ref.shape)
         own = jax.lax.broadcasted_iota(jnp.int32, pos_ref.shape, 0) // rep
@@ -283,7 +305,10 @@ def _walk_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         if quant:
             s = s * over_columns(ks_buf[slot])
-        s = jnp.where(pos_ref[:] <= cur - j * page, s * sm_scale, NEG_INF)
+        seen = pos_ref[:] <= cur - j * page
+        if window is not None:  # _NEVER stays unseen: it passes only this half
+            seen &= pos_ref[:] > cur - window - j * page
+        s = jnp.where(seen, s * sm_scale, NEG_INF)
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -299,11 +324,11 @@ def _walk_kernel(
         walk[3] = walk[3] + 1
         return carry
 
-    jax.lax.fori_loop(0, held(b), page_step, 0)
+    jax.lax.fori_loop(first(b), end(b), page_step, 0)
     o_ref[:] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
 
 
-def _walk_pool(q, pools, layer, page_table, lens, interpret):
+def _walk_pool(q, pools, layer, page_table, lens, interpret, window=None):
     """The walk both representations share: grid ``(B,)``, ``pools`` whole
     in HBM — K and V pages ``[L, P, page, Hkv, D]`` and, quantized (four
     pools: K pages, K scales, V pages, V scales), their scales ``[L, P, Hkv,
@@ -350,7 +375,7 @@ def _walk_pool(q, pools, layer, page_table, lens, interpret):
     return pl.pallas_call(
         functools.partial(
             _walk_kernel, quant=quant, page=page, hkv=hkv, head_major=head_major,
-            depth=depth, sm_scale=1.0 / float(np.sqrt(d))),
+            depth=depth, sm_scale=1.0 / float(np.sqrt(d)), window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -365,7 +390,7 @@ def _walk_pool(q, pools, layer, page_table, lens, interpret):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def paged_attention(
     q: jax.Array,           # [B, H, D] — one decode token per row
     k_pages: jax.Array,     # [L, P, page, Hkv, D] — the page pool, all layers
@@ -375,12 +400,15 @@ def paged_attention(
     lens: jax.Array,        # [B] int32 — index of the current token
     *,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
-    """Decode attention over one layer of the paged pool → [B, H, D]."""
-    return _walk_pool(q, (k_pages, v_pages), layer, page_table, lens, interpret)
+    """Decode attention over one layer of the paged pool → [B, H, D]. With a
+    ``window`` a row's query sees keys ``lens - window < j <= lens`` and the
+    walk starts at the block that holds the oldest of them."""
+    return _walk_pool(q, (k_pages, v_pages), layer, page_table, lens, interpret, window)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def paged_attention_quant(
     q: jax.Array,           # [B, H, D] — one decode token per row
     k_pages_q: jax.Array,   # [L, P, page, Hkv, D] int8 — the page pool, all layers
@@ -392,13 +420,14 @@ def paged_attention_quant(
     lens: jax.Array,        # [B] int32 — index of the current token
     *,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
     """Decode attention over one layer of the int8-quantized paged pool →
     [B, H, D]. The same walk as :func:`paged_attention`; a page's int8
     payload and its scale page are copied together and dequantized in VMEM.
     """
     return _walk_pool(q, (k_pages_q, k_scales, v_pages_q, v_scales),
-                      layer, page_table, lens, interpret)
+                      layer, page_table, lens, interpret, window)
 
 
 def make_paged_attn_impl(interpret: bool | None = None, mesh=None):
@@ -419,17 +448,17 @@ def make_paged_attn_impl(interpret: bool | None = None, mesh=None):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
-    def impl(q, k_pages, v_pages, layer, page_table, lens, n_rep):
+    def impl(q, k_pages, v_pages, layer, page_table, lens, n_rep, window: ConfigWindow = None):
         if isinstance(k_pages, dict):
             out = paged_attention_quant(
                 q[:, 0], k_pages["q"], k_pages["s"],
                 v_pages["q"], v_pages["s"],
-                layer, page_table, lens, interpret=interpret,
+                layer, page_table, lens, interpret=interpret, window=window,
             )
         else:
             out = paged_attention(
                 q[:, 0], k_pages, v_pages, layer, page_table, lens,
-                interpret=interpret,
+                interpret=interpret, window=window,
             )
         return out[:, None]
 
@@ -447,9 +476,10 @@ def make_paged_attn_impl(interpret: bool | None = None, mesh=None):
     def pool_spec(pool):
         return {"q": pages, "s": scales} if isinstance(pool, dict) else pages
 
-    def sharded_impl(q, k_pages, v_pages, layer, page_table, lens, n_rep):
+    def sharded_impl(q, k_pages, v_pages, layer, page_table, lens, n_rep,
+                     window: ConfigWindow = None):
         return jax.shard_map(
-            functools.partial(impl, n_rep=n_rep), mesh=mesh,
+            functools.partial(impl, n_rep=n_rep, window=window), mesh=mesh,
             in_specs=(heads, pool_spec(k_pages), pool_spec(v_pages),
                       P(), P(), P()),
             out_specs=heads, check_vma=False,

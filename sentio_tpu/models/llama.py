@@ -54,6 +54,11 @@ class LlamaConfig:
     def jdtype(self) -> jnp.dtype:
         return jnp.dtype(self.dtype)
 
+    def window(self, layer: int) -> Optional[int]:
+        """Keys a query of ``layer`` sees behind itself, itself included;
+        None: all of them (every layer of this family)."""
+        return None
+
     @classmethod
     def llama3_8b(cls) -> "LlamaConfig":
         return cls()
@@ -69,7 +74,8 @@ class LlamaConfig:
 
 def init_llama(rng: Array, cfg: LlamaConfig) -> dict:
     keys = iter(jax.random.split(rng, 2 + cfg.n_layers * 7))
-    kv_dim = cfg.n_kv_heads * cfg.head_dim
+    # attention is ``n_heads * head_dim`` wide, which a family may set apart from ``dim``
+    q_dim, kv_dim = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
     params: dict = {
         "embed_tokens": L.embed_init(next(keys), cfg.vocab_size, cfg.dim),
         "lm_head": L.dense_init(next(keys), cfg.dim, cfg.vocab_size, with_bias=False),
@@ -79,10 +85,10 @@ def init_llama(rng: Array, cfg: LlamaConfig) -> dict:
         params[f"layers_{i}"] = {
             "attn_norm": L.rmsnorm_init(cfg.dim),
             "attn": {
-                "wq": L.dense_init(next(keys), cfg.dim, cfg.dim, with_bias=False),
+                "wq": L.dense_init(next(keys), cfg.dim, q_dim, with_bias=False),
                 "wk": L.dense_init(next(keys), cfg.dim, kv_dim, with_bias=False),
                 "wv": L.dense_init(next(keys), cfg.dim, kv_dim, with_bias=False),
-                "wo": L.dense_init(next(keys), cfg.dim, cfg.dim, with_bias=False),
+                "wo": L.dense_init(next(keys), q_dim, cfg.dim, with_bias=False),
             },
             "mlp_norm": L.rmsnorm_init(cfg.dim),
             "mlp": {
@@ -225,9 +231,9 @@ def _attn(
     k_full = L.repeat_kv(k_full, h // hkv)
     v_full = L.repeat_kv(v_full, h // hkv)
     if use_kernel:
-        out = attn_fn(q, k_full, v_full, kv_lens).reshape(b, t, d)
+        out = attn_fn(q, k_full, v_full, kv_lens).reshape(b, t, h * hd)
     else:
-        out = L.attention(q, k_full, v_full, mask, dt).reshape(b, t, d)
+        out = L.attention(q, k_full, v_full, mask, dt).reshape(b, t, h * hd)
     return L.dense(lp["wo"], out, dt), cache
 
 

@@ -185,6 +185,139 @@ def moe_mlp(
     return out.reshape(b, t, d), aux
 
 
+# --------------------------------------------------- the share of a layer
+
+
+def routed_scores(logits: Array, gate_fn: str) -> Array:
+    """Router logits [G, E] (float32) → the scores a token ranks ALL experts
+    by: ``sigmoid`` of each logit or a ``softmax`` over them."""
+    if gate_fn == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    if gate_fn == "softmax":
+        return jax.nn.softmax(logits, axis=-1)
+    raise ValueError(f"gate_fn must be 'sigmoid' or 'softmax', got {gate_fn!r}")
+
+
+# The grouped matmul's tiles. ROWS (a pair of token and pick is a row): the
+# kernel computes a whole row tile for every expert that has a row in it, so
+# the tile follows the pairs an expert sees under even routing — a decode
+# step's two or three want 32 rows, not 256 (at 256 the MXU work of a step,
+# 1 GFLOP a weight tile, takes as long as streaming the tile) — between
+# ``_GMM_ROWS_MIN`` and ``_GMM_ROWS_MAX``. K and N of one ``[K, N]`` expert
+# matrix: the weights stream, so the tile is as large as the 16 MiB a kernel
+# is given unasked allows (PERF.md section 5 has the timings).
+_GMM_ROWS_MIN, _GMM_ROWS_MAX = 32, 256
+_GMM_TILE = (4096, 512)
+
+
+def row_tile(pairs: int, n_experts: int) -> int:
+    """Rows a tile for ``pairs`` rows routed evenly over ``n_experts``: the
+    next power of two over an expert's share, inside the two bounds."""
+    share = max(-(-pairs // n_experts), 1)
+    return min(max(1 << (share - 1).bit_length(), _GMM_ROWS_MIN), _GMM_ROWS_MAX)
+
+
+def expert_matmul(lhs: Array, rhs: Array, sizes: Array, rows: int = _GMM_ROWS_MAX,
+                  interpret: bool = False) -> Array:
+    """Grouped matmul ``lhs [M, K]`` (rows sorted by expert, ``sizes [E]``
+    of them each, the rest belonging to none; ``M`` a multiple of the row
+    tile ``rows``) x ``rhs [E, K, N]`` → ``[M, N]`` in ``lhs``'s dtype,
+    summed in float32: the megablox kernel
+    (``jax.experimental.pallas.ops.tpu.megablox``), which visits the row
+    tiles of the experts that HAVE rows and no other — an expert nothing was
+    routed to is not read, and a row past the last group is not computed (it
+    comes back as whatever the buffer held: the caller masks it). In a device
+    trace each call is one ``gmm`` custom call (the kernel's own jit name)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    tile = (rows, min(_GMM_TILE[0], lhs.shape[1]), min(_GMM_TILE[1], rhs.shape[2]))
+    return gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype, tiling=tile,
+               interpret=interpret)
+
+
+def grouped_matmul(lhs: Array, rhs: Array, sizes: Array, rows: int = _GMM_ROWS_MAX) -> Array:
+    """:func:`expert_matmul` on a TPU; ``jax.lax.ragged_dot`` (the same
+    products, XLA's own lowering) elsewhere — selected by backend, like the
+    attention kernels."""
+    if jax.default_backend() == "tpu":
+        return expert_matmul(lhs, rhs, sizes, rows)
+    return jax.lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=jnp.float32).astype(lhs.dtype)
+
+
+def expert_layer(
+    mp: dict, cfg, x: Array, valid: Optional[Array] = None
+) -> tuple[Array, Array, Array]:
+    """The routed-expert layer as ONE chip of a deployment runs it:
+    x [B, T, D] → (out [B, T, D], picks [B, T, k] int32, counts [4] int32).
+
+    Every token is routed over ALL ``cfg.n_experts`` experts (the router is
+    as wide as published): ``cfg.gate_fn`` scores, the ``experts_per_token``
+    largest picked, their scores renormalised over the picks. Of ``Σ w_e
+    F_e(x)`` this computes the part whose expert is HELD here —
+    ``mp["w_gate"]``, ``w_up`` ``[experts_held, D, F]`` and ``w_down``
+    ``[experts_held, F, D]`` are experts ``expert_offset ..`` — and leaves
+    the others' part out: what seven absent chips would add is no part of
+    this program, nor is their traffic. With every expert held that IS the
+    layer. ``mp["shared"]`` (stacks of ``n_shared_experts`` experts every
+    chip holds) adds their AVERAGE, once.
+
+    Nothing is dropped at any load: the pairs of token and pick are sorted
+    by expert and go through three grouped matmuls whose cost is the pairs
+    routed here — no capacity, no ``[tokens, experts, capacity]`` tensor. A
+    decode step reads each expert its rows picked once, and the others not
+    at all. ``valid [B, T]`` keeps pad positions and rows that do not
+    advance out of the experts (their picks are still reported).
+
+    ``counts``: pairs routed (valid tokens x picks), pairs held here, held
+    experts, held experts that at least one pair touched — the engine's
+    ``sentio_tpu_moe_*`` counters, summed on the device.
+    """
+    dt = cfg.jdtype
+    b, t, d = x.shape
+    g, k, held = b * t, cfg.experts_per_token, cfg.experts_held
+    flat = x.reshape(g, d).astype(dt)
+    ok = jnp.ones((g,), bool) if valid is None else valid.reshape(g)
+
+    with jax.named_scope("moe.route"):
+        logits = L.dense(mp["router"], flat, jnp.float32)           # [G, E] f32
+        top, picks = jax.lax.top_k(routed_scores(logits, cfg.gate_fn), k)
+        gates = top / top.sum(-1, keepdims=True)                    # over the picks
+        local = picks - cfg.expert_offset
+        here = (local >= 0) & (local < held) & ok[:, None]          # [G, k]
+        # a pair's group: its expert's index here, or ``held`` for none
+        group = jnp.where(here, local, held).reshape(g * k)
+        tile = row_tile(g * k, cfg.n_experts)
+        rows = -(-g * k // tile) * tile                              # whole row tiles
+        group = jnp.pad(group, (0, rows - g * k), constant_values=held)
+        order = jnp.argsort(group, stable=True)                     # held pairs first, by expert
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+        n_here = sizes.sum()
+
+    with jax.named_scope("moe.experts"):
+        token = jnp.minimum(order // k, g - 1)                      # a pad pair reads the last token
+        xs = flat[token]                                            # [rows, D]
+        hidden = jax.nn.silu(grouped_matmul(xs, mp["w_gate"].astype(dt), sizes, tile)) \
+            * grouped_matmul(xs, mp["w_up"].astype(dt), sizes, tile)
+        ys = grouped_matmul(hidden, mp["w_down"].astype(dt), sizes, tile)  # [rows, D]
+        ys = jnp.where((jnp.arange(rows) < n_here)[:, None], ys, 0)  # past the groups: not computed
+        back = jnp.argsort(order)[: g * k]                           # pair → its sorted row
+        weight = jnp.where(here, gates, 0.0).astype(jnp.float32)
+        out = (ys[back].reshape(g, k, d).astype(jnp.float32) * weight[..., None]).sum(1)
+
+    if "shared" in mp:
+        with jax.named_scope("moe.shared"):
+            sp = mp["shared"]
+            up = jnp.einsum("gd,sdf->gsf", flat, sp["w_up"].astype(dt))
+            gate = jax.nn.silu(jnp.einsum("gd,sdf->gsf", flat, sp["w_gate"].astype(dt)))
+            shared = jnp.einsum("gsf,sfd->gd", gate * up, sp["w_down"].astype(dt),
+                                preferred_element_type=jnp.float32)
+            out = out + shared / cfg.n_shared_experts
+
+    counts = jnp.stack([ok.sum() * k, n_here, jnp.asarray(held, jnp.int32),
+                        (sizes > 0).sum()]).astype(jnp.int32)
+    return out.astype(dt).reshape(b, t, d), picks.reshape(b, t, k).astype(jnp.int32), counts
+
+
 def moe_forward(
     params: dict,
     cfg: MoeConfig,

@@ -50,7 +50,7 @@ from __future__ import annotations
 import itertools
 import logging
 import time
-from collections import deque
+from collections import Counter, deque
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -60,7 +60,7 @@ from sentio_tpu.analysis.audit.registry import jit_family
 from sentio_tpu.analysis.sanitizer import check_engine_invariants, engine_guard
 from sentio_tpu.infra import faults
 from sentio_tpu.infra.phases import (
-    ENGINE_PHASES, KV_PAGE_KINDS, ROW_STEP_KINDS, PhaseTimer,
+    ENGINE_PHASES, KV_PAGE_KINDS, MOE_KINDS, ROW_STEP_KINDS, PhaseTimer,
 )
 from sentio_tpu.infra.tracing import annotation
 from sentio_tpu.models.llama import LlamaConfig, qkv_proj, serving_layout
@@ -242,8 +242,9 @@ class PageAllocator:
 # ------------------------------------------------------------ device kernels
 
 
-def _paged_attn_xla(q, k_pages, v_pages, layer, page_table, lens, n_rep):
-    """Decode attention over a page table, XLA gather path.
+def _paged_attn_xla(q, k_pages, v_pages, layer, page_table, lens, n_rep, window=None):
+    """Decode attention over a page table, XLA gather path (``window``: the
+    keys a query sees behind itself, itself included; None = all).
 
     q [B,1,H,D]; k/v_pages [L,P,page,Hkv,D] (the whole pool, either repr);
     layer int; page_table [B,NB]; lens [B]. Gathers each row's pages of that
@@ -258,16 +259,17 @@ def _paged_attn_xla(q, k_pages, v_pages, layer, page_table, lens, n_rep):
 
     kc = _gather_pages(k_pages, layer, page_table, q.dtype)
     vc = _gather_pages(v_pages, layer, page_table, q.dtype)
-    window = kc.shape[1]
     kc = L.repeat_kv(kc, n_rep)
     vc = L.repeat_kv(vc, n_rep)
-    kj = jnp.arange(window)[None, None, None, :]
+    kj = jnp.arange(kc.shape[1])[None, None, None, :]
     mask = kj <= lens[:, None, None, None]  # new token sits at index lens
+    if window is not None:
+        mask &= kj > (lens - window)[:, None, None, None]
     return L.attention(q, kc, vc, mask, q.dtype)
 
 
 def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_pages, v_pages,
-                         attn_impl=None, write_mask=None):
+                         attn_impl=None, write_mask=None, return_routed=False):
     """One decode step over the paged pool.
 
     tok [B] int32 (last sampled token per slot); lens [B] absolute position
@@ -277,11 +279,22 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
     ``write_mask`` [B] bool (optional) redirects masked rows' k/v writes to
     the scratch page — the multi-step tick uses it to freeze rows that hit
     EOS or their budget mid-scan without corrupting their cache.
+
+    A family whose block is PARALLEL (``models/cohere2_moe.py``: one norm
+    feeding attention and routed experts side by side, a kind per layer)
+    takes its own walk of the layers, below; ``return_routed`` then adds
+    what its expert layers decided (``{"experts": [L, B, k] picks,
+    "counts": [4]}``) as a fourth result, None for every other family.
     """
     import jax
     import jax.numpy as jnp
 
     from sentio_tpu.models import layers as L
+
+    if getattr(cfg, "parallel_block", False):
+        out = _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
+                                     attn_impl, write_mask)
+        return out if return_routed else out[:3]
 
     dt = cfg.jdtype
     b = tok.shape[0]
@@ -318,7 +331,7 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
         # pages[i] handed to a kernel is a copy of the layer's every page
         impl = attn_impl or _paged_attn_xla
         out = impl(q, k_pages, v_pages, i, page_table, attn_lens, h // hkv)
-        x = x + L.dense(lp["attn"]["wo"], out.reshape(b, 1, cfg.dim), dt)
+        x = x + L.dense(lp["attn"]["wo"], out.reshape(b, 1, h * hd), dt)
 
         xm = L.rmsnorm(lp["mlp_norm"], x, cfg.norm_eps)
         if "moe" in lp:
@@ -337,7 +350,59 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
 
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = L.dense(params["lm_head"], x, dt)[:, 0]
-    return logits.astype(jnp.float32), k_pages, v_pages
+    out = logits.astype(jnp.float32), k_pages, v_pages
+    return (*out, None) if return_routed else out
+
+
+def _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
+                           attn_impl, write_mask):
+    """:func:`paged_decode_forward` for the parallel block of
+    ``models/cohere2_moe.py``: ``h = LN(x); x += Attn_i(h) + Experts(h)``,
+    layer ``i`` rotated and windowed or neither by its kind, the head the
+    embedding. → (logits [B, V], k_pages, v_pages, routed). A window
+    reaches the attention as ``window=``: the Pallas walk then starts at the
+    window's first block, the gather path masks."""
+    import jax
+    import jax.numpy as jnp
+
+    from sentio_tpu.models import layers as L
+    from sentio_tpu.models.cohere2_moe import centred_norm, head_logits, qk_rotated
+    from sentio_tpu.models.moe import expert_layer
+
+    dt = cfg.jdtype
+    b = tok.shape[0]
+    page = _page_dim(k_pages)
+    positions = lens[:, None]
+    page_ids = jnp.take_along_axis(page_table, (lens // page)[:, None], axis=1)[:, 0]
+    offsets = lens % page
+    attn_lens, valid = lens, None
+    if write_mask is not None:  # as in the sequential block, above
+        page_ids = jnp.where(write_mask, page_ids, 0)
+        offsets = jnp.where(write_mask, offsets, 0)
+        attn_lens = jnp.where(write_mask, lens, 0)
+        valid = write_mask[:, None]
+    impl = attn_impl or _paged_attn_xla
+
+    x = L.embed(params["embed_tokens"], tok[:, None], dt)
+    picks, counts = [], jnp.zeros((4,), jnp.int32)
+    for i in range(cfg.n_layers):
+        lp = params[f"layers_{i}"]
+        h = centred_norm(lp["norm"], x, cfg.norm_eps)
+        q, k, v = qkv_proj(lp["attn"], cfg, h)
+        q, k = qk_rotated(cfg, i, q, k, positions)
+        k_pages = _page_write(k_pages, i, page_ids, offsets, k[:, 0].astype(dt))
+        v_pages = _page_write(v_pages, i, page_ids, offsets, v[:, 0].astype(dt))
+        with jax.named_scope("attn.window" if cfg.window(i) else "attn.full"):
+            attn = impl(q, k_pages, v_pages, i, page_table, attn_lens,
+                        cfg.n_heads // cfg.n_kv_heads, window=cfg.window(i))
+        # a row that does not advance is routed nowhere: it would touch
+        # experts (bytes) for a token nobody reads
+        routed, chosen, n = expert_layer(lp["moe"], cfg, h, valid)
+        x = x + L.dense(lp["attn"]["wo"], attn.reshape(b, 1, -1), dt) + routed
+        picks.append(chosen[:, 0])
+        counts = counts + n
+    logits = head_logits(params, cfg, x)[:, 0]
+    return logits, k_pages, v_pages, {"experts": jnp.stack(picks), "counts": counts}
 
 
 def scatter_prefill(k_pages, v_pages, k_cache, v_cache, page_table):
@@ -414,6 +479,12 @@ class _Slot:
     # start of prefill) and the prefill dispatches this request took so far
     admit_t: float = 0.0
     prefill_segments: int = 0
+    # ``keep_choices``: the picks this request's tokens were routed by, one
+    # [L, window, k] buffer a kind of choice (-1: not by this request), and
+    # the prefill dispatches' picks still on the device (array, row, first
+    # position, tokens), fetched when the request retires
+    choices: Optional[dict] = None
+    choice_parts: list = field(default_factory=list)
 
 
 @dataclass
@@ -479,6 +550,11 @@ class PagedResult:
     # prompt took — what the service turns into slot_wait and prefill
     admit_t: float = 0.0
     prefill_segments: int = 0
+    # only from ``run_all(return_choices=True)`` on a family that chooses:
+    # ``{"experts": int32 [layers, prompt + answer tokens - 1, k]}``, what
+    # each of THIS request's tokens was routed by at every layer — negative
+    # where the radix cache served the position (an earlier request's routing)
+    choices: Optional[dict] = None
 
     @property
     def logprob_mean(self) -> Optional[float]:
@@ -548,6 +624,7 @@ class ContinuousBatchingEngine:
         ``moe`` subtree routes through models/moe.py)."""
         import jax
 
+        from sentio_tpu.models.cohere2_moe import Cohere2MoeConfig, cohere2_forward
         from sentio_tpu.models.llama import llama_forward
         from sentio_tpu.models.moe import MoeConfig, moe_serving_forward
 
@@ -583,6 +660,15 @@ class ContinuousBatchingEngine:
             # mesh the caller has placed the tree by its sharding rules.
             params = jax.device_put(params)
         self.params = params
+        # a family whose expert layers hand back what they decided (their
+        # picks, and the pairs they routed: ``models/moe.py::expert_layer``)
+        self.routed = isinstance(self.cfg, Cohere2MoeConfig)
+        if self.routed:
+            if forward_fn not in (None, cohere2_forward):
+                raise ValueError("a Cohere2MoeConfig model prefills through cohere2_forward")
+            if draft_params is not None:
+                raise ValueError("paged speculation does not serve a routed family yet")
+            forward_fn = cohere2_forward
         if forward_fn is None:
             forward_fn = moe_serving_forward if is_moe else llama_forward
         elif forward_fn in (moe_serving_forward, llama_forward):
@@ -713,6 +799,17 @@ class ContinuousBatchingEngine:
         # table that is work
         self.kv_pages_total = dict.fromkeys(KV_PAGE_KINDS, 0)
         self.last_tick_kv_pages = dict.fromkeys(KV_PAGE_KINDS, 0)
+        # a routed family's expert layers, summed ON THE DEVICE inside the
+        # decode tick and the prefill programs and fetched as four more rows
+        # of the tick's packed tokens (no fetch of their own): pairs of token
+        # and pick ``routed`` over all experts and ``held`` here, and of the
+        # ``held`` experts x layers x decode sub-steps those a pair ``touched``
+        self.moe_total = dict.fromkeys(MOE_KINDS, 0)
+        self.last_tick_moe = dict.fromkeys(MOE_KINDS, 0)
+        self._moe_acc = None  # the prefill programs' pairs since the last tick, on the device
+        # ``run_all(return_choices=True)``: each result carries the picks its
+        # OWN tokens were routed by (fetched when asked for, never otherwise)
+        self.keep_choices = False
         self._queue: list[_Request] = []  # guarded-by: engine-thread
         # skip-ahead admission: a request too large for the current free
         # pages may be jumped by later, smaller requests — but only
@@ -835,12 +932,13 @@ class ContinuousBatchingEngine:
         eos_id = self.tokenizer.eos_id
 
         ignore_eos = self.ignore_eos
+        routed = self.routed
 
         @jit_family("paged.step_n", static_argnames=("steps",),
                     donate_argnums=(5, 6))
         def step_n(params, tok, lens, halted, page_table, k_pages, v_pages,
                    rng, temps, top_ks, budgets, lp_sum, lp_min, lp_cnt,
-                   steps):
+                   steps, moe_acc=None):
             """``steps`` decode sub-steps fused into one dispatch (lax.scan).
 
             Per-row ``budgets`` bound how far each row may advance (token
@@ -861,16 +959,23 @@ class ContinuousBatchingEngine:
             traced state — the confidence gate's raw signal, accumulated
             with zero extra dispatches. Admission seeds them with the first
             token's logprob via ``merge_admitted``.
+
+            A routed family (``moe_acc`` [4] int32: the pairs its prefill
+            programs routed since the last tick) carries its expert layers'
+            counts through the scan, returns them as four more rows of
+            ``packed`` and adds one result: every sub-step's picks
+            ``{"experts": [steps, L, B, k]}``, which stay on the device
+            unless a caller asked for them.
             """
             from sentio_tpu.runtime.sampling import sample_tokens
 
             def body(carry, idx):
                 (tok, lens, k_pages, v_pages, rng, halted,
-                 lp_sum, lp_min, lp_cnt) = carry
+                 lp_sum, lp_min, lp_cnt, *moe_n) = carry
                 active = (~halted) & (idx < budgets)
-                logits, k_pages, v_pages = paged_decode_forward(
+                logits, k_pages, v_pages, *moe = paged_decode_forward(
                     params, cfg, tok, lens, page_table, k_pages, v_pages,
-                    attn_impl=attn_impl, write_mask=active,
+                    attn_impl=attn_impl, write_mask=active, return_routed=routed,
                 )
                 rng, sub = jax.random.split(rng)
                 # temperature AND top-k sample INSIDE the scan body — the
@@ -884,6 +989,9 @@ class ContinuousBatchingEngine:
                 lp_cnt = jnp.where(active, lp_cnt + 1, lp_cnt)
                 if not ignore_eos:
                     halted = halted | (active & (nxt == eos_id))
+                if routed:
+                    return (tok, lens, k_pages, v_pages, rng, halted, lp_sum, lp_min,
+                            lp_cnt, moe_n[0] + moe[0]["counts"]), (nxt, moe[0]["experts"])
                 return (tok, lens, k_pages, v_pages, rng, halted,
                         lp_sum, lp_min, lp_cnt), nxt
 
@@ -893,21 +1001,29 @@ class ContinuousBatchingEngine:
                 halted = halted | (tok == eos_id)
             init = (tok, lens, k_pages, v_pages, rng, halted,
                     lp_sum, lp_min, lp_cnt)
+            if routed:
+                init = (*init, moe_acc)
             (tok, lens, k_pages, v_pages, rng, halted,
-             lp_sum, lp_min, lp_cnt), toks = jax.lax.scan(
+             lp_sum, lp_min, lp_cnt, *moe_n), toks = jax.lax.scan(
                 body, init, jnp.arange(steps)
             )
+            if routed:
+                toks, picks = toks
             # packed [1 + steps, B]: row 0 echoes the INPUT tokens so freshly
             # admitted rows' device-resident first tokens reach the host in
             # the same single fetch as the tick outputs
             packed = jnp.concatenate([tok_in[None, :], toks], axis=0)
+            if routed:  # [4, B] more: each count across its row
+                packed = jnp.concatenate(
+                    [packed, jnp.broadcast_to(moe_n[0][:, None], (4, packed.shape[1]))], axis=0)
             # one [3, B] fetch (not three): final accumulators, harvested
             # into the host mirrors the retiring PagedResult reads
             lp_state = jnp.stack(
                 [lp_sum, lp_min, lp_cnt.astype(jnp.float32)], axis=0
             )
-            return (packed, lp_state, tok, lens, halted,
-                    lp_sum, lp_min, lp_cnt, k_pages, v_pages, rng)
+            out = (packed, lp_state, tok, lens, halted,
+                   lp_sum, lp_min, lp_cnt, k_pages, v_pages, rng)
+            return (*out, {"experts": picks}) if routed else out
 
         self._step_n = step_n
 
@@ -931,11 +1047,13 @@ class ContinuousBatchingEngine:
 
         @jit_family("paged.prefill_scatter", donate_argnums=(7, 8))
         def prefill_scatter(params, ids, positions, lens, rng, temps, scat,
-                            k_pages, v_pages, top_ks):
+                            k_pages, v_pages, top_ks, moe_acc=None):
             """Batched admission in ONE dispatch: contiguous prefill forward,
             cache scatter into each row's pages, first-token sample (token +
             its logprob, seeding the confidence accumulators) from each
-            row's last prompt logit. Pad rows scatter to scratch page 0."""
+            row's last prompt logit. Pad rows scatter to scratch page 0. A
+            routed family returns one more: ``{"experts": [L, B, width, k]
+            picks, "counts": moe_acc + the pairs this call routed}``."""
             from sentio_tpu.models.llama import init_cache
             from sentio_tpu.runtime.sampling import sample_tokens
 
@@ -944,7 +1062,7 @@ class ContinuousBatchingEngine:
             # pad tails and junk admission rows must not claim routed-expert
             # capacity (llama ignores the mask on the cache path)
             pad_mask = jnp.arange(width)[None, :] < lens[:, None]
-            logits, cache = forward_fn(
+            logits, cache, *moe = forward_fn(
                 params, cfg, ids, positions=positions, cache=cache, cache_index=0,
                 pad_mask=pad_mask,
             )
@@ -954,7 +1072,13 @@ class ContinuousBatchingEngine:
             last = jnp.take_along_axis(logits, (lens - 1)[:, None, None], axis=1)[:, 0]
             rng, sub = jax.random.split(rng)
             first, first_lp = sample_tokens(last, sub, temps, top_k=top_ks)
-            return first, first_lp, k_pages, v_pages, rng
+            out = first, first_lp, k_pages, v_pages, rng
+            return (*out, prefill_routed(moe[0], moe_acc)) if routed else out
+
+        def prefill_routed(moe, moe_acc):
+            # a prefill's pairs are counted; expert-steps are the decode tick's
+            return {"experts": moe["experts"],
+                    "counts": moe_acc + moe["counts"] * jnp.asarray([1, 1, 0, 0], jnp.int32)}
 
         self._prefill_scatter = prefill_scatter
 
@@ -964,7 +1088,7 @@ class ContinuousBatchingEngine:
                     static_argnames=("do_sample",), donate_argnums=(7, 8))
         def prior_prefill_scatter(params, ids, positions, lens, rng, temps,
                                   scat, k_pages, v_pages, prior_table,
-                                  n_prior, top_ks, do_sample):
+                                  n_prior, top_ks, do_sample, moe_acc=None):
             """Prefill a batch of suffixes against per-row prior KV already
             in the pool — ONE compiled family for both radix-cache admission
             (prior = the matched shared-prefix pages) and chunked-prefill
@@ -1007,7 +1131,7 @@ class ContinuousBatchingEngine:
                 cache["v"] = prime(cache["v"], v_pages)
 
             pad_mask = jnp.arange(width)[None, :] < lens[:, None]
-            logits, cache = forward_fn(
+            logits, cache, *moe = forward_fn(
                 params, cfg, ids, positions=positions, cache=cache,
                 cache_index=n_prior, pad_mask=pad_mask,
             )
@@ -1031,7 +1155,8 @@ class ContinuousBatchingEngine:
             else:
                 first = jnp.zeros((b,), jnp.int32)
                 first_lp = jnp.zeros((b,), jnp.float32)
-            return first, first_lp, k_pages, v_pages, rng
+            out = first, first_lp, k_pages, v_pages, rng
+            return (*out, prefill_routed(moe[0], moe_acc)) if routed else out
 
         self._prior_prefill_scatter = prior_prefill_scatter
 
@@ -1157,8 +1282,9 @@ class ContinuousBatchingEngine:
             [(toks[:full], 0.0, 0, [0] * (matched // self.page_size) + pages)],
             width,
         )
-        _first, _first_lp, self.pool.k, self.pool.v, self._rng = \
-            self._prefill_scatter(
+        (_first, _first_lp, self.pool.k, self.pool.v, self._rng), _picks = \
+            self._prefill_call(
+                self._prefill_scatter,
                 self.params, ids, positions, lens, self._rng, temps, scat,
                 self.pool.k, self.pool.v, top_ks,
             )
@@ -1235,6 +1361,7 @@ class ContinuousBatchingEngine:
         self._finished_buffer.clear()
         self._pending_first.clear()
         self._dev_state = None
+        self._moe_acc = None
         if self._inflight is not None:
             # dispatched, never harvested: the device ran these row-steps
             # and nothing of them was delivered
@@ -1318,14 +1445,21 @@ class ContinuousBatchingEngine:
         )
 
     def run_all(
-        self, prompts: Sequence[str], max_new_tokens: int = 64, temperature: float = 0.0
+        self, prompts: Sequence[str], max_new_tokens: int = 64, temperature: float = 0.0,
+        return_choices: bool = False,
     ) -> list[PagedResult]:
-        """Submit-and-drain convenience used by tests and bench."""
-        ids = [self.submit(p, max_new_tokens, temperature) for p in prompts]
-        done: dict[int, PagedResult] = {}
-        while self.has_work:
-            for r in self.step():
-                done[r.request_id] = r
+        """Submit-and-drain convenience used by tests and bench. With
+        ``return_choices`` each result of a routed family carries
+        ``choices``: what its own tokens were routed by (``PagedResult``)."""
+        was, self.keep_choices = self.keep_choices, bool(return_choices) and self.routed
+        try:
+            ids = [self.submit(p, max_new_tokens, temperature) for p in prompts]
+            done: dict[int, PagedResult] = {}
+            while self.has_work:
+                for r in self.step():
+                    done[r.request_id] = r
+        finally:
+            self.keep_choices = was
         return [done[i] for i in ids]
 
     def step(self) -> list[PagedResult]:
@@ -1345,6 +1479,7 @@ class ContinuousBatchingEngine:
         self._phase.reset()
         self.last_tick_row_steps = dict.fromkeys(ROW_STEP_KINDS, 0)
         self.last_tick_kv_pages = dict.fromkeys(KV_PAGE_KINDS, 0)
+        self.last_tick_moe = dict.fromkeys(MOE_KINDS, 0)
         self.last_tick_sub_steps = 0
         # chaos-drill injection point: a raised fault propagates exactly like
         # a real failed device dispatch (the serving pump resets + requeues)
@@ -1651,6 +1786,10 @@ class ContinuousBatchingEngine:
             slot.prefill_segments = 0 if chunked else 1
             slot.prefill_todo = list(tok_ids[shared:]) if chunked else None
             slot.prefill_done = 0
+            slot.choice_parts = []
+            slot.choices = {"experts": np.full(
+                (self.cfg.n_layers, self.max_pages_per_seq * self.page_size,
+                 self.cfg.experts_per_token), -1, np.int32)} if self.keep_choices else None
             slot.active = True
             shared_blocks = shared // self.page_size
             row = np.zeros(self.max_pages_per_seq, np.int32)
@@ -1764,11 +1903,13 @@ class ContinuousBatchingEngine:
             width,
         )
         with self._phase.phase("prefill_dispatch"):
-            first, first_lp, self.pool.k, self.pool.v, self._rng = \
-                self._prefill_scatter(
+            (first, first_lp, self.pool.k, self.pool.v, self._rng), picks = \
+                self._prefill_call(
+                    self._prefill_scatter,
                     self.params, ids, positions, lens, self._rng, temps, scat,
                     self.pool.k, self.pool.v, top_ks,
                 )
+        self._note_prefill_picks(picks, [(i, 0, len(t)) for i, _r, t in chunk])
         self.prefill_tokens_total += sum(len(t) for _i, _r, t in chunk)
         slot_idxs = [slot_idx for slot_idx, _req, _ids in chunk]
         for slot_idx in slot_idxs:
@@ -1804,12 +1945,14 @@ class ContinuousBatchingEngine:
             rows_data, width, pos_offset=n_prior[:, None],
         )
         with self._phase.phase("prefill_dispatch"):
-            first, first_lp, self.pool.k, self.pool.v, self._rng = \
-                self._prior_prefill_scatter(
+            (first, first_lp, self.pool.k, self.pool.v, self._rng), picks = \
+                self._prefill_call(
+                    self._prior_prefill_scatter,
                     self.params, ids, positions, lens, self._rng, temps, scat,
                     self.pool.k, self.pool.v, prior_tables, n_prior, top_ks,
                     do_sample=True,
                 )
+        self._note_prefill_picks(picks, [(i, sh, len(t) - sh) for i, _r, t, sh in chunk])
         self.prefill_tokens_total += sum(len(t) - s for _i, _r, t, s in chunk)
         slot_idxs = [slot_idx for slot_idx, _req, _ids, _sh in chunk]
         for slot_idx in slot_idxs:
@@ -1817,6 +1960,32 @@ class ContinuousBatchingEngine:
         self._pending_first.append((first, first_lp, slot_idxs))
         for slot_idx, _req, tok_ids, shared in chunk:
             self._radix_insert(slot_idx, tok_ids, shared)
+
+    def _prefill_call(self, fn, *args, **static):
+        """One prefill dispatch → (its five results, its picks or None). A
+        routed family's program also takes the pairs counted on the device
+        since the last tick and returns them with its own added; its picks
+        stay on the device unless a caller asked for them."""
+        if not self.routed:
+            return fn(*args, **static), None
+        *out, moe = fn(*args, moe_acc=self._take_moe_acc(), **static)
+        self._moe_acc = moe["counts"]
+        return out, moe["experts"] if self.keep_choices else None
+
+    def _take_moe_acc(self):
+        """The pairs the prefill programs routed since the last tick, still
+        on the device ([4] int32; zeros where none ran), handed on once."""
+        acc, self._moe_acc = self._moe_acc, None
+        return np.zeros(4, np.int32) if acc is None else acc
+
+    def _note_prefill_picks(self, picks, rows) -> None:
+        """``rows``: (slot, first position, tokens) of each row of a prefill
+        dispatch whose ``picks [L, rows, width, k]`` are still on the device."""
+        if picks is None:
+            return
+        for r, (slot_idx, start, n) in enumerate(rows):
+            if self.slots[slot_idx].choices is not None:
+                self.slots[slot_idx].choice_parts.append((picks, r, start, n))
 
     def _advance_prefill(self) -> None:
         """Dispatch ONE chunked-prefill segment per tick (bounding how much
@@ -1853,12 +2022,14 @@ class ContinuousBatchingEngine:
             prior_table = np.zeros((1, pnb), np.int32)
             prior_table[0, :pb] = self._page_table[i, :pb]
             with self._phase.phase("prefill_dispatch"):
-                first, first_lp, self.pool.k, self.pool.v, self._rng = \
-                    self._prior_prefill_scatter(
+                (first, first_lp, self.pool.k, self.pool.v, self._rng), picks = \
+                    self._prefill_call(
+                        self._prior_prefill_scatter,
                         self.params, ids, positions, lens, self._rng, temps,
                         scat, self.pool.k, self.pool.v, prior_table,
                         n_prior, top_ks, do_sample=is_last,
                     )
+            self._note_prefill_picks(picks, [(i, prior, len(seg))])
             self.prefill_tokens_total += len(seg)
             slot.prefill_segments += 1
             if is_last:
@@ -2016,9 +2187,11 @@ class ContinuousBatchingEngine:
             lp_state = None
             lp_sum_out, lp_min_out, lp_cnt_out = lp_sum_in, lp_min_in, lp_cnt_in
         else:
+            # a routed family: the prefill programs' pairs ride this tick's fetch
+            moe_acc = {"moe_acc": self._take_moe_acc()} if self.routed else {}
             (packed, lp_state, tok_out, lens_out, halted_out,
              lp_sum_out, lp_min_out, lp_cnt_out,
-             self.pool.k, self.pool.v, self._rng) = self._step_n(
+             self.pool.k, self.pool.v, self._rng, *picks) = self._step_n(
                 self.params,
                 tok_in,
                 lens_in,
@@ -2033,7 +2206,7 @@ class ContinuousBatchingEngine:
                 lp_sum_in,
                 lp_min_in,
                 lp_cnt_in,
-                steps=steps,
+                steps=steps, **moe_acc,
             )
             self.total_sub_steps += steps
             spec = False
@@ -2045,6 +2218,8 @@ class ContinuousBatchingEngine:
                 slot.inflight_steps += int(budgets[i])
         return {"packed": packed, "budgets": budgets, "spec": spec,
                 "lp_state": lp_state,
+                # a routed family's picks of every sub-step, on the device
+                "picks": picks[0] if not spec and picks and self.keep_choices else None,
                 # for the row-step count at harvest: the scan's length and
                 # the rows that held a request when it was dispatched
                 "steps": int(steps),
@@ -2066,6 +2241,11 @@ class ContinuousBatchingEngine:
         budgets = record["budgets"]
         packed = np.asarray(record["packed"])
         spec = record.get("spec", False)
+        if self.routed and not spec:  # the expert layers' counts: the last four rows
+            record["moe"] = dict(zip(MOE_KINDS, (int(n) for n in packed[-4:, 0])))
+            packed = packed[:-4]
+        # [steps, L, B, k], fetched only where a caller asked for picks
+        picks = None if record.get("picks") is None else np.asarray(record["picks"]["experts"])
         # the tick's final logprob accumulators ([3, B]: sum / min / count),
         # one fetch riding the same dispatch as the packed tokens; refreshed
         # into the host mirrors so a retire inside this harvest reports the
@@ -2109,6 +2289,9 @@ class ContinuousBatchingEngine:
                 n = consumed
                 toks = packed[1 : 1 + n, i]
             for s in range(n):
+                if picks is not None and slot.choices is not None:
+                    # sub-step s fed the token at position ``length``
+                    slot.choices["experts"][:, slot.length] = picks[s, :, i]
                 slot.length += 1
                 self._lens[i] = slot.length
                 self._last_tok[i] = int(toks[s])
@@ -2134,6 +2317,9 @@ class ContinuousBatchingEngine:
         for kind, n in (record.get("kv_pages") or {}).items():
             self.kv_pages_total[kind] += n
             self.last_tick_kv_pages[kind] += n
+        for kind, n in (record.get("moe") or {}).items():
+            self.moe_total[kind] += n
+            self.last_tick_moe[kind] += n
 
     def _kv_pages(self, budgets, steps: int) -> dict:
         """K/V page blocks of the ``steps`` sub-steps being dispatched, by
@@ -2149,10 +2335,14 @@ class ContinuousBatchingEngine:
         sub = np.arange(steps)[None, :]
         at = np.asarray([s.length + s.inflight_steps if s.active else 0
                          for s in self.slots])[:, None] + sub
+        # the mean over the layers: a windowed layer's walk starts at the
+        # window's first block (one term where all layers are of one kind)
+        windows = Counter(self.cfg.window(i) for i in range(self.cfg.n_layers))
         held = np.where(
             sub < np.asarray(budgets)[:, None],
-            blocks_walked(at, self.page_size, self.max_pages_per_seq), 1)
-        return {"held": int(held.sum()),
+            sum(n * blocks_walked(at, self.page_size, self.max_pages_per_seq, w)
+                for w, n in windows.items()) / self.cfg.n_layers, 1)
+        return {"held": int(round(float(held.sum()))),
                 "tabled": steps * self.max_slots * self.max_pages_per_seq}
 
     def _fold_and_maybe_retire(self, i: int) -> Optional[PagedResult]:
@@ -2181,6 +2371,18 @@ class ContinuousBatchingEngine:
             self.ttft_samples.append(time.perf_counter() - slot.submit_t)
             self.ttft_count += 1
 
+    def _choices_of(self, slot: _Slot) -> Optional[dict]:
+        """What a retiring request's tokens were routed by: its prefill
+        dispatches' picks fetched now, its decode picks already written;
+        positions the radix cache served stay -1."""
+        if slot.choices is None:
+            return None
+        buf = slot.choices["experts"]
+        for picks, row, start, n in slot.choice_parts:
+            buf[:, start:start + n] = np.asarray(picks[:, row, :n])
+        # every position that was fed: the last sampled token never is
+        return {"experts": buf[:, : max(slot.prompt_tokens + len(slot.emitted) - 1, 0)].copy()}
+
     def _retire(self, i: int, reason: str) -> PagedResult:
         """Free a slot's pages (minus any donated to the radix cache), drop
         its prefix pins, and zero its device-mirror row."""
@@ -2198,7 +2400,9 @@ class ContinuousBatchingEngine:
             logprob_count=int(self._lp_cnt[i]),
             admit_t=slot.admit_t,
             prefill_segments=slot.prefill_segments,
+            choices=self._choices_of(slot),
         )
+        slot.choices, slot.choice_parts = None, []
         if slot.donated:
             donated = set(slot.donated)
             self.allocator.free([p for p in slot.pages if p not in donated])
@@ -2247,6 +2451,8 @@ class ContinuousBatchingEngine:
             "prefill_tokens": self.prefill_tokens_total,
             "decode_tokens": self.decode_tokens_total,
         }
+        if self.routed:
+            out.update({f"moe_{kind}": n for kind, n in self.moe_total.items()})
         if self._radix is not None:
             hit, miss = self.prefix_hit_tokens_total, self.prefix_miss_tokens_total
             out["prefix_hits"] = self.prefix_hits

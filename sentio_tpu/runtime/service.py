@@ -1217,7 +1217,8 @@ class PagedGenerationService:
                             **row_steps,
                         )
                         metrics.record_tick_phases(phase_s)
-                        metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"])
+                        metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
+                                                 row_steps["moe_pairs"])
                         for key, val in phase_s.items():
                             self._phase_totals[key] = (
                                 self._phase_totals.get(key, 0.0) + val
@@ -1383,7 +1384,8 @@ class PagedGenerationService:
                     last_hit_toks = engine.prefix_hit_tokens_total
                     last_miss_toks = engine.prefix_miss_tokens_total
                     metrics.record_tick(tick_dur_s, int(active), queued + inbox)
-                    metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"])
+                    metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
+                                                 row_steps["moe_pairs"])
                 except Exception:  # noqa: BLE001
                     logger.debug("tick telemetry failed", exc_info=True)
                 t_deliver_start = time.perf_counter()
@@ -1501,7 +1503,9 @@ class PagedGenerationService:
         latest ``engine.step()`` harvested (runtime/paged.py counts them)."""
         return {"sub_steps": self.engine.last_tick_sub_steps,
                 "row_steps": dict(self.engine.last_tick_row_steps),
-                "kv_pages": dict(self.engine.last_tick_kv_pages)}
+                "kv_pages": dict(self.engine.last_tick_kv_pages),
+                # a routed family's expert layers (zeros for any other)
+                "moe_pairs": dict(getattr(self.engine, "last_tick_moe", None) or {})}
 
     def _note_ttft_locked(self, ttft_s: float) -> None:  # lock-held: _mutex
         """Fold one observed TTFT into the EMA admission control projects

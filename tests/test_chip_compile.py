@@ -10,6 +10,7 @@ chip time. A compile that passes is not a chip run; it only says the chip's
 compiler accepts the program.
 """
 
+import functools
 import os
 import re
 
@@ -57,10 +58,10 @@ def v5e():
 
 
 def _paged_args(place, quant: bool, page: int, slots: int = 8, nb: int = 32,
-                hkv: int = HKV, hkv_spec=None, scale_spec=None):
+                hkv: int = HKV, hkv_spec=None, scale_spec=None, heads: int = H):
     """Abstract arguments of one decode-attention call at serve geometry."""
     num_pages = 1 + slots * nb
-    q = place((slots, H, D), jnp.bfloat16)
+    q = place((slots, heads, D), jnp.bfloat16)
     layer = place((), jnp.int32)
     table, lens = place((slots, nb), jnp.int32), place((slots,), jnp.int32)
     if not quant:
@@ -77,9 +78,11 @@ def _on_one_chip(topo):
         shape, dtype, sharding=chip)
 
 
-def _paged_case(quant: bool, page: int, **geometry):
+def _paged_case(quant: bool, page: int, window=None, **geometry):
     def build(topo):
         fn = paged_attention_quant if quant else paged_attention
+        if window is not None:  # static: the walk starts at the window's first block
+            fn = functools.partial(fn, window=window)
         return fn, _paged_args(_on_one_chip(topo), quant, page, **geometry)
 
     return build
@@ -139,6 +142,14 @@ CASES = {
     # 16 slots of 18 pages at 8 kv heads, 32 slots of 10 pages at 4
     "paged-bf16-cell-mistral": _paged_case(quant=False, page=128, slots=16, nb=18, hkv=8),
     "paged-bf16-cell-yi": _paged_case(quant=False, page=128, slots=32, nb=10, hkv=4),
+    # the command-a cell: 128 query heads over 8 kv heads (16 a kv head), its
+    # sliding layers' window of 4,096; and a window SHORTER than a table (the
+    # walk then starts past block 0), bf16 and int8
+    "paged-bf16-cell-commanda-window": _paged_case(
+        quant=False, page=128, slots=32, nb=10, hkv=8, heads=128, window=4096),
+    "paged-bf16-window-inside-table": _paged_case(
+        quant=False, page=128, slots=8, nb=40, hkv=8, heads=128, window=4096),
+    "paged-int8-window-inside-table": _paged_case(quant=True, page=128, window=1024),
     # 4 kv heads over tp=4: one a device
     "paged-bf16-tp4-mesh-1kv": _tp4_case(quant=False, hkv=4),
     "paged-int8-tp4-mesh-1kv": _tp4_case(quant=True, hkv=4),
@@ -416,3 +427,85 @@ def test_scatter_of_page_windows_copies_the_pool_at_4_kv_heads(v5e):
 
     assert _scatter_makes(v5e, "yi", windows).count("copy") == 4
     assert set(_scatter_makes(v5e, "mistral", windows)) <= IN_PLACE  # 8 kv heads: none
+
+
+# ------------------------------------------------- a family of another shape
+#
+# ``cohere2_moe`` (models/cohere2_moe.py) at the widths of the benchmark's
+# ``command-a-plus-ep8-l4``: attention FOUR times the hidden width (128 query
+# heads of 128 at hidden 4096), a sliding and a full layer, 16 held experts of
+# 4096 x 4096 behind the megablox grouped matmul, a head that reads the
+# embedding. The copy-free controls above, extended to this geometry.
+
+
+@pytest.fixture(scope="module")
+def commanda_programs(v5e):
+    from sentio_tpu.models import moe
+    from sentio_tpu.models.cohere2_moe import (
+        FULL, SLIDING, Cohere2MoeConfig, cohere2_forward, init_cohere2_moe)
+
+    cfg = Cohere2MoeConfig(n_layers=LAYERS, layer_kinds=f"{SLIDING},{FULL}")
+    place = _on_one_chip(v5e)
+    params = jax.eval_shape(lambda: serving_layout(init_cohere2_moe(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree_util.tree_map(
+        lambda a: place(a.shape, jnp.bfloat16 if a.ndim >= 2 else a.dtype), params)
+    slots, nb, page, segment = 32, 10, 128, 512
+    pool = place((LAYERS, 1 + slots * nb, page, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    impl = make_paged_attn_impl(interpret=False)
+
+    def step(params, tok, lens, table, k_pages, v_pages):
+        def body(carry, _):
+            tok, lens, k_pages, v_pages = carry
+            logits, k_pages, v_pages, routed = paged_decode_forward(
+                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl,
+                write_mask=lens < nb * page - 1, return_routed=True)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
+                    k_pages, v_pages), routed["experts"]
+
+        return jax.lax.scan(body, (tok, lens, k_pages, v_pages), None, length=2)
+
+    def prefill(params, ids, positions, cache, n_prior):
+        return cohere2_forward(params, cfg, ids, positions=positions, cache=cache,
+                               cache_index=n_prior)
+
+    cache = place((LAYERS, 1, 512 + segment, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    # the code asks the backend which grouped matmul to take and sees the CPU
+    # here: the test hands it the chip's, for the length of the two compiles
+    was, moe.grouped_matmul = moe.grouped_matmul, moe.expert_matmul
+    try:
+        texts = {
+            "step": jax.jit(step, donate_argnums=(4, 5)).lower(
+                params, place((slots,), jnp.int32), place((slots,), jnp.int32),
+                place((slots, nb), jnp.int32), pool, pool).compile().as_text(),
+            "prefill": jax.jit(prefill, donate_argnums=(3,)).lower(
+                params, place((1, segment), jnp.int32), place((1, segment), jnp.int32),
+                {"k": cache, "v": cache}, place((1,), jnp.int32)).compile().as_text(),
+        }
+    finally:
+        moe.grouped_matmul = was
+    return cfg, params, texts
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_commanda_programs_read_their_weights_where_they_lie(commanda_programs, program):
+    """The v5e compiler takes both programs (Mosaic: 128 query heads in the
+    decode walk's VMEM, the grouped matmul's tiles), and nothing in them makes
+    an array with the shape of a projection ([16384, 4096]: attention wider
+    than the model), of the embedding the head reads, or of a stack of
+    experts."""
+    cfg, params, texts = commanda_programs
+    assert params["layers_0"]["attn"]["wq_t"]["kernel"].shape == (cfg.n_heads * cfg.head_dim, cfg.dim)
+    assert "lm_head" not in params
+    assert _weight_copies(texts[program], params) == []
+    stack = f"bf16[{cfg.experts_held},{cfg.dim},{cfg.mlp_dim}]"
+    assert [m for m in _pool_shaped(texts[program], (stack,))
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast")] == []
+
+
+def test_commanda_decode_step_holds_its_kernels(commanda_programs):
+    """A layer of the decode step: one walk of the pages (the sliding layer's
+    starting at its window's first block) and three grouped expert matmuls,
+    each a Pallas call; the pool updated in place."""
+    cfg, _, texts = commanda_programs
+    assert texts["step"].count('custom_call_target="tpu_custom_call"') == LAYERS * 4
+    assert len(re.findall(r"%gmm[.\d]* = ", texts["step"])) == LAYERS * 3
