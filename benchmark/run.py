@@ -221,6 +221,22 @@ def measure(srv: server.Server, mix: dict, seed: int, seconds: float, rate: floa
     return requests[:sent], t0
 
 
+def stage_table(port: int, last: int) -> dict:
+    """Where the window's requests waited, by the program's own flight
+    records (``/debug/flight?last=N``): mean ms a request stage over the
+    ``last`` requests that finished. A note for a reader of a run that reads
+    apart (was it ``pool_wait``, ``slot_wait`` or delivery?); no metric
+    reads it, and a server that cannot give it is no fault of the run."""
+    try:
+        status, body = server.http_json(port, "GET", f"/debug/flight?last={last}", timeout=60.0)
+    except (OSError, BenchFailure) as exc:
+        return {"error": str(exc)[:200]}
+    if status != 200 or "stages_ms" not in body:
+        return {"error": f"status {status}"}
+    return {"requests": body["requests"], "ttft_server_ms_mean": (body["ttft_server_ms"] or {}).get("mean"),
+            "mean_ms": {stage: row["mean"] for stage, row in body["stages_ms"].items()}}
+
+
 def child_json(cmd: list[str], env: dict, timeout: float) -> tuple[int, dict]:
     proc = subprocess.run(cmd, cwd=str(REPO), env=env, capture_output=True, text=True,
                           timeout=timeout)
@@ -303,6 +319,7 @@ def run(args) -> int:
         if profiler is not None:
             profiler.join(timeout=180.0)
         status, obs.info = server.http_json(srv.port, "GET", "/info", timeout=60.0)
+        stages = stage_table(srv.port, len(requests))
         rc = srv.terminate()
     finally:
         srv.sweep()
@@ -335,6 +352,7 @@ def run(args) -> int:
             phase: round(server.series_value(obs.prom_after, series, {"phase": phase})
                          - (server.series_value(obs.prom_before, series, {"phase": phase}) or 0.0), 4)
             for phase in sorted({labels["phase"] for name, labels, _ in obs.prom_after if name == series})})
+    note(phase="stages", **stages)
     if client["failed"]:
         note(phase="failed-requests", count=client["failed"], examples=client["problems"])
 
@@ -378,6 +396,10 @@ def run(args) -> int:
          # tens of them moves in steps (a tick that carries a prefill segment
          # or not), and the list shows which step a run's median stood on
          tpot_ms_sorted=[round(v, 3) for v in sorted(client["tpot_ms"])],
+         # and every request's time to its whole answer: answers end on ticks
+         # and a closed loop's callers start on them, so these stand in groups
+         # a tick apart too, and a stalled run shows the requests it held
+         answer_ms_sorted=[round(v, 1) for v in sorted(client["answer_ms"])],
          answer_tokens_in_window=client["answer_tokens_in_window"], problems=problems)
 
     metrics: dict[str, dict] = {}

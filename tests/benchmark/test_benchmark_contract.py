@@ -19,6 +19,11 @@ def cells_of(metric):
     return metric.get("workloads", sorted(CELLS))
 
 
+def whole_step_share(metric):
+    """A share of the chip's peak over a whole step: ``mfu`` as a part of its name."""
+    return "mfu" in re.split(r"[_.\-]", metric["name"])
+
+
 def assert_reader_file(folder, name):
     """A metric's file names its reader and what it reads, and repeats nothing
     that BENCHMARK.json states (unit, direction, source, layer, moves)."""
@@ -120,8 +125,47 @@ def test_per_layer_metric_has_a_reader_and_moves_a_reported_metric(metric):
     assert_reader_file("layer_metrics", metric["name"])
     moved = next(m for m in BENCH["end_to_end"] if m["name"] == metric["moves"])
     assert set(cells_of(metric)) <= set(cells_of(moved))
-    if metric["name"].endswith("_roofline"):
-        assert metric["unit"] == "%"
+    # a share of a roofline or of the chip's peak is in %: a kernel's by its
+    # name's end, the whole step's by ``mfu`` as a part of its name
+    if metric["name"].endswith("_roofline") or whole_step_share(metric):
+        assert metric["unit"] == "%" and metric["better"] == "higher"
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_an_end_to_end_metric_with_a_cell_list_resolves_there_and_nowhere_else(cell):
+    """``run.py::resolve_cell`` on the tree's own BENCHMARK.json: a cell's
+    untraced line carries the end-to-end metrics that name it or name no cell;
+    the time to a whole answer is the closed loops' and no open loop's."""
+    from benchmark import run
+
+    resolved = run.resolve_cell(cell, REPO / "BENCHMARK.json")
+    got = [m["name"] for m in resolved["end_to_end"]]
+    assert got == [m["name"] for m in BENCH["end_to_end"] if cell in cells_of(m)]
+    closed = resolved["mix"]["loop"] == "closed"
+    assert got == (["tpot_p50_ms", "answer_latency_p50_ms", "setup_s"] if closed else ["tpot_p50_ms", "setup_s"])
+    # and every per-layer metric of the cell moves something the cell reports
+    assert {m["moves"] for m in resolved["per_layer"]} <= set(got)
+
+
+def test_a_whole_step_share_with_mfu_in_its_name_stands_beside_the_kernels_rooflines():
+    """A kernel's roofline falls silent when a later PR renames the kernel or
+    takes it off the path; the whole step's share of the peak, in every cell a
+    kernel's roofline is read in and moving the same metric, still bounds it."""
+    whole = [m for m in BENCH["per_layer"] if whole_step_share(m)]
+    assert whole
+    for kernel in (m for m in BENCH["per_layer"] if m["name"].endswith("_roofline")):
+        assert any(w["moves"] == kernel["moves"] and set(kernel["workloads"]) <= set(w["workloads"])
+                   and w["layer"] == kernel["layer"] for w in whole), kernel["name"]
+
+
+@pytest.mark.parametrize("name, moves", [
+    ("decode_rows_useful_share", "answer_latency_p50_ms"), ("window_out_tok_per_s", "answer_latency_p50_ms"),
+    ("hbm_peak_gb", "answer_latency_p50_ms"), ("stage_queue_share", "tpot_p50_ms")])
+def test_what_is_about_throughput_moves_the_time_to_a_whole_answer(name, moves):
+    """Fuller rows, more tokens a second and the memory that buys slots do not
+    shorten a step: they shorten what a closed loop's caller waits. The queue
+    share is read in the open loop too, which reports no answer latency."""
+    assert next(m for m in BENCH["per_layer"] if m["name"] == name)["moves"] == moves
 
 
 def test_files_under_paths_have_contract_names():

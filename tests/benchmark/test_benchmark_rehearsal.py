@@ -102,8 +102,17 @@ def test_a_broken_timed_path_makes_the_run_incorrect(tmp_path):
     assert check["ok"] is False and check["served_token_gap"] > check["served_token_gap_tol"]
     # the logits part never went through ``run_all``: it still agrees
     assert max(check["prefill_rel_rms"], check["decode_rel_rms"]) <= check["tolerance"]
-    problems = next(n for n in notes if n.get("phase") == "window")["problems"]
+    window = next(n for n in notes if n.get("phase") == "window")
+    problems = window["problems"]
     assert len(problems) == 2 and any(p.startswith("reference check failed") for p in problems), problems
+    # a closed-loop cell's untraced line carries the time to a whole answer
+    # (an open loop's does not: ``test_committed_cell_on_one_virtual_device``)
+    assert set(line["metrics"]) == {"tpot_p50_ms", "answer_latency_p50_ms", "setup_s"}
+    answers = window["answer_ms_sorted"]   # the note shows which group of answers the median stood on
+    assert len(answers) == line["attempted"] and answers == sorted(answers)
+    assert line["metrics"]["answer_latency_p50_ms"]["value"] == pytest.approx(answers[(len(answers) + 1) // 2 - 1], abs=0.06)
+    stages = next(n for n in notes if n.get("phase") == "stages")
+    assert stages["requests"] == line["attempted"] and {"pool_wait", "slot_wait", "decode"} <= set(stages["mean_ms"])
 
 
 def test_four_chip_cell_from_scratch_files_only(tmp_path):
@@ -159,3 +168,64 @@ def test_fewer_chips_than_the_cell_asks_is_an_error(monkeypatch, capsys, tmp_pat
                                       "--benchmark-file", str(tmp_path / "BENCHMARK.json")])
     assert run.main() == 1
     assert "asks 4 chips" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("reply, want", [
+    ((200, {"requests": 3, "ttft_server_ms": {"mean": 900.5, "p50": 880.0}, "residual_ms_max": 0.0,
+            "stages_ms": {"pool_wait": {"count": 3, "mean": 400.25, "p50": 10.0},
+                          "decode": {"count": 3, "mean": 2600.0, "p50": 2590.0}}}),
+     {"requests": 3, "ttft_server_ms_mean": 900.5, "mean_ms": {"pool_wait": 400.25, "decode": 2600.0}}),
+    ((200, {"requests": 0, "ttft_server_ms": None, "stages_ms": {}}),
+     {"requests": 0, "ttft_server_ms_mean": None, "mean_ms": {}}),
+    ((404, {"error": "not found"}), {"error": "status 404"}),
+    (OSError("connection refused"), {"error": "connection refused"}),
+], ids=["a-table", "no-request-finished", "no-such-route", "no-server"])
+def test_the_stage_table_of_a_window_is_a_note_and_never_a_failure(monkeypatch, reply, want):
+    """``run.stage_table``: mean ms a request stage over the window's requests
+    from ``/debug/flight?last=N``; a program that cannot give it costs the run
+    a note, not its result."""
+    from benchmark import run, server
+
+    asked = []
+
+    def http_json(port, method, path, payload=None, timeout=900.0):
+        asked.append((port, method, path))
+        if isinstance(reply, Exception):
+            raise reply
+        return reply
+
+    monkeypatch.setattr(server, "http_json", http_json)
+    assert run.stage_table(8123, 190) == want
+    assert asked == [(8123, "GET", "/debug/flight?last=190")]
+
+
+STAGE_SHARES = ("stage_encoders_share", "stage_queue_share", "stage_prefill_share",
+                "decode_rows_useful_share")
+
+
+def test_traced_rehearsal_reads_all_four_from_the_program(tmp_path):
+    """(Here and not beside the readers' own tests in ``test_benchmark_stages.py``:
+    every rehearsal in the tree's own ``benchmark/.work`` runs from THIS file, so
+    from one worker, one at a time — a second one at once wipes the first's
+    checkpoints.) ``--trace 1`` on the CPU, every per-layer metric asked of the one
+    cell: the three stage shares are ratios of one denominator (so they sum
+    to under 100), the row-step share is a ratio of counts, and the metrics
+    the cell had before are still there beside them."""
+    bench = json.loads(json.dumps(BENCH))
+    for metric in bench["per_layer"]:
+        metric.pop("workloads", None)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    line, _out = run_cell("mistral7b-rag-open", 1, ["--seed", "2147483693", "--seconds", "4", "--benchmark-file",
+                                                    str(tmp_path / "BENCHMARK.json")],
+                          "--xla_force_host_platform_device_count=1")   # the later --seed and --seconds hold
+    assert line["failed"] == 0 and line["attempted"] > 0
+    got = {name: line["metrics"][name]["value"] for name in STAGE_SHARES}
+    assert all(0.0 <= v <= 100.0 for v in got.values()), got
+    assert got["stage_encoders_share"] > 0 and got["stage_prefill_share"] > 0
+    assert got["decode_rows_useful_share"] > 0
+    shares = sum(got[n] for n in STAGE_SHARES[:3])
+    assert 0.0 < shares < 100.0, got
+    assert {"tick_host_share", "graph_pre_generate_ms", "client_ttft_p50_ms"} <= set(line["metrics"])
+    # the traced window's host plane is named by the program, not by frames
+    gaps = [name for name, _s in line["breakdown"]["idle_gaps"]]
+    assert not [g for g in gaps if g.startswith("$")], gaps

@@ -53,8 +53,8 @@ def lay_tree(root: Path) -> Path:
                              "reduced": config["reduced"], "why": "a second family, as files"})
     bench["workloads"].append({"name": CELL, "config": config["name"], "traffic": MIX, "chips": 1,
                                "why": "the routed family under the closed-loop mix"})
-    for metric in bench["per_layer"]:  # what the dense cell under this mix reports, this one reports
-        if LIKE in metric["workloads"]:
+    for metric in bench["per_layer"] + bench["end_to_end"]:  # what the dense cell under this mix reports, this one reports
+        if LIKE in metric.get("workloads", []):   # an end-to-end metric of every cell names none
             metric["workloads"].append(CELL)
     (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
 
@@ -107,9 +107,13 @@ def test_the_second_familys_cell_rehearses_in_child_processes(tree, trace):
     if trace:  # a CPU has no peak, no kernel by that name and no memory reading: those read nothing
         assert line["device"]["busy_s"] > 0 and line["breakdown"]["device_ops"]
         assert {"tick_host_share", "stage_queue_share", "decode_rows_useful_share", "kv_pages_held_share",
-                "answer_latency_p50_ms", "window_out_tok_per_s"} <= set(line["metrics"]) <= want
-    else:
-        assert set(line["metrics"]) == want == {"tpot_p50_ms", "setup_s"}
+                "window_out_tok_per_s"} <= set(line["metrics"]) <= want
+        assert "answer_latency_p50_ms" not in want   # end to end since PR 34: the untraced run's
+    else:  # a closed-loop cell's callers wait for the whole answer, and its untraced line says how long
+        assert set(line["metrics"]) == want == {"tpot_p50_ms", "setup_s", "answer_latency_p50_ms"}
+        assert line["metrics"]["answer_latency_p50_ms"]["value"] > 256 * line["metrics"]["tpot_p50_ms"]["value"] * 0.9
+        # where the window's requests waited, stage by stage: a note of every run, read by no metric
+        assert phase("stages")["requests"] == line["attempted"] and "pool_wait" in phase("stages")["mean_ms"]
     # the copy kept its work to itself
     assert (tree / "benchmark" / ".work" / "server.log").is_file()
 
@@ -228,7 +232,7 @@ def test_the_rooflines_read_the_second_familys_costs(scratch_family):
     context = 11 * (706 + 128)
     assert readers.decode_rows_and_context(obs) == (32, context)
     least = least_time_s(scratch_family.decode_substep_cost(CONFIG, 32, context), "TPU v5 lite")
-    got = readers.read_metric(readers.load_metric("per_layer", "decode_step_roofline"), obs)
+    got = readers.read_metric(readers.load_metric("per_layer", "decode_step_mfu"), obs)
     assert least["bound"] == "bandwidth" and got == pytest.approx(100 * least["seconds"] * 1e3 / 1.2)
     call = least_time_s(scratch_family.KERNEL_COSTS["paged_attention"](CONFIG, 32, context), "TPU v5 lite")
     got = readers.read_metric(readers.load_metric("per_layer", "paged_attn_roofline"), obs)

@@ -1,12 +1,12 @@
 """The per-layer metrics that read the program's request stages and counted
 row-steps: four data files over the ``prom_delta`` reader. By hand on made-up
 ``/metrics`` rows (a ratio of label sums; nothing when the program lacks the
-series, as the parent commit does), and end to end: a traced rehearsal of the
-RAG cell on the CPU reports all four as ratios of what the program counted."""
+series, as the parent commit does). End to end — a traced rehearsal of the RAG
+cell on the CPU reports all four as ratios of what the program counted — in
+``test_benchmark_rehearsal.py``, with every other rehearsal in the tree's own
+``benchmark/.work``: they share it and run one at a time."""
 
 import json
-import os
-import subprocess
 import sys
 from pathlib import Path
 
@@ -77,35 +77,3 @@ def test_entry_names_the_layer_as_the_file_spells_it(name):
     assert entry["layer"] in layers and entry["unit"] == "%"
     spec = readers.load_metric("per_layer", name)
     assert spec["reader"] == "prom_delta" and set(spec["num"]) <= set(spec["den"])
-
-
-def test_traced_rehearsal_reads_all_four_from_the_program(tmp_path):
-    """``--trace 1`` on the CPU, every per-layer metric asked of the one
-    cell: the three stage shares are ratios of one denominator (so they sum
-    to under 100), the row-step share is a ratio of counts, and the metrics
-    the cell had before are still there beside them."""
-    bench = json.loads(json.dumps(BENCH))
-    for metric in bench["per_layer"]:
-        metric.pop("workloads", None)
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    env = {**os.environ, "JAX_PLATFORMS": "cpu",
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
-           "JAX_ENABLE_COMPILATION_CACHE": "false", "BENCH_RUN": "ignored"}
-    proc = subprocess.run(
-        [sys.executable, str(REPO / "benchmark" / "run.py"), "--workload",
-         "mistral7b-rag-open", "--seed", "2147483693", "--seconds", "4", "--trace", "1",
-         "--benchmark-file", str(tmp_path / "BENCHMARK.json")],
-        cwd=str(REPO), env=env, capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stderr[-2000:] + proc.stdout[-2000:]
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["failed"] == 0 and line["attempted"] > 0
-    got = {name: line["metrics"][name]["value"] for name in NEW}
-    assert all(0.0 <= v <= 100.0 for v in got.values()), got
-    assert got["stage_encoders_share"] > 0 and got["stage_prefill_share"] > 0
-    assert got["decode_rows_useful_share"] > 0
-    shares = sum(got[n] for n in NEW[:3])
-    assert 0.0 < shares < 100.0, got
-    assert {"tick_host_share", "graph_pre_generate_ms", "client_ttft_p50_ms"} <= set(line["metrics"])
-    # the traced window's host plane is named by the program, not by frames
-    gaps = [name for name, _s in line["breakdown"]["idle_gaps"]]
-    assert not [g for g in gaps if g.startswith("$")], gaps

@@ -129,7 +129,7 @@ def test_kernel_roofline_by_hand():
     assert 12.7 < readers.read_metric(spec, obs) < 12.8
     # the whole step's roofline takes the SAME rows and context
     assert readers.decode_rows_and_context(obs) == (16, context)
-    step = readers.read_metric(readers.load_metric("per_layer", "decode_step_roofline"), obs)
+    step = readers.read_metric(readers.load_metric("per_layer", "decode_step_mfu"), obs)
     cost = readers.load_family(obs.model).decode_substep_cost(obs.model, 16, context)
     assert step == pytest.approx(100 * cost["bytes"] / 819e9 * 1e3 / 12.0)
 

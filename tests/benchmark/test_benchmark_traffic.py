@@ -110,6 +110,49 @@ def test_failed_requests_count_in_no_latency():
     assert out["answer_tokens_in_window"] == 4  # the second piece came after the window
 
 
+def _answered(index, t_due, first, last, problem=None, send_lag=0.0):
+    req = traffic.Request(index, 0.0, {})
+    req.t_due = t_due
+    req.result = {"t_send": t_due + send_lag, "t_sources": t_due + send_lag + 0.1, "problem": problem,
+                  "pieces": [(first, "a" * 16), (last, "b" * 240)], "t_done": last}
+    return req
+
+
+def test_the_time_to_a_whole_answer_runs_from_the_due_time_to_the_last_token():
+    """``answer_ms``: due time -> last token event, whatever the request waited
+    before it was sent; a degraded request and one that never answered are in
+    ``failed`` and in no latency."""
+    sent_late = _answered(0, t_due=10.0, first=12.0, last=15.0, send_lag=1.5)
+    degraded = _answered(1, t_due=10.0, first=11.0, last=40.0, problem="degraded: generator fallback")
+    refused = traffic.Request(2, 0.0, {})
+    refused.t_due, refused.result = 10.0, {"t_send": 10.0, "t_sources": None, "pieces": [],
+                                           "problem": "status 503", "t_done": 10.1}
+    never_sent = traffic.Request(3, 0.0, {})   # the window closed before a caller took it
+    out = traffic.reduce_requests([sent_late, degraded, refused, never_sent], 0.0, 60.0)
+    assert out["answer_ms"] == pytest.approx([5000.0]) and out["ttft_ms"] == pytest.approx([2000.0])
+    assert out["attempted"] == 3 and out["failed"] == 2
+    assert out["tpot_ms"] == pytest.approx([3000.0 / 240])
+
+
+@pytest.mark.parametrize("stalled", [3, 20])
+def test_one_stalled_request_among_24_moves_the_median_answer_by_at_most_one_request(stalled):
+    """Why the median and not the rate: a stall of delivery that holds one
+    answer for 30 s (PR 27's runs) takes that much out of the mean and the
+    rate, and moves the median by at most the step to the next request."""
+    step = 10.0   # ms between neighbours of the quiet run's sorted answers
+    quiet = [_answered(i, t_due=100.0 + i, first=101.0 + i, last=105.0 + i + i * step / 1e3) for i in range(24)]
+    held = [_answered(i, t_due=100.0 + i, first=101.0 + i, last=105.0 + i + i * step / 1e3 + (30.0 if i == stalled else 0.0))
+            for i in range(24)]
+    a, b = (traffic.reduce_requests(reqs, 100.0, 40.0) for reqs in (quiet, held))
+    p50 = lambda out: traffic.percentile(out["answer_ms"], 50)  # noqa: E731
+    assert p50(a) == pytest.approx(5000.0 + 11 * step)
+    assert 0.0 <= p50(b) - p50(a) <= step + 1e-6
+    assert p50(b) - p50(a) == pytest.approx(step if stalled < 12 else 0.0)
+    mean = lambda out: sum(out["answer_ms"]) / 24  # noqa: E731
+    assert mean(b) - mean(a) == pytest.approx(30000.0 / 24)
+    assert b["stream_gap_max_ms"] > 30000.0 > a["stream_gap_max_ms"]   # and the window note says which run it was
+
+
 def test_percentile_is_nearest_rank():
     values = list(range(1, 101))
     assert traffic.percentile(values, 50) == 50 and traffic.percentile(values, 90) == 90
