@@ -1,7 +1,7 @@
 """The configuration ``command-a-plus-ep8-l4`` and its family
 (``benchmark/families/cohere2_moe.py``): the cut and the counts by hand, the
 seeded tree under a tied head, the checkpoint through ``load_decoder``, and
-the cell ``commanda-ep8-chat-closed`` rehearsed on the CPU through ``run.py``
+the cell ``commanda-ep8-chat-closed-16`` rehearsed on the CPU through ``run.py``
 → ``server.py`` → ``check.py`` in a copy of ``benchmark/`` (its own ``.work``:
 no trace directory shared with the other rehearsals)."""
 
@@ -23,7 +23,7 @@ from benchmark.roofline import least_time_s
 
 MODEL = load_dir("configs")["command-a-plus-ep8-l4"]
 TINY = {**MODEL, **MODEL["rehearsal"]}
-CELL = "commanda-ep8-chat-closed"
+CELL = "commanda-ep8-chat-closed-16"
 
 
 def test_the_cut_by_hand():

@@ -300,11 +300,44 @@ def test_the_two_mixes_land_where_they_always_did():
                                           "audit": [(512, 8, False), (512, 16, True)]}
         assert rag["prompt_tokens"] == [1041, 1134] and all(n // 128 == 8 for n in rag["prompt_tokens"])
     assert MIXES["rag-open"]["warm_programs"]["paged.prior_prefill_scatter"] == 4
-    for chat in _band_holds(MIXES["chat-closed"]):
-        # one dispatch in the 512 bucket over the head, in any of four row buckets
-        assert chat["head_pages"] == 2 and chat["lo"] == chat["hi"] == {"answer": [(512, 2, True)], "audit": []}
-        assert chat["prompt_tokens"] == [658, 754]
-    assert MIXES["chat-closed"]["warm_programs"]["paged.prior_prefill_scatter"] == 4
+    for name in ("chat-closed", "chat-closed-16"):   # 16 callers fill the same four row buckets as 24: they stop at 8
+        for chat in _band_holds(MIXES[name]):
+            # one dispatch in the 512 bucket over the head, in any of four row buckets
+            assert chat["head_pages"] == 2 and chat["lo"] == chat["hi"] == {"answer": [(512, 2, True)], "audit": []}
+            assert chat["prompt_tokens"] == [658, 754]
+        assert MIXES[name]["warm_programs"]["paged.prior_prefill_scatter"] == 4
+
+
+# a mix named ``<mix>-<n>`` is ``<mix>`` at a stated concurrency of n callers
+STATED = sorted((name, base, int(name[len(base) + 1:])) for name in MIXES for base in MIXES
+                if name.startswith(base + "-") and name[len(base) + 1:].isdigit())
+
+
+def test_a_mix_at_a_stated_concurrency_is_found():
+    assert ("chat-closed-16", "chat-closed", 16) in STATED
+
+
+@pytest.mark.parametrize("name, base, callers", STATED)
+def test_a_mix_at_a_stated_concurrency_is_its_base_mix_in_every_other_field(name, base, callers):
+    """``chat-closed-16`` is ``chat-closed`` with 16 callers and stays so: a
+    cell whose step time follows the rows that advance (a routed family)
+    must read one load whatever the program under test does with callers it
+    cannot serve at once, and the ONLY thing that may differ from the mix the
+    dense cells run is how many callers there are — and what follows from
+    that: the callers' first requests as far apart (``stagger_s`` over
+    ``clients``), the last warm-up burst as large as the loop, the rest of
+    the bursts, every size, the serving environment, the prefill program set
+    and the rehearsal as they are."""
+    mix, like = MIXES[name], MIXES[base]
+    assert set(like) <= set(mix) and set(mix) - set(like) <= {"warm_note"}
+    differ = {k for k in like if mix[k] != like[k]}
+    assert differ - {"warm_programs"} == {"name", "why", "clients", "stagger_s", "warmup_bursts"}
+    if "warm_programs" in differ:   # the warm-up's count held tighter than the base mix holds it, never looser, and it says why
+        assert "warm_note" in mix and set(mix["warm_programs"]) == set(like["warm_programs"])
+        assert all(mix["warm_programs"][k] >= n for k, n in like["warm_programs"].items())
+    assert like["loop"] == "closed" and mix["clients"] == callers and f"{callers} callers" in mix["why"]
+    assert mix["stagger_s"] / callers == pytest.approx(like["stagger_s"] / like["clients"])
+    assert mix["warmup_bursts"] == like["warmup_bursts"][:-1] + [callers] and like["warmup_bursts"][-1] == like["clients"]
 
 
 def test_a_long_mix_lands_in_nine_segments_and_its_straddling_twin_fails_the_band_alone():
