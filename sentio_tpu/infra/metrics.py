@@ -331,6 +331,16 @@ class MetricsCollector:
                 "held experts x layers x decode sub-steps, and those a pair touched",
                 ["kind"], registry=r,
             ),
+            # a latent family's prefill dispatches (runtime/paged.py, counted
+            # on the host): tokens a call computed (new) and prior tokens
+            # whose pooled latents it turned back into keys and values
+            # (expanded). expanded / new is what chunking over a latent
+            # prior costs; 0 expanded if priors are attended absorbed
+            "prefill_latent": Counter(
+                "sentio_tpu_prefill_latent_tokens_total",
+                "tokens a latent family's prefill computed, and prior tokens it expanded",
+                ["kind"], registry=r,
+            ),
             # process-mode replica tier (runtime/worker.py): worker
             # process deaths observed by the router-side shim (SIGKILL,
             # OOM-kill, crash, broken RPC pipe). A steadily increasing
@@ -572,14 +582,16 @@ class MetricsCollector:
             hist.labels(stage=stage).observe(float(seconds))
 
     def record_row_steps(self, counts: dict, kv_pages: Optional[dict] = None,
-                         moe: Optional[dict] = None) -> None:
+                         moe: Optional[dict] = None,
+                         prefill_latent: Optional[dict] = None) -> None:
         """One harvested tick's row-steps by kind (useful / halted / empty),
-        the K/V page blocks of its sub-steps (held / tabled) and, of a routed
-        family, its expert layers' pairs (routed / held) and expert-steps
-        (held / touched) — ``MOE_KINDS`` as ``<series>_<kind>``."""
+        the K/V page blocks of its sub-steps (held / tabled), of a routed
+        family its expert layers' pairs (routed / held) and expert-steps
+        (held / touched) — ``MOE_KINDS`` as ``<series>_<kind>`` — and of a
+        latent family its prefill tokens (new / expanded)."""
         if not self.enabled:
             return
-        from sentio_tpu.infra.phases import KV_PAGE_KINDS, ROW_STEP_KINDS
+        from sentio_tpu.infra.phases import KV_PAGE_KINDS, PREFILL_LATENT_KINDS, ROW_STEP_KINDS
 
         moe = moe or {}
         for name, kinds, tick in (
@@ -588,9 +600,10 @@ class MetricsCollector:
                 ("moe_pairs", ("routed", "held"),
                  {k: moe.get(f"pairs_{k}", 0) for k in ("routed", "held")}),
                 ("moe_expert_steps", ("held", "touched"),
-                 {k: moe.get(f"experts_{k}", 0) for k in ("held", "touched")})):
-            if name.startswith("moe") and not any(tick.values()):
-                continue  # no series where no routed family is served
+                 {k: moe.get(f"experts_{k}", 0) for k in ("held", "touched")}),
+                ("prefill_latent", PREFILL_LATENT_KINDS, prefill_latent or {})):
+            if name.startswith(("moe", "prefill_latent")) and not any(tick.values()):
+                continue  # no series where no such family is served
             counter = self._prom.get(name)
             for kind in kinds:
                 n = int(tick.get(kind, 0))

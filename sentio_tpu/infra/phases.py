@@ -102,6 +102,7 @@ __all__ = [
     "ROW_STEP_KINDS",
     "KV_PAGE_KINDS",
     "MOE_KINDS",
+    "PREFILL_LATENT_KINDS",
     "TTFT_STAGES",
     "tile_ttft",
     "TICK_PHASES",
@@ -177,6 +178,13 @@ KV_PAGE_KINDS = ("held", "tabled")
 # programs alike; and of the held experts x layers x decode sub-steps
 # (`experts_held`) those at least one pair touched (`experts_touched`)
 MOE_KINDS = ("pairs_routed", "pairs_held", "experts_held", "experts_touched")
+
+# a latent family's prefill dispatches (runtime/paged.py counts them on the
+# host from each dispatch's own integers): `new` the tokens a call computed,
+# `expanded` the prior tokens whose pooled latents that call turned back into
+# keys and values (a chunked prompt's every segment after the first, and every
+# radix hit, expands its whole prior)
+PREFILL_LATENT_KINDS = ("new", "expanded")
 
 
 def tile_ttft(stage_s: dict, ttft_s: float) -> dict:

@@ -29,7 +29,7 @@ input), from angles computed at the positions asked — no table of
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional
+from typing import ClassVar, Optional
 
 import jax
 import jax.numpy as jnp
@@ -95,6 +95,15 @@ class Cohere2MoeConfig(LlamaConfig):
     experts_per_token: int = 8
     n_shared_experts: int = 4
     gate_fn: str = "sigmoid"
+    # how ``models/moe.py::expert_layer`` picks and weighs, as this family
+    # always does: over all experts at once, gates renormalised over the picks
+    # and not scaled, the shared experts' outputs averaged. Constants of the
+    # family and no fields: a checkpoint's meta and ``/info`` stay as they are
+    n_group: ClassVar[int] = 1
+    topk_group: ClassVar[int] = 1
+    norm_topk_prob: ClassVar[bool] = True
+    routed_scaling_factor: ClassVar[float] = 1.0
+    shared_combine: ClassVar[str] = "mean"
     experts_held: int = 16              # the slice of the routed experts held here ...
     expert_offset: int = 0              # ... starting at this expert
 
@@ -181,11 +190,13 @@ def _pair_swap(head_dim: int) -> np.ndarray:
     return r
 
 
-def rope_interleaved(x: Array, positions: Array, theta: float) -> Array:
+def rope_interleaved(x: Array, positions: Array, theta: float, inv_freq=None) -> Array:
     """x [B, T, H, D] rotated pair by pair — ``(2j, 2j+1)`` by ``pos ·
-    theta^(-2j/D)`` — at ``positions [B, T]``."""
+    theta^(-2j/D)``, or by ``pos · inv_freq[j]`` where a family brings its own
+    frequencies — at ``positions [B, T]``."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float32) / d))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float32) / d))
     angle = positions.astype(jnp.float32)[..., None] * inv_freq            # [B, T, D/2]
     cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)[:, :, None, :]
     sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)[:, :, None, :]

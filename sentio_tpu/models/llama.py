@@ -118,7 +118,10 @@ def _write_cache(cache_layer: Array, kv: Array, index: Array | int) -> Array:
     )(cache_layer, kv, idx)
 
 
-SERVED_AS = {"wq": "wq_t", "wk": "wk_t", "wv": "wv_t"}
+# the three of :func:`qkv_proj`, in its order; then a latent family's query
+# up-projection (``models/deepseek_v2.py``), fused with its head reshape and
+# RoPE the same way
+SERVED_AS = {"wq": "wq_t", "wk": "wk_t", "wv": "wv_t", "wq_b": "wq_b_t"}
 
 
 def serving_layout(params: dict) -> dict:

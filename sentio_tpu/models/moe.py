@@ -244,22 +244,40 @@ def grouped_matmul(lhs: Array, rhs: Array, sizes: Array, rows: int = _GMM_ROWS_M
     return jax.lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=jnp.float32).astype(lhs.dtype)
 
 
+def group_limited(scores: Array, n_group: int, topk_group: int) -> tuple[Array, Array]:
+    """Scores [G, E] over experts that lie in ``n_group`` equal groups → (the
+    scores with every expert outside a token's best ``topk_group`` groups
+    zeroed, those groups [G, topk_group] int32). A group's score is the MAX of
+    its experts' (``group_limited_greedy``): a token's picks then reach at
+    most ``topk_group`` of the devices that hold a group each."""
+    g, e = scores.shape
+    best = scores.reshape(g, n_group, e // n_group).max(-1)
+    _, groups = jax.lax.top_k(best, topk_group)
+    kept = jnp.zeros((g, n_group), bool).at[jnp.arange(g)[:, None], groups].set(True)
+    return jnp.where(jnp.repeat(kept, e // n_group, axis=1), scores, 0.0), groups.astype(jnp.int32)
+
+
 def expert_layer(
     mp: dict, cfg, x: Array, valid: Optional[Array] = None
-) -> tuple[Array, Array, Array]:
+) -> tuple[Array, ...]:
     """The routed-expert layer as ONE chip of a deployment runs it:
     x [B, T, D] → (out [B, T, D], picks [B, T, k] int32, counts [4] int32).
 
     Every token is routed over ALL ``cfg.n_experts`` experts (the router is
     as wide as published): ``cfg.gate_fn`` scores, the ``experts_per_token``
-    largest picked, their scores renormalised over the picks. Of ``Σ w_e
+    largest picked — among all experts, or with ``cfg.n_group`` over one
+    inside the token's best ``cfg.topk_group`` groups (:func:`group_limited`;
+    such a family gets those groups ``[B, T, topk_group]`` as a fourth
+    result). A pick's gate is its score, renormalised over the picks where
+    ``cfg.norm_topk_prob``, times ``cfg.routed_scaling_factor``. Of ``Σ w_e
     F_e(x)`` this computes the part whose expert is HELD here —
     ``mp["w_gate"]``, ``w_up`` ``[experts_held, D, F]`` and ``w_down``
     ``[experts_held, F, D]`` are experts ``expert_offset ..`` — and leaves
     the others' part out: what seven absent chips would add is no part of
     this program, nor is their traffic. With every expert held that IS the
     layer. ``mp["shared"]`` (stacks of ``n_shared_experts`` experts every
-    chip holds) adds their AVERAGE, once.
+    chip holds) adds their AVERAGE or their SUM (``cfg.shared_combine``:
+    ``mean`` / ``sum``), once.
 
     Nothing is dropped at any load: the pairs of token and pick are sorted
     by expert and go through three grouped matmuls whose cost is the pairs
@@ -280,8 +298,14 @@ def expert_layer(
 
     with jax.named_scope("moe.route"):
         logits = L.dense(mp["router"], flat, jnp.float32)           # [G, E] f32
-        top, picks = jax.lax.top_k(routed_scores(logits, cfg.gate_fn), k)
-        gates = top / top.sum(-1, keepdims=True)                    # over the picks
+        scores, groups = routed_scores(logits, cfg.gate_fn), None
+        if cfg.n_group > 1:
+            with jax.named_scope("moe.groups"):
+                scores, groups = group_limited(scores, cfg.n_group, cfg.topk_group)
+        top, picks = jax.lax.top_k(scores, k)
+        gates = top / top.sum(-1, keepdims=True) if cfg.norm_topk_prob else top   # over the picks
+        if cfg.routed_scaling_factor != 1.0:
+            gates = gates * cfg.routed_scaling_factor
         local = picks - cfg.expert_offset
         here = (local >= 0) & (local < held) & ok[:, None]          # [G, k]
         # a pair's group: its expert's index here, or ``held`` for none
@@ -311,11 +335,14 @@ def expert_layer(
             gate = jax.nn.silu(jnp.einsum("gd,sdf->gsf", flat, sp["w_gate"].astype(dt)))
             shared = jnp.einsum("gsf,sfd->gd", gate * up, sp["w_down"].astype(dt),
                                 preferred_element_type=jnp.float32)
-            out = out + shared / cfg.n_shared_experts
+            out = out + (shared / cfg.n_shared_experts if cfg.shared_combine == "mean" else shared)
 
     counts = jnp.stack([ok.sum() * k, n_here, jnp.asarray(held, jnp.int32),
                         (sizes > 0).sum()]).astype(jnp.int32)
-    return out.astype(dt).reshape(b, t, d), picks.reshape(b, t, k).astype(jnp.int32), counts
+    result = (out.astype(dt).reshape(b, t, d), picks.reshape(b, t, k).astype(jnp.int32), counts)
+    if groups is not None:   # a second thing the family decides by rank
+        result += (groups.reshape(b, t, -1),)
+    return result
 
 
 def moe_forward(

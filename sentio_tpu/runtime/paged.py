@@ -60,7 +60,7 @@ from sentio_tpu.analysis.audit.registry import jit_family
 from sentio_tpu.analysis.sanitizer import check_engine_invariants, engine_guard
 from sentio_tpu.infra import faults
 from sentio_tpu.infra.phases import (
-    ENGINE_PHASES, KV_PAGE_KINDS, MOE_KINDS, ROW_STEP_KINDS, PhaseTimer,
+    ENGINE_PHASES, KV_PAGE_KINDS, MOE_KINDS, PREFILL_LATENT_KINDS, ROW_STEP_KINDS, PhaseTimer,
 )
 from sentio_tpu.infra.tracing import annotation
 from sentio_tpu.models.llama import LlamaConfig, qkv_proj, serving_layout
@@ -81,6 +81,11 @@ class PagedPool:
     kernels/paged_attention.py). The pytree form
     rides through every jit signature, scan carry, and donation unchanged;
     only the read/write helpers below understand the representation.
+    A LATENT family (``models/deepseek_v2.py``: one vector a token and layer
+    in place of keys and values) has ONE pool, ``k`` ``[L, P, latent_dim,
+    page]`` — a page lies latent-major, its positions the lanes of a tile
+    (``kernels/latent_attention.py`` says why) — and ``v`` is None: an empty
+    pytree, which rides every signature, carry and donation as it is.
     Page id 0 = scratch."""
 
     k: Array
@@ -169,6 +174,32 @@ def _gather_pages(pages, layer, page_table, dtype):
     return kc.reshape(b, nb * kc.shape[2], *kc.shape[3:])
 
 
+def is_latent(cfg) -> bool:
+    """Whether ``cfg``'s family keeps a latent in place of K and V."""
+    return getattr(cfg, "kv_lora_rank", 0) > 0
+
+
+def _latent_tokens(pages, index):
+    """Latent pages ``pages[index]`` ``[..., NB, latent_dim, page]`` as the
+    tokens they hold, ``[..., NB * page, latent_dim]``."""
+    dense = pages[index].swapaxes(-1, -2)
+    return dense.reshape(*dense.shape[:-3], dense.shape[-3] * dense.shape[-2], dense.shape[-1])
+
+
+def _latent_write(pages, layer, page_ids, offsets, latents):
+    """Write latents [B, latent_dim] at (layer, page_ids[b], :, offsets[b]) a
+    row: one COLUMN of a latent-major page each, as one in-place slice update
+    a row. (Asked to scatter the columns in one operation, the TPU compiler
+    turns the WHOLE pool latent-minor for the scatter and back for the
+    kernel, every layer of every sub-step: tests/test_chip_compile.py.)"""
+    import jax
+
+    for b in range(latents.shape[0]):
+        pages = jax.lax.dynamic_update_slice(
+            pages, latents[b][None, None, :, None], (layer, page_ids[b], 0, offsets[b]))
+    return pages
+
+
 def init_pool(
     cfg: LlamaConfig, num_pages: int, page_size: int, mesh=None,
     quantized: bool = False,
@@ -179,6 +210,13 @@ def init_pool(
     ``quantized`` the pool stores int8 + per-vector scales — ~half the HBM
     and half the decode-attention read bandwidth of bf16 pages."""
     import jax.numpy as jnp
+
+    if is_latent(cfg):
+        # refused by name where the engine is built; here for every other caller
+        if quantized or mesh is not None:
+            raise ValueError("a latent pool is bf16 on one device: int8 latents and a mesh have no rules yet")
+        pages = jnp.zeros((cfg.n_layers, num_pages, cfg.latent_dim, page_size), cfg.jdtype)
+        return PagedPool(k=pages, v=None, page_size=page_size)
 
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
 
@@ -282,9 +320,12 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
 
     A family whose block is PARALLEL (``models/cohere2_moe.py``: one norm
     feeding attention and routed experts side by side, a kind per layer)
-    takes its own walk of the layers, below; ``return_routed`` then adds
-    what its expert layers decided (``{"experts": [L, B, k] picks,
-    "counts": [4]}``) as a fourth result, None for every other family.
+    takes its own walk of the layers, below, and so does a LATENT family
+    (``models/deepseek_v2.py``: sequential, absorbed attention over latent
+    pages; ``v_pages`` is None and comes back None); ``return_routed`` then
+    adds what its expert layers decided (``{"experts": [L, B, k] picks,
+    "counts": [4]}``, a latent family's ``"groups"`` beside them) as a
+    fourth result, None for every other family.
     """
     import jax
     import jax.numpy as jnp
@@ -294,6 +335,9 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
     if getattr(cfg, "parallel_block", False):
         out = _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
                                      attn_impl, write_mask)
+        return out if return_routed else out[:3]
+    if is_latent(cfg):
+        out = _paged_decode_latent(params, cfg, tok, lens, page_table, k_pages, attn_impl, write_mask)
         return out if return_routed else out[:3]
 
     dt = cfg.jdtype
@@ -405,14 +449,90 @@ def _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
     return logits, k_pages, v_pages, {"experts": jnp.stack(picks), "counts": counts}
 
 
+def _latent_attn_xla(q_lat, q_pe, pages, layer, page_table, lens, sm_scale):
+    """Absorbed decode attention over a page table, XLA gather path: each
+    row's latent pages of that layer gathered into ``[B, NB*page,
+    latent_dim]`` (``kernels/latent_attention.py`` walks the table in VMEM
+    instead and is what runs on a TPU). q_lat [B, H, r], q_pe [B, H, rope] →
+    o_lat [B, H, r]."""
+    import jax.numpy as jnp
+
+    from sentio_tpu.models.deepseek_v2 import latent_attention
+
+    latents = _latent_tokens(pages, (layer, page_table))
+    seen = jnp.arange(latents.shape[1])[None, :] <= lens[:, None]  # new token sits at index lens
+    return latent_attention(q_lat, q_pe, latents, seen, sm_scale)
+
+
+def _paged_decode_latent(params, cfg, tok, lens, page_table, pages, attn_impl, write_mask):
+    """:func:`paged_decode_forward` for the latent family of
+    ``models/deepseek_v2.py``: sequential pre-norm blocks; the step's latent
+    (``c_kv | k_pe``, normed and rotated) written into each row's current
+    page, the query ABSORBED (``W_uk`` into the query, ``W_uv`` into the
+    output) so that attention reads the latents themselves and no key or
+    value is ever formed; a dense MLP in the leading layers, the routed
+    layer's share in the others. → (logits [B, V], pages, None, routed)."""
+    import jax
+    import jax.numpy as jnp
+
+    from sentio_tpu.models import deepseek_v2 as M
+    from sentio_tpu.models import layers as L
+
+    dt = cfg.jdtype
+    b = tok.shape[0]
+    page = pages.shape[-1]
+    positions = lens[:, None]
+    page_ids = jnp.take_along_axis(page_table, (lens // page)[:, None], axis=1)[:, 0]
+    offsets = lens % page
+    attn_lens, valid = lens, None
+    if write_mask is not None:  # as in the sequential block, above
+        page_ids = jnp.where(write_mask, page_ids, 0)
+        offsets = jnp.where(write_mask, offsets, 0)
+        attn_lens = jnp.where(write_mask, lens, 0)
+        valid = write_mask[:, None]
+    impl = attn_impl or _latent_attn_xla
+
+    x = L.embed(params["embed_tokens"], tok[:, None], dt)
+    picks: dict = {"experts": [], "groups": []}
+    counts = jnp.zeros((4,), jnp.int32)
+    for i in range(cfg.n_layers):
+        lp = params[f"layers_{i}"]
+        ap = lp["attn"]
+        h = L.rmsnorm(lp["attn_norm"], x, cfg.norm_eps)
+        q_nope, q_pe = M.mla_query(ap, cfg, h, positions)
+        latent = M.mla_latent(ap, cfg, h, positions)[:, 0, 0]        # [B, latent_dim]
+        pages = _latent_write(pages, i, page_ids, offsets, latent)
+        q_lat = M.absorb_query(ap, cfg, q_nope[:, 0])
+        with jax.named_scope("attn.latent"):
+            o_lat = impl(q_lat, q_pe[:, 0], pages, i, page_table, attn_lens, cfg.softmax_scale)
+        attn = M.unabsorb(ap, cfg, o_lat.astype(dt))
+        x = x + L.dense(ap["wo"], attn.reshape(b, 1, -1), dt)
+        # a row that does not advance is routed nowhere (see the parallel block)
+        out, chosen, n = M.mlp_or_experts(lp, cfg, L.rmsnorm(lp["mlp_norm"], x, cfg.norm_eps), valid)
+        x = x + out
+        if chosen is not None:
+            for name, value in chosen.items():
+                picks[name].append(value[:, 0])
+            counts = counts + n
+    logits = M.head_logits(params, cfg, x)[:, 0]
+    routed = {name: jnp.stack(value) for name, value in picks.items() if value}
+    return logits, pages, None, {**routed, "counts": counts}
+
+
 def scatter_prefill(k_pages, v_pages, k_cache, v_cache, page_table):
     """Copy a contiguous prefill cache into the pool.
 
     k/v_cache [L, B, S, Hkv, D] (S a multiple of page size), page_table
     [B, S/page]. Blocks past a row's prompt length should map to scratch
-    page 0 in the table — their garbage lands there.
+    page 0 in the table — their garbage lands there. A latent pool
+    (``v_pages`` None) takes ``k_cache [L, B, S, 1, latent_dim]`` and turns
+    each page latent-major on the way.
     """
     lcount, b, s, hkv, hd = k_cache.shape
+    if v_pages is None:
+        page = k_pages.shape[-1]
+        r = k_cache.reshape(lcount, b, s // page, page, hd).swapaxes(-1, -2)
+        return k_pages.at[:, page_table].set(r), None
     page = _page_dim(k_pages)
     nb = s // page
 
@@ -551,7 +671,8 @@ class PagedResult:
     admit_t: float = 0.0
     prefill_segments: int = 0
     # only from ``run_all(return_choices=True)`` on a family that chooses:
-    # ``{"experts": int32 [layers, prompt + answer tokens - 1, k]}``, what
+    # ``{"experts": int32 [routed layers, prompt + answer tokens - 1, k]}`` (a
+    # family that picks GROUPS first has ``"groups"`` beside it), what
     # each of THIS request's tokens was routed by at every layer — negative
     # where the radix cache served the position (an earlier request's routing)
     choices: Optional[dict] = None
@@ -625,6 +746,7 @@ class ContinuousBatchingEngine:
         import jax
 
         from sentio_tpu.models.cohere2_moe import Cohere2MoeConfig, cohere2_forward
+        from sentio_tpu.models.deepseek_v2 import DeepseekV2Config, deepseek_v2_forward
         from sentio_tpu.models.llama import llama_forward
         from sentio_tpu.models.moe import MoeConfig, moe_serving_forward
 
@@ -662,13 +784,32 @@ class ContinuousBatchingEngine:
         self.params = params
         # a family whose expert layers hand back what they decided (their
         # picks, and the pairs they routed: ``models/moe.py::expert_layer``)
-        self.routed = isinstance(self.cfg, Cohere2MoeConfig)
+        family_forward = {Cohere2MoeConfig: cohere2_forward,
+                          DeepseekV2Config: deepseek_v2_forward}.get(type(self.cfg))
+        self.routed = family_forward is not None
+        # a family whose pool holds ONE latent a token and layer in place of K and V
+        self.latent = is_latent(self.cfg)
         if self.routed:
-            if forward_fn not in (None, cohere2_forward):
-                raise ValueError("a Cohere2MoeConfig model prefills through cohere2_forward")
+            name = type(self.cfg).__name__
+            if forward_fn not in (None, family_forward):
+                raise ValueError(f"a {name} model prefills through {family_forward.__name__}")
             if draft_params is not None:
-                raise ValueError("paged speculation does not serve a routed family yet")
-            forward_fn = cohere2_forward
+                raise ValueError(f"paged speculation does not serve a routed family ({name}) yet")
+            forward_fn = family_forward
+        if self.latent:
+            if kv_quant != "none":
+                raise ValueError(f"kv_quant={kv_quant!r}: a latent pool ({type(self.cfg).__name__}) is "
+                                 "bf16 — int8 latents have no kernel and no quality gate yet")
+            if mesh is not None:
+                raise ValueError(f"a latent pool ({type(self.cfg).__name__}) is served on one device a "
+                                 "process: a latent has no heads to split over tp")
+        # what a routed family's layers decide by rank, and how deep: a
+        # request's ``choices`` hold one buffer a kind, its routed layers deep
+        self._choice_depths = {}
+        if self.routed:
+            self._choice_depths["experts"] = self.cfg.experts_per_token
+            if getattr(self.cfg, "n_group", 1) > 1:
+                self._choice_depths["groups"] = self.cfg.topk_group
         if forward_fn is None:
             forward_fn = moe_serving_forward if is_moe else llama_forward
         elif forward_fn in (moe_serving_forward, llama_forward):
@@ -807,6 +948,15 @@ class ContinuousBatchingEngine:
         self.moe_total = dict.fromkeys(MOE_KINDS, 0)
         self.last_tick_moe = dict.fromkeys(MOE_KINDS, 0)
         self._moe_acc = None  # the prefill programs' pairs since the last tick, on the device
+        self._moe_zero = None  # four zeros on the device, made at the first dispatch that needs them
+        # a latent family's prefill dispatches, from their own integers: tokens
+        # a call computed (``new``) and prior tokens whose latents that call
+        # turned back into keys and values (``expanded``: every segment after
+        # the first, and every radix hit, expands its whole prior). Counted at
+        # dispatch, booked with the next harvested tick beside the row-steps
+        self.prefill_latent_total = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
+        self.last_tick_prefill_latent = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
+        self._prefill_latent_pending = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         # ``run_all(return_choices=True)``: each result carries the picks its
         # OWN tokens were routed by (fetched when asked for, never otherwise)
         self.keep_choices = False
@@ -900,12 +1050,14 @@ class ContinuousBatchingEngine:
         if use_pallas is None:
             use_pallas = jax.default_backend() == "tpu"
         if use_pallas and jax.default_backend() == "tpu":
+            from sentio_tpu.kernels.latent_attention import latent_untiled
             from sentio_tpu.kernels.paged_attention import untiled
             from sentio_tpu.parallel.mesh import AXIS_TP
 
             tp = mesh.shape[AXIS_TP] if mesh is not None else 1
-            why = untiled(page_size, self.cfg.n_kv_heads // tp, self.cfg.head_dim,
-                          kv_quant == "int8")
+            why = latent_untiled(page_size, self.cfg.kv_lora_rank, self.cfg.qk_rope_head_dim) \
+                if self.latent else untiled(page_size, self.cfg.n_kv_heads // tp, self.cfg.head_dim,
+                                            kv_quant == "int8")
             if why and asked:
                 raise ValueError(f"use_pallas=True, but {why}")
             if why:
@@ -914,7 +1066,11 @@ class ContinuousBatchingEngine:
                     "paged kernel: %s", why)
                 use_pallas = False
         self._attn_impl = None
-        if use_pallas:
+        if use_pallas and self.latent:
+            from sentio_tpu.kernels.latent_attention import make_latent_attn_impl
+
+            self._attn_impl = make_latent_attn_impl()
+        elif use_pallas:
             from sentio_tpu.kernels.paged_attention import make_paged_attn_impl
 
             self._attn_impl = make_paged_attn_impl(mesh=mesh)
@@ -964,10 +1120,13 @@ class ContinuousBatchingEngine:
             programs routed since the last tick) carries its expert layers'
             counts through the scan, returns them as four more rows of
             ``packed`` and adds one result: every sub-step's picks
-            ``{"experts": [steps, L, B, k]}``, which stay on the device
-            unless a caller asked for them.
+            ``{"experts": [steps, L, B, k]}`` (one entry a kind of choice),
+            which stay on the device unless a caller asked for them.
             """
             from sentio_tpu.runtime.sampling import sample_tokens
+
+            def picks_of(moe):
+                return {name: moe[name] for name in moe if name != "counts"}
 
             def body(carry, idx):
                 (tok, lens, k_pages, v_pages, rng, halted,
@@ -991,7 +1150,7 @@ class ContinuousBatchingEngine:
                     halted = halted | (active & (nxt == eos_id))
                 if routed:
                     return (tok, lens, k_pages, v_pages, rng, halted, lp_sum, lp_min,
-                            lp_cnt, moe_n[0] + moe[0]["counts"]), (nxt, moe[0]["experts"])
+                            lp_cnt, moe_n[0] + moe[0]["counts"]), (nxt, picks_of(moe[0]))
                 return (tok, lens, k_pages, v_pages, rng, halted,
                         lp_sum, lp_min, lp_cnt), nxt
 
@@ -1023,7 +1182,7 @@ class ContinuousBatchingEngine:
             )
             out = (packed, lp_state, tok, lens, halted,
                    lp_sum, lp_min, lp_cnt, k_pages, v_pages, rng)
-            return (*out, {"experts": picks}) if routed else out
+            return (*out, picks) if routed else out
 
         self._step_n = step_n
 
@@ -1054,11 +1213,10 @@ class ContinuousBatchingEngine:
             row's last prompt logit. Pad rows scatter to scratch page 0. A
             routed family returns one more: ``{"experts": [L, B, width, k]
             picks, "counts": moe_acc + the pairs this call routed}``."""
-            from sentio_tpu.models.llama import init_cache
             from sentio_tpu.runtime.sampling import sample_tokens
 
             b, width = ids.shape
-            cache = init_cache(cfg, b, width)
+            cache = new_cache(b, width)
             # pad tails and junk admission rows must not claim routed-expert
             # capacity (llama ignores the mask on the cache path)
             pad_mask = jnp.arange(width)[None, :] < lens[:, None]
@@ -1077,8 +1235,19 @@ class ContinuousBatchingEngine:
 
         def prefill_routed(moe, moe_acc):
             # a prefill's pairs are counted; expert-steps are the decode tick's
-            return {"experts": moe["experts"],
-                    "counts": moe_acc + moe["counts"] * jnp.asarray([1, 1, 0, 0], jnp.int32)}
+            return {**moe, "counts": moe_acc + moe["counts"] * jnp.asarray([1, 1, 0, 0], jnp.int32)}
+
+        latent = self.latent
+
+        def new_cache(rows, length):
+            """The contiguous cache a prefill fills: K and V, or latents alone."""
+            if latent:
+                from sentio_tpu.models.deepseek_v2 import init_latent_cache
+
+                return init_latent_cache(cfg, rows, length)
+            from sentio_tpu.models.llama import init_cache
+
+            return init_cache(cfg, rows, length)
 
         self._prefill_scatter = prefill_scatter
 
@@ -1106,16 +1275,25 @@ class ContinuousBatchingEngine:
             program per (prior, width) pair. The first token samples only
             when ``do_sample`` (chunked prefill's non-final segments pass
             False), keeping the rng stream identical to whole-prompt
-            admission."""
-            from sentio_tpu.models.llama import init_cache
+            admission.
+
+            A LATENT family's prior is latents: they are primed as they lie
+            and the forward EXPANDS them, with the segment's own, to keys and
+            values in every layer (``models/deepseek_v2.py``; PERF.md has the
+            timing of this against attending absorbed over the prior)."""
             from sentio_tpu.runtime.sampling import sample_tokens
 
             b, width = ids.shape
             pnb = prior_table.shape[1]
             prior_w = pnb * page_size
-            cache = init_cache(cfg, b, prior_w + width)
+            cache = new_cache(b, prior_w + width)
             if pnb:
                 def prime(cache_arr, pages):
+                    if pages is None:
+                        return None
+                    if latent:  # [L, B, PNB, latent_dim, page] → tokens
+                        return cache_arr.at[:, :, :prior_w, 0].set(
+                            _latent_tokens(pages, (slice(None), prior_table)))
                     if isinstance(pages, dict):
                         dense = dequantize_pages(
                             pages["q"][:, prior_table],
@@ -1142,10 +1320,11 @@ class ContinuousBatchingEngine:
                     arr, (0, start, 0, 0),
                     (arr.shape[0], width, arr.shape[2], arr.shape[3]))
 
-            k_new = jax.vmap(row_window, in_axes=(1, 0), out_axes=1)(
-                cache["k"], n_prior)
-            v_new = jax.vmap(row_window, in_axes=(1, 0), out_axes=1)(
-                cache["v"], n_prior)
+            def new_rows(arr):
+                return None if arr is None else jax.vmap(
+                    row_window, in_axes=(1, 0), out_axes=1)(arr, n_prior)
+
+            k_new, v_new = new_rows(cache["k"]), new_rows(cache["v"])
             k_pages, v_pages = scatter_prefill(k_pages, v_pages, k_new, v_new, scat)
             if do_sample:
                 last = jnp.take_along_axis(
@@ -1362,6 +1541,7 @@ class ContinuousBatchingEngine:
         self._pending_first.clear()
         self._dev_state = None
         self._moe_acc = None
+        self._prefill_latent_pending = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         if self._inflight is not None:
             # dispatched, never harvested: the device ran these row-steps
             # and nothing of them was delivered
@@ -1480,6 +1660,7 @@ class ContinuousBatchingEngine:
         self.last_tick_row_steps = dict.fromkeys(ROW_STEP_KINDS, 0)
         self.last_tick_kv_pages = dict.fromkeys(KV_PAGE_KINDS, 0)
         self.last_tick_moe = dict.fromkeys(MOE_KINDS, 0)
+        self.last_tick_prefill_latent = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         self.last_tick_sub_steps = 0
         # chaos-drill injection point: a raised fault propagates exactly like
         # a real failed device dispatch (the serving pump resets + requeues)
@@ -1787,9 +1968,10 @@ class ContinuousBatchingEngine:
             slot.prefill_todo = list(tok_ids[shared:]) if chunked else None
             slot.prefill_done = 0
             slot.choice_parts = []
-            slot.choices = {"experts": np.full(
-                (self.cfg.n_layers, self.max_pages_per_seq * self.page_size,
-                 self.cfg.experts_per_token), -1, np.int32)} if self.keep_choices else None
+            slot.choices = {name: np.full(
+                (getattr(self.cfg, "n_routed_layers", self.cfg.n_layers),
+                 self.max_pages_per_seq * self.page_size, depth), -1, np.int32)
+                for name, depth in self._choice_depths.items()} if self.keep_choices else None
             slot.active = True
             shared_blocks = shared // self.page_size
             row = np.zeros(self.max_pages_per_seq, np.int32)
@@ -1969,18 +2151,33 @@ class ContinuousBatchingEngine:
         if not self.routed:
             return fn(*args, **static), None
         *out, moe = fn(*args, moe_acc=self._take_moe_acc(), **static)
-        self._moe_acc = moe["counts"]
-        return out, moe["experts"] if self.keep_choices else None
+        self._moe_acc = moe.pop("counts")
+        return out, moe if self.keep_choices else None
 
     def _take_moe_acc(self):
         """The pairs the prefill programs routed since the last tick, still
-        on the device ([4] int32; zeros where none ran), handed on once."""
+        on the device ([4] int32; zeros where none ran), handed on once. The
+        zeros lie on the device too, made once: a host array here and a
+        device array there are two entries of a program's cache — counted as
+        two compilations, the second of which may fall inside a measured
+        window when every admission is chunked (PERF.md section 6, PR 38)."""
         acc, self._moe_acc = self._moe_acc, None
-        return np.zeros(4, np.int32) if acc is None else acc
+        if acc is None:
+            if self._moe_zero is None:
+                import jax.numpy as jnp
+
+                self._moe_zero = jnp.zeros(4, jnp.int32)
+            acc = self._moe_zero
+        return acc
 
     def _note_prefill_picks(self, picks, rows) -> None:
         """``rows``: (slot, first position, tokens) of each row of a prefill
-        dispatch whose ``picks [L, rows, width, k]`` are still on the device."""
+        dispatch whose ``picks {kind: [L, rows, width, k]}`` are still on the
+        device. A latent family's dispatch is counted here too: the tokens it
+        computed, and the prior tokens (its rows' first positions) it expanded."""
+        if self.latent:
+            self._prefill_latent_pending["new"] += sum(n for _i, _start, n in rows)
+            self._prefill_latent_pending["expanded"] += sum(start for _i, start, _n in rows)
         if picks is None:
             return
         for r, (slot_idx, start, n) in enumerate(rows):
@@ -2244,8 +2441,9 @@ class ContinuousBatchingEngine:
         if self.routed and not spec:  # the expert layers' counts: the last four rows
             record["moe"] = dict(zip(MOE_KINDS, (int(n) for n in packed[-4:, 0])))
             packed = packed[:-4]
-        # [steps, L, B, k], fetched only where a caller asked for picks
-        picks = None if record.get("picks") is None else np.asarray(record["picks"]["experts"])
+        # {kind: [steps, L, B, k]}, fetched only where a caller asked for picks
+        picks = None if record.get("picks") is None else {
+            name: np.asarray(value) for name, value in record["picks"].items()}
         # the tick's final logprob accumulators ([3, B]: sum / min / count),
         # one fetch riding the same dispatch as the packed tokens; refreshed
         # into the host mirrors so a retire inside this harvest reports the
@@ -2291,7 +2489,8 @@ class ContinuousBatchingEngine:
             for s in range(n):
                 if picks is not None and slot.choices is not None:
                     # sub-step s fed the token at position ``length``
-                    slot.choices["experts"][:, slot.length] = picks[s, :, i]
+                    for name, value in picks.items():
+                        slot.choices[name][:, slot.length] = value[s, :, i]
                 slot.length += 1
                 self._lens[i] = slot.length
                 self._last_tok[i] = int(toks[s])
@@ -2320,6 +2519,10 @@ class ContinuousBatchingEngine:
         for kind, n in (record.get("moe") or {}).items():
             self.moe_total[kind] += n
             self.last_tick_moe[kind] += n
+        for kind, n in self._prefill_latent_pending.items():
+            self.prefill_latent_total[kind] += n
+            self.last_tick_prefill_latent[kind] += n
+            self._prefill_latent_pending[kind] = 0
 
     def _kv_pages(self, budgets, steps: int) -> dict:
         """K/V page blocks of the ``steps`` sub-steps being dispatched, by
@@ -2377,11 +2580,11 @@ class ContinuousBatchingEngine:
         positions the radix cache served stay -1."""
         if slot.choices is None:
             return None
-        buf = slot.choices["experts"]
+        fed = max(slot.prompt_tokens + len(slot.emitted) - 1, 0)  # the last sampled token never is
         for picks, row, start, n in slot.choice_parts:
-            buf[:, start:start + n] = np.asarray(picks[:, row, :n])
-        # every position that was fed: the last sampled token never is
-        return {"experts": buf[:, : max(slot.prompt_tokens + len(slot.emitted) - 1, 0)].copy()}
+            for name, buf in slot.choices.items():
+                buf[:, start:start + n] = np.asarray(picks[name][:, row, :n])
+        return {name: buf[:, :fed].copy() for name, buf in slot.choices.items()}
 
     def _retire(self, i: int, reason: str) -> PagedResult:
         """Free a slot's pages (minus any donated to the radix cache), drop
@@ -2453,6 +2656,10 @@ class ContinuousBatchingEngine:
         }
         if self.routed:
             out.update({f"moe_{kind}": n for kind, n in self.moe_total.items()})
+        if self.latent:
+            # what ONE token leaves in the pool a layer (bf16)
+            out["pool_token_layer_bytes"] = self.cfg.latent_dim * np.dtype(self.pool.k.dtype).itemsize
+            out.update({f"prefill_latent_{kind}": n for kind, n in self.prefill_latent_total.items()})
         if self._radix is not None:
             hit, miss = self.prefix_hit_tokens_total, self.prefix_miss_tokens_total
             out["prefix_hits"] = self.prefix_hits

@@ -1218,7 +1218,7 @@ class PagedGenerationService:
                         )
                         metrics.record_tick_phases(phase_s)
                         metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
-                                                 row_steps["moe_pairs"])
+                                                 row_steps["moe_pairs"], row_steps["prefill_latent"])
                         for key, val in phase_s.items():
                             self._phase_totals[key] = (
                                 self._phase_totals.get(key, 0.0) + val
@@ -1385,7 +1385,7 @@ class PagedGenerationService:
                     last_miss_toks = engine.prefix_miss_tokens_total
                     metrics.record_tick(tick_dur_s, int(active), queued + inbox)
                     metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
-                                                 row_steps["moe_pairs"])
+                                                 row_steps["moe_pairs"], row_steps["prefill_latent"])
                 except Exception:  # noqa: BLE001
                     logger.debug("tick telemetry failed", exc_info=True)
                 t_deliver_start = time.perf_counter()
@@ -1505,7 +1505,9 @@ class PagedGenerationService:
                 "row_steps": dict(self.engine.last_tick_row_steps),
                 "kv_pages": dict(self.engine.last_tick_kv_pages),
                 # a routed family's expert layers (zeros for any other)
-                "moe_pairs": dict(getattr(self.engine, "last_tick_moe", None) or {})}
+                "moe_pairs": dict(getattr(self.engine, "last_tick_moe", None) or {}),
+                # a latent family's prefill tokens, new and expanded (zeros for any other)
+                "prefill_latent": dict(getattr(self.engine, "last_tick_prefill_latent", None) or {})}
 
     def _note_ttft_locked(self, ttft_s: float) -> None:  # lock-held: _mutex
         """Fold one observed TTFT into the EMA admission control projects

@@ -33,6 +33,7 @@ _FAMILY_CONFIGS = {
     "llama": ("sentio_tpu.models.llama", "LlamaConfig"),
     "moe": ("sentio_tpu.models.moe", "MoeConfig"),
     "cohere2_moe": ("sentio_tpu.models.cohere2_moe", "Cohere2MoeConfig"),
+    "deepseek_v2": ("sentio_tpu.models.deepseek_v2", "DeepseekV2Config"),
     "encoder": ("sentio_tpu.models.transformer", "EncoderConfig"),
     "cross-encoder": ("sentio_tpu.models.transformer", "EncoderConfig"),
 }
@@ -122,7 +123,7 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
     """Checkpoint or seeded init → which family → placed on the device.
 
     With ``cfg.checkpoint_path`` the weights, their configuration (llama,
-    moe or cohere2_moe, from the checkpoint's meta) and, with ``cfg.tokenizer_path``, the
+    moe, cohere2_moe or deepseek_v2, from the checkpoint's meta) and, with ``cfg.tokenizer_path``, the
     tokenizer come from the checkpoint. Without one the weights are the
     seeded random init of ``model_config``'s family (the deterministic
     fake-model mode of tests and offline development; ``cfg.model_preset``
@@ -138,6 +139,7 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
     import jax
 
     from sentio_tpu.models.cohere2_moe import Cohere2MoeConfig, init_cohere2_moe
+    from sentio_tpu.models.deepseek_v2 import DeepseekV2Config, init_deepseek_v2
     from sentio_tpu.models.llama import LlamaConfig, init_llama, serving_layout
     from sentio_tpu.models.moe import MoeConfig, init_moe
     from sentio_tpu.models.tokenizer import ByteTokenizer
@@ -156,20 +158,23 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
             raise WeightsError(
                 f"checkpoint {cfg.checkpoint_path!r} holds a "
                 f"{type(model_config).__name__} model — the generator "
-                "serves decoder families (llama, moe, cohere2_moe)"
+                "serves decoder families (llama, moe, cohere2_moe, deepseek_v2)"
             )
     if model_config is None:
         preset = cfg.model_preset if cfg is not None else "tiny"
         model_config = (LlamaConfig.tiny() if preset == "tiny"
                         else LlamaConfig.llama3_8b())
     is_moe = isinstance(model_config, MoeConfig)
-    is_cohere2 = isinstance(model_config, Cohere2MoeConfig)
-    if is_cohere2 and mesh is not None:
+    share_init = {Cohere2MoeConfig: init_cohere2_moe,
+                  DeepseekV2Config: init_deepseek_v2}.get(type(model_config))
+    if share_init is not None and mesh is not None:
         # this process IS one chip's share of a layer (``experts_held``); a
         # mesh that splits it again has no rules yet
-        raise WeightsError("a cohere2_moe model is served on one device a process")
+        family = next(k for k, (_m, cls) in _FAMILY_CONFIGS.items()
+                      if cls == type(model_config).__name__)
+        raise WeightsError(f"a {family} model is served on one device a process")
     if params is None:
-        init = init_cohere2_moe if is_cohere2 else init_moe if is_moe else init_llama
+        init = share_init or (init_moe if is_moe else init_llama)
         params = init(jax.random.PRNGKey(rng_seed), model_config)
     # q, k and v in the order the serving programs read them, made before
     # placement: a checkpoint's leaves are turned on the host and no second

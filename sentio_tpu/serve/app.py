@@ -288,8 +288,11 @@ async def ui_page(request: web.Request) -> web.Response:
     )
 
 
-def _resolve_deadline_ts(request: web.Request, req, serve_cfg) -> Optional[float]:
-    """Absolute perf_counter deadline for this request: body ``deadline_ms``
+def _resolve_deadline_ts(request: web.Request, req, serve_cfg, t_received: float) -> Optional[float]:
+    """Absolute perf_counter deadline for this request, counted from its
+    RECEIPT (the time its body took to arrive and parse is the caller's, and a
+    record's ``deadline_ms`` is then the budget as given, whatever the host's
+    speed): body ``deadline_ms``
     beats the ``X-Deadline-Ms`` header beats the serve default (0 = none).
     A malformed header is ignored rather than 422'd — proxies inject headers
     the caller never wrote."""
@@ -307,7 +310,7 @@ def _resolve_deadline_ts(request: web.Request, req, serve_cfg) -> Optional[float
         deadline_ms = serve_cfg.default_deadline_ms
     if deadline_ms is None:
         return None
-    return time.perf_counter() + deadline_ms / 1e3
+    return t_received + deadline_ms / 1e3
 
 
 def _resolve_resumable(request: web.Request, req) -> bool:
@@ -363,7 +366,7 @@ async def chat(request: web.Request) -> web.Response:
     container: DependencyContainer = request.app["container"]
     body = await _json_body(request)
     req = parse_chat_request(body, container.settings.serve)
-    deadline_ts = _resolve_deadline_ts(request, req, container.settings.serve)
+    deadline_ts = _resolve_deadline_ts(request, req, container.settings.serve, t_received)
     tenant, priority = _request_tenant(request)
     if req.stream:
         # shed BEFORE response.prepare commits the 200 status line: an SSE
@@ -736,6 +739,10 @@ async def info(request: web.Request) -> web.Response:
                 "kv_quant": serving.get("kv_quant"),
                 "paged_attention": serving.get("paged_attention"),
                 "pool_hbm_bytes": serving.get("pool_hbm_bytes"),
+                # a latent family only: what ONE token leaves in the pool a
+                # layer (1,152 B at 512 + 64 in bf16)
+                **({"pool_token_layer_bytes": serving["pool_token_layer_bytes"]}
+                   if "pool_token_layer_bytes" in serving else {}),
                 # a configured draft accelerates the decode tick
                 # (runtime/paged_spec.py); its exclusions (chunked prefill,
                 # device mesh) are surfaced here for operators

@@ -29,7 +29,7 @@ from sentio_tpu.kernels.paged_attention import (
 from sentio_tpu.models.llama import LlamaConfig, init_llama, llama_forward, serving_layout
 from sentio_tpu.parallel.mesh import MESH_AXES
 from sentio_tpu.parallel.sharding import LLAMA_TP_RULES, make_param_shardings
-from sentio_tpu.runtime.paged import paged_decode_forward, scatter_prefill
+from sentio_tpu.runtime.paged import _latent_tokens, paged_decode_forward, scatter_prefill
 
 H, HKV, D = 32, 8, 128  # LlamaConfig.llama3_8b: 32 query / 8 KV heads of 128
 LAYERS = 2  # of the pool: the kernel takes it whole and a layer's index
@@ -509,3 +509,100 @@ def test_commanda_decode_step_holds_its_kernels(commanda_programs):
     cfg, _, texts = commanda_programs
     assert texts["step"].count('custom_call_target="tpu_custom_call"') == LAYERS * 4
     assert len(re.findall(r"%gmm[.\d]* = ", texts["step"])) == LAYERS * 3
+
+
+# ------------------------------------------------- a family with a latent pool
+#
+# ``deepseek_v2`` (models/deepseek_v2.py) at the widths of the benchmark's
+# ``deepseek-v2-ep8-l8``: a dense and a routed layer, 128 heads over ONE
+# 576-wide latent a position (the pool latent-major, ``[L, P, 576, page]``),
+# absorbed decode through ``kernels/latent_attention.py``, expanded prefill
+# over a primed latent cache of 40 pages, 20 held experts of 5120 x 1536 in
+# 8 groups behind the megablox grouped matmul.
+
+
+@pytest.fixture(scope="module")
+def deepseek_programs(v5e):
+    from sentio_tpu.kernels.latent_attention import make_latent_attn_impl
+    from sentio_tpu.models import moe
+    from sentio_tpu.models.deepseek_v2 import DeepseekV2Config, deepseek_v2_forward, init_deepseek_v2
+
+    cfg = DeepseekV2Config(n_layers=LAYERS, vocab_size=12_800)
+    place = _on_one_chip(v5e)
+    params = jax.eval_shape(lambda: serving_layout(init_deepseek_v2(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree_util.tree_map(
+        lambda a: place(a.shape, jnp.bfloat16 if a.ndim >= 2 else a.dtype), params)
+    slots, nb, page, segment = 8, 40, 128, 512
+    pool = place((LAYERS, 1 + slots * nb, cfg.latent_dim, page), jnp.bfloat16)
+    impl = make_latent_attn_impl(interpret=False)
+
+    def step(params, tok, lens, table, pages):
+        def body(carry, _):
+            tok, lens, pages = carry
+            logits, pages, _none, routed = paged_decode_forward(
+                params, cfg, tok, lens, table, pages, None, attn_impl=impl,
+                write_mask=lens < nb * page - 1, return_routed=True)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1, pages), routed["experts"]
+
+        return jax.lax.scan(body, (tok, lens, pages), None, length=2)
+
+    def prefill(params, ids, positions, pages, prior_table, n_prior, scat):
+        # a segment as ``paged.prior_prefill_scatter`` runs it: the prior's latents primed from
+        # the pool, the forward (which expands them), the segment's own scattered back
+        cache = jnp.zeros((LAYERS, 1, nb * page + segment, 1, cfg.latent_dim), jnp.bfloat16)
+        cache = cache.at[:, :, : nb * page, 0].set(_latent_tokens(pages, (slice(None), prior_table)))
+        logits, cache, routed = deepseek_v2_forward(params, cfg, ids, positions=positions,
+                                                    cache={"k": cache, "v": None}, cache_index=n_prior)
+        new = jax.lax.dynamic_slice_in_dim(cache["k"], n_prior[0], segment, axis=2)
+        return logits[:, -1], scatter_prefill(pages, None, new, None, scat)[0], routed["counts"]
+
+    was, moe.grouped_matmul = moe.grouped_matmul, moe.expert_matmul   # as the commanda fixture does
+    try:
+        compiled = {
+            "step": jax.jit(step, donate_argnums=(4,)).lower(
+                params, place((slots,), jnp.int32), place((slots,), jnp.int32),
+                place((slots, nb), jnp.int32), pool).compile(),
+            "prefill": jax.jit(prefill, donate_argnums=(3,)).lower(
+                params, place((1, segment), jnp.int32), place((1, segment), jnp.int32), pool,
+                place((1, nb), jnp.int32), place((1,), jnp.int32), place((1, segment // page), jnp.int32)).compile(),
+        }
+    finally:
+        moe.grouped_matmul = was
+    return cfg, params, {k: c.as_text() for k, c in compiled.items()}, \
+        {k: c.memory_analysis() for k, c in compiled.items()}
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_deepseek_programs_read_their_weights_where_they_lie(deepseek_programs, program):
+    """The v5e compiler takes both programs (Mosaic: the latent walk's
+    latent-major pages and 128 heads in VMEM; the grouped matmul at a
+    contraction of 5120: a tile of 4096 and a masked rest), and nothing in them makes an
+    array with the shape of a projection, of a half of ``kv_b_proj``, of the
+    head, or of a stack of experts."""
+    cfg, params, texts, _ = deepseek_programs
+    assert params["layers_0"]["attn"]["w_uk"].shape == (128, 128, 512) and "moe" not in params["layers_0"]
+    assert params["layers_0"]["attn"]["wq_b_t"]["kernel"].shape == (128 * 192, 1536)
+    assert _weight_copies(texts[program], params) == []
+    stacks = (f"bf16[{cfg.experts_held},{cfg.dim},{cfg.moe_mlp_dim}]", f"bf16[{cfg.experts_held},{cfg.moe_mlp_dim},{cfg.dim}]",
+              "bf16[128,128,512]")
+    # a view, or the compiler's own prefetch of a 16 MB half of ``kv_b_proj`` into nearer memory
+    # (slices joined by a ConcatBitcast custom call): neither is a relayout
+    assert [m for m in _pool_shaped(texts[program], stacks)
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "fusion:bitcast", "custom-call")] == []
+
+
+def test_deepseek_decode_step_holds_its_kernels_and_its_pool(deepseek_programs):
+    """The decode step: one walk of the latent pages a layer and three grouped
+    expert matmuls in the routed layer, each a Pallas call; the pool — one
+    array, 1,152 B a token a layer — updated in place, never copied."""
+    cfg, _, texts, memory = deepseek_programs
+    assert texts["step"].count('custom_call_target="tpu_custom_call"') == LAYERS + 3
+    assert len(re.findall(r"%latent_attention[.\d]* = ", texts["step"])) == LAYERS
+    assert len(re.findall(r"%gmm[.\d]* = ", texts["step"])) == 3
+    pool = f"bf16[{LAYERS},321,{cfg.latent_dim},128]"
+    made = [m for m in _pool_shaped(texts["step"], (pool,))
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "while", "tuple", "custom-call")]
+    assert all(what in ("fusion:scatter", "fusion:dynamic-update-slice", "scatter", "dynamic-update-slice")
+               for _n, _s, what in made), made
+    # beside arguments it donates, the step needs little: no pool-sized temporary
+    assert memory["step"].temp_size_in_bytes < 2 * LAYERS * 321 * 128 * 1152
