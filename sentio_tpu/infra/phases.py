@@ -58,9 +58,11 @@ bounded like ``TICK_PHASES``; each stage is written where its work happens
 ``sentio_tpu_request_stage_seconds{stage}``:
 
 ``pool_wait``
-    ``/chat`` received → the request's pipeline starts on an executor
-    thread (the server runs as many streams at a time as that pool has
-    threads).
+    ``/chat`` received → the request's pipeline starts on one of the
+    server's request threads. They are as many as the generation service
+    admits (``serve/dependencies.py::RequestThreads``), so this is the hop
+    and no queue: a caller beyond the slots waits in ``inbox_wait`` and
+    ``slot_wait``.
 ``embed``
     The dense retrieval leg: query embedding dispatched → its top-k on the
     host (on the fused path the query vector never visits the host; the one

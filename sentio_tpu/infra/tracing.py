@@ -12,7 +12,8 @@ exporter, no flag, no second clock.
 The request id and the enclosing span travel in a context variable, so a
 stage written where the work happens (``ops/embedder.py``) needs no
 ``request_id`` parameter threaded through every layer above it. A thread
-hop must carry the context (``asyncio.to_thread`` does); where it does not,
+hop must carry the context (``asyncio.to_thread`` and the server's request
+threads do); where it does not,
 pass ``request_id`` and ``parent`` explicitly.
 
 Names in :data:`sentio_tpu.infra.phases.REQUEST_STAGES` are request stages;

@@ -165,8 +165,8 @@ class _SyncPending(Generic[T, R]):
 class ThreadBatcher(Generic[T, R]):
     """Cross-THREAD deadline coalescer — the sync sibling of :class:`Batcher`.
 
-    The serving pipeline runs synchronously on worker threads
-    (``asyncio.to_thread`` per request, serve/handlers.py), so coalescing
+    The serving pipeline runs synchronously on the server's request threads
+    (one per request, ``DependencyContainer.request_threads``), so coalescing
     concurrent query embeddings / rerank scores into one padded device batch
     must happen below the event loop. ``submit`` blocks the calling thread
     until its result is ready; a single daemon dispatcher thread collects

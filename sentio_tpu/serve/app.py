@@ -368,28 +368,31 @@ async def chat(request: web.Request) -> web.Response:
     req = parse_chat_request(body, container.settings.serve)
     deadline_ts = _resolve_deadline_ts(request, req, container.settings.serve, t_received)
     tenant, priority = _request_tenant(request)
+    # shed BEFORE any work is spent on the request and, for a stream, before
+    # response.prepare commits the 200 status line (an SSE stream can only
+    # degrade after that, never 429/503). The request threads are as many as
+    # the service admits, so a caller the engine would refuse is told so
+    # here and none is parked in front of it
+    service = container.peek("generation_service")
+    if service is not None and hasattr(service, "check_admission"):
+        try:
+            if getattr(service, "supports_tenants", False):
+                # replica tier: WFQ tenant check + the routed replica's
+                # own admission, exactly as the submit will see them
+                service.check_admission(
+                    deadline_ts, tenant=tenant, priority=priority,
+                    prompt=req.question,
+                )
+            else:
+                service.check_admission(deadline_ts)
+        except SentioError:
+            raise  # typed shed/deadline → 429/503/504 with Retry-After
+        except Exception:  # noqa: BLE001 — closed/broken paged path
+            # this pre-check only turns a typed shed into a status
+            # before the work starts; what else the service raises, the
+            # request itself reports
+            logger.debug("admission pre-check skipped", exc_info=True)
     if req.stream:
-        # shed BEFORE response.prepare commits the 200 status line: an SSE
-        # stream can only degrade after that, never 429/503
-        service = container.peek("generation_service")
-        if service is not None and hasattr(service, "check_admission"):
-            try:
-                if getattr(service, "supports_tenants", False):
-                    # replica tier: WFQ tenant check + the routed replica's
-                    # own admission, exactly as the submit will see them
-                    service.check_admission(
-                        deadline_ts, tenant=tenant, priority=priority,
-                        prompt=req.question,
-                    )
-                else:
-                    service.check_admission(deadline_ts)
-            except SentioError:
-                raise  # typed shed/deadline → 429/503/504 with Retry-After
-            except Exception:  # noqa: BLE001 — closed/broken paged path
-                # this pre-check only turns a typed shed into a status
-                # before the SSE headers go out; what else the service
-                # raises, the stream itself reports
-                logger.debug("stream admission pre-check skipped", exc_info=True)
         return await _chat_stream(request, container, req, deadline_ts,
                                   tenant=tenant, priority=priority,
                                   resumable=_resolve_resumable(request, req),
@@ -415,9 +418,11 @@ async def _chat_stream(request: web.Request, container: DependencyContainer, req
                        resumable: bool = True,
                        t_received: Optional[float] = None) -> web.StreamResponse:
     """SSE token streaming (reference generator.py:298-333 / openai SSE).
-    Retrieval + selection run first (blocking stage on a thread), then the
-    generator's token iterator is pumped from a worker thread into the
-    response via a queue. The flight-record id travels in ``X-Request-Id``
+    The whole pipeline — retrieval, rerank, selection, then the generator's
+    token iterator — runs on ONE of the server's request threads
+    (``DependencyContainer.request_threads``: as many as the generation
+    service admits) and is pumped into the response via a queue. The
+    flight-record id travels in ``X-Request-Id``
     (client-pinnable via ``thread_id``) so a streamed request's trace is
     retrievable from /debug/flight afterwards.
 
@@ -458,7 +463,7 @@ async def _chat_stream(request: web.Request, container: DependencyContainer, req
     def put(item) -> bool:
         # blocking put with backpressure AND a disconnect escape hatch: when
         # the consumer stops draining (client gone), `stop` is set and the
-        # producer exits instead of blocking a pool thread forever
+        # producer exits instead of blocking a request thread forever
         while not stop.is_set():
             fut = asyncio.run_coroutine_threadsafe(queue.put(item), loop)
             try:
@@ -499,7 +504,7 @@ async def _chat_stream(request: web.Request, container: DependencyContainer, req
                 return
         put(("eos", ""))
 
-    task = loop.run_in_executor(None, produce)
+    task = container.request_threads.run(produce)
     # SSE liveness: while the producer is silent (long prefill, a slow —
     # or wedged — decode pump), emit comment keepalives so the client can
     # distinguish "still working" from a dead connection and apply its own
@@ -750,6 +755,11 @@ async def info(request: web.Request) -> web.Response:
             },
             "device": (device_stats(container.mesh, decoder.model_config)
                        if decoder is not None else None),
+            # how many /chat pipelines run at a time, and what said so
+            # (the generation service's max_queue; asyncio's default width
+            # where no engine is served)
+            "request_threads": container.request_threads.width,
+            "request_threads_from": container.request_threads.origin,
             # where this process keeps JAX's persistent compile cache
             # (infra/compile_cache.py; None = not placed, e.g. under tests)
             "compile_cache_dir": os.environ.get("JAX_COMPILATION_CACHE_DIR"),

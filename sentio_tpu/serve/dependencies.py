@@ -12,16 +12,64 @@ corpus) on the first request (chat.py:38-87 there).
 
 from __future__ import annotations
 
+import asyncio
+import contextvars
+import functools
 import logging
+import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Optional
 
 from sentio_tpu.config import Settings, get_settings
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["DependencyContainer", "get_container", "set_container"]
+__all__ = ["DependencyContainer", "RequestThreads", "get_container", "set_container"]
+
+
+class RequestThreads:
+    """The threads ``/chat``'s pipelines run on, streamed and unstreamed.
+
+    A request holds one from its first line to its last event, so the pool's
+    width is how many requests can be anywhere in the server at once. It is
+    what the generation service would admit before it sheds — its
+    ``max_queue``, which a ``ReplicaSet``'s stats sum over its replicas — so
+    a caller beyond the engine's slots waits in the engine's inbox and
+    queue, where deadlines, tenant shares and the 429 of ``queue_full`` see
+    it, and none waits for a thread (the request's ``pool_wait`` stage).
+    asyncio's default executor, ``min(32, cores + 4)`` wide, keeps the rest:
+    ingest, health probes, ``/debug/profile``. Threads start on demand."""
+
+    THREAD_PREFIX = "sentio-request"
+
+    def __init__(self, service: Any = None) -> None:
+        width = 0
+        if service is not None and hasattr(service, "stats"):
+            try:
+                width = int(service.stats().get("max_queue") or 0)
+            except Exception:  # noqa: BLE001 — a service that cannot say keeps the default
+                logger.debug("generation service gave no max_queue", exc_info=True)
+        if width > 0:
+            self.width, self.origin = width, "max_queue"
+        else:
+            # no engine to read (echo / API providers): asyncio's own width
+            self.width = min(32, (os.cpu_count() or 1) + 4)
+            self.origin = "default_executor"
+        self._executor = ThreadPoolExecutor(
+            max_workers=self.width, thread_name_prefix=self.THREAD_PREFIX)
+
+    def run(self, fn, /, *args: Any, **kwargs: Any) -> asyncio.Future:
+        """``asyncio.to_thread`` on these threads: the callable runs inside
+        a copy of the caller's context, through which the stages below find
+        their request (infra/tracing.py)."""
+        ctx = contextvars.copy_context()
+        return asyncio.get_running_loop().run_in_executor(
+            self._executor, functools.partial(ctx.run, fn, *args, **kwargs))
+
+    def close(self) -> None:
+        self._executor.shutdown(wait=True, cancel_futures=True)
 
 
 class DependencyContainer:
@@ -750,6 +798,11 @@ class DependencyContainer:
         return self._get("metrics", build)
 
     @property
+    def request_threads(self) -> RequestThreads:
+        return self._get(
+            "request_threads", lambda: RequestThreads(self.generation_service))
+
+    @property
     def chat_handler(self):
         def build():
             from sentio_tpu.serve.handlers import ChatHandler
@@ -778,8 +831,8 @@ class DependencyContainer:
             t0 = time.perf_counter()
             order = [
                 "mesh", "embedder", "dense_index", "sparse_index", "retriever",
-                "reranker", "decoder", "generation_service", "generator",
-                "verifier", "graph", "ingestor", "cache_manager",
+                "reranker", "decoder", "generation_service", "request_threads",
+                "generator", "verifier", "graph", "ingestor", "cache_manager",
                 "auth_manager", "rate_limiter", "metrics", "chat_handler",
                 "health_handler",
             ]
@@ -789,20 +842,29 @@ class DependencyContainer:
             self._initialized = True
             logger.info("container initialized in %.1fs", time.perf_counter() - t0)
 
+    def _close(self, *names: str) -> None:
+        for name in names:
+            component = self._cache.get(name)
+            if component is not None and hasattr(component, "close"):
+                try:
+                    component.close()
+                except Exception:  # noqa: BLE001 — shutdown is best-effort
+                    logger.warning("%s close failed", name, exc_info=True)
+
     def cleanup(self) -> None:
         with self._lock:
             # the autoscaler stops FIRST (it must not launch or retire
-            # mid-teardown); worker_registry closes AFTER the generation
-            # service: the ReplicaSet's close reaps workers whose
-            # re-registrations the listener may still be fielding
-            for name in ("autoscaler", "generation_service", "embedder",
-                         "worker_registry"):
-                component = self._cache.get(name)
-                if component is not None and hasattr(component, "close"):
-                    try:
-                        component.close()
-                    except Exception:  # noqa: BLE001 — shutdown is best-effort
-                        logger.warning("%s close failed", name, exc_info=True)
+            # mid-teardown)
+            self._close("autoscaler", "generation_service")
+        # the request threads are joined once the service's close has failed
+        # the tickets they wait on, and OUTSIDE the lock: a pipeline on its
+        # way out may still ask the container for a component
+        self._close("request_threads")
+        with self._lock:
+            # worker_registry closes AFTER the generation service: the
+            # ReplicaSet's close reaps workers whose re-registrations the
+            # listener may still be fielding
+            self._close("embedder", "worker_registry")
             self._cache.clear()
             self._initialized = False
 

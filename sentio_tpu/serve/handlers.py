@@ -435,8 +435,11 @@ class ChatHandler:
 
     async def process_chat_request(self, **kwargs) -> dict[str, Any]:
         """The pipeline is synchronous device dispatch; keep the event loop
-        free by running it on a worker thread."""
-        return await asyncio.to_thread(self.process_chat_request_sync, **kwargs)
+        free by running it on one of the server's request threads (as many
+        as the generation service admits; the caller's span context travels
+        with it, as under ``asyncio.to_thread``)."""
+        return await self.container.request_threads.run(
+            self.process_chat_request_sync, **kwargs)
 
 
 class HealthHandler:

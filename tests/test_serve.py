@@ -7,7 +7,10 @@ validation, rate limits, handlers, graph, indexes.
 """
 
 import asyncio
+import contextvars
 import dataclasses
+import os
+import threading
 
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
@@ -40,9 +43,10 @@ def run(coro):
     return asyncio.run(coro)
 
 
-async def with_client(settings, fn, container=None):
+async def with_client(settings, fn, container=None, middlewares=()):
     container = container or DependencyContainer(settings=settings)
     app = create_app(container=container)
+    app.middlewares.extend(middlewares)
     client = TestClient(TestServer(app))
     await client.start_server()
     try:
@@ -289,7 +293,8 @@ class TestHealthAndInfo:
             info = await (await client.get("/info")).json()
             assert set(info) == {
                 "service", "version", "retrieval", "embedder", "reranker",
-                "generator", "device", "compile_cache_dir"}
+                "generator", "device", "request_threads",
+                "request_threads_from", "compile_cache_dir"}
             assert set(leaves(info["generator"])) == {
                 "provider", "preset", "verifier", "kv_quant",
                 "paged_attention", "pool_hbm_bytes", "speculative",
@@ -1062,3 +1067,234 @@ class TestUpload:
             assert resp.status == 413
 
         run(with_client(fast_settings(serve=ServeConfig(max_upload_mb=0)), body))
+
+
+class TestRequestThreads:
+    """The streams a server carries follow from what its engine admits
+    (PR 39): ``/chat``'s pipelines, streamed and unstreamed, run on threads
+    the server owns, as many as the generation service's ``max_queue`` —
+    not on asyncio's default executor, whose ``min(32, cores + 4)`` threads
+    stood in front of the engine's own admission."""
+
+    _probe: contextvars.ContextVar = contextvars.ContextVar("request_threads_probe", default=None)
+
+    class _Admitting:
+        """What the server reads of a generation service, and no engine."""
+
+        def __init__(self, max_queue):
+            self.max_queue = max_queue
+
+        def stats(self):
+            return {"max_queue": self.max_queue}
+
+        def check_admission(self, deadline_ts=None):
+            return None
+
+    class _HeldGenerator:
+        """Every caller arrives, then waits for the test's release."""
+
+        provider = None
+
+        def __init__(self, probe):
+            self.release = threading.Event()
+            self.arrived = []
+            self._probe = probe
+
+        def _arrive(self):
+            self.arrived.append((threading.current_thread().name, self._probe.get()))
+            assert self.release.wait(60.0), "the test never released its callers"
+
+        def generate(self, query, docs, **kwargs):
+            self._arrive()
+            return "held answer"
+
+        def stream(self, query, docs, **kwargs):
+            self._arrive()
+            yield "held answer"
+
+    @staticmethod
+    def _request_threads_alive():
+        from sentio_tpu.serve.dependencies import RequestThreads
+
+        return [t.name for t in threading.enumerate()
+                if t.name.startswith(RequestThreads.THREAD_PREFIX)]
+
+    @pytest.mark.parametrize("stream", [True, False], ids=["streamed", "unstreamed"])
+    def test_every_caller_reaches_the_generator(self, stream):
+        """Three callers more than asyncio's default executor has threads:
+        ALL of them stand in the generator before any is released, none
+        waited for a thread, and each carried its handler's context."""
+        from aiohttp import web
+
+        from sentio_tpu.infra.flight import get_flight_recorder
+
+        callers = min(32, (os.cpu_count() or 1) + 4) + 3
+        held = self._HeldGenerator(self._probe)
+        container = DependencyContainer(
+            settings=fast_settings(), generator=held,
+            generation_service=self._Admitting(64))
+
+        @web.middleware
+        async def mark(request, handler):
+            self._probe.set(request.path)
+            return await handler(request)
+
+        async def arrivals(n):
+            for _ in range(1200):
+                if len(held.arrived) >= n:
+                    break
+                await asyncio.sleep(0.01)
+            return len(held.arrived)
+
+        async def body(client, container):
+            await seed(client, ["request threads carry every caller"])
+            # the first request of a process imports what its handler needs
+            held.release.set()
+            await (await client.post("/chat", json={
+                "question": "first of the process", "stream": stream})).read()
+            held.release.clear()
+            held.arrived.clear()
+            posts = []
+            try:
+                # one after the other, each once the one before stands in
+                # the generator: a wait for a thread is then all that a
+                # `pool_wait` can hold, with no other pipeline on the cores
+                for i in range(callers):
+                    posts.append(asyncio.ensure_future(client.post("/chat", json={
+                        "question": f"caller {i} asks", "stream": stream,
+                        "thread_id": f"held-{stream}-{i}"})))
+                    assert await arrivals(i + 1) == i + 1, (
+                        f"caller {i + 1} of {callers} never reached the "
+                        "generator: it waits for a thread")
+            finally:
+                held.release.set()
+            for resp in await asyncio.gather(*posts):
+                assert resp.status == 200
+                assert "held answer" in (await resp.read()).decode()
+            assert {name.rsplit("_", 1)[0] for name, _ in held.arrived} <= {
+                "sentio-request", "asyncio"}  # the graph's own hop under /chat
+            assert {mark for _, mark in held.arrived} == {"/chat"}
+            waits = []
+            for i in range(callers):
+                record = get_flight_recorder().get(f"held-{stream}-{i}")
+                waits += [(sp["t1_s"] - sp["t0_s"]) * 1e3 for sp in record["spans"]
+                          if sp["name"] == "pool_wait"]
+            assert len(waits) == callers
+            assert max(waits) < 50.0, sorted(waits)
+
+        run(with_client(None, body, container=container, middlewares=[mark]))
+        assert not self._request_threads_alive()
+
+    @staticmethod
+    def _engine_settings(**serve_over):
+        return fast_settings(
+            generator=GeneratorConfig(
+                provider="tpu", model_preset="tiny", use_verifier=False,
+                max_new_tokens=8, mode="fast", kv_page_size=16,
+                kv_max_pages_per_seq=8, max_batch_size=2,
+            ),
+            serve=ServeConfig(**serve_over),
+        )
+
+    def test_span_context_reaches_the_stages_on_a_request_thread(self):
+        """The stages find their request through the context the hop
+        carries: the flight record of a streamed and of an unstreamed
+        request holds all nine TTFT stages, `embed` and `rerank` written by
+        the threads below, tiling the server-side time to first token."""
+        from sentio_tpu.infra.phases import TTFT_STAGES
+
+        async def body(client, container):
+            await seed(client, ["the span context travels with the request"])
+            for stream in (True, False):
+                rid = f"ctx-{stream}"
+                resp = await client.post("/chat", json={
+                    "question": "what travels with the request?",
+                    "stream": stream, "thread_id": rid})
+                assert resp.status == 200
+                await resp.read()
+                record = await (await client.get(f"/debug/flight/{rid}")).json()
+                assert tuple(record["stages_ms"]) == TTFT_STAGES
+                assert sum(record["stages_ms"].values()) == pytest.approx(
+                    record["ttft_server_ms"], abs=1e-6)
+                for stage in ("embed", "rerank", "prefill"):
+                    assert record["stages_ms"][stage] > 0.0, record["stages_ms"]
+                parents = {sp["name"]: sp["parent"] for sp in record["spans"]}
+                assert parents["pool_wait"] == "request"
+                assert parents["embed"] == "graph.retrieve"
+                assert parents["rerank"] == "graph.rerank"
+
+        run(with_client(self._engine_settings(), body))
+
+    @pytest.mark.parametrize("stream", [True, False], ids=["streamed", "unstreamed"])
+    def test_the_request_past_max_queue_is_shed_by_the_engine(self, stream):
+        """ADMISSION_MAX_QUEUE=2: two requests fill the engine's queue (its
+        pump wedged), and the third is told `queue_full` by the engine's
+        own admission — a typed 429 at once, not a wait for a thread that
+        the two hold."""
+        from sentio_tpu.infra import faults
+        from sentio_tpu.infra.metrics import get_metrics
+
+        async def body(client, container):
+            await seed(client, ["a full queue sheds the next caller"])
+            info = await (await client.get("/info")).json()
+            assert (info["request_threads"], info["request_threads_from"]) == (2, "max_queue")
+            replica = container.generation_service._services[0]
+            wedge = threading.Event()
+            faults.arm("paged.step", faults.FaultRule(stall_event=wedge))
+            shed_before = get_metrics().export_json()["counters"]
+            try:
+                # a tenant each: one tenant's fair share of a queue of two
+                # is one, and it is the ENGINE's bound that is wanted here
+                held = [asyncio.ensure_future(client.post(
+                    "/chat", headers={"X-Tenant": f"tenant-{i}"}, json={
+                        "question": f"held caller {i}", "stream": stream}))
+                    for i in range(2)]
+                for _ in range(1200):
+                    if replica.backlog() >= 2:
+                        break
+                    await asyncio.sleep(0.05)
+                assert replica.backlog() == 2
+                resp = await asyncio.wait_for(client.post(
+                    "/chat", headers={"X-Tenant": "tenant-2"}, json={
+                        "question": "the caller past the queue", "stream": stream}),
+                    timeout=20.0)
+                assert resp.status == 429, await resp.text()
+                data = await resp.json()
+                assert data["error"]["code"] == "OVERLOADED"
+                assert "queue full (2/2" in data["error"]["message"]
+                assert int(resp.headers["Retry-After"]) >= 1
+                assert replica.backlog() == 2
+            finally:
+                wedge.set()
+                faults.reset()
+            for resp in await asyncio.gather(*held):
+                assert resp.status == 200
+                await resp.read()
+            shed_after = get_metrics().export_json()["counters"]
+            grown = {k: v - shed_before.get(k, 0) for k, v in shed_after.items()
+                     if "shed" in k and v != shed_before.get(k, 0)}
+            assert len(grown) == 1 and "queue_full" in next(iter(grown)), grown
+
+        run(with_client(self._engine_settings(admission_max_queue=2), body))
+
+    @pytest.mark.parametrize("service, want", [
+        (_Admitting(40), (40, "max_queue")),
+        (None, (min(32, (os.cpu_count() or 1) + 4), "default_executor")),
+    ], ids=["max_queue", "no_engine"])
+    def test_info_states_the_width_and_cleanup_joins_the_threads(self, service, want):
+        container = DependencyContainer(
+            settings=fast_settings(), generation_service=service)
+
+        async def body(client, container):
+            await seed(client, ["the width is read off the service"])
+            info = await (await client.get("/info")).json()
+            assert (info["request_threads"], info["request_threads_from"]) == want
+            for stream in (True, False):
+                resp = await client.post("/chat", json={
+                    "question": "who carries me?", "stream": stream})
+                assert resp.status == 200
+                await resp.read()
+            assert self._request_threads_alive()
+
+        run(with_client(None, body, container=container))
+        assert not self._request_threads_alive()
