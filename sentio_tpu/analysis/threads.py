@@ -92,6 +92,7 @@ ROLE_REGISTRY: dict[str, tuple[str, ...]] = {
     "rebuild": ("replica-rebuild-*",),
     "eval-worker": ("eval-worker-*",),
     "cache-fill": ("embedder-cache-fill",),
+    "stamper": ("device-stamper",),
     "mock-api": ("mock-model-api",),
 }
 

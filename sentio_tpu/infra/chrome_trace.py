@@ -43,6 +43,9 @@ __all__ = ["build_chrome_trace", "build_fleet_trace", "flight_to_chrome"]
 _TICK_ARGS = (
     "active_slots", "queue_depth", "inbox_depth", "prefill_tokens",
     "decode_tokens", "free_pages", "xla_compiles",
+    # what the device ran since the previous tick record, by program, and
+    # the completion stamps that account lost or took out of order
+    "device_ms", "stamps_dropped", "stamps_set_aside",
 )
 
 _PUMP_TID = 0

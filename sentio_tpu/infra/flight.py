@@ -52,6 +52,11 @@ __all__ = ["FlightRecorder", "get_flight_recorder", "set_flight_recorder"]
 MAX_TICKS_PER_RECORD = 256
 # spans kept on one request record; a request writes about twenty
 MAX_SPANS_PER_RECORD = 64
+# completion stamps kept for a span that has not closed yet (a chunked
+# prompt's segments all land before its `prefill` span is written)
+MAX_DEVICE_PENDING = 64
+# a span's ends are kept to the microsecond
+_SPAN_SLACK_S = 2e-6
 ROOT_SPAN = "request"
 # the audit's span: the stages of ITS admission hang under it and are kept
 # out of the request's own tile and histogram samples
@@ -95,6 +100,9 @@ class FlightRecorder:
         # request id → when the pump queued tokens no socket write has
         # covered yet (the open end of a stream_lag sample)
         self._stream_puts: dict[str, float] = {}  # guarded-by: _lock
+        # request id → completion stamps (span name, dispatched, taken up,
+        # done; timeline seconds) whose span has not closed yet
+        self._device_pending: dict[str, list] = {}  # guarded-by: _lock
         self._t0 = time.perf_counter()  # timeline origin for tick timestamps
 
     # ------------------------------------------------------------- requests
@@ -155,17 +163,26 @@ class FlightRecorder:
             if graph_path:
                 record["graph_path"] = list(graph_path)
 
-    def note_engine_submit(self, request_id: str, **fields: Any) -> None:
+    def note_engine_submit(self, request_id: str, t_submit: Optional[float] = None,
+                           **fields: Any) -> None:
         """Mark where this request enters the decode engine: its tick window
         starts at the NEXT tick the pump records. Extra fields (e.g. the
         ``replica_id`` that admission routed to) merge into the engine
         section; the first admission's values win — the verify node's later
         admission under the same trace id must not overwrite which replica
-        served the user-facing generation."""
+        served the user-facing generation. Where this call is the first to
+        see the id (a bare service caller), the record starts at
+        ``t_submit`` (raw ``perf_counter``), the ticket's own stamp: its
+        stages begin there, and on a loaded host the moment between that
+        stamp and this call is no part of the request."""
         if not request_id:
             return
         with self._lock:
-            engine = self._ensure_locked(request_id).setdefault("engine", {})
+            fresh = request_id not in self._records
+            record = self._ensure_locked(request_id)
+            if fresh and t_submit is not None:
+                record["t_start_s"] = round(t_submit - self._t0, 6)
+            engine = record.setdefault("engine", {})
             engine.setdefault("tick_first", self._tick_seq)
             # timeline-origin submit stamp: lets the Chrome-trace exporter
             # place the engine span / first-token mark on the same clock as
@@ -228,6 +245,61 @@ class FlightRecorder:
             if fields:
                 entry["fields"] = dict(fields)
             spans.append(entry)
+            waiting = self._device_pending.get(request_id)
+            if waiting:
+                kept = [w for w in waiting if not self._book_device_locked(entry, *w)]
+                if kept:
+                    self._device_pending[request_id] = kept
+                else:
+                    del self._device_pending[request_id]
+
+    def note_device_time(self, request_id: str, name: str, t_dispatch: float,
+                         t_start: float, t_done: float) -> None:
+        """One of this request's programs held the device (the stamper in
+        infra/tracing.py is the writer; raw ``perf_counter`` values):
+        dispatched inside its span ``name``, taken up by the device at
+        ``t_start``, done at ``t_done``. The span that holds the dispatch
+        gains ``device_queued_ms`` (dispatched → taken up) and ``device_ms``
+        (taken up → done) in its fields, summed over its programs — written
+        onto the CLOSED span if the stamp lands late, kept until the span
+        closes if it lands early. Both are cut to the span: a stamp is taken
+        when the stamper's thread wakes, so it can be late and never early,
+        and a program whose result the span waited for was done by its end;
+        two programs of one span never count the same instant twice. What is
+        left of the span ran no program of its own: a ``prefill`` span's
+        rest is its segments' wait for their turn."""
+        if not request_id:
+            return
+        stamp = (name, t_dispatch - self._t0, t_start - self._t0, t_done - self._t0)
+        with self._lock:
+            record = self._records.get(request_id)
+            if record is None:
+                return
+            for sp in reversed(record.get("spans", ())):
+                if self._book_device_locked(sp, *stamp):
+                    return
+            waiting = self._device_pending.setdefault(request_id, [])
+            if len(waiting) < MAX_DEVICE_PENDING:
+                waiting.append(stamp)
+
+    def _book_device_locked(self, sp: dict, name: str, t_dispatch: float,
+                            t_start: float, t_done: float) -> bool:
+        """Add one completion stamp to ``sp`` if it is the span the program
+        was dispatched in."""
+        assert_held(self._lock)
+        if sp["name"] != name or not (
+                sp["t0_s"] - _SPAN_SLACK_S <= t_dispatch <= sp["t1_s"] + _SPAN_SLACK_S):
+            return False
+        fields = sp.setdefault("fields", {})
+        # from where this span's previous program ended, to the span's end
+        t_from = min(max(t_dispatch, sp["t0_s"], sp.get("device_until_s", 0.0)), sp["t1_s"])
+        t_done = min(max(t_done, t_from), sp["t1_s"])
+        t_start = min(max(t_start, t_from), t_done)
+        sp["device_until_s"] = round(t_done, 6)
+        for key, seconds in (("device_queued_ms", t_start - t_from),
+                             ("device_ms", t_done - t_start)):
+            fields[key] = round(fields.get(key, 0.0) + seconds * 1e3, 3)
+        return True
 
     def close_ttft(self, request_id: str, t_first: float) -> Optional[dict]:
         """The request's first token is host-visible at ``t_first`` (raw
@@ -279,6 +351,7 @@ class FlightRecorder:
             return
         with self._lock:
             self._stream_puts.pop(request_id, None)
+            self._device_pending.pop(request_id, None)
             record = self._records.get(request_id)
             if record is None:
                 return
@@ -466,6 +539,7 @@ class FlightRecorder:
             self._ticks.clear()
             self._records.clear()
             self._stream_puts.clear()
+            self._device_pending.clear()
             self._tick_seq = 0
             self.dropped_requests = 0
 
@@ -479,6 +553,7 @@ class FlightRecorder:
         while len(self._records) > self.max_requests:
             evicted, _ = self._records.popitem(last=False)
             self._stream_puts.pop(evicted, None)
+            self._device_pending.pop(evicted, None)
             self.dropped_requests += 1
 
 

@@ -1,10 +1,13 @@
 """Metrics: Prometheus counters/histograms/gauges with in-memory fallback,
 plus TPU device gauges the reference never needed.
 
-Parity with /root/reference/src/observability/metrics.py:46-514 — request/
-embedding/retrieval/LLM/system/breaker dimensions, context-manager tracking
-helpers, text-or-JSON export — extended with device telemetry: HBM bytes in
-use, batch occupancy, generated tokens/s (SURVEY.md §2.10 build column).
+The request dimension of /root/reference/src/observability/metrics.py and its
+text-or-JSON export, and the serving dimension this program adds: per-sequence
+TTFT and TPOT, tick phases, request stages, counted row-steps, and what the
+device ran by program (``sentio_tpu_device_program_seconds_total``). A series
+that neither the benchmark, ``deploy/kubernetes/monitoring.yaml`` nor a
+README section reads does not stay (PR 40 took out the embedding, LLM-call
+and tokens-per-second series).
 """
 
 from __future__ import annotations
@@ -144,15 +147,6 @@ class MetricsCollector:
                 "sentio_request_latency_seconds", "request latency", ["endpoint"],
                 buckets=(0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1, 2, 5, 10), registry=r,
             ),
-            "embeddings": Counter(
-                "sentio_embeddings_total", "texts embedded", ["provider"], registry=r
-            ),
-            "llm_tokens": Counter(
-                "sentio_llm_tokens_total", "tokens generated", ["kind"], registry=r
-            ),
-            "llm_latency": Histogram(
-                "sentio_llm_latency_seconds", "LLM call latency", ["op"], registry=r
-            ),
             # TPU device dimension
             "serving_stat": Gauge(
                 "sentio_tpu_serving_stat",
@@ -162,9 +156,6 @@ class MetricsCollector:
             "serving_total": Counter(
                 "sentio_tpu_serving_events_total",
                 "decode service lifetime totals", ["event"], registry=r,
-            ),
-            "tokens_per_s": Gauge(
-                "sentio_tpu_decode_tokens_per_second", "decode throughput", [], registry=r
             ),
             # per-sequence serving latency, the two numbers an LLM-serving
             # SLO is actually written against (vLLM exposes the same pair):
@@ -341,6 +332,34 @@ class MetricsCollector:
                 "tokens a latent family's prefill computed, and prior tokens it expanded",
                 ["kind"], registry=r,
             ),
+            # chunked prefill's turns (runtime/paged.py::_advance_prefill:
+            # one segment a tick over all slots): a tick in which n slots
+            # hold a pending segment books one taken and n - 1 waited.
+            # waited / (taken + waited) is the share of a segment's life
+            # spent waiting for its turn
+            "prefill_turns": Counter(
+                "sentio_tpu_prefill_turns_total",
+                "slots holding a pending prefill segment at a tick: the one dispatched, and the rest",
+                ["kind"], registry=r,
+            ),
+            # what the DEVICE was running, by the program's own completion
+            # stamps (infra/tracing.py::DeviceStamper): the seconds each
+            # dispatched program held the device, from the later of its
+            # dispatch and its predecessor's completion to its own
+            # completion. Idle is booked nowhere, so the labels sum to the
+            # device's busy time as this process saw it
+            "device_program": Counter(
+                "sentio_tpu_device_program_seconds_total",
+                "seconds the device ran each kind of program, by completion stamps",
+                ["program"], registry=r,
+            ),
+            # an encoder forward's two parts: dispatch -> the device took it
+            # up (queued behind other programs), took it up -> done (running)
+            "encoder_forward": Counter(
+                "sentio_tpu_encoder_forward_seconds_total",
+                "embed and rerank forwards: seconds queued behind device work, and running",
+                ["part"], registry=r,
+            ),
             # process-mode replica tier (runtime/worker.py): worker
             # process deaths observed by the router-side shim (SIGKILL,
             # OOM-kill, crash, broken RPC pipe). A steadily increasing
@@ -485,6 +504,13 @@ class MetricsCollector:
                 [], registry=r,
             ),
         }
+        # a reader that names a label must find it: every program from the
+        # start, zeros included (the benchmark's prom_delta reads nothing
+        # where a label its file names is absent)
+        from sentio_tpu.infra.phases import DEVICE_PROGRAMS
+
+        for program in DEVICE_PROGRAMS:
+            self._prom["device_program"].labels(program)
 
     # ------------------------------------------------------------- recording
 
@@ -496,28 +522,6 @@ class MetricsCollector:
         if self._prom:
             self._prom["requests"].labels(endpoint, str(status)).inc()
             self._prom["request_latency"].labels(endpoint).observe(latency_s)
-
-    def record_embeddings(self, provider: str, n_texts: int) -> None:
-        if not self.enabled:
-            return
-        self.memory.inc("embeddings", (provider,), n_texts)
-        if self._prom:
-            self._prom["embeddings"].labels(provider).inc(n_texts)
-
-    def record_llm(self, op: str, latency_s: float, tokens: int = 0) -> None:
-        if not self.enabled:
-            return
-        self.memory.observe("llm_latency", (op,), latency_s)
-        if tokens:
-            self.memory.inc("llm_tokens", (op,), tokens)
-            if latency_s > 0:
-                self.memory.set_gauge("tokens_per_s", (), tokens / latency_s)
-        if self._prom:
-            self._prom["llm_latency"].labels(op).observe(latency_s)
-            if tokens:
-                self._prom["llm_tokens"].labels(op).inc(tokens)
-                if latency_s > 0:
-                    self._prom["tokens_per_s"].set(tokens / latency_s)
 
     def record_ttft(self, seconds: float, path: str = "paged") -> None:
         """Time-to-first-token for one sequence (``path``: paged | stream)."""
@@ -583,15 +587,22 @@ class MetricsCollector:
 
     def record_row_steps(self, counts: dict, kv_pages: Optional[dict] = None,
                          moe: Optional[dict] = None,
-                         prefill_latent: Optional[dict] = None) -> None:
+                         prefill_latent: Optional[dict] = None,
+                         prefill_turns: Optional[dict] = None) -> None:
         """One harvested tick's row-steps by kind (useful / halted / empty),
         the K/V page blocks of its sub-steps (held / tabled), of a routed
         family its expert layers' pairs (routed / held) and expert-steps
-        (held / touched) — ``MOE_KINDS`` as ``<series>_<kind>`` — and of a
-        latent family its prefill tokens (new / expanded)."""
+        (held / touched) — ``MOE_KINDS`` as ``<series>_<kind>`` — of a
+        latent family its prefill tokens (new / expanded), and chunked
+        prefill's turns (taken / waited)."""
         if not self.enabled:
             return
-        from sentio_tpu.infra.phases import KV_PAGE_KINDS, PREFILL_LATENT_KINDS, ROW_STEP_KINDS
+        from sentio_tpu.infra.phases import (
+            KV_PAGE_KINDS,
+            PREFILL_LATENT_KINDS,
+            PREFILL_TURN_KINDS,
+            ROW_STEP_KINDS,
+        )
 
         moe = moe or {}
         for name, kinds, tick in (
@@ -601,7 +612,8 @@ class MetricsCollector:
                  {k: moe.get(f"pairs_{k}", 0) for k in ("routed", "held")}),
                 ("moe_expert_steps", ("held", "touched"),
                  {k: moe.get(f"experts_{k}", 0) for k in ("held", "touched")}),
-                ("prefill_latent", PREFILL_LATENT_KINDS, prefill_latent or {})):
+                ("prefill_latent", PREFILL_LATENT_KINDS, prefill_latent or {}),
+                ("prefill_turns", PREFILL_TURN_KINDS, prefill_turns or {})):
             if name.startswith(("moe", "prefill_latent")) and not any(tick.values()):
                 continue  # no series where no such family is served
             counter = self._prom.get(name)
@@ -610,6 +622,29 @@ class MetricsCollector:
                 self.memory.inc(name, (kind,), n)
                 if counter is not None:
                     counter.labels(kind=kind).inc(n)
+
+    def record_device_program(self, program: str, seconds: float,
+                              queued_s: float = 0.0) -> None:
+        """One completion stamp: ``seconds`` the device ran ``program``. A
+        program outside ``DEVICE_PROGRAMS`` RAISES, as a stage does (every
+        label of the set is on ``/metrics`` from the start, zeros included:
+        ``_build_prom``). An encoder forward also books its two parts:
+        ``queued_s`` behind other device work, and ``seconds`` running."""
+        from sentio_tpu.infra.phases import DEVICE_PROGRAMS, ENCODER_PROGRAMS
+
+        if program not in DEVICE_PROGRAMS:
+            raise KeyError(f"unknown program {program!r} (bounded set: {DEVICE_PROGRAMS})")
+        if not self.enabled:
+            return
+        booked = [("device_program", program, seconds)]
+        if program in ENCODER_PROGRAMS:
+            booked += [("encoder_forward", "queued", queued_s),
+                       ("encoder_forward", "running", seconds)]
+        for name, label, value in booked:
+            self.memory.inc(name, (label,), float(value))
+            counter = self._prom.get(name)
+            if counter is not None:
+                counter.labels(label).inc(float(value))
 
     def record_duty_cycle(self, replica: int, fractions: dict) -> None:
         """Publish one replica's host/device/idle duty-cycle fractions

@@ -93,6 +93,14 @@ bounded like ``TICK_PHASES``; each stage is written where its work happens
 ``stream_lag``
     Per stream event: the pump put tokens on the ticket's queue → the HTTP
     handler wrote them to the socket.
+
+**Device programs** are the third account, and the only one of the DEVICE's
+time: ``DEVICE_PROGRAMS`` is fixed and bounded like the two above, written
+by the completion stamps of infra/tracing.py (``DeviceStamper``) and read as
+``sentio_tpu_device_program_seconds_total{program}``, the tick ring's
+``device_ms`` and ``device.<program>`` annotations. A phase says what the
+pump was doing and a stage where a request stood; a program says what the
+device was running meanwhile. Idle is in no program.
 """
 
 from __future__ import annotations
@@ -105,6 +113,10 @@ __all__ = [
     "KV_PAGE_KINDS",
     "MOE_KINDS",
     "PREFILL_LATENT_KINDS",
+    "PREFILL_TURN_KINDS",
+    "DEVICE_PROGRAMS",
+    "ENCODER_PROGRAMS",
+    "ENCODER_FORWARD_PARTS",
     "TTFT_STAGES",
     "tile_ttft",
     "TICK_PHASES",
@@ -187,6 +199,25 @@ MOE_KINDS = ("pairs_routed", "pairs_held", "experts_held", "experts_touched")
 # keys and values (a chunked prompt's every segment after the first, and every
 # radix hit, expands its whole prior)
 PREFILL_LATENT_KINDS = ("new", "expanded")
+
+# chunked prefill's turns (runtime/paged.py::_advance_prefill dispatches ONE
+# segment a tick over all slots): a tick in which n slots hold a pending
+# segment books one `taken` and n - 1 `waited`
+PREFILL_TURN_KINDS = ("taken", "waited")
+
+# what the DEVICE was running (infra/tracing.py's completion stamps: every
+# dispatch site hands its program and one small output to the stamper, which
+# books the interval the program held the device): `decode` the fused decode
+# tick (``step_n``, the speculative tick), `prefill` the whole-prompt and
+# prior-primed prefill programs, `admit` ``merge_admitted``, `embed` and
+# `rerank` the encoders' forwards, `other` the dense index's top-k and
+# whatever else dispatches. Idle is in no program
+DEVICE_PROGRAMS = ("decode", "prefill", "admit", "embed", "rerank", "other")
+
+# the programs whose forwards are split into the time they lay behind other
+# device work and the time they ran (``ENCODER_FORWARD_PARTS``)
+ENCODER_PROGRAMS = ("embed", "rerank")
+ENCODER_FORWARD_PARTS = ("queued", "running")
 
 
 def tile_ttft(stage_s: dict, ttft_s: float) -> dict:

@@ -23,7 +23,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from sentio_tpu.infra.tracing import annotation
+from sentio_tpu.infra.tracing import annotation, current, dispatching
 from sentio_tpu.models.document import Document
 
 
@@ -191,9 +191,12 @@ class TpuDenseIndex:
         k_local = min(max(k, 1), n_pad // shards)
         k_out = min(k, shards * k_local)
 
-        scores, rows = _topk_fn(self.mesh, self.dtype, k_local, k_out)(
-            corpus_dev, valid_dev, qn
-        )
+        # on the fused retrieval path this is the `embed` span's second program
+        with dispatching("other", spans=[current()]) as stamp:
+            scores, rows = _topk_fn(self.mesh, self.dtype, k_local, k_out)(
+                corpus_dev, valid_dev, qn
+            )
+            stamp.out = scores
         # one blocking fetch for both outputs, not two sequential ones. On
         # the fused retrieval path it waits for the query's embedding too
         with annotation("embed.fetch"):

@@ -25,7 +25,7 @@ import numpy as np
 
 from sentio_tpu.config import EmbedderConfig, get_settings
 from sentio_tpu.infra import faults
-from sentio_tpu.infra.tracing import annotation
+from sentio_tpu.infra.tracing import annotation, current, dispatching
 
 logger = logging.getLogger(__name__)
 
@@ -249,10 +249,13 @@ class TpuEmbedder(BaseEmbedder):
         if self.config.coalesce:
             from sentio_tpu.parallel.batcher import ThreadBatcher
 
-            def process(batch_texts: list[str]):
-                out = self._embed_device_batch(batch_texts)
+            def process(batch: list[tuple]):
+                # (text, the request and span it came from): every request
+                # of a coalesced batch is given the batch's device time
+                out = self._embed_device_batch(
+                    [text for text, _sp in batch], [sp for _text, sp in batch])
                 # each caller gets its own [1, D] device slice (no download)
-                return [out[i : i + 1] for i in range(len(batch_texts))]
+                return [out[i : i + 1] for i in range(len(batch))]
 
             self._query_batcher = ThreadBatcher(
                 process,
@@ -295,8 +298,9 @@ class TpuEmbedder(BaseEmbedder):
             constant_values=self.tokenizer.pad_id,
         )
         mask = np.pad(mask, ((0, rows - n), (0, width - mask.shape[1])))
-        with annotation("embed.dispatch", rows=rows, width=width):
-            out = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask))
+        with annotation("embed.dispatch", rows=rows, width=width), \
+                dispatching("embed", spans=[current()]) as stamp:
+            out = stamp.out = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask))
         with annotation("embed.fetch"):
             return np.asarray(out, np.float32)[:n]
 
@@ -320,10 +324,10 @@ class TpuEmbedder(BaseEmbedder):
             return np.stack(cached).astype(np.float32)
 
         if len(texts) == 1 and self._query_batcher is not None:
-            return self._query_batcher.submit(texts[0])
+            return self._query_batcher.submit((texts[0], current()))
         return self._embed_device_batch(texts)
 
-    def _embed_device_batch(self, texts: list[str]):
+    def _embed_device_batch(self, texts: list[str], spans: Sequence[tuple] = ()):
         import jax.numpy as jnp
 
         from sentio_tpu.models.tokenizer import batch_encode
@@ -341,8 +345,10 @@ class TpuEmbedder(BaseEmbedder):
         )
         mask = np.pad(mask, ((0, rows - n), (0, width - mask.shape[1])))
         # the blocking half is the consumer's (ops/dense_index.py, embed.fetch)
-        with annotation("embed.dispatch", rows=rows, width=width):
-            out = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask))[:n]
+        with annotation("embed.dispatch", rows=rows, width=width), \
+                dispatching("embed", spans=spans or [current()]) as stamp:
+            stamp.out = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask))
+            out = stamp.out[:n]
 
         if self.cache.max_size > 0:  # cache off → skip the device download
 

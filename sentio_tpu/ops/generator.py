@@ -285,12 +285,10 @@ class OpenAIProvider:
         except Exception:  # noqa: BLE001 — any failure degrades to estimate
             return max(int(len(text.split()) * 4 / 3), 1)
 
-    def _note_usage(self, body: dict, prompt: str, reply: str,
-                    latency_s: float) -> None:
-        """Publish token counts to /metrics — endpoint-reported ``usage``
-        when present (a reported 0 is honored), counted locally otherwise."""
-        from sentio_tpu.infra.metrics import get_metrics
-
+    def _note_usage(self, body: dict, prompt: str, reply: str) -> None:
+        """Keep the call's token counts (``last_usage``) — endpoint-reported
+        ``usage`` when present (a reported 0 is honored), counted locally
+        otherwise."""
         usage = body.get("usage") or {}
         completion = usage.get("completion_tokens")
         if completion is None:
@@ -302,7 +300,6 @@ class OpenAIProvider:
             "prompt_tokens": int(prompt_toks),
             "completion_tokens": int(completion),
         })
-        get_metrics().record_llm("remote_chat", latency_s, tokens=int(completion))
 
     def chat(self, prompt: str, max_new_tokens: int, temperature: float,
              request_id: Optional[str] = None) -> str:
@@ -312,7 +309,6 @@ class OpenAIProvider:
         last_exc: Exception | None = None
         for attempt in range(self.max_retries + 1):
             try:
-                t0 = time.perf_counter()
                 resp = self._client().post(
                     "/chat/completions",
                     json=self._payload(prompt, max_new_tokens, temperature),
@@ -351,7 +347,7 @@ class OpenAIProvider:
                 resp.raise_for_status()
                 body = resp.json()
                 reply = body["choices"][0]["message"]["content"]
-                self._note_usage(body, prompt, reply, time.perf_counter() - t0)
+                self._note_usage(body, prompt, reply)
                 return reply
             except Exception as exc:  # noqa: BLE001 — retry transport/5xx/429
                 status = getattr(getattr(exc, "response", None), "status_code", None)

@@ -20,7 +20,7 @@ import numpy as np
 
 from sentio_tpu.config import RerankConfig, get_settings
 from sentio_tpu.infra import faults
-from sentio_tpu.infra.tracing import span
+from sentio_tpu.infra.tracing import current, dispatching, span
 from sentio_tpu.models.document import Document
 
 logger = logging.getLogger(__name__)
@@ -169,7 +169,9 @@ class CrossEncoderReranker(Reranker):
                 mask = np.pad(mask, ((0, pad), (0, 0)))
                 mask[len(chunk):, 0] = True  # keep softmax rows non-degenerate
                 types = np.pad(types, ((0, pad), (0, 0)))
-            out = self._fwd(self.params, jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(types))
+            with dispatching("rerank", spans=[current()]) as stamp:
+                out = stamp.out = self._fwd(
+                    self.params, jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(types))
             scores[start : start + len(chunk)] = np.asarray(out)[: len(chunk)]
         return scores
 

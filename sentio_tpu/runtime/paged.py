@@ -60,9 +60,10 @@ from sentio_tpu.analysis.audit.registry import jit_family
 from sentio_tpu.analysis.sanitizer import check_engine_invariants, engine_guard
 from sentio_tpu.infra import faults
 from sentio_tpu.infra.phases import (
-    ENGINE_PHASES, KV_PAGE_KINDS, MOE_KINDS, PREFILL_LATENT_KINDS, ROW_STEP_KINDS, PhaseTimer,
+    ENGINE_PHASES, KV_PAGE_KINDS, MOE_KINDS, PREFILL_LATENT_KINDS, PREFILL_TURN_KINDS,
+    ROW_STEP_KINDS, PhaseTimer,
 )
-from sentio_tpu.infra.tracing import annotation
+from sentio_tpu.infra.tracing import annotation, dispatching, get_stamper, harvested
 from sentio_tpu.models.llama import LlamaConfig, qkv_proj, serving_layout
 from sentio_tpu.parallel.batcher import bucket_size
 
@@ -599,6 +600,9 @@ class _Slot:
     # start of prefill) and the prefill dispatches this request took so far
     admit_t: float = 0.0
     prefill_segments: int = 0
+    # the flight record this request's spans are written under (None: an
+    # untraced caller): its prefill dispatches' completion stamps go there
+    trace_id: Optional[str] = None
     # ``keep_choices``: the picks this request's tokens were routed by, one
     # [L, window, k] buffer a kind of choice (-1: not by this request), and
     # the prefill dispatches' picks still on the device (array, row, first
@@ -640,6 +644,7 @@ class _Request:
     # yields reproducible draws when the request is the engine's sole
     # sampled traffic; it is NOT a per-request pinned stream
     seed: Optional[int] = None
+    trace_id: Optional[str] = None
 
 
 @dataclass
@@ -957,6 +962,16 @@ class ContinuousBatchingEngine:
         self.prefill_latent_total = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         self.last_tick_prefill_latent = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         self._prefill_latent_pending = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
+        # chunked prefill dispatches ONE segment a tick over all slots: a
+        # tick in which n slots hold a pending segment books one turn
+        # ``taken`` and n - 1 ``waited``. Counted in ``_advance_prefill``
+        # from the list it builds, booked with the next harvested tick
+        self.prefill_turns_total = dict.fromkeys(PREFILL_TURN_KINDS, 0)
+        self.last_tick_prefill_turns = dict.fromkeys(PREFILL_TURN_KINDS, 0)
+        self._prefill_turns_pending = dict.fromkeys(PREFILL_TURN_KINDS, 0)
+        # the pump's step number, carried by this engine's completion stamps
+        # (infra/tracing.py::dispatching); 0 outside a pump
+        self.tick_step = 0
         # ``run_all(return_choices=True)``: each result carries the picks its
         # OWN tokens were routed by (fetched when asked for, never otherwise)
         self.keep_choices = False
@@ -1391,7 +1406,8 @@ class ContinuousBatchingEngine:
     def submit(self, prompt: str, max_new_tokens: int = 64, temperature: float = 0.0,
                deadline_ts: Optional[float] = None, top_k: int = 0,
                prior_tokens: Optional[Sequence[int]] = None,
-               seed: Optional[int] = None) -> int:
+               seed: Optional[int] = None,
+               trace_id: Optional[str] = None) -> int:
         """``deadline_ts`` is an absolute ``time.perf_counter()`` deadline:
         the queue drops the request (finish_reason="expired") if it is still
         waiting for a slot when the deadline passes. ``top_k`` (0 = off)
@@ -1404,7 +1420,9 @@ class ContinuousBatchingEngine:
         from the splice point. The radix cache turns the replay into a
         prefix hit when the pages survive here, and a bounded replay
         prefill otherwise; emitted tokens are post-splice only.
-        ``seed`` (None = off) folds into the engine RNG at admission."""
+        ``seed`` (None = off) folds into the engine RNG at admission.
+        ``trace_id`` names the flight record whose ``prefill`` span this
+        request's prefill dispatches book their device time on."""
         if self._san is not None:
             self._san.enter("submit")
         top_k = int(top_k)
@@ -1418,7 +1436,7 @@ class ContinuousBatchingEngine:
             rid, prompt, max_new_tokens, temperature, top_k=max(top_k, 0),
             submit_t=time.perf_counter(), deadline_ts=deadline_ts,
             prior_tokens=(list(prior_tokens) if prior_tokens else None),
-            seed=seed,
+            seed=seed, trace_id=trace_id,
         ))
         return rid
 
@@ -1542,6 +1560,9 @@ class ContinuousBatchingEngine:
         self._dev_state = None
         self._moe_acc = None
         self._prefill_latent_pending = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
+        self._prefill_turns_pending = dict.fromkeys(PREFILL_TURN_KINDS, 0)
+        # the failed tick's arrays are not worth waiting for
+        get_stamper().drain()
         if self._inflight is not None:
             # dispatched, never harvested: the device ran these row-steps
             # and nothing of them was delivered
@@ -1661,6 +1682,7 @@ class ContinuousBatchingEngine:
         self.last_tick_kv_pages = dict.fromkeys(KV_PAGE_KINDS, 0)
         self.last_tick_moe = dict.fromkeys(MOE_KINDS, 0)
         self.last_tick_prefill_latent = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
+        self.last_tick_prefill_turns = dict.fromkeys(PREFILL_TURN_KINDS, 0)
         self.last_tick_sub_steps = 0
         # chaos-drill injection point: a raised fault propagates exactly like
         # a real failed device dispatch (the serving pump resets + requeues)
@@ -1964,6 +1986,7 @@ class ContinuousBatchingEngine:
             slot.donated = []
             slot.submit_t = req.submit_t
             slot.admit_t = time.perf_counter()
+            slot.trace_id = req.trace_id
             slot.prefill_segments = 0 if chunked else 1
             slot.prefill_todo = list(tok_ids[shared:]) if chunked else None
             slot.prefill_done = 0
@@ -2090,6 +2113,7 @@ class ContinuousBatchingEngine:
                     self._prefill_scatter,
                     self.params, ids, positions, lens, self._rng, temps, scat,
                     self.pool.k, self.pool.v, top_ks,
+                    slots=[i for i, _r, _t in chunk],
                 )
         self._note_prefill_picks(picks, [(i, 0, len(t)) for i, _r, t in chunk])
         self.prefill_tokens_total += sum(len(t) for _i, _r, t in chunk)
@@ -2132,7 +2156,7 @@ class ContinuousBatchingEngine:
                     self._prior_prefill_scatter,
                     self.params, ids, positions, lens, self._rng, temps, scat,
                     self.pool.k, self.pool.v, prior_tables, n_prior, top_ks,
-                    do_sample=True,
+                    do_sample=True, slots=[i for i, _r, _t, _sh in chunk],
                 )
         self._note_prefill_picks(picks, [(i, sh, len(t) - sh) for i, _r, t, sh in chunk])
         self.prefill_tokens_total += sum(len(t) - s for _i, _r, t, s in chunk)
@@ -2143,16 +2167,24 @@ class ContinuousBatchingEngine:
         for slot_idx, _req, tok_ids, shared in chunk:
             self._radix_insert(slot_idx, tok_ids, shared)
 
-    def _prefill_call(self, fn, *args, **static):
+    def _prefill_call(self, fn, *args, slots: Sequence[int] = (), **static):
         """One prefill dispatch → (its five results, its picks or None). A
         routed family's program also takes the pairs counted on the device
         since the last tick and returns them with its own added; its picks
-        stay on the device unless a caller asked for them."""
-        if not self.routed:
-            return fn(*args, **static), None
-        *out, moe = fn(*args, moe_acc=self._take_moe_acc(), **static)
-        self._moe_acc = moe.pop("counts")
-        return out, moe if self.keep_choices else None
+        stay on the device unless a caller asked for them. The sampled
+        first tokens (small, donated to nothing) carry its completion stamp,
+        booked on the ``prefill`` span of each request in ``slots``."""
+        picks = None
+        with dispatching("prefill", self.tick_step,
+                         [(self.slots[i].trace_id, "prefill") for i in slots]) as stamp:
+            if not self.routed:
+                out = fn(*args, **static)
+            else:
+                *out, moe = fn(*args, moe_acc=self._take_moe_acc(), **static)
+                self._moe_acc = moe.pop("counts")
+                picks = moe if self.keep_choices else None
+            stamp.out = out[0]
+        return out, picks
 
     def _take_moe_acc(self):
         """The pairs the prefill programs routed since the last tick, still
@@ -2194,6 +2226,9 @@ class ContinuousBatchingEngine:
             (slot.submit_t, i) for i, slot in enumerate(self.slots)
             if slot.active and slot.prefill_todo is not None
         ]
+        if waiting:
+            self._prefill_turns_pending["taken"] += 1
+            self._prefill_turns_pending["waited"] += len(waiting) - 1
         for _, i in sorted(waiting):
             slot = self.slots[i]
             chunk = self.prefill_chunk
@@ -2224,7 +2259,7 @@ class ContinuousBatchingEngine:
                         self._prior_prefill_scatter,
                         self.params, ids, positions, lens, self._rng, temps,
                         scat, self.pool.k, self.pool.v, prior_table,
-                        n_prior, top_ks, do_sample=is_last,
+                        n_prior, top_ks, do_sample=is_last, slots=[i],
                     )
             self._note_prefill_picks(picks, [(i, prior, len(seg))])
             self.prefill_tokens_total += len(seg)
@@ -2355,65 +2390,72 @@ class ContinuousBatchingEngine:
             new_lens[: len(slot_idxs)] = [
                 self.slots[i].length for i in slot_idxs
             ]
-            (tok_in, lens_in, halted_in,
-             lp_sum_in, lp_min_in, lp_cnt_in) = self._merge_admitted(
-                tok_in, lens_in, halted_in, lp_sum_in, lp_min_in, lp_cnt_in,
-                first_dev, first_lp_dev, new_lens, idxs
-            )
-
-        if self._spec_tick is not None:
-            self._ensure_draft_cache()
-            packed, tok_out, lens_out, halted_out, self.pool.k, self.pool.v, \
-                self._spec_dk, self._spec_dv, self._rng = self._spec_tick(
-                    self.params, self.draft_params, tok_in, lens_in,
-                    halted_in, self._page_table.copy(), self.pool.k,
-                    self.pool.v, self._spec_dk, self._spec_dv, self._rng,
-                    self._temps.copy(), budgets,
-                    # + k + 1 slack: dynamic_update_slice CLAMPS a start
-                    # index whose k+1-wide update would overhang, silently
-                    # corrupting the tail rounds' token offsets otherwise
-                    k=self.spec_k, out_w=int(steps) + self.spec_k + 1,
+            with dispatching("admit", self.tick_step) as stamp:
+                (tok_in, lens_in, halted_in,
+                 lp_sum_in, lp_min_in, lp_cnt_in) = self._merge_admitted(
+                    tok_in, lens_in, halted_in, lp_sum_in, lp_min_in, lp_cnt_in,
+                    first_dev, first_lp_dev, new_lens, idxs
                 )
-            spec = True
-            kv_pages = None  # the spec tick does not run the decode kernel
-            # the spec tick has its own accept/correct rule and samples no
-            # per-token logprobs; the accumulators thread through UNCHANGED
-            # (stale first-token seeds) and the host mirrors stay zeroed, so
-            # spec results report logprob_count == 0 — the confidence gate
-            # reads that as "no signal" and never skips verify on spec mode
-            lp_state = None
-            lp_sum_out, lp_min_out, lp_cnt_out = lp_sum_in, lp_min_in, lp_cnt_in
-        else:
-            # a routed family: the prefill programs' pairs ride this tick's fetch
-            moe_acc = {"moe_acc": self._take_moe_acc()} if self.routed else {}
-            (packed, lp_state, tok_out, lens_out, halted_out,
-             lp_sum_out, lp_min_out, lp_cnt_out,
-             self.pool.k, self.pool.v, self._rng, *picks) = self._step_n(
-                self.params,
-                tok_in,
-                lens_in,
-                halted_in,
-                self._page_table.copy(),
-                self.pool.k,
-                self.pool.v,
-                self._rng,
-                self._temps.copy(),
-                self._top_ks.copy(),
-                budgets,
-                lp_sum_in,
-                lp_min_in,
-                lp_cnt_in,
-                steps=steps, **moe_acc,
-            )
-            self.total_sub_steps += steps
-            spec = False
-            kv_pages = self._kv_pages(budgets, int(steps))
+                stamp.out = tok_in
+
+        # the tick's packed tokens carry its completion stamp: the harvest
+        # fetches them, and no program takes them as an input
+        with dispatching("decode", self.tick_step) as stamp:
+            if self._spec_tick is not None:
+                self._ensure_draft_cache()
+                packed, tok_out, lens_out, halted_out, self.pool.k, self.pool.v, \
+                    self._spec_dk, self._spec_dv, self._rng = self._spec_tick(
+                        self.params, self.draft_params, tok_in, lens_in,
+                        halted_in, self._page_table.copy(), self.pool.k,
+                        self.pool.v, self._spec_dk, self._spec_dv, self._rng,
+                        self._temps.copy(), budgets,
+                        # + k + 1 slack: dynamic_update_slice CLAMPS a start
+                        # index whose k+1-wide update would overhang, silently
+                        # corrupting the tail rounds' token offsets otherwise
+                        k=self.spec_k, out_w=int(steps) + self.spec_k + 1,
+                    )
+                spec = True
+                kv_pages = None  # the spec tick does not run the decode kernel
+                # the spec tick has its own accept/correct rule and samples no
+                # per-token logprobs; the accumulators thread through UNCHANGED
+                # (stale first-token seeds) and the host mirrors stay zeroed, so
+                # spec results report logprob_count == 0 — the confidence gate
+                # reads that as "no signal" and never skips verify on spec mode
+                lp_state = None
+                lp_sum_out, lp_min_out, lp_cnt_out = lp_sum_in, lp_min_in, lp_cnt_in
+            else:
+                # a routed family: the prefill programs' pairs ride this tick's fetch
+                moe_acc = {"moe_acc": self._take_moe_acc()} if self.routed else {}
+                (packed, lp_state, tok_out, lens_out, halted_out,
+                 lp_sum_out, lp_min_out, lp_cnt_out,
+                 self.pool.k, self.pool.v, self._rng, *picks) = self._step_n(
+                    self.params,
+                    tok_in,
+                    lens_in,
+                    halted_in,
+                    self._page_table.copy(),
+                    self.pool.k,
+                    self.pool.v,
+                    self._rng,
+                    self._temps.copy(),
+                    self._top_ks.copy(),
+                    budgets,
+                    lp_sum_in,
+                    lp_min_in,
+                    lp_cnt_in,
+                    steps=steps, **moe_acc,
+                )
+                self.total_sub_steps += steps
+                spec = False
+                kv_pages = self._kv_pages(budgets, int(steps))
+            stamp.out = packed
         self._dev_state = (tok_out, lens_out, halted_out,
                            lp_sum_out, lp_min_out, lp_cnt_out)
         for i, slot in enumerate(self.slots):
             if slot.active:
                 slot.inflight_steps += int(budgets[i])
         return {"packed": packed, "budgets": budgets, "spec": spec,
+                "stamp": stamp.seq,
                 "lp_state": lp_state,
                 # a routed family's picks of every sub-step, on the device
                 "picks": picks[0] if not spec and picks and self.keep_choices else None,
@@ -2437,6 +2479,7 @@ class ContinuousBatchingEngine:
         EOS (visible in packed) — identical to the device's halting rule."""
         budgets = record["budgets"]
         packed = np.asarray(record["packed"])
+        harvested(record["stamp"])  # a harvest long after its tick was done: a stall of the pump's
         spec = record.get("spec", False)
         if self.routed and not spec:  # the expert layers' counts: the last four rows
             record["moe"] = dict(zip(MOE_KINDS, (int(n) for n in packed[-4:, 0])))
@@ -2523,6 +2566,10 @@ class ContinuousBatchingEngine:
             self.prefill_latent_total[kind] += n
             self.last_tick_prefill_latent[kind] += n
             self._prefill_latent_pending[kind] = 0
+        for kind, n in self._prefill_turns_pending.items():
+            self.prefill_turns_total[kind] += n
+            self.last_tick_prefill_turns[kind] += n
+            self._prefill_turns_pending[kind] = 0
 
     def _kv_pages(self, budgets, steps: int) -> dict:
         """K/V page blocks of the ``steps`` sub-steps being dispatched, by

@@ -347,7 +347,7 @@ class PagedGenerationService:
                          parent=tracing.parent_for(request_id))
         if request_id:
             get_flight_recorder().note_engine_submit(
-                request_id, replica_id=self.replica_id)
+                request_id, t_submit=ticket.t_submit, replica_id=self.replica_id)
         try:
             with self._mutex:
                 self._admit_ticket_locked(ticket)
@@ -470,7 +470,7 @@ class PagedGenerationService:
                          parent=tracing.parent_for(request_id))
         if request_id:
             get_flight_recorder().note_engine_submit(
-                request_id, replica_id=self.replica_id)
+                request_id, t_submit=ticket.t_submit, replica_id=self.replica_id)
         try:
             with self._mutex:
                 self._admit_ticket_locked(ticket)
@@ -1145,6 +1145,7 @@ class PagedGenerationService:
                             top_k=ticket.top_k,
                             prior_tokens=ticket.prior_tokens,
                             seed=ticket.seed,
+                            trace_id=ticket.request_id,
                         )
                         self._tickets[rid] = ticket
                     self._inbox.clear()
@@ -1179,6 +1180,8 @@ class PagedGenerationService:
                 # device work runs WITHOUT any lock: the pump is the engine's
                 # only driver, and submitters must never wait on a decode tick
                 t_drain = time.perf_counter()
+                # what this iteration dispatches is stamped with its number
+                self.engine.tick_step = step_num
                 try:
                     finished = self.engine.step()
                     tick_dur_s = time.perf_counter() - t_drain
@@ -1218,7 +1221,8 @@ class PagedGenerationService:
                         )
                         metrics.record_tick_phases(phase_s)
                         metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
-                                                 row_steps["moe_pairs"], row_steps["prefill_latent"])
+                                                 row_steps["moe_pairs"], row_steps["prefill_latent"],
+                                                 row_steps["prefill_turns"])
                         for key, val in phase_s.items():
                             self._phase_totals[key] = (
                                 self._phase_totals.get(key, 0.0) + val
@@ -1349,6 +1353,10 @@ class PagedGenerationService:
                     tick_seq = recorder.record_tick(
                         **compile_fields,
                         **row_steps,
+                        # what the DEVICE ran, by program, stamped since the
+                        # previous record (beside phase_ms, the host's side),
+                        # and the stamps that record lost or took out of order
+                        **tracing.get_stamper().take_tick_fields(),
                         replica=self.replica_id,
                         # the number this iteration's decode_tick annotation
                         # carries in a profiler window (== tick unless another
@@ -1385,7 +1393,8 @@ class PagedGenerationService:
                     last_miss_toks = engine.prefix_miss_tokens_total
                     metrics.record_tick(tick_dur_s, int(active), queued + inbox)
                     metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
-                                                 row_steps["moe_pairs"], row_steps["prefill_latent"])
+                                             row_steps["moe_pairs"], row_steps["prefill_latent"],
+                                             row_steps["prefill_turns"])
                 except Exception:  # noqa: BLE001
                     logger.debug("tick telemetry failed", exc_info=True)
                 t_deliver_start = time.perf_counter()
@@ -1507,7 +1516,9 @@ class PagedGenerationService:
                 # a routed family's expert layers (zeros for any other)
                 "moe_pairs": dict(getattr(self.engine, "last_tick_moe", None) or {}),
                 # a latent family's prefill tokens, new and expanded (zeros for any other)
-                "prefill_latent": dict(getattr(self.engine, "last_tick_prefill_latent", None) or {})}
+                "prefill_latent": dict(getattr(self.engine, "last_tick_prefill_latent", None) or {}),
+                # chunked prefill's turns, taken and waited (zeros without PREFILL_CHUNK)
+                "prefill_turns": dict(getattr(self.engine, "last_tick_prefill_turns", None) or {})}
 
     def _note_ttft_locked(self, ttft_s: float) -> None:  # lock-held: _mutex
         """Fold one observed TTFT into the EMA admission control projects

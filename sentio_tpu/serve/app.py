@@ -552,7 +552,6 @@ async def embed(request: web.Request) -> web.Response:
     body = await _json_body(request)
     req = parse_embed_request(body, container.settings.serve)
     stats = await asyncio.to_thread(container.ingestor.ingest_document, req.content, req.metadata)
-    get_metrics().record_embeddings(container.settings.embedder.provider, stats.chunks_embedded)
     return web.json_response({"status": "ok", "stats": stats.to_dict()})
 
 
@@ -639,9 +638,6 @@ async def upload(request: web.Request) -> web.Response:
         if stats.errors:
             entry["error"] = "; ".join(str(e) for e in stats.errors[:3])
         files.append(entry)
-        get_metrics().record_embeddings(
-            container.settings.embedder.provider, stats.chunks_embedded
-        )
     if not files:
         raise SchemaError([{"field": "file", "error": "no file parts in form data"}])
     ok = any("error" not in f for f in files)

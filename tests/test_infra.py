@@ -478,12 +478,11 @@ class TestMetrics:
 
         m = MetricsCollector()
         m.record_request("/chat", 200, 0.12)
-        m.record_llm("generate", 0.5, tokens=64)
         m.record_row_steps({"useful": 3, "halted": 4, "empty": 9},
                            {"held": 21, "tabled": 128})
         snap = m.export_json()
         assert any("requests" in k for k in snap["counters"])
-        assert snap["gauges"]["tokens_per_s()"] == 128.0
+        assert not snap["gauges"]
         assert snap["counters"]["row_steps('empty',)"] == 9.0
         text = m.export_prometheus()
         assert b"sentio_requests_total" in text
@@ -492,7 +491,9 @@ class TestMetrics:
         assert b'sentio_tpu_decode_kv_pages_total{kind="tabled"} 128.0' in text
         # series no code wrote are gone, not exported empty
         for dead in (b"sentio_retrieval_latency", b"sentio_circuit_breaker_state",
-                     b"sentio_tpu_batch_occupancy", b"sentio_tpu_hbm_bytes_in_use"):
+                     b"sentio_tpu_batch_occupancy", b"sentio_tpu_hbm_bytes_in_use",
+                     b"sentio_tpu_decode_tokens_per_second", b"sentio_llm_tokens",
+                     b"sentio_llm_latency", b"sentio_embeddings"):
             assert dead not in text
 
     def test_track_request_context(self):
