@@ -90,7 +90,7 @@ def main(argv: list[str] | None = None) -> int:
         model_config=cfg, tokenizer=tokenizer, max_slots=4,
         page_size=args.page_size, max_pages_per_seq=4, steps_per_tick=8,
     )
-    logits, tokens, paged_attention = {}, {}, {}
+    logits, tokens, paged_attention, prefill_attention = {}, {}, {}, {}
     for name, (params, placed_on) in placements.items():
         out = np.asarray(prefill_logits(params, ids, positions), np.float32)
         logits[name] = out[mask]  # real positions only
@@ -98,6 +98,7 @@ def main(argv: list[str] | None = None) -> int:
         results = engine.run_all(list(PROMPTS), max_new_tokens=NEW_TOKENS)
         tokens[name] = [r.tokens for r in results]
         paged_attention[name] = engine.stats()["paged_attention"]
+        prefill_attention[name] = engine.stats()["prefill_attention"]
 
     ref, got = logits["one_device"], logits[f"tp{args.tp}"]
     finite = bool(np.isfinite(ref).all() and np.isfinite(got).all())
@@ -123,6 +124,7 @@ def main(argv: list[str] | None = None) -> int:
             sum(a == b for a, b in pairs) / max(len(pairs), 1), 4),
         "greedy_tokens_compared": len(pairs),
         "paged_attention": paged_attention,
+        "prefill_attention": prefill_attention,
         "seconds": round(time.perf_counter() - t_start, 1),
     }))
     return 0 if ok else 1

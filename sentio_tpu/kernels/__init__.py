@@ -1,12 +1,25 @@
-"""Pallas/TPU kernels: flash attention, ring (sequence-parallel) attention.
+"""Pallas/TPU kernels: flash attention (the encoders' bidirectional one and
+the causal one of training), the prefill's flash attention over a prior, ring
+(sequence-parallel) attention; the decode kernels (``paged_attention``,
+``latent_attention``) are built by the engine from their own modules.
 
 Every kernel has an XLA counterpart (models/layers.py:attention) so the
 whole framework runs on CPU; the kernels are SELECTED on TPU from what the
 code can observe (backend, mesh, head counts) — a kernel that fails to
 compile or run there is an error, never a reason to carry on with XLA.
-``flash_attn_fn`` is the adapter signature models accept
-(``llama_forward(..., attn_fn=...)``): (q, k, v, kv_lens) → [B, T, H, D]
-with causal semantics.
+
+Two adapter signatures reach a decoder's forward as ``attn_fn``:
+
+* ``flash_attn_fn`` / ``make_mesh_attn_fn`` / ``make_ring_attn_fn``: ``(q, k,
+  v, kv_lens) → [B, T, H, D]``, causal, keys spread to the query heads, the
+  query block at position 0 (training, whole-prompt prefill under a mesh);
+* ``make_prefill_attn_fn`` (``prefill_attention.py``; it carries
+  ``takes_prior``): ``(q, k, v, q_start, q_pe, k_pe, sm_scale=, window=)``
+  over the contiguous cache as it lies — grouped by index, causal by each
+  row's own first position, the walk ending at the row's own prior, a second
+  score term for keys wider than values, a static window. This is what
+  ``runtime/paged.py`` binds into its ``forward_fn`` for the two prefill
+  programs of every family, where it chooses the decode kernel.
 """
 
 from __future__ import annotations
@@ -14,11 +27,13 @@ from __future__ import annotations
 import jax
 
 from sentio_tpu.kernels.flash_attention import attention_auto, flash_attention
+from sentio_tpu.kernels.prefill_attention import make_prefill_attn_fn
 from sentio_tpu.kernels.ring_attention import ring_attention, ring_attention_sharded
 
 __all__ = [
     "flash_attention",
     "attention_auto",
+    "make_prefill_attn_fn",
     "ring_attention",
     "ring_attention_sharded",
     "flash_attn_fn",

@@ -270,9 +270,12 @@ def cohere2_forward(
     prefill / scoring contract of ``llama_forward`` (a fresh or primed
     contiguous cache whose index IS the position; ``cache_index`` a scalar
     or one offset a row), plus what the expert layers decided: ``routed =
-    {"experts": [L, B, T, k] int32 picks, "counts": [4] int32}``. ``attn_fn``
-    is accepted and unused: no flash kernel knows a window yet."""
-    del attn_fn
+    {"experts": [L, B, T, k] int32 picks, "counts": [4] int32}``. An
+    ``attn_fn`` that ``takes_prior`` (``kernels/prefill_attention.py``: it
+    knows a window) attends in place of :func:`windowed_attention` wherever
+    there is more than one query; any other knows no window and is ignored."""
+    if not getattr(attn_fn, "takes_prior", False):
+        attn_fn = None
     dt = cfg.jdtype
     b, t = ids.shape
     if cache is not None:
@@ -295,7 +298,12 @@ def cohere2_forward(
             key_ok = None       # causal by position hides the unwritten tail
         else:
             k_all, v_all, key_ok = k, v, pad_mask
-        attn = windowed_attention(q, k_all, v_all, positions, key_ok, cfg.window(i), dt)
+        if attn_fn is not None and t > 1:
+            # right pads lie past every real query: position alone hides them
+            attn = attn_fn(q.astype(dt), k_all.astype(dt), v_all.astype(dt),
+                           cache_index if cache is not None else 0, window=cfg.window(i)).reshape(b, t, -1)
+        else:
+            attn = windowed_attention(q, k_all, v_all, positions, key_ok, cfg.window(i), dt)
         routed, chosen, n = expert_layer(lp["moe"], cfg, h, pad_mask)
         x = x + L.dense(lp["attn"]["wo"], attn, dt) + routed
         picks.append(chosen)

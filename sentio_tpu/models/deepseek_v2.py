@@ -404,9 +404,12 @@ def deepseek_v2_forward(
     every layer writes its new latents and EXPANDS the whole cache, prior
     included, to keys and values. ``routed = {"experts": [Lr, B, T, k],
     "groups": [Lr, B, T, topk_group] int32 picks of the routed layers,
-    "counts": [4] int32}``. ``attn_fn`` is accepted and unused: no flash
-    kernel takes keys wider than values yet."""
-    del attn_fn
+    "counts": [4] int32}``. An ``attn_fn`` that ``takes_prior``
+    (``kernels/prefill_attention.py``) attends in place of
+    :func:`expanded_attention` wherever there is more than one query; any
+    other is not this family's (keys wider than values) and is ignored."""
+    if not getattr(attn_fn, "takes_prior", False):
+        attn_fn = None
     dt = cfg.jdtype
     b, t = ids.shape
     if cache is not None:
@@ -430,7 +433,13 @@ def deepseek_v2_forward(
         else:
             key_ok = pad_mask
         k_nope, k_pe, v = expand_latents(ap, cfg, latents)
-        attn = expanded_attention(q_nope, q_pe, k_nope, k_pe, v, positions, key_ok, cfg.softmax_scale, dt)
+        if attn_fn is not None and t > 1:
+            # right pads lie past every real query: position alone hides them
+            attn = attn_fn(q_nope.astype(dt), k_nope, v, cache_index if cache is not None else 0,
+                           q_pe.astype(dt), k_pe, sm_scale=cfg.softmax_scale).reshape(b, t, -1)
+        else:
+            attn = expanded_attention(q_nope, q_pe, k_nope, k_pe, v, positions, key_ok,
+                                      cfg.softmax_scale, dt)
         x = x + L.dense(ap["wo"], attn, dt)
         out, chosen, n = mlp_or_experts(lp, cfg, L.rmsnorm(lp["mlp_norm"], x, cfg.norm_eps), pad_mask)
         x = x + out

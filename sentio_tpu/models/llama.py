@@ -202,11 +202,15 @@ def _attn(
     q = L.apply_rope(q, positions, cos, sin)
     k = L.apply_rope(k, positions, cos, sin)
 
-    # kernels (flash/ring) apply for multi-token causal attention where the
-    # query block starts at position 0 (prefill writes at slot 0, training has
-    # no cache) — exactly when positions == arange(t); decode (t == 1) and
-    # ragged offsets use the masked XLA path
+    # kernels apply to multi-token causal attention. One that ``takes_prior``
+    # (kernels/prefill_attention.py: the engine's choice for its prefill
+    # programs) is handed the cache as it lies and each row's first position:
+    # grouped by index, causal by position. The others (flash, ring) take
+    # keys spread to the query heads and a query block that starts at
+    # position 0 (prefill writes at slot 0, training has no cache). Decode
+    # (t == 1) is the masked XLA path
     use_kernel = attn_fn is not None and t > 1
+    takes_prior = use_kernel and getattr(attn_fn, "takes_prior", False)
 
     if cache is not None:
         # write this step's k/v into the cache window at cache_index, which is
@@ -216,26 +220,31 @@ def _attn(
         v_cache = _write_cache(cache["v"][layer], v.astype(dt), cache_index)
         cache["k"] = cache["k"].at[layer].set(k_cache)
         cache["v"] = cache["v"].at[layer].set(v_cache)
-        s = k_cache.shape[1]
-        # query i (absolute pos = positions[:, i]) attends keys j <= pos_i
-        kj = jnp.arange(s)[None, None, None, :]
-        mask = kj <= positions[:, None, :, None]  # [B,1,T,S]
         k_full, v_full = k_cache, v_cache
         kv_lens = None  # causal mask already hides the uninitialized tail
     else:
-        s = t
-        mask = L.causal_mask(t)
-        if pad_mask is not None:
-            mask = mask & pad_mask[:, None, None, :]
         k_full, v_full = k, v
         # right-padded batches → per-row valid lengths for the kernel
         kv_lens = pad_mask.sum(axis=1).astype(jnp.int32) if pad_mask is not None else None
+
+    if takes_prior:
+        # right pads lie past every real query, so position alone hides them
+        out = attn_fn(q, k_full, v_full, cache_index if cache is not None else 0).reshape(b, t, h * hd)
+        return L.dense(lp["wo"], out, dt), cache
 
     k_full = L.repeat_kv(k_full, h // hkv)
     v_full = L.repeat_kv(v_full, h // hkv)
     if use_kernel:
         out = attn_fn(q, k_full, v_full, kv_lens).reshape(b, t, h * hd)
     else:
+        if cache is not None:
+            # query i (absolute pos = positions[:, i]) attends keys j <= pos_i
+            kj = jnp.arange(k_full.shape[1])[None, None, None, :]
+            mask = kj <= positions[:, None, :, None]  # [B,1,T,S]
+        else:
+            mask = L.causal_mask(t)
+            if pad_mask is not None:
+                mask = mask & pad_mask[:, None, None, :]
         out = L.attention(q, k_full, v_full, mask, dt).reshape(b, t, h * hd)
     return L.dense(lp["wo"], out, dt), cache
 
@@ -314,7 +323,8 @@ def llama_forward(
       ragged batch, ``positions = lens[:, None]`` and ``cache_index = lens``
       ([B] vector) so each row writes/reads at its own offset.
     * ``attn_fn`` (see sentio_tpu.kernels): flash/ring kernel used for the
-      multi-token causal paths (training + prefill); decode stays XLA.
+      multi-token causal paths (training + prefill), or the prefill kernel
+      that knows a prior (``takes_prior``); decode stays XLA.
     """
     dt = cfg.jdtype
     b, t = ids.shape

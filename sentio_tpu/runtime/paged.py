@@ -47,6 +47,7 @@ so masked lanes in the fused decode step write garbage somewhere harmless.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import logging
 import time
@@ -1061,7 +1062,7 @@ class ContinuousBatchingEngine:
         # (heads-sharded pool). int8 pools go through the same walk with
         # their scale pages copied beside the int8 pages, so kv_quant="int8"
         # keeps the fast path
-        asked = use_pallas
+        asked = self._use_pallas_asked = use_pallas
         if use_pallas is None:
             use_pallas = jax.default_backend() == "tpu"
         if use_pallas and jax.default_backend() == "tpu":
@@ -1089,6 +1090,36 @@ class ContinuousBatchingEngine:
             from sentio_tpu.kernels.paged_attention import make_paged_attn_impl
 
             self._attn_impl = make_paged_attn_impl(mesh=mesh)
+        # The prefill programs' attention is chosen HERE too, by the same
+        # ask: the flash kernel that knows a prior (kernels/
+        # prefill_attention.py) on TPU, and where a test asks for it on the
+        # CPU (interpret mode), bound into ``self.forward_fn`` so that every
+        # caller of the engine's forward — the two prefill programs, the
+        # benchmark's reference check — runs the one path. It has its own
+        # geometry rule (a head must be whole lane tiles), so decode may keep
+        # its XLA gather where prefill takes its kernel. Left as XLA, with the
+        # line logged: under a mesh (the kernel has no shard_map wrapper
+        # yet), and a forward the caller brought (its attention is its own)
+        self._family_forward = self.forward_fn
+        self._prefill_attn = None
+        if asked or (asked is None and jax.default_backend() == "tpu"):
+            from sentio_tpu.kernels.prefill_attention import make_prefill_attn_fn, prefill_untiled
+
+            why = ""
+            if self.forward_fn not in (llama_forward, moe_serving_forward, cohere2_forward,
+                                       deepseek_v2_forward):
+                why = "the caller brought its own forward_fn"
+            elif mesh is not None:
+                why = "the prefill kernel runs on one device a process, and this engine has a mesh"
+            elif jax.default_backend() == "tpu":
+                why = prefill_untiled(self.cfg.qk_nope_head_dim if self.latent else self.cfg.head_dim,
+                                      self.cfg.v_head_dim if self.latent else self.cfg.head_dim)
+            if why:
+                logging.getLogger(__name__).warning(
+                    "prefill attention runs the XLA form, not the flash kernel: %s", why)
+            else:
+                self._prefill_attn = make_prefill_attn_fn()
+                self.forward_fn = functools.partial(self.forward_fn, attn_fn=self._prefill_attn)
         self._build_fns()
 
     # ------------------------------------------------------------- compiled
@@ -1360,7 +1391,7 @@ class ContinuousBatchingEngine:
 
             dcfg = self.draft_cfg
             self._spec_tick = build_spec_tick(
-                self.forward_fn, cfg, _draft_fwd, dcfg,
+                self._family_forward, cfg, _draft_fwd, dcfg,
                 eos_id=self.tokenizer.eos_id, ignore_eos=self.ignore_eos,
                 page_size=self.page_size,
             )
@@ -1622,13 +1653,13 @@ class ContinuousBatchingEngine:
             # cannot see but the runtime ThreadGuard enforces
             num_pages=self.allocator.num_pages,
             max_pages_per_seq=self.max_pages_per_seq,
-            use_pallas=self._attn_impl is not None,
+            use_pallas=self._use_pallas_asked,
             steps_per_tick=self.steps_per_tick,
             max_tick_steps=self.max_tick_steps,
             ignore_eos=self.ignore_eos,
             pipeline_depth=self.pipeline_depth,
             mesh=self.mesh,
-            forward_fn=self.forward_fn,
+            forward_fn=self._family_forward,
             kv_quant=self.kv_quant,
             prefill_chunk=self.prefill_chunk,
             draft_params=self.draft_params,
@@ -2695,6 +2726,9 @@ class ContinuousBatchingEngine:
             # which decode-attention path the constructor SELECTED (the
             # Pallas page-table walk on TPU, the XLA gather elsewhere)
             "paged_attention": "pallas" if self._attn_impl is not None else "xla",
+            # and which attention its prefill programs run: the flash kernel
+            # that knows a prior, or the family's XLA form
+            "prefill_attention": "pallas" if self._prefill_attn is not None else "xla",
             "pool_hbm_bytes": self.pool.hbm_bytes,
             "head_skips": self._head_skips,
             "ttft_count": self.ttft_count,

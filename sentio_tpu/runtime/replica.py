@@ -2583,6 +2583,7 @@ class ReplicaSet:
         agg["page_size"] = first.get("page_size")
         agg["kv_quant"] = first.get("kv_quant")
         agg["paged_attention"] = first.get("paged_attention")
+        agg["prefill_attention"] = first.get("prefill_attention")
         agg["n_replicas"] = len(per)
         agg["replicas"] = per
         with self._mutex:

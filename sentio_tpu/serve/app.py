@@ -735,10 +735,12 @@ async def info(request: web.Request) -> web.Response:
                 "model": _model_config_of(decoder),
                 "verifier": settings.generator.use_verifier,
                 # the paged decode path as the engine resolved it: page
-                # representation, and whether decode attention is the
-                # Pallas page-table walk or the XLA gather
+                # representation, whether decode attention is the Pallas
+                # page-table walk or the XLA gather, and whether prefill
+                # attention is the flash kernel that knows a prior
                 "kv_quant": serving.get("kv_quant"),
                 "paged_attention": serving.get("paged_attention"),
+                "prefill_attention": serving.get("prefill_attention"),
                 "pool_hbm_bytes": serving.get("pool_hbm_bytes"),
                 # a latent family only: what ONE token leaves in the pool a
                 # layer (1,152 B at 512 + 64 in bf16)

@@ -21,12 +21,13 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from sentio_tpu.kernels.flash_attention import flash_attention
+from sentio_tpu.kernels.prefill_attention import make_prefill_attn_fn, prefill_attention
 from sentio_tpu.kernels.paged_attention import (
     make_paged_attn_impl,
     paged_attention,
     paged_attention_quant,
 )
-from sentio_tpu.models.llama import LlamaConfig, init_llama, llama_forward, serving_layout
+from sentio_tpu.models.llama import LlamaConfig, init_cache, init_llama, llama_forward, serving_layout
 from sentio_tpu.parallel.mesh import MESH_AXES
 from sentio_tpu.parallel.sharding import LLAMA_TP_RULES, make_param_shardings
 from sentio_tpu.runtime.paged import _latent_tokens, paged_decode_forward, scatter_prefill
@@ -98,6 +99,27 @@ def _flash_case(shape: tuple, causal: bool):
     return build
 
 
+PRIOR_KEYS = 40 * 128 + 512   # the long mix's widest program: a 40-page prior and the segment
+
+
+def _prefill_attn_case(rows: int, heads: int, hkv: int, window=None, rope: int = 0):
+    """The prefill's flash kernel (kernels/prefill_attention.py) at a cell's
+    shape: a 512-token segment of ``rows`` rows over a 40-page prior bucket;
+    ``rope`` > 0 adds the latent family's second score term (ONE rotated key
+    a position) and its own softmax scale."""
+
+    def build(topo):
+        place = _on_one_chip(topo)
+        q, kv = place((rows, 512, heads, D), jnp.bfloat16), place((rows, PRIOR_KEYS, hkv, D), jnp.bfloat16)
+        args = [q, kv, kv, place((rows,), jnp.int32)]
+        if rope:
+            args += [place((rows, 512, heads, rope), jnp.bfloat16), place((rows, PRIOR_KEYS, rope), jnp.bfloat16)]
+        return (functools.partial(prefill_attention, window=window, sm_scale=0.1147 if rope else None),
+                tuple(args))
+
+    return build
+
+
 def _tp4_case(quant: bool, hkv: int = HKV):
     """The decode kernel inside shard_map over a tp=4 mesh of the four
     described chips: pool and query heads sharded the way init_pool and the
@@ -153,6 +175,16 @@ CASES = {
     # 4 kv heads over tp=4: one a device
     "paged-bf16-tp4-mesh-1kv": _tp4_case(quant=False, hkv=4),
     "paged-int8-tp4-mesh-1kv": _tp4_case(quant=True, hkv=4),
+    # the prefill's flash kernel at the cells' shapes: mistral's 4 query heads
+    # a kv head and yi's 8, a segment alone and four rows of an admission; the
+    # latent family's 128 heads with keys 128 + 64 wide over values of 128;
+    # command-a's 16 query heads a kv head inside its window
+    "prefill-attn-mistral-rows1": _prefill_attn_case(1, H, 8),
+    "prefill-attn-mistral-rows4": _prefill_attn_case(4, H, 8),
+    "prefill-attn-yi-rows1": _prefill_attn_case(1, H, 4),
+    "prefill-attn-yi-rows4": _prefill_attn_case(4, H, 4),
+    "prefill-attn-dsv2-second-term": _prefill_attn_case(1, 128, 128, rope=64),
+    "prefill-attn-commanda-window": _prefill_attn_case(1, 128, 8, window=4096),
 }
 # the geometries whose pages the chip's DMA cannot bring (kernels/
 # paged_attention.py ``untiled``): XLA does not store such a pool in the
@@ -331,9 +363,12 @@ def _decoder_programs(topo, width: str, served: bool, tp: int = 1):
 
         return jax.lax.scan(body, (tok, lens, k_pages, v_pages), None, length=2)[0]
 
+    # as the engine binds it: the flash kernel on one chip, the XLA form under a mesh
+    attn_fn = make_prefill_attn_fn(interpret=False) if tp == 1 else None
+
     def prefill(params, ids, positions, cache, n_prior):
         return llama_forward(params, cfg, ids, positions=positions, cache=cache,
-                             cache_index=n_prior)
+                             cache_index=n_prior, attn_fn=attn_fn)
 
     cache = place((cfg.n_layers, 1, 512 + segment, cfg.n_kv_heads, cfg.head_dim),
                   jnp.bfloat16, heads)
@@ -466,7 +501,7 @@ def commanda_programs(v5e):
 
     def prefill(params, ids, positions, cache, n_prior):
         return cohere2_forward(params, cfg, ids, positions=positions, cache=cache,
-                               cache_index=n_prior)
+                               cache_index=n_prior, attn_fn=make_prefill_attn_fn(interpret=False))
 
     cache = place((LAYERS, 1, 512 + segment, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
     # the code asks the backend which grouped matmul to take and sees the CPU
@@ -552,7 +587,8 @@ def deepseek_programs(v5e):
         cache = jnp.zeros((LAYERS, 1, nb * page + segment, 1, cfg.latent_dim), jnp.bfloat16)
         cache = cache.at[:, :, : nb * page, 0].set(_latent_tokens(pages, (slice(None), prior_table)))
         logits, cache, routed = deepseek_v2_forward(params, cfg, ids, positions=positions,
-                                                    cache={"k": cache, "v": None}, cache_index=n_prior)
+                                                    cache={"k": cache, "v": None}, cache_index=n_prior,
+                                                    attn_fn=make_prefill_attn_fn(interpret=False))
         new = jax.lax.dynamic_slice_in_dim(cache["k"], n_prior[0], segment, axis=2)
         return logits[:, -1], scatter_prefill(pages, None, new, None, scat)[0], routed["counts"]
 
@@ -606,3 +642,106 @@ def test_deepseek_decode_step_holds_its_kernels_and_its_pool(deepseek_programs):
                for _n, _s, what in made), made
     # beside arguments it donates, the step needs little: no pool-sized temporary
     assert memory["step"].temp_size_in_bytes < 2 * LAYERS * 321 * 128 * 1152
+
+
+# ------------------------------------------- the prefill's attention (PR 41)
+#
+# ``paged.prior_prefill_scatter`` whole, once a family, as the engine runs it on
+# the chip: the prior primed from the pool, the forward with the flash kernel
+# bound (kernels/prefill_attention.py), the segment's own rows scattered back.
+# What the kernel is there for: no float32 array whose last dimension is the
+# keys' — the ``[.., T, S]`` scores the XLA forms wrote to HBM, masked, softmaxed
+# and read again — and what PR 29 and PR 31 trapped: nothing but the scatter in
+# place makes an array the size of the pool.
+
+
+def _scores_in_hbm(text: str, keys: int) -> list:
+    """Instructions of the compiled text that make a float32 array whose last
+    dimension is the keys' and that has more than one row of them."""
+    found = []
+    for line in text.splitlines():
+        inst = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = f32\[([\d,]+)\]\S* [\w\-]+\(", line)
+        if inst:
+            dims = [int(n) for n in inst.group(1).split(",")]
+            if dims[-1] == keys and np.prod(dims) > keys:
+                found.append(line.strip()[:120])
+    return found
+
+
+def _dense_prior_prefill(topo, kernel: bool) -> tuple:
+    """mistral's widths, two layers: one 512-token segment behind a 40-page
+    prior out of a pool of 16 slots x 40 pages → (compiled text, pool shape)."""
+    w = dict(WIDTHS["mistral"])
+    slots, nb, segment, page = w.pop("slots"), 40, w.pop("segment"), 128
+    w.pop("nb")
+    cfg = LlamaConfig(n_layers=LAYERS, max_len=8192, **w)
+    place = _on_one_chip(topo)
+    params = jax.eval_shape(lambda: serving_layout(init_llama(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree_util.tree_map(
+        lambda a: place(a.shape, jnp.bfloat16 if a.ndim == 2 else a.dtype), params)
+    pool_shape = (LAYERS, 1 + slots * nb, page, cfg.n_kv_heads, cfg.head_dim)
+    attn_fn = make_prefill_attn_fn(interpret=False) if kernel else None
+
+    def prior_prefill(params, ids, positions, k_pages, v_pages, prior_table, n_prior, scat):
+        cache = init_cache(cfg, 1, nb * page + segment)
+
+        def prime(arr, pages):
+            dense = pages[:, prior_table]                      # [L, B, PNB, page, Hkv, D]
+            return arr.at[:, :, : nb * page].set(dense.reshape(LAYERS, 1, nb * page, *dense.shape[4:]))
+
+        cache = {"k": prime(cache["k"], k_pages), "v": prime(cache["v"], v_pages)}
+        logits, cache = llama_forward(params, cfg, ids, positions=positions, cache=cache,
+                                      cache_index=n_prior, attn_fn=attn_fn)
+        new = [jax.lax.dynamic_slice_in_dim(cache[name], n_prior[0], segment, axis=2) for name in "kv"]
+        return (logits[:, -1], *scatter_prefill(k_pages, v_pages, *new, scat))
+
+    pool = place(pool_shape, jnp.bfloat16)
+    text = jax.jit(prior_prefill, donate_argnums=(3, 4)).lower(
+        params, place((1, segment), jnp.int32), place((1, segment), jnp.int32), pool, pool,
+        place((1, nb), jnp.int32), place((1,), jnp.int32),
+        place((1, segment // page), jnp.int32)).compile().as_text()
+    return text, pool_shape
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["flash-kernel", "xla-form-control"])
+def test_dense_prior_prefill_keeps_its_scores_out_of_hbm(v5e, kernel):
+    text, pool_shape = _dense_prior_prefill(v5e, kernel)
+    scores = _scores_in_hbm(text, PRIOR_KEYS)
+    if not kernel:   # the control: the parse finds what the XLA form writes, a layer
+        assert len(scores) >= LAYERS, scores
+        assert "%prefill_attention" not in text
+        return
+    assert scores == []
+    assert len(re.findall(r"%prefill_attention[.\d]* = ", text)) == LAYERS
+    view = (*pool_shape[:2], pool_shape[2] * pool_shape[3], pool_shape[4])
+    made = _pool_shaped(text, tuple("bf16[" + ",".join(map(str, dims)) + "]" for dims in (pool_shape, view)))
+    assert {op for _n, _s, op in made} <= {*IN_PLACE, "get-tuple-element"}, made
+    assert sum(op == "fusion:scatter" for _n, _s, op in made) == 2     # K and V, in place
+
+
+def test_latent_prior_prefill_holds_the_flash_kernel(deepseek_programs):
+    """The latent family's segment behind a 40-page prior: one kernel call a
+    layer with the second score term, the expansion writing K and V where the
+    kernel reads them (no copy of a ``[S, 128 x 128]`` array), no float32 score
+    block, and the pool — primed from and scattered to — updated in place."""
+    cfg, _, texts, memory = deepseek_programs
+    text = texts["prefill"]
+    assert len(re.findall(r"%prefill_attention[.\d]* = ", text)) == LAYERS
+    assert _scores_in_hbm(text, PRIOR_KEYS) == []
+    expanded = PRIOR_KEYS * cfg.n_heads * cfg.v_head_dim
+    for line in text.splitlines():
+        inst = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = bf16\[([\d,]+)\]\S* (copy|transpose|reshape)\(", line)
+        assert not (inst and np.prod([int(n) for n in inst.group(1).split(",")]) >= expanded), line[:160]
+    pool = f"bf16[{LAYERS},321,{cfg.latent_dim},128]"
+    made = [op for _n, _s, op in _pool_shaped(text, (pool,))]
+    assert set(made) <= {"parameter", "get-tuple-element", "bitcast", "scatter", "fusion:scatter"}, made
+    # K and V of two layers' expansions and no 256 MB score block beside them
+    assert memory["prefill"].temp_size_in_bytes < 3 * 2 * expanded
+
+
+def test_commanda_prefill_holds_the_flash_kernel_on_both_layer_kinds(commanda_programs):
+    """A sliding and a full layer: one kernel call each (the window a static
+    term of the same kernel), 16 query heads a kv head by index."""
+    _, _, texts = commanda_programs
+    assert len(re.findall(r"%prefill_attention[.\d]* = ", texts["prefill"])) == LAYERS
+    assert _scores_in_hbm(texts["prefill"], 512 + 512) == []

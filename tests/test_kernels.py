@@ -1,6 +1,8 @@
 """Kernel correctness: Pallas flash attention (interpret mode on the CPU
 test mesh) and ring attention (real ppermute collectives over the virtual
-8-device mesh) against the XLA reference attention."""
+8-device mesh) against the XLA reference attention — marked slow, class by
+class — and the prefill's flash kernel over a prior against the three XLA
+forms it replaces (tier-1: every case counts)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,7 @@ from sentio_tpu.kernels.ring_attention import ring_attention_sharded
 from sentio_tpu.models.layers import attention, causal_mask
 from sentio_tpu.parallel.mesh import build_mesh
 
-pytestmark = [pytest.mark.slow, pytest.mark.mesh]
+SLOW_MESH = [pytest.mark.slow, pytest.mark.mesh]
 
 
 def make_qkv(b, t, h, d, seed=0):
@@ -23,6 +25,8 @@ def make_qkv(b, t, h, d, seed=0):
 
 
 class TestFlashAttention:
+    pytestmark = SLOW_MESH
+
     def test_causal_matches_reference(self):
         q, k, v = make_qkv(2, 96, 4, 32)
         ref = attention(q, k, v, causal_mask(96), jnp.float32)
@@ -63,6 +67,8 @@ class TestFlashAttention:
 
 
 class TestRingAttention:
+    pytestmark = SLOW_MESH
+
     @pytest.fixture()
     def mesh(self):
         return build_mesh(MeshConfig(dp_size=2, sp_size=4, tp_size=1))
@@ -93,6 +99,8 @@ class TestRingAttention:
 
 
 class TestLlamaKernelIntegration:
+    pytestmark = SLOW_MESH
+
     def test_forward_with_flash_matches_xla(self):
         import jax
 
@@ -116,6 +124,8 @@ class TestLlamaKernelIntegration:
 
 
 class TestMeshAttnFn:
+    pytestmark = SLOW_MESH
+
     """make_mesh_attn_fn: kernels running INSIDE shard_map over the mesh —
     heads on tp, sequence-ring over sp — must match XLA attention."""
 
@@ -206,3 +216,88 @@ class TestMeshAttnFn:
         np.testing.assert_allclose(
             np.asarray(out)[1, :10], np.asarray(ref)[1, :10], atol=5e-2, rtol=5e-2
         )
+
+
+# ---------------------------------------------------- prefill over a prior
+#
+# kernels/prefill_attention.py in interpret mode against the XLA form each
+# family ran before it: ``layers.attention`` behind ``repeat_kv`` (dense),
+# ``cohere2_moe.windowed_attention`` (a window beside grouped queries),
+# ``deepseek_v2.expanded_attention`` (keys wider than values, ONE rotated key
+# a position). A page is 16 tokens here; every row's cache holds NaN past the
+# row's own last query, so a block the walk should not read — or a masked key
+# that reaches PV — poisons the answer.
+
+PAGE = 16
+# name → heads, kv heads, head width, segment, prior bucket (pages), each row's
+# first position, and what differs from plain causal grouped attention
+PREFILL_CASES = {
+    "gqa-4to1-rows-at-0-midblock-40pages": dict(h=8, hkv=2, d=16, t=512, bucket=40, starts=[0, 200, 40 * PAGE]),
+    "gqa-8to1": dict(h=8, hkv=1, d=16, t=512, bucket=8, starts=[8 * PAGE, 3 * PAGE]),
+    "gqa-16to1": dict(h=16, hkv=1, d=16, t=128, bucket=8, starts=[100]),
+    "gqa-16to1-window": dict(h=16, hkv=1, d=16, t=128, bucket=40, starts=[40 * PAGE, 77], window=96),
+    "latent-128-heads-second-term": dict(h=128, hkv=128, d=16, r=8, dv=32, t=512, bucket=40,
+                                         starts=[40 * PAGE], scale=0.31),
+    "latent-rows-differ": dict(h=4, hkv=4, d=16, r=8, dv=32, t=64, bucket=8, starts=[0, 37, 8 * PAGE],
+                               scale=0.31),
+    "pad-row": dict(h=8, hkv=2, d=16, t=64, bucket=4, starts=[4 * PAGE, 0], pad_rows=[1]),
+    "short-last-segment": dict(h=8, hkv=2, d=16, t=40, bucket=40, starts=[40 * PAGE, 9]),
+    "prior-smaller-than-its-bucket": dict(h=8, hkv=2, d=16, t=512, bucket=32, starts=[18 * PAGE]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PREFILL_CASES))
+def test_prefill_attention_matches_the_xla_form_it_replaces(case):
+    from sentio_tpu.kernels.prefill_attention import prefill_attention
+    from sentio_tpu.models.cohere2_moe import windowed_attention
+    from sentio_tpu.models.deepseek_v2 import expanded_attention
+    from sentio_tpu.models.layers import repeat_kv
+
+    c = PREFILL_CASES[case]
+    h, hkv, d, t = c["h"], c["hkv"], c["d"], c["t"]
+    dv, r, window = c.get("dv", d), c.get("r"), c.get("window")
+    starts = np.asarray(c["starts"], np.int32)
+    b, s = len(starts), c["bucket"] * PAGE + t
+    rng = np.random.default_rng(len(case))
+
+    def normal(*shape):
+        return rng.standard_normal(shape).astype(np.float32)
+
+    q, k = normal(b, t, h, d), normal(b, s, hkv, d)
+    v = rng.uniform(-1.0, 1.0, (b, s, hkv, dv)).astype(np.float32)   # outputs under 1: a bf16 step is 0.0039
+    q_pe, k_pe = (normal(b, t, h, r), normal(b, s, r)) if r else (None, None)
+    for row in c.get("pad_rows", ()):       # lens 0: pad tokens over the scratch page
+        q[row] = 0.0
+    bf = lambda x: None if x is None else jnp.asarray(x, jnp.bfloat16)  # noqa: E731
+    clean = [bf(x) for x in (q, k, v, q_pe, k_pe)]
+    for row, start in enumerate(starts):    # nothing past a row's last query may be read
+        for x in (k, v, k_pe):
+            if x is not None:
+                x[row, start + t:] = np.nan
+    got = prefill_attention(bf(q), bf(k), bf(v), jnp.asarray(starts), bf(q_pe), bf(k_pe),
+                            sm_scale=c.get("scale"), window=window, interpret=True)
+
+    q, k, v, q_pe, k_pe = clean
+    pos = jnp.asarray(starts)[:, None] + jnp.arange(t)[None, :]
+    if r:
+        ref = expanded_attention(q, q_pe, k, k_pe, v, pos, None, c["scale"], jnp.bfloat16)
+    elif window:
+        ref = windowed_attention(q, k, v, pos, None, window, jnp.bfloat16)
+    else:
+        mask = jnp.arange(s)[None, None, None, :] <= pos[:, None, :, None]
+        spread = [repeat_kv(x, h // hkv) for x in (k, v)]
+        ref = attention(q, *spread, mask, jnp.bfloat16)
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32).reshape(b, t, h, dv)
+    assert got.shape == ref.shape and np.isfinite(got).all()
+    real = [row for row in range(b) if row not in c.get("pad_rows", ())]
+    step = 2.0 ** -8    # one bf16 step of a value under 1: the decode kernels' 0.0039 (PERF.md section 5)
+    if r or window:
+        # these forms keep their scores in float32, as the kernel does
+        assert np.abs(got[real] - ref[real]).max() <= step
+    else:
+        # ``layers.attention`` rounds its scores to bf16 before the softmax: the kernel, which
+        # does not, may differ from it by what that rounding costs — and is held to being no
+        # further from the float32 answer than the form it replaces
+        exact = np.asarray(attention(*(x.astype(jnp.float32) for x in (q, *spread)), mask, jnp.float32))
+        assert np.abs(got[real] - ref[real]).max() <= 2 * step
+        assert np.abs(got[real] - exact[real]).max() <= max(np.abs(ref[real] - exact[real]).max(), step)
