@@ -127,9 +127,14 @@ def jit_family(
     *,
     static_argnames: tuple[str, ...] = (),
     donate_argnums: tuple[int, ...] = (),
+    donate_argnames: tuple[str, ...] = (),
     register: bool = True,
 ):
     """Decorator: ``jax.jit`` + registry entry + compile accounting.
+
+    ``donate_argnames``: keyword arguments donated beside the positional
+    ``donate_argnums`` — state only some families pass (None, an empty
+    pytree, for the others), so no positional signature moves for it.
 
     ``register=False`` builds the counting wrapper without touching the
     process-global registry — for test fixtures that must not make the
@@ -143,6 +148,7 @@ def jit_family(
             fn,
             static_argnames=tuple(static_argnames),
             donate_argnums=tuple(donate_argnums),
+            **({"donate_argnames": tuple(donate_argnames)} if donate_argnames else {}),
         )
         wrapped = FamilyFn(name, jitted)
         if register:

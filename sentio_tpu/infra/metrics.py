@@ -332,6 +332,21 @@ class MetricsCollector:
                 "tokens a latent family's prefill computed, and prior tokens it expanded",
                 ["kind"], registry=r,
             ),
+            # a family with convolution state (runtime/paged.py, counted on
+            # the host): what each prefill row started from — zeros, a cached
+            # page's tail (a radix hit), its own earlier segment's tail ...
+            "conv_starts": Counter(
+                "sentio_tpu_conv_state_starts_total",
+                "prefill rows of a family with convolution state, by what their state started from",
+                ["kind"], registry=r,
+            ),
+            # ... and the page tails written (prefill: the pages it filled;
+            # decode: a page that filled)
+            "conv_tail_pages": Counter(
+                "sentio_tpu_conv_tail_pages_total",
+                "page tails of convolution state written, by prefill and by decode",
+                registry=r,
+            ),
             # chunked prefill's turns (runtime/paged.py::_advance_prefill:
             # one segment a tick over all slots): a tick in which n slots
             # hold a pending segment books one taken and n - 1 waited.
@@ -588,16 +603,20 @@ class MetricsCollector:
     def record_row_steps(self, counts: dict, kv_pages: Optional[dict] = None,
                          moe: Optional[dict] = None,
                          prefill_latent: Optional[dict] = None,
-                         prefill_turns: Optional[dict] = None) -> None:
+                         prefill_turns: Optional[dict] = None,
+                         conv_state: Optional[dict] = None) -> None:
         """One harvested tick's row-steps by kind (useful / halted / empty),
         the K/V page blocks of its sub-steps (held / tabled), of a routed
         family its expert layers' pairs (routed / held) and expert-steps
         (held / touched) — ``MOE_KINDS`` as ``<series>_<kind>`` — of a
-        latent family its prefill tokens (new / expanded), and chunked
-        prefill's turns (taken / waited)."""
+        latent family its prefill tokens (new / expanded), chunked
+        prefill's turns (taken / waited), and of a family with convolution
+        state what its prefill rows started from (zero / tail / carried) and
+        the page tails written."""
         if not self.enabled:
             return
         from sentio_tpu.infra.phases import (
+            CONV_START_KINDS,
             KV_PAGE_KINDS,
             PREFILL_LATENT_KINDS,
             PREFILL_TURN_KINDS,
@@ -613,8 +632,9 @@ class MetricsCollector:
                 ("moe_expert_steps", ("held", "touched"),
                  {k: moe.get(f"experts_{k}", 0) for k in ("held", "touched")}),
                 ("prefill_latent", PREFILL_LATENT_KINDS, prefill_latent or {}),
+                ("conv_starts", CONV_START_KINDS, conv_state or {}),
                 ("prefill_turns", PREFILL_TURN_KINDS, prefill_turns or {})):
-            if name.startswith(("moe", "prefill_latent")) and not any(tick.values()):
+            if name.startswith(("moe", "prefill_latent", "conv")) and not any(tick.values()):
                 continue  # no series where no such family is served
             counter = self._prom.get(name)
             for kind in kinds:
@@ -622,6 +642,11 @@ class MetricsCollector:
                 self.memory.inc(name, (kind,), n)
                 if counter is not None:
                     counter.labels(kind=kind).inc(n)
+        pages = int((conv_state or {}).get("pages", 0))
+        if pages:
+            self.memory.inc("conv_tail_pages", (), pages)
+            if "conv_tail_pages" in self._prom:
+                self._prom["conv_tail_pages"].inc(pages)
 
     def record_device_program(self, program: str, seconds: float,
                               queued_s: float = 0.0) -> None:

@@ -112,6 +112,7 @@ __all__ = [
     "ROW_STEP_KINDS",
     "KV_PAGE_KINDS",
     "MOE_KINDS",
+    "CONV_STATE_KINDS",
     "PREFILL_LATENT_KINDS",
     "PREFILL_TURN_KINDS",
     "DEVICE_PROGRAMS",
@@ -199,6 +200,15 @@ MOE_KINDS = ("pairs_routed", "pairs_held", "experts_held", "experts_touched")
 # keys and values (a chunked prompt's every segment after the first, and every
 # radix hit, expands its whole prior)
 PREFILL_LATENT_KINDS = ("new", "expanded")
+
+# a family with convolution state (models/lfm2_moe.py; runtime/paged.py counts
+# on the host, books when a tick is harvested): what each row of a prefill
+# dispatch STARTED from — `zero` (position 0), `tail` (a cached page's stored
+# tail: a radix hit), `carried` (a chunked prompt's later segment, from the
+# tail its own earlier segment left) — and `pages`, the page tails written (by
+# prefill for the pages it filled, by decode when a page filled)
+CONV_STATE_KINDS = ("zero", "tail", "carried", "pages")
+CONV_START_KINDS = CONV_STATE_KINDS[:3]
 
 # chunked prefill's turns (runtime/paged.py::_advance_prefill dispatches ONE
 # segment a tick over all slots): a tick in which n slots hold a pending

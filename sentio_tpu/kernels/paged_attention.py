@@ -72,7 +72,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = float(np.finfo(np.float32).min)
 
 __all__ = ["paged_attention", "paged_attention_quant", "make_paged_attn_impl",
-           "blocks_walked", "first_block", "pages_in_flight", "untiled"]
+           "blocks_walked", "first_block", "pages_in_flight", "untiled", "lane_packing"]
 
 
 # VMEM the call's scratch may take with no limit of its own: under the 16 MiB
@@ -175,6 +175,19 @@ def untiled(page: int, hkv: int, head_dim: int, quant: bool) -> str | None:
     if quant and hkv % 2:
         return f"the scale pages of {hkv} kv head(s) do not fill a 32-bit sublane"
     return None
+
+
+def lane_packing(hkv: int, head_dim: int) -> int:
+    """How many kv heads share a row of a LANE-PACKED pool (``runtime/paged.py
+    ::init_pool``): heads narrower than a tile's 128 lanes lie side by side,
+    ``128 / head_dim`` to a row, where the kv heads make whole rows; 1 (the
+    pool as its shape says) for every other geometry. The walk then reads
+    ``[page * Hkv / pack, D * pack]`` matrices that ARE whole tiles; a query
+    head is handed over in the lanes of its own kv head, zeros in the others
+    (``make_paged_attn_impl``), so every score and every output is the
+    unpacked one: the other heads' lanes add exact zeros."""
+    pack = 128 // head_dim if 0 < head_dim < 128 and 128 % head_dim == 0 else 1
+    return pack if hkv % pack == 0 else 1
 
 
 def _walk_kernel(
@@ -328,7 +341,7 @@ def _walk_kernel(
     o_ref[:] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
 
 
-def _walk_pool(q, pools, layer, page_table, lens, interpret, window=None):
+def _walk_pool(q, pools, layer, page_table, lens, interpret, window=None, sm_scale=None):
     """The walk both representations share: grid ``(B,)``, ``pools`` whole
     in HBM — K and V pages ``[L, P, page, Hkv, D]`` and, quantized (four
     pools: K pages, K scales, V pages, V scales), their scales ``[L, P, Hkv,
@@ -375,7 +388,7 @@ def _walk_pool(q, pools, layer, page_table, lens, interpret, window=None):
     return pl.pallas_call(
         functools.partial(
             _walk_kernel, quant=quant, page=page, hkv=hkv, head_major=head_major,
-            depth=depth, sm_scale=1.0 / float(np.sqrt(d)), window=window),
+            depth=depth, sm_scale=sm_scale or 1.0 / float(np.sqrt(d)), window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -390,7 +403,7 @@ def _walk_pool(q, pools, layer, page_table, lens, interpret, window=None):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+@functools.partial(jax.jit, static_argnames=("interpret", "window", "sm_scale"))
 def paged_attention(
     q: jax.Array,           # [B, H, D] — one decode token per row
     k_pages: jax.Array,     # [L, P, page, Hkv, D] — the page pool, all layers
@@ -401,11 +414,14 @@ def paged_attention(
     *,
     interpret: bool = False,
     window: int | None = None,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """Decode attention over one layer of the paged pool → [B, H, D]. With a
     ``window`` a row's query sees keys ``lens - window < j <= lens`` and the
-    walk starts at the block that holds the oldest of them."""
-    return _walk_pool(q, (k_pages, v_pages), layer, page_table, lens, interpret, window)
+    walk starts at the block that holds the oldest of them. ``sm_scale``:
+    the softmax scale, ``D ** -0.5`` unless given (a lane-packed pool's rows
+    are wider than a head)."""
+    return _walk_pool(q, (k_pages, v_pages), layer, page_table, lens, interpret, window, sm_scale)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window"))
@@ -437,7 +453,11 @@ def make_paged_attn_impl(interpret: bool | None = None, mesh=None):
 
     Representation-aware: a plain array routes to the bf16 kernel, a
     ``{"q", "s"}`` pytree (the ``kv_quant="int8"`` pool) routes to the int8
-    kernel — so one engine attn seam serves both pool representations.
+    kernel — so one engine attn seam serves both pool representations. A
+    LANE-PACKED pool (rows wider than q's heads: :func:`lane_packing`) is
+    walked as the ``Hkv / pack`` heads of ``D * pack`` its shape says: each
+    query head goes in with its vector in the lanes of its own kv head and
+    zeros in the others, and comes out of those same lanes.
 
     With a ``mesh`` the kernel runs INSIDE ``shard_map`` over ``tp``: the
     pool is kv-head-sharded there (``runtime.paged.init_pool``) and query
@@ -455,6 +475,16 @@ def make_paged_attn_impl(interpret: bool | None = None, mesh=None):
                 v_pages["q"], v_pages["s"],
                 layer, page_table, lens, interpret=interpret, window=window,
             )
+        elif k_pages.shape[-1] != q.shape[-1]:
+            b, _, h, d = q.shape
+            pack = k_pages.shape[-1] // d
+            place = (jnp.arange(h) // n_rep) % pack          # a head's place in its kv head's row
+            lanes = jax.nn.one_hot(place, pack, dtype=q.dtype)[None, :, :, None]
+            out = paged_attention(
+                (q[:, 0][:, :, None, :] * lanes).reshape(b, h, pack * d), k_pages, v_pages,
+                layer, page_table, lens, interpret=interpret, window=window, sm_scale=d ** -0.5,
+            ).reshape(b, h, pack, d)
+            out = jnp.take_along_axis(out, place[None, :, None, None], axis=2)[:, :, 0]
         else:
             out = paged_attention(
                 q[:, 0], k_pages, v_pages, layer, page_table, lens,

@@ -268,8 +268,12 @@ def expert_layer(
     largest picked — among all experts, or with ``cfg.n_group`` over one
     inside the token's best ``cfg.topk_group`` groups (:func:`group_limited`;
     such a family gets those groups ``[B, T, topk_group]`` as a fourth
-    result). A pick's gate is its score, renormalised over the picks where
-    ``cfg.norm_topk_prob``, times ``cfg.routed_scaling_factor``. Of ``Σ w_e
+    result). Where the family has an expert bias (``mp["bias"] [E]``,
+    ``models/lfm2_moe.py``) the picks are the largest of ``score + bias``
+    and the bias goes no further. A pick's gate is its score, renormalised
+    over the picks where ``cfg.norm_topk_prob`` (over their sum plus
+    ``cfg.norm_topk_eps`` in a family that states one), times
+    ``cfg.routed_scaling_factor``. Of ``Σ w_e
     F_e(x)`` this computes the part whose expert is HELD here —
     ``mp["w_gate"]``, ``w_up`` ``[experts_held, D, F]`` and ``w_down``
     ``[experts_held, F, D]`` are experts ``expert_offset ..`` — and leaves
@@ -302,8 +306,16 @@ def expert_layer(
         if cfg.n_group > 1:
             with jax.named_scope("moe.groups"):
                 scores, groups = group_limited(scores, cfg.n_group, cfg.topk_group)
-        top, picks = jax.lax.top_k(scores, k)
-        gates = top / top.sum(-1, keepdims=True) if cfg.norm_topk_prob else top   # over the picks
+        if "bias" in mp:
+            # a selection-only bias: it decides the picks and is no part of a gate
+            _, picks = jax.lax.top_k(scores + mp["bias"].astype(jnp.float32), k)
+            top = jnp.take_along_axis(scores, picks, axis=-1)
+        else:
+            top, picks = jax.lax.top_k(scores, k)
+        gates = top
+        if cfg.norm_topk_prob:                                      # over the picks
+            total, eps = top.sum(-1, keepdims=True), getattr(cfg, "norm_topk_eps", 0.0)
+            gates = top / (total + eps if eps else total)           # no eps: the same program as ever
         if cfg.routed_scaling_factor != 1.0:
             gates = gates * cfg.routed_scaling_factor
         local = picks - cfg.expert_offset

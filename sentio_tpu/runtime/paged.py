@@ -3,8 +3,12 @@
 The reference caps context at ~2000 tokens and serves one request per HTTP
 call (/root/reference/src/core/graph/nodes.py:296-338, factory.py:90); its
 "batching" is a connection pool. Here the KV cache is *paged*: HBM holds one
-pool of fixed-size pages ([L, P, page, Hkv, D]) and every live sequence owns
-a page table mapping logical blocks to physical pages. That buys:
+pool of fixed-size pages ([L, P, page, Hkv, D]; ``L`` counts the layers that
+HAVE keys and values — every layer of most families, the attention layers
+alone of one whose other layers are convolutions, ``models/lfm2_moe.py``,
+which keeps two positions of state a convolution layer per decode slot and per
+page beside the pool) and every live sequence owns a page table mapping
+logical blocks to physical pages. That buys:
 
 * **continuous batching** — requests join and leave decode slots without
   recompiling or re-laying-out anyone else's cache; one compiled decode
@@ -61,8 +65,8 @@ from sentio_tpu.analysis.audit.registry import jit_family
 from sentio_tpu.analysis.sanitizer import check_engine_invariants, engine_guard
 from sentio_tpu.infra import faults
 from sentio_tpu.infra.phases import (
-    ENGINE_PHASES, KV_PAGE_KINDS, MOE_KINDS, PREFILL_LATENT_KINDS, PREFILL_TURN_KINDS,
-    ROW_STEP_KINDS, PhaseTimer,
+    CONV_STATE_KINDS, ENGINE_PHASES, KV_PAGE_KINDS, MOE_KINDS, PREFILL_LATENT_KINDS,
+    PREFILL_TURN_KINDS, ROW_STEP_KINDS, PhaseTimer,
 )
 from sentio_tpu.infra.tracing import annotation, dispatching, get_stamper, harvested
 from sentio_tpu.models.llama import LlamaConfig, qkv_proj, serving_layout
@@ -88,12 +92,23 @@ class PagedPool:
     page]`` — a page lies latent-major, its positions the lanes of a tile
     (``kernels/latent_attention.py`` says why) — and ``v`` is None: an empty
     pytree, which rides every signature, carry and donation as it is.
+    A family with CONVOLUTION layers (``models/lfm2_moe.py``) has pages for its
+    attention layers only — the pool's layer axis counts those, pool layer
+    ``cfg.attn_index(l)`` for model layer ``l`` — and two arrays beside them,
+    a convolution layer each: ``conv [Lc, slots, 2, d]``, the state a decode
+    slot carries (``z`` at its sequence's last two positions), and ``tail
+    [Lc, P, 2, d]``, ``z`` at the last two positions of every page, written by
+    prefill for the pages it fills and by decode when a page fills — what a
+    sequence that starts behind cached pages (a radix hit, a later chunk of
+    its own prompt) starts from. Both None for every other family.
     Page id 0 = scratch."""
 
     k: Array
     v: Array
     page_size: int
     quantized: bool = False
+    conv: Array = None
+    tail: Array = None
 
     @property
     def num_pages(self) -> int:
@@ -102,14 +117,22 @@ class PagedPool:
     @property
     def hbm_bytes(self) -> int:
         """Static device footprint of the k+v page pools (payload + scales
-        for the quantized repr) — the number the footprint claims are
-        audited by (bench phase A/C, the compile-manifest pools section)."""
+        for the quantized repr) and, where the family has one, of the
+        convolution state per slot and per page — the number the footprint
+        claims are audited by (bench phase A/C, the compile-manifest pools
+        section)."""
         import jax
 
         return sum(
             int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-            for leaf in jax.tree_util.tree_leaves((self.k, self.v))
+            for leaf in jax.tree_util.tree_leaves((self.k, self.v, self.conv, self.tail))
         )
+
+    @property
+    def conv_state_bytes(self) -> int:
+        """Of ``hbm_bytes``, the convolution state (0 for a family without)."""
+        return sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+                   for a in (self.conv, self.tail) if a is not None)
 
 
 def quantize_kv(x):
@@ -156,29 +179,37 @@ def _page_write(pages, layer, page_ids, offsets, val):
             # (layer, page_ids[b], :, offsets[b])
             "s": pages["s"].at[layer, page_ids, :, offsets].set(s),
         }
-    return pages.at[layer, page_ids, offsets].set(val)
+    # a position's vectors as the pool holds them (lane-packed: ``init_pool``)
+    return pages.at[layer, page_ids, offsets].set(val.reshape(val.shape[0], *pages.shape[-2:]))
 
 
 def _page_dim(pages) -> int:
     return (pages["q"] if isinstance(pages, dict) else pages).shape[-3]
 
 
-def _gather_pages(pages, layer, page_table, dtype):
+def _gather_pages(pages, layer, page_table, dtype, head_dim=None):
     """Pool [L, P, page, Hkv, D](-repr), a layer + table [B, NB] → that
     layer's pages, dense [B, NB*page, Hkv, D]. One gather on (layer, page):
-    no ``pages[layer]`` is formed on the way."""
+    no ``pages[layer]`` is formed on the way. ``head_dim`` tells a
+    lane-packed pool's heads apart again (``init_pool``)."""
     b, nb = page_table.shape
     if isinstance(pages, dict):
         kc = dequantize_pages(
             pages["q"][layer, page_table], pages["s"][layer, page_table], dtype)
     else:
         kc = pages[layer, page_table]
-    return kc.reshape(b, nb * kc.shape[2], *kc.shape[3:])
+    return kc.reshape(b, nb * kc.shape[2], -1, head_dim or kc.shape[-1])
 
 
 def is_latent(cfg) -> bool:
     """Whether ``cfg``'s family keeps a latent in place of K and V."""
     return getattr(cfg, "kv_lora_rank", 0) > 0
+
+
+def has_conv_state(cfg) -> bool:
+    """Whether ``cfg``'s family carries convolution state beside the pages
+    (``models/lfm2_moe.py``: its config names its convolution layers)."""
+    return bool(getattr(cfg, "conv_layers", ()))
 
 
 def _latent_tokens(pages, index):
@@ -204,13 +235,24 @@ def _latent_write(pages, layer, page_ids, offsets, latents):
 
 def init_pool(
     cfg: LlamaConfig, num_pages: int, page_size: int, mesh=None,
-    quantized: bool = False,
+    quantized: bool = False, slots: int = 0, pack: int = 1,
 ) -> PagedPool:
     """Allocate the page pool; with a mesh, kv heads shard over ``tp`` (the
     same axis the wk/wv weight columns shard on, so per-shard Q·K never
     crosses devices) and page tables stay replicated host-side. With
     ``quantized`` the pool stores int8 + per-vector scales — ~half the HBM
-    and half the decode-attention read bandwidth of bf16 pages."""
+    and half the decode-attention read bandwidth of bf16 pages. A family with
+    convolution layers gets pages for its attention layers and, for ``slots``
+    decode slots, the convolution state beside them (``PagedPool``).
+
+    ``pack`` > 1 is the pool's layout for heads NARROWER than the 128 lanes of
+    a tile (``kernels/paged_attention.py::lane_packing``): ``pack`` kv heads
+    share a row, ``[L, P, page, Hkv / pack, D * pack]`` — the same numbers in
+    the same order as ``[L, P, page, Hkv, D]``, a position's heads side by
+    side, in a shape whose pages ARE whole tiles, so the decode kernel's DMA
+    can bring them. Every reader and writer here reshapes what it holds to
+    the pool's last two axes (the update, never the pool); bf16 pages on one
+    device only."""
     import jax.numpy as jnp
 
     if is_latent(cfg):
@@ -220,7 +262,17 @@ def init_pool(
         pages = jnp.zeros((cfg.n_layers, num_pages, cfg.latent_dim, page_size), cfg.jdtype)
         return PagedPool(k=pages, v=None, page_size=page_size)
 
-    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    conv = tail = None
+    n_layers = cfg.n_layers
+    if has_conv_state(cfg):
+        if mesh is not None:
+            raise ValueError("convolution state is held on one device: it has no rule under a mesh yet")
+        n_layers, lc = len(cfg.attn_layers), len(cfg.conv_layers)
+        conv = jnp.zeros((lc, slots, cfg.conv_taps, cfg.dim), cfg.jdtype)
+        tail = jnp.zeros((lc, num_pages, cfg.conv_taps, cfg.dim), cfg.jdtype)
+    if pack > 1 and (quantized or mesh is not None or cfg.n_kv_heads % pack):
+        raise ValueError(f"pack={pack}: lane-packed pages are bf16, on one device, whole rows of heads")
+    shape = (n_layers, num_pages, page_size, cfg.n_kv_heads // pack, cfg.head_dim * pack)
 
     def alloc(arr_shape, dtype, spec=None):
         # born in its final placement: a pool zero-filled on the default
@@ -242,7 +294,7 @@ def init_pool(
         scale_spec = NamedSharding(mesh, P(None, None, AXIS_TP, None))
 
     if quantized:
-        scale_shape = (cfg.n_layers, num_pages, cfg.n_kv_heads, page_size)
+        scale_shape = (n_layers, num_pages, cfg.n_kv_heads, page_size)
         k = {"q": alloc(shape, jnp.int8, kv_spec),
              "s": alloc(scale_shape, jnp.bfloat16, scale_spec)}
         v = {"q": alloc(shape, jnp.int8, kv_spec),
@@ -250,7 +302,7 @@ def init_pool(
     else:
         k = alloc(shape, cfg.jdtype, kv_spec)
         v = alloc(shape, cfg.jdtype, kv_spec)
-    return PagedPool(k=k, v=v, page_size=page_size, quantized=quantized)
+    return PagedPool(k=k, v=v, page_size=page_size, quantized=quantized, conv=conv, tail=tail)
 
 
 class PageAllocator:
@@ -297,8 +349,8 @@ def _paged_attn_xla(q, k_pages, v_pages, layer, page_table, lens, n_rep, window=
 
     from sentio_tpu.models import layers as L
 
-    kc = _gather_pages(k_pages, layer, page_table, q.dtype)
-    vc = _gather_pages(v_pages, layer, page_table, q.dtype)
+    kc = _gather_pages(k_pages, layer, page_table, q.dtype, q.shape[-1])
+    vc = _gather_pages(v_pages, layer, page_table, q.dtype, q.shape[-1])
     kc = L.repeat_kv(kc, n_rep)
     vc = L.repeat_kv(vc, n_rep)
     kj = jnp.arange(kc.shape[1])[None, None, None, :]
@@ -309,7 +361,7 @@ def _paged_attn_xla(q, k_pages, v_pages, layer, page_table, lens, n_rep, window=
 
 
 def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_pages, v_pages,
-                         attn_impl=None, write_mask=None, return_routed=False):
+                         attn_impl=None, write_mask=None, return_routed=False, conv=None, tail=None):
     """One decode step over the paged pool.
 
     tok [B] int32 (last sampled token per slot); lens [B] absolute position
@@ -327,13 +379,19 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
     pages; ``v_pages`` is None and comes back None); ``return_routed`` then
     adds what its expert layers decided (``{"experts": [L, B, k] picks,
     "counts": [4]}``, a latent family's ``"groups"`` beside them) as a
-    fourth result, None for every other family.
+    fourth result, None for every other family. A family with CONVOLUTION
+    layers (``models/lfm2_moe.py``) is given its state — ``conv [Lc, B, 2,
+    d]``, a row a slot, and the page tails ``tail [Lc, P, 2, d]`` — and
+    returns six: the four, then both carried on.
     """
     import jax
     import jax.numpy as jnp
 
     from sentio_tpu.models import layers as L
 
+    if conv is not None:
+        return _paged_decode_conv(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail,
+                                  attn_impl, write_mask)
     if getattr(cfg, "parallel_block", False):
         out = _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
                                      attn_impl, write_mask)
@@ -521,6 +579,74 @@ def _paged_decode_latent(params, cfg, tok, lens, page_table, pages, attn_impl, w
     return logits, pages, None, {**routed, "counts": counts}
 
 
+def _paged_decode_conv(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail,
+                       attn_impl, write_mask):
+    """:func:`paged_decode_forward` for the family of ``models/lfm2_moe.py``:
+    sequential pre-norm blocks whose mixer is a gated short convolution over
+    the slot's carried state or attention over the pages (pool layer
+    ``cfg.attn_index(i)``), a dense MLP in the leading layers and routed
+    experts in the others. A row that advances shifts its state by this
+    token's ``z`` and, at the last two positions of its page, leaves ``z`` in
+    that page's tail; a row that does not advance (``write_mask`` false)
+    keeps its state and writes no tail. → (logits [B, V], k_pages, v_pages,
+    routed, conv, tail)."""
+    import jax
+    import jax.numpy as jnp
+
+    from sentio_tpu.models import layers as L
+    from sentio_tpu.models import lfm2_moe as M
+
+    dt = cfg.jdtype
+    b = tok.shape[0]
+    page = _page_dim(k_pages)
+    positions = lens[:, None]
+    page_ids = jnp.take_along_axis(page_table, (lens // page)[:, None], axis=1)[:, 0]
+    offsets = lens % page
+    advancing = jnp.ones((b,), bool) if write_mask is None else write_mask
+    # the tail's column this position is (negative: not one of the page's last
+    # two); a row with nothing to leave indexes past the pool, and is dropped
+    column = offsets - (page - cfg.conv_taps)
+    tail_ids = jnp.where(advancing & (column >= 0), page_ids, tail.shape[1])
+    column = jnp.maximum(column, 0)
+    attn_lens, valid = lens, None
+    if write_mask is not None:  # as in the sequential block, above
+        page_ids = jnp.where(write_mask, page_ids, 0)
+        offsets = jnp.where(write_mask, offsets, 0)
+        attn_lens = jnp.where(write_mask, lens, 0)
+        valid = write_mask[:, None]
+    impl = attn_impl or _paged_attn_xla
+
+    x = L.embed(params["embed_tokens"], tok[:, None], dt)
+    picks, counts = [], jnp.zeros((4,), jnp.int32)
+    for i in range(cfg.n_layers):
+        lp = params[f"layers_{i}"]
+        u = L.rmsnorm(lp["op_norm"], x, cfg.norm_eps)
+        if cfg.kinds[i] == M.CONV:
+            j = cfg.conv_index(i)
+            # a segment of one token: the state shifted by this token's z
+            out, shifted, _ = M.conv_segment(lp["conv"], cfg, u, conv[j], None)
+            conv = conv.at[j].set(jnp.where(advancing[:, None, None], shifted, conv[j]))
+            tail = tail.at[j, tail_ids, column].set(shifted[:, -1], mode="drop")
+        else:
+            a = cfg.attn_index(i)
+            q, k, v = M.qk_normed(lp["attn"], cfg, u, positions)
+            k_pages = _page_write(k_pages, a, page_ids, offsets, k[:, 0].astype(dt))
+            v_pages = _page_write(v_pages, a, page_ids, offsets, v[:, 0].astype(dt))
+            with jax.named_scope("attn.full"):
+                attn = impl(q, k_pages, v_pages, a, page_table, attn_lens, cfg.n_heads // cfg.n_kv_heads)
+            out = L.dense(lp["attn"]["wo"], attn.reshape(b, 1, -1), dt)
+        x = x + out
+        # a row that does not advance is routed nowhere (see the parallel block)
+        out, chosen, n = M.mlp_or_experts(lp, cfg, L.rmsnorm(lp["ffn_norm"], x, cfg.norm_eps), valid)
+        x = x + out
+        if chosen is not None:
+            picks.append(chosen[:, 0])
+            counts = counts + n
+    logits = M.head_logits(params, cfg, x)[:, 0]
+    routed = {"experts": jnp.stack(picks)} if picks else {}
+    return logits, k_pages, v_pages, {**routed, "counts": counts}, conv, tail
+
+
 def scatter_prefill(k_pages, v_pages, k_cache, v_cache, page_table):
     """Copy a contiguous prefill cache into the pool.
 
@@ -539,9 +665,8 @@ def scatter_prefill(k_pages, v_pages, k_cache, v_cache, page_table):
     nb = s // page
 
     def scatter_one(pages, cache):
-        r = cache.reshape(lcount, b, nb, page, hkv, hd)
         if isinstance(pages, dict):
-            q, sc = quantize_kv(r)
+            q, sc = quantize_kv(cache.reshape(lcount, b, nb, page, hkv, hd))
             return {
                 "q": pages["q"].at[:, page_table].set(q),
                 "s": pages["s"].at[:, page_table].set(sc.swapaxes(-1, -2)),
@@ -551,9 +676,12 @@ def scatter_prefill(k_pages, v_pages, k_cache, v_cache, page_table):
         # view, as the decode kernel takes it): asked to scatter
         # [page, Hkv, D] windows at 4 kv heads, the TPU compiler first turns
         # the WHOLE pool head-major and back, K and V, every call
-        flat = pages.reshape(lcount, -1, page * hkv, hd)
+        # (a lane-packed pool's rows hold several heads: the UPDATE takes the
+        # pool's shape, the same numbers in the same order)
+        rows, lanes = page * pages.shape[-2], pages.shape[-1]
+        flat = pages.reshape(lcount, -1, rows, lanes)
         return flat.at[:, page_table].set(
-            r.reshape(lcount, b, nb, page * hkv, hd)).reshape(pages.shape)
+            cache.reshape(lcount, b, nb, rows, lanes)).reshape(pages.shape)
 
     return scatter_one(k_pages, k_cache), scatter_one(v_pages, v_cache)
 
@@ -753,6 +881,7 @@ class ContinuousBatchingEngine:
 
         from sentio_tpu.models.cohere2_moe import Cohere2MoeConfig, cohere2_forward
         from sentio_tpu.models.deepseek_v2 import DeepseekV2Config, deepseek_v2_forward
+        from sentio_tpu.models.lfm2_moe import Lfm2MoeConfig, lfm2_forward
         from sentio_tpu.models.llama import llama_forward
         from sentio_tpu.models.moe import MoeConfig, moe_serving_forward
 
@@ -791,10 +920,23 @@ class ContinuousBatchingEngine:
         # a family whose expert layers hand back what they decided (their
         # picks, and the pairs they routed: ``models/moe.py::expert_layer``)
         family_forward = {Cohere2MoeConfig: cohere2_forward,
-                          DeepseekV2Config: deepseek_v2_forward}.get(type(self.cfg))
+                          DeepseekV2Config: deepseek_v2_forward,
+                          Lfm2MoeConfig: lfm2_forward}.get(type(self.cfg))
         self.routed = family_forward is not None
         # a family whose pool holds ONE latent a token and layer in place of K and V
         self.latent = is_latent(self.cfg)
+        # a family whose convolution layers carry state per slot and per page
+        # beside the pool (``PagedPool.conv``, ``.tail``)
+        self.conv_state = has_conv_state(self.cfg)
+        if self.conv_state:
+            from sentio_tpu.runtime.paged_spec import refuse_recurrent_state
+
+            if draft_params is not None:
+                refuse_recurrent_state(self.cfg)
+            if mesh is not None:
+                raise ValueError(f"a family with convolution state ({type(self.cfg).__name__}) is served on "
+                                 "one device a process: the state per slot and per page has no rule for "
+                                 "a mesh yet")
         if self.routed:
             name = type(self.cfg).__name__
             if forward_fn not in (None, family_forward):
@@ -899,9 +1041,20 @@ class ContinuousBatchingEngine:
         self.kv_quant = kv_quant
         if num_pages is None:
             num_pages = 1 + max_slots * max_pages_per_seq
+        # heads narrower than a tile's 128 lanes: where the decode kernel is
+        # wanted (the same ask as below) the pool is made lane-packed, kv
+        # heads side by side in a row, so that its pages are whole tiles
+        # (``init_pool``); bf16 pages on one device, and not under
+        # speculation, whose dense cache reads the pool's shape
+        self._kv_pack = 1
+        if (jax.default_backend() == "tpu" if use_pallas is None else use_pallas) \
+                and kv_quant == "none" and mesh is None and draft_params is None and not self.latent:
+            from sentio_tpu.kernels.paged_attention import lane_packing
+
+            self._kv_pack = lane_packing(self.cfg.n_kv_heads, self.cfg.head_dim)
         self.pool = init_pool(
             self.cfg, num_pages, page_size, mesh=mesh,
-            quantized=kv_quant == "int8",
+            quantized=kv_quant == "int8", slots=max_slots, pack=self._kv_pack,
         )
         self.allocator = PageAllocator(num_pages)  # guarded-by: engine-thread
 
@@ -963,6 +1116,16 @@ class ContinuousBatchingEngine:
         self.prefill_latent_total = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         self.last_tick_prefill_latent = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         self._prefill_latent_pending = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
+        # a family with convolution state: what each row of a prefill dispatch
+        # STARTED from — ``zero`` (position 0), ``tail`` (a cached page's
+        # stored tail: a radix hit) or ``carried`` (a later segment of a
+        # chunked prompt, from the tail its own earlier segment left) — and
+        # the page tails written (``pages``: by prefill for the pages it
+        # filled, by decode when a page filled). Counted on the host, booked
+        # with the next harvested tick beside the row-steps
+        self.conv_state_total = dict.fromkeys(CONV_STATE_KINDS, 0)
+        self.last_tick_conv_state = dict.fromkeys(CONV_STATE_KINDS, 0)
+        self._conv_state_pending = dict.fromkeys(CONV_STATE_KINDS, 0)
         # chunked prefill dispatches ONE segment a tick over all slots: a
         # tick in which n slots hold a pending segment books one turn
         # ``taken`` and n - 1 ``waited``. Counted in ``_advance_prefill``
@@ -1015,7 +1178,8 @@ class ContinuousBatchingEngine:
         self.spec_emitted_total = 0
         self.spec_verifies_total = 0
         self._finished_buffer: list[PagedResult] = []  # guarded-by: engine-thread
-        # (first_tokens_device_array, [slot_idx, ...]) per admission chunk,
+        # (first tokens, their logprobs — device arrays —, [slot_idx, ...], the
+        # rows' convolution state on the device or None) per admission chunk,
         # consumed by the next decode tick
         self._pending_first: list = []  # guarded-by: engine-thread
         # optional callable the serving layer sets so ticks stay SHORT when
@@ -1072,8 +1236,8 @@ class ContinuousBatchingEngine:
 
             tp = mesh.shape[AXIS_TP] if mesh is not None else 1
             why = latent_untiled(page_size, self.cfg.kv_lora_rank, self.cfg.qk_rope_head_dim) \
-                if self.latent else untiled(page_size, self.cfg.n_kv_heads // tp, self.cfg.head_dim,
-                                            kv_quant == "int8")
+                if self.latent else untiled(page_size, self.cfg.n_kv_heads // tp // self._kv_pack,
+                                            self.cfg.head_dim * self._kv_pack, kv_quant == "int8")
             if why and asked:
                 raise ValueError(f"use_pallas=True, but {why}")
             if why:
@@ -1107,7 +1271,7 @@ class ContinuousBatchingEngine:
 
             why = ""
             if self.forward_fn not in (llama_forward, moe_serving_forward, cohere2_forward,
-                                       deepseek_v2_forward):
+                                       deepseek_v2_forward, lfm2_forward):
                 why = "the caller brought its own forward_fn"
             elif mesh is not None:
                 why = "the prefill kernel runs on one device a process, and this engine has a mesh"
@@ -1137,10 +1301,10 @@ class ContinuousBatchingEngine:
         routed = self.routed
 
         @jit_family("paged.step_n", static_argnames=("steps",),
-                    donate_argnums=(5, 6))
+                    donate_argnums=(5, 6), donate_argnames=("conv", "tail"))
         def step_n(params, tok, lens, halted, page_table, k_pages, v_pages,
                    rng, temps, top_ks, budgets, lp_sum, lp_min, lp_cnt,
-                   steps, moe_acc=None):
+                   steps, moe_acc=None, conv=None, tail=None):
             """``steps`` decode sub-steps fused into one dispatch (lax.scan).
 
             Per-row ``budgets`` bound how far each row may advance (token
@@ -1168,6 +1332,11 @@ class ContinuousBatchingEngine:
             ``packed`` and adds one result: every sub-step's picks
             ``{"experts": [steps, L, B, k]}`` (one entry a kind of choice),
             which stay on the device unless a caller asked for them.
+
+            A family with convolution state (``conv`` [Lc, B, 2, d], a row a
+            slot, and the page tails ``tail`` [Lc, P, 2, d]; both donated)
+            carries both through the scan — a row that does not advance in a
+            sub-step keeps its state — and returns them last.
             """
             from sentio_tpu.runtime.sampling import sample_tokens
 
@@ -1175,12 +1344,15 @@ class ContinuousBatchingEngine:
                 return {name: moe[name] for name in moe if name != "counts"}
 
             def body(carry, idx):
+                # ``more``: what only some families carry (the expert layers'
+                # counts under "moe", the convolution state under its names)
                 (tok, lens, k_pages, v_pages, rng, halted,
-                 lp_sum, lp_min, lp_cnt, *moe_n) = carry
+                 lp_sum, lp_min, lp_cnt, more) = carry
                 active = (~halted) & (idx < budgets)
+                state = {name: more[name] for name in ("conv", "tail") if name in more}
                 logits, k_pages, v_pages, *moe = paged_decode_forward(
                     params, cfg, tok, lens, page_table, k_pages, v_pages,
-                    attn_impl=attn_impl, write_mask=active, return_routed=routed,
+                    attn_impl=attn_impl, write_mask=active, return_routed=routed, **state,
                 )
                 rng, sub = jax.random.split(rng)
                 # temperature AND top-k sample INSIDE the scan body — the
@@ -1194,23 +1366,25 @@ class ContinuousBatchingEngine:
                 lp_cnt = jnp.where(active, lp_cnt + 1, lp_cnt)
                 if not ignore_eos:
                     halted = halted | (active & (nxt == eos_id))
+                more = dict(more)
                 if routed:
-                    return (tok, lens, k_pages, v_pages, rng, halted, lp_sum, lp_min,
-                            lp_cnt, moe_n[0] + moe[0]["counts"]), (nxt, picks_of(moe[0]))
-                return (tok, lens, k_pages, v_pages, rng, halted,
-                        lp_sum, lp_min, lp_cnt), nxt
+                    more["moe"] = more["moe"] + moe[0]["counts"]
+                if state:
+                    more["conv"], more["tail"] = moe[1:]
+                return (tok, lens, k_pages, v_pages, rng, halted, lp_sum, lp_min,
+                        lp_cnt, more), ((nxt, picks_of(moe[0])) if routed else nxt)
 
             tok_in = tok
             # rows whose (deferred) first token is already EOS never run
             if not ignore_eos:
                 halted = halted | (tok == eos_id)
-            init = (tok, lens, k_pages, v_pages, rng, halted,
-                    lp_sum, lp_min, lp_cnt)
-            if routed:
-                init = (*init, moe_acc)
+            more = {"moe": moe_acc} if routed else {}
+            if conv is not None:
+                more.update(conv=conv, tail=tail)
             (tok, lens, k_pages, v_pages, rng, halted,
-             lp_sum, lp_min, lp_cnt, *moe_n), toks = jax.lax.scan(
-                body, init, jnp.arange(steps)
+             lp_sum, lp_min, lp_cnt, more), toks = jax.lax.scan(
+                body, (tok, lens, k_pages, v_pages, rng, halted, lp_sum, lp_min, lp_cnt, more),
+                jnp.arange(steps)
             )
             if routed:
                 toks, picks = toks
@@ -1220,7 +1394,7 @@ class ContinuousBatchingEngine:
             packed = jnp.concatenate([tok_in[None, :], toks], axis=0)
             if routed:  # [4, B] more: each count across its row
                 packed = jnp.concatenate(
-                    [packed, jnp.broadcast_to(moe_n[0][:, None], (4, packed.shape[1]))], axis=0)
+                    [packed, jnp.broadcast_to(more["moe"][:, None], (4, packed.shape[1]))], axis=0)
             # one [3, B] fetch (not three): final accumulators, harvested
             # into the host mirrors the retiring PagedResult reads
             lp_state = jnp.stack(
@@ -1228,41 +1402,55 @@ class ContinuousBatchingEngine:
             )
             out = (packed, lp_state, tok, lens, halted,
                    lp_sum, lp_min, lp_cnt, k_pages, v_pages, rng)
-            return (*out, picks) if routed else out
+            if routed:
+                out = (*out, picks)
+            return out if conv is None else (*out, more["conv"], more["tail"])
 
         self._step_n = step_n
 
-        @jit_family("paged.merge_admitted")
+        @jit_family("paged.merge_admitted", donate_argnames=("conv",))
         def merge_admitted(tok, lens, halted, lp_sum, lp_min, lp_cnt,
-                           first, first_lp, new_lens, idxs):
+                           first, first_lp, new_lens, idxs, conv=None, conv_rows=None):
             """Scatter admission's device-resident first tokens (plus their
             prompt lengths, a cleared halt flag, and the first token's
             logprob seeding the per-slot confidence accumulators) into the
             carried decode state. ``idxs`` pads to ``first``'s length with
-            an out-of-range index; mode='drop' discards the pad rows."""
+            an out-of-range index; mode='drop' discards the pad rows. A
+            family with convolution state hands in the slots' state ``conv``
+            [Lc, slots, 2, d] (donated) and the admitted rows' ``conv_rows``
+            [Lc, rows, 2, d] as their prefill left it: a slot's new request
+            starts from ITS prompt's state, whatever the last one left."""
+            if conv is not None:
+                conv = conv.at[:, idxs].set(conv_rows, mode="drop")
             tok = tok.at[idxs].set(first, mode="drop")
             lens = lens.at[idxs].set(new_lens, mode="drop")
             halted = halted.at[idxs].set(False, mode="drop")
             lp_sum = lp_sum.at[idxs].set(first_lp, mode="drop")
             lp_min = lp_min.at[idxs].set(first_lp, mode="drop")
             lp_cnt = lp_cnt.at[idxs].set(1, mode="drop")
-            return tok, lens, halted, lp_sum, lp_min, lp_cnt
+            out = tok, lens, halted, lp_sum, lp_min, lp_cnt
+            return out if conv is None else (*out, conv)
 
         self._merge_admitted = merge_admitted
 
-        @jit_family("paged.prefill_scatter", donate_argnums=(7, 8))
+        @jit_family("paged.prefill_scatter", donate_argnums=(7, 8), donate_argnames=("tail",))
         def prefill_scatter(params, ids, positions, lens, rng, temps, scat,
-                            k_pages, v_pages, top_ks, moe_acc=None):
+                            k_pages, v_pages, top_ks, moe_acc=None, tail=None):
             """Batched admission in ONE dispatch: contiguous prefill forward,
             cache scatter into each row's pages, first-token sample (token +
             its logprob, seeding the confidence accumulators) from each
             row's last prompt logit. Pad rows scatter to scratch page 0. A
             routed family returns one more: ``{"experts": [L, B, width, k]
-            picks, "counts": moe_acc + the pairs this call routed}``."""
+            picks, "counts": moe_acc + the pairs this call routed}``. A
+            family with convolution state (``tail`` [Lc, P, 2, d], donated)
+            starts every row from zeros and returns two more: each row's
+            state after ITS prompt [Lc, B, 2, d] (the merge into the decode
+            batch puts it in the row's slot) and ``tail`` with the tails of
+            the pages this call filled."""
             from sentio_tpu.runtime.sampling import sample_tokens
 
             b, width = ids.shape
-            cache = new_cache(b, width)
+            cache = new_cache(b, width, width // page_size)
             # pad tails and junk admission rows must not claim routed-expert
             # capacity (llama ignores the mask on the cache path)
             pad_mask = jnp.arange(width)[None, :] < lens[:, None]
@@ -1277,7 +1465,15 @@ class ContinuousBatchingEngine:
             rng, sub = jax.random.split(rng)
             first, first_lp = sample_tokens(last, sub, temps, top_k=top_ks)
             out = first, first_lp, k_pages, v_pages, rng
-            return (*out, prefill_routed(moe[0], moe_acc)) if routed else out
+            return prefill_results(out, moe, moe_acc, cache, scat, tail)
+
+        def prefill_results(out, moe, moe_acc, cache, scat, tail):
+            """A prefill program's five results, then what its family adds."""
+            if routed:
+                out = (*out, prefill_routed(moe[0], moe_acc))
+            if tail is not None:  # [Lc, B, NB, 2, d] at the [B, NB] pages the call filled
+                out = (*out, cache["conv"], tail.at[:, scat].set(cache["tail"]))
+            return out
 
         def prefill_routed(moe, moe_acc):
             # a prefill's pairs are counted; expert-steps are the decode tick's
@@ -1285,8 +1481,17 @@ class ContinuousBatchingEngine:
 
         latent = self.latent
 
-        def new_cache(rows, length):
-            """The contiguous cache a prefill fills: K and V, or latents alone."""
+        conv_state = self.conv_state
+        page_size = self.page_size
+
+        def new_cache(rows, length, pages=0):
+            """The contiguous cache a prefill fills: K and V, or latents alone,
+            or K and V of the attention layers beside the convolution state
+            (``pages``: the new tokens' pages, whose tails the forward leaves)."""
+            if conv_state:
+                from sentio_tpu.models.lfm2_moe import init_lfm2_cache
+
+                return init_lfm2_cache(cfg, rows, length, pages)
             if latent:
                 from sentio_tpu.models.deepseek_v2 import init_latent_cache
 
@@ -1297,13 +1502,11 @@ class ContinuousBatchingEngine:
 
         self._prefill_scatter = prefill_scatter
 
-        page_size = self.page_size
-
-        @jit_family("paged.prior_prefill_scatter",
-                    static_argnames=("do_sample",), donate_argnums=(7, 8))
+        @jit_family("paged.prior_prefill_scatter", static_argnames=("do_sample",),
+                    donate_argnums=(7, 8), donate_argnames=("tail",))
         def prior_prefill_scatter(params, ids, positions, lens, rng, temps,
                                   scat, k_pages, v_pages, prior_table,
-                                  n_prior, top_ks, do_sample, moe_acc=None):
+                                  n_prior, top_ks, do_sample, moe_acc=None, tail=None):
             """Prefill a batch of suffixes against per-row prior KV already
             in the pool — ONE compiled family for both radix-cache admission
             (prior = the matched shared-prefix pages) and chunked-prefill
@@ -1326,13 +1529,24 @@ class ContinuousBatchingEngine:
             A LATENT family's prior is latents: they are primed as they lie
             and the forward EXPANDS them, with the segment's own, to keys and
             values in every layer (``models/deepseek_v2.py``; PERF.md has the
-            timing of this against attending absorbed over the prior)."""
+            timing of this against attending absorbed over the prior).
+
+            A family with CONVOLUTION state starts each row from the tail of
+            its prior's LAST page (``tail`` [Lc, P, 2, d], donated; a prior is
+            whole pages: a radix hit's, or this prompt's earlier segments',
+            whose tails the calls that filled them left there) — zeros for a
+            row without a prior — and returns what ``prefill_scatter`` does."""
             from sentio_tpu.runtime.sampling import sample_tokens
 
             b, width = ids.shape
             pnb = prior_table.shape[1]
             prior_w = pnb * page_size
-            cache = new_cache(b, prior_w + width)
+            cache = new_cache(b, prior_w + width, width // page_size)
+            if tail is not None and pnb:
+                last = jnp.take_along_axis(
+                    prior_table, jnp.maximum(n_prior // page_size - 1, 0)[:, None], axis=1)[:, 0]
+                cache = dict(cache)
+                cache["conv"] = jnp.where((n_prior > 0)[None, :, None, None], tail[:, last], 0)
             if pnb:
                 def prime(cache_arr, pages):
                     if pages is None:
@@ -1346,9 +1560,9 @@ class ContinuousBatchingEngine:
                             pages["s"][:, prior_table], cache_arr.dtype)
                     else:
                         dense = pages[:, prior_table]  # [L, B, PNB, pg, Hk, Hd]
-                    lcount, bb, nb_, pg_, hk_, hd_ = dense.shape
+                    lcount, bb, nb_, pg_ = dense.shape[:4]
                     return cache_arr.at[:, :, :prior_w].set(
-                        dense.reshape(lcount, bb, nb_ * pg_, hk_, hd_))
+                        dense.reshape(lcount, bb, nb_ * pg_, *cache_arr.shape[-2:]))
 
                 cache = dict(cache)
                 cache["k"] = prime(cache["k"], k_pages)
@@ -1381,7 +1595,7 @@ class ContinuousBatchingEngine:
                 first = jnp.zeros((b,), jnp.int32)
                 first_lp = jnp.zeros((b,), jnp.float32)
             out = first, first_lp, k_pages, v_pages, rng
-            return (*out, prefill_routed(moe[0], moe_acc)) if routed else out
+            return prefill_results(out, moe, moe_acc, cache, scat, tail)
 
         self._prior_prefill_scatter = prior_prefill_scatter
 
@@ -1510,7 +1724,7 @@ class ContinuousBatchingEngine:
             [(toks[:full], 0.0, 0, [0] * (matched // self.page_size) + pages)],
             width,
         )
-        (_first, _first_lp, self.pool.k, self.pool.v, self._rng), _picks = \
+        (_first, _first_lp, self.pool.k, self.pool.v, self._rng), _picks, _conv_rows = \
             self._prefill_call(
                 self._prefill_scatter,
                 self.params, ids, positions, lens, self._rng, temps, scat,
@@ -1580,7 +1794,7 @@ class ContinuousBatchingEngine:
 
         self.pool = init_pool(
             self.cfg, self.allocator.num_pages, self.page_size, mesh=self.mesh,
-            quantized=self.kv_quant == "int8",
+            quantized=self.kv_quant == "int8", slots=self.max_slots, pack=self._kv_pack,
         )
         self.allocator = PageAllocator(self.allocator.num_pages)
         self.slots = [_Slot() for _ in range(self.max_slots)]
@@ -1592,6 +1806,7 @@ class ContinuousBatchingEngine:
         self._moe_acc = None
         self._prefill_latent_pending = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         self._prefill_turns_pending = dict.fromkeys(PREFILL_TURN_KINDS, 0)
+        self._conv_state_pending = dict.fromkeys(CONV_STATE_KINDS, 0)
         # the failed tick's arrays are not worth waiting for
         get_stamper().drain()
         if self._inflight is not None:
@@ -1714,6 +1929,7 @@ class ContinuousBatchingEngine:
         self.last_tick_moe = dict.fromkeys(MOE_KINDS, 0)
         self.last_tick_prefill_latent = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         self.last_tick_prefill_turns = dict.fromkeys(PREFILL_TURN_KINDS, 0)
+        self.last_tick_conv_state = dict.fromkeys(CONV_STATE_KINDS, 0)
         self.last_tick_sub_steps = 0
         # chaos-drill injection point: a raised fault propagates exactly like
         # a real failed device dispatch (the serving pump resets + requeues)
@@ -2139,7 +2355,7 @@ class ContinuousBatchingEngine:
             width,
         )
         with self._phase.phase("prefill_dispatch"):
-            (first, first_lp, self.pool.k, self.pool.v, self._rng), picks = \
+            (first, first_lp, self.pool.k, self.pool.v, self._rng), picks, conv_rows = \
                 self._prefill_call(
                     self._prefill_scatter,
                     self.params, ids, positions, lens, self._rng, temps, scat,
@@ -2151,7 +2367,7 @@ class ContinuousBatchingEngine:
         slot_idxs = [slot_idx for slot_idx, _req, _ids in chunk]
         for slot_idx in slot_idxs:
             self.slots[slot_idx].pending_first = True
-        self._pending_first.append((first, first_lp, slot_idxs))
+        self._pending_first.append((first, first_lp, slot_idxs, conv_rows))
         # the dispatch above writes these rows' full prompt KV — their
         # full-page spans now seed the radix cache for later requests
         for slot_idx, _req, tok_ids in chunk:
@@ -2182,7 +2398,7 @@ class ContinuousBatchingEngine:
             rows_data, width, pos_offset=n_prior[:, None],
         )
         with self._phase.phase("prefill_dispatch"):
-            (first, first_lp, self.pool.k, self.pool.v, self._rng), picks = \
+            (first, first_lp, self.pool.k, self.pool.v, self._rng), picks, conv_rows = \
                 self._prefill_call(
                     self._prior_prefill_scatter,
                     self.params, ids, positions, lens, self._rng, temps, scat,
@@ -2194,28 +2410,38 @@ class ContinuousBatchingEngine:
         slot_idxs = [slot_idx for slot_idx, _req, _ids, _sh in chunk]
         for slot_idx in slot_idxs:
             self.slots[slot_idx].pending_first = True
-        self._pending_first.append((first, first_lp, slot_idxs))
+        self._pending_first.append((first, first_lp, slot_idxs, conv_rows))
         for slot_idx, _req, tok_ids, shared in chunk:
             self._radix_insert(slot_idx, tok_ids, shared)
 
     def _prefill_call(self, fn, *args, slots: Sequence[int] = (), **static):
-        """One prefill dispatch → (its five results, its picks or None). A
-        routed family's program also takes the pairs counted on the device
-        since the last tick and returns them with its own added; its picks
-        stay on the device unless a caller asked for them. The sampled
-        first tokens (small, donated to nothing) carry its completion stamp,
-        booked on the ``prefill`` span of each request in ``slots``."""
-        picks = None
+        """One prefill dispatch → (its five results, its picks or None, its
+        rows' convolution state or None). A routed family's program also
+        takes the pairs counted on the device since the last tick and returns
+        them with its own added; its picks stay on the device unless a caller
+        asked for them. A family with convolution state hands the page tails
+        in (donated) and takes them back, with each row's state after its
+        tokens — which stays on the device until ``merge_admitted`` puts it
+        in the row's slot. The sampled first tokens (small, donated to
+        nothing) carry its completion stamp, booked on the ``prefill`` span
+        of each request in ``slots``."""
+        picks = conv_rows = None
         with dispatching("prefill", self.tick_step,
                          [(self.slots[i].trace_id, "prefill") for i in slots]) as stamp:
-            if not self.routed:
-                out = fn(*args, **static)
-            else:
-                *out, moe = fn(*args, moe_acc=self._take_moe_acc(), **static)
+            if self.routed:
+                static["moe_acc"] = self._take_moe_acc()
+            if self.conv_state:
+                static["tail"] = self.pool.tail
+            out = list(fn(*args, **static))
+            if self.conv_state:
+                self.pool.tail = out.pop()
+                conv_rows = out.pop()
+            if self.routed:
+                moe = out.pop()
                 self._moe_acc = moe.pop("counts")
                 picks = moe if self.keep_choices else None
             stamp.out = out[0]
-        return out, picks
+        return out, picks, conv_rows
 
     def _take_moe_acc(self):
         """The pairs the prefill programs routed since the last tick, still
@@ -2241,6 +2467,14 @@ class ContinuousBatchingEngine:
         if self.latent:
             self._prefill_latent_pending["new"] += sum(n for _i, _start, n in rows)
             self._prefill_latent_pending["expanded"] += sum(start for _i, start, _n in rows)
+        if self.conv_state:
+            # what each row started from (a prior is whole pages, so a start
+            # IS a page's end), and the pages this dispatch filled
+            for slot_idx, start, n in rows:
+                kind = "zero" if not start else \
+                    "tail" if start == self.slots[slot_idx].shared_tokens else "carried"
+                self._conv_state_pending[kind] += 1
+                self._conv_state_pending["pages"] += n // self.page_size
         if picks is None:
             return
         for r, (slot_idx, start, n) in enumerate(rows):
@@ -2285,7 +2519,7 @@ class ContinuousBatchingEngine:
             prior_table = np.zeros((1, pnb), np.int32)
             prior_table[0, :pb] = self._page_table[i, :pb]
             with self._phase.phase("prefill_dispatch"):
-                (first, first_lp, self.pool.k, self.pool.v, self._rng), picks = \
+                (first, first_lp, self.pool.k, self.pool.v, self._rng), picks, conv_rows = \
                     self._prefill_call(
                         self._prior_prefill_scatter,
                         self.params, ids, positions, lens, self._rng, temps,
@@ -2298,7 +2532,7 @@ class ContinuousBatchingEngine:
             if is_last:
                 slot.prefill_todo = None
                 slot.pending_first = True
-                self._pending_first.append((first, first_lp, [i]))
+                self._pending_first.append((first, first_lp, [i], conv_rows))
                 # the final segment completes the prompt's KV — its
                 # full-page span can now enter the radix cache
                 self._radix_insert(i, slot.prompt_ids, slot.shared_tokens)
@@ -2365,7 +2599,7 @@ class ContinuousBatchingEngine:
         if self.force_tick_steps in self.tick_step_sizes():
             steps = self.force_tick_steps  # warmup rung pin, never off-ladder
         budgets = np.minimum(remaining, steps).astype(np.int32)
-        pending_slots = [i for _f, _lp, idxs in pending for i in idxs
+        pending_slots = [i for _f, _lp, idxs, _conv in pending for i in idxs
                          if self.slots[i].active]
         # rows sharing THIS fused dispatch — the honest occupancy number
         # (post-tick slot counts miss requests that retire inside the tick)
@@ -2379,7 +2613,7 @@ class ContinuousBatchingEngine:
             # (e.g. a max_new_tokens=1 burst): fetch them directly instead
             # of dispatching a fully-masked scan that would stream the
             # weights steps-many times just to echo the inputs back
-            for first_dev, first_lp_dev, slot_idxs in pending:
+            for first_dev, first_lp_dev, slot_idxs, _conv in pending:  # rows that retire here: no state to keep
                 # a direct fetch of not-yet-ready device arrays BLOCKS —
                 # this is device wait, not dispatch cost
                 with self._phase.phase("device_wait"):
@@ -2414,7 +2648,7 @@ class ContinuousBatchingEngine:
         else:
             (tok_in, lens_in, halted_in,
              lp_sum_in, lp_min_in, lp_cnt_in) = self._dev_state
-        for first_dev, first_lp_dev, slot_idxs in pending:
+        for first_dev, first_lp_dev, slot_idxs, conv_rows in pending:
             idxs = np.full(first_dev.shape[0], self.max_slots, np.int32)
             idxs[: len(slot_idxs)] = slot_idxs
             new_lens = np.zeros(first_dev.shape[0], np.int32)
@@ -2422,11 +2656,15 @@ class ContinuousBatchingEngine:
                 self.slots[i].length for i in slot_idxs
             ]
             with dispatching("admit", self.tick_step) as stamp:
+                # a family with convolution state: the rows' state into their slots
+                state = {"conv": self.pool.conv, "conv_rows": conv_rows} if self.conv_state else {}
                 (tok_in, lens_in, halted_in,
-                 lp_sum_in, lp_min_in, lp_cnt_in) = self._merge_admitted(
+                 lp_sum_in, lp_min_in, lp_cnt_in, *conv) = self._merge_admitted(
                     tok_in, lens_in, halted_in, lp_sum_in, lp_min_in, lp_cnt_in,
-                    first_dev, first_lp_dev, new_lens, idxs
+                    first_dev, first_lp_dev, new_lens, idxs, **state
                 )
+                if conv:
+                    self.pool.conv = conv[0]
                 stamp.out = tok_in
 
         # the tick's packed tokens carry its completion stamp: the harvest
@@ -2457,6 +2695,8 @@ class ContinuousBatchingEngine:
             else:
                 # a routed family: the prefill programs' pairs ride this tick's fetch
                 moe_acc = {"moe_acc": self._take_moe_acc()} if self.routed else {}
+                if self.conv_state:
+                    moe_acc.update(conv=self.pool.conv, tail=self.pool.tail)
                 (packed, lp_state, tok_out, lens_out, halted_out,
                  lp_sum_out, lp_min_out, lp_cnt_out,
                  self.pool.k, self.pool.v, self._rng, *picks) = self._step_n(
@@ -2476,6 +2716,8 @@ class ContinuousBatchingEngine:
                     lp_cnt_in,
                     steps=steps, **moe_acc,
                 )
+                if self.conv_state:
+                    *picks, self.pool.conv, self.pool.tail = picks
                 self.total_sub_steps += steps
                 spec = False
                 kv_pages = self._kv_pages(budgets, int(steps))
@@ -2566,6 +2808,8 @@ class ContinuousBatchingEngine:
                     for name, value in picks.items():
                         slot.choices[name][:, slot.length] = value[s, :, i]
                 slot.length += 1
+                if self.conv_state and slot.length % self.page_size == 0:
+                    self._conv_state_pending["pages"] += 1  # this sub-step's token filled a page
                 self._lens[i] = slot.length
                 self._last_tok[i] = int(toks[s])
                 useful += 1
@@ -2601,6 +2845,10 @@ class ContinuousBatchingEngine:
             self.prefill_turns_total[kind] += n
             self.last_tick_prefill_turns[kind] += n
             self._prefill_turns_pending[kind] = 0
+        for kind, n in self._conv_state_pending.items():
+            self.conv_state_total[kind] += n
+            self.last_tick_conv_state[kind] += n
+            self._conv_state_pending[kind] = 0
 
     def _kv_pages(self, budgets, steps: int) -> dict:
         """K/V page blocks of the ``steps`` sub-steps being dispatched, by
@@ -2618,11 +2866,13 @@ class ContinuousBatchingEngine:
                          for s in self.slots])[:, None] + sub
         # the mean over the layers: a windowed layer's walk starts at the
         # window's first block (one term where all layers are of one kind)
-        windows = Counter(self.cfg.window(i) for i in range(self.cfg.n_layers))
+        # (the layers that HAVE pages: a family's attention layers)
+        layers = getattr(self.cfg, "attn_layers", range(self.cfg.n_layers))
+        windows = Counter(self.cfg.window(i) for i in layers)
         held = np.where(
             sub < np.asarray(budgets)[:, None],
             sum(n * blocks_walked(at, self.page_size, self.max_pages_per_seq, w)
-                for w, n in windows.items()) / self.cfg.n_layers, 1)
+                for w, n in windows.items()) / len(layers), 1)
         return {"held": int(round(float(held.sum()))),
                 "tabled": steps * self.max_slots * self.max_pages_per_seq}
 
@@ -2737,6 +2987,10 @@ class ContinuousBatchingEngine:
         }
         if self.routed:
             out.update({f"moe_{kind}": n for kind, n in self.moe_total.items()})
+        if self.conv_state:
+            # of ``pool_hbm_bytes``, the state per slot and per page
+            out["conv_state_bytes"] = self.pool.conv_state_bytes
+            out.update({f"conv_state_{kind}": n for kind, n in self.conv_state_total.items()})
         if self.latent:
             # what ONE token leaves in the pool a layer (bf16)
             out["pool_token_layer_bytes"] = self.cfg.latent_dim * np.dtype(self.pool.k.dtype).itemsize

@@ -58,6 +58,22 @@ from __future__ import annotations
 from sentio_tpu.analysis.audit.registry import jit_family
 
 
+def refuse_recurrent_state(cfg) -> None:
+    """Speculation is refused, with its reason, for a family that carries
+    RECURRENT state beside the pages (``models/lfm2_moe.py``'s convolution
+    layers): a verify block advances that state by k + 1 tokens, a rejected
+    draft token would have to roll it back, and nothing here keeps the state
+    to roll back to (K and V need no such thing: a rejected position is
+    simply overwritten)."""
+    from sentio_tpu.runtime.paged import has_conv_state
+
+    if has_conv_state(cfg):
+        raise ValueError(
+            f"paged speculation does not serve a family with recurrent state ({type(cfg).__name__}): a "
+            "rejected draft token would have to roll the convolution state back, and the tick keeps "
+            "no state to roll back to")
+
+
 def accept_and_correct(rng, drafts, qdists, tprobs):
     """Rejection-sampling acceptance for sampled speculation.
 
@@ -112,6 +128,8 @@ def build_spec_tick(target_fwd, cfg, draft_fwd, dcfg, eos_id: int,
     import jax.numpy as jnp
 
     from sentio_tpu.runtime.paged import dequantize_pages, scatter_prefill
+
+    refuse_recurrent_state(cfg)
 
     def densify(pages, table, dtype):
         if isinstance(pages, dict):

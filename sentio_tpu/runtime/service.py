@@ -1222,7 +1222,7 @@ class PagedGenerationService:
                         metrics.record_tick_phases(phase_s)
                         metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
                                                  row_steps["moe_pairs"], row_steps["prefill_latent"],
-                                                 row_steps["prefill_turns"])
+                                                 row_steps["prefill_turns"], row_steps["conv_state"])
                         for key, val in phase_s.items():
                             self._phase_totals[key] = (
                                 self._phase_totals.get(key, 0.0) + val
@@ -1394,7 +1394,7 @@ class PagedGenerationService:
                     metrics.record_tick(tick_dur_s, int(active), queued + inbox)
                     metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
                                              row_steps["moe_pairs"], row_steps["prefill_latent"],
-                                             row_steps["prefill_turns"])
+                                             row_steps["prefill_turns"], row_steps["conv_state"])
                 except Exception:  # noqa: BLE001
                     logger.debug("tick telemetry failed", exc_info=True)
                 t_deliver_start = time.perf_counter()
@@ -1518,7 +1518,10 @@ class PagedGenerationService:
                 # a latent family's prefill tokens, new and expanded (zeros for any other)
                 "prefill_latent": dict(getattr(self.engine, "last_tick_prefill_latent", None) or {}),
                 # chunked prefill's turns, taken and waited (zeros without PREFILL_CHUNK)
-                "prefill_turns": dict(getattr(self.engine, "last_tick_prefill_turns", None) or {})}
+                "prefill_turns": dict(getattr(self.engine, "last_tick_prefill_turns", None) or {}),
+                # a family with convolution state: what its prefill rows started
+                # from, and the page tails written (zeros for any other)
+                "conv_state": dict(getattr(self.engine, "last_tick_conv_state", None) or {})}
 
     def _note_ttft_locked(self, ttft_s: float) -> None:  # lock-held: _mutex
         """Fold one observed TTFT into the EMA admission control projects

@@ -746,6 +746,10 @@ async def info(request: web.Request) -> web.Response:
                 # layer (1,152 B at 512 + 64 in bf16)
                 **({"pool_token_layer_bytes": serving["pool_token_layer_bytes"]}
                    if "pool_token_layer_bytes" in serving else {}),
+                # a family with convolution layers only: of ``pool_hbm_bytes``,
+                # the state it keeps per decode slot and per page beside K and V
+                **({"conv_state_bytes": serving["conv_state_bytes"]}
+                   if "conv_state_bytes" in serving else {}),
                 # a configured draft accelerates the decode tick
                 # (runtime/paged_spec.py); its exclusions (chunked prefill,
                 # device mesh) are surfaced here for operators

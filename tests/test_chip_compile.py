@@ -745,3 +745,130 @@ def test_commanda_prefill_holds_the_flash_kernel_on_both_layer_kinds(commanda_pr
     _, _, texts = commanda_programs
     assert len(re.findall(r"%prefill_attention[.\d]* = ", texts["prefill"])) == LAYERS
     assert _scores_in_hbm(texts["prefill"], 512 + 512) == []
+
+
+# ------------------------- a family with convolution state beside the pages
+#
+# ``lfm2_moe`` (models/lfm2_moe.py) at the widths of the benchmark's
+# ``lfm2-24b-a2b-l10``, its first four layers (the check's depth: conv and
+# dense twice, attention and routed, conv and routed): 64-wide heads read by
+# the decode walk from a LANE-PACKED pool ``[La, P, page, 4, 128]`` (8 kv heads
+# of 64, two to a row), the convolution state per slot ``[Lc, 16, 2, 2048]``
+# and per page ``[Lc, P, 2, 2048]`` carried through the scan beside it, 64
+# experts of 2048 x 1536 picked under a bias behind the megablox grouped matmul.
+
+
+@pytest.fixture(scope="module")
+def lfm2_programs(v5e):
+    from sentio_tpu.kernels.paged_attention import lane_packing
+    from sentio_tpu.models import moe
+    from sentio_tpu.models.lfm2_moe import CONV, FULL, Lfm2MoeConfig, init_lfm2_cache, init_lfm2_moe, lfm2_forward
+
+    cfg = Lfm2MoeConfig(n_layers=4, layer_types=(CONV, CONV, FULL, CONV))
+    place = _on_one_chip(v5e)
+    params = jax.eval_shape(lambda: serving_layout(init_lfm2_moe(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree_util.tree_map(
+        lambda a: place(a.shape, jnp.bfloat16 if a.ndim >= 2 and a.shape[-1] > 3 else a.dtype), params)
+    # (a segment of 256: at 512 its 2,048 pairs of token and pick make activations
+    # [2048, 2048], the shape of a projection, and the weight control cannot tell them apart)
+    slots, nb, page, segment = 16, 10, 128, 256
+    pack = lane_packing(cfg.n_kv_heads, cfg.head_dim)
+    assert pack == 2
+    pages = 1 + slots * nb
+    # (two pool layers, as the cell's ten model layers have; these four use the first)
+    pool = place((2, pages, page, cfg.n_kv_heads // pack, cfg.head_dim * pack), jnp.bfloat16)
+    conv = place((3, slots, 2, cfg.dim), jnp.bfloat16)
+    tail = place((3, pages, 2, cfg.dim), jnp.bfloat16)
+    impl = make_paged_attn_impl(interpret=False)
+
+    def step(params, tok, lens, table, k_pages, v_pages, conv, tail):
+        def body(carry, _):
+            tok, lens, k_pages, v_pages, conv, tail = carry
+            logits, k_pages, v_pages, routed, conv, tail = paged_decode_forward(
+                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl,
+                write_mask=lens < nb * page - 1, return_routed=True, conv=conv, tail=tail)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
+                    k_pages, v_pages, conv, tail), routed["experts"]
+
+        return jax.lax.scan(body, (tok, lens, k_pages, v_pages, conv, tail), None, length=2)
+
+    def prefill(params, ids, positions, lens, k_pages, v_pages, tail, prior_table, n_prior, scat):
+        # a segment as ``paged.prior_prefill_scatter`` runs it: K and V primed from two prior
+        # pages, the state from the last one's tail, the segment's own scattered back
+        cache = init_lfm2_cache(cfg, 1, 2 * page + segment, segment // page)
+        for name, pool_ in (("k", k_pages), ("v", v_pages)):
+            cache[name] = cache[name].at[:, :, : 2 * page].set(
+                pool_[:1, prior_table].reshape(1, 1, 2 * page, cfg.n_kv_heads, cfg.head_dim))
+        cache["conv"] = tail[:, prior_table[:, 1]]
+        logits, cache, routed = lfm2_forward(params, cfg, ids, positions=positions, cache=cache,
+                                             cache_index=n_prior,
+                                             pad_mask=jnp.arange(segment)[None, :] < lens[:, None])
+        new = [jax.lax.dynamic_slice_in_dim(cache[name], n_prior[0], segment, axis=2) for name in ("k", "v")]
+        k_pages, v_pages = scatter_prefill(k_pages, v_pages, *(jnp.concatenate([a, a]) for a in new), scat)
+        return logits[:, -1], k_pages, v_pages, cache["conv"], tail.at[:, scat].set(cache["tail"]), routed["counts"]
+
+    was, moe.grouped_matmul = moe.grouped_matmul, moe.expert_matmul   # as the commanda fixture does
+    try:
+        compiled = {
+            "step": jax.jit(step, donate_argnums=(4, 5, 6, 7)).lower(
+                params, place((slots,), jnp.int32), place((slots,), jnp.int32),
+                place((slots, nb), jnp.int32), pool, pool, conv, tail).compile(),
+            "prefill": jax.jit(prefill, donate_argnums=(4, 5, 6)).lower(
+                params, place((1, segment), jnp.int32), place((1, segment), jnp.int32), place((1,), jnp.int32),
+                pool, pool, tail, place((1, 2), jnp.int32), place((1,), jnp.int32),
+                place((1, segment // page), jnp.int32)).compile(),
+        }
+    finally:
+        moe.grouped_matmul = was
+    return cfg, params, {k: c.as_text() for k, c in compiled.items()}, \
+        {k: c.memory_analysis() for k, c in compiled.items()}, (pool, tail)
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_lfm2_programs_read_their_weights_where_they_lie(lfm2_programs, program):
+    """The v5e compiler takes both programs (Mosaic: the decode walk over a
+    lane-packed pool of 64-wide heads; the grouped matmul over 64 experts of
+    2048 x 1536), and nothing in them makes an array with the shape of a
+    projection, of the table the head reads, or of a stack of experts."""
+    cfg, params, texts, _, _ = lfm2_programs
+    assert params["layers_0"]["conv"]["w_in"]["kernel"].shape == (2048, 6144) and "lm_head" not in params
+    assert params["layers_2"]["attn"]["wq_t"]["kernel"].shape == (2048, 2048)
+    assert _weight_copies(texts[program], params) == []
+    stacks = (f"bf16[{cfg.n_experts},{cfg.dim},{cfg.moe_mlp_dim}]", f"bf16[{cfg.n_experts},{cfg.moe_mlp_dim},{cfg.dim}]")
+    assert [m for m in _pool_shaped(texts[program], stacks)
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast")] == []
+
+
+def test_lfm2_decode_step_holds_its_kernels_and_its_state(lfm2_programs):
+    """The decode step: ONE walk of the pages (the one attention layer, over
+    the lane-packed pool) and three grouped expert matmuls in each of the two
+    routed layers, each a Pallas call; the pool and the page tails updated in
+    place, never copied."""
+    cfg, _, texts, memory, (pool, tail) = lfm2_programs
+    assert texts["step"].count('custom_call_target="tpu_custom_call"') == 1 + 2 * 3
+    assert len(re.findall(r"%paged_attention[.\d]* = ", texts["step"])) == 1
+    assert len(re.findall(r"%gmm[.\d]* = ", texts["step"])) == 6
+    shapes = tuple(f"bf16[{','.join(str(n) for n in a.shape)}]" for a in (pool, tail))
+    # ``copy-done``: the compiler's own placement of a 42 MB pool in nearer memory for the
+    # scatter and back (the same tiling in another memory space, ``S(1)``): no relayout, and
+    # 42 MB a sub-step beside 7 GB of weights
+    made = [m for m in _pool_shaped(texts["step"], shapes)
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "while", "tuple", "custom-call",
+                            "copy-start", "copy-done")]
+    assert all(what in ("fusion:scatter", "fusion:dynamic-update-slice", "scatter", "dynamic-update-slice")
+               for _n, _s, what in made), made
+    assert not re.search(r"= bf16\[2,161,128,4,128\]\S* (copy|transpose|pad)\(", texts["step"])
+    # beside arguments it donates, the step needs little: no pool-sized temporary
+    assert memory["step"].temp_size_in_bytes < int(np.prod(pool.shape)) * 2
+
+
+def test_lfm2_prefill_writes_pool_and_tails_where_they_lie(lfm2_programs):
+    """The prefill over a prior: K, V and the page tails are updated in place
+    (the UPDATE takes the lane-packed pool's shape, never the pool the
+    update's)."""
+    _, _, texts, _, (pool, tail) = lfm2_programs
+    shapes = tuple(f"bf16[{','.join(str(n) for n in a.shape)}]" for a in (pool, tail))
+    made = [m for m in _pool_shaped(texts["prefill"], shapes)
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "tuple", "custom-call")]
+    assert all(what in ("fusion:scatter", "fusion:dynamic-update-slice", "scatter", "dynamic-update-slice")
+               for _n, _s, what in made), made
