@@ -40,9 +40,9 @@ TINY = {"tiny": dict(h=4, hkv=2, d=16), "tiny_latent": dict(h=4, hkv=4, d=16, r=
 PEAK_BF16 = {"TPU v5 lite": 197e12}   # Google Cloud, "TPU v5e"
 
 
-def _device_us(trace_dir: Path, calls: int) -> dict:
-    """The device's busy time a call and the median ``prefill_attention`` op
-    of the newest trace under ``trace_dir`` (µs); {} without a device plane."""
+def device_us(trace_dir: Path, calls: int, kernel_name: str = "prefill_attention") -> dict:
+    """The device's busy time a call and the median ``kernel_name`` op of the
+    newest trace under ``trace_dir`` (µs); {} without a device plane."""
     from jax.profiler import ProfileData
 
     found = sorted(trace_dir.rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
@@ -57,7 +57,7 @@ def _device_us(trace_dir: Path, calls: int) -> dict:
                 continue
             for ev in line.events:
                 spans.append((ev.start_ns, ev.start_ns + ev.duration_ns))
-                if ev.name.lstrip("%").startswith("prefill_attention"):
+                if ev.name.lstrip("%").startswith(kernel_name):
                     kernel.append(ev.duration_ns / 1e3)
     if not spans:
         return {}
@@ -127,7 +127,7 @@ def time_point(name: str, g: dict, pages: int, bucket: int, segment: int, page: 
             where = trace_root / f"{name}-{form}-{pages}-{bucket}"
             with jax.profiler.trace(str(where)):
                 chained(q).block_until_ready()
-            line.update(_device_us(where, calls))
+            line.update(device_us(where, calls))
         lines.append(line)
     return lines
 

@@ -204,10 +204,13 @@ def routed_scores(logits: Array, gate_fn: str) -> Array:
 # step's two or three want 32 rows, not 256 (at 256 the MXU work of a step,
 # 1 GFLOP a weight tile, takes as long as streaming the tile) — between
 # ``_GMM_ROWS_MIN`` and ``_GMM_ROWS_MAX``. K and N of one ``[K, N]`` expert
-# matrix: the weights stream, so the tile is as large as the 16 MiB a kernel
-# is given unasked allows (PERF.md section 5 has the timings).
+# matrix: the weights stream, so the tile is the largest the matrix and the
+# VMEM a kernel is given unasked allow (:func:`expert_tile`; it was one
+# constant, ``(4096, 512)``, swept at 4096 x 4096 experts alone, until PR 43).
+# ``python -m sentio_tpu.eval.expert_mlp_timing`` times the candidates at
+# given widths; PERF.md section 5 has its table at the three families'.
 _GMM_ROWS_MIN, _GMM_ROWS_MAX = 32, 256
-_GMM_TILE = (4096, 512)
+_GMM_VMEM = 16 * 2 ** 20   # bytes of scoped VMEM a Pallas call gets on the chip without asking
 
 
 def row_tile(pairs: int, n_experts: int) -> int:
@@ -215,6 +218,36 @@ def row_tile(pairs: int, n_experts: int) -> int:
     next power of two over an expert's share, inside the two bounds."""
     share = max(-(-pairs // n_experts), 1)
     return min(max(1 << (share - 1).bit_length(), _GMM_ROWS_MIN), _GMM_ROWS_MAX)
+
+
+def tile_vmem(rows: int, tk: int, tn: int, lhs_item: int = 2, rhs_item: int = 2) -> int:
+    """Bytes of VMEM the grouped matmul holds at a ``[rows, tk] x [tk, tn]``
+    tile: the weight tile twice (the next one arrives while this one is
+    multiplied), the rows' block twice and a third time as the value the
+    kernel loads (the v5e compiler keeps a block larger than its registers in
+    VMEM: a ``[32, 5120] x [5120, 768]`` tile is refused at 16.04 MiB where
+    two copies would leave it at 15.8), the output block twice, and the
+    float32 accumulator. Held against every tile the v5e compiler took or
+    refused in PR 43 (``tests/test_chip_compile.py``)."""
+    return 2 * tk * tn * rhs_item + 3 * rows * tk * lhs_item + 2 * rows * tn * lhs_item + 4 * rows * tn
+
+
+def expert_tile(k: int, n: int, rows: int, lhs_item: int = 2, rhs_item: int = 2) -> tuple[int, int]:
+    """The ``(tk, tn)`` of a ``[K, N]`` expert matrix under a row tile of
+    ``rows``, from the shapes alone. The contraction stays WHOLE wherever a
+    ``K x 128``-lane slab fits ``_GMM_VMEM`` — a split contraction fetches an
+    expert's slab again on every visit and a tile that does not divide K
+    masks its rest on the VPU every step — and is halved, on a divisor, only
+    while it does not. ``tn`` is then the widest multiple of 128 lanes that
+    divides N and fits: the whole matrix where it fits, one contiguous DMA an
+    expert. A matrix narrower than 128 lanes, or no multiple of them, is its
+    own tile."""
+    lanes = [d for d in range(128, n + 1, 128) if n % d == 0] or [n]
+    tk = k
+    while tile_vmem(rows, tk, lanes[0], lhs_item, rhs_item) > _GMM_VMEM and tk % 256 == 0:
+        tk //= 2
+    fit = [d for d in lanes if tile_vmem(rows, tk, d, lhs_item, rhs_item) <= _GMM_VMEM]
+    return tk, max(fit, default=lanes[0])
 
 
 def expert_matmul(lhs: Array, rhs: Array, sizes: Array, rows: int = _GMM_ROWS_MAX,
@@ -230,9 +263,26 @@ def expert_matmul(lhs: Array, rhs: Array, sizes: Array, rows: int = _GMM_ROWS_MA
     trace each call is one ``gmm`` custom call (the kernel's own jit name)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    tile = (rows, min(_GMM_TILE[0], lhs.shape[1]), min(_GMM_TILE[1], rhs.shape[2]))
+    tile = (rows, *expert_tile(lhs.shape[1], rhs.shape[2], rows, lhs.dtype.itemsize, rhs.dtype.itemsize))
     return gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype, tiling=tile,
                interpret=interpret)
+
+
+def expert_tiles(mp: dict, cfg, tokens: int) -> dict:
+    """What :func:`expert_layer` over ``tokens`` tokens hands the grouped
+    matmul for each of a routed layer's three matrices (``mp``: the layer's
+    tree, or its shapes): the tile ``[rows, tk, tn]`` and the grid steps an
+    expert with rows in ONE row tile costs, ``ceil(K / tk) * ceil(N / tn)``.
+    Decided when the program is traced, so the engine states it once
+    (``stats()["expert_tiles"]``, ``/info``)."""
+    rows = row_tile(tokens * cfg.experts_per_token, cfg.n_experts)
+    item = jnp.dtype(cfg.jdtype).itemsize          # the layer casts rows and matrices to it
+    tiles = {}
+    for name in ("w_gate", "w_up", "w_down"):
+        _, k, n = mp[name].shape
+        tk, tn = expert_tile(k, n, rows, item, item)
+        tiles[name] = {"tile": [rows, tk, tn], "steps_per_expert": -(-k // tk) * -(-n // tn)}
+    return tiles
 
 
 def grouped_matmul(lhs: Array, rhs: Array, sizes: Array, rows: int = _GMM_ROWS_MAX) -> Array:

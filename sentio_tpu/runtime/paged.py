@@ -883,7 +883,7 @@ class ContinuousBatchingEngine:
         from sentio_tpu.models.deepseek_v2 import DeepseekV2Config, deepseek_v2_forward
         from sentio_tpu.models.lfm2_moe import Lfm2MoeConfig, lfm2_forward
         from sentio_tpu.models.llama import llama_forward
-        from sentio_tpu.models.moe import MoeConfig, moe_serving_forward
+        from sentio_tpu.models.moe import MoeConfig, expert_tiles, moe_serving_forward
 
         from sentio_tpu.models.tokenizer import ByteTokenizer
 
@@ -954,7 +954,17 @@ class ContinuousBatchingEngine:
         # what a routed family's layers decide by rank, and how deep: a
         # request's ``choices`` hold one buffer a kind, its routed layers deep
         self._choice_depths = {}
+        # how the decode program's grouped expert matmuls are tiled: decided
+        # from shapes when it is traced (``models/moe.py::expert_tile``), so
+        # said once, here and in ``stats()``
+        self._expert_tiles = None
         if self.routed:
+            layer = next(lp["moe"] for lp in self.params.values() if isinstance(lp, dict) and "moe" in lp)
+            self._expert_tiles = expert_tiles(layer, self.cfg, max_slots)
+            logging.getLogger(__name__).info(
+                "grouped expert matmuls of a decode step, [rows, tk, tn] and grid steps an expert: %s",
+                ", ".join(f"{name} {t['tile']} x{t['steps_per_expert']}"
+                          for name, t in self._expert_tiles.items()))
             self._choice_depths["experts"] = self.cfg.experts_per_token
             if getattr(self.cfg, "n_group", 1) > 1:
                 self._choice_depths["groups"] = self.cfg.topk_group
@@ -2979,6 +2989,10 @@ class ContinuousBatchingEngine:
             # and which attention its prefill programs run: the flash kernel
             # that knows a prior, or the family's XLA form
             "prefill_attention": "pallas" if self._prefill_attn is not None else "xla",
+            # a routed family: the tile ``[rows, tk, tn]`` of each of a layer's
+            # three grouped matmuls in the decode program and the grid steps an
+            # expert costs (the chip's kernel; ``ragged_dot`` elsewhere takes none)
+            "expert_tiles": self._expert_tiles,
             "pool_hbm_bytes": self.pool.hbm_bytes,
             "head_skips": self._head_skips,
             "ttft_count": self.ttft_count,

@@ -2584,6 +2584,7 @@ class ReplicaSet:
         agg["kv_quant"] = first.get("kv_quant")
         agg["paged_attention"] = first.get("paged_attention")
         agg["prefill_attention"] = first.get("prefill_attention")
+        agg["expert_tiles"] = first.get("expert_tiles")
         agg["n_replicas"] = len(per)
         agg["replicas"] = per
         with self._mutex:

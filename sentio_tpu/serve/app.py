@@ -741,6 +741,10 @@ async def info(request: web.Request) -> web.Response:
                 "kv_quant": serving.get("kv_quant"),
                 "paged_attention": serving.get("paged_attention"),
                 "prefill_attention": serving.get("prefill_attention"),
+                # a routed family only (null otherwise): how the decode
+                # program's three grouped expert matmuls a layer are tiled,
+                # ``[rows, tk, tn]``, and the grid steps an expert costs
+                "expert_tiles": serving.get("expert_tiles"),
                 "pool_hbm_bytes": serving.get("pool_hbm_bytes"),
                 # a latent family only: what ONE token leaves in the pool a
                 # layer (1,152 B at 512 + 64 in bf16)

@@ -10,6 +10,7 @@ chip time. A compile that passes is not a chip run; it only says the chip's
 compiler accepts the program.
 """
 
+import contextlib
 import functools
 import os
 import re
@@ -77,6 +78,20 @@ def _on_one_chip(topo):
     chip = SingleDeviceSharding(topo.devices[0])
     return lambda shape, dtype, _spec=None: jax.ShapeDtypeStruct(
         shape, dtype, sharding=chip)
+
+
+@contextlib.contextmanager
+def _the_chips_grouped_matmul():
+    """``models/moe.py`` asks the backend which grouped matmul to take and
+    sees the CPU here: the test hands it the chip's, for the length of its
+    compiles."""
+    from sentio_tpu.models import moe
+
+    was, moe.grouped_matmul = moe.grouped_matmul, moe.expert_matmul
+    try:
+        yield moe
+    finally:
+        moe.grouped_matmul = was
 
 
 def _paged_case(quant: bool, page: int, window=None, **geometry):
@@ -475,7 +490,6 @@ def test_scatter_of_page_windows_copies_the_pool_at_4_kv_heads(v5e):
 
 @pytest.fixture(scope="module")
 def commanda_programs(v5e):
-    from sentio_tpu.models import moe
     from sentio_tpu.models.cohere2_moe import (
         FULL, SLIDING, Cohere2MoeConfig, cohere2_forward, init_cohere2_moe)
 
@@ -504,20 +518,28 @@ def commanda_programs(v5e):
                                cache_index=n_prior, attn_fn=make_prefill_attn_fn(interpret=False))
 
     cache = place((LAYERS, 1, 512 + segment, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
-    # the code asks the backend which grouped matmul to take and sees the CPU
-    # here: the test hands it the chip's, for the length of the two compiles
-    was, moe.grouped_matmul = moe.grouped_matmul, moe.expert_matmul
-    try:
-        texts = {
-            "step": jax.jit(step, donate_argnums=(4, 5)).lower(
+
+    def lowered():
+        # (a new function each time: a second trace of the same one would come from jit's cache)
+        return {
+            "step": jax.jit(lambda *a: step(*a), donate_argnums=(4, 5)).lower(
                 params, place((slots,), jnp.int32), place((slots,), jnp.int32),
-                place((slots, nb), jnp.int32), pool, pool).compile().as_text(),
-            "prefill": jax.jit(prefill, donate_argnums=(3,)).lower(
+                place((slots, nb), jnp.int32), pool, pool),
+            "prefill": jax.jit(lambda *a: prefill(*a), donate_argnums=(3,)).lower(
                 params, place((1, segment), jnp.int32), place((1, segment), jnp.int32),
-                {"k": cache, "v": cache}, place((1,), jnp.int32)).compile().as_text(),
+                {"k": cache, "v": cache}, place((1,), jnp.int32)),
         }
-    finally:
-        moe.grouped_matmul = was
+
+    with _the_chips_grouped_matmul() as moe:
+        ours = lowered()
+        texts = {name: low.compile().as_text() for name, low in ours.items()}
+        texts.update({f"{name}.lowered": low.as_text() for name, low in ours.items()})
+        # the same two programs under the ONE tile every family had until PR 43
+        was, moe.expert_tile = moe.expert_tile, lambda k, n, *_: (min(4096, k), min(512, n))
+        try:
+            texts.update({f"{name}.parent": low.as_text() for name, low in lowered().items()})
+        finally:
+            moe.expert_tile = was
     return cfg, params, texts
 
 
@@ -546,6 +568,16 @@ def test_commanda_decode_step_holds_its_kernels(commanda_programs):
     assert len(re.findall(r"%gmm[.\d]* = ", texts["step"])) == LAYERS * 3
 
 
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_commanda_programs_are_the_parents(commanda_programs, program):
+    """At 4096 x 4096 experts the tile rule answers the constant it replaced
+    (``(4096, 512)``, PR 33's sweep), so both programs lower to the text they
+    had: the cell is kept out of PR 43's change by construction."""
+    _, _, texts = commanda_programs
+    assert "tpu_custom_call" in texts[f"{program}.lowered"]
+    assert texts[f"{program}.lowered"] == texts[f"{program}.parent"]
+
+
 # ------------------------------------------------- a family with a latent pool
 #
 # ``deepseek_v2`` (models/deepseek_v2.py) at the widths of the benchmark's
@@ -559,7 +591,6 @@ def test_commanda_decode_step_holds_its_kernels(commanda_programs):
 @pytest.fixture(scope="module")
 def deepseek_programs(v5e):
     from sentio_tpu.kernels.latent_attention import make_latent_attn_impl
-    from sentio_tpu.models import moe
     from sentio_tpu.models.deepseek_v2 import DeepseekV2Config, deepseek_v2_forward, init_deepseek_v2
 
     cfg = DeepseekV2Config(n_layers=LAYERS, vocab_size=12_800)
@@ -592,8 +623,7 @@ def deepseek_programs(v5e):
         new = jax.lax.dynamic_slice_in_dim(cache["k"], n_prior[0], segment, axis=2)
         return logits[:, -1], scatter_prefill(pages, None, new, None, scat)[0], routed["counts"]
 
-    was, moe.grouped_matmul = moe.grouped_matmul, moe.expert_matmul   # as the commanda fixture does
-    try:
+    with _the_chips_grouped_matmul():
         compiled = {
             "step": jax.jit(step, donate_argnums=(4,)).lower(
                 params, place((slots,), jnp.int32), place((slots,), jnp.int32),
@@ -602,8 +632,6 @@ def deepseek_programs(v5e):
                 params, place((1, segment), jnp.int32), place((1, segment), jnp.int32), pool,
                 place((1, nb), jnp.int32), place((1,), jnp.int32), place((1, segment // page), jnp.int32)).compile(),
         }
-    finally:
-        moe.grouped_matmul = was
     return cfg, params, {k: c.as_text() for k, c in compiled.items()}, \
         {k: c.memory_analysis() for k, c in compiled.items()}
 
@@ -761,7 +789,6 @@ def test_commanda_prefill_holds_the_flash_kernel_on_both_layer_kinds(commanda_pr
 @pytest.fixture(scope="module")
 def lfm2_programs(v5e):
     from sentio_tpu.kernels.paged_attention import lane_packing
-    from sentio_tpu.models import moe
     from sentio_tpu.models.lfm2_moe import CONV, FULL, Lfm2MoeConfig, init_lfm2_cache, init_lfm2_moe, lfm2_forward
 
     cfg = Lfm2MoeConfig(n_layers=4, layer_types=(CONV, CONV, FULL, CONV))
@@ -807,8 +834,7 @@ def lfm2_programs(v5e):
         k_pages, v_pages = scatter_prefill(k_pages, v_pages, *(jnp.concatenate([a, a]) for a in new), scat)
         return logits[:, -1], k_pages, v_pages, cache["conv"], tail.at[:, scat].set(cache["tail"]), routed["counts"]
 
-    was, moe.grouped_matmul = moe.grouped_matmul, moe.expert_matmul   # as the commanda fixture does
-    try:
+    with _the_chips_grouped_matmul():
         compiled = {
             "step": jax.jit(step, donate_argnums=(4, 5, 6, 7)).lower(
                 params, place((slots,), jnp.int32), place((slots,), jnp.int32),
@@ -818,8 +844,6 @@ def lfm2_programs(v5e):
                 pool, pool, tail, place((1, 2), jnp.int32), place((1,), jnp.int32),
                 place((1, segment // page), jnp.int32)).compile(),
         }
-    finally:
-        moe.grouped_matmul = was
     return cfg, params, {k: c.as_text() for k, c in compiled.items()}, \
         {k: c.memory_analysis() for k, c in compiled.items()}, (pool, tail)
 
@@ -872,3 +896,36 @@ def test_lfm2_prefill_writes_pool_and_tails_where_they_lie(lfm2_programs):
             if m[2] not in ("parameter", "get-tuple-element", "bitcast", "tuple", "custom-call")]
     assert all(what in ("fusion:scatter", "fusion:dynamic-update-slice", "scatter", "dynamic-update-slice")
                for _n, _s, what in made), made
+
+
+
+# ------------------------------------------ the grouped matmul's tiles (PR 43)
+#
+# ``models/moe.py::expert_tile`` sizes the weight tile from the matrix and the
+# VMEM a kernel is given unasked: the expert layer of each routed family at its
+# published widths, under a decode step's rows, a 512-token segment and an
+# admission of eight (the widest row tile), compiled for the chip — a tile
+# over VMEM is refused HERE, before any chip time.
+
+ROUTED = {"commanda": ("commanda_programs", 32), "deepseek": ("deepseek_programs", 8), "lfm2": ("lfm2_programs", 16)}
+
+
+@pytest.mark.parametrize("load", ["decode", "segment", "admission"])
+@pytest.mark.parametrize("name", sorted(ROUTED))
+def test_the_expert_layer_compiles_at_the_tiles_the_rule_picks(request, v5e, name, load):
+    fixture, slots = ROUTED[name]
+    cfg, params, *_ = request.getfixturevalue(fixture)
+    mp = next(lp["moe"] for lp in params.values() if isinstance(lp, dict) and "moe" in lp)
+    b, t = {"decode": (slots, 1), "segment": (1, 512), "admission": (8, 512)}[load]
+    x = _on_one_chip(v5e)((b, t, cfg.dim), jnp.bfloat16)
+    with _the_chips_grouped_matmul() as moe:
+        text = jax.jit(lambda mp, x: moe.expert_layer(mp, cfg, x)[0]).lower(mp, x).compile().as_text()
+        tiles = moe.expert_tiles(mp, cfg, b * t)
+    # three grouped matmuls, each the Pallas call the benchmark's trace reads by its name
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 3
+    rows = {"decode": 32, "segment": 32, "admission": 256}[load]
+    assert all(t["tile"][0] == rows and moe.tile_vmem(*t["tile"]) <= moe._GMM_VMEM for t in tiles.values())
+    if load != "admission":      # the whole expert in one step, or the contraction whole
+        assert [t["steps_per_expert"] for t in tiles.values()] == {
+            "commanda": [8, 8, 8], "deepseek": [3, 3, 2], "lfm2": [1, 1, 1]}[name]

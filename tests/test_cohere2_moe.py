@@ -258,16 +258,58 @@ def test_a_row_that_does_not_advance_touches_no_expert():
     assert not np.asarray(out)[0, 3:].any() and np.asarray(out)[0, :3].any()
 
 
-def test_the_pallas_grouped_matmul_is_the_ragged_dot():
+@pytest.mark.parametrize("k, n, rows", [
+    (128, 256, 64),      # five groups' rows, one empty, and a tail that belongs to none
+    (5120, 256, 64),     # a contraction the old constant cut at 4096, the rest of 1024 masked
+    (256, 1536, 64),     # a matrix the old constant cut in three 512-lane tiles: one tile now
+    (256, 1536, 256),    # the same under the widest row tile
+])
+def test_the_pallas_grouped_matmul_is_the_ragged_dot(k, n, rows):
     """The chip's grouped matmul (megablox, interpreted here) against XLA's
     ``ragged_dot``, which runs on the CPU: rows of five groups, one empty,
-    and a tail that belongs to none."""
-    lhs = jax.random.normal(jax.random.PRNGKey(0), (256, 128), jnp.float32)
-    rhs = jax.random.normal(jax.random.PRNGKey(1), (5, 128, 256), jnp.float32)
+    and a tail that belongs to none, at the tile the rule picks: here the
+    whole matrix."""
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (256, k), jnp.float32)
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (5, k, n), jnp.float32)
     sizes = jnp.asarray([40, 0, 100, 7, 60], jnp.int32)
-    got = moe.expert_matmul(lhs, rhs, sizes, rows=64, interpret=True)[:207]
+    assert moe.expert_tile(k, n, rows, 4, 4) == (k, n)
+    got = moe.expert_matmul(lhs, rhs, sizes, rows=rows, interpret=True)[:207]
     want = jax.lax.ragged_dot(lhs, rhs, sizes, precision="highest")[:207]
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-4 * (k / 128) ** 0.5)
+
+
+# the expert matrices ``[K, N]`` of the three routed configurations as published (``w_gate`` /
+# ``w_up``, then ``w_down``), a contraction too long to stay whole and a matrix under 128 lanes;
+# beside each what the rule answers at a decode step's 32 rows
+MATRICES = {
+    "commanda": (4096, 4096, (4096, 512)),       # the tile PR 33 swept: its programs do not change
+    "lfm2-up": (2048, 1536, (2048, 1536)),       # 6.3 MB: the whole expert in one DMA
+    "lfm2-down": (1536, 2048, (1536, 2048)),
+    "dsv2-up": (5120, 1536, (5120, 512)),        # the contraction whole: no masked rest of 1024
+    "dsv2-down": (1536, 5120, (1536, 2560)),
+    "long-k": (32768, 1024, (16384, 128)),
+    "narrow": (128, 64, (128, 64)),
+}
+
+
+@pytest.mark.parametrize("rows", [32, 64, 128, 256])        # a decode step's tile ... a large admission's
+@pytest.mark.parametrize("name", sorted(MATRICES))
+def test_the_expert_tile_follows_the_matrix(name, rows):
+    """``expert_tile`` from shapes alone: ``tk`` is K or halves of it (no
+    rest to mask), ``tn`` whole lanes that divide N, the tile inside the VMEM
+    a kernel has, and no wider ``tn`` or longer ``tk`` would be."""
+    k, n, at_32 = MATRICES[name]
+    tk, tn = moe.expert_tile(k, n, rows)
+    assert k % tk == 0 and (k // tk) & (k // tk - 1) == 0
+    assert n % tn == 0 and (tn % 128 == 0 or tn == n)
+    assert moe.tile_vmem(rows, tk, tn) <= moe._GMM_VMEM
+    wider = [d for d in range(tn + 128, n + 1, 128) if n % d == 0]
+    assert not wider or moe.tile_vmem(rows, tk, wider[0]) > moe._GMM_VMEM
+    assert tk == k or moe.tile_vmem(rows, 2 * tk, 128) > moe._GMM_VMEM
+    if rows == 32:
+        assert (tk, tn) == at_32
+    if name == "commanda":
+        assert (tk, tn) == (4096, 512)       # at every row tile: the parent's constant
 
 
 # --------------------------------- (h): as served, and one precision down

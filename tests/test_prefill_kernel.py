@@ -102,6 +102,30 @@ def test_nine_segments_through_the_kernel_are_the_xla_path_and_the_reference(nam
     assert abs(got.logprob_min - chosen.min()) < tol
 
 
+@pytest.mark.parametrize("name", sorted(FAMILIES))
+def test_an_engine_says_how_its_expert_matmuls_are_tiled(name):
+    """``stats()["expert_tiles"]``: for a routed family the tile of each of a
+    layer's three grouped matmuls in the decode program — what
+    ``models/moe.py::expert_tile`` answers for the served matrices under the
+    decode rows — and null for a family without routed experts."""
+    from sentio_tpu.models import moe
+
+    cfg, tree, _, _ = FAMILIES[name]()
+    engine = ContinuousBatchingEngine(model_config=cfg, params=tree, max_slots=2, page_size=PAGE,
+                                      max_pages_per_seq=4)
+    tiles = engine.stats()["expert_tiles"]
+    if name == "dense":
+        assert tiles is None
+        return
+    layer = next(lp["moe"] for lp in engine.params.values() if isinstance(lp, dict) and "moe" in lp)
+    assert sorted(tiles) == ["w_down", "w_gate", "w_up"]
+    for matrix, said in tiles.items():
+        _, k, n = layer[matrix].shape
+        rows, tk, tn = said["tile"]
+        assert rows == moe.row_tile(2 * cfg.experts_per_token, cfg.n_experts)
+        assert (tk, tn) == moe.expert_tile(k, n, rows, 4, 4) and said["steps_per_expert"] == (k // tk) * (n // tn)
+
+
 def test_an_engine_that_keeps_the_xla_form_says_why(caplog):
     """The kernel is chosen by what the engine can see: asked for under a
     caller's own forward it is left out, with the line logged, and the stat
