@@ -256,6 +256,11 @@ class GeneratorConfig:
     # its unmatched suffix; PREFIX_CACHE=0 restores plain whole-prompt
     # admission byte-for-byte
     prefix_cache: bool = True
+    # a decoder with Mamba layers (models/nemotron_h.py): the states the
+    # prefix cache may keep, each a whole model's state at one page boundary
+    # (12.8 MB at 6 Mamba layers of the published widths); the pool is that
+    # many, allocated once. Ignored by every other family
+    ssm_snapshots: int = 64
     max_batch_size: int = 8
     # decode sub-steps fused into one device dispatch per engine tick —
     # amortizes host round trips; admission waits at most one tick. With an
@@ -310,6 +315,7 @@ class GeneratorConfig:
             kv_max_pages_per_seq=_env_int(["KV_MAX_PAGES_PER_SEQ"], 64),
             kv_quant=_env_str(["KV_QUANT"], "none"),
             prefix_cache=_env_bool(["PREFIX_CACHE"], True),
+            ssm_snapshots=_env_int(["SSM_SNAPSHOTS"], 64),
             max_batch_size=_env_int(["LLM_MAX_BATCH"], 8),
             decode_steps_per_tick=_env_int(["DECODE_STEPS_PER_TICK"], 16),
             decode_max_tick_steps=_env_int(["DECODE_MAX_TICK_STEPS"], 64),

@@ -347,6 +347,29 @@ class MetricsCollector:
                 "page tails of convolution state written, by prefill and by decode",
                 registry=r,
             ),
+            # a family with Mamba layers (runtime/paged.py, counted on the
+            # host): what each prefill row started from — zeros, a snapshot
+            # (a radix hit, cut back to a boundary whose state was kept), its
+            # slot's own state (a chunked prompt's later segment) ...
+            "ssm_starts": Counter(
+                "sentio_tpu_ssm_state_starts_total",
+                "prefill rows of a family with Mamba layers, by what their state started from",
+                ["kind"], registry=r,
+            ),
+            # ... the snapshots written into the bounded pool and those taken
+            # from their boundary for another ...
+            "ssm_snapshots": Counter(
+                "sentio_tpu_ssm_snapshots_total",
+                "snapshots of Mamba state written by prefill, and evicted from their page boundary",
+                ["event"], registry=r,
+            ),
+            # ... and the tokens the pages matched that were computed again
+            # because no snapshot stood at their boundary
+            "prefix_cut_back": Counter(
+                "sentio_tpu_prefix_cut_back_tokens_total",
+                "prefix tokens matched in pages and recomputed for want of a state snapshot",
+                registry=r,
+            ),
             # chunked prefill's turns (runtime/paged.py::_advance_prefill:
             # one segment a tick over all slots): a tick in which n slots
             # hold a pending segment books one taken and n - 1 waited.
@@ -604,7 +627,8 @@ class MetricsCollector:
                          moe: Optional[dict] = None,
                          prefill_latent: Optional[dict] = None,
                          prefill_turns: Optional[dict] = None,
-                         conv_state: Optional[dict] = None) -> None:
+                         conv_state: Optional[dict] = None,
+                         ssm_state: Optional[dict] = None) -> None:
         """One harvested tick's row-steps by kind (useful / halted / empty),
         the K/V page blocks of its sub-steps (held / tabled), of a routed
         family its expert layers' pairs (routed / held) and expert-steps
@@ -612,7 +636,9 @@ class MetricsCollector:
         latent family its prefill tokens (new / expanded), chunked
         prefill's turns (taken / waited), and of a family with convolution
         state what its prefill rows started from (zero / tail / carried) and
-        the page tails written."""
+        the page tails written, of a family with Mamba layers the same starts
+        (zero / snapshot / carried), the snapshots written and evicted, and
+        the prefix tokens cut back."""
         if not self.enabled:
             return
         from sentio_tpu.infra.phases import (
@@ -621,6 +647,8 @@ class MetricsCollector:
             PREFILL_LATENT_KINDS,
             PREFILL_TURN_KINDS,
             ROW_STEP_KINDS,
+            SSM_SNAPSHOT_EVENTS,
+            SSM_START_KINDS,
         )
 
         moe = moe or {}
@@ -633,20 +661,23 @@ class MetricsCollector:
                  {k: moe.get(f"experts_{k}", 0) for k in ("held", "touched")}),
                 ("prefill_latent", PREFILL_LATENT_KINDS, prefill_latent or {}),
                 ("conv_starts", CONV_START_KINDS, conv_state or {}),
+                ("ssm_starts", SSM_START_KINDS, ssm_state or {}),
+                ("ssm_snapshots", SSM_SNAPSHOT_EVENTS, ssm_state or {}),
                 ("prefill_turns", PREFILL_TURN_KINDS, prefill_turns or {})):
-            if name.startswith(("moe", "prefill_latent", "conv")) and not any(tick.values()):
+            if name.startswith(("moe", "prefill_latent", "conv", "ssm")) and not any(tick.values()):
                 continue  # no series where no such family is served
             counter = self._prom.get(name)
             for kind in kinds:
                 n = int(tick.get(kind, 0))
                 self.memory.inc(name, (kind,), n)
                 if counter is not None:
-                    counter.labels(kind=kind).inc(n)
-        pages = int((conv_state or {}).get("pages", 0))
-        if pages:
-            self.memory.inc("conv_tail_pages", (), pages)
-            if "conv_tail_pages" in self._prom:
-                self._prom["conv_tail_pages"].inc(pages)
+                    counter.labels(**{"event" if name == "ssm_snapshots" else "kind": kind}).inc(n)
+        for name, n in (("conv_tail_pages", int((conv_state or {}).get("pages", 0))),
+                        ("prefix_cut_back", int((ssm_state or {}).get("cut_back_tokens", 0)))):
+            if n:
+                self.memory.inc(name, (), n)
+                if name in self._prom:
+                    self._prom[name].inc(n)
 
     def record_device_program(self, program: str, seconds: float,
                               queued_s: float = 0.0) -> None:

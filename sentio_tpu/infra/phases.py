@@ -113,6 +113,7 @@ __all__ = [
     "KV_PAGE_KINDS",
     "MOE_KINDS",
     "CONV_STATE_KINDS",
+    "SSM_STATE_KINDS",
     "PREFILL_LATENT_KINDS",
     "PREFILL_TURN_KINDS",
     "DEVICE_PROGRAMS",
@@ -209,6 +210,18 @@ PREFILL_LATENT_KINDS = ("new", "expanded")
 # prefill for the pages it filled, by decode when a page filled)
 CONV_STATE_KINDS = ("zero", "tail", "carried", "pages")
 CONV_START_KINDS = CONV_STATE_KINDS[:3]
+
+# a family with Mamba layers (models/nemotron_h.py), whose state is a matrix a
+# head: what each row of a prefill dispatch STARTED from — `zero` (position 0),
+# `snapshot` (a radix hit, cut back to a page boundary whose state the cache
+# kept) or `carried` (a chunked prompt's later segment, from its slot) —, the
+# snapshots `written` (a prefill dispatch filled a slot of the bounded pool)
+# and `evicted` (a slot taken from its boundary for another; its pages stay),
+# and `cut_back_tokens`: tokens the pages matched and the model computed again
+# for want of a snapshot
+SSM_STATE_KINDS = ("zero", "snapshot", "carried", "written", "evicted", "cut_back_tokens")
+SSM_START_KINDS = SSM_STATE_KINDS[:3]
+SSM_SNAPSHOT_EVENTS = SSM_STATE_KINDS[3:5]
 
 # chunked prefill's turns (runtime/paged.py::_advance_prefill dispatches ONE
 # segment a tick over all slots): a tick in which n slots hold a pending

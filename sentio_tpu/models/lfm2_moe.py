@@ -305,13 +305,15 @@ def _rotate_half(x: Array, cos: Array, sin: Array) -> Array:
 
 
 def _attn(ap: dict, cfg: Lfm2MoeConfig, u: Array, positions: Array, layer: int, cache: Optional[Cache],
-          cache_index, pad_mask: Optional[Array], attn_fn) -> tuple[Array, Optional[Cache]]:
-    """``models/llama.py::_attn`` with the per-head norms before the rotation;
-    ``layer`` is the POOL's layer (``attn_index``)."""
+          cache_index, pad_mask: Optional[Array], attn_fn, project=qk_normed) -> tuple[Array, Optional[Cache]]:
+    """``models/llama.py::_attn`` with the per-head norms before the rotation
+    (``project``: what makes q, k and v of ``u`` — ``models/nemotron_h.py``
+    brings its own, without either); ``layer`` is the POOL's layer
+    (``attn_index``)."""
     dt = cfg.jdtype
     b, t, _ = u.shape
     h, hkv = cfg.n_heads, cfg.n_kv_heads
-    q, k, v = qk_normed(ap, cfg, u, positions)
+    q, k, v = project(ap, cfg, u, positions)
     if cache is not None:
         k = _write_cache(cache["k"][layer], k.astype(dt), cache_index)
         v = _write_cache(cache["v"][layer], v.astype(dt), cache_index)

@@ -120,8 +120,9 @@ def _write_cache(cache_layer: Array, kv: Array, index: Array | int) -> Array:
 
 # the three of :func:`qkv_proj`, in its order; then a latent family's query
 # up-projection (``models/deepseek_v2.py``), fused with its head reshape and
-# RoPE the same way
-SERVED_AS = {"wq": "wq_t", "wk": "wk_t", "wv": "wv_t", "wq_b": "wq_b_t"}
+# RoPE the same way, and a Mamba block's input projection
+# (``models/nemotron_h.py``), split three ways behind its matmul
+SERVED_AS = {"wq": "wq_t", "wk": "wk_t", "wv": "wv_t", "wq_b": "wq_b_t", "w_in": "w_in_t"}
 
 
 def serving_layout(params: dict) -> dict:
@@ -141,7 +142,10 @@ def serving_layout(params: dict) -> dict:
     transposed leaf: ``parallel/sharding.py``). Idempotent: a tree with
     nothing to turn is returned as it is, the same object. Checkpoints, the
     initialisers and ``models/convert.py`` keep the canonical names;
-    :func:`qkv_proj` reads either tree."""
+    :func:`qkv_proj` reads either tree. A Mamba block's ``w_in`` is turned
+    the same way (``w_in_t``), and a stack of experts whose width is no
+    multiple of a tile's 128 lanes is zero-padded to the next
+    (``models/moe.py::lane_padded``): both or neither for a family without."""
 
     def as_read(w):
         if w.ndim < 2:  # a bias: one value an output column either way
@@ -155,16 +159,29 @@ def serving_layout(params: dict) -> dict:
             turned[..., i:i + 64] = np.swapaxes(w[..., i:i + 64, :], -1, -2)
         return turned
 
+    from sentio_tpu.models.moe import lane_padded
+
     out = params
     for name, lp in params.items():
-        attn = lp.get("attn") if isinstance(lp, dict) else None
-        if not attn or not any(k in attn for k in SERVED_AS):
+        if not isinstance(lp, dict):
             continue
-        if out is params:
-            out = dict(params)
-        out[name] = {**lp, "attn": {
-            SERVED_AS.get(k, k): jax.tree.map(as_read, w) if k in SERVED_AS else w
-            for k, w in attn.items()}}
+        turned = {}
+        # an attention block's three, and a Mamba block's one input projection
+        # (``models/nemotron_h.py``: split and reshaped behind its matmul the same way)
+        for block in ("attn", "mamba"):
+            held = lp.get(block)
+            if held and any(k in held for k in SERVED_AS):
+                turned[block] = {SERVED_AS.get(k, k): jax.tree.map(as_read, w) if k in SERVED_AS else w
+                                 for k, w in held.items()}
+        # an expert stack whose width is no multiple of a tile's lanes: padded once, here
+        if "moe" in lp:
+            padded = lane_padded(lp["moe"])
+            if padded is not lp["moe"]:
+                turned["moe"] = padded
+        if turned:
+            if out is params:
+                out = dict(params)
+            out[name] = {**lp, **turned}
     return out
 
 

@@ -30,6 +30,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from sentio_tpu.models import layers as L
 from sentio_tpu.models.llama import Cache, LlamaConfig, _attn, init_cache  # noqa: F401
@@ -241,13 +242,47 @@ def expert_tile(k: int, n: int, rows: int, lhs_item: int = 2, rhs_item: int = 2)
     while it does not. ``tn`` is then the widest multiple of 128 lanes that
     divides N and fits: the whole matrix where it fits, one contiguous DMA an
     expert. A matrix narrower than 128 lanes, or no multiple of them, is its
-    own tile."""
+    own tile (the contraction is then what gives way: at ``[2688, 1856]`` a
+    third of K)."""
     lanes = [d for d in range(128, n + 1, 128) if n % d == 0] or [n]
+
+    def fits(tk: int) -> bool:
+        return tile_vmem(rows, tk, lanes[0], lhs_item, rhs_item) <= _GMM_VMEM
+
     tk = k
-    while tile_vmem(rows, tk, lanes[0], lhs_item, rhs_item) > _GMM_VMEM and tk % 256 == 0:
+    while not fits(tk) and tk % 256 == 0:
         tk //= 2
+    if not fits(tk):
+        # a K with no half on a lane multiple (2,688 = 21 x 128): its largest
+        # divisor of whole 128s that fits
+        tk = max((d for d in range(128, tk, 128) if tk % d == 0 and fits(d)), default=tk)
     fit = [d for d in lanes if tile_vmem(rows, tk, d, lhs_item, rhs_item) <= _GMM_VMEM]
     return tk, max(fit, default=lanes[0])
+
+
+def lane_padded(mp: dict) -> dict:
+    """A routed layer's tree with its experts' WIDTH (``w_up`` / ``w_gate``
+    ``[E, D, F]``, ``w_down`` ``[E, F, D]``; ``shared`` the same) zero-padded
+    to the next multiple of a tile's 128 lanes, where it is wider than one
+    tile and no multiple: at ``F`` = 1,856 = 14.5 tiles the chip's compiler
+    copies a whole stack into a tiled layout at the head of EVERY program
+    that reads it (0.64 GB a routed layer read and written, my compile for a
+    described v5e, PR 44). The padded columns of ``w_up`` give ``act(0) = 0``
+    and meet zero rows of ``w_down``: the same numbers. ``mp`` itself where
+    nothing is padded (the three families before it: 768, 1,536, 12,288)."""
+    width = mp["w_down"].shape[-2]
+    pad = -width % 128
+    shared = lane_padded(mp["shared"]) if "shared" in mp else None
+    if width <= 128 or not pad:
+        return mp if shared is mp.get("shared") else {**mp, "shared": shared}
+    wider = jnp.pad if isinstance(mp["w_down"], jax.Array) else np.pad
+    out = {**mp, "w_down": wider(mp["w_down"], ((0, 0), (0, pad), (0, 0)))}
+    for name in ("w_gate", "w_up"):
+        if name in mp:
+            out[name] = wider(mp[name], ((0, 0), (0, 0), (0, pad)))
+    if shared is not None:
+        out["shared"] = shared
+    return out
 
 
 def expert_matmul(lhs: Array, rhs: Array, sizes: Array, rows: int = _GMM_ROWS_MAX,
@@ -279,6 +314,8 @@ def expert_tiles(mp: dict, cfg, tokens: int) -> dict:
     item = jnp.dtype(cfg.jdtype).itemsize          # the layer casts rows and matrices to it
     tiles = {}
     for name in ("w_gate", "w_up", "w_down"):
+        if name not in mp:   # a family of ungated experts has two matrices
+            continue
         _, k, n = mp[name].shape
         tk, tn = expert_tile(k, n, rows, item, item)
         tiles[name] = {"tile": [rows, tk, tn], "steps_per_expert": -(-k // tk) * -(-n // tn)}
@@ -307,6 +344,11 @@ def group_limited(scores: Array, n_group: int, topk_group: int) -> tuple[Array, 
     return jnp.where(jnp.repeat(kept, e // n_group, axis=1), scores, 0.0), groups.astype(jnp.int32)
 
 
+def _relu2(x: Array) -> Array:
+    """``relu(x)^2``: what an UNGATED expert puts between its two matrices."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def expert_layer(
     mp: dict, cfg, x: Array, valid: Optional[Array] = None
 ) -> tuple[Array, ...]:
@@ -329,7 +371,9 @@ def expert_layer(
     ``[experts_held, F, D]`` are experts ``expert_offset ..`` — and leaves
     the others' part out: what seven absent chips would add is no part of
     this program, nor is their traffic. With every expert held that IS the
-    layer. ``mp["shared"]`` (stacks of ``n_shared_experts`` experts every
+    layer. A family of UNGATED experts (``models/nemotron_h.py``) has no
+    ``w_gate``, there or in ``shared``: an expert is ``W_down relu(W_up x)^2``,
+    two grouped matmuls. ``mp["shared"]`` (stacks of ``n_shared_experts`` experts every
     chip holds) adds their AVERAGE or their SUM (``cfg.shared_combine``:
     ``mean`` / ``sum``), once.
 
@@ -382,8 +426,9 @@ def expert_layer(
     with jax.named_scope("moe.experts"):
         token = jnp.minimum(order // k, g - 1)                      # a pad pair reads the last token
         xs = flat[token]                                            # [rows, D]
-        hidden = jax.nn.silu(grouped_matmul(xs, mp["w_gate"].astype(dt), sizes, tile)) \
-            * grouped_matmul(xs, mp["w_up"].astype(dt), sizes, tile)
+        up = grouped_matmul(xs, mp["w_up"].astype(dt), sizes, tile)
+        hidden = jax.nn.silu(grouped_matmul(xs, mp["w_gate"].astype(dt), sizes, tile)) * up \
+            if "w_gate" in mp else _relu2(up)
         ys = grouped_matmul(hidden, mp["w_down"].astype(dt), sizes, tile)  # [rows, D]
         ys = jnp.where((jnp.arange(rows) < n_here)[:, None], ys, 0)  # past the groups: not computed
         back = jnp.argsort(order)[: g * k]                           # pair → its sorted row
@@ -394,8 +439,9 @@ def expert_layer(
         with jax.named_scope("moe.shared"):
             sp = mp["shared"]
             up = jnp.einsum("gd,sdf->gsf", flat, sp["w_up"].astype(dt))
-            gate = jax.nn.silu(jnp.einsum("gd,sdf->gsf", flat, sp["w_gate"].astype(dt)))
-            shared = jnp.einsum("gsf,sfd->gd", gate * up, sp["w_down"].astype(dt),
+            hidden = jax.nn.silu(jnp.einsum("gd,sdf->gsf", flat, sp["w_gate"].astype(dt))) * up \
+                if "w_gate" in sp else _relu2(up)
+            shared = jnp.einsum("gsf,sfd->gd", hidden, sp["w_down"].astype(dt),
                                 preferred_element_type=jnp.float32)
             out = out + (shared / cfg.n_shared_experts if cfg.shared_combine == "mean" else shared)
 

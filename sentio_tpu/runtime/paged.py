@@ -66,7 +66,7 @@ from sentio_tpu.analysis.sanitizer import check_engine_invariants, engine_guard
 from sentio_tpu.infra import faults
 from sentio_tpu.infra.phases import (
     CONV_STATE_KINDS, ENGINE_PHASES, KV_PAGE_KINDS, MOE_KINDS, PREFILL_LATENT_KINDS,
-    PREFILL_TURN_KINDS, ROW_STEP_KINDS, PhaseTimer,
+    PREFILL_TURN_KINDS, ROW_STEP_KINDS, SSM_STATE_KINDS, PhaseTimer,
 )
 from sentio_tpu.infra.tracing import annotation, dispatching, get_stamper, harvested
 from sentio_tpu.models.llama import LlamaConfig, qkv_proj, serving_layout
@@ -101,6 +101,14 @@ class PagedPool:
     prefill for the pages it fills and by decode when a page fills — what a
     sequence that starts behind cached pages (a radix hit, a later chunk of
     its own prompt) starts from. Both None for every other family.
+    A family whose layers carry a MATRIX state (``models/nemotron_h.py``: Mamba-2
+    blocks) holds the same two names as dicts of arrays, a Mamba layer each on
+    the leading axis: ``conv = {"conv": [Lm, slots, 3, 6144], "ssm": [Lm, slots,
+    64, 64, 128] float32}``, what a decode slot carries, and ``tail`` the same
+    names ``[Lm, S, ...]``: a BOUNDED pool of ``S`` snapshots in place of a
+    tail a page (a state is fifty times the K and V of the page it ends), whose
+    slots the radix cache hands to the page boundaries it chooses
+    (``runtime/radix.py``).
     Page id 0 = scratch."""
 
     k: Array
@@ -131,8 +139,26 @@ class PagedPool:
     @property
     def conv_state_bytes(self) -> int:
         """Of ``hbm_bytes``, the convolution state (0 for a family without)."""
-        return sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
-                   for a in (self.conv, self.tail) if a is not None)
+        return _tree_bytes((self.conv, self.tail))
+
+    @property
+    def snapshot_bytes(self) -> int:
+        """Of ``conv_state_bytes``, the snapshot pool of a family with a
+        matrix state (0 for every other)."""
+        return _tree_bytes(self.tail) if isinstance(self.tail, dict) else 0
+
+
+def _tree_bytes(tree) -> int:
+    import jax
+
+    return sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
+# a family with Mamba layers: the page boundaries ONE row of ONE prefill
+# dispatch may leave a snapshot at (the boundary a match was cut back from, and
+# the last whole page the dispatch reaches: ``_snapshot_plan``)
+SNAPS_PER_ROW = 2
 
 
 def quantize_kv(x):
@@ -212,6 +238,12 @@ def has_conv_state(cfg) -> bool:
     return bool(getattr(cfg, "conv_layers", ()))
 
 
+def has_ssm_state(cfg) -> bool:
+    """Whether ``cfg``'s family carries a matrix state a head beside the pages
+    (``models/nemotron_h.py``: its config names its Mamba layers)."""
+    return bool(getattr(cfg, "ssm_layers", ()))
+
+
 def _latent_tokens(pages, index):
     """Latent pages ``pages[index]`` ``[..., NB, latent_dim, page]`` as the
     tokens they hold, ``[..., NB * page, latent_dim]``."""
@@ -235,7 +267,7 @@ def _latent_write(pages, layer, page_ids, offsets, latents):
 
 def init_pool(
     cfg: LlamaConfig, num_pages: int, page_size: int, mesh=None,
-    quantized: bool = False, slots: int = 0, pack: int = 1,
+    quantized: bool = False, slots: int = 0, pack: int = 1, snapshots: int = 0,
 ) -> PagedPool:
     """Allocate the page pool; with a mesh, kv heads shard over ``tp`` (the
     same axis the wk/wv weight columns shard on, so per-shard Q·K never
@@ -243,7 +275,8 @@ def init_pool(
     ``quantized`` the pool stores int8 + per-vector scales — ~half the HBM
     and half the decode-attention read bandwidth of bf16 pages. A family with
     convolution layers gets pages for its attention layers and, for ``slots``
-    decode slots, the convolution state beside them (``PagedPool``).
+    decode slots, the convolution state beside them (``PagedPool``); one with
+    Mamba layers its state for ``slots`` slots and ``snapshots`` snapshots.
 
     ``pack`` > 1 is the pool's layout for heads NARROWER than the 128 lanes of
     a tile (``kernels/paged_attention.py::lane_packing``): ``pack`` kv heads
@@ -270,6 +303,12 @@ def init_pool(
         n_layers, lc = len(cfg.attn_layers), len(cfg.conv_layers)
         conv = jnp.zeros((lc, slots, cfg.conv_taps, cfg.dim), cfg.jdtype)
         tail = jnp.zeros((lc, num_pages, cfg.conv_taps, cfg.dim), cfg.jdtype)
+    if has_ssm_state(cfg):
+        if mesh is not None:
+            raise ValueError("a Mamba layer's state is held on one device: it has no rule under a mesh yet")
+        n_layers = len(cfg.attn_layers)
+        conv, tail = ({name: jnp.zeros(shape, dtype) for name, (shape, dtype) in cfg.state_shapes(rows).items()}
+                      for rows in (slots, snapshots))
     if pack > 1 and (quantized or mesh is not None or cfg.n_kv_heads % pack):
         raise ValueError(f"pack={pack}: lane-packed pages are bf16, on one device, whole rows of heads")
     shape = (n_layers, num_pages, page_size, cfg.n_kv_heads // pack, cfg.head_dim * pack)
@@ -382,7 +421,9 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
     fourth result, None for every other family. A family with CONVOLUTION
     layers (``models/lfm2_moe.py``) is given its state — ``conv [Lc, B, 2,
     d]``, a row a slot, and the page tails ``tail [Lc, P, 2, d]`` — and
-    returns six: the four, then both carried on.
+    returns six: the four, then both carried on. A family with MAMBA layers
+    (``models/nemotron_h.py``) the same, ``conv`` being its slots' state;
+    decode writes no snapshot, so its ``tail`` may stay away (None).
     """
     import jax
     import jax.numpy as jnp
@@ -390,8 +431,8 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
     from sentio_tpu.models import layers as L
 
     if conv is not None:
-        return _paged_decode_conv(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail,
-                                  attn_impl, write_mask)
+        decode = _paged_decode_ssm if has_ssm_state(cfg) else _paged_decode_conv
+        return decode(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail, attn_impl, write_mask)
     if getattr(cfg, "parallel_block", False):
         out = _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
                                      attn_impl, write_mask)
@@ -647,6 +688,67 @@ def _paged_decode_conv(params, cfg, tok, lens, page_table, k_pages, v_pages, con
     return logits, k_pages, v_pages, {**routed, "counts": counts}, conv, tail
 
 
+def _paged_decode_ssm(params, cfg, tok, lens, page_table, k_pages, v_pages, state, snaps,
+                      attn_impl, write_mask):
+    """:func:`paged_decode_forward` for the family of ``models/nemotron_h.py``:
+    blocks of ONE operator each — a Mamba-2 update of the slot's carried state
+    (``state = {"conv": [Lm, B, 3, C], "ssm": [Lm, B, H, P, N]}``), rotation-free
+    attention over the pages (pool layer ``cfg.attn_index(i)``) or routed
+    experts. A row that does not advance (``write_mask`` false) keeps its
+    state. Decode writes no snapshot: ``snaps`` goes through as it came. →
+    (logits [B, V], k_pages, v_pages, routed, state, snaps)."""
+    import jax
+    import jax.numpy as jnp
+
+    from sentio_tpu.models import layers as L
+    from sentio_tpu.models import nemotron_h as M
+    from sentio_tpu.models.moe import expert_layer
+
+    dt = cfg.jdtype
+    b = tok.shape[0]
+    page = _page_dim(k_pages)
+    page_ids = jnp.take_along_axis(page_table, (lens // page)[:, None], axis=1)[:, 0]
+    offsets = lens % page
+    advancing = jnp.ones((b,), bool) if write_mask is None else write_mask
+    attn_lens, valid = lens, None
+    if write_mask is not None:  # as in the sequential block, above
+        page_ids = jnp.where(write_mask, page_ids, 0)
+        offsets = jnp.where(write_mask, offsets, 0)
+        attn_lens = jnp.where(write_mask, lens, 0)
+        valid = write_mask[:, None]
+    impl = attn_impl or _paged_attn_xla
+
+    x = L.embed(params["embed_tokens"], tok[:, None], dt)
+    state = dict(state)
+    picks, counts = [], jnp.zeros((4,), jnp.int32)
+    for i, kind in enumerate(cfg.pattern):
+        lp = params[f"layers_{i}"]
+        u = L.rmsnorm(lp["norm"], x, cfg.norm_eps)
+        if kind == M.MAMBA:
+            j = cfg.ssm_index(i)
+            out, after = M.mamba_step(lp["mamba"], cfg, u, {name: s[j] for name, s in state.items()})
+            for name, s in state.items():
+                keep = advancing.reshape(b, *([1] * (s.ndim - 2)))
+                state[name] = s.at[j].set(jnp.where(keep, after[name].astype(s.dtype), s[j]))
+        elif kind == M.ATTENTION:
+            a = cfg.attn_index(i)
+            q, k, v = M.plain_qkv(lp["attn"], cfg, u, None)
+            k_pages = _page_write(k_pages, a, page_ids, offsets, k[:, 0].astype(dt))
+            v_pages = _page_write(v_pages, a, page_ids, offsets, v[:, 0].astype(dt))
+            with jax.named_scope("attn.full"):
+                attn = impl(q, k_pages, v_pages, a, page_table, attn_lens, cfg.n_heads // cfg.n_kv_heads)
+            out = L.dense(lp["attn"]["wo"], attn.reshape(b, 1, -1), dt)
+        else:
+            # a row that does not advance is routed nowhere (see the parallel block)
+            out, chosen, n = expert_layer(lp["moe"], cfg, u, valid)
+            picks.append(chosen[:, 0])
+            counts = counts + n
+        x = x + out
+    logits = M.head_logits(params, cfg, x)[:, 0]
+    routed = {"experts": jnp.stack(picks)} if picks else {}
+    return logits, k_pages, v_pages, {**routed, "counts": counts}, state, snaps
+
+
 def scatter_prefill(k_pages, v_pages, k_cache, v_cache, page_table):
     """Copy a contiguous prefill cache into the pool.
 
@@ -738,6 +840,15 @@ class _Slot:
     # position, tokens), fetched when the request retires
     choices: Optional[dict] = None
     choice_parts: list = field(default_factory=list)
+    # a family with Mamba layers: the snapshot this request starts from
+    # (pinned in the radix cache until its first prefill is dispatched), the
+    # tokens its pages matched where that is MORE than it starts behind (the
+    # boundary a snapshot is due at: the match was cut back), and the
+    # (boundary, snapshot) its prefill dispatches wrote and the radix cache
+    # has not been told of yet
+    start_snap: Optional[int] = None
+    cut_from: int = 0
+    snaps_written: list = field(default_factory=list)
 
 
 @dataclass
@@ -873,10 +984,13 @@ class ContinuousBatchingEngine:
         draft_config=None,
         spec_k: int = 4,
         prefix_cache: bool = True,
+        ssm_snapshots: int = 64,
     ) -> None:
         """``forward_fn`` swaps the prefill model family (llama_forward
         contract); the fused decode tick detects the family per layer (a
-        ``moe`` subtree routes through models/moe.py)."""
+        ``moe`` subtree routes through models/moe.py). ``ssm_snapshots``: the
+        states a family with Mamba layers keeps for the prefix cache
+        (``PagedPool``; ``SSM_SNAPSHOTS``), ignored by every other."""
         import jax
 
         from sentio_tpu.models.cohere2_moe import Cohere2MoeConfig, cohere2_forward
@@ -884,6 +998,7 @@ class ContinuousBatchingEngine:
         from sentio_tpu.models.lfm2_moe import Lfm2MoeConfig, lfm2_forward
         from sentio_tpu.models.llama import llama_forward
         from sentio_tpu.models.moe import MoeConfig, expert_tiles, moe_serving_forward
+        from sentio_tpu.models.nemotron_h import NemotronHConfig, nemotron_h_forward
 
         from sentio_tpu.models.tokenizer import ByteTokenizer
 
@@ -921,22 +1036,38 @@ class ContinuousBatchingEngine:
         # picks, and the pairs they routed: ``models/moe.py::expert_layer``)
         family_forward = {Cohere2MoeConfig: cohere2_forward,
                           DeepseekV2Config: deepseek_v2_forward,
-                          Lfm2MoeConfig: lfm2_forward}.get(type(self.cfg))
+                          Lfm2MoeConfig: lfm2_forward,
+                          NemotronHConfig: nemotron_h_forward}.get(type(self.cfg))
         self.routed = family_forward is not None
         # a family whose pool holds ONE latent a token and layer in place of K and V
         self.latent = is_latent(self.cfg)
         # a family whose convolution layers carry state per slot and per page
         # beside the pool (``PagedPool.conv``, ``.tail``)
         self.conv_state = has_conv_state(self.cfg)
-        if self.conv_state:
+        # a family whose Mamba layers carry a matrix state per slot, and in a
+        # bounded pool of snapshots the radix cache hands out (the same two
+        # names of the pool, as dicts)
+        self.ssm_state = has_ssm_state(self.cfg)
+        # either: state beside the pages, threaded through the four programs
+        self.slot_state = self.conv_state or self.ssm_state
+        if self.slot_state:
             from sentio_tpu.runtime.paged_spec import refuse_recurrent_state
 
             if draft_params is not None:
                 refuse_recurrent_state(self.cfg)
             if mesh is not None:
-                raise ValueError(f"a family with convolution state ({type(self.cfg).__name__}) is served on "
-                                 "one device a process: the state per slot and per page has no rule for "
+                what, where = ("convolution", "page") if self.conv_state else ("Mamba", "snapshot")
+                raise ValueError(f"a family with {what} state ({type(self.cfg).__name__}) is served on "
+                                 f"one device a process: the state per slot and per {where} has no rule for "
                                  "a mesh yet")
+        if self.ssm_state:
+            if kv_quant != "none":
+                raise ValueError(f"kv_quant={kv_quant!r}: K and V are a thirtieth of what a sequence of "
+                                 f"{type(self.cfg).__name__} keeps (its Mamba state is float32, as the model "
+                                 "card advises): int8 pages beside it have no quality gate and nothing to save")
+            if page_size % self.cfg.chunk_size:
+                raise ValueError(f"page_size={page_size}: a snapshot is the scan's state at a chunk boundary, "
+                                 f"so a page is whole chunks of {self.cfg.chunk_size} tokens")
         if self.routed:
             name = type(self.cfg).__name__
             if forward_fn not in (None, family_forward):
@@ -1056,6 +1187,8 @@ class ContinuousBatchingEngine:
         # heads side by side in a row, so that its pages are whole tiles
         # (``init_pool``); bf16 pages on one device, and not under
         # speculation, whose dense cache reads the pool's shape
+        # the snapshot pool's slots (at least one: the programs index it)
+        self._snapshots = max(int(ssm_snapshots), 1) if self.ssm_state else 0
         self._kv_pack = 1
         if (jax.default_backend() == "tpu" if use_pallas is None else use_pallas) \
                 and kv_quant == "none" and mesh is None and draft_params is None and not self.latent:
@@ -1065,6 +1198,7 @@ class ContinuousBatchingEngine:
         self.pool = init_pool(
             self.cfg, num_pages, page_size, mesh=mesh,
             quantized=kv_quant == "int8", slots=max_slots, pack=self._kv_pack,
+            snapshots=self._snapshots,
         )
         self.allocator = PageAllocator(num_pages)  # guarded-by: engine-thread
 
@@ -1136,6 +1270,17 @@ class ContinuousBatchingEngine:
         self.conv_state_total = dict.fromkeys(CONV_STATE_KINDS, 0)
         self.last_tick_conv_state = dict.fromkeys(CONV_STATE_KINDS, 0)
         self._conv_state_pending = dict.fromkeys(CONV_STATE_KINDS, 0)
+        # a family with Mamba layers, the same three books: what each row of a
+        # prefill dispatch STARTED from — ``zero``, ``snapshot`` (a radix hit,
+        # cut back to a boundary that kept its state) or ``carried`` (a later
+        # segment of a chunked prompt, from its slot) —, the snapshots
+        # ``written`` (handed a slot and filled by a prefill dispatch) and
+        # ``evicted`` (a slot taken from its boundary for another), and the
+        # tokens the pages matched that were computed again for want of a
+        # snapshot (``cut_back_tokens``)
+        self.ssm_state_total = dict.fromkeys(SSM_STATE_KINDS, 0)
+        self.last_tick_ssm_state = dict.fromkeys(SSM_STATE_KINDS, 0)
+        self._ssm_state_pending = dict.fromkeys(SSM_STATE_KINDS, 0)
         # chunked prefill dispatches ONE segment a tick over all slots: a
         # tick in which n slots hold a pending segment books one turn
         # ``taken`` and n - 1 ``waited``. Counted in ``_advance_prefill``
@@ -1170,7 +1315,7 @@ class ContinuousBatchingEngine:
         if self._prefix_cache_enabled:
             from sentio_tpu.runtime.radix import RadixPrefixCache
 
-            self._radix = RadixPrefixCache(page_size, self.allocator)
+            self._radix = RadixPrefixCache(page_size, self.allocator, self._snapshots)
         else:
             self._radix = None
         # operator visibility for the BPE-boundary failure mode: a cached
@@ -1281,7 +1426,7 @@ class ContinuousBatchingEngine:
 
             why = ""
             if self.forward_fn not in (llama_forward, moe_serving_forward, cohere2_forward,
-                                       deepseek_v2_forward, lfm2_forward):
+                                       deepseek_v2_forward, lfm2_forward, nemotron_h_forward):
                 why = "the caller brought its own forward_fn"
             elif mesh is not None:
                 why = "the prefill kernel runs on one device a process, and this engine has a mesh"
@@ -1309,6 +1454,14 @@ class ContinuousBatchingEngine:
 
         ignore_eos = self.ignore_eos
         routed = self.routed
+        # a forward that computes its head at ONE position a row where asked
+        # (``models/nemotron_h.py``): an admission reads no other logit
+        last_only = getattr(forward_fn, "takes_logits_at", False)
+
+        def last_logits(logits, lens):
+            """[B, V] at each row's last token, of all positions' or of that one's."""
+            return logits[:, 0] if last_only else \
+                jnp.take_along_axis(logits, (lens - 1)[:, None, None], axis=1)[:, 0]
 
         @jit_family("paged.step_n", static_argnames=("steps",),
                     donate_argnums=(5, 6), donate_argnames=("conv", "tail"))
@@ -1346,7 +1499,9 @@ class ContinuousBatchingEngine:
             A family with convolution state (``conv`` [Lc, B, 2, d], a row a
             slot, and the page tails ``tail`` [Lc, P, 2, d]; both donated)
             carries both through the scan — a row that does not advance in a
-            sub-step keeps its state — and returns them last.
+            sub-step keeps its state — and returns them last. A family with
+            MAMBA layers hands in its slots' state as ``conv`` alone: decode
+            writes no snapshot, and the last result is None.
             """
             from sentio_tpu.runtime.sampling import sample_tokens
 
@@ -1379,8 +1534,7 @@ class ContinuousBatchingEngine:
                 more = dict(more)
                 if routed:
                     more["moe"] = more["moe"] + moe[0]["counts"]
-                if state:
-                    more["conv"], more["tail"] = moe[1:]
+                more.update({name: new for name, new in zip(("conv", "tail"), moe[1:]) if name in state})
                 return (tok, lens, k_pages, v_pages, rng, halted, lp_sum, lp_min,
                         lp_cnt, more), ((nxt, picks_of(moe[0])) if routed else nxt)
 
@@ -1389,8 +1543,7 @@ class ContinuousBatchingEngine:
             if not ignore_eos:
                 halted = halted | (tok == eos_id)
             more = {"moe": moe_acc} if routed else {}
-            if conv is not None:
-                more.update(conv=conv, tail=tail)
+            more.update({name: arr for name, arr in (("conv", conv), ("tail", tail)) if arr is not None})
             (tok, lens, k_pages, v_pages, rng, halted,
              lp_sum, lp_min, lp_cnt, more), toks = jax.lax.scan(
                 body, (tok, lens, k_pages, v_pages, rng, halted, lp_sum, lp_min, lp_cnt, more),
@@ -1414,7 +1567,7 @@ class ContinuousBatchingEngine:
                    lp_sum, lp_min, lp_cnt, k_pages, v_pages, rng)
             if routed:
                 out = (*out, picks)
-            return out if conv is None else (*out, more["conv"], more["tail"])
+            return out if conv is None else (*out, more["conv"], more.get("tail"))
 
         self._step_n = step_n
 
@@ -1443,9 +1596,9 @@ class ContinuousBatchingEngine:
 
         self._merge_admitted = merge_admitted
 
-        @jit_family("paged.prefill_scatter", donate_argnums=(7, 8), donate_argnames=("tail",))
+        @jit_family("paged.prefill_scatter", donate_argnums=(7, 8), donate_argnames=("tail", "conv"))
         def prefill_scatter(params, ids, positions, lens, rng, temps, scat,
-                            k_pages, v_pages, top_ks, moe_acc=None, tail=None):
+                            k_pages, v_pages, top_ks, moe_acc=None, tail=None, conv=None, snap=None):
             """Batched admission in ONE dispatch: contiguous prefill forward,
             cache scatter into each row's pages, first-token sample (token +
             its logprob, seeding the confidence accumulators) from each
@@ -1456,34 +1609,62 @@ class ContinuousBatchingEngine:
             starts every row from zeros and returns two more: each row's
             state after ITS prompt [Lc, B, 2, d] (the merge into the decode
             batch puts it in the row's slot) and ``tail`` with the tails of
-            the pages this call filled."""
+            the pages this call filled. A family with MAMBA layers (``tail``
+            the snapshot pool, ``conv`` its slots' state, both donated; ``snap``
+            small host arrays a row: ``slot`` [B], and the chunk boundaries
+            ``at`` [B, K] whose state goes to the snapshots ``ids`` [B, K], an
+            id past the pool for none) starts every row from zeros, puts each
+            row's state after ITS prompt in its slot, and returns ``conv`` and
+            ``tail`` so written."""
             from sentio_tpu.runtime.sampling import sample_tokens
 
             b, width = ids.shape
-            cache = new_cache(b, width, width // page_size)
+            cache = ssm_start(new_cache(b, width, width // page_size), snap, conv, tail)
             # pad tails and junk admission rows must not claim routed-expert
             # capacity (llama ignores the mask on the cache path)
             pad_mask = jnp.arange(width)[None, :] < lens[:, None]
             logits, cache, *moe = forward_fn(
                 params, cfg, ids, positions=positions, cache=cache, cache_index=0,
-                pad_mask=pad_mask,
+                pad_mask=pad_mask, **({"logits_at": lens - 1} if last_only else {}),
             )
             k_pages, v_pages = scatter_prefill(
                 k_pages, v_pages, cache["k"], cache["v"], scat
             )
-            last = jnp.take_along_axis(logits, (lens - 1)[:, None, None], axis=1)[:, 0]
             rng, sub = jax.random.split(rng)
-            first, first_lp = sample_tokens(last, sub, temps, top_k=top_ks)
+            first, first_lp = sample_tokens(last_logits(logits, lens), sub, temps, top_k=top_ks)
             out = first, first_lp, k_pages, v_pages, rng
-            return prefill_results(out, moe, moe_acc, cache, scat, tail)
+            return prefill_results(out, moe, moe_acc, cache, scat, tail, conv, snap)
 
-        def prefill_results(out, moe, moe_acc, cache, scat, tail):
+        def prefill_results(out, moe, moe_acc, cache, scat, tail, conv, snap):
             """A prefill program's five results, then what its family adds."""
             if routed:
                 out = (*out, prefill_routed(moe[0], moe_acc))
-            if tail is not None:  # [Lc, B, NB, 2, d] at the [B, NB] pages the call filled
+            if snap is not None:  # each row's state into its slot, [Lm, B, K, ...] into the snapshots asked
+                out = (*out,
+                       {name: conv[name].at[:, snap["slot"]].set(cache["state"][name], mode="drop") for name in conv},
+                       {name: tail[name].at[:, snap["ids"]].set(cache["snaps"][name], mode="drop") for name in tail})
+            elif tail is not None:  # [Lc, B, NB, 2, d] at the [B, NB] pages the call filled
                 out = (*out, cache["conv"], tail.at[:, scat].set(cache["tail"]))
             return out
+
+        def ssm_start(cache, snap, conv, tail):
+            """A family with Mamba layers: the cache with the boundaries to
+            snapshot, and each row's state as ``snap["start"]`` [B] says (a
+            snapshot's id; -1 zeros, the only start without the key; -2 the
+            row's own slot: a chunked prompt's earlier segment left it there)."""
+            if snap is None:
+                return cache
+            cache = dict(cache, snap_at=snap["at"])
+            if "start" in snap:
+                start, slot = snap["start"], jnp.minimum(snap["slot"], max_slots - 1)
+
+                def pick(name):
+                    rows = (1, -1) + (1,) * (tail[name].ndim - 2)
+                    return jnp.where((start >= 0).reshape(rows), tail[name][:, jnp.maximum(start, 0)],
+                                     jnp.where((start == -2).reshape(rows), conv[name][:, slot], 0))
+
+                cache["state"] = {name: pick(name) for name in cache["state"]}
+            return cache
 
         def prefill_routed(moe, moe_acc):
             # a prefill's pairs are counted; expert-steps are the decode tick's
@@ -1491,8 +1672,8 @@ class ContinuousBatchingEngine:
 
         latent = self.latent
 
-        conv_state = self.conv_state
-        page_size = self.page_size
+        conv_state, ssm_state = self.conv_state, self.ssm_state
+        page_size, max_slots = self.page_size, self.max_slots
 
         def new_cache(rows, length, pages=0):
             """The contiguous cache a prefill fills: K and V, or latents alone,
@@ -1502,6 +1683,10 @@ class ContinuousBatchingEngine:
                 from sentio_tpu.models.lfm2_moe import init_lfm2_cache
 
                 return init_lfm2_cache(cfg, rows, length, pages)
+            if ssm_state:
+                from sentio_tpu.models.nemotron_h import init_nemotron_cache
+
+                return init_nemotron_cache(cfg, rows, length, SNAPS_PER_ROW)
             if latent:
                 from sentio_tpu.models.deepseek_v2 import init_latent_cache
 
@@ -1513,10 +1698,10 @@ class ContinuousBatchingEngine:
         self._prefill_scatter = prefill_scatter
 
         @jit_family("paged.prior_prefill_scatter", static_argnames=("do_sample",),
-                    donate_argnums=(7, 8), donate_argnames=("tail",))
+                    donate_argnums=(7, 8), donate_argnames=("tail", "conv"))
         def prior_prefill_scatter(params, ids, positions, lens, rng, temps,
                                   scat, k_pages, v_pages, prior_table,
-                                  n_prior, top_ks, do_sample, moe_acc=None, tail=None):
+                                  n_prior, top_ks, do_sample, moe_acc=None, tail=None, conv=None, snap=None):
             """Prefill a batch of suffixes against per-row prior KV already
             in the pool — ONE compiled family for both radix-cache admission
             (prior = the matched shared-prefix pages) and chunked-prefill
@@ -1545,14 +1730,19 @@ class ContinuousBatchingEngine:
             its prior's LAST page (``tail`` [Lc, P, 2, d], donated; a prior is
             whole pages: a radix hit's, or this prompt's earlier segments',
             whose tails the calls that filled them left there) — zeros for a
-            row without a prior — and returns what ``prefill_scatter`` does."""
+            row without a prior — and returns what ``prefill_scatter`` does.
+
+            A family with MAMBA layers starts each row where ``snap["start"]``
+            says (``ssm_start``): the SNAPSHOT the radix cache cut its match
+            back to, or the state its own slot carries from the prompt's
+            earlier segment — a prior's pages hold K and V, no state."""
             from sentio_tpu.runtime.sampling import sample_tokens
 
             b, width = ids.shape
             pnb = prior_table.shape[1]
             prior_w = pnb * page_size
-            cache = new_cache(b, prior_w + width, width // page_size)
-            if tail is not None and pnb:
+            cache = ssm_start(new_cache(b, prior_w + width, width // page_size), snap, conv, tail)
+            if conv_state and pnb:
                 last = jnp.take_along_axis(
                     prior_table, jnp.maximum(n_prior // page_size - 1, 0)[:, None], axis=1)[:, 0]
                 cache = dict(cache)
@@ -1581,7 +1771,7 @@ class ContinuousBatchingEngine:
             pad_mask = jnp.arange(width)[None, :] < lens[:, None]
             logits, cache, *moe = forward_fn(
                 params, cfg, ids, positions=positions, cache=cache,
-                cache_index=n_prior, pad_mask=pad_mask,
+                cache_index=n_prior, pad_mask=pad_mask, **({"logits_at": lens - 1} if last_only else {}),
             )
             # each row's new KV sits at its own dynamic offset in the primed
             # cache — slice the [n_prior, n_prior + width) window per row
@@ -1597,15 +1787,13 @@ class ContinuousBatchingEngine:
             k_new, v_new = new_rows(cache["k"]), new_rows(cache["v"])
             k_pages, v_pages = scatter_prefill(k_pages, v_pages, k_new, v_new, scat)
             if do_sample:
-                last = jnp.take_along_axis(
-                    logits, (lens - 1)[:, None, None], axis=1)[:, 0]
                 rng, sub = jax.random.split(rng)
-                first, first_lp = sample_tokens(last, sub, temps, top_k=top_ks)
+                first, first_lp = sample_tokens(last_logits(logits, lens), sub, temps, top_k=top_ks)
             else:
                 first = jnp.zeros((b,), jnp.int32)
                 first_lp = jnp.zeros((b,), jnp.float32)
             out = first, first_lp, k_pages, v_pages, rng
-            return prefill_results(out, moe, moe_acc, cache, scat, tail)
+            return prefill_results(out, moe, moe_acc, cache, scat, tail, conv, snap)
 
         self._prior_prefill_scatter = prior_prefill_scatter
 
@@ -1734,13 +1922,15 @@ class ContinuousBatchingEngine:
             [(toks[:full], 0.0, 0, [0] * (matched // self.page_size) + pages)],
             width,
         )
+        written: list = []
         (_first, _first_lp, self.pool.k, self.pool.v, self._rng), _picks, _conv_rows = \
             self._prefill_call(
                 self._prefill_scatter,
                 self.params, ids, positions, lens, self._rng, temps, scat,
-                self.pool.k, self.pool.v, top_ks,
+                self.pool.k, self.pool.v, top_ks, rows=[(written, 0, full)],
             )
         _node, donated = self._radix.insert(toks[:full], matched, pages)
+        self._attach_snapshots(toks[:full], written)
         leftover = set(pages) - set(donated)
         if leftover:  # span raced into the tree between match and insert
             self.allocator.free(list(leftover))
@@ -1805,6 +1995,7 @@ class ContinuousBatchingEngine:
         self.pool = init_pool(
             self.cfg, self.allocator.num_pages, self.page_size, mesh=self.mesh,
             quantized=self.kv_quant == "int8", slots=self.max_slots, pack=self._kv_pack,
+            snapshots=self._snapshots,
         )
         self.allocator = PageAllocator(self.allocator.num_pages)
         self.slots = [_Slot() for _ in range(self.max_slots)]
@@ -1817,6 +2008,7 @@ class ContinuousBatchingEngine:
         self._prefill_latent_pending = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         self._prefill_turns_pending = dict.fromkeys(PREFILL_TURN_KINDS, 0)
         self._conv_state_pending = dict.fromkeys(CONV_STATE_KINDS, 0)
+        self._ssm_state_pending = dict.fromkeys(SSM_STATE_KINDS, 0)
         # the failed tick's arrays are not worth waiting for
         get_stamper().drain()
         if self._inflight is not None:
@@ -1827,7 +2019,7 @@ class ContinuousBatchingEngine:
         if self._prefix_cache_enabled:
             from sentio_tpu.runtime.radix import RadixPrefixCache
 
-            self._radix = RadixPrefixCache(self.page_size, self.allocator)
+            self._radix = RadixPrefixCache(self.page_size, self.allocator, self._snapshots)
         self._spec_dk = self._spec_dv = None  # rebuilt lazily (zeros)
         self._page_table[:] = 0
         self._lens[:] = 0
@@ -1890,7 +2082,7 @@ class ContinuousBatchingEngine:
             draft_params=self.draft_params,
             draft_config=self.draft_cfg,
             spec_k=self.spec_k,
-            prefix_cache=self._prefix_cache_enabled,
+            prefix_cache=self._prefix_cache_enabled, ssm_snapshots=self._snapshots,
         )
 
     @property
@@ -1940,6 +2132,7 @@ class ContinuousBatchingEngine:
         self.last_tick_prefill_latent = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         self.last_tick_prefill_turns = dict.fromkeys(PREFILL_TURN_KINDS, 0)
         self.last_tick_conv_state = dict.fromkeys(CONV_STATE_KINDS, 0)
+        self.last_tick_ssm_state = dict.fromkeys(SSM_STATE_KINDS, 0)
         self.last_tick_sub_steps = 0
         # chaos-drill injection point: a raised fault propagates exactly like
         # a real failed device dispatch (the serving pump resets + requeues)
@@ -2082,17 +2275,23 @@ class ContinuousBatchingEngine:
     def _match_radix(self, tok_ids: Sequence[int]):
         """Longest-prefix match against the radix cache, clamped so at
         least one suffix token remains to prefill (the first sampled token
-        comes from the last prompt logit). → (shared, pages, node)."""
+        comes from the last prompt logit). → (shared, pages, node, snapshot,
+        paged): the last two a family's with Mamba layers — the match is CUT
+        BACK to the deepest boundary whose state the cache kept, ``snapshot``
+        its slot and ``paged`` the tokens the pages alone would have served
+        (None and ``shared`` for every other family)."""
         if self._radix is None or self._radix.empty:
-            return 0, [], None
-        matched, pages, node = self._radix.match(tok_ids)
+            return 0, [], None, None, 0
         max_shared = ((len(tok_ids) - 1) // self.page_size) * self.page_size
+        if self.ssm_state:
+            return self._radix.match_state(tok_ids, max_shared)
+        matched, pages, node = self._radix.match(tok_ids)
         if matched > max_shared:
             matched = max_shared
             pages = pages[: matched // self.page_size]
         if matched <= 0:
-            return 0, [], None
-        return matched, pages, node
+            return 0, [], None, None, 0
+        return matched, pages, node, None, matched
 
     def _radix_insert(self, slot_idx: int, tok_ids, shared: int) -> None:
         """Move slot ``slot_idx``'s freshly prefilled full-page prompt span
@@ -2109,11 +2308,66 @@ class ContinuousBatchingEngine:
             return
         own = slot.pages[: (full - shared) // self.page_size]
         node, donated = self._radix.insert(list(tok_ids[:full]), shared, own)
+        self._attach_snapshots(tok_ids, slot.snaps_written)
         slot.donated.extend(donated)
         if node is not None and node is not slot.prefix_node:
             self._radix.lock(node)
             self._radix.unlock(slot.prefix_node)
             slot.prefix_node = node
+
+    def _attach_snapshots(self, tok_ids, written: list) -> None:
+        """Tell the radix cache of the snapshots a prompt's prefill wrote
+        (``written``: (boundary, snapshot), emptied): each boundary of
+        ``tok_ids`` owns its snapshot from now — a boundary that owns one
+        already, or is not in the tree, gives the slot back."""
+        for boundary, snap in written:
+            self._radix.snap_attach(tok_ids, boundary, snap)
+        written.clear()
+
+    def _row_slot(self, who) -> Optional[_Slot]:
+        """A prefill row's slot: ``who`` is its index, or — a row no request
+        owns (``warm_prefix``) — the list its written snapshots go to."""
+        return self.slots[who] if isinstance(who, int) else None
+
+    def _snapshot_rows(self, n_rows: int, rows, prior: bool) -> dict:
+        """The ``snap`` arrays of one prefill dispatch of ``n_rows`` rows for
+        a family with Mamba layers. ``rows``: (slot index — ``_row_slot`` —,
+        first position, tokens) a row. THE POLICY, a row: a snapshot at the boundary its match
+        was cut back from, where the dispatch passes it (the pages said a
+        prompt is shared up to there: the next one starts there), and one at
+        the last whole page the dispatch reaches (a chunked prompt's segment:
+        its end; a prompt's last dispatch: where the same conversation comes
+        back to). A boundary gets none where the pool has no slot to give
+        (every one pinned or being written)."""
+        chunk, page = self.cfg.chunk_size, self.page_size
+        snap = {"slot": np.full(n_rows, self.max_slots, np.int32),
+                "at": np.zeros((n_rows, SNAPS_PER_ROW), np.int32),
+                "ids": np.full((n_rows, SNAPS_PER_ROW), self._snapshots, np.int32)}
+        if prior:
+            snap["start"] = np.full(n_rows, -1, np.int32)
+        for r, (who, start, n) in enumerate(rows):
+            slot = self._row_slot(who)
+            written = slot.snaps_written if slot is not None else who
+            boundaries = []
+            if slot is not None:
+                snap["slot"][r] = who
+                if prior and start > slot.shared_tokens:
+                    snap["start"][r] = -2
+                elif prior and slot.start_snap is not None:
+                    snap["start"][r] = slot.start_snap
+                if start < slot.cut_from <= start + n:
+                    boundaries.append(slot.cut_from)
+            last = (start + n) // page * page
+            if last > start and last not in boundaries:
+                boundaries.append(last)
+            for k, boundary in enumerate(boundaries if self._radix is not None else ()):
+                taken = self._radix.snap_alloc()
+                if taken is None:
+                    break
+                snap["at"][r, k], snap["ids"][r, k] = (boundary - start) // chunk, taken
+                written.append((boundary, taken))
+                self._ssm_state_pending["written"] += 1
+        return snap
 
     def _admit(self) -> None:
         free = self._free_slot_indices()
@@ -2170,7 +2424,7 @@ class ContinuousBatchingEngine:
             # already in the pool → the table reuses those pages read-only
             # and only the unmatched suffix prefills
             cache_live = self._radix is not None and not self._radix.empty
-            shared, match_pages, match_node = self._match_radix(tok_ids)
+            shared, match_pages, match_node, match_snap, paged = self._match_radix(tok_ids)
             # speculation headroom: a verify block writes KV for up to
             # spec_k+1 positions past the accepted length before acceptance
             # is known — those writes need real pages behind them
@@ -2188,7 +2442,7 @@ class ContinuousBatchingEngine:
                 # reclaim LRU unpinned cached prefixes; the match may have
                 # walked nodes the eviction just freed, so rematch after
                 if self._radix.evict(need_total - self.allocator.free_pages):
-                    shared, match_pages, match_node = self._match_radix(tok_ids)
+                    shared, match_pages, match_node, match_snap, paged = self._match_radix(tok_ids)
                     need_total = pages_needed(shared)
             if need_total > self.allocator.free_pages:
                 # skip-ahead: a too-large request must not idle free slots
@@ -2239,6 +2493,12 @@ class ContinuousBatchingEngine:
             slot.inflight_steps = 0
             slot.shared_tokens = shared
             slot.prefix_node = match_node
+            slot.start_snap, slot.cut_from, slot.snaps_written = match_snap, 0, []
+            if paged > shared:  # pages matched past the state the cache kept: computed again
+                slot.cut_from = paged
+                self._ssm_state_pending["cut_back_tokens"] += paged - shared
+            if match_snap is not None:
+                self._radix.snap_pin(match_snap)
             slot.prompt_ids = list(tok_ids) if self._radix is not None else None
             slot.donated = []
             slot.submit_t = req.submit_t
@@ -2370,7 +2630,7 @@ class ContinuousBatchingEngine:
                     self._prefill_scatter,
                     self.params, ids, positions, lens, self._rng, temps, scat,
                     self.pool.k, self.pool.v, top_ks,
-                    slots=[i for i, _r, _t in chunk],
+                    slots=[i for i, _r, _t in chunk], rows=[(i, 0, len(t)) for i, _r, t in chunk],
                 )
         self._note_prefill_picks(picks, [(i, 0, len(t)) for i, _r, t in chunk])
         self.prefill_tokens_total += sum(len(t) for _i, _r, t in chunk)
@@ -2414,6 +2674,7 @@ class ContinuousBatchingEngine:
                     self.params, ids, positions, lens, self._rng, temps, scat,
                     self.pool.k, self.pool.v, prior_tables, n_prior, top_ks,
                     do_sample=True, slots=[i for i, _r, _t, _sh in chunk],
+                    rows=[(i, sh, len(t) - sh) for i, _r, t, sh in chunk],
                 )
         self._note_prefill_picks(picks, [(i, sh, len(t) - sh) for i, _r, t, sh in chunk])
         self.prefill_tokens_total += sum(len(t) - s for _i, _r, t, s in chunk)
@@ -2424,7 +2685,7 @@ class ContinuousBatchingEngine:
         for slot_idx, _req, tok_ids, shared in chunk:
             self._radix_insert(slot_idx, tok_ids, shared)
 
-    def _prefill_call(self, fn, *args, slots: Sequence[int] = (), **static):
+    def _prefill_call(self, fn, *args, slots: Sequence[int] = (), rows=(), **static):
         """One prefill dispatch → (its five results, its picks or None, its
         rows' convolution state or None). A routed family's program also
         takes the pairs counted on the device since the last tick and returns
@@ -2432,20 +2693,34 @@ class ContinuousBatchingEngine:
         asked for them. A family with convolution state hands the page tails
         in (donated) and takes them back, with each row's state after its
         tokens — which stays on the device until ``merge_admitted`` puts it
-        in the row's slot. The sampled first tokens (small, donated to
-        nothing) carry its completion stamp, booked on the ``prefill`` span
-        of each request in ``slots``."""
+        in the row's slot. A family with MAMBA layers hands in the snapshot
+        pool and the slots' state (both donated) with what each of ``rows``
+        (slot, first position, tokens) starts from and which boundaries it
+        snapshots (``_snapshot_rows``), and takes both back: the program puts
+        a row's state in its slot itself. The sampled first tokens (small,
+        donated to nothing) carry its completion stamp, booked on the
+        ``prefill`` span of each request in ``slots``."""
         picks = conv_rows = None
         with dispatching("prefill", self.tick_step,
                          [(self.slots[i].trace_id, "prefill") for i in slots]) as stamp:
             if self.routed:
                 static["moe_acc"] = self._take_moe_acc()
-            if self.conv_state:
+            if self.slot_state:
                 static["tail"] = self.pool.tail
+            if self.ssm_state:
+                static["conv"] = self.pool.conv
+                static["snap"] = self._snapshot_rows(args[1].shape[0], rows, fn is self._prior_prefill_scatter)
             out = list(fn(*args, **static))
-            if self.conv_state:
+            if self.slot_state:
                 self.pool.tail = out.pop()
                 conv_rows = out.pop()
+            if self.ssm_state:
+                self.pool.conv, conv_rows = conv_rows, None
+                for who, _start, _n in rows:  # its start is read: the snapshot may go
+                    slot = self._row_slot(who)
+                    if slot is not None and slot.start_snap is not None:
+                        self._radix.snap_pin(slot.start_snap, -1)
+                        slot.start_snap = None
             if self.routed:
                 moe = out.pop()
                 self._moe_acc = moe.pop("counts")
@@ -2485,6 +2760,11 @@ class ContinuousBatchingEngine:
                     "tail" if start == self.slots[slot_idx].shared_tokens else "carried"
                 self._conv_state_pending[kind] += 1
                 self._conv_state_pending["pages"] += n // self.page_size
+        if self.ssm_state:
+            for slot_idx, start, _n in rows:
+                kind = "zero" if not start else \
+                    "snapshot" if start == self.slots[slot_idx].shared_tokens else "carried"
+                self._ssm_state_pending[kind] += 1
         if picks is None:
             return
         for r, (slot_idx, start, n) in enumerate(rows):
@@ -2534,7 +2814,7 @@ class ContinuousBatchingEngine:
                         self._prior_prefill_scatter,
                         self.params, ids, positions, lens, self._rng, temps,
                         scat, self.pool.k, self.pool.v, prior_table,
-                        n_prior, top_ks, do_sample=is_last, slots=[i],
+                        n_prior, top_ks, do_sample=is_last, slots=[i], rows=[(i, prior, len(seg))],
                     )
             self._note_prefill_picks(picks, [(i, prior, len(seg))])
             self.prefill_tokens_total += len(seg)
@@ -2707,6 +2987,8 @@ class ContinuousBatchingEngine:
                 moe_acc = {"moe_acc": self._take_moe_acc()} if self.routed else {}
                 if self.conv_state:
                     moe_acc.update(conv=self.pool.conv, tail=self.pool.tail)
+                elif self.ssm_state:  # decode writes no snapshot: the pool stays away
+                    moe_acc.update(conv=self.pool.conv)
                 (packed, lp_state, tok_out, lens_out, halted_out,
                  lp_sum_out, lp_min_out, lp_cnt_out,
                  self.pool.k, self.pool.v, self._rng, *picks) = self._step_n(
@@ -2726,8 +3008,10 @@ class ContinuousBatchingEngine:
                     lp_cnt_in,
                     steps=steps, **moe_acc,
                 )
-                if self.conv_state:
-                    *picks, self.pool.conv, self.pool.tail = picks
+                if self.slot_state:
+                    *picks, self.pool.conv, tail = picks
+                    if tail is not None:
+                        self.pool.tail = tail
                 self.total_sub_steps += steps
                 spec = False
                 kv_pages = self._kv_pages(budgets, int(steps))
@@ -2859,6 +3143,12 @@ class ContinuousBatchingEngine:
             self.conv_state_total[kind] += n
             self.last_tick_conv_state[kind] += n
             self._conv_state_pending[kind] = 0
+        if self.ssm_state and self._radix is not None:  # the slots the radix cache took from their boundaries
+            self._ssm_state_pending["evicted"] = self._radix.take_snapshots_evicted()
+        for kind, n in self._ssm_state_pending.items():
+            self.ssm_state_total[kind] += n
+            self.last_tick_ssm_state[kind] += n
+            self._ssm_state_pending[kind] = 0
 
     def _kv_pages(self, budgets, steps: int) -> dict:
         """K/V page blocks of the ``steps`` sub-steps being dispatched, by
@@ -2951,6 +3241,11 @@ class ContinuousBatchingEngine:
             self.allocator.free(slot.pages)
         if self._radix is not None:
             self._radix.unlock(slot.prefix_node)
+            if slot.start_snap is not None:  # retired before its prefill read it
+                self._radix.snap_pin(slot.start_snap, -1)
+            for _boundary, snap in slot.snaps_written:  # never told of: cancelled mid-prefill
+                self._radix.snap_free(snap)
+        slot.start_snap, slot.cut_from, slot.snaps_written = None, 0, []
         slot.prefix_node = None
         slot.prompt_ids = None
         slot.donated = []
@@ -3005,6 +3300,14 @@ class ContinuousBatchingEngine:
             # of ``pool_hbm_bytes``, the state per slot and per page
             out["conv_state_bytes"] = self.pool.conv_state_bytes
             out.update({f"conv_state_{kind}": n for kind, n in self.conv_state_total.items()})
+        if self.ssm_state:
+            # of ``pool_hbm_bytes``: the slots' state and the snapshot pool
+            # together, the pool alone, its slots and those handed out
+            out["ssm_state_bytes"] = self.pool.conv_state_bytes
+            out["ssm_snapshot_bytes"] = self.pool.snapshot_bytes
+            out["ssm_snapshots"] = self._snapshots
+            out["ssm_snapshots_held"] = self._radix.snapshots_held if self._radix is not None else 0
+            out.update({f"ssm_state_{kind}": n for kind, n in self.ssm_state_total.items()})
         if self.latent:
             # what ONE token leaves in the pool a layer (bf16)
             out["pool_token_layer_bytes"] = self.cfg.latent_dim * np.dtype(self.pool.k.dtype).itemsize

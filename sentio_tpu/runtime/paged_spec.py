@@ -61,16 +61,17 @@ from sentio_tpu.analysis.audit.registry import jit_family
 def refuse_recurrent_state(cfg) -> None:
     """Speculation is refused, with its reason, for a family that carries
     RECURRENT state beside the pages (``models/lfm2_moe.py``'s convolution
-    layers): a verify block advances that state by k + 1 tokens, a rejected
+    layers, ``models/nemotron_h.py``'s Mamba layers): a verify block advances that state by k + 1 tokens, a rejected
     draft token would have to roll it back, and nothing here keeps the state
     to roll back to (K and V need no such thing: a rejected position is
     simply overwritten)."""
-    from sentio_tpu.runtime.paged import has_conv_state
+    from sentio_tpu.runtime.paged import has_conv_state, has_ssm_state
 
-    if has_conv_state(cfg):
+    if has_conv_state(cfg) or has_ssm_state(cfg):
+        what = "convolution" if has_conv_state(cfg) else "Mamba"
         raise ValueError(
             f"paged speculation does not serve a family with recurrent state ({type(cfg).__name__}): a "
-            "rejected draft token would have to roll the convolution state back, and the tick keeps "
+            f"rejected draft token would have to roll the {what} state back, and the tick keeps "
             "no state to roll back to")
 
 

@@ -38,6 +38,23 @@ delivered suffix; the insert afterwards caches prompt+delivered, so a
 SECOND resume of the same stream (a flapping replica) is a full-prefix
 hit. Eviction, pinning, and page accounting are oblivious to the origin
 of the tokens — the conservation invariants hold unchanged.
+
+**Snapshots** (a family whose layers carry a STATE beside the pages,
+``models/nemotron_h.py``): K and V of a cached page serve any prompt that
+shares the page, but a state layer needs every token before the point a
+prompt starts from — so a prefix can only be served up to a page boundary at
+which the STATE was kept too. A state is far larger than the page it ends
+(fifty times, at that model's widths), so pages do not own one by right: the
+engine holds a bounded pool of ``snapshots`` slots on the device and this tree
+says which page boundary owns which slot (``RadixNode.snaps``).
+:meth:`match_state` cuts a match back to the deepest matched boundary that has
+a snapshot; the engine recomputes the rest and attaches snapshots where its
+prefill passed (:meth:`snap_alloc`, :meth:`snap_attach`). A snapshot leaves on
+its own LRU without its pages (the next match is cut back further, and writes
+it again), and with its page when the page is evicted. A slot that is about
+to START from a snapshot pins it until its prefill is dispatched: dispatches
+run in order on the device, so a later overwrite of the slot cannot pass the
+read.
 """
 
 from __future__ import annotations
@@ -54,7 +71,7 @@ class RadixNode:
     backed by ``pages`` (one id per page_size tokens)."""
 
     __slots__ = ("tokens", "pages", "children", "parent", "refcount",
-                 "last_used")
+                 "last_used", "snaps")
 
     def __init__(self, tokens: list[int], pages: list[int],
                  parent: Optional["RadixNode"]) -> None:
@@ -64,6 +81,9 @@ class RadixNode:
         self.parent = parent
         self.refcount = 0
         self.last_used = 0
+        # page boundary inside this edge (1 = after its first page) → the
+        # snapshot slot that holds the state AT that boundary
+        self.snaps: dict[int, int] = {}
 
     def __repr__(self) -> str:  # debugging aid only
         return (f"RadixNode(tokens={len(self.tokens)}, pages={self.pages}, "
@@ -79,7 +99,7 @@ class RadixPrefixCache:
     ids to the allocator.
     """
 
-    def __init__(self, page_size: int, allocator) -> None:
+    def __init__(self, page_size: int, allocator, snapshots: int = 0) -> None:
         self.page_size = page_size
         self.allocator = allocator
         self.root = RadixNode([], [], None)  # guarded-by: engine-thread
@@ -87,6 +107,14 @@ class RadixPrefixCache:
         self.node_count = 0  # guarded-by: engine-thread
         self.evicted_pages = 0  # guarded-by: engine-thread
         self._clock = itertools.count(1)
+        # the snapshot pool's slots (module docstring): free ones, and of each
+        # one handed out [node or None while its state is being written,
+        # boundary in the node's edge, last use, pins]
+        self.snapshots = snapshots
+        self._snap_free = list(range(snapshots - 1, -1, -1))  # guarded-by: engine-thread
+        self._snap_meta: dict[int, list] = {}  # guarded-by: engine-thread
+        self.snapshots_evicted = 0  # guarded-by: engine-thread
+        self._snap_evicted_taken = 0  # guarded-by: engine-thread
 
     # ------------------------------------------------------------------ reads
 
@@ -165,6 +193,109 @@ class RadixPrefixCache:
             node = child
         return pos
 
+    # -------------------------------------------------------------- snapshots
+
+    def _chain(self, node: Optional[RadixNode]) -> list[tuple[RadixNode, int]]:
+        """``node`` and its ancestors, root side first, each with the token
+        offset its edge starts at."""
+        chain = []
+        while node is not None and node is not self.root:
+            chain.append(node)
+            node = node.parent
+        out, start = [], 0
+        for link in reversed(chain):
+            out.append((link, start))
+            start += len(link.tokens)
+        return out
+
+    def match_state(self, tokens: Sequence[int], limit: int):
+        """:meth:`match`, CUT BACK to the deepest matched page boundary, no
+        deeper than ``limit`` tokens, that owns a snapshot → ``(n_matched,
+        pages, node, snapshot, n_pages_matched)``: the first three as
+        :meth:`match` gives them but for the cut prefix (``node`` the one whose
+        edge holds the boundary; ``0, [], None`` where no boundary has a
+        snapshot), the snapshot's slot (None), and the tokens the pages alone
+        would have served. The snapshot's LRU clock is touched."""
+        matched, pages, node = self.match(tokens)
+        page = self.page_size
+        matched = min(matched, limit)
+        for link, start in reversed(self._chain(node)):
+            for j in range(min(len(link.pages), (matched - start) // page), 0, -1):
+                snap = link.snaps.get(j)
+                if snap is not None:
+                    self._snap_meta[snap][2] = next(self._clock)
+                    cut = start + j * page
+                    return cut, pages[: cut // page], link, snap, matched
+        return 0, [], None, None, matched
+
+    def snap_alloc(self) -> Optional[int]:
+        """A snapshot slot to write: a free one, else the least recently used
+        that is attached and not pinned (it leaves its boundary; its pages
+        stay). None where every slot is pinned or still being written."""
+        if self._snap_free:
+            snap = self._snap_free.pop()
+        else:
+            idle = [(meta[2], snap) for snap, meta in self._snap_meta.items()
+                    if meta[0] is not None and not meta[3]]
+            if not idle:
+                return None
+            snap = min(idle)[1]
+            node, at = self._snap_meta[snap][:2]
+            del node.snaps[at]
+            self.snapshots_evicted += 1
+        self._snap_meta[snap] = [None, 0, next(self._clock), 0]
+        return snap
+
+    def snap_attach(self, tokens: Sequence[int], boundary: int, snap: int) -> bool:
+        """Slot ``snap`` holds the state after ``tokens[:boundary]`` (whole
+        pages, in the tree): the boundary owns it from now. False — and the
+        slot is free again — where the boundary already owns one or the
+        tokens are not in the tree."""
+        page = self.page_size
+        node, pos = self.root, 0
+        while pos < boundary:
+            child = node.children.get(tuple(tokens[pos: pos + page]))
+            if child is None:
+                break
+            span = min(len(child.tokens), boundary - pos)
+            if child.tokens[:span] != list(tokens[pos: pos + span]):
+                break
+            if span == boundary - pos:  # the boundary lies in (or ends) this edge
+                if span // page in child.snaps:
+                    break
+                child.snaps[span // page] = snap
+                self._snap_meta[snap][:2] = [child, span // page]
+                return True
+            node, pos = child, pos + span
+        self.snap_free(snap)
+        return False
+
+    def snap_free(self, snap: int) -> None:
+        """Give back a slot that is attached nowhere (``snap_alloc``'s, whose
+        write never came to a boundary)."""
+        del self._snap_meta[snap]
+        self._snap_free.append(snap)
+
+    def snap_pin(self, snap: int, by: int = 1) -> None:
+        """A slot about to start from ``snap`` holds it (``by`` -1: lets go)."""
+        self._snap_meta[snap][3] += by
+
+    def _drop_snaps(self, node: RadixNode) -> None:
+        """``node`` leaves the tree: its snapshots' slots are free again."""
+        for snap in node.snaps.values():
+            self.snap_free(snap)
+        node.snaps = {}
+
+    @property
+    def snapshots_held(self) -> int:
+        return len(self._snap_meta)
+
+    def take_snapshots_evicted(self) -> int:
+        """Snapshots :meth:`snap_alloc` took from their boundaries since the
+        last call (the engine's counter books them a tick at a time)."""
+        taken, self._snap_evicted_taken = self.snapshots_evicted - self._snap_evicted_taken, self.snapshots_evicted
+        return taken
+
     # ----------------------------------------------------------------- writes
 
     def insert(self, tokens: Sequence[int], start: int, pages: Sequence[int],
@@ -233,6 +364,12 @@ class RadixPrefixCache:
         # a pin on the lower node pins its whole chain; the upper node
         # inherits the count so chain pins stay consistent after the split
         upper.refcount = node.refcount
+        # a boundary's snapshot goes with the half its page lies in
+        kept, node.snaps = node.snaps, {}
+        for at, snap in kept.items():
+            owner, where = (upper, at) if at <= j else (node, at - j)
+            self._snap_meta[snap][:2] = [owner, where]
+            owner.snaps[where] = snap
         key = tuple(node.tokens[:page])
         node.parent.children[key] = upper
         node.tokens = node.tokens[j * page :]
@@ -280,6 +417,7 @@ class RadixPrefixCache:
         while freed < n_pages and heap:
             _, _, victim = heapq.heappop(heap)
             parent = victim.parent
+            self._drop_snaps(victim)
             self.allocator.free(victim.pages)
             freed += len(victim.pages)
             self.pages_held -= len(victim.pages)
@@ -298,6 +436,7 @@ class RadixPrefixCache:
         while stack:
             node = stack.pop()
             stack.extend(node.children.values())
+            self._drop_snaps(node)
             self.allocator.free(node.pages)
         self.root = RadixNode([], [], None)
         self.pages_held = 0
@@ -310,4 +449,6 @@ class RadixPrefixCache:
             "pages": self.pages_held,
             "nodes": self.node_count,
             "evicted_pages": self.evicted_pages,
+            **({"snapshots": self.snapshots, "snapshots_held": self.snapshots_held,
+                "snapshots_evicted": self.snapshots_evicted} if self.snapshots else {}),
         }

@@ -2504,7 +2504,8 @@ class ReplicaSet:
 
     _SUM_KEYS = (
         "active_slots", "max_slots", "queued", "free_pages", "total_pages",
-        "pool_hbm_bytes", "conv_state_bytes", "head_skips", "ttft_count", "prefill_tokens",
+        "pool_hbm_bytes", "conv_state_bytes", "ssm_state_bytes", "ssm_snapshot_bytes", "ssm_snapshots",
+        "ssm_snapshots_held", "head_skips", "ttft_count", "prefill_tokens",
         "decode_tokens", "prefix_hits", "prefix_misses", "prefix_hit_tokens",
         "prefix_miss_tokens", "prefix_cache_pages", "prefix_cache_nodes",
         "queued_inbox", "ticks", "completed", "max_queue", "shed", "expired",

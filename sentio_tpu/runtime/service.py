@@ -1222,7 +1222,8 @@ class PagedGenerationService:
                         metrics.record_tick_phases(phase_s)
                         metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
                                                  row_steps["moe_pairs"], row_steps["prefill_latent"],
-                                                 row_steps["prefill_turns"], row_steps["conv_state"])
+                                                 row_steps["prefill_turns"], row_steps["conv_state"],
+                                                 row_steps["ssm_state"])
                         for key, val in phase_s.items():
                             self._phase_totals[key] = (
                                 self._phase_totals.get(key, 0.0) + val
@@ -1394,7 +1395,8 @@ class PagedGenerationService:
                     metrics.record_tick(tick_dur_s, int(active), queued + inbox)
                     metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
                                              row_steps["moe_pairs"], row_steps["prefill_latent"],
-                                             row_steps["prefill_turns"], row_steps["conv_state"])
+                                             row_steps["prefill_turns"], row_steps["conv_state"],
+                                                 row_steps["ssm_state"])
                 except Exception:  # noqa: BLE001
                     logger.debug("tick telemetry failed", exc_info=True)
                 t_deliver_start = time.perf_counter()
@@ -1521,7 +1523,11 @@ class PagedGenerationService:
                 "prefill_turns": dict(getattr(self.engine, "last_tick_prefill_turns", None) or {}),
                 # a family with convolution state: what its prefill rows started
                 # from, and the page tails written (zeros for any other)
-                "conv_state": dict(getattr(self.engine, "last_tick_conv_state", None) or {})}
+                "conv_state": dict(getattr(self.engine, "last_tick_conv_state", None) or {}),
+                # a family with Mamba layers: what its prefill rows started from,
+                # snapshots written and evicted, tokens computed again for want
+                # of a snapshot (zeros for any other)
+                "ssm_state": dict(getattr(self.engine, "last_tick_ssm_state", None) or {})}
 
     def _note_ttft_locked(self, ttft_s: float) -> None:  # lock-held: _mutex
         """Fold one observed TTFT into the EMA admission control projects

@@ -35,6 +35,7 @@ _FAMILY_CONFIGS = {
     "cohere2_moe": ("sentio_tpu.models.cohere2_moe", "Cohere2MoeConfig"),
     "deepseek_v2": ("sentio_tpu.models.deepseek_v2", "DeepseekV2Config"),
     "lfm2_moe": ("sentio_tpu.models.lfm2_moe", "Lfm2MoeConfig"),
+    "nemotron_h": ("sentio_tpu.models.nemotron_h", "NemotronHConfig"),
     "encoder": ("sentio_tpu.models.transformer", "EncoderConfig"),
     "cross-encoder": ("sentio_tpu.models.transformer", "EncoderConfig"),
 }
@@ -124,7 +125,7 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
     """Checkpoint or seeded init → which family → placed on the device.
 
     With ``cfg.checkpoint_path`` the weights, their configuration (llama,
-    moe, cohere2_moe, deepseek_v2 or lfm2_moe, from the checkpoint's meta) and, with ``cfg.tokenizer_path``, the
+    moe, cohere2_moe, deepseek_v2, lfm2_moe or nemotron_h, from the checkpoint's meta) and, with ``cfg.tokenizer_path``, the
     tokenizer come from the checkpoint. Without one the weights are the
     seeded random init of ``model_config``'s family (the deterministic
     fake-model mode of tests and offline development; ``cfg.model_preset``
@@ -144,6 +145,7 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
     from sentio_tpu.models.lfm2_moe import Lfm2MoeConfig, init_lfm2_moe
     from sentio_tpu.models.llama import LlamaConfig, init_llama, serving_layout
     from sentio_tpu.models.moe import MoeConfig, init_moe
+    from sentio_tpu.models.nemotron_h import NemotronHConfig, init_nemotron_h
     from sentio_tpu.models.tokenizer import ByteTokenizer
     from sentio_tpu.parallel.sharding import (
         LLAMA_TP_RULES,
@@ -160,7 +162,7 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
             raise WeightsError(
                 f"checkpoint {cfg.checkpoint_path!r} holds a "
                 f"{type(model_config).__name__} model — the generator "
-                "serves decoder families (llama, moe, cohere2_moe, deepseek_v2, lfm2_moe)"
+                "serves decoder families (llama, moe, cohere2_moe, deepseek_v2, lfm2_moe, nemotron_h)"
             )
     if model_config is None:
         preset = cfg.model_preset if cfg is not None else "tiny"
@@ -169,12 +171,14 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
     is_moe = isinstance(model_config, MoeConfig)
     share_init = {Cohere2MoeConfig: init_cohere2_moe,
                   DeepseekV2Config: init_deepseek_v2,
-                  Lfm2MoeConfig: init_lfm2_moe}.get(type(model_config))
+                  Lfm2MoeConfig: init_lfm2_moe,
+                  NemotronHConfig: init_nemotron_h}.get(type(model_config))
     if share_init is not None and mesh is not None:
         # this process IS one chip's share of a layer (``experts_held``; of
         # ``lfm2_moe`` whole layers, with convolution state per slot and per
-        # page that no mesh has a rule for); a mesh that splits it again has
-        # no rules yet
+        # page that no mesh has a rule for, as ``nemotron_h``'s Mamba state
+        # per slot and per snapshot has none); a mesh that splits it again
+        # has no rules yet
         family = next(k for k, (_m, cls) in _FAMILY_CONFIGS.items()
                       if cls == type(model_config).__name__)
         raise WeightsError(f"a {family} model is served on one device a process")

@@ -425,6 +425,7 @@ class DependencyContainer:
                     prefill_chunk=cfg.prefill_chunk or None,
                     spec_k=cfg.speculative_k,
                     prefix_cache=cfg.prefix_cache,
+                    ssm_snapshots=cfg.ssm_snapshots,
                 )
                 service_kwargs = dict(
                     max_queue=serve.admission_max_queue or None,
@@ -648,6 +649,7 @@ class DependencyContainer:
                     draft_config=draft_cfg,
                     spec_k=cfg.speculative_k,
                     prefix_cache=cfg.prefix_cache,
+                    ssm_snapshots=cfg.ssm_snapshots,
                     mesh=meshes[i],  # pool kv-heads shard over tp with the weights
                 )
                 if warm_head:

@@ -899,6 +899,126 @@ def test_lfm2_prefill_writes_pool_and_tails_where_they_lie(lfm2_programs):
 
 
 
+# ----------------------------- a family with a matrix state beside the pages
+#
+# ``nemotron_h`` (models/nemotron_h.py) at the widths of the benchmark's
+# ``nemotron-3-nano-30b-a3b-ep2-l14``, its first seven blocks (the check's
+# depth, ``MEMEM*E``: every kind): the decode walk at TWO kv heads of 128, the
+# Mamba state per slot ``[3, 16, 64, 64, 128]`` float32 carried through the
+# scan, 64 held experts of 2688 x 1856 — served padded to 1,920, whole tiles
+# of lanes — behind two grouped matmuls a block, and a prefill segment that
+# starts from a snapshot and leaves two.
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(v5e):
+    from sentio_tpu.models.nemotron_h import NemotronHConfig, init_nemotron_cache, init_nemotron_h, nemotron_h_forward
+
+    cfg = NemotronHConfig(n_layers=7, pattern="MEMEM*E")
+    place = _on_one_chip(v5e)
+    params = jax.eval_shape(lambda: serving_layout(init_nemotron_h(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree_util.tree_map(
+        lambda a: place(a.shape, jnp.bfloat16 if a.ndim >= 2 and a.shape[-1] > 4 else a.dtype), params)
+    slots, nb, page, segment, snapshots = 16, 10, 128, 256, 64
+    pages = 1 + slots * nb
+    # (two pool layers, as the cell's fourteen blocks have; these seven use the first)
+    pool = place((2, pages, page, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    state = {name: place(shape, dtype) for name, (shape, dtype) in cfg.state_shapes(slots).items()}
+    snaps = {name: place(shape, dtype) for name, (shape, dtype) in cfg.state_shapes(snapshots).items()}
+    impl = make_paged_attn_impl(interpret=False)
+
+    def step(params, tok, lens, table, k_pages, v_pages, state):
+        def body(carry, _):
+            tok, lens, k_pages, v_pages, state = carry
+            logits, k_pages, v_pages, routed, state, _ = paged_decode_forward(
+                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl,
+                write_mask=lens < nb * page - 1, return_routed=True, conv=state)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1, k_pages, v_pages, state), routed["experts"]
+
+        return jax.lax.scan(body, (tok, lens, k_pages, v_pages, state), None, length=2)
+
+    def prefill(params, ids, positions, lens, k_pages, v_pages, state, snaps, prior_table, n_prior, scat, snap):
+        # a segment as ``paged.prior_prefill_scatter`` runs it: K and V primed from two prior
+        # pages, the state from a snapshot, the row's end state into its slot, two boundaries kept
+        cache = init_nemotron_cache(cfg, 1, 2 * page + segment, 2)
+        for name, pool_ in (("k", k_pages), ("v", v_pages)):
+            cache[name] = cache[name].at[:, :, : 2 * page].set(
+                pool_[:1, prior_table].reshape(1, 1, 2 * page, cfg.n_kv_heads, cfg.head_dim))
+        cache["state"] = {name: snaps[name][:, snap["start"]] for name in snaps}
+        cache["snap_at"] = snap["at"]
+        logits, cache, routed = nemotron_h_forward(
+            params, cfg, ids, positions=positions, cache=cache, cache_index=n_prior,
+            pad_mask=jnp.arange(segment)[None, :] < lens[:, None], logits_at=lens - 1)
+        new = [jax.lax.dynamic_slice_in_dim(cache[name], n_prior[0], segment, axis=2) for name in ("k", "v")]
+        k_pages, v_pages = scatter_prefill(k_pages, v_pages, *(jnp.concatenate([a, a]) for a in new), scat)
+        state = {name: state[name].at[:, snap["slot"]].set(cache["state"][name], mode="drop") for name in state}
+        snaps = {name: snaps[name].at[:, snap["ids"]].set(cache["snaps"][name], mode="drop") for name in snaps}
+        return logits[:, 0], k_pages, v_pages, state, snaps, routed["counts"]
+
+    snap = {"slot": place((1,), jnp.int32), "start": place((1,), jnp.int32),
+            "at": place((1, 2), jnp.int32), "ids": place((1, 2), jnp.int32)}
+    with _the_chips_grouped_matmul():
+        compiled = {
+            "step": jax.jit(step, donate_argnums=(4, 5, 6)).lower(
+                params, place((slots,), jnp.int32), place((slots,), jnp.int32),
+                place((slots, nb), jnp.int32), pool, pool, state).compile(),
+            "prefill": jax.jit(prefill, donate_argnums=(4, 5, 6, 7)).lower(
+                params, place((1, segment), jnp.int32), place((1, segment), jnp.int32), place((1,), jnp.int32),
+                pool, pool, state, snaps, place((1, 2), jnp.int32), place((1,), jnp.int32),
+                place((1, segment // page), jnp.int32), snap).compile(),
+        }
+    return cfg, params, {k: c.as_text() for k, c in compiled.items()}, \
+        {k: c.memory_analysis() for k, c in compiled.items()}, (pool, state["ssm"], snaps["ssm"])
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_nemotron_programs_read_their_weights_where_they_lie(nemotron_programs, program):
+    """The v5e compiler takes both programs (Mosaic: the decode walk at two kv
+    heads; the grouped matmul over 64 experts of 2688 x 1920), and nothing in
+    them makes an array with the shape of a projection, of the head, or of a
+    stack of experts: the serving tree holds ``w_in`` [out, in] and the
+    experts' width as whole tiles of lanes (at 1,856 the compiler copies every
+    stack at the head of every program: 0.64 GB a block)."""
+    cfg, params, texts, _, _ = nemotron_programs
+    assert params["layers_0"]["mamba"]["w_in_t"]["kernel"].shape == (10304, 2688)
+    assert params["layers_5"]["attn"]["wq_t"]["kernel"].shape == (4096, 2688)
+    assert params["layers_1"]["moe"]["w_up"].shape == (64, 2688, 1920)
+    assert _weight_copies(texts[program], params) == []
+    stacks = ("bf16[64,2688,1920]", "bf16[64,1920,2688]")
+    assert [m for m in _pool_shaped(texts[program], stacks)
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast")] == []
+
+
+def test_nemotron_decode_step_holds_its_kernels_and_its_state(nemotron_programs):
+    """The decode step: ONE walk of the pages (the one attention block) and
+    two grouped expert matmuls in each of the three routed blocks, each a
+    Pallas call; the pool and the slots' state updated in place, never
+    copied."""
+    cfg, _, texts, memory, (pool, state, _) = nemotron_programs
+    assert texts["step"].count('custom_call_target="tpu_custom_call"') == 1 + 3 * 2
+    assert len(re.findall(r"%paged_attention[.\d]* = ", texts["step"])) == 1
+    assert len(re.findall(r"%gmm[.\d]* = ", texts["step"])) == 6
+    shapes = ("bf16[2,161,128,2,128]", "f32[3,16,64,64,128]")
+    made = [m for m in _pool_shaped(texts["step"], shapes)
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "while", "tuple", "custom-call",
+                            "copy-start", "copy-done")]
+    assert all(what in ("fusion:scatter", "fusion:dynamic-update-slice", "scatter", "dynamic-update-slice")
+               for _n, _s, what in made), made
+    # beside arguments it donates, the step needs little: no temporary the size of the slots' state
+    assert memory["step"].temp_size_in_bytes < int(np.prod(state.shape)) * 4
+
+
+def test_nemotron_prefill_writes_pool_state_and_snapshots_where_they_lie(nemotron_programs):
+    """The prefill over a prior: K, V, the row's slot and the two snapshots
+    are updated in place."""
+    _, _, texts, _, _ = nemotron_programs
+    shapes = ("bf16[2,161,128,2,128]", "f32[3,16,64,64,128]", "f32[3,64,64,64,128]")
+    made = [m for m in _pool_shaped(texts["prefill"], shapes)
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "tuple", "custom-call")]
+    assert all(what in ("fusion:scatter", "fusion:dynamic-update-slice", "scatter", "dynamic-update-slice")
+               for _n, _s, what in made), made
+
+
 # ------------------------------------------ the grouped matmul's tiles (PR 43)
 #
 # ``models/moe.py::expert_tile`` sizes the weight tile from the matrix and the
@@ -907,7 +1027,8 @@ def test_lfm2_prefill_writes_pool_and_tails_where_they_lie(lfm2_programs):
 # admission of eight (the widest row tile), compiled for the chip — a tile
 # over VMEM is refused HERE, before any chip time.
 
-ROUTED = {"commanda": ("commanda_programs", 32), "deepseek": ("deepseek_programs", 8), "lfm2": ("lfm2_programs", 16)}
+ROUTED = {"commanda": ("commanda_programs", 32), "deepseek": ("deepseek_programs", 8), "lfm2": ("lfm2_programs", 16),
+          "nemotron": ("nemotron_programs", 16)}
 
 
 @pytest.mark.parametrize("load", ["decode", "segment", "admission"])
@@ -921,11 +1042,13 @@ def test_the_expert_layer_compiles_at_the_tiles_the_rule_picks(request, v5e, nam
     with _the_chips_grouped_matmul() as moe:
         text = jax.jit(lambda mp, x: moe.expert_layer(mp, cfg, x)[0]).lower(mp, x).compile().as_text()
         tiles = moe.expert_tiles(mp, cfg, b * t)
-    # three grouped matmuls, each the Pallas call the benchmark's trace reads by its name
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
-    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 3
+    # a grouped matmul a matrix (three; two for ungated experts), each the Pallas call the
+    # benchmark's trace reads by its name
+    calls = 3 if "w_gate" in mp else 2
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == calls
     rows = {"decode": 32, "segment": 32, "admission": 256}[load]
     assert all(t["tile"][0] == rows and moe.tile_vmem(*t["tile"]) <= moe._GMM_VMEM for t in tiles.values())
     if load != "admission":      # the whole expert in one step, or the contraction whole
         assert [t["steps_per_expert"] for t in tiles.values()] == {
-            "commanda": [8, 8, 8], "deepseek": [3, 3, 2], "lfm2": [1, 1, 1]}[name]
+            "commanda": [8, 8, 8], "deepseek": [3, 3, 2], "lfm2": [1, 1, 1], "nemotron": [3, 3]}[name]
