@@ -209,6 +209,18 @@ def _page_write(pages, layer, page_ids, offsets, val):
     return pages.at[layer, page_ids, offsets].set(val.reshape(val.shape[0], *pages.shape[-2:]))
 
 
+def _write_kv(k_pages, v_pages, layer, page_ids, offsets, k, v, dt, write_impl=None):
+    """This step's k and v [B, 1, Hkv, D], as ``dt``, into both pools →
+    (k_pages, v_pages): through ``write_impl`` where the engine bound one
+    (``kernels/page_write.py``: a pool the compiler would otherwise move for
+    the scatter, written in place by a DMA a row), else :func:`_page_write`'s
+    scatter, a pool at a time."""
+    if write_impl is not None:
+        return write_impl(k_pages, v_pages, layer, page_ids, offsets, k[:, 0].astype(dt), v[:, 0].astype(dt))
+    k_pages = _page_write(k_pages, layer, page_ids, offsets, k[:, 0].astype(dt))
+    return k_pages, _page_write(v_pages, layer, page_ids, offsets, v[:, 0].astype(dt))
+
+
 def _page_dim(pages) -> int:
     return (pages["q"] if isinstance(pages, dict) else pages).shape[-3]
 
@@ -400,7 +412,8 @@ def _paged_attn_xla(q, k_pages, v_pages, layer, page_table, lens, n_rep, window=
 
 
 def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_pages, v_pages,
-                         attn_impl=None, write_mask=None, return_routed=False, conv=None, tail=None):
+                         attn_impl=None, write_mask=None, return_routed=False, conv=None, tail=None,
+                         write_impl=None):
     """One decode step over the paged pool.
 
     tok [B] int32 (last sampled token per slot); lens [B] absolute position
@@ -410,6 +423,8 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
     ``write_mask`` [B] bool (optional) redirects masked rows' k/v writes to
     the scratch page — the multi-step tick uses it to freeze rows that hit
     EOS or their budget mid-scan without corrupting their cache.
+    ``write_impl`` (optional) writes the step's k and v into both pools in
+    the scatter's place (``kernels/page_write.py``; :func:`_write_kv`).
 
     A family whose block is PARALLEL (``models/cohere2_moe.py``: one norm
     feeding attention and routed experts side by side, a kind per layer)
@@ -432,10 +447,11 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
 
     if conv is not None:
         decode = _paged_decode_ssm if has_ssm_state(cfg) else _paged_decode_conv
-        return decode(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail, attn_impl, write_mask)
+        return decode(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail, attn_impl, write_mask,
+                      write_impl)
     if getattr(cfg, "parallel_block", False):
         out = _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
-                                     attn_impl, write_mask)
+                                     attn_impl, write_mask, write_impl)
         return out if return_routed else out[:3]
     if is_latent(cfg):
         out = _paged_decode_latent(params, cfg, tok, lens, page_table, k_pages, attn_impl, write_mask)
@@ -469,8 +485,7 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
         q = L.apply_rope(q, positions, cos, sin)
         k = L.apply_rope(k, positions, cos, sin)
 
-        k_pages = _page_write(k_pages, i, page_ids, offsets, k[:, 0].astype(dt))
-        v_pages = _page_write(v_pages, i, page_ids, offsets, v[:, 0].astype(dt))
+        k_pages, v_pages = _write_kv(k_pages, v_pages, i, page_ids, offsets, k, v, dt, write_impl)
 
         # the attention takes the pool whole and the layer's index: a
         # pages[i] handed to a kernel is a copy of the layer's every page
@@ -500,7 +515,7 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
 
 
 def _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
-                           attn_impl, write_mask):
+                           attn_impl, write_mask, write_impl=None):
     """:func:`paged_decode_forward` for the parallel block of
     ``models/cohere2_moe.py``: ``h = LN(x); x += Attn_i(h) + Experts(h)``,
     layer ``i`` rotated and windowed or neither by its kind, the head the
@@ -535,8 +550,7 @@ def _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
         h = centred_norm(lp["norm"], x, cfg.norm_eps)
         q, k, v = qkv_proj(lp["attn"], cfg, h)
         q, k = qk_rotated(cfg, i, q, k, positions)
-        k_pages = _page_write(k_pages, i, page_ids, offsets, k[:, 0].astype(dt))
-        v_pages = _page_write(v_pages, i, page_ids, offsets, v[:, 0].astype(dt))
+        k_pages, v_pages = _write_kv(k_pages, v_pages, i, page_ids, offsets, k, v, dt, write_impl)
         with jax.named_scope("attn.window" if cfg.window(i) else "attn.full"):
             attn = impl(q, k_pages, v_pages, i, page_table, attn_lens,
                         cfg.n_heads // cfg.n_kv_heads, window=cfg.window(i))
@@ -621,7 +635,7 @@ def _paged_decode_latent(params, cfg, tok, lens, page_table, pages, attn_impl, w
 
 
 def _paged_decode_conv(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail,
-                       attn_impl, write_mask):
+                       attn_impl, write_mask, write_impl=None):
     """:func:`paged_decode_forward` for the family of ``models/lfm2_moe.py``:
     sequential pre-norm blocks whose mixer is a gated short convolution over
     the slot's carried state or attention over the pages (pool layer
@@ -671,8 +685,7 @@ def _paged_decode_conv(params, cfg, tok, lens, page_table, k_pages, v_pages, con
         else:
             a = cfg.attn_index(i)
             q, k, v = M.qk_normed(lp["attn"], cfg, u, positions)
-            k_pages = _page_write(k_pages, a, page_ids, offsets, k[:, 0].astype(dt))
-            v_pages = _page_write(v_pages, a, page_ids, offsets, v[:, 0].astype(dt))
+            k_pages, v_pages = _write_kv(k_pages, v_pages, a, page_ids, offsets, k, v, dt, write_impl)
             with jax.named_scope("attn.full"):
                 attn = impl(q, k_pages, v_pages, a, page_table, attn_lens, cfg.n_heads // cfg.n_kv_heads)
             out = L.dense(lp["attn"]["wo"], attn.reshape(b, 1, -1), dt)
@@ -689,7 +702,7 @@ def _paged_decode_conv(params, cfg, tok, lens, page_table, k_pages, v_pages, con
 
 
 def _paged_decode_ssm(params, cfg, tok, lens, page_table, k_pages, v_pages, state, snaps,
-                      attn_impl, write_mask):
+                      attn_impl, write_mask, write_impl=None):
     """:func:`paged_decode_forward` for the family of ``models/nemotron_h.py``:
     blocks of ONE operator each — a Mamba-2 update of the slot's carried state
     (``state = {"conv": [Lm, B, 3, C], "ssm": [Lm, B, H, P, N]}``), rotation-free
@@ -733,8 +746,7 @@ def _paged_decode_ssm(params, cfg, tok, lens, page_table, k_pages, v_pages, stat
         elif kind == M.ATTENTION:
             a = cfg.attn_index(i)
             q, k, v = M.plain_qkv(lp["attn"], cfg, u, None)
-            k_pages = _page_write(k_pages, a, page_ids, offsets, k[:, 0].astype(dt))
-            v_pages = _page_write(v_pages, a, page_ids, offsets, v[:, 0].astype(dt))
+            k_pages, v_pages = _write_kv(k_pages, v_pages, a, page_ids, offsets, k, v, dt, write_impl)
             with jax.named_scope("attn.full"):
                 attn = impl(q, k_pages, v_pages, a, page_table, attn_lens, cfg.n_heads // cfg.n_kv_heads)
             out = L.dense(lp["attn"]["wo"], attn.reshape(b, 1, -1), dt)
@@ -1409,6 +1421,21 @@ class ContinuousBatchingEngine:
             from sentio_tpu.kernels.paged_attention import make_paged_attn_impl
 
             self._attn_impl = make_paged_attn_impl(mesh=mesh)
+        # The step's K and V rows are written by a kernel too where the walk
+        # above was chosen and ``page_write_path`` says so of the pool: a
+        # plain bf16 pool on one device, small enough for the compiler to
+        # place in nearer memory — which it then moves there for the XLA
+        # scatter and back for the walk, every sub-step. Every other pool
+        # keeps the scatter, and its program
+        self._write_impl = None
+        if self._attn_impl is not None and not self.latent:
+            from sentio_tpu.kernels.page_write import make_page_write_impl, page_write_path
+
+            if page_write_path(self.pool.k, mesh) == "pallas":
+                self._write_impl = make_page_write_impl()
+        logging.getLogger(__name__).info(
+            "decode writes K and V into the pool by %s",
+            "the page-write kernel, in place in HBM" if self._write_impl is not None else "the XLA scatter")
         # The prefill programs' attention is chosen HERE too, by the same
         # ask: the flash kernel that knows a prior (kernels/
         # prefill_attention.py) on TPU, and where a test asks for it on the
@@ -1449,6 +1476,7 @@ class ContinuousBatchingEngine:
 
         cfg = self.cfg
         attn_impl = self._attn_impl
+        write_impl = self._write_impl
         forward_fn = self.forward_fn
         eos_id = self.tokenizer.eos_id
 
@@ -1517,7 +1545,8 @@ class ContinuousBatchingEngine:
                 state = {name: more[name] for name in ("conv", "tail") if name in more}
                 logits, k_pages, v_pages, *moe = paged_decode_forward(
                     params, cfg, tok, lens, page_table, k_pages, v_pages,
-                    attn_impl=attn_impl, write_mask=active, return_routed=routed, **state,
+                    attn_impl=attn_impl, write_mask=active, return_routed=routed,
+                    write_impl=write_impl, **state,
                 )
                 rng, sub = jax.random.split(rng)
                 # temperature AND top-k sample INSIDE the scan body — the
@@ -3284,6 +3313,9 @@ class ContinuousBatchingEngine:
             # and which attention its prefill programs run: the flash kernel
             # that knows a prior, or the family's XLA form
             "prefill_attention": "pallas" if self._prefill_attn is not None else "xla",
+            # and how a decode step writes its K and V rows into the pool: the
+            # kernel that leaves the pool in HBM, or the XLA scatter
+            "page_write": "pallas" if self._write_impl is not None else "xla",
             # a routed family: the tile ``[rows, tk, tn]`` of each of a layer's
             # three grouped matmuls in the decode program and the grid steps an
             # expert costs (the chip's kernel; ``ragged_dot`` elsewhere takes none)

@@ -2585,6 +2585,7 @@ class ReplicaSet:
         agg["kv_quant"] = first.get("kv_quant")
         agg["paged_attention"] = first.get("paged_attention")
         agg["prefill_attention"] = first.get("prefill_attention")
+        agg["page_write"] = first.get("page_write")
         agg["expert_tiles"] = first.get("expert_tiles")
         agg["n_replicas"] = len(per)
         agg["replicas"] = per

@@ -741,6 +741,9 @@ async def info(request: web.Request) -> web.Response:
                 "kv_quant": serving.get("kv_quant"),
                 "paged_attention": serving.get("paged_attention"),
                 "prefill_attention": serving.get("prefill_attention"),
+                # and whether a decode step writes its K and V rows by the
+                # kernel that leaves the pool in HBM or by the XLA scatter
+                "page_write": serving.get("page_write"),
                 # a routed family only (null otherwise): how the decode
                 # program's three grouped expert matmuls a layer are tiled,
                 # ``[rows, tk, tn]``, and the grid steps an expert costs

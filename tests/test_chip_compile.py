@@ -22,6 +22,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from sentio_tpu.kernels.flash_attention import flash_attention
+from sentio_tpu.kernels.page_write import make_page_write_impl, page_write, page_write_path
 from sentio_tpu.kernels.prefill_attention import make_prefill_attn_fn, prefill_attention
 from sentio_tpu.kernels.paged_attention import (
     make_paged_attn_impl,
@@ -135,6 +136,20 @@ def _prefill_attn_case(rows: int, heads: int, hkv: int, window=None, rope: int =
     return build
 
 
+def _page_write_case(rows: int, slots: int = 16, nb: int = 10, layers: int = LAYERS):
+    """The decode step's page write (kernels/page_write.py) alone: K and V of
+    ``slots`` rows into pools whose positions hold ``rows`` rows of 128 lanes."""
+
+    def build(topo):
+        place = _on_one_chip(topo)
+        pool = place((layers, 1 + slots * nb, 128, rows, D), jnp.bfloat16)
+        val, ids = place((slots, rows, D), jnp.bfloat16), place((slots,), jnp.int32)
+        return (lambda k, v, layer, ids, offsets, a, b: page_write((k, v), layer, ids, offsets, (a, b)),
+                (pool, pool, place((), jnp.int32), ids, ids, val, val))
+
+    return build
+
+
 def _tp4_case(quant: bool, hkv: int = HKV):
     """The decode kernel inside shard_map over a tp=4 mesh of the four
     described chips: pool and query heads sharded the way init_pool and the
@@ -200,6 +215,12 @@ CASES = {
     "prefill-attn-yi-rows4": _prefill_attn_case(4, H, 4),
     "prefill-attn-dsv2-second-term": _prefill_attn_case(1, 128, 128, rope=64),
     "prefill-attn-commanda-window": _prefill_attn_case(1, 128, 8, window=4096),
+    # the page write at the two cells that take it — lfm2's lane-packed rows
+    # (8 kv heads of 64, two to a row) and nemotron's two kv heads — and at 8
+    # kv heads, which the reference check's two-layer dense engines write
+    "page-write-cell-lfm2": _page_write_case(rows=4),
+    "page-write-cell-nemotron": _page_write_case(rows=2),
+    "page-write-8kv": _page_write_case(rows=8, slots=8, nb=4),
 }
 # the geometries whose pages the chip's DMA cannot bring (kernels/
 # paged_attention.py ``untiled``): XLA does not store such a pool in the
@@ -278,6 +299,9 @@ def test_decode_step_reads_the_pool_where_it_lies(decoder_programs):
     w = WIDTHS["mistral"]
     pool_shape = (LAYERS, 1 + w["slots"] * w["nb"], 128, w["n_kv_heads"], D)
     text = decoder_programs("mistral")[1]["step"]
+    # too large for the compiler to place (151 MB here, 1.2 GB at the cell's 16 layers): the
+    # write stays the scatter the parse below finds, and the walk the one Pallas call a layer
+    assert page_write_path(jax.ShapeDtypeStruct(pool_shape, jnp.bfloat16)) == "xla"
 
     assert text.count('custom_call_target="tpu_custom_call"') == LAYERS
 
@@ -564,6 +588,8 @@ def test_commanda_decode_step_holds_its_kernels(commanda_programs):
     starting at its window's first block) and three grouped expert matmuls,
     each a Pallas call; the pool updated in place."""
     cfg, _, texts = commanda_programs
+    # (168 MB a pool at these two layers, 337 at the cell's four: the page write stays the scatter)
+    assert page_write_path(jax.ShapeDtypeStruct((LAYERS, 321, 128, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)) == "xla"
     assert texts["step"].count('custom_call_target="tpu_custom_call"') == LAYERS * 4
     assert len(re.findall(r"%gmm[.\d]* = ", texts["step"])) == LAYERS * 3
 
@@ -807,12 +833,14 @@ def lfm2_programs(v5e):
     conv = place((3, slots, 2, cfg.dim), jnp.bfloat16)
     tail = place((3, pages, 2, cfg.dim), jnp.bfloat16)
     impl = make_paged_attn_impl(interpret=False)
+    assert page_write_path(pool) == "pallas"     # 42 MB: the compiler could place it
+    write = make_page_write_impl(interpret=False)
 
     def step(params, tok, lens, table, k_pages, v_pages, conv, tail):
         def body(carry, _):
             tok, lens, k_pages, v_pages, conv, tail = carry
             logits, k_pages, v_pages, routed, conv, tail = paged_decode_forward(
-                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl,
+                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl, write_impl=write,
                 write_mask=lens < nb * page - 1, return_routed=True, conv=conv, tail=tail)
             return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
                     k_pages, v_pages, conv, tail), routed["experts"]
@@ -865,20 +893,21 @@ def test_lfm2_programs_read_their_weights_where_they_lie(lfm2_programs, program)
 
 def test_lfm2_decode_step_holds_its_kernels_and_its_state(lfm2_programs):
     """The decode step: ONE walk of the pages (the one attention layer, over
-    the lane-packed pool) and three grouped expert matmuls in each of the two
-    routed layers, each a Pallas call; the pool and the page tails updated in
-    place, never copied."""
+    the lane-packed pool), ONE write of the step's K and V rows before it
+    (kernels/page_write.py) and three grouped expert matmuls in each of the two
+    routed layers, each a Pallas call; the page tails updated in place; the
+    pool touched by the two kernels alone — until PR 45 the compiler moved each
+    42 MB pool into nearer memory (``S(1)``) for the XLA scatter and back,
+    every sub-step (``copy-start`` / ``copy-done`` of its shape)."""
     cfg, _, texts, memory, (pool, tail) = lfm2_programs
-    assert texts["step"].count('custom_call_target="tpu_custom_call"') == 1 + 2 * 3
+    assert texts["step"].count('custom_call_target="tpu_custom_call"') == 1 + 1 + 2 * 3
     assert len(re.findall(r"%paged_attention[.\d]* = ", texts["step"])) == 1
+    assert len(re.findall(r"%page_write[.\d]* = ", texts["step"])) == 1
     assert len(re.findall(r"%gmm[.\d]* = ", texts["step"])) == 6
     shapes = tuple(f"bf16[{','.join(str(n) for n in a.shape)}]" for a in (pool, tail))
-    # ``copy-done``: the compiler's own placement of a 42 MB pool in nearer memory for the
-    # scatter and back (the same tiling in another memory space, ``S(1)``): no relayout, and
-    # 42 MB a sub-step beside 7 GB of weights
+    # (a ``copy-done`` of the pool's shape, which this list allowed until PR 45, now fails it)
     made = [m for m in _pool_shaped(texts["step"], shapes)
-            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "while", "tuple", "custom-call",
-                            "copy-start", "copy-done")]
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "while", "tuple", "custom-call")]
     assert all(what in ("fusion:scatter", "fusion:dynamic-update-slice", "scatter", "dynamic-update-slice")
                for _n, _s, what in made), made
     assert not re.search(r"= bf16\[2,161,128,4,128\]\S* (copy|transpose|pad)\(", texts["step"])
@@ -926,12 +955,14 @@ def nemotron_programs(v5e):
     state = {name: place(shape, dtype) for name, (shape, dtype) in cfg.state_shapes(slots).items()}
     snaps = {name: place(shape, dtype) for name, (shape, dtype) in cfg.state_shapes(snapshots).items()}
     impl = make_paged_attn_impl(interpret=False)
+    assert page_write_path(pool) == "pallas"     # 21 MB: the compiler could place it
+    write = make_page_write_impl(interpret=False)
 
     def step(params, tok, lens, table, k_pages, v_pages, state):
         def body(carry, _):
             tok, lens, k_pages, v_pages, state = carry
             logits, k_pages, v_pages, routed, state, _ = paged_decode_forward(
-                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl,
+                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl, write_impl=write,
                 write_mask=lens < nb * page - 1, return_routed=True, conv=state)
             return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1, k_pages, v_pages, state), routed["experts"]
 
@@ -990,18 +1021,20 @@ def test_nemotron_programs_read_their_weights_where_they_lie(nemotron_programs, 
 
 
 def test_nemotron_decode_step_holds_its_kernels_and_its_state(nemotron_programs):
-    """The decode step: ONE walk of the pages (the one attention block) and
-    two grouped expert matmuls in each of the three routed blocks, each a
-    Pallas call; the pool and the slots' state updated in place, never
-    copied."""
+    """The decode step: ONE walk of the pages (the one attention block), ONE
+    write of the step's K and V rows before it and two grouped expert matmuls
+    in each of the three routed blocks, each a Pallas call; the slots' state
+    updated in place; the 21 MB pools touched by the two kernels alone, never
+    moved into nearer memory and back."""
     cfg, _, texts, memory, (pool, state, _) = nemotron_programs
-    assert texts["step"].count('custom_call_target="tpu_custom_call"') == 1 + 3 * 2
+    assert texts["step"].count('custom_call_target="tpu_custom_call"') == 1 + 1 + 3 * 2
     assert len(re.findall(r"%paged_attention[.\d]* = ", texts["step"])) == 1
+    assert len(re.findall(r"%page_write[.\d]* = ", texts["step"])) == 1
     assert len(re.findall(r"%gmm[.\d]* = ", texts["step"])) == 6
     shapes = ("bf16[2,161,128,2,128]", "f32[3,16,64,64,128]")
+    # (a ``copy-done`` of either shape, which this list allowed until PR 45, now fails it)
     made = [m for m in _pool_shaped(texts["step"], shapes)
-            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "while", "tuple", "custom-call",
-                            "copy-start", "copy-done")]
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "while", "tuple", "custom-call")]
     assert all(what in ("fusion:scatter", "fusion:dynamic-update-slice", "scatter", "dynamic-update-slice")
                for _n, _s, what in made), made
     # beside arguments it donates, the step needs little: no temporary the size of the slots' state

@@ -192,6 +192,9 @@ class TestPagedAttentionKernel:
             model_config=cfg, params=oracle.params, tokenizer=oracle.tokenizer,
             max_slots=2, page_size=16, max_pages_per_seq=8, use_pallas=True,
         )
+        # the walk is bound; the page write keeps the scatter (16-wide heads are
+        # no whole rows of lanes: kernels/page_write.py::page_write_path)
+        assert eng.stats()["paged_attention"] == "pallas" and eng.stats()["page_write"] == "xla"
         prompt = "kernel path equivalence"
         ref = oracle.generate([prompt], max_new_tokens=8, temperature=0.0)[0]
         got = eng.run_all([prompt], max_new_tokens=8, temperature=0.0)[0]

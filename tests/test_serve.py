@@ -297,7 +297,8 @@ class TestHealthAndInfo:
                 "request_threads_from", "compile_cache_dir"}
             assert set(leaves(info["generator"])) == {
                 "provider", "preset", "verifier", "kv_quant",
-                "paged_attention", "prefill_attention", "expert_tiles", "pool_hbm_bytes", "speculative",
+                "paged_attention", "prefill_attention", "page_write", "expert_tiles", "pool_hbm_bytes",
+                "speculative",
                 "speculative.draft_configured", "speculative.active",
                 "model", *("model." + f.name for f in
                            dataclasses.fields(LlamaConfig))}
