@@ -363,6 +363,14 @@ class MetricsCollector:
                 "snapshots of Mamba state written by prefill, and evicted from their page boundary",
                 ["event"], registry=r,
             ),
+            # ... the one-token updates of a slot's state the decode ticks did
+            # and skipped (a row that does not advance: the update kernel
+            # moves no byte of it) ...
+            "ssm_row_updates": Counter(
+                "sentio_tpu_ssm_state_rows_total",
+                "decode row-steps x Mamba blocks whose state update the device did, and skipped",
+                ["kind"], registry=r,
+            ),
             # ... and the tokens the pages matched that were computed again
             # because no snapshot stood at their boundary
             "prefix_cut_back": Counter(
@@ -637,8 +645,9 @@ class MetricsCollector:
         prefill's turns (taken / waited), and of a family with convolution
         state what its prefill rows started from (zero / tail / carried) and
         the page tails written, of a family with Mamba layers the same starts
-        (zero / snapshot / carried), the snapshots written and evicted, and
-        the prefix tokens cut back."""
+        (zero / snapshot / carried), the snapshots written and evicted, the
+        state updates its decode sub-steps did and skipped, and the prefix
+        tokens cut back."""
         if not self.enabled:
             return
         from sentio_tpu.infra.phases import (
@@ -647,6 +656,7 @@ class MetricsCollector:
             PREFILL_LATENT_KINDS,
             PREFILL_TURN_KINDS,
             ROW_STEP_KINDS,
+            SSM_ROW_UPDATE_KINDS,
             SSM_SNAPSHOT_EVENTS,
             SSM_START_KINDS,
         )
@@ -663,6 +673,7 @@ class MetricsCollector:
                 ("conv_starts", CONV_START_KINDS, conv_state or {}),
                 ("ssm_starts", SSM_START_KINDS, ssm_state or {}),
                 ("ssm_snapshots", SSM_SNAPSHOT_EVENTS, ssm_state or {}),
+                ("ssm_row_updates", SSM_ROW_UPDATE_KINDS, ssm_state or {}),
                 ("prefill_turns", PREFILL_TURN_KINDS, prefill_turns or {})):
             if name.startswith(("moe", "prefill_latent", "conv", "ssm")) and not any(tick.values()):
                 continue  # no series where no such family is served

@@ -113,6 +113,7 @@ __all__ = [
     "KV_PAGE_KINDS",
     "MOE_KINDS",
     "CONV_STATE_KINDS",
+    "SSM_ROW_UPDATE_KINDS",
     "SSM_STATE_KINDS",
     "PREFILL_LATENT_KINDS",
     "PREFILL_TURN_KINDS",
@@ -217,11 +218,15 @@ CONV_START_KINDS = CONV_STATE_KINDS[:3]
 # kept) or `carried` (a chunked prompt's later segment, from its slot) —, the
 # snapshots `written` (a prefill dispatch filled a slot of the bounded pool)
 # and `evicted` (a slot taken from its boundary for another; its pages stay),
-# and `cut_back_tokens`: tokens the pages matched and the model computed again
-# for want of a snapshot
-SSM_STATE_KINDS = ("zero", "snapshot", "carried", "written", "evicted", "cut_back_tokens")
+# `cut_back_tokens`: tokens the pages matched and the model computed again
+# for want of a snapshot, and of the decode ticks' slots x sub-steps x Mamba
+# blocks one-token updates of a slot's state the `row_updates` the device did
+# and the `row_skips` it did not (kernels/ssm_update.py moves no byte of a row
+# that does not advance; the XLA form updates every row and skips none)
+SSM_STATE_KINDS = ("zero", "snapshot", "carried", "written", "evicted", "cut_back_tokens", "row_updates", "row_skips")
 SSM_START_KINDS = SSM_STATE_KINDS[:3]
 SSM_SNAPSHOT_EVENTS = SSM_STATE_KINDS[3:5]
+SSM_ROW_UPDATE_KINDS = SSM_STATE_KINDS[6:]
 
 # chunked prefill's turns (runtime/paged.py::_advance_prefill dispatches ONE
 # segment a tick over all slots): a tick in which n slots hold a pending

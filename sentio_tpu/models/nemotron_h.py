@@ -364,10 +364,17 @@ def mamba_segment(mp: dict, cfg: NemotronHConfig, u: Array, state: dict, lens: O
     return out, last, snaps
 
 
-def mamba_step(mp: dict, cfg: NemotronHConfig, u: Array, state: dict) -> tuple[Array, dict]:
+def mamba_step(mp: dict, cfg: NemotronHConfig, u: Array, state: dict, update=None) -> tuple[Array, dict]:
     """One token a row: u ``[B, 1, d]`` over ``state`` → (out ``[B, 1, d]``,
     the state after it): the convolution's columns shifted by this token's,
-    ``S <- exp(D A) S + D x (x) B``, ``y = S C + D x``."""
+    ``S <- exp(D A) S + D x (x) B``, ``y = S C + D x``. The state's sum and
+    ``y`` are written HERE, in XLA, unless the caller brings ``update``
+    (``runtime/paged.py::_paged_decode_ssm`` where the engine bound
+    ``kernels/ssm_update.py``, by that module's ``ssm_update_path``):
+    ``update(state["ssm"], decay [B, H], x dt [B, H, P], B [B, G, N], C)`` →
+    (what to hand back as ``"ssm"``, ``S C`` [B, H, P]) — the caller's
+    ``state["ssm"]`` is then whatever its ``update`` takes (all blocks' states,
+    updated in place), and which rows it advances is the caller's too."""
     b = u.shape[0]
     rep = cfg.mamba_heads // cfg.n_groups
     with jax.named_scope("ssm_update"):
@@ -375,10 +382,14 @@ def mamba_step(mp: dict, cfg: NemotronHConfig, u: Array, state: dict) -> tuple[A
         ext = jnp.concatenate([state["conv"].astype(xbc.dtype), xbc], axis=1)     # [B, taps + 1, conv_dim]
         x, bmat, cmat = _conv(mp, cfg, ext, 1)
         x, step = x[:, 0], step[:, 0]                                             # [B, H, P], [B, H]
-        bmat, cmat = (jnp.repeat(m[:, 0], rep, axis=1) for m in (bmat, cmat))     # [B, H, N]
         decay = jnp.exp(step * -jnp.exp(mp["a_log"]))
-        ssm = state["ssm"] * decay[..., None, None] + (x * step[..., None])[..., None] * bmat[:, :, None, :]
-        y = jnp.einsum("bhpn,bhn->bhp", ssm, cmat) + x * mp["d"][:, None]
+        if update is not None:
+            ssm, y = update(state["ssm"], decay, x * step[..., None], bmat[:, 0], cmat[:, 0])
+        else:
+            bmat, cmat = (jnp.repeat(m[:, 0], rep, axis=1) for m in (bmat, cmat))     # [B, H, N]
+            ssm = state["ssm"] * decay[..., None, None] + (x * step[..., None])[..., None] * bmat[:, :, None, :]
+            y = jnp.einsum("bhpn,bhn->bhp", ssm, cmat)
+        y = y + x * mp["d"][:, None]
         out = _out_proj(mp, cfg, y.reshape(b, 1, cfg.inner), z)
     return out, {"conv": ext[:, 1:], "ssm": ssm}
 

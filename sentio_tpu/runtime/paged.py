@@ -413,7 +413,7 @@ def _paged_attn_xla(q, k_pages, v_pages, layer, page_table, lens, n_rep, window=
 
 def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_pages, v_pages,
                          attn_impl=None, write_mask=None, return_routed=False, conv=None, tail=None,
-                         write_impl=None):
+                         write_impl=None, ssm_impl=None):
     """One decode step over the paged pool.
 
     tok [B] int32 (last sampled token per slot); lens [B] absolute position
@@ -425,6 +425,8 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
     EOS or their budget mid-scan without corrupting their cache.
     ``write_impl`` (optional) writes the step's k and v into both pools in
     the scatter's place (``kernels/page_write.py``; :func:`_write_kv`).
+    ``ssm_impl`` (optional) updates a Mamba family's ``conv["ssm"]`` in place
+    (``kernels/ssm_update.py``; :func:`_paged_decode_ssm`).
 
     A family whose block is PARALLEL (``models/cohere2_moe.py``: one norm
     feeding attention and routed experts side by side, a kind per layer)
@@ -446,9 +448,11 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
     from sentio_tpu.models import layers as L
 
     if conv is not None:
-        decode = _paged_decode_ssm if has_ssm_state(cfg) else _paged_decode_conv
-        return decode(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail, attn_impl, write_mask,
-                      write_impl)
+        if has_ssm_state(cfg):
+            return _paged_decode_ssm(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail, attn_impl,
+                                     write_mask, write_impl, ssm_impl)
+        return _paged_decode_conv(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail, attn_impl,
+                                  write_mask, write_impl)
     if getattr(cfg, "parallel_block", False):
         out = _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
                                      attn_impl, write_mask, write_impl)
@@ -702,14 +706,20 @@ def _paged_decode_conv(params, cfg, tok, lens, page_table, k_pages, v_pages, con
 
 
 def _paged_decode_ssm(params, cfg, tok, lens, page_table, k_pages, v_pages, state, snaps,
-                      attn_impl, write_mask, write_impl=None):
+                      attn_impl, write_mask, write_impl=None, ssm_impl=None):
     """:func:`paged_decode_forward` for the family of ``models/nemotron_h.py``:
     blocks of ONE operator each — a Mamba-2 update of the slot's carried state
     (``state = {"conv": [Lm, B, 3, C], "ssm": [Lm, B, H, P, N]}``), rotation-free
     attention over the pages (pool layer ``cfg.attn_index(i)``) or routed
     experts. A row that does not advance (``write_mask`` false) keeps its
-    state. Decode writes no snapshot: ``snaps`` goes through as it came. →
-    (logits [B, V], k_pages, v_pages, routed, state, snaps)."""
+    state. ``state["ssm"]`` is written through ``ssm_impl`` where the engine
+    bound one (``kernels/ssm_update.py``, by its ``ssm_update_path``: a
+    float32 state in whole tiles on one device — the advancing rows' state
+    read once and written in place, no byte of the others moved), else by
+    ``mamba_step``'s own sum, a ``where`` against the mask and ``.at[j].set``,
+    as ``state["conv"]`` is either way. Decode writes no snapshot: ``snaps``
+    goes through as it came. → (logits [B, V], k_pages, v_pages, routed,
+    state, snaps)."""
     import jax
     import jax.numpy as jnp
 
@@ -739,8 +749,15 @@ def _paged_decode_ssm(params, cfg, tok, lens, page_table, k_pages, v_pages, stat
         u = L.rmsnorm(lp["norm"], x, cfg.norm_eps)
         if kind == M.MAMBA:
             j = cfg.ssm_index(i)
-            out, after = M.mamba_step(lp["mamba"], cfg, u, {name: s[j] for name, s in state.items()})
-            for name, s in state.items():
+            if ssm_impl is None:
+                out, after = M.mamba_step(lp["mamba"], cfg, u, {name: s[j] for name, s in state.items()})
+            else:   # the kernel takes all blocks' states and the mask, and hands them back updated
+                out, after = M.mamba_step(
+                    lp["mamba"], cfg, u, {"conv": state["conv"][j], "ssm": state["ssm"]},
+                    update=lambda ssm, *step, j=j: ssm_impl(ssm, j, advancing, *step))
+                state["ssm"] = after.pop("ssm")
+            for name in after:
+                s = state[name]
                 keep = advancing.reshape(b, *([1] * (s.ndim - 2)))
                 state[name] = s.at[j].set(jnp.where(keep, after[name].astype(s.dtype), s[j]))
         elif kind == M.ATTENTION:
@@ -1436,6 +1453,22 @@ class ContinuousBatchingEngine:
         logging.getLogger(__name__).info(
             "decode writes K and V into the pool by %s",
             "the page-write kernel, in place in HBM" if self._write_impl is not None else "the XLA scatter")
+        # A Mamba family's one-token update of ``pool.conv["ssm"]`` likewise,
+        # where the kernels were asked for and ``ssm_update_path`` says so of
+        # the state: float32 in whole tiles on one device. The narrow
+        # rehearsal widths and a state under a mesh keep ``mamba_step``'s sum
+        # and the masked ``.at[j].set``
+        self._ssm_impl = None
+        if use_pallas and self.ssm_state:
+            from sentio_tpu.kernels.ssm_update import make_ssm_update_impl, ssm_update_path
+
+            if ssm_update_path(self.pool.conv["ssm"], mesh) == "pallas":
+                self._ssm_impl = make_ssm_update_impl()
+        if self.ssm_state:
+            logging.getLogger(__name__).info(
+                "decode updates the Mamba state by %s",
+                "the ssm-update kernel: the advancing rows' state read once and written in place"
+                if self._ssm_impl is not None else "the XLA form, every slot's state")
         # The prefill programs' attention is chosen HERE too, by the same
         # ask: the flash kernel that knows a prior (kernels/
         # prefill_attention.py) on TPU, and where a test asks for it on the
@@ -1477,6 +1510,7 @@ class ContinuousBatchingEngine:
         cfg = self.cfg
         attn_impl = self._attn_impl
         write_impl = self._write_impl
+        ssm_impl = self._ssm_impl
         forward_fn = self.forward_fn
         eos_id = self.tokenizer.eos_id
 
@@ -1546,7 +1580,7 @@ class ContinuousBatchingEngine:
                 logits, k_pages, v_pages, *moe = paged_decode_forward(
                     params, cfg, tok, lens, page_table, k_pages, v_pages,
                     attn_impl=attn_impl, write_mask=active, return_routed=routed,
-                    write_impl=write_impl, **state,
+                    write_impl=write_impl, ssm_impl=ssm_impl, **state,
                 )
                 rng, sub = jax.random.split(rng)
                 # temperature AND top-k sample INSIDE the scan body — the
@@ -3174,6 +3208,12 @@ class ContinuousBatchingEngine:
             self._conv_state_pending[kind] = 0
         if self.ssm_state and self._radix is not None:  # the slots the radix cache took from their boundaries
             self._ssm_state_pending["evicted"] = self._radix.take_snapshots_evicted()
+        if self.ssm_state:  # the tick's one-token state updates: the kernel walks the advancing rows alone
+            blocks = len(self.cfg.ssm_layers)
+            all_rows = steps * self.max_slots * blocks
+            done = useful * blocks if self._ssm_impl is not None else all_rows
+            self._ssm_state_pending["row_updates"] += done
+            self._ssm_state_pending["row_skips"] += all_rows - done
         for kind, n in self._ssm_state_pending.items():
             self.ssm_state_total[kind] += n
             self.last_tick_ssm_state[kind] += n
@@ -3316,6 +3356,9 @@ class ContinuousBatchingEngine:
             # and how a decode step writes its K and V rows into the pool: the
             # kernel that leaves the pool in HBM, or the XLA scatter
             "page_write": "pallas" if self._write_impl is not None else "xla",
+            # and, of a family with Mamba layers, how it updates a slot's state
+            # (``kernels/ssm_update.py`` or the XLA form; null for the others)
+            "ssm_update": ("pallas" if self._ssm_impl is not None else "xla") if self.ssm_state else None,
             # a routed family: the tile ``[rows, tk, tn]`` of each of a layer's
             # three grouped matmuls in the decode program and the grid steps an
             # expert costs (the chip's kernel; ``ragged_dot`` elsewhere takes none)

@@ -2586,6 +2586,7 @@ class ReplicaSet:
         agg["paged_attention"] = first.get("paged_attention")
         agg["prefill_attention"] = first.get("prefill_attention")
         agg["page_write"] = first.get("page_write")
+        agg["ssm_update"] = first.get("ssm_update")
         agg["expert_tiles"] = first.get("expert_tiles")
         agg["n_replicas"] = len(per)
         agg["replicas"] = per

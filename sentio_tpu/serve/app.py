@@ -760,9 +760,11 @@ async def info(request: web.Request) -> web.Response:
                 # a family with Mamba layers only: of ``pool_hbm_bytes``, its
                 # state per decode slot and the bounded pool of snapshots the
                 # prefix cache chooses from (``SSM_SNAPSHOTS`` of them), with
-                # how many a page boundary owns now
-                **({key: serving[key] for key in ("ssm_state_bytes", "ssm_snapshot_bytes", "ssm_snapshots",
-                                                  "ssm_snapshots_held")} if "ssm_state_bytes" in serving else {}),
+                # how many a page boundary owns now; and whether a decode step
+                # updates a slot's state by the kernel or by the XLA form
+                **({key: serving.get(key) for key in ("ssm_state_bytes", "ssm_snapshot_bytes", "ssm_snapshots",
+                                                      "ssm_snapshots_held", "ssm_update")}
+                   if "ssm_state_bytes" in serving else {}),
                 # a configured draft accelerates the decode tick
                 # (runtime/paged_spec.py); its exclusions (chunked prefill,
                 # device mesh) are surfaced here for operators

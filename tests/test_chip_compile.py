@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from sentio_tpu.kernels.flash_attention import flash_attention
 from sentio_tpu.kernels.page_write import make_page_write_impl, page_write, page_write_path
 from sentio_tpu.kernels.prefill_attention import make_prefill_attn_fn, prefill_attention
+from sentio_tpu.kernels.ssm_update import make_ssm_update_impl, ssm_update, ssm_update_path
 from sentio_tpu.kernels.paged_attention import (
     make_paged_attn_impl,
     paged_attention,
@@ -150,6 +151,21 @@ def _page_write_case(rows: int, slots: int = 16, nb: int = 10, layers: int = LAY
     return build
 
 
+def _ssm_update_case():
+    """The decode step's Mamba state update (kernels/ssm_update.py) alone, at
+    the nemotron cell's state: six blocks, 16 slots, 64 heads of 64 x 128 in
+    8 groups."""
+
+    def build(topo):
+        place = _on_one_chip(topo)
+        f32 = jnp.float32
+        return ssm_update, (place((6, 16, 64, 64, 128), f32), place((), jnp.int32), place((16,), bool),
+                            place((16, 64), f32), place((16, 64, 64), f32),
+                            place((16, 8, 128), f32), place((16, 8, 128), f32))
+
+    return build
+
+
 def _tp4_case(quant: bool, hkv: int = HKV):
     """The decode kernel inside shard_map over a tp=4 mesh of the four
     described chips: pool and query heads sharded the way init_pool and the
@@ -221,6 +237,8 @@ CASES = {
     "page-write-cell-lfm2": _page_write_case(rows=4),
     "page-write-cell-nemotron": _page_write_case(rows=2),
     "page-write-8kv": _page_write_case(rows=8, slots=8, nb=4),
+    # the Mamba state update at the one cell that takes it
+    "ssm-update-cell-nemotron": _ssm_update_case(),
 }
 # the geometries whose pages the chip's DMA cannot bring (kernels/
 # paged_attention.py ``untiled``): XLA does not store such a pool in the
@@ -957,12 +975,14 @@ def nemotron_programs(v5e):
     impl = make_paged_attn_impl(interpret=False)
     assert page_write_path(pool) == "pallas"     # 21 MB: the compiler could place it
     write = make_page_write_impl(interpret=False)
+    assert ssm_update_path(state["ssm"]) == "pallas"   # float32, a head 64 x 128: whole tiles
+    update = make_ssm_update_impl(interpret=False)
 
     def step(params, tok, lens, table, k_pages, v_pages, state):
         def body(carry, _):
             tok, lens, k_pages, v_pages, state = carry
             logits, k_pages, v_pages, routed, state, _ = paged_decode_forward(
-                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl, write_impl=write,
+                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl, write_impl=write, ssm_impl=update,
                 write_mask=lens < nb * page - 1, return_routed=True, conv=state)
             return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1, k_pages, v_pages, state), routed["experts"]
 
@@ -1022,21 +1042,30 @@ def test_nemotron_programs_read_their_weights_where_they_lie(nemotron_programs, 
 
 def test_nemotron_decode_step_holds_its_kernels_and_its_state(nemotron_programs):
     """The decode step: ONE walk of the pages (the one attention block), ONE
-    write of the step's K and V rows before it and two grouped expert matmuls
-    in each of the three routed blocks, each a Pallas call; the slots' state
-    updated in place; the 21 MB pools touched by the two kernels alone, never
-    moved into nearer memory and back."""
+    write of the step's K and V rows before it, two grouped expert matmuls in
+    each of the three routed blocks and ONE state update in each of the three
+    Mamba blocks, each a Pallas call; the 21 MB pools and the slots' 100 MB
+    of state touched by their kernels alone — no XLA instruction reads or
+    makes the state or a block's slice of it (until PR 46 two fusions a block
+    did: the ``y`` reduce and the masked write), and none is moved into
+    nearer memory and back."""
     cfg, _, texts, memory, (pool, state, _) = nemotron_programs
-    assert texts["step"].count('custom_call_target="tpu_custom_call"') == 1 + 1 + 3 * 2
+    assert texts["step"].count('custom_call_target="tpu_custom_call"') == 1 + 1 + 3 * 2 + 3
     assert len(re.findall(r"%paged_attention[.\d]* = ", texts["step"])) == 1
     assert len(re.findall(r"%page_write[.\d]* = ", texts["step"])) == 1
     assert len(re.findall(r"%gmm[.\d]* = ", texts["step"])) == 6
-    shapes = ("bf16[2,161,128,2,128]", "f32[3,16,64,64,128]")
-    # (a ``copy-done`` of either shape, which this list allowed until PR 45, now fails it)
-    made = [m for m in _pool_shaped(texts["step"], shapes)
-            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "while", "tuple", "custom-call")]
-    assert all(what in ("fusion:scatter", "fusion:dynamic-update-slice", "scatter", "dynamic-update-slice")
-               for _n, _s, what in made), made
+    assert len(re.findall(r"%ssm_update[.\d]* = ", texts["step"])) == 3
+    shapes = ("bf16[2,161,128,2,128]", "f32[3,16,64,64,128]", "f32[16,64,64,128]")
+    carried = ("parameter", "get-tuple-element", "bitcast", "while", "tuple", "custom-call")
+    # nothing but the calls MAKES a pool, the state or a block's slice of it (a ``copy-done`` fails this) ...
+    made = [m for m in _pool_shaped(texts["step"], shapes) if m[2] not in carried]
+    assert made == [], made
+    # ... and nothing but the calls READS one
+    held = {name for name, _shape, _op in _pool_shaped(texts["step"], shapes)}
+    readers = [line.strip()[:160] for line in texts["step"].splitlines()
+               if (inst := re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\((.*)", line))
+               and inst.group(1) not in carried and held & set(re.findall(r"%([\w.\-]+)", inst.group(2)))]
+    assert readers == [], readers
     # beside arguments it donates, the step needs little: no temporary the size of the slots' state
     assert memory["step"].temp_size_in_bytes < int(np.prod(state.shape)) * 4
 

@@ -75,8 +75,9 @@ def engine_of(cfg, tree, **over):
 
 
 def ssm(stats: dict) -> dict:
+    """What prefill counted (the decode ticks' row updates have a test of their own)."""
     return {k.removeprefix("ssm_state_"): v for k, v in stats.items() if k.startswith("ssm_state_") and v
-            and k != "ssm_state_bytes"}
+            and k not in ("ssm_state_bytes", "ssm_state_row_updates", "ssm_state_row_skips")}
 
 
 # ----------------------------------------------- the two forms of the recurrence
@@ -285,6 +286,11 @@ def test_a_prompt_prefilled_in_chunks_is_one_prefilled_whole():
     assert whole.tokens == chunked.tokens == greedy(cfg, tree, whole_engine, PROMPT, 10)
     assert chunked.logprob_sum == pytest.approx(whole.logprob_sum, abs=2e-4)
     assert ssm(whole_engine.stats()) == {"zero": 1, "written": 1}
+    # the decode ticks' one-token state updates, a Mamba block each: these widths are no float32
+    # tile, so the XLA form serves them, which updates every slot's state and skips none
+    stats = whole_engine.stats()
+    assert stats["ssm_update"] == "xla" and stats["ssm_state_row_skips"] == 0
+    assert stats["ssm_state_row_updates"] == sum(whole_engine.row_steps_total.values()) * len(cfg.ssm_layers) > 0
     # 113 prompt tokens: seven whole segments and a last of one token, which reaches no new page
     assert ssm(chunked_engine.stats()) == {"zero": 1, "carried": 7, "written": 7}
     assert chunked_engine.stats()["ssm_snapshots_held"] == 7
@@ -408,3 +414,55 @@ def test_what_this_family_is_not_served_with_says_why():
         engine_of(cfg, tree, mesh=object())
     with pytest.raises(ValueError, match="letters"):
         tiny(pattern="MEMEM*X")
+
+
+def test_the_engine_updates_the_state_by_the_kernel_where_the_rule_says_so(caplog):
+    """A state whose head is whole float32 tiles (``ssm_state`` 128), the
+    kernels asked for: the engine binds ``kernels/ssm_update.py`` (interpret
+    mode here), says so in ``stats()`` and in its log, answers what the same
+    engine answers with the XLA form in its place, and counts the row-updates
+    the kernel did — the tokens the ticks folded, a Mamba block each — and
+    those it skipped: the rest of slots x sub-steps x blocks. The rehearsal
+    widths under the same ask keep the XLA form."""
+    import logging
+
+    cfg = tiny(ssm_state=128, head_dim=128)
+    tree = seeded(cfg)
+    with caplog.at_level(logging.INFO, logger="sentio_tpu.runtime.paged"):
+        kernel = engine_of(cfg, tree, use_pallas=True)
+    assert kernel.stats()["ssm_update"] == "pallas"
+    assert "the ssm-update kernel" in caplog.text
+    xla = engine_of(cfg, tree, use_pallas=True)
+    xla._ssm_impl = None
+    xla._build_fns()
+    prompts = [PROMPT[:40], HEAD]
+    got, want = (engine.run_all(prompts, max_new_tokens=6) for engine in (kernel, xla))
+    assert [r.tokens for r in got] == [r.tokens for r in want] and all(len(r.tokens) == 6 for r in got)
+    assert [r.logprob_sum for r in got] == pytest.approx([r.logprob_sum for r in want], abs=2e-4)
+    stats, blocks = kernel.stats(), len(cfg.ssm_layers)
+    assert stats["ssm_state_row_updates"] == kernel.row_steps_total["useful"] * blocks > 0
+    assert stats["ssm_state_row_updates"] + stats["ssm_state_row_skips"] == sum(kernel.row_steps_total.values()) * blocks
+    assert xla.stats()["ssm_update"] == "xla" and xla.stats()["ssm_state_row_skips"] == 0
+    assert engine_of(tiny(head_dim=128), seeded(tiny(head_dim=128)), use_pallas=True).stats()["ssm_update"] == "xla"
+
+
+def test_the_row_update_counters_reach_metrics_with_the_familys_others():
+    """``sentio_tpu_ssm_state_rows_total{kind}`` as ``/metrics`` exports it,
+    from a harvested tick's ``ssm_state`` beside the starts and snapshots it
+    already carried; a tick of a family without Mamba layers (zeros) makes no
+    series."""
+    from sentio_tpu.infra.metrics import MetricsCollector
+    from sentio_tpu.infra.phases import SSM_ROW_UPDATE_KINDS, SSM_STATE_KINDS
+
+    assert SSM_ROW_UPDATE_KINDS == ("row_updates", "row_skips") == SSM_STATE_KINDS[-2:]
+    m = MetricsCollector()
+    rows = {"useful": 21, "halted": 11, "empty": 32}
+    m.record_row_steps(rows, ssm_state=dict.fromkeys(SSM_STATE_KINDS, 0))
+    assert b"sentio_tpu_ssm_state_rows_total{kind=" not in m.export_prometheus()
+    m.record_row_steps(rows, ssm_state={**dict.fromkeys(SSM_STATE_KINDS, 0), "zero": 1,
+                                        "row_updates": 21 * 6, "row_skips": 43 * 6})
+    text = m.export_prometheus()
+    assert b'sentio_tpu_ssm_state_rows_total{kind="row_updates"} 126.0' in text
+    assert b'sentio_tpu_ssm_state_rows_total{kind="row_skips"} 258.0' in text
+    assert b'sentio_tpu_ssm_state_starts_total{kind="zero"} 1.0' in text
+    assert m.export_json()["counters"]["ssm_row_updates('row_skips',)"] == 258.0
