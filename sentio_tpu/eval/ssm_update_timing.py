@@ -5,7 +5,7 @@
 (6 x 16 x 64 x 64 x 128) with 16, 10, 1 and 0 of the 16 rows advancing, in two
 forms: the kernel (``kernels/ssm_update.py``) and the XLA form it replaces
 (``models/nemotron_h.py::mamba_step``'s arithmetic and the masked ``.at[j].set``
-of ``runtime/paged.py::_paged_decode_ssm``). The calls are chained through
+of ``runtime/paged.py::paged_decode_forward``). The calls are chained through
 the donated state inside one jitted loop, a block after the other, as a
 decode sub-step visits them. The clock is the device's own (a profiler trace:
 the device's busy time a call, and the median ``ssm_update`` op); on the CPU
@@ -29,7 +29,7 @@ TINY = dict(layers=2, slots=4, heads=4, head_dim=8, state=128, groups=2)
 
 
 def xla_update(state, layer, advancing, decay, xdt, bmat, cmat):
-    """The form the kernel replaces, as ``mamba_step`` and ``_paged_decode_ssm`` write it."""
+    """The form the kernel replaces, as ``mamba_step`` and ``paged_decode_forward`` write it."""
     import jax.numpy as jnp
 
     rep = state.shape[2] // bmat.shape[1]

@@ -36,8 +36,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from sentio_tpu.models import layers as L
-from sentio_tpu.models.llama import Cache, LlamaConfig, _write_cache, qkv_proj
-from sentio_tpu.models.moe import expert_layer
+from sentio_tpu.models.families import DecodeStep, Family
+from sentio_tpu.models.llama import Cache, LlamaConfig, _write_cache, init_cache, qkv_proj
+from sentio_tpu.models.moe import expert_layer, expert_tiles
 
 Array = jax.Array
 
@@ -317,3 +318,31 @@ def head_logits(params: dict, cfg: Cohere2MoeConfig, x: Array) -> Array:
     logits = jnp.einsum("...d,vd->...v", x, params["embed_tokens"]["embedding"].astype(x.dtype),
                         preferred_element_type=jnp.float32)
     return logits * cfg.logit_scale
+
+
+def decode_layer(lp: dict, cfg: Cohere2MoeConfig, i: int, x: Array, step: DecodeStep) -> Array:
+    """Layer ``i`` of a decode step on ``x [B, 1, d]``: ``h = LN(x); x +=
+    Attn_i(h) + Experts(h)``. A window reaches the attention as ``window=``:
+    the Pallas walk starts at its first block, the gather path masks."""
+    h = centred_norm(lp["norm"], x, cfg.norm_eps)
+    q, k, v = qkv_proj(lp["attn"], cfg, h)
+    q, k = qk_rotated(cfg, i, q, k, step.positions)
+    window = cfg.window(i)
+    attn = step.attend(q, k, v, i, window=window, scope="attn.window" if window else "attn.full")
+    # a row that does not advance is routed nowhere: it would touch
+    # experts (bytes) for a token nobody reads
+    routed, chosen, n = expert_layer(lp["moe"], cfg, h, step.valid)
+    x = x + L.dense(lp["attn"]["wo"], attn.reshape(x.shape[0], 1, -1), cfg.jdtype) + routed
+    step.note({"experts": chosen}, n)
+    return x
+
+
+ROUTED_DRAFT = "paged speculation does not serve a routed family ({cfg}) yet"
+FAMILY = Family(
+    name="cohere2_moe", config=Cohere2MoeConfig, init=init_cohere2_moe, forward=cohere2_forward,
+    init_cache=init_cache, decode_layer=decode_layer,
+    head=lambda params, cfg, x: head_logits(params, cfg, x)[:, 0],
+    picks=lambda cfg: {"experts": cfg.experts_per_token}, expert_tiles=expert_tiles,
+    refuses={"draft": ROUTED_DRAFT,
+             "mesh": "a {cfg} model is one chip's share of each layer (``experts_held`` of its experts): a mesh "
+                     "that splits the share again has no rules yet"})

@@ -54,9 +54,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from sentio_tpu.models import layers as L
-from sentio_tpu.models.cohere2_moe import _SCORE_BLOCK_BYTES, rope_interleaved
+from sentio_tpu.models.cohere2_moe import _SCORE_BLOCK_BYTES, ROUTED_DRAFT, rope_interleaved
+from sentio_tpu.models.families import DecodeStep, Family
 from sentio_tpu.models.llama import Cache, LlamaConfig, _write_cache
-from sentio_tpu.models.moe import expert_layer
+from sentio_tpu.models.moe import expert_layer, expert_tiles
 
 Array = jax.Array
 
@@ -454,3 +455,40 @@ def deepseek_v2_forward(
 def head_logits(params: dict, cfg: DeepseekV2Config, x: Array) -> Array:
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return L.dense(params["lm_head"], x, cfg.jdtype).astype(jnp.float32)
+
+
+def decode_layer(lp: dict, cfg: DeepseekV2Config, i: int, x: Array, step: DecodeStep) -> Array:
+    """Layer ``i`` of a decode step on ``x [B, 1, d]``: the step's latent goes
+    into each row's current page and the query is ABSORBED, so that attention
+    reads the latents themselves and no key or value is ever formed (the head
+    of this file); then the layer's MLP or its share of the experts."""
+    dt = cfg.jdtype
+    ap = lp["attn"]
+    h = L.rmsnorm(lp["attn_norm"], x, cfg.norm_eps)
+    q_nope, q_pe = mla_query(ap, cfg, h, step.positions)
+    latent = mla_latent(ap, cfg, h, step.positions)[:, 0, 0]        # [B, latent_dim]
+    # (the queries as a thunk: made once the latent is written, the order this step has always traced)
+    o_lat = step.attend(latent, lambda: (absorb_query(ap, cfg, q_nope[:, 0]), q_pe[:, 0]), i, cfg.softmax_scale)
+    attn = unabsorb(ap, cfg, o_lat.astype(dt))
+    x = x + L.dense(ap["wo"], attn.reshape(x.shape[0], 1, -1), dt)
+    # a row that does not advance is routed nowhere (``models/cohere2_moe.py``)
+    out, chosen, n = mlp_or_experts(lp, cfg, L.rmsnorm(lp["mlp_norm"], x, cfg.norm_eps), step.valid)
+    x = x + out
+    if chosen is not None:
+        step.note(chosen, n)
+    return x
+
+
+def _picks(cfg: DeepseekV2Config) -> dict:
+    return {"experts": cfg.experts_per_token, **({"groups": cfg.topk_group} if cfg.n_group > 1 else {})}
+
+
+FAMILY = Family(
+    name="deepseek_v2", config=DeepseekV2Config, init=init_deepseek_v2, forward=deepseek_v2_forward,
+    init_cache=init_latent_cache, decode_layer=decode_layer,
+    head=lambda params, cfg, x: head_logits(params, cfg, x)[:, 0],
+    latent=True, picks=_picks, expert_tiles=expert_tiles,
+    refuses={"draft": ROUTED_DRAFT,
+             "int8": "a latent pool ({cfg}) is bf16 — int8 latents have no kernel and no quality gate yet",
+             "mesh": "a latent pool ({cfg}) is served on one device a process: a latent has no heads to "
+                     "split over tp"})

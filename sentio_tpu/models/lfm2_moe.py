@@ -51,8 +51,9 @@ import jax
 import jax.numpy as jnp
 
 from sentio_tpu.models import layers as L
+from sentio_tpu.models.families import DecodeStep, Family, StateBeside
 from sentio_tpu.models.llama import Cache, LlamaConfig, _write_cache, qkv_proj
-from sentio_tpu.models.moe import expert_layer
+from sentio_tpu.models.moe import expert_layer, expert_tiles
 
 Array = jax.Array
 
@@ -408,3 +409,42 @@ def lfm2_forward(
             counts = counts + n
     routed = {"experts": jnp.stack(picks)} if picks else {}
     return head_logits(params, cfg, x), cache, {**routed, "counts": counts}
+
+
+def decode_layer(lp: dict, cfg: Lfm2MoeConfig, i: int, x: Array, step: DecodeStep) -> Array:
+    """Layer ``i`` of a decode step on ``x [B, 1, d]``: the convolution as a
+    segment of ONE token over the slot's state, or attention over the pages
+    (pool layer ``attn_index``); then the layer's MLP or experts."""
+    u = L.rmsnorm(lp["op_norm"], x, cfg.norm_eps)
+    if cfg.kinds[i] == CONV:
+        out = step.advance(cfg.conv_index(i), lambda held, _: conv_segment(lp["conv"], cfg, u, held, None)[:2])
+    else:
+        q, k, v = qk_normed(lp["attn"], cfg, u, step.positions)
+        attn = step.attend(q, k, v, cfg.attn_index(i), scope="attn.full")
+        out = L.dense(lp["attn"]["wo"], attn.reshape(x.shape[0], 1, -1), cfg.jdtype)
+    x = x + out
+    # a row that does not advance is routed nowhere (``models/cohere2_moe.py``)
+    out, chosen, n = mlp_or_experts(lp, cfg, L.rmsnorm(lp["ffn_norm"], x, cfg.norm_eps), step.valid)
+    x = x + out
+    if chosen is not None:
+        step.note({"experts": chosen}, n)
+    return x
+
+
+def recurrent_refusals(state: str, per: str) -> dict:
+    """What a family with recurrent ``state`` beside the pages is not served with."""
+    return {"draft": f"paged speculation does not serve a family with recurrent state ({{cfg}}): a rejected draft "
+                     f"token would have to roll the {state} state back, and the tick keeps no state to roll back to",
+            "mesh": f"a family with {state} state ({{cfg}}) is served on one device a process: the state per slot "
+                    f"and per {per} has no rule under a mesh yet"}
+
+
+FAMILY = Family(
+    name="lfm2_moe", config=Lfm2MoeConfig, init=init_lfm2_moe, forward=lfm2_forward,
+    init_cache=init_lfm2_cache, decode_layer=decode_layer,
+    head=lambda params, cfg, x: head_logits(params, cfg, x)[:, 0],
+    pool_layers=lambda cfg: len(cfg.attn_layers),
+    state=StateBeside(per="page", zeros=lambda cfg, rows: jnp.zeros(
+        (len(cfg.conv_layers), rows, cfg.conv_taps, cfg.dim), cfg.jdtype)),
+    picks=lambda cfg: {"experts": cfg.experts_per_token}, expert_tiles=expert_tiles,
+    refuses=recurrent_refusals("convolution", "page"))

@@ -28,6 +28,8 @@ import numpy as np
 
 from sentio_tpu.analysis.audit.registry import jit_family
 from sentio_tpu.models import layers as L
+from sentio_tpu.models.families import DecodeStep, Family
+from sentio_tpu.parallel.sharding import LLAMA_TP_RULES
 
 Array = jax.Array
 Cache = dict  # {"k": [L,B,S,Hkv,D], "v": [L,B,S,Hkv,D]}
@@ -367,6 +369,34 @@ def llama_forward(
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = L.dense(params["lm_head"], x, dt)
     return logits.astype(jnp.float32), cache
+
+
+def decode_layer(lp: dict, cfg: LlamaConfig, i: int, x: Array, step: DecodeStep, ffn=None) -> Array:
+    """Layer ``i`` of a decode step on ``x [B, 1, d]``: :func:`llama_forward`'s
+    block over the pages. ``ffn(lp, cfg, xm, step)``: the second half of a
+    family that shares the block (``models/moe.py``); else the dense SwiGLU."""
+    dt = cfg.jdtype
+    cos, sin = step.tables
+    xn = L.rmsnorm(lp["attn_norm"], x, cfg.norm_eps)
+    q, k, v = qkv_proj(lp["attn"], cfg, xn)
+    q = L.apply_rope(q, step.positions, cos, sin)
+    k = L.apply_rope(k, step.positions, cos, sin)
+    out = step.attend(q, k, v, i)
+    x = x + L.dense(lp["attn"]["wo"], out.reshape(x.shape[0], 1, -1), dt)
+    xm = L.rmsnorm(lp["mlp_norm"], x, cfg.norm_eps)
+    return x + (_mlp(lp["mlp"], cfg, xm) if ffn is None else ffn(lp, cfg, xm, step))
+
+
+def decode_head(params: dict, cfg: LlamaConfig, x: Array) -> Array:
+    """A decode step's logits ``[B, V]`` float32 of ``x [B, 1, d]``."""
+    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    return L.dense(params["lm_head"], x, cfg.jdtype)[:, 0].astype(jnp.float32)
+
+
+FAMILY = Family(
+    name="llama", config=LlamaConfig, init=init_llama, forward=llama_forward, init_cache=init_cache,
+    decode_layer=decode_layer, head=decode_head, mesh_rules=LLAMA_TP_RULES,
+    decode_tables=lambda cfg, reach: L.rope_frequencies(cfg.head_dim, max(reach, cfg.max_len), cfg.rope_theta))
 
 
 @jit_family("llama.loss", static_argnames=("cfg",))

@@ -33,7 +33,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from sentio_tpu.models import layers as L
+from sentio_tpu.models import llama
+from sentio_tpu.models.families import DecodeStep, Family
 from sentio_tpu.models.llama import Cache, LlamaConfig, _attn, init_cache  # noqa: F401
+from sentio_tpu.parallel.sharding import MOE_EP_RULES
 
 Array = jax.Array
 
@@ -515,6 +518,21 @@ def moe_serving_forward(
         params, cfg, ids, positions, cache, cache_index, pad_mask, attn_fn
     )
     return logits, cache
+
+
+def decode_layer(lp: dict, cfg: MoeConfig, i: int, x: Array, step: DecodeStep) -> Array:
+    """``models/llama.py``'s block with the routed SwiGLU as its second half;
+    frozen and free rows are masked out of routing: they claim no capacity."""
+    def routed(lp, cfg, xm, step):
+        return moe_mlp(lp["moe"], cfg, xm, step.valid)[0]
+
+    return llama.decode_layer(lp, cfg, i, x, step, ffn=routed)
+
+
+FAMILY = Family(
+    name="moe", config=MoeConfig, init=init_moe, forward=moe_serving_forward, init_cache=init_cache,
+    decode_layer=decode_layer, head=llama.decode_head, decode_tables=llama.FAMILY.decode_tables,
+    mesh_rules=MOE_EP_RULES)
 
 
 def moe_loss(params: dict, cfg: MoeConfig, ids: Array, mask: Array) -> Array:

@@ -47,9 +47,10 @@ import jax
 import jax.numpy as jnp
 
 from sentio_tpu.models import layers as L
-from sentio_tpu.models.lfm2_moe import _attn
+from sentio_tpu.models.families import DecodeStep, Family, StateBeside
+from sentio_tpu.models.lfm2_moe import _attn, recurrent_refusals
 from sentio_tpu.models.llama import Cache, LlamaConfig, qkv_proj
-from sentio_tpu.models.moe import expert_layer
+from sentio_tpu.models.moe import expert_layer, expert_tiles
 
 Array = jax.Array
 
@@ -369,7 +370,7 @@ def mamba_step(mp: dict, cfg: NemotronHConfig, u: Array, state: dict, update=Non
     the state after it): the convolution's columns shifted by this token's,
     ``S <- exp(D A) S + D x (x) B``, ``y = S C + D x``. The state's sum and
     ``y`` are written HERE, in XLA, unless the caller brings ``update``
-    (``runtime/paged.py::_paged_decode_ssm`` where the engine bound
+    (``runtime/paged.py::paged_decode_forward`` where the engine bound
     ``kernels/ssm_update.py``, by that module's ``ssm_update_path``):
     ``update(state["ssm"], decay [B, H], x dt [B, H, P], B [B, G, N], C)`` →
     (what to hand back as ``"ssm"``, ``S C`` [B, H, P]) — the caller's
@@ -474,4 +475,32 @@ def nemotron_h_forward(
     return head_logits(params, cfg, x), cache, {**routed, "counts": counts}
 
 
-nemotron_h_forward.takes_logits_at = True   # ``runtime/paged.py``'s prefill programs ask before they pass it
+def decode_layer(lp: dict, cfg: NemotronHConfig, i: int, x: Array, step: DecodeStep) -> Array:
+    """Block ``i`` of a decode step on ``x [B, 1, d]``, ONE operator: the slot's
+    state advanced by :func:`mamba_step`, rotation-free attention over the
+    pages (pool layer ``attn_index``) or routed experts."""
+    kind = cfg.pattern[i]
+    u = L.rmsnorm(lp["norm"], x, cfg.norm_eps)
+    if kind == MAMBA:
+        out = step.advance(cfg.ssm_index(i), lambda held, update: mamba_step(lp["mamba"], cfg, u, held, update))
+    elif kind == ATTENTION:
+        q, k, v = plain_qkv(lp["attn"], cfg, u, None)
+        attn = step.attend(q, k, v, cfg.attn_index(i), scope="attn.full")
+        out = L.dense(lp["attn"]["wo"], attn.reshape(x.shape[0], 1, -1), cfg.jdtype)
+    else:
+        # a row that does not advance is routed nowhere (``models/cohere2_moe.py``)
+        out, chosen, n = expert_layer(lp["moe"], cfg, u, step.valid)
+        step.note({"experts": chosen}, n)
+    return x + out
+
+
+FAMILY = Family(
+    name="nemotron_h", config=NemotronHConfig, init=init_nemotron_h, forward=nemotron_h_forward,
+    logits_at=True, init_cache=init_nemotron_cache, decode_layer=decode_layer,
+    head=lambda params, cfg, x: head_logits(params, cfg, x)[:, 0],
+    pool_layers=lambda cfg: len(cfg.attn_layers),
+    state=StateBeside(per="snapshot", zeros=zero_state, page_tokens=lambda cfg: cfg.chunk_size),
+    picks=lambda cfg: {"experts": cfg.experts_per_token}, expert_tiles=expert_tiles,
+    refuses={**recurrent_refusals("Mamba", "snapshot"),
+             "int8": "K and V are a thirtieth of what a sequence of {cfg} keeps (its Mamba state is float32, as "
+                     "the model card advises): int8 pages beside it have no quality gate and nothing to save"})

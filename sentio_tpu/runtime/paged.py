@@ -4,11 +4,9 @@ The reference caps context at ~2000 tokens and serves one request per HTTP
 call (/root/reference/src/core/graph/nodes.py:296-338, factory.py:90); its
 "batching" is a connection pool. Here the KV cache is *paged*: HBM holds one
 pool of fixed-size pages ([L, P, page, Hkv, D]; ``L`` counts the layers that
-HAVE keys and values — every layer of most families, the attention layers
-alone of one whose other layers are convolutions, ``models/lfm2_moe.py``,
-which keeps two positions of state a convolution layer per decode slot and per
-page beside the pool) and every live sequence owns a page table mapping
-logical blocks to physical pages. That buys:
+HAVE keys and values: ``PagedPool`` says what a family keeps beside them) and
+every live sequence owns a page table mapping logical blocks to physical
+pages. That buys:
 
 * **continuous batching** — requests join and leave decode slots without
   recompiling or re-laying-out anyone else's cache; one compiled decode
@@ -69,7 +67,8 @@ from sentio_tpu.infra.phases import (
     PREFILL_TURN_KINDS, ROW_STEP_KINDS, SSM_STATE_KINDS, PhaseTimer,
 )
 from sentio_tpu.infra.tracing import annotation, dispatching, get_stamper, harvested
-from sentio_tpu.models.llama import LlamaConfig, qkv_proj, serving_layout
+from sentio_tpu.models.families import DecodeStep, family_of
+from sentio_tpu.models.llama import LlamaConfig, serving_layout
 from sentio_tpu.parallel.batcher import bucket_size
 
 Array = object  # jax.Array — jax imported lazily
@@ -87,28 +86,21 @@ class PagedPool:
     kernels/paged_attention.py). The pytree form
     rides through every jit signature, scan carry, and donation unchanged;
     only the read/write helpers below understand the representation.
-    A LATENT family (``models/deepseek_v2.py``: one vector a token and layer
-    in place of keys and values) has ONE pool, ``k`` ``[L, P, latent_dim,
-    page]`` — a page lies latent-major, its positions the lanes of a tile
+    What a family keeps is its record's (``models/families.py``). A LATENT
+    family has ONE pool, ``k`` ``[L, P, latent_dim, page]`` — a page lies
+    latent-major, its positions the lanes of a tile
     (``kernels/latent_attention.py`` says why) — and ``v`` is None: an empty
-    pytree, which rides every signature, carry and donation as it is.
-    A family with CONVOLUTION layers (``models/lfm2_moe.py``) has pages for its
-    attention layers only — the pool's layer axis counts those, pool layer
-    ``cfg.attn_index(l)`` for model layer ``l`` — and two arrays beside them,
-    a convolution layer each: ``conv [Lc, slots, 2, d]``, the state a decode
-    slot carries (``z`` at its sequence's last two positions), and ``tail
-    [Lc, P, 2, d]``, ``z`` at the last two positions of every page, written by
-    prefill for the pages it fills and by decode when a page fills — what a
-    sequence that starts behind cached pages (a radix hit, a later chunk of
-    its own prompt) starts from. Both None for every other family.
-    A family whose layers carry a MATRIX state (``models/nemotron_h.py``: Mamba-2
-    blocks) holds the same two names as dicts of arrays, a Mamba layer each on
-    the leading axis: ``conv = {"conv": [Lm, slots, 3, 6144], "ssm": [Lm, slots,
-    64, 64, 128] float32}``, what a decode slot carries, and ``tail`` the same
-    names ``[Lm, S, ...]``: a BOUNDED pool of ``S`` snapshots in place of a
-    tail a page (a state is fifty times the K and V of the page it ends), whose
-    slots the radix cache hands to the page boundaries it chooses
-    (``runtime/radix.py``).
+    pytree, which rides every signature, carry and donation as it is. A family
+    with STATE BESIDE THE PAGES (``StateBeside``) has pages for its attention
+    layers only (the pool's layer axis counts those) and two more entries, a
+    state layer each on the leading axis: ``conv [Ls, slots, ...]``, what a
+    decode slot carries, and ``tail`` — per page ``[Ls, P, ...]``, the state at
+    every page's end, written by prefill for the pages it fills and by decode
+    when a page fills: what a sequence that starts behind cached pages starts
+    from — or per snapshot ``[Ls, S, ...]``, a BOUNDED pool whose slots the
+    radix cache hands to the page boundaries it chooses (``runtime/radix.py``);
+    arrays, or dicts of arrays under the state's own names. Both None for
+    every other family.
     Page id 0 = scratch."""
 
     k: Array
@@ -239,23 +231,6 @@ def _gather_pages(pages, layer, page_table, dtype, head_dim=None):
     return kc.reshape(b, nb * kc.shape[2], -1, head_dim or kc.shape[-1])
 
 
-def is_latent(cfg) -> bool:
-    """Whether ``cfg``'s family keeps a latent in place of K and V."""
-    return getattr(cfg, "kv_lora_rank", 0) > 0
-
-
-def has_conv_state(cfg) -> bool:
-    """Whether ``cfg``'s family carries convolution state beside the pages
-    (``models/lfm2_moe.py``: its config names its convolution layers)."""
-    return bool(getattr(cfg, "conv_layers", ()))
-
-
-def has_ssm_state(cfg) -> bool:
-    """Whether ``cfg``'s family carries a matrix state a head beside the pages
-    (``models/nemotron_h.py``: its config names its Mamba layers)."""
-    return bool(getattr(cfg, "ssm_layers", ()))
-
-
 def _latent_tokens(pages, index):
     """Latent pages ``pages[index]`` ``[..., NB, latent_dim, page]`` as the
     tokens they hold, ``[..., NB * page, latent_dim]``."""
@@ -286,9 +261,8 @@ def init_pool(
     crosses devices) and page tables stay replicated host-side. With
     ``quantized`` the pool stores int8 + per-vector scales — ~half the HBM
     and half the decode-attention read bandwidth of bf16 pages. A family with
-    convolution layers gets pages for its attention layers and, for ``slots``
-    decode slots, the convolution state beside them (``PagedPool``); one with
-    Mamba layers its state for ``slots`` slots and ``snapshots`` snapshots.
+    state beside the pages (``PagedPool``) gets it for ``slots`` decode slots
+    and for every page or ``snapshots`` snapshots.
 
     ``pack`` > 1 is the pool's layout for heads NARROWER than the 128 lanes of
     a tile (``kernels/paged_attention.py::lane_packing``): ``pack`` kv heads
@@ -300,27 +274,20 @@ def init_pool(
     device only."""
     import jax.numpy as jnp
 
-    if is_latent(cfg):
-        # refused by name where the engine is built; here for every other caller
-        if quantized or mesh is not None:
-            raise ValueError("a latent pool is bf16 on one device: int8 latents and a mesh have no rules yet")
+    family = family_of(cfg)
+    # refused where the engine is built; here for every other caller
+    for what, asked_for in (("mesh", mesh is not None), ("int8", quantized)):
+        if asked_for and (reason := family.refusal(what, cfg)):
+            raise ValueError(reason)
+    if family.latent:
         pages = jnp.zeros((cfg.n_layers, num_pages, cfg.latent_dim, page_size), cfg.jdtype)
         return PagedPool(k=pages, v=None, page_size=page_size)
 
     conv = tail = None
-    n_layers = cfg.n_layers
-    if has_conv_state(cfg):
-        if mesh is not None:
-            raise ValueError("convolution state is held on one device: it has no rule under a mesh yet")
-        n_layers, lc = len(cfg.attn_layers), len(cfg.conv_layers)
-        conv = jnp.zeros((lc, slots, cfg.conv_taps, cfg.dim), cfg.jdtype)
-        tail = jnp.zeros((lc, num_pages, cfg.conv_taps, cfg.dim), cfg.jdtype)
-    if has_ssm_state(cfg):
-        if mesh is not None:
-            raise ValueError("a Mamba layer's state is held on one device: it has no rule under a mesh yet")
-        n_layers = len(cfg.attn_layers)
-        conv, tail = ({name: jnp.zeros(shape, dtype) for name, (shape, dtype) in cfg.state_shapes(rows).items()}
-                      for rows in (slots, snapshots))
+    n_layers = family.pool_layers(cfg)
+    if family.state is not None:
+        conv = family.state.zeros(cfg, slots)
+        tail = family.state.zeros(cfg, num_pages if family.state.per == "page" else snapshots)
     if pack > 1 and (quantized or mesh is not None or cfg.n_kv_heads % pack):
         raise ValueError(f"pack={pack}: lane-packed pages are bf16, on one device, whole rows of heads")
     shape = (n_layers, num_pages, page_size, cfg.n_kv_heads // pack, cfg.head_dim * pack)
@@ -414,7 +381,10 @@ def _paged_attn_xla(q, k_pages, v_pages, layer, page_table, lens, n_rep, window=
 def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_pages, v_pages,
                          attn_impl=None, write_mask=None, return_routed=False, conv=None, tail=None,
                          write_impl=None, ssm_impl=None):
-    """One decode step over the paged pool.
+    """One decode step over the paged pool: the ONE walk of a step's layers,
+    whatever the family (``models/families.py``: its record's ``decode_layer``
+    and ``head`` are what differs; the pool's operations reach them as a
+    ``DecodeStep``).
 
     tok [B] int32 (last sampled token per slot); lens [B] absolute position
     the new token occupies; page_table [B, NB]. Returns (logits [B, V],
@@ -426,146 +396,116 @@ def paged_decode_forward(params, cfg: LlamaConfig, tok, lens, page_table, k_page
     ``write_impl`` (optional) writes the step's k and v into both pools in
     the scatter's place (``kernels/page_write.py``; :func:`_write_kv`).
     ``ssm_impl`` (optional) updates a Mamba family's ``conv["ssm"]`` in place
-    (``kernels/ssm_update.py``; :func:`_paged_decode_ssm`).
+    (``kernels/ssm_update.py``).
 
-    A family whose block is PARALLEL (``models/cohere2_moe.py``: one norm
-    feeding attention and routed experts side by side, a kind per layer)
-    takes its own walk of the layers, below, and so does a LATENT family
-    (``models/deepseek_v2.py``: sequential, absorbed attention over latent
-    pages; ``v_pages`` is None and comes back None); ``return_routed`` then
-    adds what its expert layers decided (``{"experts": [L, B, k] picks,
-    "counts": [4]}``, a latent family's ``"groups"`` beside them) as a
-    fourth result, None for every other family. A family with CONVOLUTION
-    layers (``models/lfm2_moe.py``) is given its state — ``conv [Lc, B, 2,
-    d]``, a row a slot, and the page tails ``tail [Lc, P, 2, d]`` — and
-    returns six: the four, then both carried on. A family with MAMBA layers
-    (``models/nemotron_h.py``) the same, ``conv`` being its slots' state;
-    decode writes no snapshot, so its ``tail`` may stay away (None).
+    A LATENT family's ``k_pages`` are its latents; ``v_pages`` is None and
+    comes back None. ``return_routed`` adds what a family's expert layers
+    decided (``{"experts": [L, B, k] picks, "counts": [4]}``, ``"groups"``
+    beside them where it picks groups first) as a fourth result, None for a
+    family whose layers hand back nothing. A family with STATE BESIDE THE
+    PAGES is given it as ``conv`` (a row a slot) and ``tail`` (``PagedPool``;
+    decode writes no snapshot, so a snapshot pool may stay away, None, and
+    goes through as it came) and returns six: the four, then both carried on.
+    A row that advances shifts its state and, at the last positions of its
+    page, leaves the newest in that page's tail; a row that does not
+    (``write_mask`` false) keeps its state and writes no tail.
     """
+    import contextlib
+
     import jax
     import jax.numpy as jnp
 
     from sentio_tpu.models import layers as L
 
-    if conv is not None:
-        if has_ssm_state(cfg):
-            return _paged_decode_ssm(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail, attn_impl,
-                                     write_mask, write_impl, ssm_impl)
-        return _paged_decode_conv(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail, attn_impl,
-                                  write_mask, write_impl)
-    if getattr(cfg, "parallel_block", False):
-        out = _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
-                                     attn_impl, write_mask, write_impl)
-        return out if return_routed else out[:3]
-    if is_latent(cfg):
-        out = _paged_decode_latent(params, cfg, tok, lens, page_table, k_pages, attn_impl, write_mask)
-        return out if return_routed else out[:3]
-
+    family = family_of(cfg)
     dt = cfg.jdtype
     b = tok.shape[0]
-    h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    page = _page_dim(k_pages)
-    positions = lens[:, None]  # [B,1]
-    window = page_table.shape[1] * page
-    cos, sin = L.rope_frequencies(hd, max(window, cfg.max_len), cfg.rope_theta)
+    page = k_pages.shape[-1] if family.latent else _page_dim(k_pages)
+    pool = {"k": k_pages, "v": v_pages, "conv": conv, "tail": tail}
 
+    positions = lens[:, None]  # [B,1]
+    reach = page_table.shape[1] * page
+    tables = family.decode_tables(cfg, reach) if family.decode_tables else None
     page_ids = jnp.take_along_axis(page_table, (lens // page)[:, None], axis=1)[:, 0]
     offsets = lens % page
-    attn_lens = lens
+    advancing = jnp.ones((b,), bool) if write_mask is None else write_mask
+    per = family.state.per if conv is not None else None   # the kind of state the caller handed in
+    if per == "page":
+        # the tail's column this position is (negative: none); a row with nothing to leave indexes past the pool
+        column = offsets - (page - tail.shape[2])
+        tail_ids = jnp.where(advancing & (column >= 0), page_ids, tail.shape[1])
+        column = jnp.maximum(column, 0)
+    attn_lens, valid = lens, None
     if write_mask is not None:
         page_ids = jnp.where(write_mask, page_ids, 0)
         offsets = jnp.where(write_mask, offsets, 0)
-        # a row that does not advance — a free slot, whose carried ``lens``
-        # is its last request's, or one frozen mid-tick — keeps nothing of
-        # this step: its attention reads one block, not its whole length
-        # (the decode kernel's cost is the blocks a row's ``lens`` names)
-        attn_lens = jnp.where(write_mask, lens, 0)
-
-    x = L.embed(params["embed_tokens"], tok[:, None], dt)  # [B,1,d]
-    for i in range(cfg.n_layers):
-        lp = params[f"layers_{i}"]
-        xn = L.rmsnorm(lp["attn_norm"], x, cfg.norm_eps)
-        q, k, v = qkv_proj(lp["attn"], cfg, xn)
-        q = L.apply_rope(q, positions, cos, sin)
-        k = L.apply_rope(k, positions, cos, sin)
-
-        k_pages, v_pages = _write_kv(k_pages, v_pages, i, page_ids, offsets, k, v, dt, write_impl)
-
-        # the attention takes the pool whole and the layer's index: a
-        # pages[i] handed to a kernel is a copy of the layer's every page
-        impl = attn_impl or _paged_attn_xla
-        out = impl(q, k_pages, v_pages, i, page_table, attn_lens, h // hkv)
-        x = x + L.dense(lp["attn"]["wo"], out.reshape(b, 1, h * hd), dt)
-
-        xm = L.rmsnorm(lp["mlp_norm"], x, cfg.norm_eps)
-        if "moe" in lp:
-            # routed-expert family (models/moe.py): frozen/free rows are
-            # masked out of routing so they claim no expert capacity
-            from sentio_tpu.models.moe import moe_mlp
-
-            routed, _ = moe_mlp(
-                lp["moe"], cfg, xm,
-                None if write_mask is None else write_mask[:, None],
-            )
-            x = x + routed
-        else:
-            gate = jax.nn.silu(L.dense(lp["mlp"]["w_gate"], xm, dt))
-            x = x + L.dense(lp["mlp"]["w_down"], gate * L.dense(lp["mlp"]["w_up"], xm, dt), dt)
-
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.dense(params["lm_head"], x, dt)[:, 0]
-    out = logits.astype(jnp.float32), k_pages, v_pages
-    return (*out, None) if return_routed else out
-
-
-def _paged_decode_parallel(params, cfg, tok, lens, page_table, k_pages, v_pages,
-                           attn_impl, write_mask, write_impl=None):
-    """:func:`paged_decode_forward` for the parallel block of
-    ``models/cohere2_moe.py``: ``h = LN(x); x += Attn_i(h) + Experts(h)``,
-    layer ``i`` rotated and windowed or neither by its kind, the head the
-    embedding. → (logits [B, V], k_pages, v_pages, routed). A window
-    reaches the attention as ``window=``: the Pallas walk then starts at the
-    window's first block, the gather path masks."""
-    import jax
-    import jax.numpy as jnp
-
-    from sentio_tpu.models import layers as L
-    from sentio_tpu.models.cohere2_moe import centred_norm, head_logits, qk_rotated
-    from sentio_tpu.models.moe import expert_layer
-
-    dt = cfg.jdtype
-    b = tok.shape[0]
-    page = _page_dim(k_pages)
-    positions = lens[:, None]
-    page_ids = jnp.take_along_axis(page_table, (lens // page)[:, None], axis=1)[:, 0]
-    offsets = lens % page
-    attn_lens, valid = lens, None
-    if write_mask is not None:  # as in the sequential block, above
-        page_ids = jnp.where(write_mask, page_ids, 0)
-        offsets = jnp.where(write_mask, offsets, 0)
+        # a row that does not advance (a free slot, one frozen mid-tick) keeps
+        # nothing of this step: its attention reads one block, not its whole
+        # length (the decode kernel's cost is the blocks a row's ``lens`` names)
         attn_lens = jnp.where(write_mask, lens, 0)
         valid = write_mask[:, None]
-    impl = attn_impl or _paged_attn_xla
 
-    x = L.embed(params["embed_tokens"], tok[:, None], dt)
-    picks, counts = [], jnp.zeros((4,), jnp.int32)
+    def attend(q, k, v, layer, window=None, scope=None):
+        pool["k"], pool["v"] = _write_kv(pool["k"], pool["v"], layer, page_ids, offsets, k, v, dt, write_impl)
+        # (the pool whole and the layer's index: a pages[i] handed to a kernel is a copy of the layer)
+        windowed = {} if window is None else {"window": window}   # an impl that knows no window is never told of one
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            return (attn_impl or _paged_attn_xla)(q, pool["k"], pool["v"], layer, page_table, attn_lens,
+                                                  cfg.n_heads // cfg.n_kv_heads, **windowed)
+
+    def attend_latent(latent, queries, layer, sm_scale):
+        pool["k"] = _latent_write(pool["k"], layer, page_ids, offsets, latent)
+        q_lat, q_pe = queries()
+        with jax.named_scope("attn.latent"):
+            return (attn_impl or _latent_attn_xla)(q_lat, q_pe, pool["k"], layer, page_table, attn_lens, sm_scale)
+
+    def advance_paged(j, step_fn):
+        held = pool["conv"]
+        out, shifted = step_fn(held[j], None)
+        pool["conv"] = held.at[j].set(jnp.where(advancing[:, None, None], shifted, held[j]))
+        pool["tail"] = pool["tail"].at[j, tail_ids, column].set(shifted[:, -1], mode="drop")
+        return out
+
+    def advance_snapshot(j, step_fn):
+        state = pool["conv"]
+        if ssm_impl is None:
+            out, after = step_fn({name: s[j] for name, s in state.items()}, None)
+        else:   # the kernel takes all blocks' states and the mask, and hands them back updated
+            out, after = step_fn({"conv": state["conv"][j], "ssm": state["ssm"]},
+                                 lambda ssm, *terms: ssm_impl(ssm, j, advancing, *terms))
+            state["ssm"] = after.pop("ssm")
+        for name in after:
+            held = state[name]
+            rows = advancing.reshape(b, *([1] * (held.ndim - 2)))
+            state[name] = held.at[j].set(jnp.where(rows, after[name].astype(held.dtype), held[j]))
+        return out
+
+    picks, counts = {}, []   # a kind of pick → a layer's [B, k] each; the [4] counts, where the family has them
+
+    def note(chosen, n):
+        for name, value in chosen.items():
+            picks.setdefault(name, []).append(value[:, 0])
+        counts[0] = counts[0] + n
+
+    if per == "snapshot":
+        pool["conv"] = dict(conv)
+    advance = {"page": advance_paged, "snapshot": advance_snapshot}.get(per)
+    step = DecodeStep(positions=positions, valid=valid, tables=tables,
+                      attend=attend_latent if family.latent else attend, advance=advance, note=note)
+
+    x = L.embed(params["embed_tokens"], tok[:, None], dt)  # [B,1,d]
+    if family.picks is not None:
+        counts.append(jnp.zeros((4,), jnp.int32))
     for i in range(cfg.n_layers):
-        lp = params[f"layers_{i}"]
-        h = centred_norm(lp["norm"], x, cfg.norm_eps)
-        q, k, v = qkv_proj(lp["attn"], cfg, h)
-        q, k = qk_rotated(cfg, i, q, k, positions)
-        k_pages, v_pages = _write_kv(k_pages, v_pages, i, page_ids, offsets, k, v, dt, write_impl)
-        with jax.named_scope("attn.window" if cfg.window(i) else "attn.full"):
-            attn = impl(q, k_pages, v_pages, i, page_table, attn_lens,
-                        cfg.n_heads // cfg.n_kv_heads, window=cfg.window(i))
-        # a row that does not advance is routed nowhere: it would touch
-        # experts (bytes) for a token nobody reads
-        routed, chosen, n = expert_layer(lp["moe"], cfg, h, valid)
-        x = x + L.dense(lp["attn"]["wo"], attn.reshape(b, 1, -1), dt) + routed
-        picks.append(chosen[:, 0])
-        counts = counts + n
-    logits = head_logits(params, cfg, x)[:, 0]
-    return logits, k_pages, v_pages, {"experts": jnp.stack(picks), "counts": counts}
+        x = family.decode_layer(params[f"layers_{i}"], cfg, i, x, step)
+    logits = family.head(params, cfg, x)
+
+    # three results; the picks a fourth where asked for; six for a family that was handed its state
+    routed = {**{name: jnp.stack(value) for name, value in picks.items()}, "counts": counts[0]} if counts else None
+    out = logits, pool["k"], pool["v"]
+    if conv is not None:
+        return (*out, routed, pool["conv"], pool["tail"])
+    return (*out, routed) if return_routed else out
 
 
 def _latent_attn_xla(q_lat, q_pe, pages, layer, page_table, lens, sm_scale):
@@ -581,201 +521,6 @@ def _latent_attn_xla(q_lat, q_pe, pages, layer, page_table, lens, sm_scale):
     latents = _latent_tokens(pages, (layer, page_table))
     seen = jnp.arange(latents.shape[1])[None, :] <= lens[:, None]  # new token sits at index lens
     return latent_attention(q_lat, q_pe, latents, seen, sm_scale)
-
-
-def _paged_decode_latent(params, cfg, tok, lens, page_table, pages, attn_impl, write_mask):
-    """:func:`paged_decode_forward` for the latent family of
-    ``models/deepseek_v2.py``: sequential pre-norm blocks; the step's latent
-    (``c_kv | k_pe``, normed and rotated) written into each row's current
-    page, the query ABSORBED (``W_uk`` into the query, ``W_uv`` into the
-    output) so that attention reads the latents themselves and no key or
-    value is ever formed; a dense MLP in the leading layers, the routed
-    layer's share in the others. → (logits [B, V], pages, None, routed)."""
-    import jax
-    import jax.numpy as jnp
-
-    from sentio_tpu.models import deepseek_v2 as M
-    from sentio_tpu.models import layers as L
-
-    dt = cfg.jdtype
-    b = tok.shape[0]
-    page = pages.shape[-1]
-    positions = lens[:, None]
-    page_ids = jnp.take_along_axis(page_table, (lens // page)[:, None], axis=1)[:, 0]
-    offsets = lens % page
-    attn_lens, valid = lens, None
-    if write_mask is not None:  # as in the sequential block, above
-        page_ids = jnp.where(write_mask, page_ids, 0)
-        offsets = jnp.where(write_mask, offsets, 0)
-        attn_lens = jnp.where(write_mask, lens, 0)
-        valid = write_mask[:, None]
-    impl = attn_impl or _latent_attn_xla
-
-    x = L.embed(params["embed_tokens"], tok[:, None], dt)
-    picks: dict = {"experts": [], "groups": []}
-    counts = jnp.zeros((4,), jnp.int32)
-    for i in range(cfg.n_layers):
-        lp = params[f"layers_{i}"]
-        ap = lp["attn"]
-        h = L.rmsnorm(lp["attn_norm"], x, cfg.norm_eps)
-        q_nope, q_pe = M.mla_query(ap, cfg, h, positions)
-        latent = M.mla_latent(ap, cfg, h, positions)[:, 0, 0]        # [B, latent_dim]
-        pages = _latent_write(pages, i, page_ids, offsets, latent)
-        q_lat = M.absorb_query(ap, cfg, q_nope[:, 0])
-        with jax.named_scope("attn.latent"):
-            o_lat = impl(q_lat, q_pe[:, 0], pages, i, page_table, attn_lens, cfg.softmax_scale)
-        attn = M.unabsorb(ap, cfg, o_lat.astype(dt))
-        x = x + L.dense(ap["wo"], attn.reshape(b, 1, -1), dt)
-        # a row that does not advance is routed nowhere (see the parallel block)
-        out, chosen, n = M.mlp_or_experts(lp, cfg, L.rmsnorm(lp["mlp_norm"], x, cfg.norm_eps), valid)
-        x = x + out
-        if chosen is not None:
-            for name, value in chosen.items():
-                picks[name].append(value[:, 0])
-            counts = counts + n
-    logits = M.head_logits(params, cfg, x)[:, 0]
-    routed = {name: jnp.stack(value) for name, value in picks.items() if value}
-    return logits, pages, None, {**routed, "counts": counts}
-
-
-def _paged_decode_conv(params, cfg, tok, lens, page_table, k_pages, v_pages, conv, tail,
-                       attn_impl, write_mask, write_impl=None):
-    """:func:`paged_decode_forward` for the family of ``models/lfm2_moe.py``:
-    sequential pre-norm blocks whose mixer is a gated short convolution over
-    the slot's carried state or attention over the pages (pool layer
-    ``cfg.attn_index(i)``), a dense MLP in the leading layers and routed
-    experts in the others. A row that advances shifts its state by this
-    token's ``z`` and, at the last two positions of its page, leaves ``z`` in
-    that page's tail; a row that does not advance (``write_mask`` false)
-    keeps its state and writes no tail. → (logits [B, V], k_pages, v_pages,
-    routed, conv, tail)."""
-    import jax
-    import jax.numpy as jnp
-
-    from sentio_tpu.models import layers as L
-    from sentio_tpu.models import lfm2_moe as M
-
-    dt = cfg.jdtype
-    b = tok.shape[0]
-    page = _page_dim(k_pages)
-    positions = lens[:, None]
-    page_ids = jnp.take_along_axis(page_table, (lens // page)[:, None], axis=1)[:, 0]
-    offsets = lens % page
-    advancing = jnp.ones((b,), bool) if write_mask is None else write_mask
-    # the tail's column this position is (negative: not one of the page's last
-    # two); a row with nothing to leave indexes past the pool, and is dropped
-    column = offsets - (page - cfg.conv_taps)
-    tail_ids = jnp.where(advancing & (column >= 0), page_ids, tail.shape[1])
-    column = jnp.maximum(column, 0)
-    attn_lens, valid = lens, None
-    if write_mask is not None:  # as in the sequential block, above
-        page_ids = jnp.where(write_mask, page_ids, 0)
-        offsets = jnp.where(write_mask, offsets, 0)
-        attn_lens = jnp.where(write_mask, lens, 0)
-        valid = write_mask[:, None]
-    impl = attn_impl or _paged_attn_xla
-
-    x = L.embed(params["embed_tokens"], tok[:, None], dt)
-    picks, counts = [], jnp.zeros((4,), jnp.int32)
-    for i in range(cfg.n_layers):
-        lp = params[f"layers_{i}"]
-        u = L.rmsnorm(lp["op_norm"], x, cfg.norm_eps)
-        if cfg.kinds[i] == M.CONV:
-            j = cfg.conv_index(i)
-            # a segment of one token: the state shifted by this token's z
-            out, shifted, _ = M.conv_segment(lp["conv"], cfg, u, conv[j], None)
-            conv = conv.at[j].set(jnp.where(advancing[:, None, None], shifted, conv[j]))
-            tail = tail.at[j, tail_ids, column].set(shifted[:, -1], mode="drop")
-        else:
-            a = cfg.attn_index(i)
-            q, k, v = M.qk_normed(lp["attn"], cfg, u, positions)
-            k_pages, v_pages = _write_kv(k_pages, v_pages, a, page_ids, offsets, k, v, dt, write_impl)
-            with jax.named_scope("attn.full"):
-                attn = impl(q, k_pages, v_pages, a, page_table, attn_lens, cfg.n_heads // cfg.n_kv_heads)
-            out = L.dense(lp["attn"]["wo"], attn.reshape(b, 1, -1), dt)
-        x = x + out
-        # a row that does not advance is routed nowhere (see the parallel block)
-        out, chosen, n = M.mlp_or_experts(lp, cfg, L.rmsnorm(lp["ffn_norm"], x, cfg.norm_eps), valid)
-        x = x + out
-        if chosen is not None:
-            picks.append(chosen[:, 0])
-            counts = counts + n
-    logits = M.head_logits(params, cfg, x)[:, 0]
-    routed = {"experts": jnp.stack(picks)} if picks else {}
-    return logits, k_pages, v_pages, {**routed, "counts": counts}, conv, tail
-
-
-def _paged_decode_ssm(params, cfg, tok, lens, page_table, k_pages, v_pages, state, snaps,
-                      attn_impl, write_mask, write_impl=None, ssm_impl=None):
-    """:func:`paged_decode_forward` for the family of ``models/nemotron_h.py``:
-    blocks of ONE operator each — a Mamba-2 update of the slot's carried state
-    (``state = {"conv": [Lm, B, 3, C], "ssm": [Lm, B, H, P, N]}``), rotation-free
-    attention over the pages (pool layer ``cfg.attn_index(i)``) or routed
-    experts. A row that does not advance (``write_mask`` false) keeps its
-    state. ``state["ssm"]`` is written through ``ssm_impl`` where the engine
-    bound one (``kernels/ssm_update.py``, by its ``ssm_update_path``: a
-    float32 state in whole tiles on one device — the advancing rows' state
-    read once and written in place, no byte of the others moved), else by
-    ``mamba_step``'s own sum, a ``where`` against the mask and ``.at[j].set``,
-    as ``state["conv"]`` is either way. Decode writes no snapshot: ``snaps``
-    goes through as it came. → (logits [B, V], k_pages, v_pages, routed,
-    state, snaps)."""
-    import jax
-    import jax.numpy as jnp
-
-    from sentio_tpu.models import layers as L
-    from sentio_tpu.models import nemotron_h as M
-    from sentio_tpu.models.moe import expert_layer
-
-    dt = cfg.jdtype
-    b = tok.shape[0]
-    page = _page_dim(k_pages)
-    page_ids = jnp.take_along_axis(page_table, (lens // page)[:, None], axis=1)[:, 0]
-    offsets = lens % page
-    advancing = jnp.ones((b,), bool) if write_mask is None else write_mask
-    attn_lens, valid = lens, None
-    if write_mask is not None:  # as in the sequential block, above
-        page_ids = jnp.where(write_mask, page_ids, 0)
-        offsets = jnp.where(write_mask, offsets, 0)
-        attn_lens = jnp.where(write_mask, lens, 0)
-        valid = write_mask[:, None]
-    impl = attn_impl or _paged_attn_xla
-
-    x = L.embed(params["embed_tokens"], tok[:, None], dt)
-    state = dict(state)
-    picks, counts = [], jnp.zeros((4,), jnp.int32)
-    for i, kind in enumerate(cfg.pattern):
-        lp = params[f"layers_{i}"]
-        u = L.rmsnorm(lp["norm"], x, cfg.norm_eps)
-        if kind == M.MAMBA:
-            j = cfg.ssm_index(i)
-            if ssm_impl is None:
-                out, after = M.mamba_step(lp["mamba"], cfg, u, {name: s[j] for name, s in state.items()})
-            else:   # the kernel takes all blocks' states and the mask, and hands them back updated
-                out, after = M.mamba_step(
-                    lp["mamba"], cfg, u, {"conv": state["conv"][j], "ssm": state["ssm"]},
-                    update=lambda ssm, *step, j=j: ssm_impl(ssm, j, advancing, *step))
-                state["ssm"] = after.pop("ssm")
-            for name in after:
-                s = state[name]
-                keep = advancing.reshape(b, *([1] * (s.ndim - 2)))
-                state[name] = s.at[j].set(jnp.where(keep, after[name].astype(s.dtype), s[j]))
-        elif kind == M.ATTENTION:
-            a = cfg.attn_index(i)
-            q, k, v = M.plain_qkv(lp["attn"], cfg, u, None)
-            k_pages, v_pages = _write_kv(k_pages, v_pages, a, page_ids, offsets, k, v, dt, write_impl)
-            with jax.named_scope("attn.full"):
-                attn = impl(q, k_pages, v_pages, a, page_table, attn_lens, cfg.n_heads // cfg.n_kv_heads)
-            out = L.dense(lp["attn"]["wo"], attn.reshape(b, 1, -1), dt)
-        else:
-            # a row that does not advance is routed nowhere (see the parallel block)
-            out, chosen, n = expert_layer(lp["moe"], cfg, u, valid)
-            picks.append(chosen[:, 0])
-            counts = counts + n
-        x = x + out
-    logits = M.head_logits(params, cfg, x)[:, 0]
-    routed = {"experts": jnp.stack(picks)} if picks else {}
-    return logits, k_pages, v_pages, {**routed, "counts": counts}, state, snaps
 
 
 def scatter_prefill(k_pages, v_pages, k_cache, v_cache, page_table):
@@ -1015,23 +760,19 @@ class ContinuousBatchingEngine:
         prefix_cache: bool = True,
         ssm_snapshots: int = 64,
     ) -> None:
-        """``forward_fn`` swaps the prefill model family (llama_forward
-        contract); the fused decode tick detects the family per layer (a
-        ``moe`` subtree routes through models/moe.py). ``ssm_snapshots``: the
+        """``forward_fn`` swaps the prefill forward (llama_forward contract)
+        of a family that lets it; the fused decode tick walks the layers of
+        the configuration's family (``models/families.py``). ``ssm_snapshots``: the
         states a family with Mamba layers keeps for the prefix cache
         (``PagedPool``; ``SSM_SNAPSHOTS``), ignored by every other."""
         import jax
 
-        from sentio_tpu.models.cohere2_moe import Cohere2MoeConfig, cohere2_forward
-        from sentio_tpu.models.deepseek_v2 import DeepseekV2Config, deepseek_v2_forward
-        from sentio_tpu.models.lfm2_moe import Lfm2MoeConfig, lfm2_forward
-        from sentio_tpu.models.llama import llama_forward
-        from sentio_tpu.models.moe import MoeConfig, expert_tiles, moe_serving_forward
-        from sentio_tpu.models.nemotron_h import NemotronHConfig, nemotron_h_forward
-
         from sentio_tpu.models.tokenizer import ByteTokenizer
 
         self.cfg = model_config or LlamaConfig.tiny()
+        # what the engine asks of the configuration's family (``models/families.py``)
+        family = self.family = family_of(self.cfg)
+        cfg_name = type(self.cfg).__name__
         explicit_params = params
         if params is None:
             # seeded init of the configuration's family, placed by its rules
@@ -1040,7 +781,6 @@ class ContinuousBatchingEngine:
             params = load_decoder(
                 mesh=mesh, model_config=self.cfg, rng_seed=rng_seed).params
         self.tokenizer = tokenizer or ByteTokenizer(self.cfg.vocab_size)
-        is_moe = isinstance(self.cfg, MoeConfig)
         # the tree the compiled programs read (models/llama.py
         # ``serving_layout``): made here once from a canonical tree, passed
         # through untouched — the same object, so replicas and
@@ -1061,86 +801,47 @@ class ContinuousBatchingEngine:
             # mesh the caller has placed the tree by its sharding rules.
             params = jax.device_put(params)
         self.params = params
-        # a family whose expert layers hand back what they decided (their
-        # picks, and the pairs they routed: ``models/moe.py::expert_layer``)
-        family_forward = {Cohere2MoeConfig: cohere2_forward,
-                          DeepseekV2Config: deepseek_v2_forward,
-                          Lfm2MoeConfig: lfm2_forward,
-                          NemotronHConfig: nemotron_h_forward}.get(type(self.cfg))
-        self.routed = family_forward is not None
-        # a family whose pool holds ONE latent a token and layer in place of K and V
-        self.latent = is_latent(self.cfg)
-        # a family whose convolution layers carry state per slot and per page
-        # beside the pool (``PagedPool.conv``, ``.tail``)
-        self.conv_state = has_conv_state(self.cfg)
-        # a family whose Mamba layers carry a matrix state per slot, and in a
-        # bounded pool of snapshots the radix cache hands out (the same two
-        # names of the pool, as dicts)
-        self.ssm_state = has_ssm_state(self.cfg)
-        # either: state beside the pages, threaded through the four programs
-        self.slot_state = self.conv_state or self.ssm_state
-        if self.slot_state:
-            from sentio_tpu.runtime.paged_spec import refuse_recurrent_state
-
-            if draft_params is not None:
-                refuse_recurrent_state(self.cfg)
-            if mesh is not None:
-                what, where = ("convolution", "page") if self.conv_state else ("Mamba", "snapshot")
-                raise ValueError(f"a family with {what} state ({type(self.cfg).__name__}) is served on "
-                                 f"one device a process: the state per slot and per {where} has no rule for "
-                                 "a mesh yet")
-        if self.ssm_state:
-            if kv_quant != "none":
-                raise ValueError(f"kv_quant={kv_quant!r}: K and V are a thirtieth of what a sequence of "
-                                 f"{type(self.cfg).__name__} keeps (its Mamba state is float32, as the model "
-                                 "card advises): int8 pages beside it have no quality gate and nothing to save")
-            if page_size % self.cfg.chunk_size:
-                raise ValueError(f"page_size={page_size}: a snapshot is the scan's state at a chunk boundary, "
-                                 f"so a page is whole chunks of {self.cfg.chunk_size} tokens")
-        if self.routed:
-            name = type(self.cfg).__name__
-            if forward_fn not in (None, family_forward):
-                raise ValueError(f"a {name} model prefills through {family_forward.__name__}")
-            if draft_params is not None:
-                raise ValueError(f"paged speculation does not serve a routed family ({name}) yet")
-            forward_fn = family_forward
-        if self.latent:
-            if kv_quant != "none":
-                raise ValueError(f"kv_quant={kv_quant!r}: a latent pool ({type(self.cfg).__name__}) is "
-                                 "bf16 — int8 latents have no kernel and no quality gate yet")
-            if mesh is not None:
-                raise ValueError(f"a latent pool ({type(self.cfg).__name__}) is served on one device a "
-                                 "process: a latent has no heads to split over tp")
-        # what a routed family's layers decide by rank, and how deep: a
-        # request's ``choices`` hold one buffer a kind, its routed layers deep
-        self._choice_depths = {}
+        # its record's facts, as the host code reads them: expert layers that
+        # hand back their picks and pairs; ONE latent a token and layer in
+        # place of K and V; state beside the pool (``PagedPool.conv``,
+        # ``.tail``) threaded through the four programs, per slot and per page
+        # or per slot and in a bounded pool of snapshots
+        self.routed = family.picks is not None
+        self.latent = family.latent
+        self.slot_state = family.state is not None
+        self.conv_state = self.slot_state and family.state.per == "page"
+        self.ssm_state = self.slot_state and family.state.per == "snapshot"
+        # what the family is not served with, and its reason
+        for what, asked_for in (("draft", draft_params is not None), ("mesh", mesh is not None),
+                                ("int8", kv_quant != "none")):
+            if asked_for and (reason := family.refusal(what, self.cfg)):
+                raise ValueError(f"kv_quant={kv_quant!r}: {reason}" if what == "int8" else reason)
+        if self.ssm_state and page_size % family.state.page_tokens(self.cfg):
+            raise ValueError(f"page_size={page_size}: a snapshot is the scan's state at a chunk boundary, "
+                             f"so a page is whole chunks of {family.state.page_tokens(self.cfg)} tokens")
+        # a kind of choice → how many a token: a request's ``choices`` hold one buffer a kind
+        self._choice_depths = family.picks(self.cfg) if self.routed else {}
         # how the decode program's grouped expert matmuls are tiled: decided
         # from shapes when it is traced (``models/moe.py::expert_tile``), so
         # said once, here and in ``stats()``
         self._expert_tiles = None
-        if self.routed:
+        if family.expert_tiles is not None:
             layer = next(lp["moe"] for lp in self.params.values() if isinstance(lp, dict) and "moe" in lp)
-            self._expert_tiles = expert_tiles(layer, self.cfg, max_slots)
+            self._expert_tiles = family.expert_tiles(layer, self.cfg, max_slots)
             logging.getLogger(__name__).info(
                 "grouped expert matmuls of a decode step, [rows, tk, tn] and grid steps an expert: %s",
                 ", ".join(f"{name} {t['tile']} x{t['steps_per_expert']}"
                           for name, t in self._expert_tiles.items()))
-            self._choice_depths["experts"] = self.cfg.experts_per_token
-            if getattr(self.cfg, "n_group", 1) > 1:
-                self._choice_depths["groups"] = self.cfg.topk_group
         if forward_fn is None:
-            forward_fn = moe_serving_forward if is_moe else llama_forward
-        elif forward_fn in (moe_serving_forward, llama_forward):
-            if (forward_fn is moe_serving_forward) != is_moe:
+            forward_fn = family.forward
+        elif forward_fn is not family.forward:
+            if self.routed:
+                raise ValueError(f"a {cfg_name} model prefills through {family.forward.__name__}")
+            if explicit_params is None:
                 raise ValueError(
-                    f"forward_fn {forward_fn.__name__} does not match the "
-                    f"{type(self.cfg).__name__} model family"
-                )
-        elif explicit_params is None:
-            raise ValueError(
-                "forward_fn overrides the model family; pass matching params "
-                "explicitly (the default init builds the config family's tree)"
-            )
+                    f"forward_fn {getattr(forward_fn, '__name__', forward_fn)} does not match the {cfg_name} "
+                    f"model family ({family.forward.__name__}): pass matching params explicitly (the default "
+                    "init builds the config family's tree)")
         self.forward_fn = forward_fn
         self.max_slots = max_slots
         self.page_size = page_size
@@ -1485,8 +1186,7 @@ class ContinuousBatchingEngine:
             from sentio_tpu.kernels.prefill_attention import make_prefill_attn_fn, prefill_untiled
 
             why = ""
-            if self.forward_fn not in (llama_forward, moe_serving_forward, cohere2_forward,
-                                       deepseek_v2_forward, lfm2_forward, nemotron_h_forward):
+            if self.forward_fn is not family.forward:
                 why = "the caller brought its own forward_fn"
             elif mesh is not None:
                 why = "the prefill kernel runs on one device a process, and this engine has a mesh"
@@ -1516,9 +1216,11 @@ class ContinuousBatchingEngine:
 
         ignore_eos = self.ignore_eos
         routed = self.routed
-        # a forward that computes its head at ONE position a row where asked
-        # (``models/nemotron_h.py``): an admission reads no other logit
-        last_only = getattr(forward_fn, "takes_logits_at", False)
+        # a forward that computes its head at ONE position a row where asked: an admission reads no other
+        family = self.family
+        # (only of the family's forward called bare: one the prefill kernel is bound into, a ``partial``,
+        # keeps its head at every position until ROADMAP S15 turns that on with a measurement of its own)
+        last_only = family.logits_at and forward_fn is family.forward
 
         def last_logits(logits, lens):
             """[B, V] at each row's last token, of all positions' or of that one's."""
@@ -1739,24 +1441,10 @@ class ContinuousBatchingEngine:
         page_size, max_slots = self.page_size, self.max_slots
 
         def new_cache(rows, length, pages=0):
-            """The contiguous cache a prefill fills: K and V, or latents alone,
-            or K and V of the attention layers beside the convolution state
-            (``pages``: the new tokens' pages, whose tails the forward leaves)."""
-            if conv_state:
-                from sentio_tpu.models.lfm2_moe import init_lfm2_cache
-
-                return init_lfm2_cache(cfg, rows, length, pages)
-            if ssm_state:
-                from sentio_tpu.models.nemotron_h import init_nemotron_cache
-
-                return init_nemotron_cache(cfg, rows, length, SNAPS_PER_ROW)
-            if latent:
-                from sentio_tpu.models.deepseek_v2 import init_latent_cache
-
-                return init_latent_cache(cfg, rows, length)
-            from sentio_tpu.models.llama import init_cache
-
-            return init_cache(cfg, rows, length)
+            """The contiguous cache a prefill fills, as the family makes it
+            (``pages``: the new tokens' pages, whose tails its forward leaves)."""
+            beside = {"page": (pages,), "snapshot": (SNAPS_PER_ROW,)}[family.state.per] if family.state else ()
+            return family.init_cache(cfg, rows, length, *beside)
 
         self._prefill_scatter = prefill_scatter
 

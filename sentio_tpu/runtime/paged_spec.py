@@ -59,20 +59,16 @@ from sentio_tpu.analysis.audit.registry import jit_family
 
 
 def refuse_recurrent_state(cfg) -> None:
-    """Speculation is refused, with its reason, for a family that carries
-    RECURRENT state beside the pages (``models/lfm2_moe.py``'s convolution
-    layers, ``models/nemotron_h.py``'s Mamba layers): a verify block advances that state by k + 1 tokens, a rejected
-    draft token would have to roll it back, and nothing here keeps the state
-    to roll back to (K and V need no such thing: a rejected position is
-    simply overwritten)."""
-    from sentio_tpu.runtime.paged import has_conv_state, has_ssm_state
+    """Speculation is refused, with its record's reason (``models/families.py``),
+    for a family not served with a draft — above all one with RECURRENT state
+    beside the pages: a verify block advances it by k + 1 tokens and nothing
+    here keeps the state a rejected token would roll back to (K and V need no
+    such thing: a rejected position is simply overwritten)."""
+    from sentio_tpu.models.families import family_of
 
-    if has_conv_state(cfg) or has_ssm_state(cfg):
-        what = "convolution" if has_conv_state(cfg) else "Mamba"
-        raise ValueError(
-            f"paged speculation does not serve a family with recurrent state ({type(cfg).__name__}): a "
-            f"rejected draft token would have to roll the {what} state back, and the tick keeps "
-            "no state to roll back to")
+    reason = family_of(cfg).refusal("draft", cfg)
+    if reason:
+        raise ValueError(reason)
 
 
 def accept_and_correct(rng, drafts, qdists, tprobs):

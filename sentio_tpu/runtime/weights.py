@@ -25,20 +25,13 @@ import logging
 from dataclasses import dataclass
 from typing import Any, Optional
 
+from sentio_tpu.models import families
 from sentio_tpu.runtime.checkpoint import CheckpointError, load_pytree
 
 logger = logging.getLogger(__name__)
 
-_FAMILY_CONFIGS = {
-    "llama": ("sentio_tpu.models.llama", "LlamaConfig"),
-    "moe": ("sentio_tpu.models.moe", "MoeConfig"),
-    "cohere2_moe": ("sentio_tpu.models.cohere2_moe", "Cohere2MoeConfig"),
-    "deepseek_v2": ("sentio_tpu.models.deepseek_v2", "DeepseekV2Config"),
-    "lfm2_moe": ("sentio_tpu.models.lfm2_moe", "Lfm2MoeConfig"),
-    "nemotron_h": ("sentio_tpu.models.nemotron_h", "NemotronHConfig"),
-    "encoder": ("sentio_tpu.models.transformer", "EncoderConfig"),
-    "cross-encoder": ("sentio_tpu.models.transformer", "EncoderConfig"),
-}
+# the families that are no decoder (those are ``models/families.py``'s)
+_ENCODER_FAMILIES = ("encoder", "cross-encoder")
 
 
 class WeightsError(Exception):
@@ -72,24 +65,13 @@ def load_model(
     if not cfg_dict:
         raise WeightsError(f"checkpoint {checkpoint_path!r} has no config in meta")
     lookup = family or expect_family
-    if lookup not in _FAMILY_CONFIGS:
+    if lookup in _ENCODER_FAMILIES:
+        from sentio_tpu.models.transformer import EncoderConfig as cfg_cls
+    elif lookup in families.names():
+        cfg_cls = families.family(lookup).config
+    else:
         raise WeightsError(f"unknown model family {lookup!r} in {checkpoint_path!r}")
-    mod_name, cls_name = _FAMILY_CONFIGS[lookup]
-    import importlib
-
-    cfg_cls = getattr(importlib.import_module(mod_name), cls_name)
-    # tuples serialize as lists in JSON meta; convert back for fields whose
-    # annotation is a tuple type so frozen configs stay hashable
-    fields = {f.name: f.type for f in cfg_cls.__dataclass_fields__.values()}  # type: ignore[attr-defined]
-    kwargs = {}
-    for k, v in cfg_dict.items():
-        if k not in fields:
-            continue
-        ann = str(fields[k]).lower()
-        if isinstance(v, list) and ("tuple" in ann):
-            v = tuple(v)
-        kwargs[k] = v
-    model_config = cfg_cls(**kwargs)
+    model_config = families.rebuild_config(cfg_cls, cfg_dict)
 
     tokenizer = None
     if tokenizer_path:
@@ -124,9 +106,10 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
                  mmap: bool = False) -> Decoder:
     """Checkpoint or seeded init → which family → placed on the device.
 
-    With ``cfg.checkpoint_path`` the weights, their configuration (llama,
-    moe, cohere2_moe, deepseek_v2, lfm2_moe or nemotron_h, from the checkpoint's meta) and, with ``cfg.tokenizer_path``, the
-    tokenizer come from the checkpoint. Without one the weights are the
+    With ``cfg.checkpoint_path`` the weights, their configuration (of the
+    decoder family the checkpoint's meta names: ``models/families.py`` has
+    them) and, with ``cfg.tokenizer_path``, the tokenizer come from the
+    checkpoint. Without one the weights are the
     seeded random init of ``model_config``'s family (the deterministic
     fake-model mode of tests and offline development; ``cfg.model_preset``
     picks the configuration when none is given) under a byte tokenizer.
@@ -134,24 +117,15 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
     the SERVING tree (``models/llama.py::serving_layout``: ``attn.wq_t``,
     ``wk_t``, ``wv_t`` stored [out, in] where a checkpoint holds ``wq``,
     ``wk``, ``wv``) and goes
-    to its final placement ONCE: by ``LLAMA_TP_RULES`` / ``MOE_EP_RULES``
-    under a mesh, onto the default device without one. ``mmap`` maps the
+    to its final placement ONCE: by its family's ``mesh_rules`` under a mesh,
+    onto the default device without one. ``mmap`` maps the
     checkpoint's leaves in place (worker processes on one host share one
     page-cache copy)."""
     import jax
 
-    from sentio_tpu.models.cohere2_moe import Cohere2MoeConfig, init_cohere2_moe
-    from sentio_tpu.models.deepseek_v2 import DeepseekV2Config, init_deepseek_v2
-    from sentio_tpu.models.lfm2_moe import Lfm2MoeConfig, init_lfm2_moe
-    from sentio_tpu.models.llama import LlamaConfig, init_llama, serving_layout
-    from sentio_tpu.models.moe import MoeConfig, init_moe
-    from sentio_tpu.models.nemotron_h import NemotronHConfig, init_nemotron_h
+    from sentio_tpu.models.llama import LlamaConfig, serving_layout
     from sentio_tpu.models.tokenizer import ByteTokenizer
-    from sentio_tpu.parallel.sharding import (
-        LLAMA_TP_RULES,
-        MOE_EP_RULES,
-        shard_params,
-    )
+    from sentio_tpu.parallel.sharding import shard_params
 
     params = tokenizer = None
     if cfg is not None and cfg.checkpoint_path:
@@ -162,35 +136,22 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
             raise WeightsError(
                 f"checkpoint {cfg.checkpoint_path!r} holds a "
                 f"{type(model_config).__name__} model — the generator "
-                "serves decoder families (llama, moe, cohere2_moe, deepseek_v2, lfm2_moe, nemotron_h)"
+                f"serves decoder families ({', '.join(families.names())})"
             )
     if model_config is None:
         preset = cfg.model_preset if cfg is not None else "tiny"
         model_config = (LlamaConfig.tiny() if preset == "tiny"
                         else LlamaConfig.llama3_8b())
-    is_moe = isinstance(model_config, MoeConfig)
-    share_init = {Cohere2MoeConfig: init_cohere2_moe,
-                  DeepseekV2Config: init_deepseek_v2,
-                  Lfm2MoeConfig: init_lfm2_moe,
-                  NemotronHConfig: init_nemotron_h}.get(type(model_config))
-    if share_init is not None and mesh is not None:
-        # this process IS one chip's share of a layer (``experts_held``; of
-        # ``lfm2_moe`` whole layers, with convolution state per slot and per
-        # page that no mesh has a rule for, as ``nemotron_h``'s Mamba state
-        # per slot and per snapshot has none); a mesh that splits it again
-        # has no rules yet
-        family = next(k for k, (_m, cls) in _FAMILY_CONFIGS.items()
-                      if cls == type(model_config).__name__)
-        raise WeightsError(f"a {family} model is served on one device a process")
+    family = families.family_of(model_config)
+    if mesh is not None and family.refusal("mesh", model_config):
+        # (its record says why: none of these has rules under a mesh yet)
+        raise WeightsError(f"a {family.name} model is served on one device a process")
     if params is None:
-        init = share_init or (init_moe if is_moe else init_llama)
-        params = init(jax.random.PRNGKey(rng_seed), model_config)
+        params = family.init(jax.random.PRNGKey(rng_seed), model_config)
     # q, k and v in the order the serving programs read them, made before
     # placement: a checkpoint's leaves are turned on the host and no second
     # copy of a weight ever reaches the device
-    params = shard_params(
-        serving_layout(params), mesh,
-        MOE_EP_RULES if is_moe else LLAMA_TP_RULES)
+    params = shard_params(serving_layout(params), mesh, family.mesh_rules)
     return Decoder(
         params=params, model_config=model_config,
         tokenizer=tokenizer or ByteTokenizer(model_config.vocab_size),

@@ -262,15 +262,15 @@ def default_service_factory(
     from sentio_tpu.runtime.service import PagedGenerationService
 
     from sentio_tpu.config import GeneratorConfig
-    from sentio_tpu.models.llama import LlamaConfig
-    from sentio_tpu.models.moe import MoeConfig
+    from sentio_tpu.models.families import family, rebuild_config
     from sentio_tpu.runtime.weights import load_decoder, load_model
 
+    # the family by the name the router's spec carries, its configuration
+    # from the ``asdict`` beside it (none: the family's tiny one)
     cfg = None
     if not checkpoint_path:
-        family = MoeConfig if model_family == "moe" else LlamaConfig
-        cfg = (family(**model_config) if model_config is not None
-               else LlamaConfig.tiny())
+        cls = family(model_family).config
+        cfg = rebuild_config(cls, model_config) if model_config is not None else cls.tiny()
     decoder = load_decoder(
         GeneratorConfig(checkpoint_path=checkpoint_path,
                         tokenizer_path=tokenizer_path),

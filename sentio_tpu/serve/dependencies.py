@@ -409,6 +409,7 @@ class DependencyContainer:
                 # re-registration with backoff.
                 import dataclasses as _dc
 
+                from sentio_tpu.models.families import family_of
                 from sentio_tpu.runtime.worker import (
                     ProcessReplica,
                     WorkerSpec,
@@ -476,10 +477,7 @@ class DependencyContainer:
                     # membership source, and the autoscaler's launcher —
                     # one spec recipe, three registration paths
                     return WorkerSpec(factory_kwargs=dict(
-                        model_family=(
-                            "moe" if type(decoder.model_config).__name__
-                            == "MoeConfig" else "llama"
-                        ),
+                        model_family=family_of(decoder.model_config).name,
                         model_config=(
                             None if cfg.checkpoint_path
                             else _dc.asdict(decoder.model_config)

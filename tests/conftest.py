@@ -23,6 +23,17 @@ import pytest  # noqa: E402
 
 from sentio_tpu.config import Settings, set_settings  # noqa: E402
 
+
+
+def pytest_configure(config):
+    # ``--dist loadfile`` hands files out in collection order, not by their count of tests (xdist's
+    # default, ``--no-loadscope-reorder`` its switch: set here, because an option in ``pytest.ini`` would
+    # fail a run without xdist): ``tests/benchmark/`` is collected first and holds the files of few,
+    # long tests that were otherwise handed out last and made the run's tail (ROADMAP D11)
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
 # Suites exercising the paged engine / radix cache / decode service run with
 # the runtime sanitizer armed (analysis/sanitizer.py): engine entry points
 # assert the single-driver-thread contract, annotated locks record
@@ -107,8 +118,7 @@ class CacheFreeGreedy:
         import jax
         import jax.numpy as jnp
 
-        from sentio_tpu.models.llama import llama_forward
-        from sentio_tpu.models.moe import MoeConfig, moe_serving_forward
+        from sentio_tpu.models.families import family_of
         from sentio_tpu.models.tokenizer import ByteTokenizer
         from sentio_tpu.runtime.weights import load_decoder
 
@@ -119,15 +129,14 @@ class CacheFreeGreedy:
         self.model_config = cfg = model_config
         self.tokenizer = tokenizer or ByteTokenizer(cfg.vocab_size)
         self.width = width
-        forward = (moe_serving_forward if isinstance(cfg, MoeConfig)
-                   else llama_forward)
+        forward = family_of(cfg).forward
 
         @jax.jit
         def last_logits(params, ids, n):
             # right padding sits after every real token, so causal attention
             # never reads it; the mask keeps it out of expert capacity
             real = jnp.arange(ids.shape[1])[None, :] < n
-            logits, _ = forward(params, cfg, ids, pad_mask=real)
+            logits, *_ = forward(params, cfg, ids, pad_mask=real)
             return logits[0, n - 1]
 
         self._last_logits = last_logits

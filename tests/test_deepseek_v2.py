@@ -431,7 +431,7 @@ def test_int8_pages_speculation_and_a_mesh_refuse_this_family_by_name():
         ContinuousBatchingEngine(model_config=cfg, params=tree, kv_quant="int8")
     with pytest.raises(ValueError, match="speculation does not serve a routed family .DeepseekV2Config."):
         ContinuousBatchingEngine(model_config=cfg, params=tree, draft_params=tree, draft_config=cfg)
-    with pytest.raises(ValueError, match="int8 latents and a mesh"):
+    with pytest.raises(ValueError, match="int8 latents have no kernel"):
         init_pool(cfg, 4, 8, quantized=True)
     from sentio_tpu.config import MeshConfig
     from sentio_tpu.parallel.mesh import build_mesh

@@ -77,7 +77,7 @@ def test_rows_of_another_shape_or_dtype_are_refused():
 
 
 def conv_step():
-    """``_paged_decode_conv``: 8 kv heads of 64 in a lane-packed pool, three
+    """A family with convolution state (``models/lfm2_moe.py``): 8 kv heads of 64 in a lane-packed pool, three
     rows of which the second is halted."""
     cfg = dataclasses.replace(Lfm2MoeConfig.tiny(), dim=512, n_heads=8, n_kv_heads=8)
     tree = init_lfm2_moe(jax.random.PRNGKey(0), cfg)
@@ -87,7 +87,7 @@ def conv_step():
 
 
 def ssm_step():
-    """``_paged_decode_ssm``: 2 kv heads of 128, the Mamba state beside them."""
+    """A family with Mamba state (``models/nemotron_h.py``): 2 kv heads of 128, the Mamba state beside them."""
     cfg = dataclasses.replace(NemotronHConfig.tiny(), head_dim=128)
     tree = init_nemotron_h(jax.random.PRNGKey(0), cfg)
     pool = init_pool(cfg, num_pages=8, page_size=PAGE, slots=3, snapshots=1)

@@ -319,7 +319,11 @@ class TestRowSteps:
     def test_every_tick_conserves_failed_ticks_included(self, recorder, metrics, depth):
         from sentio_tpu.infra import faults
 
-        engine = _engine(pipeline_depth=depth)
+        # ticks of four sub-steps and no longer: the longest answer is then three
+        # ticks however the three requests arrive (on a loaded host all three are
+        # in the inbox before the first tick, and ticks that grow to eight over an
+        # empty queue decode them in two: no third step to die)
+        engine = _engine(pipeline_depth=depth, max_tick_steps=4)
         svc = PagedGenerationService(engine, retry_budget=2)
         try:
             # the third step dies: at depth 2 with a tick in flight, which

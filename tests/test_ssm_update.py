@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-# ``mamba_step``'s sum and ``y`` and the masked ``.at[j].set`` of ``_paged_decode_ssm``, as the timing tool writes them
+# ``mamba_step``'s sum and ``y`` and the masked ``.at[j].set`` of ``paged_decode_forward``, as the timing tool writes them
 from sentio_tpu.eval.ssm_update_timing import xla_update as reference
 from sentio_tpu.kernels.ssm_update import make_ssm_update_impl, ssm_update, ssm_update_path
 from sentio_tpu.models.nemotron_h import NemotronHConfig, init_nemotron_h
@@ -118,7 +118,7 @@ def test_the_rule_on_the_configurations_the_repo_serves():
 
 
 def test_a_decode_step_with_the_kernel_is_the_step_without():
-    """``_paged_decode_ssm`` at a tiny width whose state is whole tiles, two
+    """``paged_decode_forward`` over a Mamba family at a tiny width whose state is whole tiles, two
     steps in a row (the second reads what the first wrote), one row halted:
     the logits and the advancing rows' state to float32 rounding, the halted
     row's state to the bit, the state float32 as it was. (A float32 model: in
