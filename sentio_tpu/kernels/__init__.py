@@ -1,7 +1,9 @@
 """Pallas/TPU kernels: flash attention (the encoders' bidirectional one and
 the causal one of training), the prefill's flash attention over a prior, ring
 (sequence-parallel) attention; the decode kernels (``paged_attention``,
-``latent_attention``) are built by the engine from their own modules.
+``latent_attention``) are built by the engine from their own modules, and a
+state-space family's (``ssm_update``, ``selective_scan``) are taken by the
+engine or by the family's module from theirs.
 
 Every kernel has an XLA counterpart (models/layers.py:attention) so the
 whole framework runs on CPU; the kernels are SELECTED on TPU from what the

@@ -195,7 +195,7 @@ def prefill_attention(
     # the query heads of one kv head are one score tile's rows: a block of
     # queries is as long as ROWS allow, a multiple of the bf16 sublane tile
     if block_q is None:
-        block_q = max(min(ROWS // group, 512), 16)
+        block_q = max(min(ROWS // group, 512) // 16 * 16, 16)   # (a group of 20 would ask for 51)
     block_q = min(block_q, -(-t // 16) * 16)
     block_k = min(block_k or BLOCK_K, -(-s // 16) * 16)
     # keys and values are read as ``[B, S, Hkv * D]`` rows: a block holds the

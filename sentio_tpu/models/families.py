@@ -28,6 +28,7 @@ _MODULES = {
     "deepseek_v2": "sentio_tpu.models.deepseek_v2",
     "lfm2_moe": "sentio_tpu.models.lfm2_moe",
     "nemotron_h": "sentio_tpu.models.nemotron_h",
+    "jamba": "sentio_tpu.models.jamba",
 }
 
 

@@ -2090,7 +2090,7 @@ class ContinuousBatchingEngine:
         its end; a prompt's last dispatch: where the same conversation comes
         back to). A boundary gets none where the pool has no slot to give
         (every one pinned or being written)."""
-        chunk, page = self.cfg.chunk_size, self.page_size
+        chunk, page = self.family.state.page_tokens(self.cfg), self.page_size
         snap = {"slot": np.full(n_rows, self.max_slots, np.int32),
                 "at": np.zeros((n_rows, SNAPS_PER_ROW), np.int32),
                 "ids": np.full((n_rows, SNAPS_PER_ROW), self._snapshots, np.int32)}

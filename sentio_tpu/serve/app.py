@@ -810,6 +810,8 @@ def _publish_serving_gauges(container: DependencyContainer):
         "max_queue", "draining",
         # static KV page-pool footprint (bytes) — halves under KV_QUANT=int8
         "pool_hbm_bytes",
+        # a family with a snapshot pool only: the slots a page boundary owns now
+        "ssm_snapshots_held",
     ):
         if key in stats:
             m.set_serving_stat(key, float(stats[key]))

@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from sentio_tpu.kernels.flash_attention import flash_attention
 from sentio_tpu.kernels.page_write import make_page_write_impl, page_write, page_write_path
 from sentio_tpu.kernels.prefill_attention import make_prefill_attn_fn, prefill_attention
+from sentio_tpu.kernels.selective_scan import selective_scan_kernel
 from sentio_tpu.kernels.ssm_update import make_ssm_update_impl, ssm_update, ssm_update_path
 from sentio_tpu.kernels.paged_attention import (
     make_paged_attn_impl,
@@ -166,6 +167,21 @@ def _ssm_update_case():
     return build
 
 
+def _selective_scan_case():
+    """The prefill's selective scan (kernels/selective_scan.py) alone: one row
+    of 512 tokens over a ``[16, 5120]`` float32 state, the state written out
+    every 16 tokens."""
+
+    def build(topo):
+        place = _on_one_chip(topo)
+        row = place((1, 512, 5120), jnp.float32)
+        cols = place((1, 512, 16), jnp.float32)
+        return (functools.partial(selective_scan_kernel, snap=16),
+                (row, row, place((16, 5120), jnp.float32), cols, cols, place((1, 16, 5120), jnp.float32)))
+
+    return build
+
+
 def _tp4_case(quant: bool, hkv: int = HKV):
     """The decode kernel inside shard_map over a tp=4 mesh of the four
     described chips: pool and query heads sharded the way init_pool and the
@@ -239,6 +255,13 @@ CASES = {
     "page-write-8kv": _page_write_case(rows=8, slots=8, nb=4),
     # the Mamba state update at the one cell that takes it
     "ssm-update-cell-nemotron": _ssm_update_case(),
+    # multi-query attention, 20 query heads on ONE kv head of 128 (the jamba cell): a page of 128 x 1
+    # vectors is eight 16-row tiles, a group that is no multiple of 8 sublanes — the decode walk over
+    # 8 slots x 40 pages, and the prefill's flash kernel for a 512-token segment behind a 40-page prior
+    "paged-bf16-20q-1kv": _paged_case(False, 128, hkv=1, heads=20, nb=40),
+    "prefill-attn-jamba-rows1": _prefill_attn_case(1, 20, 1),
+    # the selective scan of one layer over a 512-token row at the jamba cell's widths
+    "selective-scan-cell-jamba": _selective_scan_case(),
 }
 # the geometries whose pages the chip's DMA cannot bring (kernels/
 # paged_attention.py ``untiled``): XLA does not store such a pool in the
@@ -1079,6 +1102,150 @@ def test_nemotron_prefill_writes_pool_state_and_snapshots_where_they_lie(nemotro
             if m[2] not in ("parameter", "get-tuple-element", "bitcast", "tuple", "custom-call")]
     assert all(what in ("fusion:scatter", "fusion:dynamic-update-slice", "scatter", "dynamic-update-slice")
                for _n, _s, what in made), made
+
+
+# ------------------------- a family whose recurrence is a selective scan (PR 48)
+#
+# ``jamba`` (models/jamba.py) at the published widths of the benchmark's
+# ``ai21-jamba2-3b``, its first eight layers (the check's depth: seven Mamba-1
+# layers and the attention layer): the decode walk at ONE kv head under 20
+# query heads, the state per slot ``[7, 8, 16, 5120]`` float32 (``S``
+# transposed) carried through the scan and updated by XLA (``kernels/
+# ssm_update.py`` is Mamba-2's), and a 512-token prefill segment — the
+# selective scan a kernel call a Mamba layer, the flash kernel over a 40-page
+# prior bucket — that starts from a snapshot and leaves two.
+
+
+@pytest.fixture(scope="module")
+def jamba_programs(v5e):
+    from sentio_tpu.models import jamba
+    from sentio_tpu.models.jamba import JambaConfig, init_jamba, init_jamba_cache, jamba_forward
+
+    cfg = JambaConfig(n_layers=8)
+    place = _on_one_chip(v5e)
+    params = jax.eval_shape(lambda: serving_layout(init_jamba(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree_util.tree_map(
+        lambda a: place(a.shape, jnp.bfloat16 if a.ndim == 2 and a.shape[-1] > 4 and a.shape[0] > 16 else a.dtype),
+        params)
+    slots, nb, page, segment, snapshots = 8, 40, 128, 512, 64
+    # (two pool layers, as the cell's 28 layers have; these eight use the first)
+    pool = place((2, 1 + slots * nb, page, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    state = {name: place(shape, dtype) for name, (shape, dtype) in cfg.state_shapes(slots).items()}
+    snaps = {name: place(shape, dtype) for name, (shape, dtype) in cfg.state_shapes(snapshots).items()}
+    impl = make_paged_attn_impl(interpret=False)
+    # one row of lanes a position: Mosaic refuses the half-sublane slice, the scatter writes K and V; a state
+    # that is no matrix a head keeps the XLA update
+    assert page_write_path(pool) == "xla" and ssm_update_path(state["ssm"]) == "xla"
+    attn_fn = make_prefill_attn_fn(interpret=False)
+
+    def step(params, tok, lens, table, k_pages, v_pages, state):
+        def body(carry, _):
+            tok, lens, k_pages, v_pages, state = carry
+            logits, k_pages, v_pages, _, state, _ = paged_decode_forward(
+                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl,
+                write_mask=lens < nb * page - 1, conv=state)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1, k_pages, v_pages, state), None
+
+        return jax.lax.scan(body, (tok, lens, k_pages, v_pages, state), None, length=2)[0]
+
+    def prefill(params, ids, positions, lens, k_pages, v_pages, state, snaps, prior_table, n_prior, scat, snap):
+        # a segment as ``paged.prior_prefill_scatter`` runs it: K and V primed from a 40-page prior
+        # bucket, the state from a snapshot, the row's end state into its slot, two boundaries kept
+        cache = init_jamba_cache(cfg, 1, nb * page + segment, 2)
+        for name, pool_ in (("k", k_pages), ("v", v_pages)):
+            cache[name] = cache[name].at[:, :, : nb * page].set(
+                pool_[:1, prior_table].reshape(1, 1, nb * page, cfg.n_kv_heads, cfg.head_dim))
+        cache["state"] = {name: snaps[name][:, snap["start"]] for name in snaps}
+        cache["snap_at"] = snap["at"]
+        logits, cache = jamba_forward(
+            params, cfg, ids, positions=positions, cache=cache, cache_index=n_prior, attn_fn=attn_fn,
+            pad_mask=jnp.arange(segment)[None, :] < lens[:, None], logits_at=lens - 1)
+        new = [jax.lax.dynamic_slice_in_dim(cache[name], n_prior[0], segment, axis=2) for name in ("k", "v")]
+        k_pages, v_pages = scatter_prefill(k_pages, v_pages, *(jnp.concatenate([a, a]) for a in new), scat)
+        state = {name: state[name].at[:, snap["slot"]].set(cache["state"][name], mode="drop") for name in state}
+        snaps = {name: snaps[name].at[:, snap["ids"]].set(cache["snaps"][name], mode="drop") for name in snaps}
+        return logits[:, 0], k_pages, v_pages, state, snaps
+
+    snap = {"slot": place((1,), jnp.int32), "start": place((1,), jnp.int32),
+            "at": place((1, 2), jnp.int32), "ids": place((1, 2), jnp.int32)}
+    was, jamba.SCAN_FORM = jamba.SCAN_FORM, "pallas"   # what a TPU backend picks; the CPU here would take the loop
+    try:
+        compiled = {
+            "step": jax.jit(step, donate_argnums=(4, 5, 6)).lower(
+                params, place((slots,), jnp.int32), place((slots,), jnp.int32),
+                place((slots, nb), jnp.int32), pool, pool, state).compile(),
+            "prefill": jax.jit(prefill, donate_argnums=(4, 5, 6, 7)).lower(
+                params, place((1, segment), jnp.int32), place((1, segment), jnp.int32), place((1,), jnp.int32),
+                pool, pool, state, snaps, place((1, nb), jnp.int32), place((1,), jnp.int32),
+                place((1, segment // page), jnp.int32), snap).compile(),
+        }
+    finally:
+        jamba.SCAN_FORM = was
+    return cfg, params, {k: c.as_text() for k, c in compiled.items()}, \
+        {k: c.memory_analysis() for k, c in compiled.items()}
+
+
+JAMBA_HELD = ("bf16[2,321,128,1,128]", "f32[7,8,16,5120]", "bf16[7,8,3,5120]", "f32[7,64,16,5120]",
+              "bf16[7,64,3,5120]")
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_jamba_programs_read_their_weights_where_they_lie(jamba_programs, program):
+    """The v5e compiler takes both programs (Mosaic: the decode walk and the
+    flash prefill kernel at 20 query heads on one kv head), and nothing in
+    them makes an array with the shape of a projection, of the SwiGLU or of
+    the tied table: the serving tree holds ``w_in`` [out, in]."""
+    cfg, params, texts, _ = jamba_programs
+    assert params["layers_0"]["mamba"]["w_in_t"]["kernel"].shape == (10240, 2560)
+    assert params["layers_7"]["attn"]["wq_t"]["kernel"].shape == (2560, 2560)
+    assert params["layers_7"]["attn"]["wk_t"]["kernel"].shape == (128, 2560)
+    assert params["layers_0"]["mamba"]["a_log"].dtype == jnp.float32
+    assert _weight_copies(texts[program], params) == []
+
+
+def test_jamba_decode_step_holds_its_walk_and_relays_neither_pool_nor_state(jamba_programs):
+    """The decode step: ONE Pallas call, the walk of the pages in the one
+    attention layer (K and V are written by the scatter; the state by XLA's
+    fusions, a dynamic-update-slice a layer, in place: no kernel here, PERF.md
+    section 7). The pools are made by nothing. The slots' float32 state — 18
+    MB here, 68 MB at the cell's 26 layers, under the 128 MiB the compiler will
+    place — is PLACED in nearer memory for a sub-step and copied back, in the
+    order it lies (no relayout): the bytes the layers' own reads and writes
+    would move, once each way; the step's temporaries stay under it."""
+    cfg, _, texts, memory = jamba_programs
+    assert texts["step"].count('custom_call_target="tpu_custom_call"') == 1
+    assert len(re.findall(r"%paged_attention[.\d]* = ", texts["step"])) == 1
+    carried = ("parameter", "get-tuple-element", "bitcast", "while", "tuple")
+    made = [m for m in _pool_shaped(texts["step"], JAMBA_HELD) if m[2] not in carried]
+    assert not [m for m in made if m[1] == JAMBA_HELD[0]], made                  # the pools: made by nothing
+    state = [op for _n, shape, op in made if shape == "f32[7,8,16,5120]"]
+    assert set(state) <= {"dynamic-update-slice", "fusion:dynamic-update-slice", "custom-call", "copy-done"}
+    assert state.count("copy-done") == 1 and state.count("fusion:dynamic-update-slice") == 7
+    moved = [ln for ln in texts["step"].splitlines() if re.search(r"= f32\[7,8,16,5120\]\S* (copy|copy-done)\(", ln)]
+    assert all("f32[7,8,16,5120]{3,2,1,0:" in ln for ln in moved), moved           # as it lies: a placement
+    assert memory["step"].temp_size_in_bytes < 7 * 8 * 16 * 5120 * 4
+
+
+def test_jamba_prefill_holds_the_flash_kernel_and_writes_state_and_snapshots_where_they_lie(jamba_programs):
+    """The prefill over a 40-page prior: one flash kernel call (the attention
+    layer), the selective scan ONE kernel call a Mamba layer and no loop (the
+    loop it replaces is a fusion or two a token: 20 thousand device operations
+    a segment at the cell's depth), never ``[T, N, inner]``; K, V, the row's slot and the two
+    float32 snapshots are updated in place. (The snapshots' three bf16 COLUMNS
+    — 51 MB of the pool's 0.60 GB at the cell's depth — are relaid around
+    their scatter: three rows are no tile; named in PERF.md section 7.)"""
+    cfg, _, texts, memory = jamba_programs
+    assert len(re.findall(r"%prefill_attention[.\d]* = ", texts["prefill"])) == 1
+    assert len(re.findall(r"%selective_scan[.\d]* = ", texts["prefill"])) == 7 and " while(" not in texts["prefill"]
+    large = (JAMBA_HELD[0], "f32[7,8,16,5120]", "f32[7,64,16,5120]")
+    made = [m for m in _pool_shaped(texts["prefill"], large)
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast", "tuple", "custom-call")]
+    assert {op for _n, _s, op in made} <= {"scatter", "fusion:scatter", "dynamic-update-slice",
+                                           "fusion:dynamic-update-slice"}, made
+    # no array of a segment's positions x the state: 512 x 16 x 5120 float32 would be 168 MB
+    assert not re.findall(r"f32\[(?:1,)?5(?:12|28),(?:1,)?16,5120\]|f32\[(?:1,)?5(?:12|28),(?:1,)?5120,16\]",
+                          texts["prefill"])
+    assert memory["prefill"].temp_size_in_bytes < 0.25e9
 
 
 # ------------------------------------------ the grouped matmul's tiles (PR 43)

@@ -235,6 +235,8 @@ PREFILL_CASES = {
     "gqa-4to1-rows-at-0-midblock-40pages": dict(h=8, hkv=2, d=16, t=512, bucket=40, starts=[0, 200, 40 * PAGE]),
     "gqa-8to1": dict(h=8, hkv=1, d=16, t=512, bucket=8, starts=[8 * PAGE, 3 * PAGE]),
     "gqa-16to1": dict(h=16, hkv=1, d=16, t=128, bucket=8, starts=[100]),
+    # a group that divides no power of two: query blocks of 48 (1024 // 20 = 51, rounded to the bf16 tile), the last padded
+    "mqa-20to1-blocks-of-48": dict(h=20, hkv=1, d=16, t=512, bucket=8, starts=[8 * PAGE, 61]),
     "gqa-16to1-window": dict(h=16, hkv=1, d=16, t=128, bucket=40, starts=[40 * PAGE, 77], window=96),
     "latent-128-heads-second-term": dict(h=128, hkv=128, d=16, r=8, dv=32, t=512, bucket=40,
                                          starts=[40 * PAGE], scale=0.31),
