@@ -46,7 +46,10 @@ reason.
 earlier segment). A pad position has ``D = 0``, which neither decays nor adds,
 so the state after the segment IS the state after the row's own tokens; the
 state at every boundary asked for comes out of the scan.
-:func:`mamba1_step` is decode: the one-token update of the slot's state.
+:func:`mamba1_step` is decode: the one-token update of the slot's state — on the
+chip through ``kernels/ssm_update.py::selective_update``, ONE call a layer over
+the rows that advance, the state left in HBM and written in place, where that
+module's ``ssm_update_path`` says so of the state; in XLA everywhere else.
 """
 
 from __future__ import annotations
@@ -340,17 +343,29 @@ def mamba1_segment(mp: dict, cfg: JambaConfig, u: Array, state: dict, lens: Opti
     return out, after, snaps
 
 
-def mamba1_step(mp: dict, cfg: JambaConfig, u: Array, state: dict) -> tuple[Array, dict]:
+def mamba1_step(mp: dict, cfg: JambaConfig, u: Array, state: dict, update=None) -> tuple[Array, dict]:
     """One token a row: u ``[B, 1, d]`` over ``state`` → (out ``[B, 1, d]``,
     the state after it): the convolution's columns shifted by this token's,
-    ``S <- exp(D (x) A) * S + (D x) (x) B``, ``y = S C + D x``."""
+    ``S <- exp(D (x) A) * S + (D x) (x) B``, ``y = S C + D x``. The state's
+    sum and ``y`` are written HERE, in XLA, unless the caller brings ``update``
+    (``runtime/paged.py::paged_decode_forward`` where the engine bound
+    ``kernels/ssm_update.py``, by that module's ``ssm_update_path``):
+    ``update(state["ssm"], D [B, inner], D x [B, inner], B [B, N], C [B, N],
+    a_log [N, inner])`` → (what to hand back as ``"ssm"``, ``S C`` [B, inner])
+    — the caller's ``state["ssm"]`` is then whatever its ``update`` takes (all
+    layers' states, updated in place), and which rows it advances is the
+    caller's too."""
     with jax.named_scope("ssm_update"):
         x, z = _in_proj(mp, cfg, u)
         ext = jnp.concatenate([state["conv"].astype(x.dtype), x], axis=1)         # [B, taps + 1, inner]
         x, step, bmat, cmat = _conv_dt(mp, cfg, ext, 1)
-        ssm = (jnp.exp(step[:, 0, None, :] * -jnp.exp(mp["a_log"])) * state["ssm"]
-               + (step * x)[:, 0, None, :] * bmat[:, 0, :, None])
-        y = jnp.sum(ssm * cmat[:, 0, :, None], axis=1)[:, None]
+        if update is not None:
+            ssm, y = update(state["ssm"], step[:, 0], (step * x)[:, 0], bmat[:, 0], cmat[:, 0], mp["a_log"])
+            y = y[:, None]
+        else:
+            ssm = (jnp.exp(step[:, 0, None, :] * -jnp.exp(mp["a_log"])) * state["ssm"]
+                   + (step * x)[:, 0, None, :] * bmat[:, 0, :, None])
+            y = jnp.sum(ssm * cmat[:, 0, :, None], axis=1)[:, None]
         out = _out_proj(mp, cfg, y + x * mp["d"], z)
     return out, {"conv": ext[:, 1:], "ssm": ssm}
 
@@ -427,7 +442,7 @@ def decode_layer(lp: dict, cfg: JambaConfig, i: int, x: Array, step: DecodeStep)
     (pool layer ``attn_index``); then the SwiGLU."""
     u = L.rmsnorm(lp["norm"], x, cfg.norm_eps)
     if "mamba" in lp:
-        out = step.advance(cfg.ssm_index(i), lambda held, update: mamba1_step(lp["mamba"], cfg, u, held))
+        out = step.advance(cfg.ssm_index(i), lambda held, update: mamba1_step(lp["mamba"], cfg, u, held, update))
     else:
         q, k, v = plain_qkv(lp["attn"], cfg, u, None)
         attn = step.attend(q, k, v, cfg.attn_index(i), scope="attn.full")

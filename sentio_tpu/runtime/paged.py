@@ -1156,9 +1156,10 @@ class ContinuousBatchingEngine:
             "the page-write kernel, in place in HBM" if self._write_impl is not None else "the XLA scatter")
         # A Mamba family's one-token update of ``pool.conv["ssm"]`` likewise,
         # where the kernels were asked for and ``ssm_update_path`` says so of
-        # the state: float32 in whole tiles on one device. The narrow
-        # rehearsal widths and a state under a mesh keep ``mamba_step``'s sum
-        # and the masked ``.at[j].set``
+        # the state: float32 in whole tiles on one device — a matrix a head
+        # (Mamba-2) or a row's ``[N, inner]`` (Mamba-1), the adapter reads
+        # which from the rank. The narrow rehearsal widths and a state under
+        # a mesh keep the model's own sum and the masked ``.at[j].set``
         self._ssm_impl = None
         if use_pallas and self.ssm_state:
             from sentio_tpu.kernels.ssm_update import make_ssm_update_impl, ssm_update_path

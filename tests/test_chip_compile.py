@@ -25,7 +25,7 @@ from sentio_tpu.kernels.flash_attention import flash_attention
 from sentio_tpu.kernels.page_write import make_page_write_impl, page_write, page_write_path
 from sentio_tpu.kernels.prefill_attention import make_prefill_attn_fn, prefill_attention
 from sentio_tpu.kernels.selective_scan import selective_scan_kernel
-from sentio_tpu.kernels.ssm_update import make_ssm_update_impl, ssm_update, ssm_update_path
+from sentio_tpu.kernels.ssm_update import make_ssm_update_impl, selective_update, ssm_update, ssm_update_path
 from sentio_tpu.kernels.paged_attention import (
     make_paged_attn_impl,
     paged_attention,
@@ -167,6 +167,21 @@ def _ssm_update_case():
     return build
 
 
+def _selective_update_case():
+    """The decode step's Mamba-1 state update (kernels/ssm_update.py::
+    selective_update) alone, at the jamba cell's state: 26 layers, 8 slots, a
+    row ``[16, 5120]`` float32."""
+
+    def build(topo):
+        place = _on_one_chip(topo)
+        f32 = jnp.float32
+        return selective_update, (place((26, 8, 16, 5120), f32), place((), jnp.int32), place((8,), bool),
+                                  place((8, 5120), f32), place((8, 5120), f32), place((8, 16), f32),
+                                  place((8, 16), f32), place((16, 5120), f32))
+
+    return build
+
+
 def _selective_scan_case():
     """The prefill's selective scan (kernels/selective_scan.py) alone: one row
     of 512 tokens over a ``[16, 5120]`` float32 state, the state written out
@@ -262,6 +277,8 @@ CASES = {
     "prefill-attn-jamba-rows1": _prefill_attn_case(1, 20, 1),
     # the selective scan of one layer over a 512-token row at the jamba cell's widths
     "selective-scan-cell-jamba": _selective_scan_case(),
+    # the Mamba-1 state update at the one cell that takes it
+    "selective-update-cell-jamba": _selective_update_case(),
 }
 # the geometries whose pages the chip's DMA cannot bring (kernels/
 # paged_attention.py ``untiled``): XLA does not store such a pool in the
@@ -1110,8 +1127,8 @@ def test_nemotron_prefill_writes_pool_state_and_snapshots_where_they_lie(nemotro
 # ``ai21-jamba2-3b``, its first eight layers (the check's depth: seven Mamba-1
 # layers and the attention layer): the decode walk at ONE kv head under 20
 # query heads, the state per slot ``[7, 8, 16, 5120]`` float32 (``S``
-# transposed) carried through the scan and updated by XLA (``kernels/
-# ssm_update.py`` is Mamba-2's), and a 512-token prefill segment — the
+# transposed) carried through the scan and updated in place by ``kernels/
+# ssm_update.py::selective_update`` (PR 49), and a 512-token prefill segment — the
 # selective scan a kernel call a Mamba layer, the flash kernel over a 40-page
 # prior bucket — that starts from a snapshot and leaves two.
 
@@ -1133,16 +1150,17 @@ def jamba_programs(v5e):
     state = {name: place(shape, dtype) for name, (shape, dtype) in cfg.state_shapes(slots).items()}
     snaps = {name: place(shape, dtype) for name, (shape, dtype) in cfg.state_shapes(snapshots).items()}
     impl = make_paged_attn_impl(interpret=False)
-    # one row of lanes a position: Mosaic refuses the half-sublane slice, the scatter writes K and V; a state
-    # that is no matrix a head keeps the XLA update
-    assert page_write_path(pool) == "xla" and ssm_update_path(state["ssm"]) == "xla"
+    # one row of lanes a position: Mosaic refuses the half-sublane slice, the scatter writes K and V; the
+    # state, float32 and a row's [16, 5120] whole tiles, takes the update kernel (PR 49)
+    assert page_write_path(pool) == "xla" and ssm_update_path(state["ssm"]) == "pallas"
+    update = make_ssm_update_impl(interpret=False)
     attn_fn = make_prefill_attn_fn(interpret=False)
 
     def step(params, tok, lens, table, k_pages, v_pages, state):
         def body(carry, _):
             tok, lens, k_pages, v_pages, state = carry
             logits, k_pages, v_pages, _, state, _ = paged_decode_forward(
-                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl,
+                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl, ssm_impl=update,
                 write_mask=lens < nb * page - 1, conv=state)
             return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1, k_pages, v_pages, state), None
 
@@ -1204,25 +1222,38 @@ def test_jamba_programs_read_their_weights_where_they_lie(jamba_programs, progra
 
 
 def test_jamba_decode_step_holds_its_walk_and_relays_neither_pool_nor_state(jamba_programs):
-    """The decode step: ONE Pallas call, the walk of the pages in the one
-    attention layer (K and V are written by the scatter; the state by XLA's
-    fusions, a dynamic-update-slice a layer, in place: no kernel here, PERF.md
-    section 7). The pools are made by nothing. The slots' float32 state — 18
-    MB here, 68 MB at the cell's 26 layers, under the 128 MiB the compiler will
-    place — is PLACED in nearer memory for a sub-step and copied back, in the
-    order it lies (no relayout): the bytes the layers' own reads and writes
-    would move, once each way; the step's temporaries stay under it."""
+    """The decode step: 1 + 7 Pallas calls — the walk of the pages in the one
+    attention layer (K and V are written by the scatter) and ONE state update
+    in each of the seven Mamba-1 layers. The pools are made by nothing. The
+    slots' float32 state — 18 MB here, 68 MB at the cell's 26 layers — is made
+    by the update calls and by nothing else, and read by nothing else: until
+    PR 49 the compiler PLACED it in nearer memory at the head of every
+    sub-step and copied it back at its end (a ``copy`` / ``copy-done`` of its
+    shape, 136 MB a sub-step at the cell's depth) and seven fusions updated
+    every slot's. The convolution's columns keep the masked write, a
+    dynamic-update-slice a layer in place; the step's temporaries stay under
+    the state's size."""
     cfg, _, texts, memory = jamba_programs
-    assert texts["step"].count('custom_call_target="tpu_custom_call"') == 1
+    assert texts["step"].count('custom_call_target="tpu_custom_call"') == 1 + 7
     assert len(re.findall(r"%paged_attention[.\d]* = ", texts["step"])) == 1
+    assert len(re.findall(r"%ssm_update[.\d]* = ", texts["step"])) == 7
     carried = ("parameter", "get-tuple-element", "bitcast", "while", "tuple")
     made = [m for m in _pool_shaped(texts["step"], JAMBA_HELD) if m[2] not in carried]
     assert not [m for m in made if m[1] == JAMBA_HELD[0]], made                  # the pools: made by nothing
-    state = [op for _n, shape, op in made if shape == "f32[7,8,16,5120]"]
-    assert set(state) <= {"dynamic-update-slice", "fusion:dynamic-update-slice", "custom-call", "copy-done"}
-    assert state.count("copy-done") == 1 and state.count("fusion:dynamic-update-slice") == 7
-    moved = [ln for ln in texts["step"].splitlines() if re.search(r"= f32\[7,8,16,5120\]\S* (copy|copy-done)\(", ln)]
-    assert all("f32[7,8,16,5120]{3,2,1,0:" in ln for ln in moved), moved           # as it lies: a placement
+    views = ("f32[7,8,16,5120]", "f32[8,16,5120]")     # the state, a layer's slice of it
+    # nothing but the calls MAKES the state or a layer's slice of it (each call's first result, aliased to its operand) ...
+    assert [m for m in _pool_shaped(texts["step"], views) if m[2] not in carried] == []
+    assert len(re.findall(r"%ssm_update[.\d]* = \(f32\[7,8,16,5120\].*output_to_operand_aliasing=\{\{0\}: \(7, \{\}\)\}",
+                          texts["step"])) == 7
+    assert not re.search(r"= f32\[7,8,16,5120\]\S* (copy|copy-start|copy-done)\(", texts["step"])
+    # ... and nothing but the calls READS it
+    held = {name for name, _shape, _op in _pool_shaped(texts["step"], views)}
+    readers = [line.strip()[:160] for line in texts["step"].splitlines()
+               if (inst := re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\((.*)", line))
+               and inst.group(1) not in (*carried, "custom-call") and held & set(re.findall(r"%([\w.\-]+)", inst.group(2)))]
+    assert readers == [], readers
+    columns = [op for _n, shape, op in made if shape == "bf16[7,8,3,5120]"]
+    assert columns.count("fusion:dynamic-update-slice") == 7, columns
     assert memory["step"].temp_size_in_bytes < 7 * 8 * 16 * 5120 * 4
 
 

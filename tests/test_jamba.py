@@ -358,28 +358,45 @@ def test_what_this_family_is_not_served_with_says_why():
         tiny(mamba_proj_bias=True)
 
 
-def test_the_update_kernel_is_not_bound_for_this_state_and_its_own_binding_is_unchanged(caplog):
-    """``kernels/ssm_update.py`` is Mamba-2's: a matrix a head, ``[Lm, B, H, P,
-    N]``. This family's ``[Lm, B, N, inner]`` keeps the XLA form whatever is
-    asked — at the published widths too — and every row of a tick is an
-    update; the rule's answer for the state it was written for has not moved."""
+def test_the_update_kernel_is_bound_where_asked_and_counts_the_rows_it_skips(caplog):
+    """``kernels/ssm_update.py`` takes this family's ``[Lm, B, N, inner]`` too
+    since PR 49 (``selective_update``: a decay a channel and column), by the
+    rule that reads the operand: the published widths take it, and so does
+    this file's tiny state (``[8, 128]`` a row is one whole float32 tile) once
+    the kernels are ASKED for — interpret mode here. The engine says so in
+    ``stats()`` and its log, answers what the same engine answers with the
+    XLA form in its place, and counts the row-updates the kernel did and those
+    it skipped; left unasked (every other engine of this file, a CPU server)
+    the XLA form stays and every row of a tick is an update. The rule's
+    answers for the Mamba-2 state have not moved."""
     import logging
 
     from sentio_tpu.kernels.ssm_update import ssm_update_path
 
     published = JambaConfig().state_shapes(8)["ssm"]
     assert published == ((26, 8, 16, 5120), jnp.float32)
-    assert ssm_update_path(jax.ShapeDtypeStruct(*published)) == "xla"
+    assert ssm_update_path(jax.ShapeDtypeStruct(*published)) == "pallas"
     assert ssm_update_path(jax.ShapeDtypeStruct((6, 16, 64, 64, 128), jnp.float32)) == "pallas"
     assert ssm_update_path(jax.ShapeDtypeStruct((3, 2, 8, 8, 16), jnp.float32)) == "xla"
     cfg = tiny()
+    tree = seeded(cfg)
     with caplog.at_level(logging.INFO, logger="sentio_tpu.runtime.paged"):
-        engine = engine_of(cfg, seeded(cfg), use_pallas=True)
-    assert engine._ssm_impl is None and "the XLA form, every slot's state" in caplog.text
-    assert len(engine.run_all([PROMPT[:40]], max_new_tokens=6)[0].tokens) == 6
-    stats = engine.stats()
-    assert stats["ssm_update"] == "xla" and stats["ssm_state_row_skips"] == 0
-    assert stats["ssm_state_row_updates"] == sum(engine.row_steps_total.values()) * len(cfg.ssm_layers) > 0
+        kernel = engine_of(cfg, tree, use_pallas=True)
+    assert kernel.stats()["ssm_update"] == "pallas" and "the ssm-update kernel" in caplog.text
+    xla = engine_of(cfg, tree, use_pallas=True)
+    xla._ssm_impl = None
+    xla._build_fns()
+    prompts = [PROMPT[:40], HEAD]
+    got, want = (engine.run_all(prompts, max_new_tokens=6) for engine in (kernel, xla))
+    assert [r.tokens for r in got] == [r.tokens for r in want] and all(len(r.tokens) == 6 for r in got)
+    assert [r.logprob_sum for r in got] == pytest.approx([r.logprob_sum for r in want], abs=2e-4)
+    stats, layers = kernel.stats(), len(cfg.ssm_layers)
+    assert stats["ssm_state_row_updates"] == kernel.row_steps_total["useful"] * layers > 0
+    assert stats["ssm_state_row_updates"] + stats["ssm_state_row_skips"] == sum(kernel.row_steps_total.values()) * layers
+    assert stats["ssm_state_row_skips"] > 0
+    assert xla.stats()["ssm_update"] == "xla" and xla.stats()["ssm_state_row_skips"] == 0
+    unasked = engine_of(cfg, tree)
+    assert unasked._ssm_impl is None and unasked.stats()["ssm_update"] == "xla"
 
 
 def test_the_snapshots_held_reach_metrics_as_a_gauge():
