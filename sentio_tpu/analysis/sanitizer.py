@@ -450,6 +450,16 @@ def _check_pool_repr(engine) -> None:
             )
 
 
+def _live_slots(engine) -> list:
+    """Every slot that holds a request: the lanes' active ones, and the
+    active slots of the tick in flight that no longer own their lane."""
+    live = [slot for slot in engine.slots if slot.active]
+    record = getattr(engine, "_inflight", None) or {}
+    live += [slot for i, slot in enumerate(record.get("slots", ()))
+             if slot.active and engine.slots[i] is not slot]
+    return live
+
+
 def check_engine_invariants(engine) -> None:
     """Page-pool conservation + radix refcount consistency. Called by the
     engine at the end of every tick under the sanitizer.
@@ -458,7 +468,10 @@ def check_engine_invariants(engine) -> None:
     is owned by exactly one of (a) the allocator free list, (b) an active
     slot's ``pages`` minus the span it donated to the radix cache, (c) the
     radix tree. Refcounts: each active slot pins the chain from its
-    ``prefix_node`` to the root, contributing exactly 1 per node. The pool
+    ``prefix_node`` to the root, contributing exactly 1 per node. The slots
+    are the lanes' and — inside a ``step()`` only — a spent slot whose lane
+    was handed on, which the record of the tick in flight keeps until its
+    harvest retires it (:func:`_live_slots`). The pool
     representation check (:func:`_check_pool_repr`) runs first so the
     quantized ``{"q","s"}`` pool is held to the same per-tick standard as
     plain arrays."""
@@ -476,9 +489,7 @@ def check_engine_invariants(engine) -> None:
 
     slot_pages: list[int] = []
     donated: set[int] = set()
-    for slot in engine.slots:
-        if not slot.active:
-            continue
+    for slot in _live_slots(engine):
         slot_pages.extend(slot.pages)
         donated.update(slot.donated)
     if len(set(slot_pages)) != len(slot_pages):
@@ -525,9 +536,7 @@ def check_engine_invariants(engine) -> None:
 
     if radix is not None:
         expected_rc: dict[int, int] = {}
-        for slot in engine.slots:
-            if not slot.active:
-                continue
+        for slot in _live_slots(engine):
             node = slot.prefix_node
             while node is not None and node is not radix.root:
                 expected_rc[id(node)] = expected_rc.get(id(node), 0) + 1

@@ -40,6 +40,7 @@ from typing import Any, Optional
 from sentio_tpu.analysis.sanitizer import assert_held, guard_locksets, make_lock
 from sentio_tpu.infra.phases import (
     REQUEST_STAGES,
+    LANE_ADMISSION_KINDS,
     ROW_STEP_KINDS,
     TTFT_STAGES,
     tile_ttft,
@@ -460,7 +461,8 @@ class FlightRecorder:
         from each record's largest. ``residual_ms_max``
         is the largest |sum of a request's tile − its server-side TTFT|:
         zero but for rounding, by construction. ``row_steps`` sums the
-        retained ticks' counted row-steps."""
+        retained ticks' counted row-steps, ``lane_admissions`` the lanes
+        their admissions took."""
         with self._lock:
             done = [r for r in self._records.values()
                     if r.get("status") == "done" and "stages_ms" in r]
@@ -480,11 +482,14 @@ class FlightRecorder:
                     samples["stream_lag"].append(record["stream_lag_max_ms"])
             ttft = [r["ttft_server_ms"] for r in done]
             row_steps = dict.fromkeys(ROW_STEP_KINDS, 0)
+            lane_admissions = dict.fromkeys(LANE_ADMISSION_KINDS, 0)
             sub_steps = 0
             for event in self._ticks:
                 sub_steps += event.get("sub_steps", 0)
                 for kind, n in (event.get("row_steps") or {}).items():
                     row_steps[kind] = row_steps.get(kind, 0) + n
+                for kind, n in (event.get("lane_admissions") or {}).items():
+                    lane_admissions[kind] = lane_admissions.get(kind, 0) + n
         return {
             "requests": len(done),
             "ttft_server_ms": ({"mean": round(sum(ttft) / len(ttft), 3),
@@ -497,6 +502,9 @@ class FlightRecorder:
             # over the retained ticks: the kinds sum to slots x sub_steps
             "row_steps": row_steps,
             "sub_steps": sub_steps,
+            # and the lanes their admissions took: free, or spent (handed on
+            # while the old row's last tick was in flight)
+            "lane_admissions": lane_admissions,
         }
 
     def origin(self) -> float:

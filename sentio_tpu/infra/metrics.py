@@ -388,6 +388,16 @@ class MetricsCollector:
                 "slots holding a pending prefill segment at a tick: the one dispatched, and the rest",
                 ["kind"], registry=r,
             ),
+            # the lane each admission took (runtime/paged.py::_admit): free
+            # (it held no request) or spent (its row's last tokens rode the
+            # tick in flight: handed on before that tick's harvest).
+            # spent / (free + spent) is how often a request did NOT wait a
+            # tick for a lane the host already knew was done
+            "lane_admissions": Counter(
+                "sentio_tpu_lane_admissions_total",
+                "admissions by the lane they took: one that held no request, or one whose row was spent",
+                ["kind"], registry=r,
+            ),
             # what the DEVICE was running, by the program's own completion
             # stamps (infra/tracing.py::DeviceStamper): the seconds each
             # dispatched program held the device, from the later of its
@@ -636,7 +646,8 @@ class MetricsCollector:
                          prefill_latent: Optional[dict] = None,
                          prefill_turns: Optional[dict] = None,
                          conv_state: Optional[dict] = None,
-                         ssm_state: Optional[dict] = None) -> None:
+                         ssm_state: Optional[dict] = None,
+                         lane_admissions: Optional[dict] = None) -> None:
         """One harvested tick's row-steps by kind (useful / halted / empty),
         the K/V page blocks of its sub-steps (held / tabled), of a routed
         family its expert layers' pairs (routed / held) and expert-steps
@@ -647,12 +658,14 @@ class MetricsCollector:
         the page tails written, of a family with Mamba layers the same starts
         (zero / snapshot / carried), the snapshots written and evicted, the
         state updates its decode sub-steps did and skipped, and the prefix
-        tokens cut back."""
+        tokens cut back; and the lanes the step's admissions took (free /
+        spent)."""
         if not self.enabled:
             return
         from sentio_tpu.infra.phases import (
             CONV_START_KINDS,
             KV_PAGE_KINDS,
+            LANE_ADMISSION_KINDS,
             PREFILL_LATENT_KINDS,
             PREFILL_TURN_KINDS,
             ROW_STEP_KINDS,
@@ -674,7 +687,8 @@ class MetricsCollector:
                 ("ssm_starts", SSM_START_KINDS, ssm_state or {}),
                 ("ssm_snapshots", SSM_SNAPSHOT_EVENTS, ssm_state or {}),
                 ("ssm_row_updates", SSM_ROW_UPDATE_KINDS, ssm_state or {}),
-                ("prefill_turns", PREFILL_TURN_KINDS, prefill_turns or {})):
+                ("prefill_turns", PREFILL_TURN_KINDS, prefill_turns or {}),
+                ("lane_admissions", LANE_ADMISSION_KINDS, lane_admissions or {})):
             if name.startswith(("moe", "prefill_latent", "conv", "ssm")) and not any(tick.values()):
                 continue  # no series where no such family is served
             counter = self._prom.get(name)

@@ -117,6 +117,7 @@ __all__ = [
     "SSM_STATE_KINDS",
     "PREFILL_LATENT_KINDS",
     "PREFILL_TURN_KINDS",
+    "LANE_ADMISSION_KINDS",
     "DEVICE_PROGRAMS",
     "ENCODER_PROGRAMS",
     "ENCODER_FORWARD_PARTS",
@@ -232,6 +233,12 @@ SSM_ROW_UPDATE_KINDS = SSM_STATE_KINDS[6:]
 # segment a tick over all slots): a tick in which n slots hold a pending
 # segment books one `taken` and n - 1 `waited`
 PREFILL_TURN_KINDS = ("taken", "waited")
+
+# the lane an admission took (runtime/paged.py::_admit): `free` held no
+# request; `spent` held a row whose every remaining token rode the tick in
+# flight, and was handed on before that tick's harvest retired the row (depth
+# 2 only: at depth 1, and on a speculative engine, every admission is `free`)
+LANE_ADMISSION_KINDS = ("free", "spent")
 
 # what the DEVICE was running (infra/tracing.py's completion stamps: every
 # dispatch site hands its program and one small output to the stamper, which

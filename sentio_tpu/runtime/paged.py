@@ -39,9 +39,12 @@ trip (how much each costs on the chip is not measured yet). Hence:
   tick to tick as device arrays (host numpy rides jit calls, never eager
   uploads), which enables
 * **pipelined ticks** (``pipeline_depth=2``) — tick N+1 dispatches BEFORE
-  tick N's fetch, overlapping the round trip with device compute; per-lane
-  request ids guard against stale replays when slots retire and are reused
-  mid-flight.
+  tick N's fetch, overlapping the round trip with device compute. A tick's
+  record keeps the ``_Slot`` objects it was dispatched with and is folded
+  into THEM: a lane whose row was granted its last token is handed to the
+  next queued request at once (``_admit``), while that row's last tick is
+  still in flight, and a lane retired and refilled since dispatch is never
+  replayed into its new request.
 
 Page 0 is reserved as a scratch page: free slots' page tables point at it,
 so masked lanes in the fused decode step write garbage somewhere harmless.
@@ -63,8 +66,8 @@ from sentio_tpu.analysis.audit.registry import jit_family
 from sentio_tpu.analysis.sanitizer import check_engine_invariants, engine_guard
 from sentio_tpu.infra import faults
 from sentio_tpu.infra.phases import (
-    CONV_STATE_KINDS, ENGINE_PHASES, KV_PAGE_KINDS, MOE_KINDS, PREFILL_LATENT_KINDS,
-    PREFILL_TURN_KINDS, ROW_STEP_KINDS, SSM_STATE_KINDS, PhaseTimer,
+    CONV_STATE_KINDS, ENGINE_PHASES, KV_PAGE_KINDS, LANE_ADMISSION_KINDS, MOE_KINDS,
+    PREFILL_LATENT_KINDS, PREFILL_TURN_KINDS, ROW_STEP_KINDS, SSM_STATE_KINDS, PhaseTimer,
 )
 from sentio_tpu.infra.tracing import annotation, dispatching, get_stamper, harvested
 from sentio_tpu.models.families import DecodeStep, family_of
@@ -567,6 +570,11 @@ def scatter_prefill(k_pages, v_pages, k_cache, v_cache, page_table):
 
 @dataclass
 class _Slot:
+    # one request's stay in a lane: made at admission, never reused. The lane
+    # it was admitted to (row of the decode batch and of the host mirrors);
+    # it OWNS that lane while ``engine.slots[lane] is slot`` — a spent slot
+    # whose lane was handed on lives on in its tick's record until harvested
+    lane: int = -1
     request_id: int = -1
     pages: list[int] = field(default_factory=list)
     length: int = 0          # tokens currently in cache (prompt + generated)
@@ -623,6 +631,10 @@ class _Slot:
     start_snap: Optional[int] = None
     cut_from: int = 0
     snaps_written: list = field(default_factory=list)
+    # sampled-token logprob accumulators (sum, min, count) as of the last
+    # harvest that folded this request's tokens, read by _retire into the
+    # PagedResult; zeros on the spec path, which samples no logprobs
+    lp: tuple = (0.0, 0.0, 0)
 
 
 @dataclass
@@ -937,7 +949,7 @@ class ContinuousBatchingEngine:
         # when disabled, so the steady-state cost is one attribute test.
         self._san = engine_guard("ContinuousBatchingEngine")
 
-        self.slots = [_Slot() for _ in range(max_slots)]  # guarded-by: engine-thread
+        self.slots = [_Slot(lane=i) for i in range(max_slots)]  # guarded-by: engine-thread
         self.last_tick_active = 0
         # tick-phase attribution (infra/phases.py): reset at the top of
         # every step(), accumulated by the dispatch helpers, closed out at
@@ -1018,6 +1030,12 @@ class ContinuousBatchingEngine:
         self.prefill_turns_total = dict.fromkeys(PREFILL_TURN_KINDS, 0)
         self.last_tick_prefill_turns = dict.fromkeys(PREFILL_TURN_KINDS, 0)
         self._prefill_turns_pending = dict.fromkeys(PREFILL_TURN_KINDS, 0)
+        # admissions by the lane they took: ``free`` held no request,
+        # ``spent`` held a row whose last tokens ride the tick in flight
+        # (``_spent``) and was handed on before that tick's harvest. Counted
+        # in ``_admit``, inside the step() that the last_tick dict belongs to
+        self.lane_admissions_total = dict.fromkeys(LANE_ADMISSION_KINDS, 0)
+        self.last_tick_lane_admissions = dict.fromkeys(LANE_ADMISSION_KINDS, 0)
         # the pump's step number, carried by this engine's completion stamps
         # (infra/tracing.py::dispatching); 0 outside a pump
         self.tick_step = 0
@@ -1089,12 +1107,6 @@ class ContinuousBatchingEngine:
         self._temps = np.zeros(max_slots, np.float32)
         self._top_ks = np.zeros(max_slots, np.int32)
         self._last_tok = np.zeros(max_slots, np.int32)
-        # per-slot logprob accumulator mirrors: seeded into the first
-        # dispatch after a reset, refreshed at harvest from the tick's
-        # packed lp_state fetch, read by _retire into the PagedResult
-        self._lp_sum = np.zeros(max_slots, np.float32)
-        self._lp_min = np.zeros(max_slots, np.float32)
-        self._lp_cnt = np.zeros(max_slots, np.int32)
         # On TPU the Pallas paged-attention kernel reads the [L, P, ...] pool
         # where it lies: it walks the blocks each row HOLDS (its ``lens``
         # names them; one for a free slot) and copies each by its own DMA,
@@ -1710,7 +1722,9 @@ class ContinuousBatchingEngine:
     def cancel(self, request_id: int) -> bool:
         """Abandon a request: queued → dropped; decoding → slot retired and
         pages freed (the tokens so far are discarded). Must be called by the
-        engine's single driver thread, like every other engine method."""
+        engine's single driver thread, like every other engine method — so
+        BETWEEN steps, where every request that holds pages owns its lane (a
+        slot whose lane was handed on is retired by the same ``step()``)."""
         if self._san is not None:
             self._san.enter("cancel")
         for idx, req in enumerate(self._queue):
@@ -1722,9 +1736,9 @@ class ContinuousBatchingEngine:
                     # disable skip-ahead on its first blocked scan)
                     self._head_skips = 0
                 return True
-        for i, slot in enumerate(self.slots):
+        for slot in self.slots:
             if slot.active and slot.request_id == request_id:
-                self._retire(i, "cancelled")
+                self._retire(slot, "cancelled")
                 return True
         return False
 
@@ -1750,7 +1764,7 @@ class ContinuousBatchingEngine:
             snapshots=self._snapshots,
         )
         self.allocator = PageAllocator(self.allocator.num_pages)
-        self.slots = [_Slot() for _ in range(self.max_slots)]
+        self.slots = [_Slot(lane=i) for i in range(self.max_slots)]
         self._queue.clear()
         self._head_skips = 0
         self._finished_buffer.clear()
@@ -1778,9 +1792,6 @@ class ContinuousBatchingEngine:
         self._temps[:] = 0.0
         self._top_ks[:] = 0
         self._last_tok[:] = 0
-        self._lp_sum[:] = 0.0
-        self._lp_min[:] = 0.0
-        self._lp_cnt[:] = 0
         self._rng = jax.random.PRNGKey(int(np.random.default_rng().integers(2**31)))
 
     # FamilyFn instances owned by THIS engine (fresh jit wrappers per
@@ -1868,7 +1879,12 @@ class ContinuousBatchingEngine:
         fetch), one fused multi-step decode dispatch, ONE host fetch, retire
         finished slots. With ``pipeline_depth`` 2 the dispatch goes out
         BEFORE the previous tick's fetch, overlapping the host round trip
-        with device compute (results then lag one tick). Returns results
+        with device compute: RESULTS then lag one tick, lanes do not — a row
+        whose last tokens ride the tick in flight has its lane admitted into
+        here (``_admit``, ``_spent``), and the harvest below retires it from
+        that tick's record. Only a row that ends EARLY, on an EOS the host
+        has not seen yet, or whose successor's pages do not fit the pool
+        beside its own, keeps its lane until its harvest. Returns results
         completed this tick."""
         if self._san is not None:
             self._san.enter("step")
@@ -1883,6 +1899,7 @@ class ContinuousBatchingEngine:
         self.last_tick_moe = dict.fromkeys(MOE_KINDS, 0)
         self.last_tick_prefill_latent = dict.fromkeys(PREFILL_LATENT_KINDS, 0)
         self.last_tick_prefill_turns = dict.fromkeys(PREFILL_TURN_KINDS, 0)
+        self.last_tick_lane_admissions = dict.fromkeys(LANE_ADMISSION_KINDS, 0)
         self.last_tick_conv_state = dict.fromkeys(CONV_STATE_KINDS, 0)
         self.last_tick_ssm_state = dict.fromkeys(SSM_STATE_KINDS, 0)
         self.last_tick_sub_steps = 0
@@ -1943,6 +1960,54 @@ class ContinuousBatchingEngine:
 
     def _free_slot_indices(self) -> list[int]:
         return [i for i, s in enumerate(self.slots) if not s.active]
+
+    def _remaining(self, slot: _Slot) -> int:
+        """Tokens ``slot`` may still be granted: what ``max_new`` and its
+        page capacity leave once everything folded, pending and in flight is
+        counted. THE budget arithmetic — ``_dispatch_tick`` grants from it,
+        ``_spent`` hands a lane on by it, and ``_fold_and_maybe_retire``
+        retires on the same two bounds."""
+        capacity = slot.shared_tokens + len(slot.pages) * self.page_size
+        # a pending (still-on-device) first token and any sub-steps
+        # already granted to an unharvested tick count against the
+        # budget exactly as if they had been folded
+        base_emit = (
+            len(slot.emitted) + slot.inflight_steps
+            + (1 if slot.pending_first else 0)
+        )
+        written = slot.length + slot.inflight_steps
+        # spec mode reserves verify-block headroom inside capacity.
+        # Admission over-allocates by the same amount, EXCEPT when the
+        # request already hits the max_pages_per_seq window — there the
+        # headroom comes out of the emission budget, so window-limited
+        # requests finish up to spec_k+1 tokens earlier than the plain
+        # engine would (documented in runtime/paged_spec.py)
+        spec_head = (self.spec_k + 1) if self._spec_tick is not None else 0
+        return max(min(slot.max_new - base_emit,
+                       capacity - 1 - spec_head - written), 0)
+
+    def _spent(self, slot: _Slot) -> bool:
+        """Every token ``slot`` may still emit is granted to the tick in
+        flight: its lane can take the next request NOW. That tick's record
+        holds the slot and its harvest folds and retires it — always, since
+        the bounds that leave nothing to grant are the ones the fold retires
+        on; an EOS only ends the row earlier inside the same tick. Safe by
+        DEVICE ORDER, which this relies on: the tick in flight took its own
+        copy of the page table, the new request's prefill and
+        ``merge_admitted`` are enqueued behind it (they overwrite the lane's
+        carried token, length, halt flag, logprob and state rows only after
+        it read them), and the prefill writes pages the old row never held —
+        its own stay allocated until it retires. Never at depth 1 (nothing is
+        in flight when ``_admit`` runs) and never on a spec engine (its
+        budgets are verify blocks, and the draft cache is per lane)."""
+        record = self._inflight
+        return (
+            record is not None and self._spec_tick is None
+            and record["slots"][slot.lane] is slot
+            and slot.active and slot.prefill_todo is None
+            and (slot.inflight_steps > 0 or slot.pending_first)
+            and self._remaining(slot) == 0
+        )
 
     ADMIT_BUCKETS = (1, 2, 4, 8)
 
@@ -2122,14 +2187,21 @@ class ContinuousBatchingEngine:
         return snap
 
     def _admit(self) -> None:
-        free = self._free_slot_indices()
-        if not free or not self._queue:
+        if not self._queue:
+            return
+        # the lanes this admission may take, in order: those that hold no
+        # request, then — only where the queue is longer than they are —
+        # those whose row is spent (its last tokens ride the tick in flight)
+        lanes = [(i, "free") for i in self._free_slot_indices()]
+        if len(self._queue) > len(lanes):
+            lanes += [(s.lane, "spent") for s in self.slots if self._spent(s)]
+        if not lanes:
             return
 
         batch: list[tuple[int, _Request, list[int], int]] = []
         now = time.perf_counter()
         qi = 0
-        while qi < len(self._queue) and free:
+        while qi < len(self._queue) and lanes:
             req = self._queue[qi]
             if req.deadline_ts is not None and now >= req.deadline_ts:
                 # caller's deadline passed while queued: drop BEFORE paying
@@ -2190,12 +2262,23 @@ class ContinuousBatchingEngine:
                 )
 
             need_total = pages_needed(shared)
+            # a spent lane is taken only where the request's pages fit BESIDE
+            # the old row's. Where they cannot, the lane waits for its
+            # harvest, which frees those, and the request is admitted then
+            # like any other: nothing is evicted in vain, nothing jumps the
+            # head into a lane that is not free yet, no head skip is counted
+            handover = lanes[0][1] == "spent"
+            cached = self._radix.pages_held if self._radix is not None else 0
+            if handover and need_total > self.allocator.free_pages + cached:
+                break
             if need_total > self.allocator.free_pages and self._radix is not None:
                 # reclaim LRU unpinned cached prefixes; the match may have
                 # walked nodes the eviction just freed, so rematch after
                 if self._radix.evict(need_total - self.allocator.free_pages):
                     shared, match_pages, match_node, match_snap, paged = self._match_radix(tok_ids)
                     need_total = pages_needed(shared)
+            if need_total > self.allocator.free_pages and handover:
+                break  # what the cache holds is pinned by live rows
             if need_total > self.allocator.free_pages:
                 # skip-ahead: a too-large request must not idle free slots
                 # while smaller requests queue behind it (round-4 weak #3:
@@ -2207,7 +2290,9 @@ class ContinuousBatchingEngine:
                 qi += 1
                 continue
             pages = self.allocator.alloc(need_total)
-            slot_idx = free.pop(0)
+            slot_idx, kind = lanes.pop(0)
+            self.lane_admissions_total[kind] += 1
+            self.last_tick_lane_admissions[kind] += 1
             self._queue.pop(qi)
             if qi == 0:
                 self._head_skips = 0
@@ -2233,38 +2318,29 @@ class ContinuousBatchingEngine:
             )
             if not chunked:
                 batch.append((slot_idx, req, tok_ids, shared))
-            slot = self.slots[slot_idx]
-            slot.request_id = req.request_id
-            slot.pages = pages
-            slot.prompt_tokens = len(tok_ids)
-            slot.length = len(tok_ids)
-            slot.max_new = req.max_new
-            slot.temperature = req.temperature
-            slot.top_k = req.top_k
-            slot.emitted = []
-            slot.inflight_steps = 0
-            slot.shared_tokens = shared
-            slot.prefix_node = match_node
-            slot.start_snap, slot.cut_from, slot.snaps_written = match_snap, 0, []
+            # a fresh slot takes the lane. A spent one stays with the record
+            # of the tick in flight, which folds and retires it (its pages are
+            # its own until then: the new request's were allocated beside them)
+            slot = self.slots[slot_idx] = _Slot(
+                lane=slot_idx, request_id=req.request_id, pages=pages,
+                length=len(tok_ids), prompt_tokens=len(tok_ids), max_new=req.max_new,
+                temperature=req.temperature, top_k=req.top_k, active=True,
+                shared_tokens=shared, prefix_node=match_node,
+                prompt_ids=list(tok_ids) if self._radix is not None else None,
+                submit_t=req.submit_t, admit_t=time.perf_counter(), trace_id=req.trace_id,
+                prefill_segments=0 if chunked else 1,
+                prefill_todo=list(tok_ids[shared:]) if chunked else None,
+                start_snap=match_snap,
+                choices={name: np.full(
+                    (getattr(self.cfg, "n_routed_layers", self.cfg.n_layers),
+                     self.max_pages_per_seq * self.page_size, depth), -1, np.int32)
+                    for name, depth in self._choice_depths.items()} if self.keep_choices else None,
+            )
             if paged > shared:  # pages matched past the state the cache kept: computed again
                 slot.cut_from = paged
                 self._ssm_state_pending["cut_back_tokens"] += paged - shared
             if match_snap is not None:
                 self._radix.snap_pin(match_snap)
-            slot.prompt_ids = list(tok_ids) if self._radix is not None else None
-            slot.donated = []
-            slot.submit_t = req.submit_t
-            slot.admit_t = time.perf_counter()
-            slot.trace_id = req.trace_id
-            slot.prefill_segments = 0 if chunked else 1
-            slot.prefill_todo = list(tok_ids[shared:]) if chunked else None
-            slot.prefill_done = 0
-            slot.choice_parts = []
-            slot.choices = {name: np.full(
-                (getattr(self.cfg, "n_routed_layers", self.cfg.n_layers),
-                 self.max_pages_per_seq * self.page_size, depth), -1, np.int32)
-                for name, depth in self._choice_depths.items()} if self.keep_choices else None
-            slot.active = True
             shared_blocks = shared // self.page_size
             row = np.zeros(self.max_pages_per_seq, np.int32)
             if shared_blocks:
@@ -2587,7 +2663,8 @@ class ContinuousBatchingEngine:
         """Compute per-row budgets, merge freshly admitted rows into the
         device-carried decode state, and dispatch ONE fused multi-step scan.
         No host fetch happens here — the returned record is harvested later
-        (immediately at pipeline depth 1, one step() later at depth 2)."""
+        (immediately at pipeline depth 1, one step() later at depth 2) into
+        the slots it names, whoever holds their lanes by then."""
         pending, self._pending_first = self._pending_first, []
         remaining = np.zeros(self.max_slots, np.int32)
         for i, slot in enumerate(self.slots):
@@ -2595,31 +2672,12 @@ class ContinuousBatchingEngine:
                 continue
             if slot.prefill_todo is not None:
                 continue  # mid-chunked-prefill: no decode budget, no retire
-            capacity = slot.shared_tokens + len(slot.pages) * self.page_size
-            # a pending (still-on-device) first token and any sub-steps
-            # already granted to an unharvested tick count against the
-            # budget exactly as if they had been folded
-            base_emit = (
-                len(slot.emitted) + slot.inflight_steps
-                + (1 if slot.pending_first else 0)
-            )
-            written = slot.length + slot.inflight_steps
-            # spec mode reserves verify-block headroom inside capacity.
-            # Admission over-allocates by the same amount, EXCEPT when the
-            # request already hits the max_pages_per_seq window — there the
-            # headroom comes out of the emission budget, so window-limited
-            # requests finish up to spec_k+1 tokens earlier than the plain
-            # engine would (documented in runtime/paged_spec.py)
-            spec_head = (self.spec_k + 1) if self._spec_tick is not None else 0
-            remaining[i] = max(
-                min(slot.max_new - base_emit,
-                    capacity - 1 - spec_head - written), 0
-            )
+            remaining[i] = self._remaining(slot)
             if (remaining[i] == 0 and not slot.pending_first
                     and slot.inflight_steps == 0):
                 # defensive: a zero-budget row with nothing in flight can't
                 # progress
-                self._finished_buffer.append(self._retire(i, "length"))
+                self._finished_buffer.append(self._retire(slot, "length"))
         # adaptive tick size, scaled by backlog depth: waiting requests
         # (engine queue + the serving layer's inbox, via pressure_hint) cap
         # the tick so admission waits fewer decode sub-steps the deeper the
@@ -2662,15 +2720,13 @@ class ContinuousBatchingEngine:
                     vals = np.asarray(first_dev)
                     lps = np.asarray(first_lp_dev)
                 for r, i in enumerate(slot_idxs):
-                    if not self.slots[i].active:
+                    slot = self.slots[i]
+                    if not slot.active:
                         continue
-                    self.slots[i].pending_first = False
-                    self._note_ttft(self.slots[i])
-                    self._last_tok[i] = int(vals[r])
-                    self._lp_sum[i] = lps[r]
-                    self._lp_min[i] = lps[r]
-                    self._lp_cnt[i] = 1
-                    result = self._fold_and_maybe_retire(i)
+                    slot.pending_first = False
+                    self._note_ttft(slot)
+                    slot.lp = (float(lps[r]), float(lps[r]), 1)
+                    result = self._fold_and_maybe_retire(slot, int(vals[r]))
                     if result is not None:
                         self._finished_buffer.append(result)
             return None
@@ -2681,12 +2737,15 @@ class ContinuousBatchingEngine:
         # merge. Jit dispatches are async; eager index-update ops and
         # explicit jnp.asarray uploads each block.
         if self._dev_state is None:
+            # the first dispatch since construction or a reset: every row that
+            # decodes in it was admitted in this step, and the merge below
+            # seeds its token, length and logprob accumulators
             tok_in = self._last_tok.copy()
             lens_in = self._lens.copy()
             halted_in = np.zeros(self.max_slots, bool)
-            lp_sum_in = self._lp_sum.copy()
-            lp_min_in = self._lp_min.copy()
-            lp_cnt_in = self._lp_cnt.copy()
+            lp_sum_in = np.zeros(self.max_slots, np.float32)
+            lp_min_in = np.zeros(self.max_slots, np.float32)
+            lp_cnt_in = np.zeros(self.max_slots, np.int32)
         else:
             (tok_in, lens_in, halted_in,
              lp_sum_in, lp_min_in, lp_cnt_in) = self._dev_state
@@ -2784,10 +2843,12 @@ class ContinuousBatchingEngine:
                 "live": sum(s.active for s in self.slots),
                 "kv_pages": kv_pages,
                 "pending_slots": set(pending_slots),
-                # request ids pin each lane: a slot retired at harvest time
-                # and re-admitted before THIS record is harvested must not
-                # have the old request's speculative tokens replayed into it
-                "rids": [s.request_id for s in self.slots]}
+                # the slots this tick was dispatched with, lane by lane: the
+                # harvest folds its tokens into THEM. A lane handed on since
+                # (``_spent``), or retired by a cancel and refilled, holds
+                # another request's slot by then, which must not be replayed
+                # the old one's tokens
+                "slots": list(self.slots)}
 
     def _harvest(self, record: dict) -> list[PagedResult]:
         """Fetch a dispatched tick's packed tokens ([1 + steps, B] — the ONE
@@ -2795,7 +2856,10 @@ class ContinuousBatchingEngine:
         executed sub-step is exactly one old-style tick — write counted,
         token folded, retirement checked. Execution-mask reconstruction: a
         row runs until its budget (host-known) or the step after its first
-        EOS (visible in packed) — identical to the device's halting rule."""
+        EOS (visible in packed) — identical to the device's halting rule.
+        Everything is folded into the RECORD's slots; the lane-indexed host
+        mirrors are written only for a slot that still owns its lane (one
+        whose lane was handed on retires here without touching it)."""
         budgets = record["budgets"]
         packed = np.asarray(record["packed"])
         harvested(record["stamp"])  # a harvest long after its tick was done: a stall of the pump's
@@ -2814,24 +2878,24 @@ class ContinuousBatchingEngine:
         lp_rows = np.asarray(lp_state) if lp_state is not None else None
         finished: list[PagedResult] = []
         useful = 0
-        for i, slot in enumerate(self.slots):
-            if not slot.active or slot.request_id != record["rids"][i]:
-                continue  # lane retired+reused since dispatch: stale tokens
+        for i, slot in enumerate(record["slots"]):
+            if not slot.active:
+                continue  # retired since dispatch (cancelled): stale tokens
+            owns = self.slots[i] is slot  # else: its lane was handed on
             consumed = int(budgets[i])
             if consumed or i in record["pending_slots"]:
                 slot.inflight_steps = max(slot.inflight_steps - consumed, 0)
             else:
                 continue
             if lp_rows is not None:
-                self._lp_sum[i] = lp_rows[0, i]
-                self._lp_min[i] = lp_rows[1, i]
-                self._lp_cnt[i] = int(lp_rows[2, i])
+                slot.lp = (float(lp_rows[0, i]), float(lp_rows[1, i]), int(lp_rows[2, i]))
             if slot.pending_first and i in record["pending_slots"]:
                 slot.pending_first = False
                 self._note_ttft(slot)
-                echo = packed[i, 0] if spec else packed[0, i]
-                self._last_tok[i] = int(echo)
-                result = self._fold_and_maybe_retire(i)
+                echo = int(packed[i, 0] if spec else packed[0, i])
+                if owns:
+                    self._last_tok[i] = echo
+                result = self._fold_and_maybe_retire(slot, echo)
                 if result is not None:
                     finished.append(result)
                     continue
@@ -2856,10 +2920,11 @@ class ContinuousBatchingEngine:
                 slot.length += 1
                 if self.conv_state and slot.length % self.page_size == 0:
                     self._conv_state_pending["pages"] += 1  # this sub-step's token filled a page
-                self._lens[i] = slot.length
-                self._last_tok[i] = int(toks[s])
+                if owns:
+                    self._lens[i] = slot.length
+                    self._last_tok[i] = int(toks[s])
                 useful += 1
-                result = self._fold_and_maybe_retire(i)
+                result = self._fold_and_maybe_retire(slot, int(toks[s]))
                 if result is not None:
                     finished.append(result)
                     break
@@ -2934,13 +2999,11 @@ class ContinuousBatchingEngine:
         return {"held": int(round(float(held.sum()))),
                 "tabled": steps * self.max_slots * self.max_pages_per_seq}
 
-    def _fold_and_maybe_retire(self, i: int) -> Optional[PagedResult]:
-        """Fold ``_last_tok[i]`` (sampled, not yet forwarded) into slot ``i``;
-        retire on EOS / token budget / page capacity. The ONE place the
-        retirement conditions live — admission-time and decode-replay paths
-        must never diverge, and the decode budgets mirror these bounds."""
-        slot = self.slots[i]
-        tok = int(self._last_tok[i])
+    def _fold_and_maybe_retire(self, slot: _Slot, tok: int) -> Optional[PagedResult]:
+        """Fold ``tok`` (sampled, not yet forwarded) into ``slot``; retire
+        on EOS / token budget / page capacity. The ONE place the retirement
+        conditions live — admission-time and decode-replay paths must never
+        diverge, and the decode budgets (``_remaining``) mirror these bounds."""
         self.decode_tokens_total += 1
         hit_eos = tok == self.tokenizer.eos_id and not self.ignore_eos
         if not hit_eos:
@@ -2949,7 +3012,7 @@ class ContinuousBatchingEngine:
         capacity = slot.shared_tokens + len(slot.pages) * self.page_size
         out_of_pages = slot.length + 1 >= capacity
         if hit_eos or hit_len or out_of_pages:
-            return self._retire(i, "stop" if hit_eos else "length")
+            return self._retire(slot, "stop" if hit_eos else "length")
         return None
 
     def _note_ttft(self, slot: _Slot) -> None:
@@ -2972,10 +3035,10 @@ class ContinuousBatchingEngine:
                 buf[:, start:start + n] = np.asarray(picks[name][:, row, :n])
         return {name: buf[:, :fed].copy() for name, buf in slot.choices.items()}
 
-    def _retire(self, i: int, reason: str) -> PagedResult:
+    def _retire(self, slot: _Slot, reason: str) -> PagedResult:
         """Free a slot's pages (minus any donated to the radix cache), drop
-        its prefix pins, and zero its device-mirror row."""
-        slot = self.slots[i]
+        its prefix pins, and — where it still owns its lane — zero the
+        lane's device-mirror row (a lane handed on is its new request's)."""
         result = PagedResult(
             request_id=slot.request_id,
             text=self.tokenizer.decode(slot.emitted),
@@ -2984,9 +3047,9 @@ class ContinuousBatchingEngine:
             finish_reason=reason,
             prefill_tokens=slot.prompt_tokens - slot.shared_tokens,
             prefix_hit_tokens=slot.shared_tokens,
-            logprob_sum=float(self._lp_sum[i]),
-            logprob_min=float(self._lp_min[i]),
-            logprob_count=int(self._lp_cnt[i]),
+            logprob_sum=slot.lp[0],
+            logprob_min=slot.lp[1],
+            logprob_count=slot.lp[2],
             admit_t=slot.admit_t,
             prefill_segments=slot.prefill_segments,
             choices=self._choices_of(slot),
@@ -3003,25 +3066,19 @@ class ContinuousBatchingEngine:
                 self._radix.snap_pin(slot.start_snap, -1)
             for _boundary, snap in slot.snaps_written:  # never told of: cancelled mid-prefill
                 self._radix.snap_free(snap)
-        slot.start_snap, slot.cut_from, slot.snaps_written = None, 0, []
-        slot.prefix_node = None
-        slot.prompt_ids = None
-        slot.donated = []
+        # a slot is never admitted into again: it has only to read as holding
+        # nothing to whoever still names it (its lane until the next
+        # admission, a record in flight)
         slot.active = False
-        slot.pending_first = False
-        slot.inflight_steps = 0
-        slot.pages = []
-        slot.shared_tokens = 0
-        slot.prefill_todo = None
-        slot.prefill_done = 0
-        self._page_table[i] = 0
-        self._lens[i] = 0
-        self._temps[i] = 0.0
-        self._top_ks[i] = 0
-        self._last_tok[i] = 0
-        self._lp_sum[i] = 0.0
-        self._lp_min[i] = 0.0
-        self._lp_cnt[i] = 0
+        slot.pages, slot.donated, slot.prefix_node, slot.prompt_ids = [], [], None, None
+        slot.start_snap, slot.snaps_written = None, []
+        i = slot.lane
+        if self.slots[i] is slot:
+            self._page_table[i] = 0
+            self._lens[i] = 0
+            self._temps[i] = 0.0
+            self._top_ks[i] = 0
+            self._last_tok[i] = 0
         return result
 
     # ---------------------------------------------------------------- stats
@@ -3057,6 +3114,9 @@ class ContinuousBatchingEngine:
             "ttft_count": self.ttft_count,
             "prefill_tokens": self.prefill_tokens_total,
             "decode_tokens": self.decode_tokens_total,
+            # admissions by the lane they took: one that held no request, or
+            # one handed on while its row's last tick was in flight
+            **{f"lane_admissions_{kind}": n for kind, n in self.lane_admissions_total.items()},
         }
         if self.routed:
             out.update({f"moe_{kind}": n for kind, n in self.moe_total.items()})

@@ -2506,7 +2506,8 @@ class ReplicaSet:
         "active_slots", "max_slots", "queued", "free_pages", "total_pages",
         "pool_hbm_bytes", "conv_state_bytes", "ssm_state_bytes", "ssm_snapshot_bytes", "ssm_snapshots",
         "ssm_snapshots_held", "head_skips", "ttft_count", "prefill_tokens",
-        "decode_tokens", "prefix_hits", "prefix_misses", "prefix_hit_tokens",
+        "decode_tokens", "lane_admissions_free", "lane_admissions_spent",
+        "prefix_hits", "prefix_misses", "prefix_hit_tokens",
         "prefix_miss_tokens", "prefix_cache_pages", "prefix_cache_nodes",
         "queued_inbox", "ticks", "completed", "max_queue", "shed", "expired",
         "cancelled", "requeued", "tick_failures", "pump_leaked",

@@ -1223,7 +1223,7 @@ class PagedGenerationService:
                         metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
                                                  row_steps["moe_pairs"], row_steps["prefill_latent"],
                                                  row_steps["prefill_turns"], row_steps["conv_state"],
-                                                 row_steps["ssm_state"])
+                                                 row_steps["ssm_state"], row_steps["lane_admissions"])
                         for key, val in phase_s.items():
                             self._phase_totals[key] = (
                                 self._phase_totals.get(key, 0.0) + val
@@ -1396,7 +1396,7 @@ class PagedGenerationService:
                     metrics.record_row_steps(row_steps["row_steps"], row_steps["kv_pages"],
                                              row_steps["moe_pairs"], row_steps["prefill_latent"],
                                              row_steps["prefill_turns"], row_steps["conv_state"],
-                                                 row_steps["ssm_state"])
+                                                 row_steps["ssm_state"], row_steps["lane_admissions"])
                 except Exception:  # noqa: BLE001
                     logger.debug("tick telemetry failed", exc_info=True)
                 t_deliver_start = time.perf_counter()
@@ -1527,7 +1527,10 @@ class PagedGenerationService:
                 # a family with Mamba layers: what its prefill rows started from,
                 # snapshots written and evicted, tokens computed again for want
                 # of a snapshot (zeros for any other)
-                "ssm_state": dict(getattr(self.engine, "last_tick_ssm_state", None) or {})}
+                "ssm_state": dict(getattr(self.engine, "last_tick_ssm_state", None) or {}),
+                # the lanes this step's admissions took: free, or spent (handed
+                # on while the old row's last tick was in flight)
+                "lane_admissions": dict(getattr(self.engine, "last_tick_lane_admissions", None) or {})}
 
     def _note_ttft_locked(self, ttft_s: float) -> None:  # lock-held: _mutex
         """Fold one observed TTFT into the EMA admission control projects

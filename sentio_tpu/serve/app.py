@@ -32,6 +32,7 @@ from aiohttp import web
 from sentio_tpu.config import Settings, get_settings
 from sentio_tpu.infra.exceptions import ErrorHandler, RateLimitError, SentioError
 from sentio_tpu.infra.metrics import get_metrics
+from sentio_tpu.infra.phases import LANE_ADMISSION_KINDS
 from sentio_tpu.infra.security import SECURITY_HEADERS, setup_log_sanitization
 from sentio_tpu.runtime.weights import device_stats
 from sentio_tpu.serve.dependencies import DependencyContainer, get_container, set_container
@@ -749,6 +750,10 @@ async def info(request: web.Request) -> web.Response:
                 # ``[rows, tk, tn]``, and the grid steps an expert costs
                 "expert_tiles": serving.get("expert_tiles"),
                 "pool_hbm_bytes": serving.get("pool_hbm_bytes"),
+                # admissions by the lane they took: ``free`` held no request,
+                # ``spent`` was handed on while its row's last tick was in flight
+                "lane_admissions": {kind: serving.get(f"lane_admissions_{kind}")
+                                    for kind in LANE_ADMISSION_KINDS},
                 # a latent family only: what ONE token leaves in the pool a
                 # layer (1,152 B at 512 + 64 in bf16)
                 **({"pool_token_layer_bytes": serving["pool_token_layer_bytes"]}

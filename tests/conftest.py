@@ -43,6 +43,7 @@ def pytest_configure(config):
 _SANITIZED_MODULES = {
     "test_chaos",
     "test_elastic",
+    "test_lane_handover",
     "test_paged",
     "test_paged_sched",
     "test_paged_spec",

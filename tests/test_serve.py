@@ -298,6 +298,7 @@ class TestHealthAndInfo:
             assert set(leaves(info["generator"])) == {
                 "provider", "preset", "verifier", "kv_quant",
                 "paged_attention", "prefill_attention", "page_write", "expert_tiles", "pool_hbm_bytes",
+                "lane_admissions", "lane_admissions.free", "lane_admissions.spent",
                 "speculative",
                 "speculative.draft_configured", "speculative.active",
                 "model", *("model." + f.name for f in
