@@ -706,6 +706,11 @@ class ObservabilityConfig:
     metrics_enabled: bool = True
     monitor_interval_s: float = 30.0
     profiler_dir: str = ""  # where /debug/profile writes when the caller names no dir
+    # the longest window /debug/profile traces, whatever a caller asks: a trace's export
+    # takes time with the device operations it holds (a 4 s window of a routed model of
+    # twelve layers, 0.9 M of them, was exported in 124 s on the chip and its caller had
+    # left), so a deployment whose steps are many small operations bounds the window
+    profile_max_seconds: float = 60.0
 
     @classmethod
     def from_env(cls) -> "ObservabilityConfig":
@@ -713,6 +718,7 @@ class ObservabilityConfig:
             metrics_enabled=_env_bool(["METRICS_ENABLED"], True),
             monitor_interval_s=_env_float(["MONITOR_INTERVAL_S"], 30.0),
             profiler_dir=_env_str(["JAX_PROFILER_DIR"], ""),
+            profile_max_seconds=_env_float(["PROFILE_MAX_SECONDS"], 60.0),
         )
 
 

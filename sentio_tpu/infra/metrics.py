@@ -300,10 +300,11 @@ class MetricsCollector:
             # kernel's walk copies and computes (a row's lens // page + 1,
             # one for a row that holds nothing), tabled = every cell of
             # every page table. held / tabled is the share of a walk of the
-            # table that is work
+            # table that is work; behind_window = blocks the rows hold in
+            # layers whose window no longer reaches them
             "kv_pages": Counter(
                 "sentio_tpu_decode_kv_pages_total",
-                "K/V page blocks of the decode sub-steps, held by rows vs tabled",
+                "K/V page blocks of the decode sub-steps: held by rows, tabled, held behind a window",
                 ["kind"], registry=r,
             ),
             # a routed family's expert layers, counted on the device inside
@@ -649,7 +650,7 @@ class MetricsCollector:
                          ssm_state: Optional[dict] = None,
                          lane_admissions: Optional[dict] = None) -> None:
         """One harvested tick's row-steps by kind (useful / halted / empty),
-        the K/V page blocks of its sub-steps (held / tabled), of a routed
+        the K/V page blocks of its sub-steps (held / tabled / behind_window), of a routed
         family its expert layers' pairs (routed / held) and expert-steps
         (held / touched) — ``MOE_KINDS`` as ``<series>_<kind>`` — of a
         latent family its prefill tokens (new / expanded), chunked

@@ -185,9 +185,11 @@ ROW_STEP_KINDS = ("useful", "halted", "empty")
 # K/V page blocks of the sub-steps the device ran (runtime/paged.py counts
 # them per dispatched tick, by the decode kernel's own rule): `held` the
 # blocks the kernel's walk copies and computes — a row's ``lens // page + 1``,
-# one for a row that holds no request or does not advance — and `tabled`
-# every cell of every page table, what a walk of the table would touch
-KV_PAGE_KINDS = ("held", "tabled")
+# one for a row that holds no request or does not advance — `tabled`
+# every cell of every page table, what a walk of the table would touch, and
+# `behind_window` the blocks an advancing row holds in layers whose window no
+# longer reaches them (the mean over layers, as `held` is; 0 with no window)
+KV_PAGE_KINDS = ("held", "tabled", "behind_window")
 
 # a routed family's expert layers (models/moe.py::expert_layer counts them on
 # the device; runtime/paged.py books them when a tick is harvested): pairs of

@@ -6,7 +6,7 @@ the loader (``runtime/weights.py``), the engine (``runtime/paged.py``), a worker
 (``runtime/worker.py``) and the router (``serve/dependencies.py``) ask of it.
 They ask HERE, by the name a checkpoint's meta carries or by a configuration's
 class, and name no family themselves; a module is imported when it is first
-asked for, so a server imports the family it serves and not six. Adding a
+asked for, so a server imports the family it serves and not seven. Adding a
 family is its module and one line of ``_MODULES``; ``runtime/`` changes only for
 a KIND of state beside the pages that no family has yet (:class:`StateBeside`).
 Nothing here or in a family's module imports ``runtime/`` or ``serve/``: what a
@@ -29,6 +29,7 @@ _MODULES = {
     "lfm2_moe": "sentio_tpu.models.lfm2_moe",
     "nemotron_h": "sentio_tpu.models.nemotron_h",
     "jamba": "sentio_tpu.models.jamba",
+    "mellum": "sentio_tpu.models.mellum",
 }
 
 

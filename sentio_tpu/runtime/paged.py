@@ -132,6 +132,13 @@ class PagedPool:
         )
 
     @property
+    def token_bytes(self) -> int:
+        """Of ``hbm_bytes``, what ONE token keeps in the pages over all the
+        layers that have them (K and V, or the latents; scales with int8
+        pages): whatever a layer's window, a row holds it for every position."""
+        return _tree_bytes((self.k, self.v)) // (self.num_pages * self.page_size)
+
+    @property
     def conv_state_bytes(self) -> int:
         """Of ``hbm_bytes``, the convolution state (0 for a family without)."""
         return _tree_bytes((self.conv, self.tail))
@@ -981,8 +988,9 @@ class ContinuousBatchingEngine:
         # K/V page blocks of those sub-steps, counted at dispatch by the
         # decode kernel's own rule (``_kv_pages``) and booked at harvest:
         # ``held`` what its walk copies and computes, ``tabled`` every cell
-        # of every page table. held / tabled is the share of a walk of the
-        # table that is work
+        # of every page table (held / tabled is the share of a walk of the
+        # table that is work), ``behind_window`` what the rows hold where a
+        # layer's window no longer reaches
         self.kv_pages_total = dict.fromkeys(KV_PAGE_KINDS, 0)
         self.last_tick_kv_pages = dict.fromkeys(KV_PAGE_KINDS, 0)
         # a routed family's expert layers, summed ON THE DEVICE inside the
@@ -2980,8 +2988,9 @@ class ContinuousBatchingEngine:
         budget) is walked at its length then — the host mirror plus what an
         unharvested tick already granted plus ``s`` — every other row for its
         one block. An EOS inside the tick is not known here; the row is
-        counted as advancing to its budget. A few integers a slot, with no
-        device fetch."""
+        counted as advancing to its budget. ``behind_window``: the blocks such
+        a row holds in layers whose window starts past them. A few integers a
+        slot, with no device fetch."""
         from sentio_tpu.kernels.paged_attention import blocks_walked
 
         sub = np.arange(steps)[None, :]
@@ -2992,12 +3001,18 @@ class ContinuousBatchingEngine:
         # (the layers that HAVE pages: a family's attention layers)
         layers = getattr(self.cfg, "attn_layers", range(self.cfg.n_layers))
         windows = Counter(self.cfg.window(i) for i in layers)
-        held = np.where(
-            sub < np.asarray(budgets)[:, None],
-            sum(n * blocks_walked(at, self.page_size, self.max_pages_per_seq, w)
-                for w, n in windows.items()) / len(layers), 1)
+        whole = blocks_walked(at, self.page_size, self.max_pages_per_seq)
+        walked = {w: whole if w is None else blocks_walked(at, self.page_size, self.max_pages_per_seq, w)
+                  for w in windows}
+        advancing = sub < np.asarray(budgets)[:, None]
+        held = np.where(advancing, sum(n * walked[w] for w, n in windows.items()) / len(layers), 1)
+        # and the blocks an advancing row HOLDS in the layers whose window no
+        # longer reaches them (one page table serves every layer): what an
+        # allocator by layer kind would give back; 0 where no window bites
+        behind = np.where(advancing, sum(n * (whole - walked[w]) for w, n in windows.items()) / len(layers), 0)
         return {"held": int(round(float(held.sum()))),
-                "tabled": steps * self.max_slots * self.max_pages_per_seq}
+                "tabled": steps * self.max_slots * self.max_pages_per_seq,
+                "behind_window": int(round(float(behind.sum())))}
 
     def _fold_and_maybe_retire(self, slot: _Slot, tok: int) -> Optional[PagedResult]:
         """Fold ``tok`` (sampled, not yet forwarded) into ``slot``; retire
@@ -3110,6 +3125,7 @@ class ContinuousBatchingEngine:
             # expert costs (the chip's kernel; ``ragged_dot`` elsewhere takes none)
             "expert_tiles": self._expert_tiles,
             "pool_hbm_bytes": self.pool.hbm_bytes,
+            "kv_bytes_per_token": self.pool.token_bytes,
             "head_skips": self._head_skips,
             "ttft_count": self.ttft_count,
             "prefill_tokens": self.prefill_tokens_total,

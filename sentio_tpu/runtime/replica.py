@@ -2584,6 +2584,7 @@ class ReplicaSet:
         first = per[0]
         agg["page_size"] = first.get("page_size")
         agg["kv_quant"] = first.get("kv_quant")
+        agg["kv_bytes_per_token"] = first.get("kv_bytes_per_token")
         agg["paged_attention"] = first.get("paged_attention")
         agg["prefill_attention"] = first.get("prefill_attention")
         agg["page_write"] = first.get("page_write")

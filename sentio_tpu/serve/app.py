@@ -750,6 +750,9 @@ async def info(request: web.Request) -> web.Response:
                 # ``[rows, tk, tn]``, and the grid steps an expert costs
                 "expert_tiles": serving.get("expert_tiles"),
                 "pool_hbm_bytes": serving.get("pool_hbm_bytes"),
+                # of it, what one token keeps in the pages over all layers (``model`` says
+                # which of them attend inside a window: a row holds the pages all the same)
+                "kv_bytes_per_token": serving.get("kv_bytes_per_token"),
                 # admissions by the lane they took: ``free`` held no request,
                 # ``spent`` was handed on while its row's last tick was in flight
                 "lane_admissions": {kind: serving.get(f"lane_admissions_{kind}")
@@ -1022,8 +1025,8 @@ async def debug_flight_summary(request: web.Request) -> web.Response:
 
 async def debug_profile(request: web.Request) -> web.Response:
     """On-demand windowed XLA profiling: arm ``jax.profiler`` for
-    ``?seconds=N`` (0.1–60, default 3) and stop it, writing the device
-    trace under ``?dir=`` / ``JAX_PROFILER_DIR`` / a tmp directory. Every
+    ``?seconds=N`` (0.1–60, default 3; at most ``PROFILE_MAX_SECONDS``, the
+    answer says how long) and stop it, writing the device trace under ``?dir=`` / ``JAX_PROFILER_DIR`` / a tmp directory. Every
     pump iteration runs under a ``decode_tick`` step annotation carrying
     the flight tick number, its phases under ``tick.<phase>`` and the
     request stages under their names (infra/tracing.py), so the host plane
@@ -1052,9 +1055,11 @@ async def debug_profile(request: web.Request) -> web.Response:
         or tempfile.mkdtemp(prefix="sentio-xla-profile-")
     )
     python_tracer = request.query.get("python", "0").lower() in ("1", "true", "yes")
-    outcome = await asyncio.to_thread(profile_window, seconds, log_dir, python_tracer)
+    # the deployment's bound on a window (``PROFILE_MAX_SECONDS``): the answer's ``seconds`` says what was traced
+    window = min(seconds, container.settings.observability.profile_max_seconds)
+    outcome = await asyncio.to_thread(profile_window, window, log_dir, python_tracer)
     status = 200 if outcome.get("started") else 409
-    return web.json_response(outcome, status=status)
+    return web.json_response({**outcome, "asked_seconds": seconds}, status=status)
 
 
 async def auth_token(request: web.Request) -> web.Response:

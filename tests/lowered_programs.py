@@ -14,7 +14,7 @@ tree: a Pallas call's serialized body carries its source's file name. Two
 checkouts on one machine share nothing.
 
 ``fixtures``: the programs of ``tests/test_chip_compile.py``'s fixtures (decode step
-and prior prefill of the benchmark's seven configurations at its widths), lowered
+and prior prefill of the benchmark's eight configurations at its widths), lowered
 for a described v5e, nothing compiled (~100 s a tree). ``engines``: every jit
 family a tiny engine of each registered family dispatches on the CPU — whole
 and chunked admission, a radix hit, with and without the kernels in interpret
@@ -101,7 +101,9 @@ if what == "fixtures":
             tcc._decoder_programs(topo, width, True, tp)
             for name, text in zip(("step", "prefill"), seen):
                 put(f"decoder-{width}-tp{tp}-{name}", text)
-    for fam in ("commanda", "deepseek", "lfm2", "nemotron", "jamba"):
+    for fam in ("commanda", "deepseek", "lfm2", "nemotron", "jamba", "mellum"):
+        if not hasattr(tcc, f"{fam}_programs"):   # a parent tree from before the family came
+            continue
         seen.clear()
         res = raw(getattr(tcc, f"{fam}_programs"))(topo)
         texts = res[-1] if isinstance(res[-1], dict) else {}

@@ -1279,6 +1279,103 @@ def test_jamba_prefill_holds_the_flash_kernel_and_writes_state_and_snapshots_whe
     assert memory["prefill"].temp_size_in_bytes < 0.25e9
 
 
+# ------------------------------ a family whose window is shorter than a row
+#
+# ``mellum`` (models/mellum.py) at the widths of the benchmark's
+# ``mellum2-12b-a2.5b-l12``, a sliding and a full layer: 32 query heads on 4 kv
+# heads of 128 at hidden 2,304, a window of 1,024 keys against tables of 40
+# pages (the decode walk of the sliding layer starts at the window's first
+# block), rotate-half rotary with YaRN's frequencies in the full layer, 64
+# experts of 2304 x 896 — seven tiles of 128 lanes — behind the megablox
+# grouped matmul, an untied head of 98,304 columns; and one prior-prefill
+# program: a segment behind 32 pages — of 384 tokens, a prompt's last: the
+# 4,096 pairs of a 512-token one are an ACTIVATION [4096, 2304] with the shape
+# of ``wq_t``, which the parse of weight copies could not tell from it (the
+# expert layer alone is compiled at 512 tokens further down).
+@pytest.fixture(scope="module")
+def mellum_programs(v5e):
+    from sentio_tpu.models.mellum import FULL, SLIDING, MellumConfig, init_mellum, mellum_forward
+
+    cfg = MellumConfig(n_layers=LAYERS, layer_kinds=f"{SLIDING},{FULL}")
+    place = _on_one_chip(v5e)
+    params = jax.eval_shape(lambda: serving_layout(init_mellum(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree_util.tree_map(
+        lambda a: place(a.shape, jnp.bfloat16 if a.ndim >= 2 else a.dtype), params)
+    slots, nb, page, segment, prior = 8, 40, 128, 384, 32 * 128
+    pool = place((LAYERS, 1 + slots * nb, page, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    impl = make_paged_attn_impl(interpret=False)
+
+    def step(params, tok, lens, table, k_pages, v_pages):
+        def body(carry, _):
+            tok, lens, k_pages, v_pages = carry
+            logits, k_pages, v_pages, routed = paged_decode_forward(
+                params, cfg, tok, lens, table, k_pages, v_pages, attn_impl=impl,
+                write_mask=lens < nb * page - 1, return_routed=True)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), lens + 1,
+                    k_pages, v_pages), routed["experts"]
+
+        return jax.lax.scan(body, (tok, lens, k_pages, v_pages), None, length=2)
+
+    def prefill(params, ids, positions, cache, n_prior):
+        return mellum_forward(params, cfg, ids, positions=positions, cache=cache,
+                              cache_index=n_prior, attn_fn=make_prefill_attn_fn(interpret=False))
+
+    cache = place((LAYERS, 1, prior + segment, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    with _the_chips_grouped_matmul():
+        texts = {
+            "step": jax.jit(step, donate_argnums=(4, 5)).lower(
+                params, place((slots,), jnp.int32), place((slots,), jnp.int32),
+                place((slots, nb), jnp.int32), pool, pool).compile().as_text(),
+            "prefill": jax.jit(prefill, donate_argnums=(3,)).lower(
+                params, place((1, segment), jnp.int32), place((1, segment), jnp.int32),
+                {"k": cache, "v": cache}, place((1,), jnp.int32)).compile().as_text(),
+        }
+    return cfg, params, texts, pool.shape
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_mellum_programs_read_their_weights_where_they_lie(mellum_programs, program):
+    """The v5e compiler takes both programs, and nothing in them makes an array
+    with the shape of a projection ([4096, 2304]), of the table or the head
+    ([2304, 98304]), or of a stack of experts ([64, 2304, 896]: 896 is seven
+    whole tiles of lanes, so ``lane_padded`` pads nothing)."""
+    cfg, params, texts, _ = mellum_programs
+    assert params["layers_0"]["attn"]["wq_t"]["kernel"].shape == (cfg.n_heads * cfg.head_dim, cfg.dim)
+    assert params["lm_head"]["kernel"].shape == (cfg.dim, cfg.vocab_size)
+    assert params["layers_0"]["moe"]["w_up"].shape == (64, 2304, 896)
+    assert _weight_copies(texts[program], params) == []
+    stacks = (f"bf16[{cfg.experts_held},{cfg.dim},{cfg.mlp_dim}]", f"bf16[{cfg.experts_held},{cfg.mlp_dim},{cfg.dim}]")
+    assert [m for m in _pool_shaped(texts[program], stacks)
+            if m[2] not in ("parameter", "get-tuple-element", "bitcast")] == []
+
+
+def test_mellum_decode_step_holds_its_kernels_and_copies_no_pool(mellum_programs):
+    """A layer of the decode step: one walk of the pages (the sliding layer's
+    from its window's first block) and three grouped expert matmuls, each a
+    Pallas call; beside them only the scatter that updates the pool in place
+    makes an array of its size, and nothing one of a layer of it."""
+    cfg, _, texts, pool_shape = mellum_programs
+    # (84 MB a pool at these two layers, 505 at the cell's twelve: the page write stays the scatter)
+    assert page_write_path(jax.ShapeDtypeStruct((12, *pool_shape[1:]), jnp.bfloat16)) == "xla"
+    assert texts["step"].count('custom_call_target="tpu_custom_call"') == LAYERS * 4
+    assert len(re.findall(r"%gmm[.\d]* = ", texts["step"])) == LAYERS * 3
+    hlo_shape = lambda dims: "bf16[" + ",".join(map(str, dims)) + "]"  # noqa: E731
+    made = _pool_shaped(texts["step"], (hlo_shape(pool_shape), hlo_shape(pool_shape[1:])))
+    assert [m for m in made if m[2] not in ("parameter", "get-tuple-element", "scatter", "fusion:scatter")] == []
+    assert sum(m[2] == "fusion:scatter" for m in made) == 2 * LAYERS
+
+
+def test_mellum_prior_prefill_holds_the_flash_kernel_on_both_layer_kinds(mellum_programs):
+    """A sliding and a full layer of a segment behind 32 pages: one flash kernel
+    call each (the window a static term of the same kernel, 8 query heads a kv
+    head by index), three grouped matmuls a layer over the segment's 3,072
+    pairs, and no score tensor over the prior's keys in HBM."""
+    _, _, texts, _ = mellum_programs
+    assert len(re.findall(r"%prefill_attention[.\d]* = ", texts["prefill"])) == LAYERS
+    assert len(re.findall(r"%gmm[.\d]* = ", texts["prefill"])) == LAYERS * 3
+    assert _scores_in_hbm(texts["prefill"], 32 * 128 + 384) == []
+
+
 # ------------------------------------------ the grouped matmul's tiles (PR 43)
 #
 # ``models/moe.py::expert_tile`` sizes the weight tile from the matrix and the
@@ -1288,7 +1385,7 @@ def test_jamba_prefill_holds_the_flash_kernel_and_writes_state_and_snapshots_whe
 # over VMEM is refused HERE, before any chip time.
 
 ROUTED = {"commanda": ("commanda_programs", 32), "deepseek": ("deepseek_programs", 8), "lfm2": ("lfm2_programs", 16),
-          "nemotron": ("nemotron_programs", 16)}
+          "nemotron": ("nemotron_programs", 16), "mellum": ("mellum_programs", 8)}
 
 
 @pytest.mark.parametrize("load", ["decode", "segment", "admission"])
@@ -1308,7 +1405,10 @@ def test_the_expert_layer_compiles_at_the_tiles_the_rule_picks(request, v5e, nam
     assert text.count('custom_call_target="tpu_custom_call"') == calls
     assert len(re.findall(r"%gmm[.\d]* = ", text)) == calls
     rows = {"decode": 32, "segment": 32, "admission": 256}[load]
+    if (name, load) == ("mellum", "segment"):   # eight picks of 64 experts: 4,096 pairs, 64 an expert
+        rows = 64
     assert all(t["tile"][0] == rows and moe.tile_vmem(*t["tile"]) <= moe._GMM_VMEM for t in tiles.values())
     if load != "admission":      # the whole expert in one step, or the contraction whole
         assert [t["steps_per_expert"] for t in tiles.values()] == {
-            "commanda": [8, 8, 8], "deepseek": [3, 3, 2], "lfm2": [1, 1, 1], "nemotron": [3, 3]}[name]
+            "commanda": [8, 8, 8], "deepseek": [3, 3, 2], "lfm2": [1, 1, 1], "nemotron": [3, 3],
+            "mellum": [1, 1, 1]}[name]

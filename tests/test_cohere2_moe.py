@@ -379,5 +379,6 @@ def test_the_walk_and_its_counter_start_at_the_windows_first_block():
                                       max_pages_per_seq=nb)
     engine.slots[0].active, engine.slots[0].length = True, 60
     got = engine._kv_pages([2, 0], 2)
-    # row 0 at 60 and 61: 8 blocks in the full layer, 4 in the sliding one; row 1 one block a sub-step
-    assert got == {"held": (8 + 4) // 2 * 2 + 2, "tabled": 2 * 2 * nb}
+    # row 0 at 60 and 61: 8 blocks in the full layer, 4 in the sliding one (the other 4 it HOLDS lie behind the
+    # window: a mean of 2 over the two layers, each sub-step); row 1 one block a sub-step, and nothing behind
+    assert got == {"held": (8 + 4) // 2 * 2 + 2, "tabled": 2 * 2 * nb, "behind_window": (0 + 4) // 2 * 2}

@@ -7,6 +7,7 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 
 import pytest
 
@@ -392,6 +393,20 @@ class TestFlightEndpoint:
             assert too_long.status == 422
 
         asyncio.run(self._with_client(self._settings(), body))
+
+    def test_debug_profile_window_is_bounded_by_the_deployment(self, recorder, tmp_path):
+        """``PROFILE_MAX_SECONDS``: a longer window asked for is traced that long and the answer says so."""
+        from sentio_tpu.config import ObservabilityConfig
+
+        async def body(client, container):
+            t0 = time.perf_counter()
+            out = await (await client.get(f"/debug/profile?seconds=30&dir={tmp_path}")).json()
+            assert out["started"] is True and (out["seconds"], out["asked_seconds"]) == (0.1, 30.0)
+            assert time.perf_counter() - t0 < 20.0
+
+        settings = self._settings()
+        settings.observability = ObservabilityConfig(profile_max_seconds=0.1)
+        asyncio.run(self._with_client(settings, body))
 
     def test_sse_stream_records_ttft(self, recorder):
         """The SSE path must trace too: X-Request-Id names the record, and

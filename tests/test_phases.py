@@ -378,7 +378,8 @@ class TestRowSteps:
 class TestKvPages:
     """``sentio_tpu_decode_kv_pages_total``: the K/V page blocks of the
     sub-steps the device ran — ``held`` what the decode kernel's walk copies
-    and computes, by its own rule, ``tabled`` every cell of every table."""
+    and computes, by its own rule, ``tabled`` every cell of every table,
+    ``behind_window`` what the rows hold where a layer's window starts later."""
 
     def test_a_hand_counted_tick(self):
         engine = _engine(max_slots=4, page_size=16, max_pages_per_seq=8)
@@ -390,7 +391,8 @@ class TestKvPages:
         # slot 1 is at 15 + 4 in flight: 19, 20 (2 blocks each), then 1, 1: 6
         # slots 2, 3: one block (the scratch page) a sub-step: 4 + 4
         got = engine._kv_pages([3, 2, 0, 0], 4)
-        assert got == {"held": 7 + 6 + 4 + 4, "tabled": 4 * 4 * 8}
+        # (a family with no window holds nothing behind one)
+        assert got == {"held": 7 + 6 + 4 + 4, "tabled": 4 * 4 * 8, "behind_window": 0}
 
     @pytest.mark.parametrize("lens, blocks", [
         (0, 1), (15, 1), (16, 2), (17, 2), (8 * 16 - 1, 8), (8 * 16 + 40, 8)])

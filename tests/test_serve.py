@@ -298,13 +298,15 @@ class TestHealthAndInfo:
             assert set(leaves(info["generator"])) == {
                 "provider", "preset", "verifier", "kv_quant",
                 "paged_attention", "prefill_attention", "page_write", "expert_tiles", "pool_hbm_bytes",
-                "lane_admissions", "lane_admissions.free", "lane_admissions.spent",
+                "kv_bytes_per_token", "lane_admissions", "lane_admissions.free", "lane_admissions.spent",
                 "speculative",
                 "speculative.draft_configured", "speculative.active",
                 "model", *("model." + f.name for f in
                            dataclasses.fields(LlamaConfig))}
             assert info["generator"]["model"] == dataclasses.asdict(
                 LlamaConfig.tiny())
+            # K and V of 2 kv heads of 16 over 2 layers, bf16 pages
+            assert info["generator"]["kv_bytes_per_token"] == 2 * 2 * 2 * 16 * 2
             device = info["device"]
             assert {"platform", "kind", "n_devices", "mesh", "model"} \
                 <= set(device) <= {"platform", "kind", "n_devices", "mesh",
