@@ -67,7 +67,7 @@ LLAMA3_8B = dict(
     norm_eps=1e-5,
 )
 # 32 layers are 16 GB in bf16; 8 leave room on a 16 GB chip for the page
-# pool (1 GB), EncoderConfig.base (2.3 GB as initialised) and the reranker
+# pool (1 GB), EncoderConfig.base (1.1 GB as held: cast to bf16 at load) and the reranker
 SMOKE_LAYERS = 8
 # bge-reranker-base, the width BASELINE.json names for the reranker
 RERANKER_BASE = dict(

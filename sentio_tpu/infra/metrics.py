@@ -153,6 +153,14 @@ class MetricsCollector:
                 "decode service point-in-time stats (occupancy, queue depth, pages)",
                 ["stat"], registry=r,
             ),
+            # what each in-process encoder's weights hold of the device, as
+            # its class keeps them (cast once at load to the forward's dtype:
+            # the README's memory table and /info's ``param_bytes`` read it)
+            "encoder_param_bytes": Gauge(
+                "sentio_tpu_encoder_param_bytes",
+                "bytes of weights an encoder holds on the device",
+                ["model"], registry=r,
+            ),
             "serving_total": Counter(
                 "sentio_tpu_serving_events_total",
                 "decode service lifetime totals", ["event"], registry=r,
@@ -1123,6 +1131,13 @@ class MetricsCollector:
         gauge = self._prom.get("serving_stat")
         if gauge is not None:
             gauge.labels(stat=key).set(value)
+
+    def set_encoder_param_bytes(self, model: str, held: int) -> None:
+        """The weights ``model`` (``embedder`` / ``reranker``) holds, bytes."""
+        self.memory.set_gauge("encoder_param_bytes", (model,), float(held))
+        gauge = self._prom.get("encoder_param_bytes")
+        if gauge is not None:
+            gauge.labels(model=model).set(held)
 
     def bump_serving_total(self, event: str, lifetime_total: float) -> None:
         """Publish a MONOTONIC decode-service total as a Counter (rate()

@@ -5,8 +5,12 @@ explicit parameter pytree + pure apply functions. No module framework: param
 paths are then stable and human-chosen, which is what the tensor-parallel
 partition rules in :mod:`sentio_tpu.parallel.sharding` match on, and the KV
 cache threads through calls as a plain pytree (jit/pjit-friendly, no mutable
-state). Compute dtype is bfloat16 on TPU (MXU-native); params stay float32
-and are cast at use.
+state). Compute dtype is bfloat16 on TPU (MXU-native). A training tree keeps
+float32 masters and the functions here cast at use; a SERVING tree holds each
+leaf in the dtype it is used in, cast once where it is loaded (the decoders'
+checkpoints; ``transformer.serving_dtypes`` for the encoders), and the
+``astype`` of the dtype held emits nothing — a cast at use is a cast on every
+call.
 """
 
 from __future__ import annotations
@@ -55,7 +59,10 @@ def embed_init(rng: Array, vocab: int, dim: int) -> PyTree:
 
 
 def embed(params: PyTree, ids: Array, dtype: jnp.dtype = jnp.bfloat16) -> Array:
-    return params["embedding"].astype(dtype)[ids]
+    # gather, then cast: the same values, and a table held wider than ``dtype``
+    # costs its rows — the compiler does not move a cast through a gather, so
+    # cast-then-gather converted the WHOLE table on every call (PR 52)
+    return params["embedding"][ids].astype(dtype)
 
 
 def layernorm_init(dim: int) -> PyTree:

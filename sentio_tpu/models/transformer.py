@@ -81,6 +81,60 @@ def init_encoder(rng: Array, cfg: EncoderConfig) -> dict:
     return params
 
 
+# subtrees the forwards use in float32 whatever ``cfg.dtype`` says: a LayerNorm
+# (found by its ``scale``) and the cross-encoder's pooler and scalar head
+_FLOAT32_PARTS = ("pooler", "head")
+
+
+def serving_dtypes(params: dict, cfg: EncoderConfig, owned: bool = False) -> tuple[dict, int, int]:
+    """→ (the tree a serving class holds, leaves cast, bytes given back).
+
+    Every leaf in the dtype the forward USES it in — ``cfg.jdtype`` for the
+    embedding tables and the layers' ``kernel`` / ``bias``, float32 for the
+    norms, the pooler and the head — so that no program converts a weight at
+    use: ``astype`` of the dtype held emits nothing, and a cast at use is a
+    cast on EVERY call (a float32 token table was converted whole, 1.0 GB
+    read and 0.5 written, to look up one query's rows). The values are the
+    ones the forward computed with before. Whatever the weights came from:
+    ``init_encoder`` / ``init_cross_encoder`` (float32), a ``cli convert``
+    checkpoint, the benchmark's bf16 one. Training keeps float32 masters and
+    never comes here (eval/train_encoder.py).
+
+    Cast leaf by leaf, the largest first, each wide leaf let go before the
+    next is made: beside the float32 tree the transient is one leaf in the
+    narrow dtype, which is what a forward held before. ``owned`` says the
+    caller made the tree and keeps no other reference, so its containers are
+    reused; a tree someone else holds is left as it was."""
+    if not owned:
+        params = jax.tree_util.tree_map(lambda leaf: leaf, params)  # fresh containers
+    float32 = jnp.dtype(jnp.float32)
+    todo: list[tuple[int, dict, str, jnp.dtype]] = []
+
+    def walk(node: dict, want: jnp.dtype) -> None:
+        if "scale" in node:
+            want = float32
+        for key, child in node.items():
+            if isinstance(child, dict):
+                walk(child, float32 if key in _FLOAT32_PARTS else want)
+            elif jnp.issubdtype(child.dtype, jnp.floating) and child.dtype != want:
+                todo.append((child.nbytes, node, key, want))
+
+    walk(params, cfg.jdtype)
+    given_back = 0
+    for nbytes, node, key, want in sorted(todo, key=lambda item: -item[0]):
+        node[key] = jax.block_until_ready(node[key].astype(want))
+        given_back += nbytes - node[key].nbytes
+    return params, len(todo), given_back
+
+
+def param_summary(params: dict) -> tuple[str, int]:
+    """→ (the dtype most of the tree's bytes are held in, its bytes)."""
+    held: dict[str, int] = {}
+    for leaf in jax.tree_util.tree_leaves(params):
+        held[str(leaf.dtype)] = held.get(str(leaf.dtype), 0) + leaf.nbytes
+    return max(held, key=held.get), sum(held.values())
+
+
 def encoder_forward(
     params: dict,
     cfg: EncoderConfig,

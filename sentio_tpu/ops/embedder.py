@@ -209,6 +209,7 @@ class TpuEmbedder(BaseEmbedder):
             mean_pool,
         )
 
+        owned = params is None  # a tree made here is this object's alone
         if params is None and self.config.checkpoint_path:
             # real weights: a `cli convert encoder` checkpoint + HF tokenizer
             from sentio_tpu.runtime.weights import load_model
@@ -224,9 +225,12 @@ class TpuEmbedder(BaseEmbedder):
         self.tokenizer = tokenizer or ByteTokenizer(self.model_config.vocab_size)
         if params is None:
             params = init_encoder(jax.random.PRNGKey(0), self.model_config)
-        from sentio_tpu.parallel.sharding import ENCODER_TP_RULES, shard_params
+        from sentio_tpu.parallel.sharding import place_encoder
 
-        self.params = shard_params(params, mesh, ENCODER_TP_RULES)
+        # held in the dtype the forward computes in, cast once, here: a weight
+        # cast at use is a weight converted on every call (`/info` says both)
+        self.params, self.param_dtype, self.param_bytes = place_encoder(
+            "embedder", params, self.model_config, mesh, owned)
         self.mesh = mesh
 
         cfg = self.model_config

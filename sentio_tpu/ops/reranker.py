@@ -122,6 +122,7 @@ class CrossEncoderReranker(Reranker):
         from sentio_tpu.models.transformer import EncoderConfig
 
         self.config = config or get_settings().rerank
+        owned = params is None  # a tree made here is this object's alone
         if params is None and self.config.checkpoint_path:
             # real weights: a `cli convert cross-encoder` checkpoint
             from sentio_tpu.runtime.weights import load_model
@@ -135,9 +136,11 @@ class CrossEncoderReranker(Reranker):
         self.tokenizer = tokenizer or ByteTokenizer(self.model_config.vocab_size)
         if params is None:
             params = init_cross_encoder(jax.random.PRNGKey(7), self.model_config)
-        from sentio_tpu.parallel.sharding import ENCODER_TP_RULES, shard_params
+        from sentio_tpu.parallel.sharding import place_encoder
 
-        self.params = shard_params(params, mesh, ENCODER_TP_RULES)
+        # as the embedder's: cast once to what the forward computes in, then placed
+        self.params, self.param_dtype, self.param_bytes = place_encoder(
+            "reranker", params, self.model_config, mesh, owned)
         cfg = self.model_config
         # bidirectional flash kernel for pair scoring — policy lives in
         # kernels.select_encoder_attn_fn (shared with the embedder)

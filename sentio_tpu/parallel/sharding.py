@@ -10,6 +10,7 @@ dim on ``tp``) — so each transformer block needs exactly two psums.
 
 from __future__ import annotations
 
+import logging
 import re
 from typing import Any, Optional, Sequence
 
@@ -17,6 +18,8 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sentio_tpu.parallel.mesh import AXIS_DCN, AXIS_DP, AXIS_EP, AXIS_TP
+
+logger = logging.getLogger(__name__)
 
 # (path regex, PartitionSpec). First match wins; unmatched params replicate.
 # Param paths are "/"-joined pytree key paths, e.g. "layers_0/attn/wq/kernel".
@@ -107,6 +110,24 @@ def shard_params(params: Any, mesh: Optional[Mesh], rules: Rules) -> Any:
         return jax.device_put(params)
     shardings = make_param_shardings(params, mesh, rules)
     return jax.device_put(params, shardings)
+
+
+def place_encoder(model: str, params: Any, cfg: Any, mesh: Optional[Mesh],
+                  owned: bool) -> tuple[Any, str, int]:
+    """An encoder's weights as its serving class keeps them → (tree on the
+    device or mesh, the dtype most of it is held in, its bytes). Cast ONCE,
+    here, to what the forward computes in (models/transformer.py
+    ``serving_dtypes``; ``owned``: the class made the tree, so each wide leaf
+    is let go as it is cast), and only then placed: under ``ENCODER_TP_RULES``
+    the shards are cut from the narrow leaves."""
+    from sentio_tpu.models.transformer import param_summary, serving_dtypes
+
+    params, cast, given_back = serving_dtypes(params, cfg, owned=owned)
+    params = shard_params(params, mesh, ENCODER_TP_RULES)
+    dtype, held = param_summary(params)
+    logger.info("%s weights placed: %d leaves cast at load, %.3f GB given back, %.3f GB held (%s)",
+                model, cast, given_back / 1e9, held / 1e9, dtype)
+    return params, dtype, held
 
 
 def batch_sharding(mesh: Mesh, ndim: int = 2) -> NamedSharding:

@@ -696,6 +696,14 @@ def _speculative_info(container: DependencyContainer) -> dict:
     return out
 
 
+def _params_of(component) -> dict:
+    """What an encoder's weights are HELD as (ops/embedder.py, ops/reranker.py:
+    cast once at load to the dtype the forward computes in) and their bytes on
+    the device; nulls for a fake with no model."""
+    return {"param_dtype": getattr(component, "param_dtype", None),
+            "param_bytes": getattr(component, "param_bytes", None)}
+
+
 def _model_config_of(component) -> Optional[dict]:
     """The config a component's model ACTUALLY runs at (None for fakes with
     no model) — a reranker with no checkpoint is ``EncoderConfig.tiny()``,
@@ -724,11 +732,13 @@ async def info(request: web.Request) -> web.Response:
             "embedder": {
                 "provider": settings.embedder.provider,
                 "model": _model_config_of(container.embedder),
+                **_params_of(container.embedder),
             },
             "reranker": {
                 "enabled": settings.rerank.enabled,
                 "kind": settings.rerank.kind,
                 "model": _model_config_of(container.reranker),
+                **_params_of(container.reranker),
             },
             "generator": {
                 "provider": settings.generator.provider,
@@ -798,6 +808,11 @@ def _publish_serving_gauges(container: DependencyContainer):
     prior rounds collected them in the engine but published them nowhere).
     Returns the stats dict (or None) so callers can embed it without a
     second, skew-prone lookup."""
+    m = get_metrics()
+    for model in ("embedder", "reranker"):  # the weights each encoder holds
+        held = getattr(container.peek(model), "param_bytes", None)
+        if held is not None:
+            m.set_encoder_param_bytes(model, held)
     service = container.peek("generation_service")
     if service is None:  # never built (non-tpu provider / paged off)
         return None
@@ -805,7 +820,6 @@ def _publish_serving_gauges(container: DependencyContainer):
         stats = service.stats()
     except Exception:  # noqa: BLE001 — metrics must not break the scrape
         return None
-    m = get_metrics()
     for key in (
         "active_slots", "queued", "queued_inbox", "free_pages",
         "avg_active_slots", "max_active_slots",

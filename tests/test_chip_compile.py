@@ -422,11 +422,12 @@ def _weight_copies(hlo_text: str, params) -> list:
     return found
 
 
-def _decoder_programs(topo, width: str, served: bool, tp: int = 1):
+def _decoder_programs(topo, width: str, served: bool, tp: int = 1, compiled: bool = True):
     """→ (params, {program: its compiled text}) for two layers at one of the
     benchmark's widths, weights in bf16 as a checkpoint holds them: the decode
     step as ``step_n`` runs it (a scan over the donated pool, the engine's
-    Pallas kernel) and one prefill segment behind 512 prior tokens."""
+    Pallas kernel) and one prefill segment behind 512 prior tokens.
+    ``compiled=False``: the lowered text, nothing compiled."""
     w = dict(WIDTHS[width])
     slots, nb, segment, page = w.pop("slots"), w.pop("nb"), w.pop("segment"), 128
     cfg = LlamaConfig(n_layers=LAYERS, **w)
@@ -469,15 +470,15 @@ def _decoder_programs(topo, width: str, served: bool, tp: int = 1):
 
     cache = place((cfg.n_layers, 1, 512 + segment, cfg.n_kv_heads, cfg.head_dim),
                   jnp.bfloat16, heads)
-    texts = {
+    lowered = {
         "step": jax.jit(step, donate_argnums=(4, 5)).lower(
             params, place((slots,), jnp.int32), place((slots,), jnp.int32),
-            place((slots, nb), jnp.int32), pool, pool).compile().as_text(),
+            place((slots, nb), jnp.int32), pool, pool),
         "prefill": jax.jit(prefill, donate_argnums=(3,)).lower(
             params, place((1, segment), jnp.int32), place((1, segment), jnp.int32),
-            {"k": cache, "v": cache}, place((1,), jnp.int32)).compile().as_text(),
+            {"k": cache, "v": cache}, place((1,), jnp.int32)),
     }
-    return params, texts
+    return params, {name: (low.compile() if compiled else low).as_text() for name, low in lowered.items()}
 
 
 @pytest.fixture(scope="module")
@@ -1412,3 +1413,122 @@ def test_the_expert_layer_compiles_at_the_tiles_the_rule_picks(request, v5e, nam
         assert [t["steps_per_expert"] for t in tiles.values()] == {
             "commanda": [8, 8, 8], "deepseek": [3, 3, 2], "lfm2": [1, 1, 1], "nemotron": [3, 3],
             "mellum": [1, 1, 1]}[name]
+
+
+# ------------------------------------------- the encoders' weights, cast once
+#
+# PR 52. ``L.embed`` was ``table.astype(dtype)[ids]`` and the embedder's leaves
+# float32 (``init_encoder``): the v5e compiler keeps a cast where it is given,
+# so the compiled forward converted the whole ``[250002, 1024]`` table — 1.0 GB
+# read, 0.5 written — to look up one query's 32 rows, and streamed 302 M matrix
+# parameters as float32, on every /chat request (`convert_element_type` in every
+# chat cell's trace). The serving classes now hold each leaf in the dtype the
+# forward uses it in (models/transformer.py::serving_dtypes) and ``L.embed``
+# gathers before it casts. Held to the compiled programs at the benchmark's
+# widths: the embedder ``EncoderConfig.base()`` over one query of 32 tokens, the
+# reranker its configurations' 250002 x 768 in 12 layers over 8 pairs of 512.
+
+RERANKER = dict(vocab_size=250_002, dim=768, n_layers=12, n_heads=12, mlp_dim=3072, max_len=512)
+
+
+def _table_converts(text: str, cfg) -> list:
+    """Instructions that make an array of the token table's shape."""
+    return [line.strip()[:140] for line in text.splitlines()
+            if re.search(rf"= \w+\[{cfg.vocab_size},{cfg.dim}\]\S* (convert|fusion|copy)\(", line)]
+
+
+def _float32_matrices(text: str, cfg) -> set:
+    """Float32 parameters of a layer's matrix shapes, ``[dim, dim]`` / ``[dim, mlp]`` / ``[mlp, dim]``."""
+    shapes = "|".join(f"{a},{b}" for a, b in ((cfg.dim, cfg.dim), (cfg.dim, cfg.mlp_dim), (cfg.mlp_dim, cfg.dim)))
+    found = (re.match(rf"\s+(\S+) = f32\[(?:{shapes})\]\S* parameter\(", line) for line in text.splitlines())
+    return {m.group(1) for m in found if m}
+
+
+@pytest.fixture(scope="module")
+def encoder_programs(v5e):
+    import dataclasses
+
+    from sentio_tpu.models.cross_encoder import cross_encoder_scores, init_cross_encoder
+    from sentio_tpu.models.transformer import (
+        EncoderConfig, encoder_forward, init_encoder, mean_pool, serving_dtypes)
+
+    place = _on_one_chip(v5e)
+
+    def attn(q, k, v, n):   # what kernels.select_encoder_attn_fn picks on the chip
+        return flash_attention(q, k, v, n, causal=False)
+
+    def leaves(init, cfg, held=True):
+        tree = jax.eval_shape(lambda: (serving_dtypes(init(jax.random.PRNGKey(0), cfg), cfg, owned=True)[0]
+                                       if held else init(jax.random.PRNGKey(0), cfg)))
+        return jax.tree_util.tree_map(lambda a: place(a.shape, a.dtype), tree)
+
+    def embed(cfg, held=True):
+        return jax.jit(lambda p, ids, mask: mean_pool(encoder_forward(p, cfg, ids, mask, attn_fn=attn), mask)).lower(
+            leaves(init_encoder, cfg, held), place((1, 32), jnp.int32), place((1, 32), jnp.bool_)).compile()
+
+    base, reranker = EncoderConfig.base(), EncoderConfig(**RERANKER)
+    shallow = dataclasses.replace(base, n_layers=2)
+    return {
+        "embedder": (base, embed(base)),
+        "reranker": (reranker, jax.jit(
+            lambda p, ids, mask, types: cross_encoder_scores(p, reranker, ids, mask, types, attn_fn=attn)).lower(
+            leaves(init_cross_encoder, reranker), place((8, 512), jnp.int32), place((8, 512), jnp.bool_),
+            place((8, 512), jnp.int32)).compile()),
+        # the controls, two layers deep: ``init_encoder``'s float32 leaves as the classes held them until PR 52
+        "float32-leaves": (shallow, embed(shallow, held=False)),
+        "held-shallow": (shallow, embed(shallow)),
+    }
+
+
+@pytest.mark.parametrize("model", ["embedder", "reranker"])
+def test_encoder_forwards_convert_no_weight(encoder_programs, model):
+    """Compiled from the leaves the classes hold: no instruction makes an array
+    of the token table's shape, no matrix arrives as float32, and the Pallas
+    flash kernel is in the program."""
+    cfg, compiled = encoder_programs[model]
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _table_converts(text, cfg) == []
+    assert _float32_matrices(text, cfg) == set()
+
+
+def test_float32_leaves_cost_their_rows_and_are_streamed_wide(encoder_programs, v5e):
+    """The controls. A table that still arrives float32 is gathered and its 32
+    rows cast — ``L.embed``'s order alone spares the table — while the parent's
+    order, compiled by itself, holds the conversion the traces showed; the
+    float32 matrices are found by the parse the test above relies on, six a
+    layer; and the program over the held leaves reads under half the bytes."""
+    cfg, wide = encoder_programs["float32-leaves"]
+    assert _table_converts(wide.as_text(), cfg) == []
+    assert len([name for name in _float32_matrices(wide.as_text(), cfg) if "kernel" in name]) == 6 * cfg.n_layers
+    place = _on_one_chip(v5e)
+    parents = jax.jit(lambda table, ids: table.astype(jnp.bfloat16)[ids]).lower(
+        place((cfg.vocab_size, cfg.dim), jnp.float32), place((1, 32), jnp.int32)).compile().as_text()
+    assert len(_table_converts(parents, cfg)) == 1
+    accessed = {name: encoder_programs[name][1].cost_analysis()["bytes accessed"]
+                for name in ("float32-leaves", "held-shallow")}
+    assert accessed["held-shallow"] < 0.52 * accessed["float32-leaves"], accessed
+
+
+@pytest.fixture(scope="module")
+def decoder_lowered(v5e):
+    """The dense family's decode step and prefill segment at the mistral cell's
+    widths, LOWERED under ``L.embed`` as it is and under the parent's order."""
+    from sentio_tpu.models import layers
+
+    ours = _decoder_programs(v5e, "mistral", True, compiled=False)[1]
+    was, layers.embed = layers.embed, lambda params, ids, dtype=jnp.bfloat16: params["embedding"].astype(dtype)[ids]
+    try:
+        parents = _decoder_programs(v5e, "mistral", True, compiled=False)[1]
+    finally:
+        layers.embed = was
+    return ours, parents
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_decoder_programs_lower_to_the_parents_text(decoder_lowered, program):
+    """Every decoder's table is held in the compute dtype already: ``astype``
+    of it emits nothing whichever side of the gather it stands, and the
+    programs are the parent's, text for text."""
+    ours, parents = decoder_lowered
+    assert "gather" in ours[program] and ours[program] == parents[program]

@@ -163,3 +163,114 @@ class TestEmbedDevice:
                 break
             time.sleep(0.05)
         assert emb.cache.get("fresh text") is not None
+
+
+# ------------------------------------------------- weights held for serving
+#
+# PR 52: a serving class holds each leaf in the dtype its forward uses it in,
+# cast once at load (models/transformer.py::serving_dtypes); the forward's
+# ``astype`` of the dtype held emits nothing, and the numbers are the ones the
+# float32 leaves gave when they were cast at every use.
+
+
+def _float32_parts(tree, under=False):
+    """(path, leaf, is it one the forward uses in float32) of a param tree."""
+    for key, child in tree.items():
+        f32 = under or "scale" in tree or key in ("head", "pooler")
+        if isinstance(child, dict):
+            yield from _float32_parts(child, f32)
+        else:
+            yield key, child, f32
+
+
+class TestWeightsHeldForServing:
+    TEXTS = ["what compiles to xla?", "a rather longer sentence " * 6, "x"]
+
+    def test_embeddings_are_those_of_the_float32_leaves_bit_for_bit(self):
+        import jax
+
+        from sentio_tpu.models.transformer import EncoderConfig, init_encoder
+
+        cfg = EncoderConfig.tiny()
+        params = init_encoder(jax.random.PRNGKey(3), cfg)
+        config = EmbedderConfig(provider="tpu", model_preset="tiny", cache_size=0)
+        held = TpuEmbedder(config, params=params, model_config=cfg)
+        wide = TpuEmbedder(config, model_config=cfg)
+        wide.params = jax.device_put(params)  # float32, cast at use: the parent's embedder
+        assert str(held.params["embed_tokens"]["embedding"].dtype) == "bfloat16"
+        np.testing.assert_array_equal(held.embed_many(self.TEXTS), wide.embed_many(self.TEXTS))
+        np.testing.assert_array_equal(np.asarray(held.embed_device(self.TEXTS)),
+                                      np.asarray(wide.embed_device(self.TEXTS)))
+        # the caller's tree is the caller's still
+        assert all(str(leaf.dtype) == "float32" for leaf in jax.tree_util.tree_leaves(params))
+
+    def test_rerank_scores_are_those_of_the_float32_leaves_bit_for_bit(self):
+        import jax
+
+        from sentio_tpu.config import RerankConfig
+        from sentio_tpu.models.cross_encoder import init_cross_encoder
+        from sentio_tpu.models.document import Document
+        from sentio_tpu.models.transformer import EncoderConfig
+        from sentio_tpu.ops.reranker import CrossEncoderReranker
+
+        cfg = EncoderConfig.tiny()
+        params = init_cross_encoder(jax.random.PRNGKey(5), cfg)
+        params["pooler"] = {"kernel": params["encoder"]["layers_0"]["attn"]["wq"]["kernel"] * 3.0,
+                            "bias": params["encoder"]["layers_0"]["attn"]["wq"]["bias"] + 0.1}
+        held = CrossEncoderReranker(RerankConfig(), params=params, model_config=cfg)
+        wide = CrossEncoderReranker(RerankConfig(), model_config=cfg)
+        wide.params = jax.device_put(params)
+        docs = [Document(text=t) for t in self.TEXTS]
+        np.testing.assert_array_equal(held._score("what is xla", docs), wide._score("what is xla", docs))
+        assert held.param_dtype == "bfloat16"
+        assert held.param_bytes < 0.6 * sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(params))
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("source", ["init", "checkpoint"])
+    def test_every_leaf_is_held_in_the_dtype_the_forward_uses_it_in(self, source, dtype):
+        """Norms, pooler and head float32, everything else ``cfg.jdtype`` — from
+        float32 device leaves (``init_cross_encoder``) and from the host leaves
+        of a checkpoint whose matrices are bf16 and whose biases are float32
+        (benchmark/families/cross_encoder.py), which stay on the host."""
+        import dataclasses
+
+        import jax
+        import jax.numpy as jnp
+
+        from sentio_tpu.models.cross_encoder import init_cross_encoder
+        from sentio_tpu.models.transformer import EncoderConfig, param_summary, serving_dtypes
+
+        cfg = dataclasses.replace(EncoderConfig.tiny(), dtype=dtype)
+        tree = init_cross_encoder(jax.random.PRNGKey(1), cfg)
+        if source == "checkpoint":
+            tree = jax.tree_util.tree_map(
+                lambda leaf: np.asarray(leaf.astype(jnp.bfloat16) if leaf.ndim == 2 else leaf), tree)
+        before = param_summary(tree)[1]
+        held, cast, given_back = serving_dtypes(tree, cfg, owned=True)
+        assert held is tree  # an owned tree's containers are reused: each wide leaf is let go as it is cast
+        for key, leaf, f32 in _float32_parts(held):
+            assert str(leaf.dtype) == ("float32" if f32 else dtype), key
+            assert isinstance(leaf, np.ndarray) == (source == "checkpoint"), key
+        assert param_summary(held) == (dtype, before - given_back)
+        assert (cast > 0) == (given_back != 0) == ((source, dtype) != ("init", "float32"))
+        assert serving_dtypes(held, cfg)[1:] == (0, 0)  # and a second pass finds nothing to do
+
+
+def test_embed_gathers_before_it_casts():
+    """``L.embed`` over a table wider than the compute dtype converts the rows
+    it looked up, never the table: the TPU compiler keeps the order it is
+    given, and the other order converted 1.0 GB to embed one query."""
+    import jax
+    import jax.numpy as jnp
+
+    from sentio_tpu.models import layers as L
+
+    table, ids = jnp.zeros((512, 64), jnp.float32), jnp.zeros((2, 3), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda t, i: L.embed({"embedding": t}, i, jnp.bfloat16))(table, ids)
+    converts = [eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "convert_element_type"]
+    assert [eqn.invars[0].aval.shape for eqn in converts] == [(2, 3, 64)]
+    assert jaxpr.jaxpr.invars[0] not in [eqn.invars[0] for eqn in converts]
+    # and of a table already in the compute dtype nothing is converted at all
+    same = jax.make_jaxpr(lambda t, i: L.embed({"embedding": t}, i, jnp.bfloat16))(
+        table.astype(jnp.bfloat16), ids)
+    assert "convert_element_type" not in str(same)

@@ -277,6 +277,30 @@ class TestHealthAndInfo:
 
         run(with_client(fast_settings(), body))
 
+    def test_info_and_metrics_say_what_the_encoders_hold(self):
+        """``param_dtype`` / ``param_bytes`` of both encoders on /info and the
+        ``sentio_tpu_encoder_param_bytes{model}`` gauge: the weights as the
+        serving classes keep them (cast once at load to the forward's dtype);
+        nulls and no series for a fake that holds no model."""
+
+        async def body(client, container):
+            info = await (await client.get("/info")).json()
+            metrics = await (await client.get("/metrics")).text()
+            return info, metrics
+
+        info, metrics = run(with_client(fast_settings(), body))
+        assert info["embedder"]["param_dtype"] is None and info["reranker"]["param_bytes"] is None
+        settings = fast_settings(
+            embedder=EmbedderConfig(provider="tpu", model_preset="tiny"),
+            rerank=RerankConfig(enabled=True, kind="cross_encoder"))
+        info, metrics = run(with_client(
+            settings, body, container=DependencyContainer(settings=settings, mesh=None)))
+        for model in ("embedder", "reranker"):
+            held = info[model]["param_bytes"]
+            # EncoderConfig.tiny(): 113.9 k parameters, all but the norms' 640 in bf16
+            assert info[model]["param_dtype"] == "bfloat16" and 217_000 < held < 219_000, info[model]
+            assert f'sentio_tpu_encoder_param_bytes{{model="{model}"}} {float(held)}' in metrics
+
     def test_info_and_health_of_the_served_decoder(self):
         """What /info and /health say of the decoder and the device is read
         from outside (the benchmark's server driver checks `generator.model`
