@@ -88,6 +88,9 @@ class FamilyFn:
     def __init__(self, family: str, fn: Any) -> None:
         self.family = family
         self._fn = fn
+        # JAX's own name of what it compiles here: the label the timed
+        # compile account keeps this family under
+        self.program = getattr(fn, "__name__", None)
         self._cache_size_fn = getattr(fn, "_cache_size", None)
         self._seen = 0
         # armed-fence bypass for THIS instance only: a supervised replica
@@ -109,7 +112,7 @@ class FamilyFn:
                 # compile already happened; the error is the report
                 fence.note_compile(
                     self.family, abstract_signature(args, kwargs), delta,
-                    exempt=self.fence_exempt,
+                    exempt=self.fence_exempt, program=self.program,
                 )
         return out
 
@@ -151,6 +154,10 @@ def jit_family(
             **({"donate_argnames": tuple(donate_argnames)} if donate_argnames else {}),
         )
         wrapped = FamilyFn(name, jitted)
+        if wrapped.program:
+            from sentio_tpu.analysis.audit import fence
+
+            fence.register_program(wrapped.program)
         if register:
             with _registry_lock:
                 _REGISTRY[name] = JitFamily(

@@ -18,8 +18,13 @@ import argparse
 import json
 import os
 import sys
+import time
 
 __all__ = ["main"]
+
+# when ``main`` was entered (raw ``perf_counter``): the end of a server's
+# ``import`` phase that the process's start began (infra/startup.py)
+_t_main = time.perf_counter()
 
 
 def _cmd_ingest(args: argparse.Namespace) -> int:
@@ -40,7 +45,15 @@ def _cmd_serve(args: argparse.Namespace) -> int:
     import logging
 
     from sentio_tpu.config import get_settings
+    from sentio_tpu.infra import startup
+    from sentio_tpu.infra.tracing import install_compile_listeners
     from sentio_tpu.serve.app import run_server
+
+    install_compile_listeners()  # imports JAX: every compile from here on is timed
+    # the ``import`` phase: the process's start → the server's own modules
+    # and JAX are imported (``main`` was entered at ``main_entered_s``)
+    startup.stamp_phase("import", startup.process_start(), time.perf_counter(),
+                        main_entered_s=round(_t_main - startup.process_start(), 3))
 
     # a server's start-up story (weights loaded, kernels selected, address
     # bound) is logged at INFO; without a handler it was never printed
@@ -382,6 +395,8 @@ def _cmd_info(args: argparse.Namespace) -> int:
 
 
 def main(argv: list[str] | None = None) -> int:
+    global _t_main
+    _t_main = time.perf_counter()
     from sentio_tpu.infra.compile_cache import ensure_compile_cache
 
     ensure_compile_cache()  # before any subcommand imports JAX

@@ -24,6 +24,8 @@ Layout conventions (Chrome trace event fields):
   and every span the request wrote, each a slice carrying its parent and
   fields — an async or gated audit's ``verify`` span visibly overhangs the
   root, whose record closed when the answer did;
+* the ``startup`` record (infra/startup.py: process start → ready, a slice
+  a phase) is a lane of its own named ``startup``;
 * health transitions ride ``tid 0`` as process-scoped instants.
 
 Timestamps: flight records share one ``perf_counter`` origin
@@ -34,7 +36,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from sentio_tpu.infra.flight import ROOT_SPAN, shift_spans, span_tree
+from sentio_tpu.infra.flight import ROOT_SPAN, STARTUP_ID, shift_spans, span_tree
 from sentio_tpu.infra.phases import TICK_PHASES
 
 __all__ = ["build_chrome_trace", "build_fleet_trace", "flight_to_chrome"]
@@ -109,19 +111,23 @@ def _tick_events(ticks: list[dict]) -> list[dict]:
     return events
 
 
-def _request_events(records: list[dict]) -> tuple[list[dict], dict]:
+def _request_events(records: list[dict]) -> tuple[list[dict], dict, dict]:
     """Request lanes, one per record per replica, laid out from the
     record's ``spans`` (infra/tracing.py wrote them; flight.span_tree adds
     the ``request`` root): one slice a span, its parent and fields as args.
     Returns the events plus {pid: max_tid} so thread-name metadata can be
-    emitted."""
+    emitted, and {(pid, tid): name} of the lanes that are no request's (the
+    ``startup`` track)."""
     events: list[dict] = []
     lanes: dict[int, int] = {}
+    named: dict[tuple, str] = {}
     for record in records:
         pid = int((record.get("engine") or {}).get("replica_id", 0))
         tid = lanes.get(pid, _REQUEST_TID_BASE)
         lanes[pid] = tid + 1
         rid = record.get("request_id", "?")
+        if rid == STARTUP_ID:
+            named[(pid, tid)] = STARTUP_ID
         spans = record.get("spans") or []
         if not spans or spans[0]["name"] != ROOT_SPAN:
             spans = span_tree(record)
@@ -131,7 +137,8 @@ def _request_events(records: list[dict]) -> tuple[list[dict], dict]:
             if root:
                 args.update({k: record[k] for k in
                              ("status", "mode", "endpoint", "question_chars",
-                              "ttft_server_ms", "stages_ms", "stream_lag_max_ms")
+                              "ttft_server_ms", "stages_ms", "stream_lag_max_ms",
+                              "process_start_unix", "ready_s", "phases", "ingest")
                              if k in record})
             else:
                 args["parent"] = sp["parent"]
@@ -140,13 +147,13 @@ def _request_events(records: list[dict]) -> tuple[list[dict], dict]:
                                  ("mode", "outcome", "confidence", "skipped")
                                  if k in record["verify"]})
             events.append({
-                "name": f"request {rid}" if root else sp["name"],
+                "name": (rid if rid == STARTUP_ID else f"request {rid}") if root else sp["name"],
                 "ph": "X", "pid": pid, "tid": tid,
                 "ts": _us(sp["t0_s"]),
                 "dur": _us(sp["t1_s"] - sp["t0_s"]),
                 "args": args,
             })
-    return events, lanes
+    return events, lanes, named
 
 
 def build_chrome_trace(ticks: list[dict], records: list[dict],
@@ -157,7 +164,7 @@ def build_chrome_trace(ticks: list[dict], records: list[dict],
     events: list[dict] = []
     pids: set[int] = set()
     tick_events = _tick_events(ticks)
-    request_events, lanes = _request_events(records)
+    request_events, lanes, named = _request_events(records)
     for event in tick_events + request_events:
         pids.add(event["pid"])
     # metadata rows first: name each replica's process + its lanes
@@ -168,7 +175,8 @@ def build_chrome_trace(ticks: list[dict], records: list[dict],
                        "tid": _PUMP_TID, "args": {"name": "pump"}})
         for tid in range(_REQUEST_TID_BASE, lanes.get(pid, _REQUEST_TID_BASE)):
             events.append({"name": "thread_name", "ph": "M", "pid": pid,
-                           "tid": tid, "args": {"name": f"request lane {tid}"}})
+                           "tid": tid, "args": {"name": named.get((pid, tid),
+                                                                  f"request lane {tid}")}})
     # stable order for byte-stable golden artifacts (Chrome doesn't care)
     events.extend(sorted(
         tick_events + request_events,

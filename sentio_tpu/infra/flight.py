@@ -62,6 +62,9 @@ ROOT_SPAN = "request"
 # the audit's span: the stages of ITS admission hang under it and are kept
 # out of the request's own tile and histogram samples
 AUDIT_SPAN = "verify"
+# the record of the process's own start (infra/startup.py writes it, pinned;
+# infra/chrome_trace.py gives it a track of its own)
+STARTUP_ID = "startup"
 
 
 def shift_spans(spans: list[dict], shift_s: float) -> list[dict]:
@@ -91,7 +94,11 @@ class FlightRecorder:
     operations under one lock; safe to call from the HTTP event loop, graph
     worker threads, and the engine pump thread concurrently."""
 
-    def __init__(self, max_ticks: int = 4096, max_requests: int = 512) -> None:
+    def __init__(self, max_ticks: int = 4096, max_requests: int = 512,
+                 origin: Optional[float] = None) -> None:
+        """``origin``: the timeline's zero as a raw ``perf_counter`` value
+        (the process's recorder counts from the process's start, so the
+        ``startup`` record begins at 0); now when not given."""
         self._lock = make_lock("FlightRecorder._lock")
         self._ticks: deque = deque(maxlen=max_ticks)  # guarded-by: _lock
         self._tick_seq = 0  # guarded-by: _lock
@@ -104,7 +111,13 @@ class FlightRecorder:
         # request id → completion stamps (span name, dispatched, taken up,
         # done; timeline seconds) whose span has not closed yet
         self._device_pending: dict[str, list] = {}  # guarded-by: _lock
-        self._t0 = time.perf_counter()  # timeline origin for tick timestamps
+        # request id → compiles (span name, ended, ms, cache outcome) that ran
+        # inside a span that has not closed yet
+        self._compile_pending: dict[str, list] = {}  # guarded-by: _lock
+        # ids the table never evicts (the ``startup`` record)
+        self._pinned: set[str] = set()  # guarded-by: _lock
+        # timeline origin for tick timestamps
+        self._t0 = time.perf_counter() if origin is None else origin
 
     # ------------------------------------------------------------- requests
 
@@ -246,13 +259,57 @@ class FlightRecorder:
             if fields:
                 entry["fields"] = dict(fields)
             spans.append(entry)
-            waiting = self._device_pending.get(request_id)
-            if waiting:
-                kept = [w for w in waiting if not self._book_device_locked(entry, *w)]
-                if kept:
-                    self._device_pending[request_id] = kept
-                else:
-                    del self._device_pending[request_id]
+            # what landed before this span closed: completion stamps, compiles
+            for pending, book in ((self._device_pending, self._book_device_locked),
+                                  (self._compile_pending, self._book_compile_locked)):
+                waiting = pending.get(request_id)
+                if waiting:
+                    kept = [w for w in waiting if not book(entry, *w)]
+                    if kept:
+                        pending[request_id] = kept
+                    else:
+                        del pending[request_id]
+
+    def pin(self, request_id: str) -> None:
+        """Keep this record whatever the table evicts (the ``startup``
+        record outlives the 512 requests that follow it)."""
+        with self._lock:
+            self._pinned.add(request_id)
+
+    def note_compile_time(self, request_id: str, name: str, t_end: float,
+                          seconds: float, cache: Optional[str]) -> None:
+        """A compile ran inside this request's span ``name`` and ended at
+        ``t_end`` (raw ``perf_counter``; infra/tracing.py's listeners are the
+        writer): the span gains ``compile_ms``, summed over its compiles, and
+        ``compile_cache`` (``miss`` if the backend compiled any of them,
+        ``hit`` if the persistent cache had them all) — kept until the span
+        closes, as a completion stamp that lands early is (a compile ends
+        before the call it ran in returns, so before its span does)."""
+        if not request_id:
+            return
+        stamp = (name, t_end - self._t0, seconds * 1e3, cache)
+        with self._lock:
+            record = self._records.get(request_id)
+            if record is None:
+                return
+            for sp in reversed(record.get("spans", ())):
+                if self._book_compile_locked(sp, *stamp):
+                    return
+            waiting = self._compile_pending.setdefault(request_id, [])
+            if len(waiting) < MAX_DEVICE_PENDING:
+                waiting.append(stamp)
+
+    def _book_compile_locked(self, sp: dict, name: str, t_end: float, ms: float,
+                             cache: Optional[str]) -> bool:
+        assert_held(self._lock)
+        if sp["name"] != name or not (
+                sp["t0_s"] - _SPAN_SLACK_S <= t_end <= sp["t1_s"] + _SPAN_SLACK_S):
+            return False
+        fields = sp.setdefault("fields", {})
+        fields["compile_ms"] = round(fields.get("compile_ms", 0.0) + ms, 3)
+        if cache is not None and fields.get("compile_cache") != "miss":
+            fields["compile_cache"] = cache
+        return True
 
     def note_device_time(self, request_id: str, name: str, t_dispatch: float,
                          t_start: float, t_done: float) -> None:
@@ -353,6 +410,7 @@ class FlightRecorder:
         with self._lock:
             self._stream_puts.pop(request_id, None)
             self._device_pending.pop(request_id, None)
+            self._compile_pending.pop(request_id, None)
             record = self._records.get(request_id)
             if record is None:
                 return
@@ -548,6 +606,8 @@ class FlightRecorder:
             self._records.clear()
             self._stream_puts.clear()
             self._device_pending.clear()
+            self._compile_pending.clear()
+            self._pinned.clear()
             self._tick_seq = 0
             self.dropped_requests = 0
 
@@ -558,10 +618,12 @@ class FlightRecorder:
 
     def _evict_locked(self) -> None:
         assert_held(self._lock)
-        while len(self._records) > self.max_requests:
-            evicted, _ = self._records.popitem(last=False)
+        while len(self._records) > self.max_requests + len(self._pinned & self._records.keys()):
+            evicted = next(rid for rid in self._records if rid not in self._pinned)
+            del self._records[evicted]
             self._stream_puts.pop(evicted, None)
             self._device_pending.pop(evicted, None)
+            self._compile_pending.pop(evicted, None)
             self.dropped_requests += 1
 
 
@@ -574,7 +636,9 @@ def get_flight_recorder() -> FlightRecorder:
     if _recorder is None:
         with _recorder_lock:
             if _recorder is None:
-                _recorder = FlightRecorder()
+                from sentio_tpu.infra import startup
+
+                _recorder = FlightRecorder(origin=startup.process_start())
     return _recorder
 
 

@@ -418,6 +418,34 @@ class MetricsCollector:
                 "seconds the device ran each kind of program, by completion stamps",
                 ["program"], registry=r,
             ),
+            # what a START cost (infra/startup.py tiles process start ->
+            # listening from the ``startup`` flight record: self time a
+            # phase, ``other`` the residual, so the labels sum to ``ready``'s
+            # seconds), what every compile cost by program and part with
+            # what the persistent cache answered (analysis/audit/fence.py's
+            # timed account; infra/tracing.py's jax.monitoring listeners
+            # write it), and an ingest call's stages (ops/ingest.py)
+            "startup_seconds": Gauge(
+                "sentio_tpu_startup_seconds",
+                "process start -> listening, by phase (self time; sums to ready_s)",
+                ["phase"], registry=r,
+            ),
+            "compile_seconds": Counter(
+                "sentio_tpu_compile_seconds_total",
+                "seconds of compiling by program and part "
+                "(trace, lower, backend_miss, backend_hit)",
+                ["program", "part"], registry=r,
+            ),
+            "compile_cache": Counter(
+                "sentio_tpu_compile_cache_total",
+                "backend compiles by program and what JAX's persistent cache answered",
+                ["program", "outcome"], registry=r,
+            ),
+            "ingest_stage_seconds": Counter(
+                "sentio_tpu_ingest_stage_seconds_total",
+                "seconds of ingest calls by stage (chunk, embed, dense_add, sparse_add)",
+                ["stage"], registry=r,
+            ),
             # an encoder forward's two parts: dispatch -> the device took it
             # up (queued behind other programs), took it up -> done (running)
             "encoder_forward": Counter(
@@ -735,6 +763,50 @@ class MetricsCollector:
             counter = self._prom.get(name)
             if counter is not None:
                 counter.labels(label).inc(float(value))
+
+    def set_startup_phases(self, phases: dict) -> None:
+        """The tile of one start, seconds a phase (infra/startup.py). A phase
+        outside ``STARTUP_PHASES`` RAISES: the writer folds into ``other``."""
+        from sentio_tpu.infra.phases import STARTUP_PHASES
+
+        gauge = self._prom.get("startup_seconds")
+        for phase, seconds in phases.items():
+            if phase not in STARTUP_PHASES:
+                raise KeyError(f"unknown phase {phase!r} (bounded set: {STARTUP_PHASES})")
+            self.memory.set_gauge("startup_seconds", (phase,), float(seconds))
+            if gauge is not None:
+                gauge.labels(phase).set(float(seconds))
+
+    def record_compile_time(self, program: str, part: str, seconds: float,
+                            cache: Optional[str] = None) -> None:
+        """``seconds`` of one compile's ``part`` at ``program`` (a label of
+        analysis/audit/fence.py: bounded), and for the backend's part what
+        the persistent cache answered."""
+        from sentio_tpu.infra.phases import CACHE_OUTCOMES, COMPILE_PARTS
+
+        if part not in COMPILE_PARTS or (cache is not None and cache not in CACHE_OUTCOMES):
+            raise KeyError(f"unknown compile part {part!r} or cache outcome {cache!r}")
+        if not self.enabled:
+            return
+        self.memory.inc("compile_seconds", (program, part), float(seconds))
+        if "compile_seconds" in self._prom:
+            self._prom["compile_seconds"].labels(program, part).inc(float(seconds))
+        if cache is not None:
+            self.memory.inc("compile_cache", (program, cache))
+            if "compile_cache" in self._prom:
+                self._prom["compile_cache"].labels(program, cache).inc()
+
+    def record_ingest_stage(self, stage: str, seconds: float) -> None:
+        """``seconds`` of one ingest call's ``stage`` (ops/ingest.py)."""
+        from sentio_tpu.infra.phases import INGEST_STAGES
+
+        if stage not in INGEST_STAGES:
+            raise KeyError(f"unknown ingest stage {stage!r} (bounded set: {INGEST_STAGES})")
+        if not self.enabled:
+            return
+        self.memory.inc("ingest_stage_seconds", (stage,), float(seconds))
+        if "ingest_stage_seconds" in self._prom:
+            self._prom["ingest_stage_seconds"].labels(stage).inc(float(seconds))
 
     def record_duty_cycle(self, replica: int, fractions: dict) -> None:
         """Publish one replica's host/device/idle duty-cycle fractions

@@ -119,6 +119,10 @@ __all__ = [
     "PREFILL_TURN_KINDS",
     "LANE_ADMISSION_KINDS",
     "DEVICE_PROGRAMS",
+    "STARTUP_PHASES",
+    "COMPILE_PARTS",
+    "CACHE_OUTCOMES",
+    "INGEST_STAGES",
     "ENCODER_PROGRAMS",
     "ENCODER_FORWARD_PARTS",
     "TTFT_STAGES",
@@ -255,6 +259,39 @@ DEVICE_PROGRAMS = ("decode", "prefill", "admit", "embed", "rerank", "other")
 # device work and the time they ran (``ENCODER_FORWARD_PARTS``)
 ENCODER_PROGRAMS = ("embed", "rerank")
 ENCODER_FORWARD_PARTS = ("queued", "running")
+
+
+# where a START's time goes, process start → listening (infra/startup.py
+# tiles it from the ``startup`` flight record's spans, as SELF time: a
+# component built inside another's build is its child and is not counted
+# twice): `import` the interpreter and the modules up to the server's own,
+# `backend` the first device enumeration (the TPU runtime's own start), one
+# phase a component built through ``DependencyContainer._get`` that is worth a
+# name, `weights` every checkpoint read and every placement on the device
+# (``weights.read`` / ``weights.place`` spans), `pool.alloc` the page pool,
+# `prefix.warm` the prefix cache's template head, `warmup` the compile
+# fence's sweep where it is armed, `listen` the container built → the socket
+# accepting, and `other` what no named span covers (the components that are
+# no phase of their own among it), explicit so the phases sum to ``ready_s``
+STARTUP_PHASES = (
+    "import", "backend", "mesh", "embedder", "dense_index", "sparse_index",
+    "retriever", "reranker", "decoder", "generation_service", "request_threads",
+    "generator", "verifier", "graph", "ingestor", "weights", "pool.alloc",
+    "prefix.warm", "warmup", "listen", "other",
+)
+
+# a compile's parts (infra/tracing.py's ``jax.monitoring`` listeners book them
+# into analysis/audit/fence.py by program): `trace` the Python tracing of the
+# outermost function (the functions it calls are traced inside it), `lower`
+# jaxpr → MLIR module, and the backend's share as `backend_miss` (XLA
+# compiled) or `backend_hit` (the persistent cache had it: key, read,
+# deserialisation)
+COMPILE_PARTS = ("trace", "lower", "backend_miss", "backend_hit")
+CACHE_OUTCOMES = ("hit", "miss")
+
+# an ingest call's stages (ops/ingest.py): chunking, the embedder's forward
+# over the chunks, the dense index's add and the sparse index's
+INGEST_STAGES = ("chunk", "embed", "dense_add", "sparse_add")
 
 
 def tile_ttft(stage_s: dict, ttft_s: float) -> dict:

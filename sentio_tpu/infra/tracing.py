@@ -25,6 +25,18 @@ interval splits into queued and running
 program was dispatched in gains ``device_queued_ms`` and ``device_ms``
 (infra/flight.py::note_device_time).
 
+**Every compile, timed** (:func:`install_compile_listeners`): JAX's own
+``jax.monitoring`` events — tracing, lowering and the backend's share of every
+compile, each with the function's name, and the persistent cache's answer,
+which fires on the same thread inside the backend's share — are booked by
+program into the compile fence's account (analysis/audit/fence.py:
+``sentio_tpu_compile_seconds_total{program, part}``,
+``sentio_tpu_compile_cache_total{program, outcome}``). Each part runs under a
+``compile.<program>`` annotation, and the span the compile ran in — the
+dispatch's, else the running context's — gains ``compile_ms`` and
+``compile_cache`` (infra/flight.py::note_compile_time). In steady state no
+such event fires and no listener is called.
+
 The request id and the enclosing span travel in a context variable, so a
 stage written where the work happens (``ops/embedder.py``) needs no
 ``request_id`` parameter threaded through every layer above it. A thread
@@ -62,7 +74,8 @@ from sentio_tpu.infra.phases import (
 logger = logging.getLogger(__name__)
 
 __all__ = ["DeviceStamper", "annotation", "close_ttft", "current",
-           "dispatching", "get_stamper", "harvested", "parent_for",
+           "dispatching", "get_stamper", "harvested", "install_compile_listeners",
+           "parent_for",
            "profile_window", "set_stamper", "span", "stamp",
            "stream_written", "tick_annotation"]
 
@@ -227,10 +240,13 @@ class _Dispatch:
         self.armed = threading.Event()
 
     def __enter__(self) -> "_Dispatch":
+        # a compile inside this call is booked on the spans it is dispatched for
+        _compiling.dispatch = self
         return self
 
     def __exit__(self, *exc) -> bool:
         self.t_dispatch = time.perf_counter()
+        _compiling.dispatch = None
         self.armed.set()
         return False
 
@@ -531,6 +547,103 @@ def dispatching(program: str, tick: Optional[int] = None,
 def harvested(seq: int) -> None:
     """The results of the tick stamped ``seq`` are on the host."""
     get_stamper().harvested(seq)
+
+
+# ------------------------------------------------------- every compile, timed
+
+# JAX's events (jax/_src/dispatch.py): each opens with ``record_scalar(event,
+# start, fun_name=)`` and closes with ``record_event_duration_secs(event,
+# seconds, fun_name=)``, on the compiling thread
+_COMPILE_PARTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class _Compiling(threading.local):
+    """Per thread: the dispatch whose call is running (``_Dispatch`` sets
+    it), and the compile parts open here, outermost first:
+    ``[part, program, annotation, cache hit seen]``."""
+
+    def __init__(self) -> None:
+        self.dispatch: Optional[_Dispatch] = None
+        self.open: list[list] = []
+
+
+_compiling = _Compiling()
+_listeners_lock = threading.Lock()
+_listeners_installed = False  # guarded-by: _listeners_lock
+
+
+def _compile_opened(event: str, _value: float, fun_name: str = "", **_kw: Any) -> None:
+    part = _COMPILE_PARTS.get(event)
+    if part is None:
+        return
+    opened = _compiling.open
+    ann = None
+    if not opened:
+        # the outermost part: what opens inside it (the functions a trace
+        # calls, 8,500 of them a start; what a lowering rule traces) is its own
+        program = fence.program_label(fun_name)
+        ann = annotation(f"compile.{program}", part=part)
+        ann.__enter__()
+    else:
+        program = opened[-1][1]
+    opened.append([part, program, ann, False])
+
+
+def _cache_answered(event: str, **_kw: Any) -> None:
+    if event == _CACHE_HIT_EVENT and _compiling.open and _compiling.open[-1][0] == "backend":
+        _compiling.open[-1][3] = True
+
+
+def _compile_closed(event: str, seconds: float, **_kw: Any) -> None:
+    part = _COMPILE_PARTS.get(event)
+    opened = _compiling.open
+    if part is None or not opened:
+        return
+    at = next((i for i in range(len(opened) - 1, -1, -1) if opened[i][0] == part), None)
+    if at is None:
+        return
+    _part, program, ann, hit = opened[at]
+    del opened[at:]  # an opening whose close never came goes with it
+    if ann is None:
+        return  # traced inside another function's trace: that one's seconds
+    t_end = time.perf_counter()
+    ann.__exit__(None, None, None)
+    cache = None
+    if part == "backend":
+        cache = "hit" if hit else "miss"
+        part = f"backend_{cache}"
+    try:
+        fence.note_compile_time(program, part, seconds, cache)
+        dispatch = _compiling.dispatch
+        spans = dispatch.spans if dispatch is not None and dispatch.spans else [current()]
+        recorder = get_flight_recorder()
+        for request_id, name in spans:
+            if request_id and name:
+                recorder.note_compile_time(request_id, name, t_end, seconds, cache)
+    except Exception:  # noqa: BLE001 — telemetry never raises into a compile
+        logger.debug("compile timing failed", exc_info=True)
+
+
+def install_compile_listeners() -> None:
+    """Register the three ``jax.monitoring`` listeners, once a process (the
+    entry points that call ``ensure_compile_cache`` do, and the container's
+    ``initialize_all``). Imports JAX: call it where JAX is about to be
+    imported anyway."""
+    global _listeners_installed
+    with _listeners_lock:
+        if _listeners_installed:
+            return
+        from jax import monitoring
+
+        monitoring.register_scalar_listener(_compile_opened)
+        monitoring.register_event_listener(_cache_answered)
+        monitoring.register_event_duration_secs_listener(_compile_closed)
+        _listeners_installed = True
 
 
 # ------------------------------------------------------- windowed profiler

@@ -34,6 +34,9 @@ from typing import Iterable, Optional, Sequence
 import numpy as np
 
 from sentio_tpu.config import Settings, get_settings
+from sentio_tpu.infra import tracing
+from sentio_tpu.infra.metrics import get_metrics
+from sentio_tpu.infra.phases import INGEST_STAGES
 from sentio_tpu.models.document import Document
 
 logger = logging.getLogger(__name__)
@@ -189,6 +192,10 @@ class IngestStats:
     files_skipped: int = 0
     errors: list[str] = field(default_factory=list)
     elapsed_s: float = 0.0
+    # seconds by stage (``INGEST_STAGES``); no part of ``to_dict``, the
+    # response's shape: ``/info`` and the upload's flight record read it
+    stage_s: dict = field(default_factory=lambda: dict.fromkeys(INGEST_STAGES, 0.0))
+    calls: int = 0
 
     def to_dict(self) -> dict:
         return {
@@ -200,6 +207,24 @@ class IngestStats:
             "errors": self.errors,
             "elapsed_s": round(self.elapsed_s, 3),
         }
+
+
+class _Stage(tracing.span):
+    """One stage of one ingest call: an ``ingest.<stage>`` span (on the
+    upload request's flight record where the call runs for one), its seconds
+    on the call's stats and in ``sentio_tpu_ingest_stage_seconds_total``."""
+
+    __slots__ = ("call", "stage")
+
+    def __init__(self, call: IngestStats, stage: str, **fields) -> None:
+        super().__init__(f"ingest.{stage}", **fields)
+        self.call, self.stage = call, stage
+
+    def __exit__(self, *exc) -> bool:
+        seconds = time.perf_counter() - self._t0
+        self.call.stage_s[self.stage] += seconds
+        get_metrics().record_ingest_stage(self.stage, seconds)
+        return super().__exit__(*exc)
 
 
 class DocumentIngestor:
@@ -336,20 +361,26 @@ class DocumentIngestor:
         chunks are dropped before embedding (reference: ingest.py:291-334).
         Returns THIS call's stats; lifetime totals accumulate on ``.stats``."""
         t0 = time.perf_counter()
-        call = IngestStats(documents_loaded=len(documents))
+        call = IngestStats(documents_loaded=len(documents), calls=1)
 
-        chunks = self.chunker.split(list(documents))
-        chunks = [c for c in chunks if c.text.strip()]
-        call.chunks_created = len(chunks)
+        with _Stage(call, "chunk", docs=len(documents)) as stage:
+            chunks = self.chunker.split(list(documents))
+            chunks = [c for c in chunks if c.text.strip()]
+            stage.fields["chunks"] = call.chunks_created = len(chunks)
         if chunks:
-            vecs = self.embedder.embed_many([c.text for c in chunks])
-            vecs = np.asarray(vecs, np.float32)
+            with _Stage(call, "embed", chunks=len(chunks)):
+                vecs = self.embedder.embed_many([c.text for c in chunks])
+                vecs = np.asarray(vecs, np.float32)
             call.chunks_embedded = len(chunks)
 
             with self._write_lock:
-                self.dense_index.add(chunks, vecs)
+                with _Stage(call, "dense_add", chunks=len(chunks)) as stage:
+                    self.dense_index.add(chunks, vecs)
+                    stage.fields["index_size"] = self.dense_index.size
                 if self._sparse_index is not None:
-                    self._sparse_index.build(self.dense_index.documents())
+                    with _Stage(call, "sparse_add", chunks=len(chunks)) as stage:
+                        self._sparse_index.build(self.dense_index.documents())
+                        stage.fields["index_size"] = getattr(self._sparse_index, "size", None)
             call.chunks_stored = len(chunks)
         call.elapsed_s = time.perf_counter() - t0
         self._accumulate(call)
@@ -362,6 +393,20 @@ class DocumentIngestor:
         s.chunks_embedded += call.chunks_embedded
         s.chunks_stored += call.chunks_stored
         s.elapsed_s += call.elapsed_s
+        s.calls += call.calls
+        for stage, seconds in call.stage_s.items():
+            s.stage_s[stage] += seconds
+
+    def stage_summary(self) -> dict:
+        """Lifetime seconds by stage, as ``/info``'s ``startup.ingest`` gives
+        them: an upload's stages are the third of a warm set-up no one saw."""
+        s = self.stats
+        return {
+            "seconds_total": round(sum(s.stage_s.values()), 6),
+            "stages": {stage: round(seconds, 6) for stage, seconds in s.stage_s.items()},
+            "calls": s.calls, "docs": s.documents_loaded, "chunks": s.chunks_stored,
+            "index_size": self.dense_index.size if self._dense_index is not None else 0,
+        }
 
     def ingest_document(self, text: str, metadata: Optional[dict] = None) -> IngestStats:
         """Single in-memory document — the ``POST /embed`` path (reference:

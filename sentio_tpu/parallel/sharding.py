@@ -120,11 +120,15 @@ def place_encoder(model: str, params: Any, cfg: Any, mesh: Optional[Mesh],
     ``serving_dtypes``; ``owned``: the class made the tree, so each wide leaf
     is let go as it is cast), and only then placed: under ``ENCODER_TP_RULES``
     the shards are cut from the narrow leaves."""
+    from sentio_tpu.infra import tracing
     from sentio_tpu.models.transformer import param_summary, serving_dtypes
 
-    params, cast, given_back = serving_dtypes(params, cfg, owned=owned)
-    params = shard_params(params, mesh, ENCODER_TP_RULES)
-    dtype, held = param_summary(params)
+    with tracing.span("weights.place", model=model) as place:
+        params, cast, given_back = serving_dtypes(params, cfg, owned=owned)
+        params = shard_params(params, mesh, ENCODER_TP_RULES)
+        jax.block_until_ready(params)  # the span's seconds are the placement's
+        dtype, held = param_summary(params)
+        place.fields.update(bytes=held, leaves_cast=cast)
     logger.info("%s weights placed: %d leaves cast at load, %.3f GB given back, %.3f GB held (%s)",
                 model, cast, given_back / 1e9, held / 1e9, dtype)
     return params, dtype, held

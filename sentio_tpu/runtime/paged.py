@@ -69,7 +69,7 @@ from sentio_tpu.infra.phases import (
     CONV_STATE_KINDS, ENGINE_PHASES, KV_PAGE_KINDS, LANE_ADMISSION_KINDS, MOE_KINDS,
     PREFILL_LATENT_KINDS, PREFILL_TURN_KINDS, ROW_STEP_KINDS, SSM_STATE_KINDS, PhaseTimer,
 )
-from sentio_tpu.infra.tracing import annotation, dispatching, get_stamper, harvested
+from sentio_tpu.infra.tracing import annotation, dispatching, get_stamper, harvested, span
 from sentio_tpu.models.families import DecodeStep, family_of
 from sentio_tpu.models.llama import LlamaConfig, serving_layout
 from sentio_tpu.parallel.batcher import bucket_size
@@ -944,11 +944,14 @@ class ContinuousBatchingEngine:
             from sentio_tpu.kernels.paged_attention import lane_packing
 
             self._kv_pack = lane_packing(self.cfg.n_kv_heads, self.cfg.head_dim)
-        self.pool = init_pool(
-            self.cfg, num_pages, page_size, mesh=mesh,
-            quantized=kv_quant == "int8", slots=max_slots, pack=self._kv_pack,
-            snapshots=self._snapshots,
-        )
+        with span("pool.alloc", pages=int(num_pages)) as alloc:
+            self.pool = init_pool(
+                self.cfg, num_pages, page_size, mesh=mesh,
+                quantized=kv_quant == "int8", slots=max_slots, pack=self._kv_pack,
+                snapshots=self._snapshots,
+            )
+            jax.block_until_ready(self.pool.k)  # the span's seconds are the allocation's
+            alloc.fields["bytes"] = int(self.pool.hbm_bytes)
         self.allocator = PageAllocator(num_pages)  # guarded-by: engine-thread
 
         # SENTIO_SANITIZE=1: single-driver-thread guard on mutating entry

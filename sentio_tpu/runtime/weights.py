@@ -25,6 +25,7 @@ import logging
 from dataclasses import dataclass
 from typing import Any, Optional
 
+from sentio_tpu.infra import tracing
 from sentio_tpu.models import families
 from sentio_tpu.runtime.checkpoint import CheckpointError, load_pytree
 
@@ -50,10 +51,15 @@ def load_model(
     cannot silently produce shape errors deep in the first forward pass.
     ``mmap=True`` memory-maps the param leaves in place (process-mode
     replica workers share one page-cache copy per host)."""
-    try:
-        params, meta = load_pytree(checkpoint_path, mmap=mmap)
-    except CheckpointError as exc:
-        raise WeightsError(f"cannot load checkpoint {checkpoint_path!r}: {exc}") from exc
+    # a span of the record it runs for (the ``startup`` record's ``weights``
+    # phase when a component's build reads it): with ``mmap`` the leaves are
+    # mapped, and their bytes are read where they are placed
+    with tracing.span("weights.read", mmap=bool(mmap)) as read:
+        try:
+            params, meta = load_pytree(checkpoint_path, mmap=mmap)
+        except CheckpointError as exc:
+            raise WeightsError(f"cannot load checkpoint {checkpoint_path!r}: {exc}") from exc
+        read.fields["bytes"] = tree_bytes(params)
 
     family = meta.get("family")
     if expect_family and family and family != expect_family:
@@ -151,11 +157,23 @@ def load_decoder(cfg=None, mesh=None, model_config=None, rng_seed: int = 0,
     # q, k and v in the order the serving programs read them, made before
     # placement: a checkpoint's leaves are turned on the host and no second
     # copy of a weight ever reaches the device
-    params = shard_params(serving_layout(params), mesh, family.mesh_rules)
+    with tracing.span("weights.place") as place:
+        params = shard_params(serving_layout(params), mesh, family.mesh_rules)
+        # a placement returns before its bytes have moved: the span waits
+        # for them, so that the seconds are the placement's
+        jax.block_until_ready(params)
+        place.fields["bytes"] = tree_bytes(params)
     return Decoder(
         params=params, model_config=model_config,
         tokenizer=tokenizer or ByteTokenizer(model_config.vocab_size),
     )
+
+
+def tree_bytes(params: Any) -> int:
+    """The bytes a tree of arrays holds (host or device)."""
+    import jax
+
+    return int(sum(getattr(leaf, "nbytes", 0) for leaf in jax.tree_util.tree_leaves(params)))
 
 
 def device_stats(mesh, model_config) -> dict:

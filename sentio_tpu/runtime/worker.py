@@ -111,6 +111,7 @@ from sentio_tpu.infra.exceptions import (
     ReplicaUnavailable,
     SentioError,
 )
+from sentio_tpu.infra.tracing import install_compile_listeners
 from sentio_tpu.runtime.paged import PagedResult
 from sentio_tpu.runtime.service import (
     StreamProgress,
@@ -704,6 +705,7 @@ class _WorkerServer:  # frame-emit: worker-to-router
 def worker_main(conn, spec: WorkerSpec) -> None:
     """Child-process entry point (spawned by :class:`ProcessReplica`)."""
     ensure_compile_cache()
+    install_compile_listeners()  # this process's compiles, timed by program
     # the worker must die with its router even when wedged in XLA: the
     # router holds the other pipe end, so a clean router close() still
     # reaches the recv loop; SIGTERM from terminate() gets a fast exit
@@ -740,6 +742,7 @@ def worker_main_socket(addr, spec: WorkerSpec, slot: int) -> None:
     the same fleet identity instead of allocating a new slot per
     reconnect."""
     ensure_compile_cache()
+    install_compile_listeners()  # this process's compiles, timed by program
     signal.signal(signal.SIGTERM, lambda *_: os._exit(0))
     logging.basicConfig(level=logging.WARNING)
     svc = None
@@ -835,6 +838,7 @@ def worker_serve(
     import socket as _socket
 
     ensure_compile_cache()
+    install_compile_listeners()  # this process's compiles, timed by program
 
     stop = stop_event or threading.Event()
     listener = _socket.socket(_socket.AF_INET, _socket.SOCK_STREAM)

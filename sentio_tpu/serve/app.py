@@ -19,17 +19,19 @@ from __future__ import annotations
 import asyncio
 import concurrent.futures
 import dataclasses
+import itertools
 import json
 import logging
 import os
 import threading
 import time
 from pathlib import Path
-from typing import Optional
+from typing import Any, Optional
 
 from aiohttp import web
 
 from sentio_tpu.config import Settings, get_settings
+from sentio_tpu.infra import startup, tracing
 from sentio_tpu.infra.exceptions import ErrorHandler, RateLimitError, SentioError
 from sentio_tpu.infra.metrics import get_metrics
 from sentio_tpu.infra.phases import LANE_ADMISSION_KINDS
@@ -565,13 +567,34 @@ async def upload(request: web.Request) -> web.Response:
     ops/ingest.py (docx via stdlib zipfile+XML, gated pdf, text formats)
     parse it, then the server chunks + embeds + indexes. Per-file errors
     are reported per file; one bad document never fails the batch."""
+    container: DependencyContainer = request.app["container"]
+    if not (request.content_type or "").startswith("multipart/"):
+        raise SchemaError([{"field": "body", "error": "multipart/form-data required"}])
+    # the request's own flight record: each file's ``ingest.*`` stages are
+    # spans on it (the first 64 of them; the record's ``ingest`` sums them all)
+    from sentio_tpu.infra.flight import get_flight_recorder
+
+    recorder = get_flight_recorder()
+    upload_id = f"upload-{next(_upload_seq)}"
+    recorder.start_request(upload_id, t_received=time.perf_counter(), endpoint="/upload")
+    ingested = {"files": 0, "docs": 0, "chunks": 0, "stage_ms": {}}
+    try:
+        return await _upload(request, container, upload_id, ingested)
+    finally:
+        recorder.finish_request(upload_id, ingest=dict(
+            ingested, index_size=container.dense_index.size,
+            stage_ms={k: round(v, 3) for k, v in ingested["stage_ms"].items()}))
+
+
+_upload_seq = itertools.count(1)
+
+
+async def _upload(request: web.Request, container: DependencyContainer, upload_id: str,
+                  ingested: dict) -> web.Response:
     import tempfile
 
     from sentio_tpu.ops.ingest import SUPPORTED_SUFFIXES
 
-    container: DependencyContainer = request.app["container"]
-    if not (request.content_type or "").startswith("multipart/"):
-        raise SchemaError([{"field": "body", "error": "multipart/form-data required"}])
     reader = await request.multipart()
     files: list[dict] = []
     # one cap for the WHOLE request (all parts): aiohttp's client_max_size
@@ -631,10 +654,16 @@ async def upload(request: web.Request) -> web.Response:
                 return ing.ingest_documents(docs)
 
             try:
-                stats = await asyncio.to_thread(parse_and_index, container.ingestor)
+                with tracing.span("ingest", request_id=upload_id, filename=name):
+                    stats = await asyncio.to_thread(parse_and_index, container.ingestor)
             except Exception as exc:  # noqa: BLE001 — per-file isolation
                 files.append({"filename": name, "error": str(exc)})
                 continue
+        ingested["files"] += 1
+        ingested["docs"] += stats.documents_loaded
+        ingested["chunks"] += stats.chunks_stored
+        for stage, seconds in stats.stage_s.items():
+            ingested["stage_ms"][stage] = ingested["stage_ms"].get(stage, 0.0) + seconds * 1e3
         entry = {"filename": name, **stats.to_dict()}
         if stats.errors:
             entry["error"] = "; ".join(str(e) for e in stats.errors[:3])
@@ -798,8 +827,18 @@ async def info(request: web.Request) -> web.Response:
             # where this process keeps JAX's persistent compile cache
             # (infra/compile_cache.py; None = not placed, e.g. under tests)
             "compile_cache_dir": os.environ.get("JAX_COMPILATION_CACHE_DIR"),
+            # what the start cost (infra/startup.py): process start → ready
+            # tiled by phase, every compile of the process by part and cache
+            # outcome (the warm-up's follow ``ready``), the ingest stages
+            "startup": _startup_info(container),
         }
     )
+
+
+def _startup_info(container: DependencyContainer) -> dict:
+    ingestor = container.peek("ingestor")
+    return {**startup.info(),
+            "ingest": ingestor.stage_summary() if hasattr(ingestor, "stage_summary") else {}}
 
 
 def _publish_serving_gauges(container: DependencyContainer):
@@ -1163,7 +1202,13 @@ def create_app(
                 )
                 fence.arm()
 
-            await asyncio.to_thread(_warm_and_arm)
+            def _warm_and_arm_phase() -> None:
+                with startup.phase("warmup"):
+                    _warm_and_arm()
+
+            await asyncio.to_thread(_warm_and_arm_phase)
+        # everything is built: until the socket accepts is the ``listen`` phase
+        startup.listening_from()
 
     async def on_cleanup(app: web.Application) -> None:
         # graceful drain BEFORE teardown: stop admitting (new submits shed
@@ -1195,4 +1240,13 @@ def run_server(settings: Optional[Settings] = None) -> None:
     settings = settings or get_settings()
     app = create_app(settings=settings)
     logger.info("serving on %s:%d", settings.serve.host, settings.serve.port)
-    web.run_app(app, host=settings.serve.host, port=settings.serve.port, print=None)
+
+    def listening(*_lines: Any, **_kw: Any) -> None:
+        # aiohttp calls its ``print`` once, when every site accepts: the
+        # start is over (infra/startup.py closes and tiles the record)
+        try:
+            startup.mark_ready()
+        except Exception:  # noqa: BLE001 — the account of a start must never stop the server it describes
+            logger.warning("the startup record could not be closed", exc_info=True)
+
+    web.run_app(app, host=settings.serve.host, port=settings.serve.port, print=listening)

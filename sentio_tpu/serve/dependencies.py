@@ -8,6 +8,11 @@ weights, corpus embeddings in HBM — is built ONCE at startup by
 ``initialize_all``, so the first ``/chat`` pays no model cold start. The
 reference instead lazily builds its graph (and scrolls the whole Qdrant
 corpus) on the first request (chat.py:38-87 there).
+
+What that start costs is written here: ``_get`` is the one seam every
+component is built through, and each build runs under a ``startup.<name>``
+span of the ``startup`` flight record (infra/startup.py), a component built
+inside another's ``build()`` as its child.
 """
 
 from __future__ import annotations
@@ -23,6 +28,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Optional
 
 from sentio_tpu.config import Settings, get_settings
+from sentio_tpu.infra import startup
 
 logger = logging.getLogger(__name__)
 
@@ -83,12 +89,12 @@ class DependencyContainer:
         self._cache: dict[str, Any] = dict(overrides)
         self._lock = threading.RLock()
         self._initialized = False
-        self.started_at = time.perf_counter()
 
     def _get(self, name: str, build) -> Any:
         with self._lock:
             if name not in self._cache:
-                self._cache[name] = build()
+                with startup.phase(name):
+                    self._cache[name] = build()
             return self._cache[name]
 
     def override(self, name: str, value: Any) -> None:
@@ -272,6 +278,7 @@ class DependencyContainer:
             decoder = self.decoder
             if decoder is None:
                 return None
+            from sentio_tpu.infra import tracing
             from sentio_tpu.runtime.paged import ContinuousBatchingEngine
             from sentio_tpu.runtime.replica import ReplicaSet
             from sentio_tpu.runtime.service import PagedGenerationService
@@ -651,7 +658,8 @@ class DependencyContainer:
                     mesh=meshes[i],  # pool kv-heads shard over tp with the weights
                 )
                 if warm_head:
-                    shared = paged.warm_prefix(warm_head)
+                    with tracing.span("prefix.warm") as warm:
+                        shared = warm.fields["tokens"] = paged.warm_prefix(warm_head)
                     if shared and i == 0:
                         logger.info(
                             "prefix cache warmed: %d tokens of the /chat "
@@ -829,6 +837,19 @@ class DependencyContainer:
             if self._initialized:
                 return
             t0 = time.perf_counter()
+            from sentio_tpu.infra import tracing
+
+            # every compile from here on is timed by program (the entry
+            # points register the listeners too: once a process)
+            tracing.install_compile_listeners()
+            with startup.phase("backend") as backend:
+                # the first device enumeration starts the accelerator's
+                # runtime: a phase of its own, not whichever component's
+                # build touched JAX first
+                import jax
+
+                devices = jax.devices()
+                backend.fields.update(platform=devices[0].platform, devices=len(devices))
             order = [
                 "mesh", "embedder", "dense_index", "sparse_index", "retriever",
                 "reranker", "decoder", "generation_service", "request_threads",
@@ -840,7 +861,8 @@ class DependencyContainer:
                 getattr(self, name)
                 logger.debug("container: %s ready", name)
             self._initialized = True
-            logger.info("container initialized in %.1fs", time.perf_counter() - t0)
+            logger.info("container initialized in %.1fs, %.1fs after the process started",
+                        time.perf_counter() - t0, startup.uptime_s())
 
     def _close(self, *names: str) -> None:
         for name in names:

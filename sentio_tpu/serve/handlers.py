@@ -19,7 +19,7 @@ import uuid
 from typing import Any, Optional
 
 from sentio_tpu.graph.state import create_initial_state
-from sentio_tpu.infra import tracing
+from sentio_tpu.infra import startup, tracing
 
 logger = logging.getLogger(__name__)
 
@@ -464,7 +464,8 @@ class HealthHandler:
         out = {
             "status": "healthy",
             "service": "sentio-tpu",
-            "uptime_s": round(time.perf_counter() - self.container.started_at, 1),
+            # since the PROCESS started: the ``startup`` record's clock
+            "uptime_s": round(startup.uptime_s(), 1),
         }
         service = self.container.peek("generation_service")
         if service is not None and hasattr(service, "health_summary"):

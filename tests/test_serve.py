@@ -318,7 +318,9 @@ class TestHealthAndInfo:
             assert set(info) == {
                 "service", "version", "retrieval", "embedder", "reranker",
                 "generator", "device", "request_threads",
-                "request_threads_from", "compile_cache_dir"}
+                "request_threads_from", "compile_cache_dir", "startup"}
+            assert set(info["startup"]) == {
+                "process_start_unix", "ready_s", "phases", "weights", "compile", "ingest"}
             assert set(leaves(info["generator"])) == {
                 "provider", "preset", "verifier", "kv_quant",
                 "paged_attention", "prefill_attention", "page_write", "expert_tiles", "pool_hbm_bytes",
