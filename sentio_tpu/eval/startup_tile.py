@@ -139,7 +139,8 @@ def run(args) -> dict:
         out["requests"] = [r for r in warm_requests(chrome) if not r["id"].startswith("upload-")]
         out["metrics"] = [(name, labels, value) for name, labels, value in server.scrape(srv.port)
                           if name.startswith(("sentio_tpu_compile_", "sentio_tpu_startup_",
-                                              "sentio_tpu_ingest_", "sentio_tpu_xla_compiles"))]
+                                              "sentio_tpu_ingest_", "sentio_tpu_bm25_",
+                                              "sentio_tpu_xla_compiles"))]
         srv.terminate()
     finally:
         srv.sweep()

@@ -446,6 +446,12 @@ class MetricsCollector:
                 "seconds of ingest calls by stage (chunk, embed, dense_add, sparse_add)",
                 ["stage"], registry=r,
             ),
+            "bm25_updates": Counter(
+                "sentio_tpu_bm25_updates_total",
+                "sparse index updates of ingest calls: add (the call's chunks "
+                "alone) or build (the whole store again)",
+                ["kind"], registry=r,
+            ),
             # an encoder forward's two parts: dispatch -> the device took it
             # up (queued behind other programs), took it up -> done (running)
             "encoder_forward": Counter(
@@ -807,6 +813,18 @@ class MetricsCollector:
         self.memory.inc("ingest_stage_seconds", (stage,), float(seconds))
         if "ingest_stage_seconds" in self._prom:
             self._prom["ingest_stage_seconds"].labels(stage).inc(float(seconds))
+
+    def record_bm25_update(self, kind: str) -> None:
+        """One ingest call's sparse stage took path ``kind`` (ops/ingest.py)."""
+        from sentio_tpu.infra.phases import BM25_UPDATE_KINDS
+
+        if kind not in BM25_UPDATE_KINDS:
+            raise KeyError(f"unknown bm25 update {kind!r} (bounded set: {BM25_UPDATE_KINDS})")
+        if not self.enabled:
+            return
+        self.memory.inc("bm25_updates", (kind,))
+        if "bm25_updates" in self._prom:
+            self._prom["bm25_updates"].labels(kind).inc()
 
     def record_duty_cycle(self, replica: int, fractions: dict) -> None:
         """Publish one replica's host/device/idle duty-cycle fractions

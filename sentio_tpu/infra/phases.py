@@ -123,6 +123,7 @@ __all__ = [
     "COMPILE_PARTS",
     "CACHE_OUTCOMES",
     "INGEST_STAGES",
+    "BM25_UPDATE_KINDS",
     "ENCODER_PROGRAMS",
     "ENCODER_FORWARD_PARTS",
     "TTFT_STAGES",
@@ -292,6 +293,10 @@ CACHE_OUTCOMES = ("hit", "miss")
 # an ingest call's stages (ops/ingest.py): chunking, the embedder's forward
 # over the chunks, the dense index's add and the sparse index's
 INGEST_STAGES = ("chunk", "embed", "dense_add", "sparse_add")
+# what the sparse stage did: `add` indexed the call's chunks after those held
+# (what the dense index did was append), `build` indexed the store's documents
+# anew (an id written again, a delete, a store that counts otherwise)
+BM25_UPDATE_KINDS = ("add", "build")
 
 
 def tile_ttft(stage_s: dict, ttft_s: float) -> dict:

@@ -20,7 +20,7 @@ import re
 import threading
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Callable, NamedTuple, Optional, Sequence
+from typing import Callable, Iterable, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -45,9 +45,9 @@ class BM25Params:
 
 class _Postings(NamedTuple):
     """One consistent, immutable snapshot of the index state. ``build()``
-    publishes a new snapshot in a single reference assignment AFTER all
-    arrays are final, so concurrent queries read either the old or the new
-    corpus — never a torn mix. Arrays referenced by a published snapshot
+    and ``add()`` publish a new snapshot in a single reference assignment
+    AFTER all arrays are final, so concurrent queries read either the old or
+    the new corpus — never a torn mix. Arrays referenced by a published snapshot
     are never written again."""
 
     term_offsets: np.ndarray
@@ -61,7 +61,8 @@ class _Postings(NamedTuple):
 
 
 class BM25Index:
-    """Immutable-after-build BM25 index.
+    """BM25 index that is built whole (``build``) or grown (``add``); what a
+    query reads is immutable once published.
 
     Layout: ``term_offsets[t]:term_offsets[t+1]`` slices ``post_docs``/
     ``post_tfs`` — the postings of term ``t``. Per-term slices have unique doc
@@ -70,8 +71,9 @@ class BM25Index:
     without the JVM).
 
     Queries read only the :class:`_Postings` snapshot (``self._epoch``), so
-    they are lock-free and safe against a concurrent ``build()``; the vocab
-    is shared across rebuilds and append-only, and snapshot readers bounds-
+    they are lock-free and safe against a concurrent ``build()`` or ``add()``
+    (ONE writer at a time: the ingestor's write lock); the vocab is shared
+    across rebuilds and append-only, and snapshot readers bounds-
     check term ids against their own snapshot's term count.
     """
 
@@ -96,45 +98,78 @@ class BM25Index:
         self.post_tfs = np.zeros(0, dtype=np.float32)
         self.idf = np.zeros(0, dtype=np.float32)
         self._documents: list[Document] = []
+        self._held_ids: set[str] = set()  # the writer's: ``holds_any``
+        self.tokenised = 0  # tokens ``build`` and ``add`` tokenised, lifetime
         self._epoch = self._snapshot()
 
     # ------------------------------------------------------------------ build
 
     def build(self, documents: Sequence[Document]) -> "BM25Index":
-        self._documents = list(documents)
-        self.doc_ids = [d.id for d in documents]
-        n_docs = len(documents)
-        term_postings: dict[int, dict[int, int]] = {}
-        doc_lens = np.zeros(n_docs, dtype=np.float32)
+        """Index ``documents`` alone: an addition to nothing held."""
+        return self._grow(documents, onto_held=False)
+
+    def add(self, documents: Sequence[Document]) -> "BM25Index":
+        """Index ``documents`` after those held. Only they are tokenised; the
+        held postings move in whole-array passes, never in a Python loop, and
+        the index left is ``build``'s of all the documents in the same order,
+        array for array."""
+        return self._grow(documents, onto_held=True) if documents else self
+
+    def _grow(self, documents: Sequence[Document], onto_held: bool) -> "BM25Index":
+        held = len(self.doc_ids) if onto_held else 0
+        offsets = self.term_offsets if onto_held else np.zeros(1, dtype=np.int64)
+        new_lens = np.zeros(len(documents), dtype=np.float32)
+        tids: list[int] = []
+        tfs: list[int] = []
+        terms_a_doc: list[int] = []
         for di, doc in enumerate(documents):
             tokens = self.tokenizer(doc.content)
-            doc_lens[di] = len(tokens)
+            new_lens[di] = len(tokens)
+            counts: dict[int, int] = {}
             for tok in tokens:
                 tid = self.vocab.setdefault(tok, len(self.vocab))
-                postings = term_postings.setdefault(tid, {})
-                postings[di] = postings.get(di, 0) + 1
-        self.doc_lens = doc_lens
-        self.avgdl = float(doc_lens.mean()) if n_docs else 0.0
+                counts[tid] = counts.get(tid, 0) + 1
+            tids.extend(counts)
+            tfs.extend(counts.values())
+            terms_a_doc.append(len(counts))
+        self.tokenised += int(new_lens.sum())
 
+        # a new posting goes to the END of its term's slice: new documents
+        # have the largest ids, so every slice stays sorted by document. The
+        # stable sort keeps one term's new postings in document order, and
+        # np.insert keeps the given order among equal positions
         n_terms = len(self.vocab)
-        lengths = np.zeros(n_terms, dtype=np.int64)
-        for tid, postings in term_postings.items():
-            lengths[tid] = len(postings)
-        self.term_offsets = np.concatenate([[0], np.cumsum(lengths)])
-        total = int(self.term_offsets[-1])
-        self.post_docs = np.zeros(total, dtype=np.int32)
-        self.post_tfs = np.zeros(total, dtype=np.float32)
-        for tid, postings in term_postings.items():
-            start = self.term_offsets[tid]
-            docs = np.fromiter(postings.keys(), dtype=np.int32, count=len(postings))
-            tfs = np.fromiter(postings.values(), dtype=np.float32, count=len(postings))
-            order = np.argsort(docs)
-            self.post_docs[start : start + len(docs)] = docs[order]
-            self.post_tfs[start : start + len(docs)] = tfs[order]
+        new_tids = np.asarray(tids, dtype=np.int64)
+        rows = np.repeat(np.arange(held, held + len(documents), dtype=np.int32), terms_a_doc)
+        order = np.argsort(new_tids, kind="stable")
+        ends = np.full(n_terms, offsets[-1], dtype=np.int64)  # a new term's slice: the end
+        ends[: len(offsets) - 1] = offsets[1:]
+        at = ends[new_tids[order]]
+        post_docs = np.insert(self.post_docs[: offsets[-1]], at, rows[order])
+        post_tfs = np.insert(self.post_tfs[: offsets[-1]], at,
+                             np.asarray(tfs, dtype=np.float32)[order])
+        lengths = np.bincount(new_tids, minlength=n_terms)
+        lengths[: len(offsets) - 1] += np.diff(offsets)
+        doc_lens = np.concatenate([self.doc_lens[:held], new_lens])
+        n_docs = held + len(documents)
         # Robertson-Sparck-Jones idf with 0.5 smoothing, floored at 0 like Lucene
         df = lengths.astype(np.float64)
         with np.errstate(divide="ignore"):
             idf = np.log(1.0 + (n_docs - df + 0.5) / (df + 0.5))
+
+        # new lists and arrays throughout: what a published snapshot (or a
+        # native handle) references is never written again
+        new_ids = [d.id for d in documents]
+        self._documents = self._documents[:held] + list(documents)
+        self.doc_ids = self.doc_ids[:held] + new_ids
+        if onto_held:
+            self._held_ids.update(new_ids)
+        else:
+            self._held_ids = set(new_ids)
+        self.doc_lens = doc_lens
+        self.avgdl = float(doc_lens.mean()) if n_docs else 0.0
+        self.term_offsets = np.concatenate([[0], np.cumsum(lengths)])
+        self.post_docs, self.post_tfs = post_docs, post_tfs
         self.idf = np.maximum(idf, 0.0).astype(np.float32)
         self._finalize_norm()
         # single atomic publish: queries in flight keep the old snapshot
@@ -163,6 +198,11 @@ class BM25Index:
     @property
     def size(self) -> int:
         return len(self.doc_ids)
+
+    def holds_any(self, doc_ids: Iterable[str]) -> bool:
+        """Whether a document of one of ``doc_ids`` is held (a writer's
+        question: such a document is written again, not added)."""
+        return not self._held_ids.isdisjoint(doc_ids)
 
     # ------------------------------------------------------------------ score
 
@@ -276,6 +316,7 @@ class BM25Index:
         index = cls(params=params, tokenizer=tokenizer or default_tokenizer)
         index.vocab = {str(k): int(v) for k, v in meta["vocab"].items()}
         index.doc_ids = list(meta["doc_ids"])
+        index._held_ids = set(index.doc_ids)
         index.avgdl = float(meta["avgdl"])
         index._documents = [Document.from_dict(d) for d in meta["documents"]]
         arrays = np.load(path.with_suffix(".npz"))
@@ -370,14 +411,15 @@ class NativeBM25Index(BM25Index):
         self._box: Optional[_NativeHandle] = None
         self._native_lock = threading.Lock()
 
-    # build() swaps the CSR arrays out from under a live handle — retire it
-    # (in-flight searches finish against the old buffers, then it frees)
-    def build(self, documents: Sequence[Document]) -> "NativeBM25Index":
+    # build() and add() swap the CSR arrays out from under a live handle —
+    # retire it (in-flight searches finish against the old buffers, then it
+    # frees); the next query makes the new one (``_get_box``)
+    def _grow(self, documents: Sequence[Document], onto_held: bool) -> "NativeBM25Index":
         with self._native_lock:
             if self._box is not None:
                 self._box.retire()
                 self._box = None
-            super().build(documents)
+            super()._grow(documents, onto_held)
         return self
 
     def __del__(self) -> None:  # noqa: D105

@@ -36,7 +36,7 @@ import numpy as np
 from sentio_tpu.config import Settings, get_settings
 from sentio_tpu.infra import tracing
 from sentio_tpu.infra.metrics import get_metrics
-from sentio_tpu.infra.phases import INGEST_STAGES
+from sentio_tpu.infra.phases import BM25_UPDATE_KINDS, INGEST_STAGES
 from sentio_tpu.models.document import Document
 
 logger = logging.getLogger(__name__)
@@ -195,6 +195,8 @@ class IngestStats:
     # seconds by stage (``INGEST_STAGES``); no part of ``to_dict``, the
     # response's shape: ``/info`` and the upload's flight record read it
     stage_s: dict = field(default_factory=lambda: dict.fromkeys(INGEST_STAGES, 0.0))
+    # sparse stages by what they did: added the call's chunks, or built anew
+    bm25_updates: dict = field(default_factory=lambda: dict.fromkeys(BM25_UPDATE_KINDS, 0))
     calls: int = 0
 
     def to_dict(self) -> dict:
@@ -232,9 +234,10 @@ class DocumentIngestor:
 
     Components are injected so the serving container shares one embedder and
     one index across ingest + retrieval (the reference's shared-component
-    init, ingest.py:125-170 there). ``sparse_index`` is rebuilt after each
-    ingest batch — BM25 postings build at millions of tokens/s host-side, so
-    rebuild beats incremental bookkeeping at NQ scale.
+    init, ingest.py:125-170 there). ``sparse_index`` takes each call's
+    chunks as an ADDITION where the dense index appended them, and is built
+    anew from the store where it did otherwise (``_update_sparse``): an
+    ingest tokenises what it adds, not the corpus held.
     """
 
     def __init__(
@@ -251,7 +254,7 @@ class DocumentIngestor:
         self._dense_index = dense_index
         self._sparse_index = sparse_index
         self.stats = IngestStats()  # lifetime totals; per-call stats are returned
-        # index mutation (dense add + sparse rebuild) is multi-step and not
+        # index mutation (dense add + sparse add) is multi-step and not
         # atomic — concurrent /embed requests serialize here
         self._write_lock = threading.Lock()
 
@@ -379,12 +382,31 @@ class DocumentIngestor:
                     stage.fields["index_size"] = self.dense_index.size
                 if self._sparse_index is not None:
                     with _Stage(call, "sparse_add", chunks=len(chunks)) as stage:
-                        self._sparse_index.build(self.dense_index.documents())
-                        stage.fields["index_size"] = getattr(self._sparse_index, "size", None)
+                        stage.fields.update(self._update_sparse(call, chunks))
             call.chunks_stored = len(chunks)
         call.elapsed_s = time.perf_counter() - t0
         self._accumulate(call)
         return call
+
+    def _update_sparse(self, call: IngestStats, chunks: Sequence[Document]) -> dict:
+        """The sparse leg of one call, after ``dense_index.add(chunks)``; the
+        ``sparse_add`` span's fields. The dense add is last-write-wins, so
+        the chunks were APPENDED only where none of their ids was held and
+        the store now counts the sparse index's documents plus these: then
+        they are added. Anything else the store reports (an id written again,
+        twice in one call, a delete since) builds from its documents."""
+        sparse = self._sparse_index
+        tokens0 = sparse.tokenised
+        appended = (not sparse.holds_any(c.id for c in chunks)
+                    and self.dense_index.size == sparse.size + len(chunks))
+        path = "add" if appended else "build"
+        if appended:
+            sparse.add(chunks)
+        else:
+            sparse.build(self.dense_index.documents())
+        call.bm25_updates[path] += 1
+        get_metrics().record_bm25_update(path)
+        return {"path": path, "tokens": sparse.tokenised - tokens0, "index_size": sparse.size}
 
     def _accumulate(self, call: IngestStats) -> None:
         s = self.stats
@@ -396,6 +418,8 @@ class DocumentIngestor:
         s.calls += call.calls
         for stage, seconds in call.stage_s.items():
             s.stage_s[stage] += seconds
+        for kind, n in call.bm25_updates.items():
+            s.bm25_updates[kind] += n
 
     def stage_summary(self) -> dict:
         """Lifetime seconds by stage, as ``/info``'s ``startup.ingest`` gives
@@ -404,6 +428,7 @@ class DocumentIngestor:
         return {
             "seconds_total": round(sum(s.stage_s.values()), 6),
             "stages": {stage: round(seconds, 6) for stage, seconds in s.stage_s.items()},
+            "bm25_updates": dict(s.bm25_updates),
             "calls": s.calls, "docs": s.documents_loaded, "chunks": s.chunks_stored,
             "index_size": self.dense_index.size if self._dense_index is not None else 0,
         }

@@ -5,14 +5,20 @@ test_document_ingestor_comprehensive.py there) with the hash-embedder fake
 backend (SURVEY.md §4) — full pipeline, no device model needed.
 """
 
+import itertools
 import json
 import zipfile
 
+import numpy as np
 import pytest
 
 from sentio_tpu.config import EmbedderConfig, Settings
+from sentio_tpu.infra import tracing
+from sentio_tpu.infra.flight import FlightRecorder, set_flight_recorder
+from sentio_tpu.infra.metrics import MetricsCollector, set_metrics
+from sentio_tpu.infra.phases import BM25_UPDATE_KINDS
 from sentio_tpu.models.document import Document
-from sentio_tpu.ops.bm25 import BM25Index
+from sentio_tpu.ops.bm25 import BM25Index, default_tokenizer
 from sentio_tpu.ops.dense_index import TpuDenseIndex
 from sentio_tpu.ops.embedder import HashEmbedder
 from sentio_tpu.ops.ingest import DocumentIngestor, IngestError, ingest_directory
@@ -150,6 +156,132 @@ class TestIngestPipeline:
         (tmp_path / "doc.txt").write_text("directory helper body")
         stats = ingest_directory(tmp_path, settings=settings)
         assert stats.documents_loaded == 1 and stats.chunks_stored >= 1
+
+
+class TestSparseAddition:
+    """The sparse leg of an ingest call: the call's chunks ADDED where the
+    dense index appended them, the store's documents built anew otherwise."""
+
+    @pytest.fixture()
+    def metrics(self):
+        m = MetricsCollector()
+        set_metrics(m)
+        yield m
+        set_metrics(None)
+
+    @pytest.fixture()
+    def traced(self, ingestor):
+        """``traced(docs)`` ingests under a flight record of its own and
+        returns the call's stats and its ``ingest.sparse_add`` span."""
+        rec = FlightRecorder()
+        set_flight_recorder(rec)
+        seq = itertools.count()
+
+        def ingest(docs):
+            rid = f"upload-{next(seq)}"
+            rec.start_request(rid, endpoint="/upload")
+            with tracing.span("ingest", request_id=rid):
+                stats = ingestor.ingest_documents(docs)
+            spans = [s for s in rec.get(rid)["spans"] if s["name"] == "ingest.sparse_add"]
+            rec.finish_request(rid)
+            return stats, spans
+
+        yield ingest
+        set_flight_recorder(None)
+
+    @staticmethod
+    def _updates(metrics):
+        counters = metrics.export_json()["counters"]
+        return {kind: counters.get(f"bm25_updates('{kind}',)", 0) for kind in BM25_UPDATE_KINDS}
+
+    @staticmethod
+    def _assert_is_a_fresh_build(ingestor):
+        """What the index holds is what building it now from the store's
+        documents leaves (the vocabulary outlives a build, so the SAME index
+        is built: term ids are its own)."""
+        sparse = ingestor._sparse_index
+        names = ("term_offsets", "post_docs", "post_tfs", "idf", "doc_lens", "_norm")
+        held = {name: getattr(sparse, name) for name in names}
+        ids, vocab, avgdl = sparse.doc_ids, dict(sparse.vocab), sparse.avgdl
+        sparse.build(ingestor.dense_index.documents())
+        assert (sparse.doc_ids, sparse.vocab, sparse.avgdl) == (ids, vocab, avgdl)
+        for name in names:
+            assert getattr(sparse, name) is not held[name]
+            np.testing.assert_array_equal(held[name], getattr(sparse, name), err_msg=name)
+
+    def test_files_ingested_one_a_call_are_added(self, ingestor, metrics):
+        files = [f"file {i} holds key{i} and key{i % 3} beside common words" for i in range(9)]
+        for i, text in enumerate(files):
+            call = ingestor.ingest_documents([Document(text=text, id=f"f{i}")])
+            assert call.bm25_updates == {"add": 1, "build": 0}
+        assert self._updates(metrics) == {"add": 9, "build": 0}
+        assert ingestor.stage_summary()["bm25_updates"] == {"add": 9, "build": 0}
+        assert ingestor._sparse_index.size == ingestor.dense_index.size == 9
+        self._assert_is_a_fresh_build(ingestor)
+        hits = ingestor._sparse_index.retrieve("key7", top_k=1)  # held when its call returned
+        assert hits and hits[0].metadata["parent_id"] == "f7"
+
+    def test_an_id_written_again_builds_and_is_held_once(self, ingestor, metrics):
+        chunk = lambda text: Document(text=text, id="same")  # noqa: E731
+        ingestor.chunker.split = lambda docs: list(docs)  # a chunk keeps its document's id
+        ingestor.ingest_documents([Document(text="the other file", id="other")])
+        ingestor.ingest_documents([chunk("first wording about walruses")])
+        assert self._updates(metrics) == {"add": 2, "build": 0}
+        call = ingestor.ingest_documents([chunk("second wording about narwhals")])
+        assert call.bm25_updates == {"add": 0, "build": 1}
+        assert self._updates(metrics) == {"add": 2, "build": 1}
+        sparse = ingestor._sparse_index
+        assert sorted(sparse.doc_ids) == ["other", "same"] and ingestor.dense_index.size == 2
+        assert sparse.search("walruses") == [] and len(sparse.search("narwhals")) == 1
+        self._assert_is_a_fresh_build(ingestor)
+        # an id twice in ONE call: the dense add keeps the last, so does the build
+        call = ingestor.ingest_documents([Document(text="twin one", id="t"), Document(text="twin two", id="t")])
+        assert call.bm25_updates == {"add": 0, "build": 1} and sparse.size == 3
+        self._assert_is_a_fresh_build(ingestor)
+
+    def test_a_delete_the_sparse_index_missed_builds(self, ingestor, metrics):
+        ingestor.ingest_documents([Document(text="kept file", id="a")])
+        ingestor.ingest_documents([Document(text="dropped file", id="b")])
+        [dropped] = [d.id for d in ingestor.dense_index.documents() if "dropped" in d.text]
+        ingestor.dense_index.delete([dropped])
+        call = ingestor.ingest_documents([Document(text="late file", id="c")])
+        assert call.bm25_updates == {"add": 0, "build": 1}
+        assert ingestor._sparse_index.size == 2 and ingestor._sparse_index.search("dropped") == []
+        self._assert_is_a_fresh_build(ingestor)
+
+    def test_clear_then_an_ingest(self, ingestor, metrics):
+        ingestor.ingest_documents([Document(text="before the clearing", id="x")])
+        assert ingestor.clear() == 1 and ingestor._sparse_index.size == 0
+        call = ingestor.ingest_documents([Document(text="after the clearing", id="x")])
+        assert call.bm25_updates == {"add": 1, "build": 0}  # the id is no longer held
+        assert ingestor._sparse_index.search("before") == []
+        assert len(ingestor._sparse_index.search("after")) == 1
+        self._assert_is_a_fresh_build(ingestor)
+
+    def test_the_sparse_span_says_its_path_and_its_tokens(self, ingestor, traced):
+        ingestor.chunker.split = lambda docs: list(docs)
+        _, [first] = traced([Document(text="one two three", id="a")])
+        assert first["fields"] == {"chunks": 1, "path": "add", "tokens": 3, "index_size": 1}
+        _, [second] = traced([Document(text="four five", id="b"), Document(text="six", id="c")])
+        assert second["fields"] == {"chunks": 2, "path": "add", "tokens": 3, "index_size": 3}
+        _, [again] = traced([Document(text="one two three four", id="a")])  # all three, anew
+        assert again["fields"] == {"chunks": 1, "path": "build", "tokens": 7, "index_size": 3}
+
+    def test_two_hundred_ingests_tokenise_two_hundred_documents(self, ingestor, traced):
+        """The scaling guard, without a clock: the tokens tokenised are the
+        corpus's (a build a call tokenises about a hundred times as many)."""
+        rng = np.random.default_rng(5)
+        texts = [" ".join(f"t{w}" for w in rng.integers(0, 400, size=rng.integers(8, 30)))
+                 for _ in range(200)]
+        tokens = 0
+        for i, text in enumerate(texts):
+            stats, [span] = traced([Document(text=text, id=f"doc{i}")])
+            assert span["fields"]["path"] == "add" and span["fields"]["index_size"] == i + 1
+            assert stats.chunks_stored == 1
+            tokens += span["fields"]["tokens"]
+        assert tokens == sum(len(default_tokenizer(t)) for t in texts)
+        assert tokens == ingestor._sparse_index.tokenised
+        self._assert_is_a_fresh_build(ingestor)
 
 
 class TestPersistence:

@@ -311,3 +311,47 @@ class TestRebuildConsistency:
         for t in threads:
             t.join()
         assert not errors
+
+
+class TestAddition:
+    @pytest.fixture()
+    def toolchain(self):
+        from sentio_tpu import native
+
+        if native.load_bm25() is None:
+            pytest.skip("no toolchain: the C++ core did not build")
+
+    def test_search_after_add_equals_numpy_on_the_grown_index(self, toolchain):
+        docs = corpus(120)
+        params = BM25Params(k1=0.9, b=0.4)
+        nat = NativeBM25Index(params=params).build(docs[:50])
+        ref = BM25Index(params=params).build(docs)
+        assert nat._get_box() is not None
+        for start in range(50, 120, 35):
+            nat.add(docs[start:start + 35])
+            assert nat._box is None  # retired by the add; made again by the next query
+            for q in QUERIES:
+                # the numpy search over the same grown arrays (a pinned snapshot)
+                assert nat.search(q, top_k=10) == BM25Index.search(nat, q, 10, nat._epoch)
+            assert nat._get_box().n_docs == nat.size
+        for name in ("term_offsets", "post_docs", "post_tfs", "idf", "_norm"):
+            np.testing.assert_array_equal(getattr(nat, name), getattr(ref, name))
+        for q in QUERIES:
+            n, r = nat.search(q, top_k=10), ref.search(q, top_k=10)
+            assert [i for i, _ in n] == [i for i, _ in r]
+            np.testing.assert_allclose([s for _, s in n], [s for _, s in r], rtol=1e-5)
+
+    def test_add_retires_the_handle_and_the_last_reader_frees_it(self, toolchain):
+        nat = NativeBM25Index().build(corpus(60))
+        box = nat._get_box()
+        assert box is not None and box.acquire()
+        try:
+            nat.add(corpus(90)[60:])  # grow under the in-flight query
+            assert box._dead and box._pinned and box.n_docs == 60
+            assert all(0 <= di < 60 for di, _ in nat._native_search(box, "tpu jax kernel", 5))
+        finally:
+            box.release()
+        assert box._pinned == ()  # freed by its last reader: buffers unpinned
+        new = nat._get_box()
+        assert new is not box and new.n_docs == 90 and new.documents is nat._documents
+        assert nat.add([]) is nat and nat._box is new  # nothing added: the handle stays

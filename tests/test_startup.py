@@ -464,6 +464,7 @@ def test_info_startup_has_every_key(served, key):
     ("compile", "hits"), ("compile", "misses"), ("compile", "by_program"),
     ("ingest", "seconds_total"), ("ingest", "stages"), ("ingest", "calls"),
     ("ingest", "docs"), ("ingest", "chunks"), ("ingest", "index_size"),
+    ("ingest", "bm25_updates", "add"), ("ingest", "bm25_updates", "build"),
     ("weights", "read_s"), ("weights", "place_s"), ("phases", "weights"),
 ])
 def test_info_startup_has_what_the_queued_metrics_read(served, path):
@@ -482,6 +483,15 @@ def test_info_sums_the_uploads_stages(served):
     for stage in INGEST_STAGES:
         assert f'sentio_tpu_ingest_stage_seconds_total{{stage="{stage}"}}' in served["metrics"]
     assert served["metrics"].count("sentio_tpu_ingest_stage_seconds_total{") == len(INGEST_STAGES)
+
+
+def test_an_uploads_files_are_added_to_the_sparse_index_not_built_again(served):
+    assert served["info"]["startup"]["ingest"]["bm25_updates"] == {"add": 2, "build": 0}
+    assert 'sentio_tpu_bm25_updates_total{kind="add"} 2.0' in served["metrics"]
+    assert 'sentio_tpu_bm25_updates_total{kind="build"}' not in served["metrics"]
+    sparse = [s["fields"] for s in served["upload_record"]["spans"] if s["name"] == "ingest.sparse_add"]
+    assert [(f["path"], f["index_size"]) for f in sparse] == [("add", 1), ("add", 2)]
+    assert all(f["tokens"] > 0 for f in sparse)
 
 
 @pytest.mark.parametrize("stage", INGEST_STAGES)
